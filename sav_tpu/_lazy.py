@@ -2,11 +2,11 @@
 
 Four subpackages (:mod:`sav_tpu.utils`, :mod:`sav_tpu.obs`,
 :mod:`sav_tpu.data`, :mod:`sav_tpu.train`) carry the same import
-contract: their stdlib-only submodules (``backend_probe``, ``manifest``,
+contract: their stdlib-only submodules (``device_check``, ``manifest``,
 ``synthetic``, ``supervisor`` ...) must be importable without dragging
-``jax``/TF into the process — the backend probe and the elasticity
-supervisor run on exactly the paths (down relay, on-chip parent) where a
-heavy import hangs or delays the abort decision. One factory instead of
+``jax``/TF into the process — the elasticity supervisor and the serve
+pool are parents of on-chip children, and a parent that touched the
+backend would hold the chip against them. One factory instead of
 four hand-copied ``__getattr__``/``__dir__`` bodies keeps the contract's
 implementation in one place.
 

@@ -1,7 +1,7 @@
 """savlint core: file walking, AST facts, pragmas, baseline, reporting.
 
 The linter is deliberately stdlib-only (``ast`` + ``re``): it must run in
-CI frontends and pre-commit hooks that have no jax, no TPU relay, and no
+CI frontends and pre-commit hooks that have no jax, no TPU, and no
 interest in importing the training stack. Rules live in
 :mod:`sav_tpu.analysis.rules`; this module owns everything rule-agnostic:
 

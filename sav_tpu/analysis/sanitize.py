@@ -25,8 +25,8 @@ are exempt):
   on the jitted step that raises :class:`RetraceSanitizerError` the
   moment the compile cache grows after warmup. PR 1's ``retraces``
   metric *reports* silent recompilation at the next log window; the
-  sanitizer turns it into a step-attributed hard failure (on the relay
-  each silent retrace is minutes of compile, so "fail at the step that
+  sanitizer turns it into a step-attributed hard failure (each silent
+  retrace is seconds to minutes of compile, so "fail at the step that
   caused it" beats "notice it in telemetry later").
 """
 

@@ -40,6 +40,7 @@ def synth_batch(
     batch_size: int,
     image_size: int = 32,
     num_classes: int = 10,
+    transpose: bool = False,
     dtype=np.float32,
 ) -> dict:
     """The deterministic synthetic batch at schedule ``position``.
@@ -55,7 +56,8 @@ def synth_batch(
     train-step tests rely on), so loss curves carry information.
 
     Positions are 1-indexed completed-step numbers, matching the
-    recorder's ring entries and ``--skip-steps`` semantics.
+    recorder's ring entries and ``--skip-steps`` semantics. ``transpose``
+    lays the same images out HWCN (``TrainConfig.transpose_images``).
     """
     key = np.array([seed & 0xFFFFFFFFFFFFFFFF, position], np.uint64)
     rng = np.random.Generator(np.random.Philox(key=key))
@@ -64,6 +66,8 @@ def synth_batch(
         (batch_size, image_size, image_size, 3)
     ).astype(np.float32)
     images += (labels[:, None, None, None] / num_classes - 0.5) * 4.0
+    if transpose:
+        images = np.ascontiguousarray(images.transpose(1, 2, 3, 0))
     return {"images": images.astype(dtype), "labels": labels}
 
 
@@ -74,6 +78,7 @@ def synth_resumable_iterator(
     batch_size: int,
     image_size: int = 32,
     num_classes: int = 10,
+    transpose: bool = False,
     num_batches: Optional[int] = None,
     dtype=np.float32,
 ) -> Iterator[dict]:
@@ -92,6 +97,7 @@ def synth_resumable_iterator(
             batch_size=batch_size,
             image_size=image_size,
             num_classes=num_classes,
+            transpose=transpose,
             dtype=dtype,
         )
 

@@ -41,6 +41,7 @@ from typing import Any, Optional
 import numpy as np
 
 from sav_tpu.obs.diagnostics import _group_of
+from sav_tpu.utils.device_check import cpu_requested
 from sav_tpu.utils.flops import per_chip_peak_flops, xla_cost_analysis
 
 # Deterministic stand-in peak for CPU runs: obviously fake (no CPU does
@@ -97,14 +98,19 @@ def resolve_peak_flops(
     devices=None,
     *,
     dot_dtype: Optional[str] = None,
-) -> tuple[Optional[float], str]:
+) -> tuple[float, str]:
     """Per-chip peak FLOP/s and where the number came from.
 
     Resolution order: explicit ``override`` (``--peak-flops`` /
-    ``TrainConfig.peak_flops``) → the device-kind table
-    (:data:`~sav_tpu.utils.flops.PEAK_FLOPS_PER_CHIP`) → the
-    deterministic CPU fake → ``(None, 'unknown')`` for an accelerator the
-    table does not know (MFU is then unreportable rather than wrong).
+    ``TrainConfig.peak_flops``) → on the CPU, when the CPU was asked for
+    (``JAX_PLATFORMS=cpu`` and its equivalents —
+    :func:`sav_tpu.utils.device_check.cpu_requested`), the deterministic
+    fake, labeled ``cpu-fake`` → the device-kind table
+    (:data:`~sav_tpu.utils.flops.PEAK_FLOPS_PER_CHIP`, exact match on
+    ``device_kind``). An accelerator the table does not know raises
+    :class:`~sav_tpu.utils.flops.UnknownDeviceKindError`, and so does a
+    CPU nobody asked for: a made-up peak must never stand in for a
+    device's.
 
     ``dot_dtype`` keys the peak by what the dots actually run in
     (:data:`DOT_DTYPE_PEAK_FACTOR` — ``"int8"`` doubles the table's bf16
@@ -122,12 +128,10 @@ def resolve_peak_flops(
     )
     tag = f":{str(dot_dtype).lower()}" if factor != 1.0 else ""
     devices = jax.devices() if devices is None else devices
-    peak = per_chip_peak_flops(devices)
-    if peak:
-        return peak * factor, "device-table" + tag
-    if getattr(devices[0], "platform", None) == "cpu":
+    if getattr(devices[0], "platform", None) == "cpu" and cpu_requested():
         return CPU_FAKE_PEAK_FLOPS * factor, "cpu-fake" + tag
-    return None, "unknown"
+    peak, _source = per_chip_peak_flops(devices)
+    return peak * factor, "device-table" + tag
 
 
 @dataclasses.dataclass

@@ -15,7 +15,6 @@ Artifact layout (everything under ``<log_dir>/fleet/``)::
 
     fleet/proc_<i>.jsonl       one JSON line per heartbeat (per process)
     fleet/fleet.json           merged fleet manifest (process 0, atomic)
-    fleet/backend_probe.jsonl  startup probe timeline (bench.py give-up)
 
 Heartbeat discipline — the same contract savlint SAV111 enforces for the
 flight recorder, here enforced as SAV112: the per-beat path
@@ -369,45 +368,6 @@ class HeartbeatWriter:
         }
 
 
-def write_probe_timeline(
-    log_dir: str, probe_log: list, *, deadline_s: float, tag: str
-) -> Optional[str]:
-    """Write the backend-probe timeline into ``fleet/backend_probe.jsonl``.
-
-    The give-up path's post-mortem contract: the manifest says the run
-    never started (``outcome: backend_unreachable``), and the fleet dir
-    holds the per-probe timeline in the SAME artifact layout heartbeats
-    use — so "backend never came up" (probe lines, no ``proc_*.jsonl``)
-    and "backend died mid-run" (heartbeats that stop) are distinguishable
-    from one directory. Never raises; returns the path or None.
-    """
-    path = os.path.join(fleet_dir(log_dir), "backend_probe.jsonl")
-    try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        with open(path, "a") as f:
-            now = round(time.time(), 3)
-            for probe in probe_log:
-                record = {
-                    "schema": FLEET_SCHEMA,
-                    "kind": "probe",
-                    "tag": tag,
-                    "t": now,
-                }
-                record.update(probe)
-                f.write(json.dumps(record) + "\n")
-            f.write(json.dumps({
-                "schema": FLEET_SCHEMA,
-                "kind": "probe_giveup",
-                "tag": tag,
-                "deadline_s": deadline_s,
-                "attempts": len(probe_log),
-                "t": now,
-            }) + "\n")
-        return path
-    except OSError:
-        return None
-
-
 # ------------------------------------------------------------- aggregation
 
 
@@ -487,24 +447,6 @@ def read_router_beats(
                     continue  # torn tail of a killed router
                 if isinstance(doc, dict) and doc.get("kind") == "router":
                     records.append(doc)
-    except OSError:
-        pass
-    return records
-
-
-def read_probe_timeline(log_dir: str) -> list[dict]:
-    path = os.path.join(fleet_dir(log_dir), "backend_probe.jsonl")
-    records = []
-    try:
-        with open(path) as f:
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    records.append(json.loads(line))
-                except json.JSONDecodeError:
-                    continue
     except OSError:
         pass
     return records

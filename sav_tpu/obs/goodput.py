@@ -19,8 +19,7 @@ wall clock is split into buckets:
   eval        — evaluation passes
   checkpoint  — checkpoint save time on the training thread
   stall       — the *excess* of anomalous step windows over the expected
-                step time (the relay's >5x transient slowdowns,
-                bench.py docstring)
+                step time (transient slowdowns, bench.py docstring)
   other       — residual loop overhead (computed, never accounted)
 
 Gauges (:meth:`GoodputLedger.set_gauge`) carry scalar telemetry that is

@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from sav_tpu.ops import _backend
+
 _NEG_INF = float("-inf")
 
 
@@ -155,7 +157,7 @@ def _flash_forward(
     batch, q_len, heads, dim = q.shape
     kv_len = k.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _backend.default_interpret()
 
     # [B, L, H, D] -> [B*H, L, D]
     def to_bhld(x):
@@ -448,7 +450,7 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
     """Blocked backward; q/k/v/out/g are ``[B, L, H, D]``, lse is the padded
     ``[B·H, q_len_p, 128]`` forward residual."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _backend.default_interpret()
 
     geom = _bwd_prep(q, k, v, out, g, block_q, block_kv)
     qf, kf, vf, dof, delta = geom.qf, geom.kf, geom.vf, geom.dof, geom.delta
@@ -722,7 +724,7 @@ def _rel_forward(q, k, v, rw_abs, rh_abs, height, width, scale, block_q,
     batch, q_len, heads, dim = q.shape
     kv_len = k.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _backend.default_interpret()
 
     def to_bhld(x):
         b, l, h, d = x.shape
@@ -951,7 +953,7 @@ def _rel_backward_pallas(q, k, v, rw_abs, rh_abs, out, lse, g, height, width,
     gradient reduced to the compact per-axis ``[B, H, L, W]/[B, H, L, H]``
     tables — ``[B,H,L,L]`` never materializes in either direction."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _backend.default_interpret()
 
     geom = _bwd_prep(q, k, v, out, g, block_q, block_kv)
     qf, kf, vf, dof, delta = geom.qf, geom.kf, geom.vf, geom.dof, geom.delta

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from sav_tpu.ops import _backend
+
 from sav_tpu.ops.flash_attention import (
     _bwd_prep,
     _dense_recompute_bwd,
@@ -212,7 +214,7 @@ def _fused_forward(
     batch, q_len, heads, dim = q.shape
     kv_len = k.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _backend.default_interpret()
 
     def to_bhld(x):
         b, l, h, d = x.shape
@@ -411,7 +413,7 @@ def _fused_backward(q, k, v, out, lse, g, scale, block_q, block_b,
     """q/k/v/out/g ``[B, L, H, D]``; lse is the padded ``[B·H, q_len_p,
     128]`` forward residual."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _backend.default_interpret()
 
     kv_p = _kv_pad(k.shape[1])
     geom = _bwd_prep(q, k, v, out, g, block_q, kv_p)

@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from sav_tpu.ops import _backend
+
 _NEG_INF = float("-inf")
 
 # Soft cap on the kernel's VMEM working set. The dominant terms per grid
@@ -106,7 +108,7 @@ def _th_forward(q, k, v, w_pre, w_post, scale, block_q, interpret):
     batch, q_len, heads, dim = q.shape
     kv_len = k.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _backend.default_interpret()
 
     def to_bhld(x):
         return jnp.transpose(x, (0, 2, 1, 3))  # [B, H, L, D]
@@ -301,7 +303,7 @@ def _th_backward(q, k, v, w_pre, w_post, g, scale, block_q, interpret):
     batch, q_len, heads, dim = q.shape
     kv_len = k.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _backend.default_interpret()
 
     def to_bhld(x):
         return jnp.transpose(x, (0, 2, 1, 3))

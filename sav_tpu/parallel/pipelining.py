@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sav_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 from sav_tpu.parallel.mesh import PIPE_AXIS
 
@@ -174,6 +174,6 @@ def pipeline(
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(pipe_axis), stacked_params), spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stacked_params, x)

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from sav_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 from sav_tpu.parallel.mesh import SEQ_AXIS
 
@@ -359,6 +359,6 @@ def ring_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(query, key, value)

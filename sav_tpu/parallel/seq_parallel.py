@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from sav_tpu.parallel._compat import shard_map
+from jax import shard_map
 from sav_tpu.parallel.mesh import SEQ_AXIS, batch_axes
 from sav_tpu.parallel.ring_attention import (
     _ring_shard_fn,
@@ -186,7 +186,7 @@ def sequence_parallel_attention(
             mesh=mesh,
             in_specs=(spec, spec, spec, rep, rep),
             out_specs=spec,
-            check_rep=False,
+            check_vma=False,
         )(query, key, value, w_pre, w_post)
         if pad:
             out = out[:, :length]
@@ -211,7 +211,7 @@ def sequence_parallel_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(query, key, value)
     if pad:
         out = out[:, :length]

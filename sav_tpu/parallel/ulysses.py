@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from sav_tpu.parallel._compat import shard_map
+from jax import shard_map
 from sav_tpu.parallel.mesh import SEQ_AXIS
 
 
@@ -127,6 +127,6 @@ def ulysses_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(query, key, value)

@@ -6,9 +6,9 @@ engine owns:
 
 - **A bucket ladder of AOT executables.** Startup lowers + compiles one
   inference executable per (model, bucket batch size) — request time
-  never traces or compiles. With ``compilation_cache_dir`` set the
-  compiles round-trip the persistent XLA cache
-  (:mod:`sav_tpu.utils.compile_cache`): a restart re-reads them from
+  never traces or compiles. Where the persistent XLA cache is on
+  (:mod:`sav_tpu.utils.compile_cache`: always on a TPU, on the CPU when
+  a directory was given) a restart re-reads the compiles from
   disk in milliseconds, and :attr:`startup_report` counts cache hits vs
   from-scratch compiles so the warm path is assertable, not assumed.
 - **A deadline-aware dynamic batcher** (:mod:`sav_tpu.serve.batcher`):
@@ -112,8 +112,10 @@ class ServeConfig:
     # prerequisite). None keeps the single-device default (replicate
     # engines for more chips).
     layout_preset: Optional[str] = None
-    # Persistent XLA compile cache: a warm second start compiles nothing
-    # from scratch (startup_report["compiled_from_scratch"] == 0).
+    # Override of the persistent compile cache's default directory
+    # (loses to JAX_COMPILATION_CACHE_DIR — sav_tpu/utils/compile_cache.py).
+    # With the cache on, a warm second start compiles nothing from scratch
+    # (startup_report["compiled_from_scratch"] == 0).
     compilation_cache_dir: Optional[str] = None
     # Sink for the serving run manifest (None disables).
     log_dir: Optional[str] = None
@@ -199,21 +201,6 @@ def build_infer_fn(model, compute_dtype) -> Callable:
     return infer
 
 
-def _count_cache_entries(cache_dir: Optional[str]) -> Optional[int]:
-    """Executable entries in the persistent compile cache (None when
-    disabled) — the before/after delta across the AOT loop is the
-    from-scratch compile count. jax writes a ``*-cache`` payload plus a
-    ``*-atime`` access stamp per entry; only the payloads are entries
-    (and the stamps are REWRITTEN on cache hits, so counting them would
-    book a warm start as a recompile)."""
-    if not cache_dir or not os.path.isdir(cache_dir):
-        return 0 if cache_dir else None
-    total = 0
-    for _, _, files in os.walk(cache_dir):
-        total += sum(1 for f in files if not f.endswith("-atime"))
-    return total
-
-
 class ServeEngine:
     """One model, one bucket ladder of warm executables, one batcher.
 
@@ -247,17 +234,19 @@ class ServeEngine:
         self.ladder = config.ladder()
         self.place_hook = place_hook
         self.execute_hook = execute_hook
-        cache_before = _count_cache_entries(config.compilation_cache_dir)
-        if config.compilation_cache_dir:
-            from sav_tpu.utils.compile_cache import enable_persistent_cache
+        from sav_tpu.utils.compile_cache import (
+            count_cache_entries,
+            enable_persistent_cache,
+        )
 
-            # min_compile_time 0: jax's ~1s default floor is tuned for
-            # training (don't litter the cache with trivial programs),
-            # but a serving restart wants EVERY bucket executable back
-            # from disk — a warm start must compile nothing from scratch.
-            enable_persistent_cache(
-                config.compilation_cache_dir, min_compile_time_secs=0.0
-            )
+        # min_compile_time 0: jax's 1 s default floor is tuned for
+        # training (don't litter the cache with trivial programs), but a
+        # serving restart wants EVERY bucket executable back from disk —
+        # a warm start must compile nothing from scratch.
+        cache_dir = enable_persistent_cache(
+            config.compilation_cache_dir, min_compile_time_secs=0.0
+        )
+        cache_before = count_cache_entries(cache_dir)
         if config.attention_tune_cache:
             from sav_tpu.ops.attn_tuning import set_cache_path
 
@@ -401,7 +390,7 @@ class ServeEngine:
         )
         # ---- AOT: one executable per bucket, warmed from the cache ----
         compile_t0 = time.perf_counter()
-        cache_pre_aot = _count_cache_entries(config.compilation_cache_dir)
+        cache_pre_aot = count_cache_entries(cache_dir)
         self._executables: dict = {}
         for bucket in self.ladder.buckets:
             lowered = self._infer.lower(
@@ -409,7 +398,7 @@ class ServeEngine:
             )
             self._executables[bucket] = lowered.compile()
         compile_s = time.perf_counter() - compile_t0
-        cache_after = _count_cache_entries(config.compilation_cache_dir)
+        cache_after = count_cache_entries(cache_dir)
         # Per-bucket executable HBM estimate (ride-along fix: the report
         # used to say nothing about how much device memory each rung
         # costs, so a ladder that barely fit was invisible until the
@@ -484,6 +473,7 @@ class ServeEngine:
             "warmup_step_s": {
                 str(b): round(s, 5) for b, s in self._step_est.items()
             },
+            "cache_dir": cache_dir,
             "cache_entries_before": cache_before,
             "cache_entries_after": cache_after,
             # The warm-start proof: from-scratch compiles this startup

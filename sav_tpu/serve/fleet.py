@@ -26,10 +26,9 @@ cue to mark the replica down and reroute — and a replica-side
 admission reject as :class:`~sav_tpu.serve.router.ReplicaShedError`.
 
 Import contract: **stdlib-only at module scope** (no jax, no numpy) —
-the pool runs in the parent of on-chip replicas, where importing the
-backend is exactly what hangs (the supervisor/backend_probe
-philosophy), and the transport runs inside the router's no-jax
-surface. docs/serving.md "Fleet" is the subsystem guide.
+the pool runs in the parent of on-chip replicas, and a parent that
+touched the backend would hold the chip against them; the transport
+runs inside the router's no-jax surface. docs/serving.md "Fleet" is the subsystem guide.
 """
 
 from __future__ import annotations
@@ -44,6 +43,10 @@ from typing import Callable, Optional
 
 from sav_tpu.serve.router import ReplicaShedError, ReplicaTransportError
 from sav_tpu.train.supervisor import Supervisor
+from sav_tpu.utils.device_check import (
+    EXIT_BACKEND_UNREACHABLE,
+    BackendUnreachableError,
+)
 
 FLEET_POOL_SCHEMA = 1
 
@@ -439,6 +442,16 @@ class ReplicaPool:
                     and not entry.thread.is_alive()
                     and entry.exit_code not in (None, 0)
                 ):
+                    if entry.exit_code == EXIT_BACKEND_UNREACHABLE:
+                        raise BackendUnreachableError(
+                            f"replica {rank} found no TPU it could claim "
+                            "(exit 3, backend_unreachable). A chip belongs "
+                            "to one process at a time and every replica is "
+                            f"its own process: {self.replicas} replicas "
+                            f"need {self.replicas} chips, each visible to "
+                            "one replica only — see "
+                            f"{self.rank_dir(rank)}/attempts/ for its output"
+                        )
                     raise RuntimeError(
                         f"replica {rank}'s supervisor chain ended "
                         f"(exit {entry.exit_code}) before the replica "

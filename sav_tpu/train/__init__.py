@@ -4,8 +4,8 @@ and the elastic-training supervisor.
 Re-exports are lazy (PEP 562 via :mod:`sav_tpu._lazy`, the same pattern
 as :mod:`sav_tpu.obs` / :mod:`sav_tpu.utils`):
 :mod:`sav_tpu.train.supervisor` is stdlib-only by contract (it runs in
-the parent of on-chip jobs, where importing the backend is exactly what
-hangs — see ``utils.backend_probe``), so the package import must not
+the parent of on-chip jobs, and a parent that touched the backend would
+hold the chip against its child), so the package import must not
 drag jax/orbax in eagerly.
 """
 

@@ -79,10 +79,7 @@ class Checkpointer:
                 raise FileNotFoundError(
                     f"checkpoint directory does not exist: {self._dir!r}"
                 )
-            try:
-                options = ocp.CheckpointManagerOptions(read_only=True)
-            except TypeError:  # older orbax without the flag
-                options = ocp.CheckpointManagerOptions(create=False)
+            options = ocp.CheckpointManagerOptions(read_only=True)
         else:
             os.makedirs(self._dir, exist_ok=True)
             options = ocp.CheckpointManagerOptions(
@@ -225,13 +222,9 @@ class Checkpointer:
         # StandardSave writes through PyTreeCheckpointHandler, so the
         # on-disk layout is shared; only PyTreeRestore exposes the
         # partial-tree ``transforms`` path.
-        try:
-            options = ocp.CheckpointManagerOptions(read_only=True)
-        except TypeError:  # pragma: no cover - older orbax
-            options = ocp.CheckpointManagerOptions(create=False)
         reader = ocp.CheckpointManager(
             self._dir,
-            options=options,
+            options=ocp.CheckpointManagerOptions(read_only=True),
             item_handlers=ocp.PyTreeCheckpointHandler(),
         )
         try:

@@ -86,10 +86,10 @@ class TrainConfig:
     # eval inside fit() the train feeder's queue stays full, so the two
     # bounds stack.
     feed_depth: int = 2
-    # Persistent XLA compilation cache directory
-    # (jax_compilation_cache_dir; sav_tpu/utils/compile_cache.py). Repeat
-    # runs of the same program skip the multi-minute compile — the 493 s
-    # TNT trace (PERF.md §12) becomes a disk read. None disables.
+    # Override of the persistent compile cache's default directory
+    # (sav_tpu/utils/compile_cache.py states the rule: the
+    # JAX_COMPILATION_CACHE_DIR variable wins over this; unset, a TPU run
+    # caches under the checkout's .jax_cache/ and a CPU run not at all).
     compilation_cache_dir: Optional[str] = None
 
     # Data

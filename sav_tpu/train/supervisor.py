@@ -1,10 +1,9 @@
 """Preemption-tolerant supervised training — bounded restarts, manifest
 chains, rewind-and-skip, and goodput-loss accounting.
 
-Two of five on-chip bench rounds died ``backend_unreachable`` (BENCH_r03/
-r05): at production scale preemption and chip loss are the steady state,
+At production scale preemption and chip loss are the steady state,
 not the exception. The observability substrate already *names* every
-failure — the backend probe exits 3, the hang watchdog exits 4, the run
+failure — the device check exits 3, the hang watchdog exits 4, the run
 manifest stamps ``nonfinite``/``oom``/``error`` on crash paths, the
 flight recorder dumps the offending batches — but nothing *survived*
 them: a killed run stayed dead until a human restarted it, and the lost
@@ -17,11 +16,12 @@ its goodput to exactly this automation):
 - **Bounded restarts.** The child ``train.py`` is re-spawned on failure
   with exponential backoff, up to ``max_restarts``. Exit 0 ends the
   chain; exit 2 (usage error) is terminal — restarting a typo does not
-  help. Everything else (probe exit 3, watchdog exit 4, crash, signal
-  kill) restarts. Resume is the trainer's own step-exact restore: the
-  supervisor only observes the checkpoint directory, it never touches
-  jax (same philosophy as ``utils.backend_probe`` — the parent must
-  stay alive precisely when backend init would hang).
+  help. Everything else (device-check exit 3, watchdog exit 4, crash,
+  signal kill) restarts — except that a *serving* replica's exit 3 is
+  terminal too (its siblings hold the chips; see ``run``). Resume is
+  the trainer's own step-exact restore: the supervisor only observes the checkpoint directory, it never touches
+  jax (a chip belongs to one process at a time — the parent must
+  leave it to the child).
 - **Manifest chain.** Each attempt's ``manifest.json`` is preserved
   under ``<log_dir>/attempts/`` before the next attempt overwrites it,
   and one supervisor manifest (``supervisor.json`` — a regular
@@ -69,7 +69,7 @@ CHAIN_SCHEMA = 1
 
 #: Exit codes with contract meaning (docs/elasticity.md):
 #:   0 — done;  2 — usage error (terminal, restarting cannot help);
-#:   3 — backend unreachable (utils.backend_probe);  4 — hang watchdog.
+#:   3 — backend unreachable (utils.device_check);  4 — hang watchdog.
 EXIT_OK = 0
 EXIT_USAGE = 2
 EXIT_BACKEND = 3
@@ -820,6 +820,22 @@ class Supervisor:
                     exit_code=EXIT_USAGE,
                 )
                 return EXIT_USAGE
+            if self.serve and rc == EXIT_BACKEND:
+                # A serving replica that found no chip of its own at
+                # start will not find one by restarting: its siblings
+                # hold the chips for as long as the fleet lives (one
+                # process per chip). Terminal, so the pool fails at once
+                # with the reason instead of spending the restart budget.
+                goodput = self._account()
+                self._publish(goodput)
+                self.manifest.finalize(
+                    "backend_unreachable",
+                    error="replica found no TPU it could claim (exit 3): "
+                    "restarting cannot help while other processes hold "
+                    "the chips",
+                    exit_code=EXIT_BACKEND,
+                )
+                return EXIT_BACKEND
             decided = (
                 [] if self.serve else self._decide_skip(outcome, t_start)
             )

@@ -11,29 +11,42 @@ which is correct for any mesh size without knowing the global batch.
 
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 
-# Peak dense bf16 FLOP/s per chip, matched on substrings of
-# ``jax.Device.device_kind``.
+# Peak dense bf16 FLOP/s per chip, keyed by the exact
+# ``jax.Device.device_kind`` string, each with the source of the number.
+# A device that is not in the table is an error, not a default: a wrong
+# peak makes every MFU and roofline share wrong without a trace.
 PEAK_FLOPS_PER_CHIP = {
-    "v5 lite": 197e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v4": 275e12,
-    "v6": 918e12,
+    "TPU v4": (275e12, "Google Cloud documentation, TPU v4"),
+    "TPU v5 lite": (197e12, "Google Cloud documentation, TPU v5e"),
+    "TPU v5": (459e12, "Google Cloud documentation, TPU v5p"),
+    "TPU v6 lite": (918e12, "Google Cloud documentation, TPU v6e"),
 }
 
 
-def per_chip_peak_flops(devices=None) -> Optional[float]:
-    """Peak bf16 FLOP/s of one chip (None if the device kind is unknown)."""
+class UnknownDeviceKindError(LookupError):
+    """``device_kind`` has no entry in :data:`PEAK_FLOPS_PER_CHIP`."""
+
+
+def per_chip_peak_flops(devices=None) -> tuple[float, str]:
+    """(peak bf16 FLOP/s of one chip, where the number comes from).
+
+    Raises :class:`UnknownDeviceKindError` for a ``device_kind`` the table
+    does not list — add the kind and its published peak to the table, or
+    pass an explicit ``--peak-flops``.
+    """
     devices = jax.devices() if devices is None else devices
-    kind = getattr(devices[0], "device_kind", "").lower()
-    for key, peak in PEAK_FLOPS_PER_CHIP.items():
-        if key in kind:
-            return peak
-    return None
+    kind = getattr(devices[0], "device_kind", None)
+    try:
+        return PEAK_FLOPS_PER_CHIP[kind]
+    except KeyError:
+        raise UnknownDeviceKindError(
+            f"no peak FLOP/s known for device_kind {kind!r} (known: "
+            f"{sorted(PEAK_FLOPS_PER_CHIP)}); add it to "
+            "sav_tpu/utils/flops.py::PEAK_FLOPS_PER_CHIP with its source "
+            "or pass --peak-flops"
+        ) from None
 
 
 def xla_cost_analysis(compiled) -> dict:

@@ -122,8 +122,8 @@ def benchmark_fn(
 
     Returns mean/min seconds per call. ``fn`` should return a jax array or
     pytree of arrays; synchronization is via ``block_until_ready`` on every
-    leaf plus a final ``device_get`` (some relayed platforms complete
-    ``block_until_ready`` before execution finishes).
+    leaf plus a final ``device_get`` (the host then holds a value only a
+    finished execution can produce).
     """
 
     def sync(out):

@@ -410,7 +410,7 @@ def test_evaluate_feeder_matches_serial_with_padded_final_batch(devices):
 def test_compilation_cache_dir_persists_compiles(tmp_path, devices):
     """TrainConfig.compilation_cache_dir routes compiles through the
     persistent XLA cache: after one step, the directory holds entries
-    (what makes the 493 s TNT recompile a disk read on round trips)."""
+    (what makes a repeated multi-minute compile a disk read)."""
     from sav_tpu.utils.compile_cache import (
         disable_persistent_cache,
         enable_persistent_cache,
@@ -420,7 +420,9 @@ def test_compilation_cache_dir_persists_compiles(tmp_path, devices):
     try:
         # Floor at 0 so the tiny CPU test program qualifies for the cache
         # (the Trainer default keeps jax's ~1 s floor for real programs).
-        assert enable_persistent_cache(cache_dir, min_compile_time_secs=0.0)
+        assert enable_persistent_cache(
+            cache_dir, min_compile_time_secs=0.0
+        ) == cache_dir
         trainer = _feeder_trainer(compilation_cache_dir=cache_dir)
         state = trainer.init_state()
         batch = _batches(1)[0]

@@ -2,8 +2,7 @@
 enumerated + ranked, infeasible configs recorded (never fatal), top-K
 measured with the Trap-pinned scan loop, a preset emitted — and the
 preset consumed by the trainer, closing the ISSUE-13 loop on CPU before
-the on-chip battery round (tools/battery/r13.steps) proves it at chip
-step times."""
+an on-chip round (not yet run) proves it at chip step times."""
 
 import importlib.util
 import json

@@ -39,28 +39,64 @@ def vit_params():
 
 
 def test_peak_resolution_order():
-    # Override beats everything; CPU falls through the device table to
-    # the deterministic fake — labeled, so it can never masquerade as a
-    # hardware number.
+    # Override beats everything; the CPU this suite asked for
+    # (tests/conftest.py pins jax_platforms=cpu) gets the deterministic
+    # fake — labeled, so it can never masquerade as a hardware number.
     assert resolve_peak_flops(5e12) == (5e12, "override")
     peak, source = resolve_peak_flops()
     assert source == "cpu-fake"
     assert peak == CPU_FAKE_PEAK_FLOPS
 
 
-def test_device_table_matches_on_kind():
+def test_cpu_fake_only_where_the_cpu_was_asked_for(monkeypatch):
+    """A CPU that jax fell back to by itself gets no made-up peak: the
+    lookup fails like any device the table does not know."""
+    import jax
+
+    from sav_tpu.obs import costs
+    from sav_tpu.utils.flops import UnknownDeviceKindError
+
+    monkeypatch.setattr(costs, "cpu_requested", lambda: False)
+    with pytest.raises(UnknownDeviceKindError, match="cpu"):
+        resolve_peak_flops(None, devices=jax.devices()[:1])
+    # An explicit override still works anywhere.
+    assert resolve_peak_flops(2e12, devices=jax.devices()[:1]) == (
+        2e12, "override",
+    )
+
+
+def test_device_table_matches_kind_exactly_and_carries_sources():
+    from sav_tpu.utils.flops import (
+        PEAK_FLOPS_PER_CHIP,
+        UnknownDeviceKindError,
+        per_chip_peak_flops,
+    )
+
     class FakeDevice:
         platform = "tpu"
-        device_kind = "TPU v5 lite"
+        device_kind = "TPU v5 lite"  # what a v5e reports
 
     peak, source = resolve_peak_flops(None, devices=[FakeDevice()])
     assert (peak, source) == (197e12, "device-table")
+    assert per_chip_peak_flops([FakeDevice()]) == (
+        197e12, "Google Cloud documentation, TPU v5e",
+    )
+    # Every entry names where its number comes from.
+    assert all(
+        isinstance(p, float) and src for p, src in PEAK_FLOPS_PER_CHIP.values()
+    )
 
-    class Unknown:
-        platform = "tpu"
-        device_kind = "TPU v99"
+    # An unknown accelerator is an error, not a default — and kinds that
+    # merely CONTAIN a known one ("v4", "v6" as substrings) do not match.
+    for kind in ("TPU v99", "TPU v5 lite pod", "NVIDIA v4", "tpu v5 lite", ""):
+        class Unknown:
+            platform = "tpu"
+            device_kind = kind
 
-    assert resolve_peak_flops(None, devices=[Unknown()]) == (None, "unknown")
+        with pytest.raises(UnknownDeviceKindError, match="no peak FLOP/s"):
+            resolve_peak_flops(None, devices=[Unknown()])
+        with pytest.raises(UnknownDeviceKindError):
+            per_chip_peak_flops([Unknown()])
 
 
 def test_dot_dtype_axis_scales_peak_and_tags_source():

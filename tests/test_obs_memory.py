@@ -35,7 +35,7 @@ def test_hbm_stats_aggregates_fake_devices():
 def test_hbm_stats_skips_raising_devices():
     class Bad:
         def memory_stats(self):
-            raise RuntimeError("relay refused")
+            raise RuntimeError("backend refused")
 
     assert hbm_stats([Bad()]) == {}
 

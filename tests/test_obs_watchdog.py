@@ -22,8 +22,8 @@ class FakeExit:
         self.called.set()
 
 
-def test_exit_code_contract_distinct_from_backend_probe():
-    # backend_probe aborts startup with 3; the watchdog owns 4. Wrapper
+def test_exit_code_contract_distinct_from_device_check():
+    # device_check aborts startup with 3; the watchdog owns 4. Wrapper
     # scripts key on both — pin the constant.
     assert WATCHDOG_EXIT_CODE == 4
 

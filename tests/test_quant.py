@@ -436,8 +436,8 @@ def test_full_depth_hbm_ratio_clears_the_gate():
     no compile): at real depth the int8 kernels dominate the param
     bytes and the serving tree weighs ≤0.6× its bf16 equivalent. The
     shallow smoke models do NOT clear this (conv-embed tables dominate
-    at depth 1-2) — depth is what the gate speaks to, which is why
-    tools/battery/r17.steps proves it on the full-size model."""
+    at depth 1-2) — depth is what the gate speaks to; the full-size
+    model's proof on the chip has not been run."""
     from sav_tpu.models import create_model
 
     kwargs = dict(
@@ -509,8 +509,8 @@ def test_serve_bench_quant_does_not_compose_with_replicas(capsys):
 
 def test_zoo_quant_serve_check_all_seven_families_on_cpu(capsys):
     """Every family's int8 serving program builds and runs finite on
-    CPU under the smoke shrink (the full-size on-chip sweep is
-    tools/battery/r17.steps zoo_int8)."""
+    CPU under the smoke shrink (the full-size on-chip sweep has not
+    been run)."""
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     try:
         import zoo_tpu_check

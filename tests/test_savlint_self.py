@@ -96,7 +96,7 @@ def test_trainer_hot_loop_suppressions_are_the_known_set():
     assert rules.count("SAV113") == 4
     # + the ONE sanctioned unbounded wait (SAV123): fit's final
     # checkpointer.wait() — the watchdog is deliberately stopped first
-    # so the flush can take as long as the relay needs.
+    # so the flush can take as long as the storage needs.
     assert rules.count("SAV123") == 1
     assert len(suppressed) == 15
 
@@ -205,17 +205,17 @@ def test_unscaled_int8_cast_suppressions_are_zero():
 def test_library_exit_suppressions_are_the_two_contracts():
     """SAV114's sanctioned library exits stay exactly the documented
     pair (docs/elasticity.md exit-code table): the watchdog's os._exit
-    capability and the backend probe's SystemExit(3). A third bare exit
+    capability and the device check's SystemExit(3). A third bare exit
     in sav_tpu/ must extend this consciously, not ride in on a pragma."""
     paths = [
         os.path.join(ROOT, "sav_tpu", "obs", "watchdog.py"),
-        os.path.join(ROOT, "sav_tpu", "utils", "backend_probe.py"),
+        os.path.join(ROOT, "sav_tpu", "utils", "device_check.py"),
     ]
     result = lint_paths(paths, root=ROOT)
     assert result.findings == []
     sav114 = [f for f in result.suppressed if f.rule == "SAV114"]
     assert sorted(os.path.basename(f.path) for f in sav114) == [
-        "backend_probe.py", "watchdog.py",
+        "device_check.py", "watchdog.py",
     ]
     # The supervisor itself — the layer most tempted to exit — never
     # does: it RETURNS exit codes (train.py owns the process exit).

@@ -1,0 +1,292 @@
+"""Ask the TPU's compiler, without a TPU: the kernels and the main-path
+programs compile for a described ``v5e:2x2`` chip with ``interpret=False``.
+
+Interpret-mode tests cannot see what the chip's compiler refuses — a slice
+not aligned to the tiling, more fast memory than a kernel may use, a
+program that does not fit 16 GB. These compiles can, at no chip time. A
+compile that passes is not a chip run and says nothing about results or
+speed (``chip_smoke.py`` is the chip run).
+
+The topology is described inside a module-scoped fixture and nowhere
+else: only one process may load the TPU's library, every xdist worker
+imports every test file, and a module that touched the topology while it
+was imported would make the workers disagree about what to collect. The
+compiles run in this test's own process, all in this one file, with the
+persistent compile cache off (a described-device entry cannot be read
+back without a chip and would only produce warnings).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def compiled_kernels(monkeypatch):
+    """Whole programs pick interpret mode from the backend's name, and the
+    backend here is the CPU: steer them to the compiled kernel from the
+    test (the program has no option for it, on purpose)."""
+    from sav_tpu.ops import _backend
+
+    monkeypatch.setattr(_backend, "default_interpret", lambda: False)
+
+
+def _bytes_on_device(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+
+
+# ------------------------------------------------------------- the kernels
+
+# (name, kernel, [B, L, H, D]) at the shapes the zoo uses, plus one
+# long-sequence shape the flash kernel exists for.
+KERNEL_CASES = [
+    ("flash-deit_s", "flash", (256, 197, 6, 64)),
+    ("fused-deit_s", "fused", (256, 197, 6, 64)),
+    ("flash-long", "flash", (8, 4096, 6, 64)),
+    ("talking_heads-cait_xxs24", "talking_heads", (256, 196, 4, 48)),
+    ("flash-tnt_outer", "flash", (64, 785, 6, 64)),
+    ("fused-tnt_outer", "fused", (64, 785, 6, 64)),
+    ("flash-tnt_inner", "flash", (12544, 17, 4, 6)),
+    ("fused-tnt_inner", "fused", (12544, 17, 4, 6)),
+    ("relative_position-botnet_14x14", "botnet", (64, 196, 4, 128)),
+]
+
+
+def _kernel_fn_and_args(kernel, shape, sharding):
+    from sav_tpu.ops.flash_attention import (
+        flash_attention,
+        flash_botnet_attention,
+    )
+    from sav_tpu.ops.fused_attention import fused_attention
+    from sav_tpu.ops.talking_heads import flash_talking_heads_attention
+
+    def spec(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
+
+    qkv = (spec(shape),) * 3
+    heads, dim = shape[2], shape[3]
+    if kernel == "flash":
+        return (lambda q, k, v: flash_attention(q, k, v, interpret=False)), qkv
+    if kernel == "fused":
+        return (lambda q, k, v: fused_attention(q, k, v, interpret=False)), qkv
+    if kernel == "talking_heads":
+        mix = spec((heads, heads), jnp.float32)
+        return (
+            lambda q, k, v, w_pre, w_post: flash_talking_heads_attention(
+                q, k, v, w_pre, w_post, interpret=False
+            )
+        ), qkv + (mix, mix)
+    if kernel == "botnet":
+        side = int(round(shape[1] ** 0.5))
+        rel = spec((2 * side - 1, dim), jnp.float32)
+        return (
+            lambda q, k, v, rel_h, rel_w: flash_botnet_attention(
+                q, k, v, rel_h, rel_w, side, side, interpret=False
+            )
+        ), qkv + (rel, rel)
+    raise ValueError(kernel)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize(
+    "kernel,shape",
+    [(kernel, shape) for _, kernel, shape in KERNEL_CASES],
+    ids=[name for name, _, _ in KERNEL_CASES],
+)
+def test_kernel_compiles_for_v5e(one_chip, kernel, shape, direction):
+    fn, args = _kernel_fn_and_args(kernel, shape, one_chip)
+    if direction == "backward":
+        forward = fn
+
+        def fn(*a):
+            return jax.grad(
+                lambda *b: forward(*b).astype(jnp.float32).sum(),
+                argnums=tuple(range(len(a))),
+            )(*a)
+
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _bytes_on_device(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_ring_attention_compiles_for_four_chips(topo, backend, direction):
+    """The sequence-parallel ring over the 2x2 host at a length the dense
+    path cannot hold (L 16384): the kernel inside ``shard_map`` and the
+    ``ppermute`` ring both reach the compiler."""
+    from jax.sharding import Mesh
+
+    from sav_tpu.parallel.ring_attention import ring_attention
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "seq"))
+    qkv = jax.ShapeDtypeStruct(
+        (2, 16384, 6, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, "seq", None, None)),
+    )
+
+    def fn(q, k, v):
+        return ring_attention(
+            q, k, v, mesh=mesh, backend=backend,
+            interpret=False if backend == "pallas" else None,
+        )
+
+    if direction == "backward":
+        forward = fn
+
+        def fn(q, k, v):
+            return jax.grad(
+                lambda *a: forward(*a).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2),
+            )(q, k, v)
+
+    text = jax.jit(fn).lower(qkv, qkv, qkv).compile().as_text()
+    assert "collective-permute" in text
+    assert ("tpu_custom_call" in text) == (backend == "pallas")
+
+
+# ------------------------------------------------- the main-path programs
+
+
+def _abstract_train_args(trainer, batch_size, image_size, mesh):
+    abstract = jax.eval_shape(trainer.init_state)
+    shardings = trainer._blayout.param_shardings(abstract)
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        abstract, shardings,
+    )
+    batch_sharding = trainer._blayout.batch_sharding()
+    batch = {
+        "images": jax.ShapeDtypeStruct(
+            (batch_size, image_size, image_size, 3), jnp.float32,
+            sharding=batch_sharding,
+        ),
+        "labels": jax.ShapeDtypeStruct(
+            (batch_size,), jnp.int32, sharding=batch_sharding
+        ),
+    }
+    rng = jax.ShapeDtypeStruct(
+        (2,), jnp.uint32, sharding=NamedSharding(mesh, P())
+    )
+    return state, batch, rng
+
+
+def _deit_s_trainer(mesh_axes, devices, backend=None):
+    from sav_tpu.parallel import create_mesh
+    from sav_tpu.train import TrainConfig, Trainer
+
+    mesh = create_mesh(mesh_axes, devices=devices)
+    config = TrainConfig(
+        model_name="deit_s_patch16",
+        num_classes=1000,
+        image_size=224,
+        compute_dtype="bfloat16",
+        global_batch_size=256,
+        transpose_images=False,
+        attention_backend=backend,
+        seed=0,
+    )
+    return Trainer(config, mesh=mesh), mesh
+
+
+@pytest.mark.parametrize("backend", [None, "fused", "pallas"])
+def test_deit_s_train_step_compiles_and_fits_one_chip(
+    topo, compiled_kernels, backend
+):
+    """chip_smoke.py's train phase: the whole DeiT-S step at batch 256."""
+    trainer, mesh = _deit_s_trainer({"data": 1}, topo.devices[:1], backend)
+    compiled = trainer.compile_train_step(
+        *_abstract_train_args(trainer, 256, 224, mesh)
+    )
+    assert _bytes_on_device(compiled) < HBM_BYTES
+    has_kernel = "tpu_custom_call" in compiled.as_text()
+    assert has_kernel == (backend is not None)
+
+
+def test_deit_s_sharded_train_step_compiles_for_four_chips(topo):
+    """chip_smoke.py --chips 4: data x model over the 2x2 host."""
+    trainer, mesh = _deit_s_trainer({"data": 2, "model": 2}, topo.devices)
+    state, batch, rng = _abstract_train_args(trainer, 256, 224, mesh)
+    compiled = trainer.compile_train_step(state, batch, rng)
+    assert "all-reduce" in compiled.as_text()
+    # Per-device bytes: less than the one-chip step's, and a kernel of
+    # the tensor-parallel FFN is split over the model axis.
+    assert _bytes_on_device(compiled) < HBM_BYTES // 2
+    fc1 = state.params["Encoder_0"]["block_0"]["FFBlock_0"]["fc1"]["kernel"]
+    assert "model" in jax.tree.leaves(tuple(fc1.sharding.spec))
+
+
+def test_largest_serve_bucket_compiles_and_fits_one_chip(one_chip):
+    """chip_smoke.py's serve phase: the engine's program at bucket 8."""
+    from sav_tpu.models import create_model
+    from sav_tpu.serve.engine import build_infer_fn
+    from sav_tpu.serve.quality import digested_infer_fn
+
+    model = create_model(
+        "deit_s_patch16", num_classes=1000, dtype=jnp.bfloat16
+    )
+    params = jax.eval_shape(
+        lambda rng: model.init(
+            {"params": rng}, jnp.zeros((2, 224, 224, 3), jnp.bfloat16),
+            is_training=False,
+        )["params"],
+        jax.random.PRNGKey(0),
+    )
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        params,
+    )
+    batch = {
+        "images": jax.ShapeDtypeStruct(
+            (8, 224, 224, 3), jnp.uint8, sharding=one_chip
+        ),
+        "valid": jax.ShapeDtypeStruct((8,), jnp.float32, sharding=one_chip),
+    }
+    infer = jax.jit(digested_infer_fn(build_infer_fn(model, jnp.bfloat16)))
+    compiled = infer.lower(params, {}, batch).compile()
+    assert _bytes_on_device(compiled) < HBM_BYTES
+
+
+def test_described_device_kind_is_in_the_peak_table(topo):
+    """The v5e reports ``TPU v5 lite``; the peak table matches it exactly."""
+    from sav_tpu.utils.flops import per_chip_peak_flops
+
+    peak, source = per_chip_peak_flops(topo.devices[:1])
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    assert peak == 197e12 and "v5e" in source
+    assert np.isfinite(peak)

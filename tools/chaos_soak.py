@@ -41,14 +41,13 @@ CPU smoke (tier-1 runs a scaled version of exactly this):
   python tools/chaos_soak.py --log-dir /tmp/soak --platform cpu \\
       --steps 60 --kill-at-steps 12,28 --nan-at-step 40
 
-On-chip soak (tools/battery/r9.steps): seeded-random kills over a long
+On-chip soak (not yet run): seeded-random kills over a long
 run, ``--loss-tol`` loosened for bf16, the sentinel gating
 ``goodput_frac`` from the supervisor manifest afterwards.
 
 The harness itself NEVER imports jax — it is the parent of on-chip
-children, and the parent must not be hangable by the backend (the
-``utils.backend_probe`` philosophy; numpy loads lazily for the batch
-fingerprints).
+children, and a parent that touched the backend would hold the chip
+against them (numpy loads lazily for the batch fingerprints).
 """
 
 from __future__ import annotations

@@ -19,7 +19,7 @@ harness demonstrates overlap even on rigs where the real host stream is
 faster than the device step (a laptop CPU run); leave it 0 to measure
 your actual pipeline balance.
 
-CPU-safe (no relay probe): a virtual-device run measures real overlap of
+CPU-safe: a virtual-device run measures real overlap of
 real device_puts, just at CPU scale.
 
 Usage:

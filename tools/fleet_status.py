@@ -6,7 +6,6 @@ writer produces (``sav_tpu/obs/fleet.py``, docs/fleet.md):
 
   proc_<i>.jsonl       per-process heartbeat streams
   fleet.json           merged fleet manifest (process 0's in-run view)
-  backend_probe.jsonl  startup probe timeline (the bench give-up path)
 
 and re-aggregates the streams offline — the rendered straggler ranking /
 dead-host suspicion always reflects the COMPLETE streams, not the
@@ -42,7 +41,6 @@ from sav_tpu.obs.fleet import (  # noqa: E402
     fleet_dir,
     format_unix as _fmt_unix,
     read_autoprof_captures as autoprof_captures,
-    read_probe_timeline,
     read_router_beats,
 )
 from sav_tpu.serve.telemetry import aggregate_serve  # noqa: E402
@@ -70,7 +68,7 @@ def render(log_dir: str, summary: dict, out) -> None:
         print(
             f"(no heartbeat streams under {fleet_dir(log_dir)} — run with "
             "fleet telemetry on, or the backend never came up: see the "
-            "probe timeline below, if any)",
+            "run manifest's outcome)",
             file=out,
         )
     else:
@@ -282,25 +280,6 @@ def render(log_dir: str, summary: dict, out) -> None:
                 ),
                 file=out,
             )
-    probes = read_probe_timeline(log_dir)
-    if probes:
-        attempts = [p for p in probes if p.get("kind") == "probe"]
-        giveups = [p for p in probes if p.get("kind") == "probe_giveup"]
-        print(
-            f"Backend probe timeline: {len(attempts)} probe(s), "
-            f"{len(giveups)} give-up(s)"
-            + (
-                " — the backend never came up (no heartbeats followed)"
-                if not processes else ""
-            ),
-            file=out,
-        )
-        for p in attempts[-5:]:
-            print(
-                f"  attempt {p.get('attempt')}: platform "
-                f"{p.get('platform')} at +{p.get('elapsed_s')}s",
-                file=out,
-            )
     captures = autoprof_captures(log_dir)
     if captures:
         print(f"Autoprof captures: {len(captures)}", file=out)
@@ -357,7 +336,6 @@ def main(argv=None) -> int:
     summary = aggregate_fleet(args.log_dir, straggler_k=args.straggler_k)
     summary["layouts"] = read_layout_notes(args.log_dir)
     summary["autoprof"] = autoprof_captures(args.log_dir)
-    summary["probe_timeline"] = read_probe_timeline(args.log_dir)
     # Serve heartbeats (kind=serve) share the fleet/proc_*.jsonl files;
     # fold the per-replica serving view in when any process emitted them.
     serve = aggregate_serve(args.log_dir)

@@ -15,7 +15,7 @@ docs/observability.md):
 ``BENCH_r*.json`` wrappers / raw bench lines / manifests) WITHOUT assuming
 healthy inputs: ``rc != 0`` / ``parsed: null`` records land in an "infra
 failures" section instead of crashing the report or being silently
-skipped (the BENCH_r05 lesson).
+skipped.
 
 ``--chain`` (or any log dir with a ``supervisor.json``) renders the
 elastic-training supervisor's manifest chain (docs/elasticity.md):
@@ -54,7 +54,6 @@ from sav_tpu.obs.fleet import (  # noqa: E402
     aggregate_fleet,
     fleet_dir,
     iter_manifests,
-    read_probe_timeline,
 )
 from sav_tpu.obs.manifest import load_run_history  # noqa: E402
 from sav_tpu.obs.traceview import fleet_request_spans  # noqa: E402
@@ -300,13 +299,9 @@ def report_manifest(doc: dict, out) -> None:
             f"{info.get('data_axis_product')})",
             file=out,
         )
-    probe = (notes.get("backend_probe") or {})
-    if probe:
-        print(
-            f"  backend probe: {probe.get('attempts')} attempts over "
-            f"{probe.get('deadline_s')}s deadline",
-            file=out,
-        )
+    device_check = (notes.get("device_check") or {})
+    if device_check:
+        print(f"  device check: {device_check.get('error')}", file=out)
     incidents = notes.get("incidents") or (
         [{"path": notes["incident"]}] if notes.get("incident") else []
     )
@@ -522,11 +517,10 @@ def report_incidents(log_dir: str, out) -> None:
 
 def report_fleet(log_dir: str, out) -> None:
     """Render the fleet-telemetry summary (docs/fleet.md): per-process
-    heartbeats, step skew, straggler ranking, dead-host suspicion, and
-    the backend-probe timeline. Degrades gracefully — a run with no
+    heartbeats, step skew, straggler ranking and dead-host suspicion.
+    Degrades gracefully — a run with no
     ``fleet/`` dir (fleet telemetry off, or predating it) reports that
     instead of erroring."""
-    probes = read_probe_timeline(log_dir)
     if not os.path.isdir(fleet_dir(log_dir)):
         print(f"(no fleet directory at {fleet_dir(log_dir)} — run with "
               "fleet telemetry on)", file=out)
@@ -535,11 +529,7 @@ def report_fleet(log_dir: str, out) -> None:
     processes = summary.get("processes") or {}
     if not processes:
         print(
-            f"Fleet: no heartbeat streams under {fleet_dir(log_dir)}"
-            + (
-                f" ({len(probes)} backend-probe records — the backend "
-                "never came up)" if probes else ""
-            ),
+            f"Fleet: no heartbeat streams under {fleet_dir(log_dir)}",
             file=out,
         )
         return
@@ -582,9 +572,6 @@ def report_fleet(log_dir: str, out) -> None:
             f"{e.get('step')}",
             file=out,
         )
-    if probes:
-        print(f"  backend-probe timeline: {len(probes)} record(s) "
-              "(fleet/backend_probe.jsonl)", file=out)
 
 
 def report_serve(log_dir: str, out, manifests: list = None) -> None:

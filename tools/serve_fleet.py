@@ -187,6 +187,12 @@ def run_replica(args) -> int:
     )
     manifest = RunManifest(args.manifest, kind="serve", argv=sys.argv[1:])
     manifest.begin()
+    from sav_tpu.utils.device_check import require_accelerator
+
+    # Every replica is its own process and checks for itself: exit 3 +
+    # outcome backend_unreachable when this process finds no TPU it can
+    # claim (the pool's supervisor treats that as terminal).
+    platform = require_accelerator(f"replica {rank}", manifest=manifest)
     # Chaos seam: an injected per-batch delay occupies the device loop
     # (books as device time) — the replica is honestly slower, the
     # shape the router's straggler attribution must flag.
@@ -201,9 +207,6 @@ def run_replica(args) -> int:
     except BaseException as e:
         manifest.finalize(classify_exception(e), error=repr(e), exit_code=1)
         raise
-    import jax
-
-    platform = jax.devices()[0].platform
     s = args.image_size
     nbytes_expected = s * s * 3
     stop_event = threading.Event()
@@ -331,6 +334,10 @@ def run_replica(args) -> int:
 
 def run_pool(args) -> int:
     from sav_tpu.serve.fleet import TcpTransport
+    from sav_tpu.utils.device_check import (
+        EXIT_BACKEND_UNREACHABLE,
+        BackendUnreachableError,
+    )
 
     log_dir = args.log_dir or os.path.join("runs", "serve_fleet")
     os.makedirs(log_dir, exist_ok=True)
@@ -344,7 +351,10 @@ def run_pool(args) -> int:
             ready = pool.wait_ready(
                 args.startup_timeout, transport=transport
             )
-        except TimeoutError as e:
+        except BackendUnreachableError as e:
+            print(f"serve_fleet: {e}", file=sys.stderr)
+            return EXIT_BACKEND_UNREACHABLE
+        except (TimeoutError, RuntimeError) as e:
             print(f"serve_fleet: {e}", file=sys.stderr)
             return 1
         print(json.dumps({

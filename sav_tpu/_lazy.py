@@ -10,13 +10,43 @@ backend would hold the chip against them. One factory instead of
 four hand-copied ``__getattr__``/``__dir__`` bodies keeps the contract's
 implementation in one place.
 
+Every import resolved here is a phase span of the process timeline
+(``sav:startup/import:<module>``, :mod:`sav_tpu.obs.spans`): the seam
+through which the package's heavy modules are first loaded is where
+start-up's import seconds are measured. Outermost only: a lazy import made
+while another resolves is part of that one's span.
+
 Stdlib-only, and importing it only executes ``sav_tpu/__init__``'s
 docstring — free on every path.
 """
 
 from __future__ import annotations
 
+import importlib
+import sys
+import threading
+import time
 from typing import Iterable
+
+_resolving = threading.local()
+
+
+def _import(target: str):
+    """``import_module``; a module's first import, if no other lazy import
+    is resolving on this thread, is a phase span of the timeline. Timed
+    with a bare clock pair: nothing else runs before or around the import."""
+    if target in sys.modules or getattr(_resolving, "active", False):
+        return importlib.import_module(target)
+    _resolving.active = True
+    start = time.perf_counter()
+    try:
+        return importlib.import_module(target)
+    finally:
+        end = time.perf_counter()
+        _resolving.active = False
+        from sav_tpu.obs.spans import record_phase  # stdlib-only, like this module
+
+        record_phase("startup/import:" + target, start, end)
 
 
 def install_lazy_exports(
@@ -45,10 +75,8 @@ def install_lazy_exports(
     submodules = frozenset(submodules)
 
     def __getattr__(name: str):
-        import importlib
-
         if name in submodules:
-            module = importlib.import_module(f"{package}.{name}")
+            module = _import(f"{package}.{name}")
             namespace[name] = module
             return module
         target = exports.get(name)
@@ -56,7 +84,7 @@ def install_lazy_exports(
             raise AttributeError(
                 f"module {package!r} has no attribute {name!r}"
             )
-        value = getattr(importlib.import_module(target), name)
+        value = getattr(_import(target), name)
         namespace[name] = value
         return value
 

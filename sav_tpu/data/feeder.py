@@ -39,7 +39,10 @@ device_put seconds, queue-depth high-water/occupancy) exposed by
 goodput ledger so a run's report shows the overlap working — in feeder
 mode the ledger's ``input_wait`` is the consumer's residual queue wait
 and ``h2d`` on the training thread is ~0, while ``feeder/h2d_s`` shows
-where the placement time actually went (overlapped).
+where the placement time actually went (overlapped). The same two stages
+are the spans ``sav:feeder/fetch`` and ``sav:feeder/place``
+(sav_tpu/obs/spans.py): a profiler session shows the worker's thread
+beside the device's operations.
 
 Stdlib + the injected ``place_fn`` only — no jax import at module level,
 so the data layer stays importable in TF-free/device-free contexts.
@@ -51,6 +54,8 @@ import queue
 import threading
 import time
 from typing import Any, Callable, Iterator, Optional
+
+from sav_tpu.obs.spans import SpanTracer
 
 
 class DeviceFeeder:
@@ -65,6 +70,8 @@ class DeviceFeeder:
         (2 = double buffering). Also the backpressure bound.
       name: thread-name suffix for stack dumps (the obs watchdog prints
         every thread; a recognizable name keeps its reports readable).
+      tracer: the caller's span tracer, so that the worker's spans land in
+        its Chrome file too; without one they reach the profiler only.
     """
 
     _POLL_S = 0.1  # stop-flag responsiveness for blocking queue ops
@@ -76,12 +83,14 @@ class DeviceFeeder:
         *,
         depth: int = 2,
         name: str = "device-feeder",
+        tracer: Optional[SpanTracer] = None,
     ):
         if depth < 1:
             raise ValueError(f"feeder depth must be >= 1, got {depth}")
         self.depth = depth
         self._iterator = iterator
         self._place_fn = place_fn
+        self._tracer = tracer if tracer is not None else SpanTracer(None)
         self._queue: queue.Queue = queue.Queue(maxsize=depth)
         self._done = object()
         self._stop = threading.Event()
@@ -115,15 +124,15 @@ class DeviceFeeder:
     def _worker(self):
         try:
             while not self._stop.is_set():
-                t0 = time.perf_counter()
-                try:
-                    batch = next(self._iterator)
-                except StopIteration:
-                    break
-                self._fetch_s += time.perf_counter() - t0
-                t0 = time.perf_counter()
-                placed = self._place_fn(batch)
-                self._put_s += time.perf_counter() - t0
+                with self._tracer.span("feeder/fetch") as span:
+                    try:
+                        batch = next(self._iterator)
+                    except StopIteration:
+                        break
+                self._fetch_s += span.seconds
+                with self._tracer.span("feeder/place") as span:
+                    placed = self._place_fn(batch)
+                self._put_s += span.seconds
                 self._batches += 1
                 if not self._enqueue(placed):
                     return  # closed while blocked on a full queue

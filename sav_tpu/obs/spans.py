@@ -1,46 +1,150 @@
-"""Host-side span tracing — Chrome-trace-event JSON around ``fit()`` phases.
+"""The program's own timeline: host spans where the device trace is.
 
-A full XPlane capture (``utils.profiler``) answers "what is the device
-doing" at ~GB granularity; these spans answer the cheaper, always-on
-question "where did the *host* spend wall time" — batch fetch vs
-``shard_batch``/H2D vs step dispatch vs the log-sync ``device_get`` vs
-eval vs checkpoint. The output is the Trace Event Format
-(``{"traceEvents": [...]}`` with ``ph: "X"`` complete events, microsecond
-timestamps), which both Perfetto (https://ui.perfetto.dev) and
-``chrome://tracing`` load directly.
+One span API for ``Trainer.fit``, ``DeviceFeeder`` and start-up. A span
+named ``fit/dispatch`` is emitted three ways from one ``with``:
 
-Stdlib-only on purpose: the tracer must be constructible before (and
-usable without) any jax import, and a disabled tracer
-(``SpanTracer(None)``) costs one ``if`` per span so call sites wire it
-unconditionally.
+- as a ``jax.profiler.TraceAnnotation`` called ``sav:fit/dispatch``. While
+  any profiler session runs (``TrainConfig.profile_dir``'s window,
+  autoprof, the benchmark's ``--trace 1``) it lands in the trace's
+  ``/host:CPU`` plane beside the device's ``XLA Ops``, on the profiler's
+  clock, from whichever thread emitted it. With no session the annotation
+  is a flag test and records nothing: nothing turns spans on or off.
+- as a complete event of the Chrome file (``spans.trace.json``, Trace
+  Event Format, loadable in Perfetto and ``chrome://tracing``) when the
+  tracer was given a path (``TrainConfig.trace_spans``);
+- as seconds on a goodput ledger's bucket, where the span names one.
+
+*Phase* spans (``Trainer.__init__``, ``init_state``, ``fit``'s compile)
+also enter the process timeline: a bounded, always-on list of finished
+spans on ``time.perf_counter``, for what happens before any profiler can
+run. :func:`timeline` reads it. Per-step spans never enter it. The lazy
+imports of ``sav_tpu/_lazy.py`` enter it through :func:`record_phase`,
+timed there with a bare clock pair.
+
+Stdlib-only at import: the supervisor and the serve pool import this
+package without ``jax``. The annotation class is looked up only once
+``jax`` is in ``sys.modules``: a process without jax has no profiler
+session to write to.
 """
 
 from __future__ import annotations
 
-import contextlib
+import functools
 import json
 import os
+import sys
 import threading
 import time
+from collections import deque
 from typing import Optional
+
+PREFIX = "sav:"
+TIMELINE_MAX = 256
+
+_timeline: deque = deque(maxlen=TIMELINE_MAX)
+_timeline_lock = threading.Lock()
+_annotation_cls = None
+
+
+def timeline() -> list[tuple[str, float, float]]:
+    """The process's finished phase spans, oldest first, as ``(name,
+    start, end)`` on ``time.perf_counter``; the newest ``TIMELINE_MAX``."""
+    with _timeline_lock:
+        return list(_timeline)
+
+
+def _keep(name: str, start: float, end: float) -> None:
+    with _timeline_lock:
+        _timeline.append((name, start, end))
+
+
+def record_phase(name: str, start: float, end: float) -> None:
+    """Enter a finished phase span, timed by the caller on
+    ``time.perf_counter``, in the process timeline as ``sav:<name>``."""
+    _keep(PREFIX + name, start, end)
+
+
+def _annotation(name: str):
+    global _annotation_cls
+    if _annotation_cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name)
+
+
+class _Span:
+    __slots__ = ("tracer", "name", "bucket", "in_timeline", "args", "start", "seconds", "annotation")
+
+    def __init__(self, tracer, name, bucket, in_timeline, args):
+        self.tracer, self.name, self.bucket = tracer, PREFIX + name, bucket
+        self.in_timeline, self.args = in_timeline, args
+
+    def __enter__(self):
+        self.annotation = _annotation(self.name)
+        if self.annotation is not None:
+            self.annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        end = time.perf_counter()
+        self.seconds = end - self.start
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
+        if self.in_timeline:
+            _keep(self.name, self.start, end)
+        tracer = self.tracer
+        if tracer is None:
+            return False
+        if self.bucket is not None:
+            tracer.ledger.account(self.bucket, self.seconds)
+        if tracer.enabled:
+            tracer._append(
+                {"name": self.name, "ph": "X", "ts": self.start * 1e6, "dur": self.seconds * 1e6},
+                self.args,
+            )
+        return False
+
+
+def phase(name: str) -> _Span:
+    """A phase span with no tracer behind it (start-up, constructors): the
+    annotation and the timeline only."""
+    return _Span(None, name, None, True, None)
+
+
+def in_phase(name: str):
+    """Decorator: every call of the function is the phase span ``name``."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with phase(name):
+                return fn(*args, **kwargs)
+
+        return wrapped
+
+    return wrap
 
 
 class SpanTracer:
-    """Collects complete-events in memory; :meth:`write` dumps the file.
+    """Emits spans; collects the Chrome file's events where it has a path.
 
-    ``path=None`` disables the tracer entirely (every method is a cheap
-    no-op), so the trainer wires spans unconditionally and the flag only
-    decides whether anything is recorded. Thread-safe: the watchdog and
-    checkpoint threads may emit instants while the train loop records
-    spans.
+    ``path=None`` writes no file: spans still reach the profiler and the
+    ledger, so call sites wire one tracer unconditionally. Thread-safe:
+    the feeder, watchdog and checkpoint threads emit beside the train loop.
+    Timestamps are ``time.perf_counter`` microseconds, the clock of the
+    process timeline.
     """
 
-    def __init__(self, path: Optional[str], *, process_name: str = "sav_tpu"):
+    def __init__(self, path: Optional[str], *, ledger=None, process_name: str = "sav_tpu"):
         self.path = path
         self.enabled = path is not None
+        self.ledger = ledger
         self._events: list[dict] = []
         self._lock = threading.Lock()
-        self._t0 = time.perf_counter()
         if self.enabled:
             # Metadata event names the process row in the Perfetto UI.
             self._events.append({
@@ -49,41 +153,31 @@ class SpanTracer:
                 "args": {"name": process_name},
             })
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    def span(self, name: str, *, bucket: Optional[str] = None, in_timeline: bool = False,
+             **args) -> _Span:
+        """``with tracer.span("fit/dispatch", step=3):`` emits
+        ``sav:fit/dispatch``. ``bucket`` books the span's seconds on the
+        tracer's ledger; ``in_timeline`` marks a phase span, kept in the
+        process timeline; ``args`` go to the Chrome event."""
+        if bucket is not None and self.ledger is None:
+            raise ValueError(f"span {name!r} names bucket {bucket!r} but the tracer has no ledger")
+        return _Span(self, name, bucket, in_timeline, args)
 
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
-        """Record a complete event around the ``with`` body."""
-        if not self.enabled:
-            yield
-            return
-        start = self._now_us()
-        try:
-            yield
-        finally:
-            event = {
-                "name": name, "ph": "X", "ts": start,
-                "dur": self._now_us() - start,
-                "pid": os.getpid(), "tid": threading.get_ident(),
-            }
-            if args:
-                event["args"] = args
-            with self._lock:
-                self._events.append(event)
-
-    def instant(self, name: str, **args) -> None:
-        """Zero-duration marker (eval boundaries, stall anomalies...)."""
-        if not self.enabled:
-            return
-        event = {
-            "name": name, "ph": "i", "ts": self._now_us(), "s": "t",
-            "pid": os.getpid(), "tid": threading.get_ident(),
-        }
+    def _append(self, event: dict, args) -> None:
+        event.update(pid=os.getpid(), tid=threading.get_ident())
         if args:
             event["args"] = args
         with self._lock:
             self._events.append(event)
+
+    def instant(self, name: str, **args) -> None:
+        """Zero-duration marker of the Chrome file (stall anomalies,
+        incidents...)."""
+        if not self.enabled:
+            return
+        self._append(
+            {"name": PREFIX + name, "ph": "i", "ts": time.perf_counter() * 1e6, "s": "t"}, args
+        )
 
     @property
     def num_events(self) -> int:

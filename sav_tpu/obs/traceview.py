@@ -363,9 +363,11 @@ def group_of_scope(op_name: str) -> str:
 
     A module scope always has at least two segments (module path + the
     primitive, e.g. ``Encoder_0/block_0/.../dot_general``); a
-    single-segment scope is a bare top-level primitive — the loss math,
-    the optimizer update, a donation copy — and belongs to ``other``,
-    not to a fake group named after the primitive.
+    single-segment scope is a bare top-level primitive — step glue, a
+    donation copy — and belongs to ``other``, not to a fake group named
+    after the primitive. The train step's own named scopes
+    (``preprocess``, ``loss``, ``optimizer``, ``metrics``) are groups of
+    their own, beside the modules'.
     """
     segments = scope_segments(op_name)
     return segments[0] if len(segments) >= 2 else COMP_OTHER

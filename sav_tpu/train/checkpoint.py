@@ -18,7 +18,6 @@ import threading
 from typing import Any, Optional
 
 import jax
-import orbax.checkpoint as ocp
 
 
 def _tree_paths(tree: Any, prefix: tuple = ()) -> list:
@@ -73,26 +72,34 @@ class Checkpointer:
         """``read_only`` opens an existing checkpoint dir for restore-only
         use (warm starts): no directory creation — a typo'd path raises
         instead of materializing an empty dir — and no retention policy."""
+        # Imported with the first Checkpointer, not with the module: orbax
+        # brings google.cloud.logging, 12 s of a bare import on the TPU's
+        # host and 25-30 s in a process that already holds jax (PERF.md
+        # section 5, ``startup.import_s``). A run that saves nothing and
+        # restores nothing never pays it.
+        import orbax.checkpoint as ocp
+
+        self._ocp = ocp
         self._dir = os.path.abspath(directory)
         if read_only:
             if not os.path.isdir(self._dir):
                 raise FileNotFoundError(
                     f"checkpoint directory does not exist: {self._dir!r}"
                 )
-            options = ocp.CheckpointManagerOptions(read_only=True)
+            options = self._ocp.CheckpointManagerOptions(read_only=True)
         else:
             os.makedirs(self._dir, exist_ok=True)
-            options = ocp.CheckpointManagerOptions(
+            options = self._ocp.CheckpointManagerOptions(
                 max_to_keep=keep, create=True, enable_async_checkpointing=True
             )
         # The item handler is registered up front so ``item_metadata``
         # (the opt-state layout probe) works on a FRESH manager — a
         # restarted process probes before its first save/restore, and
         # without the registration orbax returns a placeholder.
-        self._mgr = ocp.CheckpointManager(
+        self._mgr = self._ocp.CheckpointManager(
             self._dir,
             options=options,
-            item_handlers=ocp.StandardCheckpointHandler(),
+            item_handlers=self._ocp.StandardCheckpointHandler(),
         )
 
     @property
@@ -100,7 +107,7 @@ class Checkpointer:
         return self._dir
 
     def save(self, step: int, state: Any) -> None:
-        self._mgr.save(step, args=ocp.args.StandardSave(state))
+        self._mgr.save(step, args=self._ocp.args.StandardSave(state))
 
     def latest_step(self) -> Optional[int]:
         return self._mgr.latest_step()
@@ -146,12 +153,12 @@ class Checkpointer:
         steps = self.all_steps()
         if not steps:
             return None
-        abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, template)
+        abstract = jax.tree.map(self._ocp.utils.to_shape_dtype_struct, template)
         first_error: Optional[Exception] = None
         for step in reversed(steps):
             try:
                 restored = self._mgr.restore(
-                    step, args=ocp.args.StandardRestore(abstract)
+                    step, args=self._ocp.args.StandardRestore(abstract)
                 )
             except Exception as e:  # noqa: BLE001 — every orbax failure
                 if first_error is None:
@@ -211,9 +218,9 @@ class Checkpointer:
                 "flat-buffer" if layout.get("fused") else "per-leaf",
                 " + EMA" if layout.get("ema") else "",
             )
-        abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, template)
+        abstract = jax.tree.map(self._ocp.utils.to_shape_dtype_struct, template)
         restore_args = jax.tree.map(
-            lambda s: ocp.ArrayRestoreArgs(
+            lambda s: self._ocp.ArrayRestoreArgs(
                 dtype=s.dtype, sharding=getattr(s, "sharding", None)
             ),
             abstract,
@@ -222,15 +229,15 @@ class Checkpointer:
         # StandardSave writes through PyTreeCheckpointHandler, so the
         # on-disk layout is shared; only PyTreeRestore exposes the
         # partial-tree ``transforms`` path.
-        reader = ocp.CheckpointManager(
+        reader = self._ocp.CheckpointManager(
             self._dir,
-            options=ocp.CheckpointManagerOptions(read_only=True),
-            item_handlers=ocp.PyTreeCheckpointHandler(),
+            options=self._ocp.CheckpointManagerOptions(read_only=True),
+            item_handlers=self._ocp.PyTreeCheckpointHandler(),
         )
         try:
             return reader.restore(
                 step,
-                args=ocp.args.PyTreeRestore(
+                args=self._ocp.args.PyTreeRestore(
                     item=abstract, transforms={}, restore_args=restore_args
                 ),
             )
@@ -249,7 +256,7 @@ class Checkpointer:
             step = self._mgr.latest_step()
         if step is None:
             return None
-        return self._mgr.restore(step, args=ocp.args.StandardRestore())
+        return self._mgr.restore(step, args=self._ocp.args.StandardRestore())
 
     def wait(self, timeout_s: Optional[float] = None) -> bool:
         """Block until in-flight async saves commit.

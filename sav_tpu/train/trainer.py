@@ -34,7 +34,7 @@ from sav_tpu.models import create_model
 from sav_tpu.obs.diagnostics import diagnostics_metrics
 from sav_tpu.obs.goodput import GoodputLedger
 from sav_tpu.obs.memory import RetraceCounter, hbm_stats
-from sav_tpu.obs.spans import SpanTracer
+from sav_tpu.obs.spans import SpanTracer, in_phase
 from sav_tpu.parallel.layout import (
     BoundLayout,
     layout_from_mesh,
@@ -71,6 +71,7 @@ def _cost_note(cost, peak_flops, peak_source) -> dict:
 
 
 class Trainer:
+    @in_phase("trainer/init")
     def __init__(
         self,
         config: TrainConfig,
@@ -323,6 +324,7 @@ class Trainer:
         )
         return (b, s, s, 3)
 
+    @in_phase("trainer/init_state")
     def init_state(self, seed: Optional[int] = None) -> TrainState:
         """Build a sharded TrainState directly on the mesh.
 
@@ -618,17 +620,21 @@ class Trainer:
         return batch
 
     def _train_step_impl(self, state: TrainState, batch: dict, rng: jax.Array):
+        # Four named scopes (preprocess, loss, optimizer, metrics) name the
+        # device time that no flax module does; metadata only. None goes
+        # around model.apply: the module paths must read as they are.
         step_rng = jax.random.fold_in(rng, state.step)
-        if self.config.device_preprocess:
-            # Dedicated fold so the mix draws are independent of the
-            # dropout/stochastic-depth streams split from step_rng below.
-            batch = self._device_preprocess(
-                batch, jax.random.fold_in(step_rng, 0x6D69), training=True
-            )
-            images = batch["images"]  # already NHWC, compute dtype
-        else:
-            images = self._prep_images(batch["images"])
-        label_probs = self._label_probs(batch)
+        with jax.named_scope("preprocess"):
+            if self.config.device_preprocess:
+                # Dedicated fold so the mix draws are independent of the
+                # dropout/stochastic-depth streams split from step_rng below.
+                batch = self._device_preprocess(
+                    batch, jax.random.fold_in(step_rng, 0x6D69), training=True
+                )
+                images = batch["images"]  # already NHWC, compute dtype
+            else:
+                images = self._prep_images(batch["images"])
+            label_probs = self._label_probs(batch)
         has_bn = bool(state.batch_stats)
 
         def loss_fn(
@@ -660,15 +666,16 @@ class Trainer:
             # scales (see MoEFFBlock's convention note); aux_loss_weight is
             # the single relative→loss-units conversion, and the logged
             # aux_loss metric is the relative-units sum.
-            aux = sum(
-                jnp.sum(leaf)
-                for leaf in jax.tree.leaves(new_vars.get("losses", {}))
-            )
-            aux = jnp.asarray(aux, jnp.float32)
-            loss = (
-                cross_entropy(logits, label_probs)
-                + self.config.aux_loss_weight * aux
-            )
+            with jax.named_scope("loss"):
+                aux = sum(
+                    jnp.sum(leaf)
+                    for leaf in jax.tree.leaves(new_vars.get("losses", {}))
+                )
+                aux = jnp.asarray(aux, jnp.float32)
+                loss = (
+                    cross_entropy(logits, label_probs)
+                    + self.config.aux_loss_weight * aux
+                )
             return loss, (logits, new_batch_stats, aux)
 
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
@@ -730,32 +737,34 @@ class Trainer:
             loss = lsum / accum
             aux_loss = asum / accum
             logits = logits_stack.reshape(b, *logits_stack.shape[2:])
-        updates, new_opt_state = self.tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = self.tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,
             opt_state=new_opt_state,
             batch_stats=new_batch_stats,
         )
-        acc = topk_correct(logits.astype(jnp.float32), batch["labels"])
-        metrics = {
-            "loss": loss,
-            "top_1_acc": jnp.mean(acc["top_1_acc"]),
-            "top_5_acc": jnp.mean(acc["top_5_acc"]),
-            "learning_rate": self.schedule(state.step),
-            "grad_norm": optax.global_norm(grads),
-            "aux_loss": aux_loss,
-        }
-        if self.config.diagnostics:
-            # In-jit diagnostics (sav_tpu.obs.diagnostics): computed on
-            # device, returned with the step metrics, so they ride the
-            # per-log device_get with zero extra transfers.
-            metrics.update(
-                diagnostics_metrics(
-                    grads=grads, params=state.params, updates=updates
+        with jax.named_scope("metrics"):
+            acc = topk_correct(logits.astype(jnp.float32), batch["labels"])
+            metrics = {
+                "loss": loss,
+                "top_1_acc": jnp.mean(acc["top_1_acc"]),
+                "top_5_acc": jnp.mean(acc["top_5_acc"]),
+                "learning_rate": self.schedule(state.step),
+                "grad_norm": optax.global_norm(grads),
+                "aux_loss": aux_loss,
+            }
+            if self.config.diagnostics:
+                # In-jit diagnostics (sav_tpu.obs.diagnostics): computed on
+                # device, returned with the step metrics, so they ride the
+                # per-log device_get with zero extra transfers.
+                metrics.update(
+                    diagnostics_metrics(
+                        grads=grads, params=state.params, updates=updates
+                    )
                 )
-            )
         return new_state, metrics
 
     def _train_many_impl(self, state: TrainState, batches: dict, rng: jax.Array):
@@ -789,11 +798,12 @@ class Trainer:
         return self._train_many(state, placed, rng)
 
     def _eval_step_impl(self, state: TrainState, batch: dict):
-        if self.config.device_preprocess:
-            batch = self._device_preprocess(batch, None, training=False)
-            images = batch["images"]
-        else:
-            images = self._prep_images(batch["images"])
+        with jax.named_scope("preprocess"):
+            if self.config.device_preprocess:
+                batch = self._device_preprocess(batch, None, training=False)
+                images = batch["images"]
+            else:
+                images = self._prep_images(batch["images"])
         # Eval on the parameter EMA when configured (the DeiT/CaiT-recipe
         # standard: the averaged weights generalize better than the last
         # step's). The EMA tree lives in opt_state (optimizer.py
@@ -807,24 +817,25 @@ class Trainer:
         if state.batch_stats:
             variables["batch_stats"] = state.batch_stats
         logits = self.model.apply(variables, images, is_training=False)
-        logits = logits.astype(jnp.float32)
-        labels = batch["labels"]
-        onehot = jax.nn.one_hot(labels, self.config.num_classes, dtype=jnp.float32)
-        n = labels.shape[0]
-        # 'valid' marks real rows in a padded final batch (evaluate() pads
-        # remainders so every batch has one static, mesh-divisible shape).
-        valid = batch.get("valid")
-        if valid is None:
-            valid = jnp.ones((n,), jnp.float32)
-        acc = topk_correct(logits, labels)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        per_example_loss = -jnp.sum(onehot * logp, axis=-1)
-        return {
-            "loss_sum": jnp.sum(per_example_loss * valid),
-            "top_1_sum": jnp.sum(acc["top_1_acc"] * valid),
-            "top_5_sum": jnp.sum(acc["top_5_acc"] * valid),
-            "count": jnp.sum(valid),
-        }
+        with jax.named_scope("metrics"):
+            logits = logits.astype(jnp.float32)
+            labels = batch["labels"]
+            onehot = jax.nn.one_hot(labels, self.config.num_classes, dtype=jnp.float32)
+            n = labels.shape[0]
+            # 'valid' marks real rows in a padded final batch (evaluate() pads
+            # remainders so every batch has one static, mesh-divisible shape).
+            valid = batch.get("valid")
+            if valid is None:
+                valid = jnp.ones((n,), jnp.float32)
+            acc = topk_correct(logits, labels)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            per_example_loss = -jnp.sum(onehot * logp, axis=-1)
+            return {
+                "loss_sum": jnp.sum(per_example_loss * valid),
+                "top_1_sum": jnp.sum(acc["top_1_acc"] * valid),
+                "top_5_sum": jnp.sum(acc["top_5_acc"] * valid),
+                "count": jnp.sum(valid),
+            }
 
     # ------------------------------------------------------------- data flow
 
@@ -1115,9 +1126,11 @@ class Trainer:
         a goodput ledger (compile/step/input-wait/h2d/eval/checkpoint/stall
         buckets plus ``feeder/*`` gauges, written to <log_dir>/goodput.json
         and exposed as
-        ``self.last_goodput``); ``config.trace_spans`` additionally records
-        host-side spans around each phase into a Perfetto-loadable
-        <log_dir>/spans.trace.json, and ``config.watchdog_secs`` arms a
+        ``self.last_goodput``); every phase is a ``sav:fit/<phase>`` span
+        (sav_tpu/obs/spans.py) that any running profiler session records
+        beside the device's operations, and ``config.trace_spans``
+        additionally writes them to a Perfetto-loadable
+        <log_dir>/spans.trace.json; ``config.watchdog_secs`` arms a
         hang watchdog that aborts with exit 4 + stack dump when no step
         completes in time.
         """
@@ -1144,11 +1157,15 @@ class Trainer:
             jax.process_index(), jax.process_count()
         )
         obs_writer = fleet_proc == 0
+        ledger = GoodputLedger()
+        # One span per phase (sav_tpu/obs/spans.py): each reaches any
+        # running profiler session as ``sav:fit/<phase>``, books its bucket
+        # on the ledger, and lands in the Chrome file under trace_spans.
         tracer = SpanTracer(
             os.path.join(obs_dir or ".", "spans.trace.json")
-            if cfg.trace_spans and obs_writer else None
+            if cfg.trace_spans and obs_writer else None,
+            ledger=ledger,
         )
-        ledger = GoodputLedger()
         retraces = RetraceCounter(self._train_step) if cfg.diagnostics else None
         sanitizer = None
         if cfg.sanitize:
@@ -1366,7 +1383,7 @@ class Trainer:
                     "notes.seq_replication_fallback)",
                     stacklevel=2,
                 )
-                tracer.instant("seq_replication_fallback", **info)
+                tracer.instant("fit/seq_replication_fallback", **info)
                 # set_gauge coerces to float itself (info is a plain host
                 # dict — no device value anywhere near this path).
                 ledger.set_gauge("seq/replicated_batch", info["batch"])
@@ -1418,7 +1435,7 @@ class Trainer:
             )
             feeder = DeviceFeeder(
                 data_iter, place_fn, depth=cfg.feed_depth,
-                name="train-feeder",
+                name="train-feeder", tracer=tracer,
             )
         # Dispatch run-ahead bound (see the step_dispatch block below);
         # metrics are tiny device scalars, so the deque itself is free.
@@ -1447,23 +1464,26 @@ class Trainer:
                 if feeder is not None:
                     # Placed batches arrive ready; the only critical-path
                     # cost left is the residual queue wait.
-                    with tracer.span("batch_wait", step=step + 1), \
-                            ledger.measure("input_wait"):
+                    with tracer.span(
+                        "fit/batch_wait", bucket="input_wait", step=step + 1
+                    ):
                         try:
                             sharded = next(feeder)
                         except StopIteration:
                             break
                 else:
-                    with tracer.span("batch_fetch", step=step + 1), \
-                            ledger.measure("input_wait"):
+                    with tracer.span(
+                        "fit/batch_wait", bucket="input_wait", step=step + 1
+                    ):
                         try:
                             batch = next(data_iter)
                         except StopIteration:
                             break
                     if recorder is not None:
                         recorder.observe_batch(batch)
-                    with tracer.span("shard_batch", step=step + 1), \
-                            ledger.measure("h2d"):
+                    with tracer.span(
+                        "fit/shard_batch", bucket="h2d", step=step + 1
+                    ):
                         sharded = self.shard_batch(batch)  # savlint: disable=SAV106 -- the sanctioned serial fallback (async_feed=False)
                 if recorder is not None and recorder.wants_snapshot(step):
                     # The one sync recording adds: a periodic pre-step state
@@ -1473,7 +1493,9 @@ class Trainer:
                 if use_aot and compiled_step is None:
                     from sav_tpu.utils.flops import compiled_flops
 
-                    with tracer.span("compile"), ledger.measure("compile"):
+                    with tracer.span(
+                        "fit/compile", bucket="compile", in_timeline=True
+                    ):
                         compiled_step = self._train_step.lower(
                             state, sharded, rng
                         ).compile()
@@ -1503,7 +1525,13 @@ class Trainer:
                     t_last = time.time()
                 step_fn = compiled_step if compiled_step is not None else self._train_step
                 t_step = time.perf_counter()
-                with tracer.span("step_dispatch", step=step + 1):
+                # The first jit dispatch blocks through trace and compile:
+                # it is the compile span the AOT path has above.
+                first_jit = step == start_step and compiled_step is None
+                with (
+                    tracer.span("fit/compile", in_timeline=True) if first_jit
+                    else tracer.span("fit/dispatch", step=step + 1)
+                ):
                     state, metrics = step_fn(state, sharded, rng)
                 # Cap dispatch run-ahead the same way evaluate() does:
                 # every dispatched-not-retired step holds its placed input
@@ -1516,17 +1544,17 @@ class Trainer:
                 # into the step window: it is device-compute wait.
                 inflight_metrics.append(metrics)
                 if len(inflight_metrics) > max_inflight:
-                    jax.block_until_ready(  # savlint: disable=SAV101 -- run-ahead cap: device-compute wait that retires placed inputs
-                        inflight_metrics.popleft()
-                    )
+                    with tracer.span("fit/run_ahead_wait", step=step + 1):
+                        jax.block_until_ready(  # savlint: disable=SAV101 -- run-ahead cap: device-compute wait that retires placed inputs
+                            inflight_metrics.popleft()
+                        )
                 if recorder is not None:
                     # Host-only bookkeeping (pairs the dispatched step with
                     # its observed batch); never touches device values.
                     recorder.on_step(step + 1)
                 dispatch_s = time.perf_counter() - t_step
-                if step == start_step and compiled_step is None:
-                    # The first jit dispatch blocks through trace+compile;
-                    # bucket it as compile (it carries one step of device
+                if first_jit:
+                    # Bucketed as compile (it carries one step of device
                     # time too — noise next to the compile).
                     ledger.account("compile", dispatch_s)
                 else:
@@ -1561,84 +1589,85 @@ class Trainer:
                 if cfg.debug_nans:
                     assert_all_finite(metrics, f"metrics at step {step + 1}")
                 if (step + 1) % cfg.log_every_steps == 0 or step + 1 == num_steps:
-                    t_sync = time.perf_counter()
-                    with tracer.span("log_sync", step=step + 1):
-                        m = {
-                            k: float(v)
-                            for k, v in jax.device_get(metrics).items()  # savlint: disable=SAV101 -- the per-log-window metrics sync; priced into the step bucket
-                        }
-                    window_s += time.perf_counter() - t_sync
-                    now = time.time()
-                    m["step"] = step + 1
-                    steps_since = step + 1 - last_logged_step
-                    if ledger.note_window(steps_since, window_s, step=step + 1):
-                        tracer.instant("stall_anomaly", step=step + 1)
-                        if autoprof is not None:
-                            autoprof.request("stall_anomaly", step + 1)
-                    if autoprof is not None:
-                        # Wall per-step (host view: includes input wait +
-                        # collective wait, unlike the ledger's dispatch
-                        # window) through the robust spike gate.
-                        autoprof.note_window(
-                            step + 1,
-                            (now - t_last) / max(steps_since, 1),
-                        )
-                    window_s = 0.0
-                    m["images_per_sec"] = (
-                        cfg.global_batch_size * steps_since / max(now - t_last, 1e-9)
-                    )
-                    if step_flops and peak_flops:
-                        # Model-FLOPs utilization, per chip: cost_analysis
-                        # FLOPs are per-device (sav_tpu/utils/flops.py) —
-                        # the north star in its own unit (BASELINE.md).
-                        step_s = max(now - t_last, 1e-9) / max(steps_since, 1)
-                        m["mfu"] = step_flops / step_s / peak_flops
-                    if cfg.diagnostics:
-                        # Host-side telemetry sampled only at log boundaries:
-                        # HBM occupancy ({} on backends without memory_stats)
-                        # and silent-recompilation detection.
-                        hbm = hbm_stats()
-                        m.update(hbm)
-                        watermark.observe(hbm)
-                        if retraces is not None:
-                            m["retraces"] = float(retraces.delta())
-                    else:
-                        # The watermark samples regardless of diagnostics
-                        # (a host-side counter read — no device sync; {}
-                        # on CPU, backfilled once at finalize).
-                        watermark.observe()
-                    t_last = now
-                    last_logged_step = step + 1
-                    history.append(m)
-                    if log_fn is not None:
-                        log_fn(m)
-                    if recorder is not None:
-                        # Incident detection piggybacks on the metrics this
-                        # window already synced: nonfinite values or a loss
-                        # beyond the robust spike gate dump a bundle.
-                        trigger = recorder.note_metrics(step + 1, m)
-                        if trigger:
-                            incident = recorder.dump_incident(
-                                trigger, step + 1
-                            )
-                            if incident is not None:
-                                tracer.instant(
-                                    "incident", step=step + 1,
-                                    trigger=trigger,
+                    with tracer.span("fit/log_boundary", step=step + 1):
+                        t_sync = time.perf_counter()
+                        with tracer.span("fit/log_sync", step=step + 1):
+                            fetched = jax.device_get(metrics)  # savlint: disable=SAV101 -- the per-log-window metrics sync; priced into the step bucket
+                        window_s += time.perf_counter() - t_sync
+                        with tracer.span("fit/log_host", step=step + 1):
+                            m = {k: float(v) for k, v in fetched.items()}
+                            now = time.time()
+                            m["step"] = step + 1
+                            steps_since = step + 1 - last_logged_step
+                            if ledger.note_window(steps_since, window_s, step=step + 1):
+                                tracer.instant("fit/stall_anomaly", step=step + 1)
+                                if autoprof is not None:
+                                    autoprof.request("stall_anomaly", step + 1)
+                            if autoprof is not None:
+                                # Wall per-step (host view: includes input wait +
+                                # collective wait, unlike the ledger's dispatch
+                                # window) through the robust spike gate.
+                                autoprof.note_window(
+                                    step + 1,
+                                    (now - t_last) / max(steps_since, 1),
                                 )
-                    if fleet_hb is not None:
-                        # Fleet heartbeat: one appended line from values
-                        # this window already holds on the host (the
-                        # synced metrics dict + the ledger's wall-clock
-                        # aggregates) — SAV112 pins the path sync-free.
-                        fleet_hb.beat(
-                            step + 1, ledger=ledger, metrics=m,
-                            incident=(
-                                recorder.incidents[-1]["path"]
-                                if recorder is not None
-                                and recorder.incidents else None
-                            ),
-                        )
+                            window_s = 0.0
+                            m["images_per_sec"] = (
+                                cfg.global_batch_size * steps_since / max(now - t_last, 1e-9)
+                            )
+                            if step_flops and peak_flops:
+                                # Model-FLOPs utilization, per chip: cost_analysis
+                                # FLOPs are per-device (sav_tpu/utils/flops.py) —
+                                # the north star in its own unit (BASELINE.md).
+                                step_s = max(now - t_last, 1e-9) / max(steps_since, 1)
+                                m["mfu"] = step_flops / step_s / peak_flops
+                            if cfg.diagnostics:
+                                # Host-side telemetry sampled only at log boundaries:
+                                # HBM occupancy ({} on backends without memory_stats)
+                                # and silent-recompilation detection.
+                                hbm = hbm_stats()
+                                m.update(hbm)
+                                watermark.observe(hbm)
+                                if retraces is not None:
+                                    m["retraces"] = float(retraces.delta())
+                            else:
+                                # The watermark samples regardless of diagnostics
+                                # (a host-side counter read — no device sync; {}
+                                # on CPU, backfilled once at finalize).
+                                watermark.observe()
+                            t_last = now
+                            last_logged_step = step + 1
+                            history.append(m)
+                        if log_fn is not None:
+                            with tracer.span("fit/log_fn", step=step + 1):
+                                log_fn(m)
+                        if recorder is not None:
+                            # Incident detection piggybacks on the metrics this
+                            # window already synced: nonfinite values or a loss
+                            # beyond the robust spike gate dump a bundle.
+                            trigger = recorder.note_metrics(step + 1, m)
+                            if trigger:
+                                incident = recorder.dump_incident(
+                                    trigger, step + 1
+                                )
+                                if incident is not None:
+                                    tracer.instant(
+                                        "fit/incident", step=step + 1,
+                                        trigger=trigger,
+                                    )
+                        if fleet_hb is not None:
+                            # Fleet heartbeat: one appended line from values
+                            # this window already holds on the host (the
+                            # synced metrics dict + the ledger's wall-clock
+                            # aggregates) — SAV112 pins the path sync-free.
+                            fleet_hb.beat(
+                                step + 1, ledger=ledger, metrics=m,
+                                incident=(
+                                    recorder.incidents[-1]["path"]
+                                    if recorder is not None
+                                    and recorder.incidents else None
+                                ),
+                            )
                     if self.checkpointer is not None and (
                         step + 1
                     ) != last_saved_step:
@@ -1665,8 +1694,10 @@ class Trainer:
                             >= cfg.checkpoint_every_secs
                         )
                         if due:
-                            with tracer.span("checkpoint", step=step + 1), \
-                                    ledger.measure("checkpoint"):
+                            with tracer.span(
+                                "fit/checkpoint", bucket="checkpoint",
+                                step=step + 1,
+                            ):
                                 self._save_with_stamp(step + 1, state)
                             last_saved_step = step + 1
                             t_last_ckpt = time.time()
@@ -1674,8 +1705,7 @@ class Trainer:
                 if epoch_done:
                     epoch = (step + 1) // cfg.steps_per_epoch
                     if eval_iter_fn is not None and epoch % cfg.eval_every_epochs == 0:
-                        with tracer.span("eval", epoch=epoch), \
-                                ledger.measure("eval"):
+                        with tracer.span("fit/eval", bucket="eval", epoch=epoch):
                             em = self.evaluate(
                                 state, eval_iter_fn(), recorder=recorder
                             )
@@ -1688,8 +1718,9 @@ class Trainer:
                         and epoch % cfg.checkpoint_every_epochs == 0
                         and (step + 1) != last_saved_step
                     ):
-                        with tracer.span("checkpoint", step=step + 1), \
-                                ledger.measure("checkpoint"):
+                        with tracer.span(
+                            "fit/checkpoint", bucket="checkpoint", step=step + 1
+                        ):
                             self._save_with_stamp(step + 1, state)
                         last_saved_step = step + 1
                         t_last_ckpt = time.time()
@@ -1705,7 +1736,7 @@ class Trainer:
                             step + 1 - last_logged_step, window_s,
                             step=step + 1,
                         ):
-                            tracer.instant("stall_anomaly", step=step + 1)
+                            tracer.instant("fit/stall_anomaly", step=step + 1)
                             if autoprof is not None:
                                 autoprof.request("stall_anomaly", step + 1)
                         window_s = 0.0
@@ -1728,10 +1759,11 @@ class Trainer:
                 watchdog.stop()
             if self.checkpointer is not None:
                 if last_saved_step != num_steps:
-                    with tracer.span("checkpoint", step=num_steps), \
-                            ledger.measure("checkpoint"):
+                    with tracer.span(
+                        "fit/checkpoint", bucket="checkpoint", step=num_steps
+                    ):
                         self._save_with_stamp(num_steps, state)
-                with ledger.measure("checkpoint"):
+                with tracer.span("fit/checkpoint_wait", bucket="checkpoint"):
                     # The watchdog was stopped above precisely so this
                     # final flush can take as long as the storage needs.
                     self.checkpointer.wait()  # savlint: disable=SAV123 -- bounding the final checkpoint flush would truncate the save; watchdog already stopped
@@ -1804,7 +1836,7 @@ class Trainer:
                 # must not inherit the very hang it is escaping) and runs
                 # AFTER the watchdog disarms, so a slow drain on a crash
                 # path cannot be misclassified as a steady-state hang.
-                with ledger.measure("checkpoint"):
+                with tracer.span("fit/checkpoint_wait", bucket="checkpoint"):
                     if not self.checkpointer.wait(timeout_s=120.0):
                         print(
                             "trainer: in-flight checkpoint save still "

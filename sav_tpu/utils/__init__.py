@@ -18,7 +18,6 @@ _EXPORTS = {
     "parameter_overview": "sav_tpu.utils.param_overview",
     "log_parameter_overview": "sav_tpu.utils.param_overview",
     "StepTimer": "sav_tpu.utils.profiler",
-    "annotate": "sav_tpu.utils.profiler",
     "benchmark_fn": "sav_tpu.utils.profiler",
     "trace": "sav_tpu.utils.profiler",
     "assert_all_finite": "sav_tpu.utils.debug",

@@ -61,11 +61,6 @@ def trace(log_dir: Optional[str], *, host_tracer_level: int = 2):
         stop_trace()
 
 
-def annotate(name: str):
-    """Named trace span (shows up in the profiler timeline)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
 class StepTimer:
     """Rolling step-latency / throughput tracker.
 

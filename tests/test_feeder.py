@@ -367,8 +367,10 @@ def test_fit_feeder_with_watchdog_and_spans(tmp_path, devices):
             e["name"] for e in json.load(f)["traceEvents"]
             if e.get("ph") == "X"
         }
-    assert "batch_wait" in names
-    assert "shard_batch" not in names
+    assert "sav:fit/batch_wait" in names
+    assert "sav:fit/shard_batch" not in names
+    # The worker's stages are spans of the same file, from its own thread.
+    assert {"sav:feeder/fetch", "sav:feeder/place"} <= names
     # Ledger invariant survives the feeder: buckets still partition the
     # training thread's wall clock (background h2d is gauges, not time).
     g = trainer.last_goodput

@@ -19,12 +19,12 @@ def test_disabled_tracer_is_noop(tmp_path):
 def test_trace_file_is_perfetto_loadable_json(tmp_path):
     path = str(tmp_path / "spans.trace.json")
     tracer = SpanTracer(path)
-    with tracer.span("batch_fetch", step=1):
+    with tracer.span("fit/batch_wait", step=1):
         pass
-    with tracer.span("step_dispatch", step=1):
+    with tracer.span("fit/dispatch", step=1):
         with tracer.span("inner"):
             pass
-    tracer.instant("stall_anomaly", step=1)
+    tracer.instant("fit/stall_anomaly", step=1)
     assert tracer.write() == path
 
     with open(path) as f:
@@ -32,7 +32,7 @@ def test_trace_file_is_perfetto_loadable_json(tmp_path):
     events = doc["traceEvents"]
     complete = [e for e in events if e.get("ph") == "X"]
     assert {e["name"] for e in complete} == {
-        "batch_fetch", "step_dispatch", "inner"
+        "sav:fit/batch_wait", "sav:fit/dispatch", "sav:inner"
     }
     for e in complete:
         # The Trace Event Format's required complete-event fields.
@@ -40,7 +40,7 @@ def test_trace_file_is_perfetto_loadable_json(tmp_path):
         assert e["dur"] >= 0
         assert e["ts"] >= 0
     instants = [e for e in events if e.get("ph") == "i"]
-    assert instants and instants[0]["name"] == "stall_anomaly"
+    assert instants and instants[0]["name"] == "sav:fit/stall_anomaly"
     assert instants[0]["args"] == {"step": 1}
 
 
@@ -56,7 +56,7 @@ def test_nested_span_ordering(tmp_path):
             e["name"]: e for e in json.load(f)["traceEvents"]
             if e.get("ph") == "X"
         }
-    outer, inner = events["outer"], events["inner"]
+    outer, inner = events["sav:outer"], events["sav:inner"]
     assert outer["ts"] <= inner["ts"]
     assert outer["ts"] + outer["dur"] >= inner["ts"] + inner["dur"]
 
@@ -75,7 +75,7 @@ def test_span_records_on_exception(tmp_path):
             e["name"] for e in json.load(f)["traceEvents"]
             if e.get("ph") == "X"
         }
-    assert "failing" in names
+    assert "sav:failing" in names
 
 
 def test_write_is_idempotent_after_midrun_exception(tmp_path):
@@ -84,13 +84,13 @@ def test_write_is_idempotent_after_midrun_exception(tmp_path):
     flush (e.g. from an exception handler) is safe and wins."""
     path = str(tmp_path / "t.json")
     tracer = SpanTracer(path)
-    with tracer.span("step_dispatch", step=1):
+    with tracer.span("fit/dispatch", step=1):
         pass
     assert tracer.write() == path  # periodic flush mid-run
     with open(path) as f:
         first = json.load(f)["traceEvents"]
     try:
-        with tracer.span("step_dispatch", step=2):
+        with tracer.span("fit/dispatch", step=2):
             raise RuntimeError("mid-run crash")
     except RuntimeError:
         pass

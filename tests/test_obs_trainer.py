@@ -50,7 +50,7 @@ def _obs_trainer(tmp_path, **config_overrides):
 
 def test_fit_emits_diagnostics_spans_and_goodput(tmp_path, devices):
     # async_feed=False pins the *serial* loop's telemetry contract
-    # (batch_fetch/shard_batch spans, h2d bucket on the training thread);
+    # (batch_wait/shard_batch spans, h2d bucket on the training thread);
     # feeder-mode telemetry is covered in tests/test_feeder.py.
     trainer = _obs_trainer(tmp_path, watchdog_secs=300.0, async_feed=False)
     data = fake_data_iterator(batch_size=8, image_size=32, num_classes=10)
@@ -78,7 +78,10 @@ def test_fit_emits_diagnostics_spans_and_goodput(tmp_path, devices):
     with open(span_path) as f:
         events = json.load(f)["traceEvents"]
     names = {e["name"] for e in events if e.get("ph") == "X"}
-    assert {"batch_fetch", "shard_batch", "step_dispatch", "log_sync"} <= names
+    assert {
+        "sav:fit/batch_wait", "sav:fit/shard_batch", "sav:fit/dispatch",
+        "sav:fit/log_sync",
+    } <= names
 
     # --- goodput ledger: buckets sum to wall time within 5% ---
     goodput_path = os.path.join(str(tmp_path), "goodput.json")

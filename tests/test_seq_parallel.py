@@ -398,4 +398,4 @@ def test_fit_records_replication_fallback_once(devices, tmp_path):
     assert trainer.last_goodput["gauges"]["seq/replicated_batch"] == 2.0
     with open(tmp_path / "spans.trace.json") as f:
         names = {e["name"] for e in _json.load(f)["traceEvents"]}
-    assert "seq_replication_fallback" in names
+    assert "sav:fit/seq_replication_fallback" in names

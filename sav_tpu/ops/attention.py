@@ -6,7 +6,8 @@ Functional equivalent of the einsum pipeline inside the reference's
 
 Layout convention everywhere in this framework: ``[batch..., length, heads, head_dim]``
 (the natural output of ``nn.DenseGeneral`` head-splitting), matching the
-reference. The Pallas path transposes to ``[B*H, L, D]`` internally.
+reference. The flash kernel transposes to ``[B*H, L, D]`` internally; the
+fused kernel reads ``[B, L, H*D]`` in place.
 
 ``backend``:
   - ``'xla'``    — jnp/einsum path, plain autodiff backward. Measured faster
@@ -19,8 +20,9 @@ reference. The Pallas path transposes to ``[B*H, L, D]`` internally.
   - ``'fused'``  — single-pass fused short-sequence kernel
                    (:mod:`sav_tpu.ops.fused_attention`): the whole KV
                    sequence in one VMEM block, plain softmax (no online
-                   carry), single fused backward. Raises when the shape
-                   exceeds the single-block VMEM budget. Deterministic only.
+                   carry), single fused backward, ``[B, L, H, D]`` read
+                   and written in place. Raises when the shape exceeds the
+                   single-block VMEM budget. Deterministic only.
   - ``'pallas'`` — blockwise online-softmax flash kernel
                    (:mod:`sav_tpu.ops.flash_attention`) for shapes beyond
                    the single block. Deterministic only (attention dropout
@@ -35,8 +37,10 @@ reference. The Pallas path transposes to ``[B*H, L, D]`` internally.
                      ``fused_attention.fused_eligible``) → the measured
                      winner from the ``tools/attn_tune.py`` cache
                      (:mod:`sav_tpu.ops.attn_tuning`) — ``fused`` only
-                     where a sweep + ``ab_step`` gate confirmed the win on
-                     chip, else XLA (the PERF.md §5 measured winner);
+                     where a chip run confirmed the win (L 197, D 64, H 6
+                     and 12: PERF.md §6, PR 25), else XLA; never ``fused``
+                     in a program XLA partitions over several devices
+                     (:func:`partitioned_over`);
                    * middle band → ``xla`` (L² fits HBM comfortably and
                      XLA keeps the MXU busy).
 
@@ -48,6 +52,7 @@ reference. The Pallas path transposes to ``[B*H, L, D]`` internally.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import threading
@@ -95,6 +100,27 @@ def set_default_logits_dtype(dtype) -> None:
     """
     global _DEFAULT_LOGITS_DTYPE
     _DEFAULT_LOGITS_DTYPE = jnp.dtype(dtype).type
+
+
+# How many devices XLA partitions the program being traced over. A Mosaic
+# call cannot be partitioned automatically: the step would fail to lower.
+_TRACE = threading.local()
+
+
+@contextlib.contextmanager
+def partitioned_over(num_devices: int):
+    """Trace-time only: while the ``with`` block traces a program, ``auto``
+    knows that XLA will partition it over ``num_devices`` and promotes no
+    measured ``fused`` entry on more than one (wrapping the kernel in
+    ``shard_map`` is what would lift this). The trainer and the serve
+    engine trace their steps inside it with their mesh's size; a bare call
+    counts as one device."""
+    previous = getattr(_TRACE, "devices", 1)
+    _TRACE.devices = num_devices
+    try:
+        yield
+    finally:
+        _TRACE.devices = previous
 
 
 def _dense_logits_bytes(batch: int, heads: int, q_len: int, kv_len: int) -> int:
@@ -311,17 +337,21 @@ def resolve_attention_backend(
     requested: Optional[str] = None,
     kernels_ok: bool = True,
     on_tpu: Optional[bool] = None,
+    num_devices: Optional[int] = None,
 ) -> AttentionDispatch:
     """The three-way ``auto`` rule on static shapes (see module docstring).
 
     ``kernels_ok`` is the caller's eligibility for the Pallas paths (4-D
-    inputs, deterministic); ``on_tpu`` defaults to the live backend. Every
-    threshold here is test-pinned (tests/test_attn_dispatch.py). Explicit
-    ``requested`` backends pass through, picking up any tuned block config
-    for the shape.
+    inputs, deterministic); ``on_tpu`` defaults to the live backend and
+    ``num_devices`` to the enclosing :func:`partitioned_over` (one without
+    it). Every threshold here is test-pinned
+    (tests/test_attn_dispatch.py). Explicit ``requested`` backends pass
+    through, picking up any tuned block config for the shape.
     """
     if on_tpu is None:
         on_tpu = _on_tpu()
+    if num_devices is None:
+        num_devices = getattr(_TRACE, "devices", 1)
     entry = attn_tuning.lookup(batch, q_len, kv_len, heads, dim, dtype)
     tuned_cfg = attn_tuning.block_config(entry)
     if requested and requested != "auto":
@@ -353,14 +383,18 @@ def resolve_attention_backend(
             source="threshold",
             block_config=cfg,
         )
-    short = _fused.fused_eligible(q_len, kv_len, dim, itemsize=itemsize)
+    short = _fused.fused_eligible(
+        q_len, kv_len, dim, heads=heads, itemsize=itemsize
+    )
     if entry:
         # A measured winner from the tune cache. Fused is additionally
         # gated on the VMEM band (a fused verdict at an over-budget shape
-        # is stale/foreign — ignore it); xla and pallas verdicts apply at
+        # is stale/foreign — ignore it) and on a program of one device (a
+        # Mosaic call cannot be partitioned: the dense path runs as it did
+        # before the entry was measured); xla and pallas verdicts apply at
         # any shape the sweep measured.
         winner = entry["backend"]
-        if winner == "fused" and not short:
+        if winner == "fused" and (not short or num_devices > 1):
             winner = None
         if winner:
             return AttentionDispatch(
@@ -375,8 +409,8 @@ def resolve_attention_backend(
     return AttentionDispatch(
         backend="xla",
         reason=(
-            "short band, no measured fused win yet (promotion is gated on "
-            "the attn_tune + ab_step battery)"
+            "short band, no measured fused win to promote (promotion is "
+            "gated on a chip measurement of the shape, on one device)"
             if short
             else "middle band: dense logits fit HBM, XLA keeps the MXU busy"
         ),

@@ -8,12 +8,12 @@ traced shape up here to pick the measured-winner backend and block
 config instead of a hand-picked one.
 
 Promotion is evidence-gated by construction: without a measured cache
-entry the short-sequence band stays on XLA (the PERF.md §5 measured
-winner), and a fused/flash entry only exists where the autotuner +
-``tools/ab_step.py`` + the regression sentinel confirmed the win on
-chip. The checked-in default cache (``attn_tune_cache.json`` next to
-this module) carries the PERF.md §5 measurements; point
-``SAV_ATTN_TUNE_CACHE`` / :func:`set_cache_path` /
+entry the short-sequence band stays on XLA, and a fused/flash entry only
+exists where a chip run confirmed the win, in the kernel's own time and
+in the whole train step. The checked-in default cache
+(``attn_tune_cache.json`` next to this module) carries the v5e
+measurements of PERF.md §6 (PR 25): ``fused`` at L 197, D 64, H 6 and
+12. Point ``SAV_ATTN_TUNE_CACHE`` / :func:`set_cache_path` /
 ``TrainConfig.attention_tune_cache`` at a fresh sweep to override.
 
 Everything here runs at TRACE time only (the lookup is keyed on static
@@ -30,6 +30,7 @@ Cache schema (version 1)::
                    "block_q": int|null, "block_kv": int|null,
                    "block_b": int|null,
                    "fwd_ms": float|null, "fwd_bwd_ms": float|null,
+                   "min_batch": int (optional),
                    "source": "<tool / PERF.md section>"}
       },
       "infeasible": {
@@ -40,7 +41,10 @@ Cache schema (version 1)::
 
 Keys come from :func:`shape_key`; a lookup tries the exact batch first,
 then the batch-wildcard key (``B*``) so one measured model-zoo shape
-covers every batch size that shares its sequence geometry.
+covers every batch size that shares its sequence geometry — from
+``min_batch`` up, where the entry has one: the smallest batch the verdict
+was measured at (below it XLA keeps the dense tensors on chip and a
+kernel's win does not carry over; such a shape reads as never swept).
 """
 
 from __future__ import annotations
@@ -131,8 +135,11 @@ def lookup(
     entries = load_cache(path).get("entries", {})
     for b in (batch, "*"):
         entry = entries.get(shape_key(b, q_len, kv_len, heads, dim, dtype))
-        if isinstance(entry, dict) and entry.get("backend") in _BACKENDS:
-            return entry
+        if not isinstance(entry, dict) or entry.get("backend") not in _BACKENDS:
+            continue
+        if b == "*" and batch < entry.get("min_batch", 0):
+            continue
+        return entry
     return None
 
 

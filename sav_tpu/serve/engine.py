@@ -392,11 +392,16 @@ class ServeEngine:
         compile_t0 = time.perf_counter()
         cache_pre_aot = count_cache_entries(cache_dir)
         self._executables: dict = {}
-        for bucket in self.ladder.buckets:
-            lowered = self._infer.lower(
-                self._params, self._batch_stats, self._abstract_batch(bucket)
-            )
-            self._executables[bucket] = lowered.compile()
+        from sav_tpu.ops.attention import partitioned_over
+
+        # The buckets are traced here: 'auto' attention promotes a Mosaic
+        # kernel only in a program of one device.
+        with partitioned_over(self.mesh.size):
+            for bucket in self.ladder.buckets:
+                lowered = self._infer.lower(
+                    self._params, self._batch_stats, self._abstract_batch(bucket)
+                )
+                self._executables[bucket] = lowered.compile()
         compile_s = time.perf_counter() - compile_t0
         cache_after = count_cache_entries(cache_dir)
         # Per-bucket executable HBM estimate (ride-along fix: the report

@@ -35,6 +35,7 @@ from sav_tpu.obs.diagnostics import diagnostics_metrics
 from sav_tpu.obs.goodput import GoodputLedger
 from sav_tpu.obs.memory import RetraceCounter, hbm_stats
 from sav_tpu.obs.spans import SpanTracer, in_phase
+from sav_tpu.ops.attention import partitioned_over
 from sav_tpu.parallel.layout import (
     BoundLayout,
     layout_from_mesh,
@@ -654,13 +655,16 @@ class Trainer:
             # 'losses' collects auxiliary objectives modules sow (e.g. the
             # MoE load-balancing loss); empty for most models.
             mutable = ["batch_stats", "losses"] if has_bn else ["losses"]
-            logits, new_vars = self.model.apply(
-                variables,
-                images,
-                is_training=True,
-                rngs=rngs,
-                mutable=mutable,
-            )
+            # 'auto' attention resolves while the model is traced, and
+            # promotes a Mosaic kernel only in a program of one device.
+            with partitioned_over(self.mesh.size):
+                logits, new_vars = self.model.apply(
+                    variables,
+                    images,
+                    is_training=True,
+                    rngs=rngs,
+                    mutable=mutable,
+                )
             new_batch_stats = new_vars["batch_stats"] if has_bn else batch_stats
             # Sown 'losses' are ready-to-sum penalties at their relative
             # scales (see MoEFFBlock's convention note); aux_loss_weight is
@@ -816,7 +820,8 @@ class Trainer:
         variables = {"params": params}
         if state.batch_stats:
             variables["batch_stats"] = state.batch_stats
-        logits = self.model.apply(variables, images, is_training=False)
+        with partitioned_over(self.mesh.size):
+            logits = self.model.apply(variables, images, is_training=False)
         with jax.named_scope("metrics"):
             logits = logits.astype(jnp.float32)
             labels = batch["labels"]

@@ -125,13 +125,15 @@ def test_tuned_xla_entry_reports_tuned_source(tmp_path):
 
 def test_checked_in_default_cache_is_loadable_and_consulted():
     """The shipped table (sav_tpu/ops/attn_tune_cache.json) parses and
-    resolves the DeiT-S shape to the measured XLA win."""
+    resolves the DeiT-S shape to the fused win measured on the chip
+    (PERF.md §6, PR 25), with that run named as its source."""
     attn_tuning.set_cache_path(None)  # default resolution
     assert os.path.exists(attn_tuning.DEFAULT_CACHE_PATH)
     cache = attn_tuning.load_cache(attn_tuning.DEFAULT_CACHE_PATH)
     assert cache.get("version") == attn_tuning.CACHE_VERSION
     d = resolve_attention_backend(256, 197, 197, 6, 64, on_tpu=True)
-    assert d.backend == "xla" and d.source == "tuned"
+    assert d.backend == "fused" and d.source == "tuned"
+    assert "PR 25" in d.reason
     # The recorded Mosaic infeasibilities (block_b 16/32) survive too.
     inf = cache.get("infeasible", {})
     assert any(
@@ -139,6 +141,105 @@ def test_checked_in_default_cache_is_loadable_and_consulted():
         for recs in inf.values()
         for rec in recs
     )
+
+
+@pytest.mark.parametrize(
+    "shape, backend, source",
+    [
+        # (batch, q_len, kv_len, heads, dim): what PR 25 measured on the chip
+        ((256, 197, 197, 6, 64), "fused", "tuned"),  # DeiT-S, cell 1
+        ((128, 197, 197, 12, 64), "fused", "tuned"),  # ViT-B, cell 3
+        ((128, 197, 197, 6, 64), "fused", "tuned"),  # the smallest batch measured winning
+        # below it XLA keeps the dense tensors on chip and wins (measured):
+        ((96, 197, 197, 6, 64), "xla", "default"),
+        ((64, 197, 197, 12, 64), "xla", "default"),
+        ((8, 197, 197, 12, 64), "xla", "default"),  # a serve bucket
+        # and what it did not: no cell runs these, they stay dense
+        ((16, 785, 785, 6, 64), "xla", "tuned"),  # TNT outer keeps its entry
+        ((256, 1, 197, 6, 64), "xla", "default"),  # class attention
+        ((256, 196, 49, 6, 64), "xla", "default"),  # CvT: Lq != Lkv
+        ((256, 197, 197, 3, 64), "xla", "default"),  # DeiT-Ti: not measured
+        ((256, 197, 197, 4, 48), "xla", "default"),  # CaiT-XXS width
+        ((3136, 17, 17, 4, 6), "xla", "default"),  # TNT inner
+    ],
+)
+def test_checked_in_cache_promotes_only_what_was_measured(shape, backend, source):
+    attn_tuning.set_cache_path(None)
+    d = resolve_attention_backend(*shape, on_tpu=True)
+    assert (d.backend, d.source) == (backend, source)
+    # Off the TPU nothing is promoted, whatever the table says.
+    assert resolve_attention_backend(*shape, on_tpu=False).backend == "xla"
+
+
+@pytest.mark.parametrize("num_devices", [2, 4, 8])
+def test_partitioned_program_resolves_as_before_the_fused_entries(num_devices):
+    """A Mosaic call cannot be partitioned automatically: in a program over
+    more than one device ``auto`` answers what it answered before PR 25's
+    cache entries, so a working multi-chip run is not turned into a
+    lowering failure."""
+    attn_tuning.set_cache_path(None)
+    for shape in ((256, 197, 197, 6, 64), (128, 197, 197, 12, 64)):
+        by_argument = resolve_attention_backend(*shape, on_tpu=True, num_devices=num_devices)
+        with att.partitioned_over(num_devices):
+            in_context = resolve_attention_backend(*shape, on_tpu=True)
+            # The enclosing trace's word stands until the block ends, and a
+            # nested single-device trace has its own.
+            with att.partitioned_over(1):
+                assert resolve_attention_backend(*shape, on_tpu=True).backend == "fused"
+        assert by_argument == in_context
+        assert in_context.backend == "xla" and in_context.block_config is None
+    assert resolve_attention_backend(256, 197, 197, 6, 64, on_tpu=True).backend == "fused"
+    with att.partitioned_over(num_devices):
+        # What the bands and the other entries said, they still say: the
+        # recipe's global batch goes to the flash kernel (and an explicit
+        # backend is the caller's own business).
+        assert resolve_attention_backend(1024, 197, 197, 6, 64, on_tpu=True).backend == "pallas"
+        assert resolve_attention_backend(16, 785, 785, 6, 64, on_tpu=True).source == "tuned"
+        assert resolve_attention_backend(
+            256, 197, 197, 6, 64, on_tpu=True, requested="fused").backend == "fused"
+
+
+@pytest.mark.parametrize("mesh_devices, backend", [(1, "fused"), (2, "xla"), (4, "xla")])
+def test_trainer_traces_its_step_knowing_its_mesh(monkeypatch, devices, mesh_devices, backend):
+    """The trainer's own trace: DeiT-S's attention shape on a TPU resolves
+    to the kernel on a mesh of one device and to the dense path on a
+    data-parallel mesh (traced only: nothing is compiled or run)."""
+    from sav_tpu.parallel import create_mesh
+    from sav_tpu.train import TrainConfig, Trainer
+
+    attn_tuning.set_cache_path(None)
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    batch = 128  # the checked-in entry holds from batch 128 up
+    config = TrainConfig(
+        model_name="deit_s_patch16", num_classes=10, image_size=224,
+        compute_dtype="bfloat16", global_batch_size=batch, transpose_images=False,
+        model_overrides={"num_layers": 1}, seed=0,
+    )
+    trainer = Trainer(config, mesh=create_mesh({"data": mesh_devices}, devices=devices[:mesh_devices]))
+    state = jax.eval_shape(trainer.init_state)
+    images = jax.ShapeDtypeStruct((batch, 224, 224, 3), jnp.float32)
+    labels = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    att.clear_dispatch_log()
+    trainer._train_step.lower(state, {"images": images, "labels": labels}, rng)
+    trainer._eval_step.lower(state, {"images": images, "labels": labels})
+    log = att.snapshot_dispatch_log()
+    att.clear_dispatch_log()
+    assert [(e["shape"], e["backend"]) for e in log] == [([batch, 197, 6, 64], backend)]
+
+
+def test_wildcard_entry_holds_from_its_smallest_measured_batch(tmp_path):
+    key_star = attn_tuning.shape_key("*", 197, 197, 6, 64)
+    key_exact = attn_tuning.shape_key(16, 197, 197, 6, 64)
+    _install_cache(tmp_path, {
+        key_star: {"backend": "fused", "min_batch": 128, "source": "star"},
+        key_exact: {"backend": "fused", "min_batch": 128, "source": "exact"},
+    })
+    assert attn_tuning.lookup(128, 197, 197, 6, 64)["source"] == "star"
+    assert attn_tuning.lookup(127, 197, 197, 6, 64) is None
+    # An entry for the exact batch is that batch's own measurement.
+    assert attn_tuning.lookup(16, 197, 197, 6, 64)["source"] == "exact"
+    assert resolve_attention_backend(64, 197, 197, 6, 64, on_tpu=True).source == "default"
 
 
 def test_lookup_batch_wildcard_and_exact_precedence(tmp_path):

@@ -38,6 +38,9 @@ def _qkv(b=2, lq=197, lk=None, h=2, d=64, dtype=jnp.float32, seed=0):
         (2, 50, 50, 2, 32),  # ragged: padded q rows AND kv cols
         (2, 1, 197, 2, 64),  # class attention: single query row
         (2, 196, 49, 2, 64),  # CvT: downsampled K/V
+        (2, 197, 197, 6, 64),  # DeiT-S whole: six heads of one [L, 384] block
+        (2, 197, 197, 12, 64),  # ViT-B whole: twelve heads, [L, 768]
+        (3, 50, 50, 3, 40),  # ragged: odd batch, heads that straddle lane tiles
     ],
 )
 def test_fused_matches_xla_fwd_and_grads(b, lq, lk, h, d):
@@ -151,6 +154,110 @@ def test_fused_bf16_grads_finite_and_close():
         a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
         assert np.isfinite(a).all()
         np.testing.assert_allclose(a, b_, atol=0.15, rtol=0.15)
+
+
+@pytest.mark.parametrize(
+    "b,lq,lk,h,d,block_b",
+    [
+        (2, 197, 197, 6, 64, None),  # DeiT-S
+        (2, 197, 197, 12, 64, 2),  # ViT-B, two batch elements a grid cell
+        (3, 50, 50, 3, 40, None),  # ragged
+    ],
+)
+def test_fused_bf16_matches_f32_reference_fwd_and_grads(b, lq, lk, h, d, block_b):
+    """bf16 in, f32 softmax in VMEM: forward and all three gradients stay
+    as close to the float32 reference as the dense bf16 path does (the
+    kernel rounds the probabilities once, the dense path the logits too)."""
+    q, k, v = _qkv(b=b, lq=lq, lk=lk, h=h, d=d, dtype=jnp.bfloat16)
+    g = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.bfloat16)
+
+    def fwd_and_grads(fn, *args):
+        out, vjp = jax.vjp(fn, *args[:3])
+        return (out,) + vjp(args[3].astype(out.dtype))
+
+    ref = fwd_and_grads(xla_attention, *(x.astype(jnp.float32) for x in (q, k, v, g)))
+    dense = fwd_and_grads(
+        lambda q, k, v: xla_attention(q, k, v, logits_dtype=jnp.bfloat16), q, k, v, g
+    )
+    fused = fwd_and_grads(
+        lambda q, k, v: fused_attention(q, k, v, block_b=block_b), q, k, v, g
+    )
+
+    def gap(x, r):
+        x, r = np.asarray(x, np.float32), np.asarray(r, np.float32)
+        return np.linalg.norm(x - r) / np.linalg.norm(r)
+
+    for f, x, r in zip(fused, dense, ref):
+        assert f.dtype == jnp.bfloat16 and np.isfinite(np.asarray(f, np.float32)).all()
+        assert gap(f, r) < 8e-3
+        assert gap(f, r) < 1.25 * gap(x, r)
+
+
+@pytest.mark.parametrize(
+    "b,lq,lk,h,d,block_q",
+    [
+        (2, 197, 197, 6, 64, 256),  # one q block of the sequence's own length
+        (1, 785, 785, 2, 32, 256),  # four q blocks, the last one 17 rows
+        (2, 196, 49, 2, 64, 128),  # Lq != Lkv, two q blocks
+    ],
+)
+def test_fused_residual_is_the_compact_logsumexp(b, lq, lk, h, d, block_q):
+    """What the forward saves for the backward: ``[B, H, Lq]`` float32,
+    the rows' logsumexp and nothing lane-broadcast."""
+    from sav_tpu.ops.fused_attention import _fused_forward
+
+    q, k, v = _qkv(b=b, lq=lq, lk=lk, h=h, d=d)
+    scale = d**-0.5
+    out, lse = _fused_forward(q, k, v, None, scale, block_q, None, None, with_lse=True)
+    assert lse.shape == (b, h, lq) and lse.dtype == jnp.float32
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.nn.logsumexp(logits, axis=-1)),
+        atol=1e-4, rtol=1e-5,
+    )
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(xla_attention(q, k, v)), atol=2e-5, rtol=2e-5
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_fused_wrapper_moves_no_tensor_outside_the_kernel(dtype):
+    """Forward and backward are each ONE ``pallas_call`` between free
+    reshapes: no pad of L or D, no transpose to ``[B·H, L, D]``, no
+    broadcast residual, and every kernel operand is the model's own
+    ``[B, L, H·D]`` (or the ``[B, H, L]`` logsumexp)."""
+    q, k, v = _qkv(b=2, lq=197, h=6, d=64, dtype=dtype)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fused_attention(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    def flat(jaxpr):
+        """Every equation, those of nested calls (custom_vjp, pjit)
+        included; a kernel's own body is not the wrapper's."""
+        for e in jaxpr.eqns:
+            nested = [] if e.primitive.name == "pallas_call" else [
+                getattr(v, "jaxpr", v) for v in e.params.values()
+                if hasattr(getattr(v, "jaxpr", v), "eqns")
+            ]
+            if nested:
+                for sub in nested:
+                    yield from flat(sub)
+            else:
+                yield e
+
+    eqns = list(flat(jax.make_jaxpr(fwd_bwd)(q, k, v).jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 2
+    moved = {e.primitive.name for e in eqns} & {
+        "pad", "transpose", "broadcast_in_dim", "concatenate", "dynamic_slice", "slice", "gather",
+    }
+    # The loss's own broadcast of its cotangent is the test's, not the wrapper's.
+    assert moved <= {"broadcast_in_dim"}
+    for call in calls:
+        for var in list(call.invars) + list(call.outvars):
+            assert var.aval.shape in ((2, 197, 384), (2, 6, 197)), var.aval
 
 
 def test_fused_softmax_stability():

@@ -117,13 +117,14 @@ def variant_specs(b, lq, lkv, h, d, *, blocks, block_bs, backends, itemsize):
     if "fused" in backends:
         for bq, _ in blocks:
             for bb in block_bs:
-                if bh % bb != 0:
+                if b % bb != 0:  # the fused kernel's cells hold batch elements
                     continue
                 cfg = {"block_q": bq, "block_kv": None, "block_b": bb}
                 name = f"fused bq={bq} bb={bb}"
                 if (
                     fumod.fused_vmem_bytes(
-                        lq, lkv, d, block_q=bq, block_b=bb, itemsize=itemsize
+                        lq, lkv, d, heads=h, block_q=bq, block_b=bb,
+                        itemsize=itemsize,
                     )
                     > fumod.FUSED_VMEM_BUDGET
                 ):

@@ -5,10 +5,12 @@ from sav_tpu.models.cait import CaiT
 from sav_tpu.models.ceit import CeiT
 from sav_tpu.models.cvt import CvT
 from sav_tpu.models.mlp_mixer import MLPMixer
+from sav_tpu.models.ouro import OuroLM
 from sav_tpu.models.registry import (
     create_model,
     model_names,
     model_supports,
+    model_task,
     register,
 )
 from sav_tpu.models.surgery import adapt_pos_embeds, resize_pos_embed_table
@@ -25,8 +27,10 @@ __all__ = [
     "CvT",
     "TNT",
     "MLPMixer",
+    "OuroLM",
     "create_model",
     "model_names",
     "model_supports",
+    "model_task",
     "register",
 ]

@@ -15,9 +15,9 @@ from sav_tpu.models.layers.cvt_attention import (
     CvTAttentionBlock,
     CvTSelfAttentionBlock,
 )
-from sav_tpu.models.layers.feedforward import FFBlock, LeFFBlock
+from sav_tpu.models.layers.feedforward import FFBlock, GatedFFBlock, LeFFBlock
 from sav_tpu.models.layers.moe import MoEFFBlock
-from sav_tpu.models.layers.normalization import LayerScaleBlock
+from sav_tpu.models.layers.normalization import LayerScaleBlock, RMSNorm
 from sav_tpu.models.layers.position_embed import (
     AddAbsPosEmbed,
     FixedPositionalEmbedding,
@@ -38,9 +38,11 @@ __all__ = [
     "CvTAttentionBlock",
     "CvTSelfAttentionBlock",
     "FFBlock",
+    "GatedFFBlock",
     "LeFFBlock",
     "MoEFFBlock",
     "LayerScaleBlock",
+    "RMSNorm",
     "AddAbsPosEmbed",
     "FixedPositionalEmbedding",
     "RotaryPositionalEmbedding",

@@ -29,7 +29,12 @@ from sav_tpu.ops.quant import (
     int8_ste_dot,
     quant_rng_data,
 )
-from sav_tpu.ops.rotary import apply_rotary_pos_emb, fixed_positional_embedding
+from sav_tpu.ops.rotary import (
+    apply_rotary_half,
+    apply_rotary_pos_emb,
+    fixed_positional_embedding,
+    half_split_tables,
+)
 
 Dtype = Any
 
@@ -189,6 +194,13 @@ class AttentionBlock(nn.Module):
     # RoPE on Q/K after projection (the working rebuild of the reference's
     # broken, never-wired rotary path — SURVEY.md §2.9 #12).
     use_rotary: bool = False
+    # The decoder family's rotary: lanes paired by halves (i with i + D/2)
+    # at this base, rotated in float32. None keeps the zoo's every-two
+    # pairing at base 10,000.
+    rotary_half_base: Optional[float] = None
+    # Decoder self-attention: position i sees j <= i (the dense path masks by
+    # an iota comparison, the flash kernel skips blocks above the diagonal).
+    causal: bool = False
     # Attention-core backend: None/'auto' = measured three-way dispatch
     # (sav_tpu.ops.attention.resolve_attention_backend — fused-short /
     # xla / flash by shape band + the attn_tune cache), or force 'xla' |
@@ -254,7 +266,18 @@ class AttentionBlock(nn.Module):
             key = proj(name="to_k")(inputs_kv)
             value = proj(name="to_v")(inputs_kv)
 
-        if self.use_rotary:
+        if self.causal and (
+            self.seq_parallel or self.talking_heads or inputs_q is not inputs_kv
+        ):
+            raise ValueError(
+                "causal attention is plain self-attention on one device: no "
+                "talking heads, no sequence parallelism, no cross-attention"
+            )
+        if self.use_rotary and self.rotary_half_base is not None:
+            sincos = half_split_tables(query.shape[1], head_ch, self.rotary_half_base)
+            query = apply_rotary_half(query, sincos)
+            key = apply_rotary_half(key, sincos)
+        elif self.use_rotary:
             sincos = fixed_positional_embedding(query.shape[1], head_ch)
             query = apply_rotary_pos_emb(query, sincos)
             if key.shape[1] != query.shape[1]:
@@ -397,6 +420,7 @@ class AttentionBlock(nn.Module):
                 deterministic=not is_training,
                 backend=self.backend,
                 logits_dtype=self.logits_dtype or self.dtype,
+                causal=self.causal,
             )
 
         out = dense(

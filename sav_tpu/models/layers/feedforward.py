@@ -1,4 +1,5 @@
-"""Feed-forward blocks: transformer MLP and CeiT's locally-enhanced FF.
+"""Feed-forward blocks: transformer MLP, the gated (SwiGLU) MLP and CeiT's
+locally-enhanced FF.
 
 Reference: FFBlock (/root/reference/models/layers/feedforwards/ff.py:8-34),
 LeFFBlock (/root/reference/models/layers/feedforwards/leff.py:9-63).
@@ -46,6 +47,49 @@ class FFBlock(nn.Module):
         x = dense(in_ch, use_bias=self.use_bias, dtype=self.dtype, name="fc2")(x)
         x = nn.Dropout(rate=self.dropout_rate)(x, deterministic=not is_training)
         return x
+
+
+def _bias_free_dense(quant: Optional[str], dtype):
+    return functools.partial(
+        functools.partial(QuantDense, mode=quant) if quant else nn.Dense,
+        use_bias=False,
+        dtype=dtype,
+    )
+
+
+class _GateUp(nn.Module):
+    """The gated MLP's input matmuls as two children of one scope (``fc1``):
+    ``act(x W_gate) * (x W_up)``."""
+
+    hidden_ch: int
+    activation_fn: Callable
+    quant: Optional[str]
+    dtype: Dtype
+
+    @nn.compact
+    def __call__(self, inputs: jax.Array) -> jax.Array:
+        dense = _bias_free_dense(self.quant, self.dtype)
+        gate = dense(self.hidden_ch, name="gate")(inputs)
+        up = dense(self.hidden_ch, name="up")(inputs)
+        return self.activation_fn(gate) * up
+
+
+class GatedFFBlock(nn.Module):
+    """``W_down(act(W_gate x) * W_up x)``, no bias (SwiGLU with ``silu``).
+    Scopes as :class:`FFBlock`'s: the input matmuls under ``fc1``, the down
+    projection ``fc2``."""
+
+    hidden_ch: int
+    activation_fn: Callable = nn.silu
+    quant: Optional[str] = None  # as FFBlock.quant
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, inputs: jax.Array) -> jax.Array:
+        x = _GateUp(
+            self.hidden_ch, self.activation_fn, self.quant, self.dtype, name="fc1"
+        )(inputs)
+        return _bias_free_dense(self.quant, self.dtype)(inputs.shape[-1], name="fc2")(x)
 
 
 class LeFFBlock(nn.Module):

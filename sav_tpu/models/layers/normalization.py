@@ -1,4 +1,4 @@
-"""LayerScale (CaiT). Reference: /root/reference/models/layers/normalizations/layerscale.py:5-23."""
+"""LayerScale (CaiT; /root/reference/models/layers/normalizations/layerscale.py:5-23) and RMSNorm (the decoder family)."""
 
 from __future__ import annotations
 
@@ -22,3 +22,19 @@ class LayerScaleBlock(nn.Module):
         dim = inputs.shape[-1]
         scale = self.param("scale", nn.initializers.constant(self.eps), (dim,))
         return inputs * scale.astype(inputs.dtype)
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * scale``: no mean subtraction, no bias.
+    Statistics in float32 whatever the input's dtype; the result is cast to
+    ``dtype``."""
+
+    eps: float = 1e-6
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, inputs: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones, (inputs.shape[-1],))
+        x = inputs.astype(jnp.float32)
+        x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps)
+        return (x * scale).astype(self.dtype)

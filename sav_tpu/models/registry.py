@@ -23,14 +23,19 @@ from sav_tpu.models.cait import CaiT
 from sav_tpu.models.ceit import CeiT
 from sav_tpu.models.cvt import CvT
 from sav_tpu.models.mlp_mixer import MLPMixer
+from sav_tpu.models.ouro import OuroLM
 from sav_tpu.models.tnt import TNT
 from sav_tpu.models.vit import ViT
 
 _REGISTRY: dict[str, tuple[type, dict[str, Any]]] = {}
+# What the trainer's step feeds a model and scores it by
+# (sav_tpu/train/tasks.py); every entry that names none classifies images.
+_TASKS: dict[str, str] = {}
 
 
-def register(name: str, cls: type, **kwargs):
+def register(name: str, cls: type, *, task: str = "image", **kwargs):
     _REGISTRY[name] = (cls, kwargs)
+    _TASKS[name] = task
 
 
 def _vit(embed_dim, num_layers, num_heads, patch):
@@ -147,6 +152,19 @@ register("mixer_l_patch32", MLPMixer, **_mixer(1024, 24, 512, 4096, 32))
 register("mixer_l_patch16", MLPMixer, **_mixer(1024, 24, 512, 4096, 16))
 
 
+# --- Ouro (looped language model; arXiv:2510.25741) -------------------------
+# Sizes of https://huggingface.co/ByteDance/Ouro-2.6B/blob/main/config.json;
+# ``num_classes`` is the vocabulary (49,152 there). 2.67 B parameters: one
+# chip holds a cut in depth (model_overrides={"num_layers": 4, ...}).
+register(
+    "ouro_2_6b",
+    OuroLM,
+    task="tokens",
+    embed_dim=2048, num_layers=48, num_heads=16, head_ch=128, mlp_ch=5632,
+    ut_steps=4, rope_theta=1e6, norm_eps=1e-6,
+)
+
+
 def model_names() -> list[str]:
     return sorted(_REGISTRY)
 
@@ -228,6 +246,15 @@ def create_model(
         merged["seq_parallel"] = seq_parallel
         merged["seq_mesh"] = seq_mesh
     return cls(**merged)
+
+
+def model_task(model_name: str) -> str:
+    """The task the named model trains on: ``"image"`` or ``"tokens"``."""
+    if model_name not in _REGISTRY:
+        raise ValueError(
+            f"unknown model {model_name!r}; available: {', '.join(model_names())}"
+        )
+    return _TASKS[model_name]
 
 
 def model_supports(model_name: str, field: str) -> bool:

@@ -44,6 +44,12 @@ fused kernel reads ``[B, L, H*D]`` in place.
                    * middle band → ``xla`` (L² fits HBM comfortably and
                      XLA keeps the MXU busy).
 
+                   A ``causal`` core (decoder self-attention) follows the
+                   same rule on the cache's ``.causal`` entries, never
+                   resolves to ``fused``, masks by an iota comparison on
+                   the dense path and skips the blocks above the diagonal
+                   in the flash kernel.
+
                    Every resolution is recorded in a trace-time dispatch
                    log (:func:`snapshot_dispatch_log`) that ``bench.py``
                    stamps into its JSON line and run manifest, so perf
@@ -141,6 +147,7 @@ def xla_attention(
     dropout_rng: Optional[jax.Array] = None,
     deterministic: bool = True,
     logits_dtype=None,
+    causal: bool = False,
 ) -> jax.Array:
     """Reference attention core in pure XLA ops.
 
@@ -152,6 +159,9 @@ def xla_attention(
       logits_dtype: dtype for softmax math; None = the process default
         (:func:`set_default_logits_dtype`, f32 unless configured). fp32
         keeps bf16 runs stable; bf16 halves the L² HBM traffic.
+      causal: position ``i`` attends to ``j <= i`` (self-attention:
+        ``q_len == kv_len``). The mask is an iota comparison inside the
+        program, never an ``[L, L]`` bias operand.
 
     Returns:
       ``[..., q_len, heads, head_dim]`` in the query dtype.
@@ -162,7 +172,7 @@ def xla_attention(
         logits_dtype = _DEFAULT_LOGITS_DTYPE
     # Canonicalize: config-layer callers pass strings ('bfloat16').
     logits_dtype = jnp.dtype(logits_dtype)
-    probs = _softmax_probs(query, key, bias, scale, logits_dtype)
+    probs = _softmax_probs(query, key, bias, scale, logits_dtype, causal)
     if dropout_rate > 0.0 and not deterministic:
         if dropout_rng is None:
             raise ValueError("dropout_rng required for non-deterministic attention dropout")
@@ -172,7 +182,16 @@ def xla_attention(
     return jnp.einsum("...hqk,...khd->...qhd", probs, value)
 
 
-def _softmax_probs(q, k, bias, scale, logits_dtype):
+def causal_mask(q_len: int, kv_len: int) -> jax.Array:
+    """``[q_len, kv_len]`` bool, true where a query may look: ``j <= i``."""
+    if q_len != kv_len:
+        raise ValueError(
+            f"causal attention is self-attention: q_len {q_len} != kv_len {kv_len}"
+        )
+    return _flash._causal_keep(0, 0, q_len, kv_len)
+
+
+def _softmax_probs(q, k, bias, scale, logits_dtype, causal=False):
     """Shared scaled-QK softmax — the single source of forward numerics for
     both the autodiff reference path and the fast-VJP path."""
     qs = q * jnp.asarray(scale, dtype=q.dtype)
@@ -181,6 +200,9 @@ def _softmax_probs(q, k, bias, scale, logits_dtype):
     )
     if bias is not None:
         logits = logits + bias.astype(logits_dtype)
+    if causal:
+        mask = causal_mask(logits.shape[-2], logits.shape[-1])
+        logits = jnp.where(mask, logits, jnp.asarray(-jnp.inf, logits.dtype))
     return jax.nn.softmax(logits, axis=-1)
 
 
@@ -338,6 +360,7 @@ def resolve_attention_backend(
     kernels_ok: bool = True,
     on_tpu: Optional[bool] = None,
     num_devices: Optional[int] = None,
+    causal: bool = False,
 ) -> AttentionDispatch:
     """The three-way ``auto`` rule on static shapes (see module docstring).
 
@@ -346,13 +369,15 @@ def resolve_attention_backend(
     ``num_devices`` to the enclosing :func:`partitioned_over` (one without
     it). Every threshold here is test-pinned
     (tests/test_attn_dispatch.py). Explicit ``requested`` backends pass
-    through, picking up any tuned block config for the shape.
+    through, picking up any tuned block config for the shape. A ``causal``
+    core reads the cache's ``.causal`` entries only, and is never ``fused``
+    (the single-pass kernel has no mask).
     """
     if on_tpu is None:
         on_tpu = _on_tpu()
     if num_devices is None:
         num_devices = getattr(_TRACE, "devices", 1)
-    entry = attn_tuning.lookup(batch, q_len, kv_len, heads, dim, dtype)
+    entry = attn_tuning.lookup(batch, q_len, kv_len, heads, dim, dtype, causal=causal)
     tuned_cfg = attn_tuning.block_config(entry)
     if requested and requested != "auto":
         cfg = tuned_cfg if (entry and entry["backend"] == requested) else None
@@ -383,7 +408,7 @@ def resolve_attention_backend(
             source="threshold",
             block_config=cfg,
         )
-    short = _fused.fused_eligible(
+    short = not causal and _fused.fused_eligible(
         q_len, kv_len, dim, heads=heads, itemsize=itemsize
     )
     if entry:
@@ -430,8 +455,13 @@ def dot_product_attention(
     deterministic: bool = True,
     backend: Optional[str] = None,
     logits_dtype=None,
+    causal: bool = False,
 ) -> jax.Array:
     """Backend-dispatched attention. See module docstring.
+
+    ``causal`` masks ``j > i`` on the dense path (an iota comparison) and
+    in the flash kernel (blocks above the diagonal are skipped); the
+    single-pass ``fused`` kernel has no causal arm and refuses it.
 
     ``logits_dtype`` sets the XLA path's softmax dtype (None = the
     deprecated process-wide default, f32 unless configured). The Pallas
@@ -454,6 +484,7 @@ def dot_product_attention(
         dispatch = resolve_attention_backend(
             b, lq, key.shape[1], h, d,
             dtype=query.dtype, requested=requested, kernels_ok=True,
+            causal=causal,
         )
         _log_dispatch(tuple(query.shape), key.shape[1], requested, dispatch)
         backend = dispatch.backend
@@ -467,13 +498,17 @@ def dot_product_attention(
             )
         backend, cfg = "xla", {}
     if backend == "fused":
+        if causal:
+            raise ValueError("the fused attention kernel has no causal arm")
         # Shape ineligibility (kv_len over the single-block VMEM budget)
         # raises inside fused_attention with the budget numbers.
         kw = {k: cfg[k] for k in ("block_q", "block_b") if k in cfg}
         return _fused.fused_attention(query, key, value, bias, scale=scale, **kw)
     if backend == "pallas":
-        kw = {k: cfg[k] for k in ("block_q", "block_kv") if k in cfg}
-        return _flash.flash_attention(query, key, value, bias, scale=scale, **kw)
+        kw = {k: cfg[k] for k in ("block_q", "block_kv", "block_b") if k in cfg}
+        return _flash.flash_attention(
+            query, key, value, bias, scale=scale, causal=causal, **kw
+        )
     return xla_attention(
         query,
         key,
@@ -484,4 +519,5 @@ def dot_product_attention(
         dropout_rng=dropout_rng,
         deterministic=deterministic,
         logits_dtype=logits_dtype,
+        causal=causal,
     )

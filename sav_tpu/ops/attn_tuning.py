@@ -39,7 +39,9 @@ Cache schema (version 1)::
       }
     }
 
-Keys come from :func:`shape_key`; a lookup tries the exact batch first,
+Keys come from :func:`shape_key` (a causal core's end in ``.causal``: it
+does half the work of its shape unmasked and is measured apart); a lookup
+tries the exact batch first,
 then the batch-wildcard key (``B*``) so one measured model-zoo shape
 covers every batch size that shares its sequence geometry — from
 ``min_batch`` up, where the entry has one: the smallest batch the verdict
@@ -71,11 +73,15 @@ _loaded: dict = {}
 
 
 def shape_key(
-    batch, q_len: int, kv_len: int, heads: int, dim: int, dtype="bfloat16"
+    batch, q_len: int, kv_len: int, heads: int, dim: int, dtype="bfloat16",
+    causal: bool = False,
 ) -> str:
-    """Canonical cache key. ``batch`` may be ``'*'`` for the wildcard."""
+    """Canonical cache key. ``batch`` may be ``'*'`` for the wildcard; a
+    causal core does half the work of the same shape unmasked and has
+    entries of its own (``....causal``)."""
     dt = jnp.dtype(dtype).name
-    return f"B{batch}.Lq{q_len}.Lkv{kv_len}.H{heads}.D{dim}.{dt}"
+    key = f"B{batch}.Lq{q_len}.Lkv{kv_len}.H{heads}.D{dim}.{dt}"
+    return key + ".causal" if causal else key
 
 
 def set_cache_path(path: Optional[str]) -> None:
@@ -127,6 +133,7 @@ def lookup(
     dim: int,
     dtype="bfloat16",
     *,
+    causal: bool = False,
     path: Optional[str] = None,
 ) -> Optional[dict]:
     """Measured entry for a shape (exact batch, then batch-wildcard);
@@ -134,7 +141,7 @@ def lookup(
     backend name are ignored rather than dispatched on."""
     entries = load_cache(path).get("entries", {})
     for b in (batch, "*"):
-        entry = entries.get(shape_key(b, q_len, kv_len, heads, dim, dtype))
+        entry = entries.get(shape_key(b, q_len, kv_len, heads, dim, dtype, causal))
         if not isinstance(entry, dict) or entry.get("backend") not in _BACKENDS:
             continue
         if b == "*" and batch < entry.get("min_batch", 0):

@@ -5,7 +5,10 @@ The TPU execution backend for every attention family in the layer zoo
 that streams K/V tiles through VMEM, keeps the running ``(max, sum, acc)``
 statistics in scratch, and never materializes the ``[B, H, Lq, Lk]`` logits
 in HBM. An optional additive bias input carries 2-D relative-position logits
-(BoTNet) or masks through the fused softmax.
+(BoTNet) or masks through the fused softmax. ``causal=True`` (decoder
+self-attention) needs no bias: a block wholly above the diagonal is neither
+fetched nor computed, a block the diagonal crosses is masked in VMEM by an
+iota comparison, forward and in both backward kernels.
 
 Differentiation: ``flash_attention`` is a ``jax.custom_vjp``. Without a
 bias, the backward is fully blocked Pallas too: the forward saves only the
@@ -56,12 +59,62 @@ def _pick_block_b(bh: int, *, force_one: bool = False) -> int:
     return 1
 
 
-def _online_softmax_step(s, v, o_ref, m_scr, l_scr, acc_scr, ki,
-                         num_kv_blocks, bi, lse_ref=None):
-    """Shared flash epilogue for one batch·head slice ``bi``: fold this
-    block's logits ``s`` into the running (max, sum, acc) statistics; write
-    the normalized output (and, when ``lse_ref`` is given, the per-row
-    logsumexp the blocked backward needs) on the last kv block."""
+def _causal_blocks(qi, ki, block_q: int, block_kv: int):
+    """For q block ``qi`` against kv block ``ki`` under the causal mask:
+    ``(visible, crossed)``. ``visible``: some ``col <= row`` exists, the
+    block has work. ``crossed``: the diagonal runs through it, so it needs
+    the element mask (a visible block that is not crossed lies wholly below
+    the diagonal)."""
+    first_row, last_row = qi * block_q, qi * block_q + block_q - 1
+    first_col, last_col = ki * block_kv, ki * block_kv + block_kv - 1
+    visible = first_col <= last_row
+    crossed = jnp.logical_and(visible, last_col > first_row)
+    return visible, crossed
+
+
+def _causal_keep(qi, ki, block_q: int, block_kv: int):
+    """``[block_q, block_kv]`` bool: ``col <= row`` in global positions."""
+    shape = (block_q, block_kv)
+    row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return col <= row
+
+
+def _for_causal_blocks(causal: bool, qi, ki, block_q: int, block_kv: int, body):
+    """Run ``body(masked)`` for this grid cell: always and unmasked without
+    ``causal``; else not at all above the diagonal, masked on it, unmasked
+    below it."""
+    if not causal:
+        body(False)
+        return
+    visible, crossed = _causal_blocks(qi, ki, block_q, block_kv)
+    pl.when(crossed)(lambda: body(True))
+    pl.when(jnp.logical_and(visible, jnp.logical_not(crossed)))(lambda: body(False))
+
+
+def _last_kv_block(qi, block_q: int, block_kv: int):
+    """Index of the last kv block a causal q block can see."""
+    return (qi * block_q + block_q - 1) // block_kv
+
+
+def _first_q_block(ki, block_q: int, block_kv: int):
+    """Index of the first q block that can see a causal kv block."""
+    return (ki * block_kv) // block_q
+
+
+def _resolve_block_b(block_b: Optional[int], bh: int, *, force_one: bool = False) -> int:
+    """The caller's ``block_b`` where it gives one that divides ``bh``
+    (a measured entry of the tune cache), else :func:`_pick_block_b`."""
+    if force_one or block_b is None:
+        return _pick_block_b(bh, force_one=force_one)
+    if bh % block_b:
+        raise ValueError(f"block_b {block_b} does not divide batch x heads {bh}")
+    return block_b
+
+
+def _online_softmax_step(s, v, m_scr, l_scr, acc_scr, bi):
+    """Fold one block's logits ``s`` of batch·head slice ``bi`` into the
+    running (max, sum, acc) statistics."""
     m_prev = m_scr[bi, :, 0:1]
     l_prev = l_scr[bi, :, 0:1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -76,13 +129,16 @@ def _online_softmax_step(s, v, o_ref, m_scr, l_scr, acc_scr, ki,
     )
     acc_scr[bi] = acc_scr[bi] * alpha + pv
 
-    @pl.when(ki == num_kv_blocks - 1)
-    def _finalize():
-        o_ref[bi] = (acc_scr[bi] / l_scr[bi, :, 0:1]).astype(o_ref.dtype)
-        if lse_ref is not None:
-            # Combined logsumexp, broadcast across the lane tile so the
-            # backward reads it with no relayout.
-            lse_ref[bi] = m_scr[bi] + jnp.log(l_scr[bi])
+
+def _write_output(o_ref, lse_ref, m_scr, l_scr, acc_scr, bi):
+    """After the last kv block: the normalized output of slice ``bi`` and,
+    when ``lse_ref`` is given, the per-row logsumexp the blocked backward
+    needs."""
+    o_ref[bi] = (acc_scr[bi] / l_scr[bi, :, 0:1]).astype(o_ref.dtype)
+    if lse_ref is not None:
+        # Combined logsumexp, broadcast across the lane tile so the
+        # backward reads it with no relayout.
+        lse_ref[bi] = m_scr[bi] + jnp.log(l_scr[bi])
 
 
 def _kernel(
@@ -95,8 +151,10 @@ def _kernel(
     scale: float,
     kv_len: int,
     block_b: int,
+    block_q: int,
     block_kv: int,
     num_kv_blocks: int,
+    causal: bool,
 ):
     """Online-softmax flash kernel;
     ``rest`` = ([bias_ref], o_ref, [lse_ref], m, l, acc).
@@ -120,21 +178,34 @@ def _kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    for bi in range(block_b):
-        q = q_ref[bi]  # [block_q, d]
-        k = k_ref[bi]  # [block_kv, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        s = s * scale
-        if has_bias:
-            s = s + bias_ref[bi].astype(jnp.float32)
-        if kv_len % block_kv != 0:
-            col = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(col < kv_len, s, _NEG_INF)
+    qi = pl.program_id(1)
 
-        _online_softmax_step(s, v_ref[bi], o_ref, m_scr, l_scr, acc_scr, ki,
-                             num_kv_blocks, bi, lse_ref=lse_ref)
+    def fold(masked: bool):
+        for bi in range(block_b):
+            q = q_ref[bi]  # [block_q, d]
+            k = k_ref[bi]  # [block_kv, d]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            s = s * scale
+            if has_bias:
+                s = s + bias_ref[bi].astype(jnp.float32)
+            if kv_len % block_kv != 0:
+                col = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = jnp.where(col < kv_len, s, _NEG_INF)
+            if masked:
+                # Block 0 comes first and shows every row its column 0, so
+                # the running max is finite before a row meets a block that
+                # hides all of its columns.
+                s = jnp.where(_causal_keep(qi, ki, block_q, block_kv), s, _NEG_INF)
+            _online_softmax_step(s, v_ref[bi], m_scr, l_scr, acc_scr, bi)
+
+    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
+
+    @pl.when(ki == num_kv_blocks - 1)
+    def _finalize():
+        for bi in range(block_b):
+            _write_output(o_ref, lse_ref, m_scr, l_scr, acc_scr, bi)
 
 
 def _flash_forward(
@@ -147,6 +218,9 @@ def _flash_forward(
     block_kv: int,
     interpret: Optional[bool],
     with_lse: bool = False,
+    *,
+    causal: bool = False,
+    block_b: Optional[int] = None,
 ):
     """Run the kernel. Layout in/out: ``[B, L, H, D]``.
 
@@ -186,15 +260,23 @@ def _flash_forward(
             bb, bh = batch, heads
         shared_bias = bb * bh == 1
 
-    block_b = _pick_block_b(batch * heads, force_one=shared_bias)
+    if causal and q_len != kv_len:
+        raise ValueError(f"causal attention is self-attention: q_len {q_len} != kv_len {kv_len}")
+    block_b = _resolve_block_b(block_b, batch * heads, force_one=shared_bias)
     num_q_blocks = q_len_p // block_q
     num_kv_blocks = kv_len_p // block_kv
     grid = (batch * heads // block_b, num_q_blocks, num_kv_blocks)
 
+    # A causal cell above the diagonal names the block the cell before it
+    # held: Pallas fetches a block only when its index changes.
+    if causal:
+        kv_index = lambda b, i, j: (b, jnp.minimum(j, _last_kv_block(i, block_q, block_kv)), 0)
+    else:
+        kv_index = lambda b, i, j: (b, j, 0)
     in_specs = [
         pl.BlockSpec((block_b, block_q, dim_p), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((block_b, block_kv, dim_p), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((block_b, block_kv, dim_p), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((block_b, block_kv, dim_p), kv_index),
+        pl.BlockSpec((block_b, block_kv, dim_p), kv_index),
     ]
     args = [qf, kf, vf]
     if bias is not None:
@@ -216,8 +298,10 @@ def _flash_forward(
         scale=scale,
         kv_len=kv_len,
         block_b=block_b,
+        block_q=block_q,
         block_kv=block_kv,
         num_kv_blocks=num_kv_blocks,
+        causal=causal,
     )
 
     out_specs = [
@@ -362,42 +446,47 @@ def _bwd_prep(q, k, v, out, g, block_q, block_kv) -> _BwdGeom:
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_acc, *, scale: float, q_len: int, kv_len: int,
                    block_b: int, block_q: int, block_kv: int,
-                   num_kv_blocks: int):
-    ki = pl.program_id(2)
+                   num_kv_blocks: int, causal: bool):
+    qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    for bi in range(block_b):
-        q, k, v, do = q_ref[bi], k_ref[bi], v_ref[bi], do_ref[bi]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        p = jnp.exp(s - _lanes(lse_ref[bi], s.shape[1]))
-        if kv_len % block_kv != 0:
-            col = ki * block_kv + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1
+    def fold(masked: bool):
+        for bi in range(block_b):
+            q, k, v, do = q_ref[bi], k_ref[bi], v_ref[bi], do_ref[bi]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale
+            p = jnp.exp(s - _lanes(lse_ref[bi], s.shape[1]))
+            if kv_len % block_kv != 0:
+                col = ki * block_kv + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1
+                )
+                p = jnp.where(col < kv_len, p, 0.0)
+            if q_len % block_q != 0:
+                # Padded (zero) q rows carry a finite lse ≈ log(kv_len), so p
+                # is finite garbage, not NaN; their dq rows are sliced off
+                # outside. Zero them anyway so the padded rows cost nothing
+                # downstream and the invariant "p == 0 outside the real
+                # block" holds in both backward kernels.
+                row = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 0
+                )
+                p = jnp.where(row < q_len, p, 0.0)
+            if masked:
+                p = jnp.where(_causal_keep(qi, ki, block_q, block_kv), p, 0.0)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             )
-            p = jnp.where(col < kv_len, p, 0.0)
-        if q_len % block_q != 0:
-            # Padded (zero) q rows carry a finite lse ≈ log(kv_len), so p is
-            # finite garbage, not NaN; their dq rows are sliced off outside.
-            # Zero them anyway so the padded rows cost nothing downstream and
-            # the invariant "p == 0 outside the real block" holds in both
-            # backward kernels.
-            row = pl.program_id(1) * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0
-            )
-            p = jnp.where(row < q_len, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - _lanes(delta_ref[bi], s.shape[1]))
-        dq_acc[bi] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+            ds = p * (dp - _lanes(delta_ref[bi], s.shape[1]))
+            dq_acc[bi] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+
+    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
 
     @pl.when(ki == num_kv_blocks - 1)
     def _write():
@@ -406,38 +495,44 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                     dv_ref, dk_acc, dv_acc, *, scale: float, q_len: int,
-                    block_b: int, block_q: int, num_q_blocks: int):
-    qi = pl.program_id(2)
+                    block_b: int, block_q: int, block_kv: int,
+                    num_q_blocks: int, causal: bool):
+    ki, qi = pl.program_id(1), pl.program_id(2)
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    for bi in range(block_b):
-        q, k, v, do = q_ref[bi], k_ref[bi], v_ref[bi], do_ref[bi]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [block_q, block_kv]
-        p = jnp.exp(s - _lanes(lse_ref[bi], s.shape[1]))
-        if q_len % block_q != 0:
-            # Padded q rows must not contribute to the dk/dv sums.
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0
+    def fold(masked: bool):
+        for bi in range(block_b):
+            q, k, v, do = q_ref[bi], k_ref[bi], v_ref[bi], do_ref[bi]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale  # [block_q, block_kv]
+            p = jnp.exp(s - _lanes(lse_ref[bi], s.shape[1]))
+            if q_len % block_q != 0:
+                # Padded q rows must not contribute to the dk/dv sums.
+                row = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 0
+                )
+                p = jnp.where(row < q_len, p, 0.0)
+            if masked:
+                p = jnp.where(_causal_keep(qi, ki, block_q, block_kv), p, 0.0)
+            dv_acc[bi] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            p = jnp.where(row < q_len, p, 0.0)
-        dv_acc[bi] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - _lanes(delta_ref[bi], s.shape[1]))
-        dk_acc[bi] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            ds = p * (dp - _lanes(delta_ref[bi], s.shape[1]))
+            dk_acc[bi] += jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+
+    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
 
     @pl.when(qi == num_q_blocks - 1)
     def _write():
@@ -446,7 +541,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
 
 def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
-                           interpret):
+                           interpret, *, causal: bool = False,
+                           block_b: Optional[int] = None):
     """Blocked backward; q/k/v/out/g are ``[B, L, H, D]``, lse is the padded
     ``[B·H, q_len_p, 128]`` forward residual."""
     if interpret is None:
@@ -461,10 +557,18 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
     num_q_blocks = q_len_p // block_q
     num_kv_blocks = kv_len_p // block_kv
     bh = geom.batch * geom.heads
-    block_b = _pick_block_b(bh)
+    block_b = _resolve_block_b(block_b, bh)
 
+    # Under the causal mask a skipped cell names the block its neighbour
+    # held, so nothing is fetched for it (see _flash_forward).
+    if causal:
+        kv_index = lambda b, i, j: (b, jnp.minimum(j, _last_kv_block(i, block_q, block_kv)), 0)
+        q_index2 = lambda b, j, i: (b, jnp.maximum(i, _first_q_block(j, block_q, block_kv)), 0)
+    else:
+        kv_index = lambda b, i, j: (b, j, 0)
+        q_index2 = lambda b, j, i: (b, i, 0)
     qspec = pl.BlockSpec((block_b, block_q, dim_p), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((block_b, block_kv, dim_p), lambda b, i, j: (b, j, 0))
+    kspec = pl.BlockSpec((block_b, block_kv, dim_p), kv_index)
     rowq = pl.BlockSpec((block_b, block_q, 128), lambda b, i, j: (b, i, 0))
 
     dq = pl.pallas_call(
@@ -477,6 +581,7 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
             block_q=block_q,
             block_kv=block_kv,
             num_kv_blocks=num_kv_blocks,
+            causal=causal,
         ),
         grid=(bh // block_b, num_q_blocks, num_kv_blocks),
         in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
@@ -488,9 +593,9 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
 
     # q-innermost grid for dk/dv: block index 1 is the kv block, index 2
     # sweeps q blocks into the accumulators.
-    qspec2 = pl.BlockSpec((block_b, block_q, dim_p), lambda b, j, i: (b, i, 0))
+    qspec2 = pl.BlockSpec((block_b, block_q, dim_p), q_index2)
     kspec2 = pl.BlockSpec((block_b, block_kv, dim_p), lambda b, j, i: (b, j, 0))
-    rowq2 = pl.BlockSpec((block_b, block_q, 128), lambda b, j, i: (b, i, 0))
+    rowq2 = pl.BlockSpec((block_b, block_q, 128), q_index2)
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel,
@@ -498,7 +603,9 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
             q_len=q_len,
             block_b=block_b,
             block_q=block_q,
+            block_kv=block_kv,
             num_q_blocks=num_q_blocks,
+            causal=causal,
         ),
         grid=(bh // block_b, num_kv_blocks, num_q_blocks),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2],
@@ -517,36 +624,44 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
     return geom.unprep(dq, q_len), geom.unprep(dk, kv_len), geom.unprep(dv, kv_len)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, bias, scale, block_q, block_kv, interpret):
-    return _flash_forward(q, k, v, bias, scale, block_q, block_kv, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b):
+    return _flash_forward(
+        q, k, v, bias, scale, block_q, block_kv, interpret,
+        causal=causal, block_b=block_b,
+    )
 
 
-def _flash_fwd(q, k, v, bias, scale, block_q, block_kv, interpret):
+def _flash_fwd(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b):
     if bias is None:
         out, lse = _flash_forward(
-            q, k, v, bias, scale, block_q, block_kv, interpret, with_lse=True
+            q, k, v, bias, scale, block_q, block_kv, interpret, with_lse=True,
+            causal=causal, block_b=block_b,
         )
         return out, (q, k, v, bias, out, lse)
-    out = _flash_forward(q, k, v, bias, scale, block_q, block_kv, interpret)
+    out = _flash_forward(
+        q, k, v, bias, scale, block_q, block_kv, interpret,
+        causal=causal, block_b=block_b,
+    )
     return out, (q, k, v, bias, None, None)
 
 
-def _flash_bwd(scale, block_q, block_kv, interpret, residuals, g):
+def _flash_bwd(scale, block_q, block_kv, interpret, causal, block_b, residuals, g):
     """Backward dispatch: blocked Pallas kernels when there is no bias;
     XLA flash-style recompute when a dbias is needed (the dense ``ds`` is
     unavoidable for the bias gradient)."""
     q, k, v, bias, out, lse = residuals
     if bias is None:
         dq, dk, dv = _flash_backward_pallas(
-            q, k, v, out, lse, g, scale, block_q, block_kv, interpret
+            q, k, v, out, lse, g, scale, block_q, block_kv, interpret,
+            causal=causal, block_b=block_b,
         )
         return dq, dk, dv, None
-    del block_q, block_kv, interpret
-    return _dense_recompute_bwd(q, k, v, bias, g, scale)
+    del block_q, block_kv, interpret, block_b
+    return _dense_recompute_bwd(q, k, v, bias, g, scale, causal=causal)
 
 
-def _dense_recompute_bwd(q, k, v, bias, g, scale):
+def _dense_recompute_bwd(q, k, v, bias, g, scale, *, causal: bool = False):
     """XLA flash-style recompute backward for the biased path — shared by
     this kernel and the fused short-sequence kernel
     (:mod:`sav_tpu.ops.fused_attention`): a dense dbias is O(L²) by
@@ -556,6 +671,8 @@ def _dense_recompute_bwd(q, k, v, bias, g, scale):
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias.astype(jnp.float32)
+    if causal:
+        s = jnp.where(_causal_keep(0, 0, *s.shape[-2:]), s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)  # [B, H, Lq, Lk] fp32
     p_mm = p.astype(mm_dtype)
     g_mm = g.astype(mm_dtype)
@@ -599,6 +716,8 @@ def flash_attention(
     block_q: int = 256,
     block_kv: int = 256,
     interpret: Optional[bool] = None,
+    causal: bool = False,
+    block_b: Optional[int] = None,
 ) -> jax.Array:
     """Fused flash attention.
 
@@ -612,6 +731,12 @@ def flash_attention(
         Default 256: the v5e block sweep (now tools/attn_tune.py, PERF.md §5)
         measured 256/256 ~1.6x faster than 128/128 at model-zoo shapes.
       interpret: force Pallas interpreter mode; default = auto (on for non-TPU).
+      causal: position ``i`` attends to ``j <= i`` (``q_len == kv_len``);
+        blocks above the diagonal are skipped, forward and backward.
+      block_b: batch·head slices per grid cell; default by
+        :func:`_pick_block_b`. A long causal sequence wants 1: many kv
+        blocks a cell already amortise the grid's step, and VMEM holds
+        ``block_b`` tiles of everything.
 
     Returns:
       ``[B, q_len, heads, head_dim]`` in the query dtype.
@@ -622,7 +747,10 @@ def flash_attention(
         scale = query.shape[-1] ** -0.5
     if bias is not None and bias.ndim != 4:
         raise ValueError(f"bias must be 4-D broadcastable, got {bias.shape}")
-    return _flash(query, key, value, bias, float(scale), block_q, block_kv, interpret)
+    return _flash(
+        query, key, value, bias, float(scale), block_q, block_kv, interpret,
+        bool(causal), block_b,
+    )
 
 # ---------------------------------------------------------------------------
 # BoTNet 2-D relative-position flash attention (SURVEY.md §7 "hard parts"):
@@ -711,8 +839,11 @@ def _rel_kernel(
         kcol = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kcol < kv_len, s, _NEG_INF)
 
-    _online_softmax_step(s, v_ref[0], o_ref, m_scr, l_scr, acc_scr, ki,
-                         num_kv_blocks, 0, lse_ref=lse_ref)
+    _online_softmax_step(s, v_ref[0], m_scr, l_scr, acc_scr, 0)
+
+    @pl.when(ki == num_kv_blocks - 1)
+    def _finalize():
+        _write_output(o_ref, lse_ref, m_scr, l_scr, acc_scr, 0)
 
 
 def _rel_forward(q, k, v, rw_abs, rh_abs, height, width, scale, block_q,

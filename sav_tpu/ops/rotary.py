@@ -4,7 +4,12 @@ Working rebuild of the reference's broken rotary path
 (/root/reference/models/layers/position_embed.py:8-45 — undefined ``self.dim``,
 malformed ``10e4 ** intervals / dim`` frequency formula; SURVEY.md §2.9 #12).
 Frequencies here follow the standard RoPE formulation
-``inv_freq_i = 10000 ** (-2i / dim)``.
+``inv_freq_i = base ** (-2i / dim)`` (``base`` 10,000 unless given).
+
+Two pairings of the lanes: the vision zoo's :func:`rotate_every_two` pairs
+lane ``2i`` with ``2i + 1``; the decoder family's :func:`rotate_half` pairs
+lane ``i`` with ``i + dim/2`` (the layout of the public language-model
+checkpoints), with tables from :func:`half_split_tables`.
 """
 
 from __future__ import annotations
@@ -13,18 +18,22 @@ import jax
 import jax.numpy as jnp
 
 
+def _angles(seq_len: int, dim: int, base) -> jax.Array:
+    """``[seq_len, dim/2]`` float32: position times ``base ** (-2i / dim)``."""
+    if dim % 2 != 0:
+        raise ValueError(f"rotary dim must be even, got {dim}")
+    inv_freq = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    t = jnp.arange(seq_len, dtype=jnp.float32)
+    return jnp.einsum("i,j->ij", t, inv_freq)
+
+
 def fixed_positional_embedding(seq_len: int, dim: int, dtype=jnp.float32):
     """Sinusoidal (sin, cos) tables of shape ``[seq_len, dim]`` each.
 
     Each frequency is repeated twice along the feature axis so the tables
     align with :func:`rotate_every_two` pairing.
     """
-    if dim % 2 != 0:
-        raise ValueError(f"rotary dim must be even, got {dim}")
-    inv_freq = 1.0 / (10000 ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
-    t = jnp.arange(seq_len, dtype=jnp.float32)
-    freqs = jnp.einsum("i,j->ij", t, inv_freq)  # [L, dim/2]
-    freqs = jnp.repeat(freqs, 2, axis=-1)  # [L, dim]
+    freqs = jnp.repeat(_angles(seq_len, dim, 10000), 2, axis=-1)  # [L, dim]
     return jnp.sin(freqs).astype(dtype), jnp.cos(freqs).astype(dtype)
 
 
@@ -49,3 +58,30 @@ def apply_rotary_pos_emb(x: jax.Array, sincos) -> jax.Array:
     sin = sin.astype(x.dtype)
     cos = cos.astype(x.dtype)
     return x * cos + rotate_every_two(x) * sin
+
+
+def half_split_tables(seq_len: int, dim: int, base: float = 10000.0):
+    """Float32 ``(sin, cos)`` tables ``[seq_len, dim]`` for :func:`rotate_half`
+    pairing: frequency ``i`` sits at lanes ``i`` and ``i + dim/2``."""
+    freqs = _angles(seq_len, dim, base)
+    freqs = jnp.concatenate([freqs, freqs], axis=-1)  # [L, dim]
+    return jnp.sin(freqs), jnp.cos(freqs)
+
+
+def rotate_half(x: jax.Array) -> jax.Array:
+    """``(a, b) -> (-b, a)`` for the two halves of the last axis."""
+    a, b = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([-b, a], axis=-1)
+
+
+def apply_rotary_half(x: jax.Array, sincos) -> jax.Array:
+    """RoPE with the rotate-halves pairing on ``x: [B, seq_len, heads, dim]``.
+
+    The rotation runs in float32 and is cast back: at position 4,095 a bf16
+    cosine is off by 2**-9 of a turn's amplitude, which the float32
+    reference would see on every logit.
+    """
+    sin, cos = sincos
+    sin, cos = sin[None, :, None, :], cos[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    return (x32 * cos + rotate_half(x32) * sin).astype(x.dtype)

@@ -1,0 +1,262 @@
+"""What a train step feeds its model and scores it by.
+
+``Trainer`` owns the loop, the gradient, the optimizer, the sharding and
+the spans; a task owns the part of the step that faces the model: the
+dummy input ``init`` traces with, what becomes of a batch before the model
+sees it, the loss, and the metrics the log boundary fetches. A model's
+registry entry names its task (``sav_tpu.models.registry.model_task``):
+
+- ``image``: uint8 or float images ``[B, S, S, 3]`` with integer labels,
+  label-smoothed (and mixed) cross-entropy, top-1 / top-5;
+- ``tokens``: int32 ids ``[B, S + 1]``, next-token loss at every position
+  of a looped language model (:func:`looped_lm_loss`).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import optax
+
+from sav_tpu.utils.metrics import cross_entropy, topk_correct
+
+
+class ImageClassification:
+    """Batches ``{"images", "labels"[, "mix_labels", "ratio"]}``; the model
+    returns logits ``[B, classes]``."""
+
+    def __init__(self, config, compute_dtype):
+        self.config = config
+        self.compute_dtype = compute_dtype
+        if config.device_preprocess:
+            # Host ships post-augment uint8; normalize + the augment
+            # string's mixes run inside the jitted steps
+            # (sav_tpu/ops/preprocess.py). Parsed once — the spec is
+            # static, baked into the trace.
+            from sav_tpu.data.augment_spec import parse_augment_spec
+
+            self._mix_spec = parse_augment_spec(config.augment)
+        else:
+            self._mix_spec = None
+
+    def dummy_input(self, batch: int) -> jax.Array:
+        s = self.config.image_size
+        return jnp.zeros((batch, s, s, 3), self.compute_dtype)
+
+    def rows(self, batch: dict) -> int:
+        return len(batch["labels"])
+
+    def batch_dim(self, key: str, ndim: int) -> int:
+        """The axis that counts examples in the batch's leaf ``key`` of
+        ``ndim`` axes: the last for HWCN images."""
+        transposed = key == "images" and self.config.transpose_images
+        return ndim - 1 if transposed and ndim >= 4 else 0
+
+    def _prep_images(self, images: jax.Array) -> jax.Array:
+        if images.dtype == jnp.uint8:
+            # uint8 batches belong to device_preprocess=True (which
+            # normalizes on device); a plain astype here would silently
+            # train on unnormalized 0..255 values (ADVICE r3). Trace-time
+            # check — dtypes are static under jit.
+            raise ValueError(
+                "got uint8 images with device_preprocess=False; either set "
+                "TrainConfig.device_preprocess=True or feed normalized "
+                "float batches (load(device_preprocess=...) must match the "
+                "trainer)"
+            )
+        if self.config.transpose_images and images.ndim == 4:
+            # HWCN → NHWC (the reference's double-transpose trick lands the
+            # device-side transpose here, train.py:80).
+            images = jnp.transpose(images, (3, 0, 1, 2))
+        return images.astype(self.compute_dtype)
+
+    def _label_probs(self, batch: dict) -> jax.Array:
+        labels = batch["labels"]
+        onehot = jax.nn.one_hot(labels, self.config.num_classes, dtype=jnp.float32)
+        if "mix_labels" in batch:
+            ratio = batch["ratio"].astype(jnp.float32)[:, None]
+            mix = jax.nn.one_hot(
+                batch["mix_labels"], self.config.num_classes, dtype=jnp.float32
+            )
+            onehot = ratio * onehot + (1.0 - ratio) * mix
+        if self.config.label_smoothing > 0.0:
+            onehot = optax.smooth_labels(onehot, self.config.label_smoothing)
+        return onehot
+
+    def _device_preprocess(self, batch: dict, rng, training: bool) -> dict:
+        """uint8 host batch → mixed (train) + normalized compute-dtype
+        images, on device (TrainConfig.device_preprocess; see
+        sav_tpu/ops/preprocess.py for the host-parity contract)."""
+        from sav_tpu.ops import preprocess as pp
+
+        images = batch["images"]
+        if images.dtype != jnp.uint8:
+            # The device_preprocess contract ships post-augment 0..255
+            # uint8 (load(device_preprocess=True) / savrec
+            # normalize=False); an already-normalized float batch here
+            # would be normalized twice — silently wrong training
+            # (ADVICE r3). Trace-time check: dtypes are static under jit.
+            raise ValueError(
+                "device_preprocess=True expects uint8 batches from the "
+                f"matching pipeline mode, got {images.dtype}; feed "
+                "load(device_preprocess=True) / "
+                "savrec_train_iterator(normalize=False) batches, or turn "
+                "device_preprocess off"
+            )
+        if self.config.transpose_images and images.ndim == 4:
+            images = jnp.transpose(images, (3, 0, 1, 2))  # HWCN → NHWC
+        batch = dict(batch)
+        if training and self._mix_spec is not None and self._mix_spec.mixes:
+            images, mix_labels, ratio = pp.apply_mixes(
+                rng, images, batch["labels"], self._mix_spec
+            )
+            if mix_labels is not None:
+                batch["mix_labels"] = mix_labels
+                batch["ratio"] = ratio
+        batch["images"] = pp.normalize_images(images, self.compute_dtype)
+        return batch
+
+    def prepare(self, batch: dict, step_rng, training: bool):
+        """``(inputs, targets)`` for the model and the loss: NHWC images in
+        the compute dtype and, in training, the label distributions. Both
+        split along axis 0 under gradient accumulation. ``step_rng`` is
+        None in eval."""
+        if self.config.device_preprocess:
+            # Dedicated fold so the mix draws are independent of the
+            # dropout/stochastic-depth streams split from step_rng.
+            rng = jax.random.fold_in(step_rng, 0x6D69) if training else None
+            batch = self._device_preprocess(batch, rng, training=training)
+            images = batch["images"]  # already NHWC, compute dtype
+        else:
+            images = self._prep_images(batch["images"])
+        return images, (self._label_probs(batch) if training else None)
+
+    def apply_kwargs(self, targets) -> dict:
+        return {}
+
+    def loss(self, logits, label_probs) -> jax.Array:
+        return cross_entropy(logits, label_probs)
+
+    def train_metrics(self, logits, batch: dict) -> dict:
+        acc = topk_correct(logits.astype(jnp.float32), batch["labels"])
+        return {
+            "top_1_acc": jnp.mean(acc["top_1_acc"]),
+            "top_5_acc": jnp.mean(acc["top_5_acc"]),
+        }
+
+    def eval_sums(self, logits, batch: dict) -> dict:
+        logits = logits.astype(jnp.float32)
+        labels = batch["labels"]
+        onehot = jax.nn.one_hot(labels, self.config.num_classes, dtype=jnp.float32)
+        n = labels.shape[0]
+        # 'valid' marks real rows in a padded final batch (evaluate() pads
+        # remainders so every batch has one static, mesh-divisible shape).
+        valid = batch.get("valid")
+        if valid is None:
+            valid = jnp.ones((n,), jnp.float32)
+        acc = topk_correct(logits, labels)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        per_example_loss = -jnp.sum(onehot * logp, axis=-1)
+        return {
+            "loss_sum": jnp.sum(per_example_loss * valid),
+            "top_1_sum": jnp.sum(acc["top_1_acc"] * valid),
+            "top_5_sum": jnp.sum(acc["top_5_acc"] * valid),
+            "count": jnp.sum(valid),
+        }
+
+
+def exit_distribution(exit_logit: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(p, log p)`` over the passes (last axis) from the gates' logits:
+    ``p_t = lambda_t prod_{j<t}(1 - lambda_j)`` for ``t < T`` and ``p_T =
+    prod_{j<T}(1 - lambda_j)``, ``lambda = sigmoid(logit)``; the last pass's
+    gate is not used. Sums to 1 by construction; computed in log space."""
+    stay = jax.nn.log_sigmoid(-exit_logit)  # log(1 - lambda_t)
+    stayed = jnp.cumsum(stay, axis=-1) - stay  # sum over j < t
+    log_p = jnp.concatenate(
+        [jax.nn.log_sigmoid(exit_logit[..., :-1]) + stayed[..., :-1], stayed[..., -1:]],
+        axis=-1,
+    )
+    return jnp.exp(log_p), log_p
+
+
+def looped_lm_loss(ce: jax.Array, exit_logit: jax.Array, beta: float):
+    """The entropy-regularised objective of arXiv:2510.25741: the mean over
+    positions of ``sum_t p_t CE_t - beta H(p)``. ``ce`` and ``exit_logit``
+    are ``[..., T]``. Returns ``(loss, p, entropy per position)``."""
+    p, log_p = exit_distribution(exit_logit)
+    entropy = -jnp.sum(p * log_p, axis=-1)
+    per_position = jnp.sum(p * ce, axis=-1) - beta * entropy
+    return jnp.mean(per_position), p, entropy
+
+
+class LoopedTokenPrediction:
+    """Batches ``{"tokens": int32 [B, S + 1]}``: the model reads the first
+    ``S`` ids and predicts ids 1..S, every pass of its loop at every
+    position. The model (``sav_tpu/models/ouro.py``) returns each pass's
+    cross-entropy and exit-gate logit; the loss is :func:`looped_lm_loss`.
+    Documents are concatenated without a boundary mask."""
+
+    # H(p)'s weight (arXiv:2510.25741's pre-training objective; the value is
+    # assumed: benchmark/configs/ouro_2.6b.json).
+    entropy_weight = 0.1
+    # Any length traces the same parameters: the family has no position table.
+    dummy_length = 16
+
+    def __init__(self, config, compute_dtype):
+        del compute_dtype
+        if config.label_smoothing or config.device_preprocess:
+            raise ValueError(
+                "the token task has no label smoothing and no image "
+                "preprocessing: set label_smoothing=0, device_preprocess=False"
+            )
+        self.config = config
+
+    def dummy_input(self, batch: int) -> jax.Array:
+        return jnp.zeros((batch, self.dummy_length), jnp.int32)
+
+    def rows(self, batch: dict) -> int:
+        return len(batch["tokens"])
+
+    def batch_dim(self, key: str, ndim: int) -> int:
+        return 0
+
+    def prepare(self, batch: dict, step_rng, training: bool):
+        del step_rng, training
+        tokens = batch["tokens"]
+        return tokens[:, :-1], tokens[:, 1:]
+
+    def apply_kwargs(self, targets) -> dict:
+        return {"targets": targets}
+
+    def loss(self, outputs: dict, targets) -> jax.Array:
+        del targets  # the model has already scored every position
+        return looped_lm_loss(outputs["ce"], outputs["exit_logit"], self.entropy_weight)[0]
+
+    def train_metrics(self, outputs: dict, batch: dict) -> dict:
+        _, p, entropy = looped_lm_loss(
+            outputs["ce"], outputs["exit_logit"], self.entropy_weight
+        )
+        passes = outputs["ce"].shape[-1]
+        metrics = {"exit_entropy": jnp.mean(entropy), "tokens": jnp.float32(p[..., 0].size)}
+        for t in range(passes):
+            metrics[f"loss_ut{t + 1}"] = jnp.mean(outputs["ce"][..., t])
+            metrics[f"exit_p{t + 1}"] = jnp.mean(p[..., t])
+        return metrics
+
+    def eval_sums(self, outputs: dict, batch: dict) -> dict:
+        _, p, _ = looped_lm_loss(outputs["ce"], outputs["exit_logit"], self.entropy_weight)
+        per_position = jnp.sum(p * outputs["ce"], axis=-1)
+        valid = batch.get("valid")
+        if valid is None:
+            valid = jnp.ones((per_position.shape[0],), jnp.float32)
+        weights = jnp.broadcast_to(valid[:, None], per_position.shape)
+        return {"loss_sum": jnp.sum(per_position * weights), "count": jnp.sum(weights)}
+
+
+TASKS = {"image": ImageClassification, "tokens": LoopedTokenPrediction}
+
+
+def make_task(name: str, config, compute_dtype):
+    if name not in TASKS:
+        raise ValueError(f"unknown task {name!r}; available: {', '.join(sorted(TASKS))}")
+    return TASKS[name](config, compute_dtype)

@@ -31,6 +31,7 @@ import numpy as np
 import optax
 
 from sav_tpu.models import create_model
+from sav_tpu.models.registry import model_task
 from sav_tpu.obs.diagnostics import diagnostics_metrics
 from sav_tpu.obs.goodput import GoodputLedger
 from sav_tpu.obs.memory import RetraceCounter, hbm_stats
@@ -51,9 +52,9 @@ from sav_tpu.train.optimizer import (
     warmup_cosine_schedule,
 )
 from sav_tpu.train.state import TrainState
+from sav_tpu.train.tasks import make_task
 from sav_tpu.utils import profiler
 from sav_tpu.utils.debug import assert_all_finite
-from sav_tpu.utils.metrics import cross_entropy, topk_correct
 
 
 def _cost_note(cost, peak_flops, peak_source) -> dict:
@@ -132,16 +133,14 @@ class Trainer:
         self.compute_dtype = (
             jnp.bfloat16 if config.compute_dtype == "bfloat16" else jnp.float32
         )
-        if config.device_preprocess:
-            # Host ships post-augment uint8; normalize + the augment
-            # string's mixes run inside the jitted steps
-            # (sav_tpu/ops/preprocess.py). Parsed once — the spec is
-            # static, baked into the trace.
-            from sav_tpu.data.augment_spec import parse_augment_spec
-
-            self._mix_spec = parse_augment_spec(config.augment)
-        else:
-            self._mix_spec = None
+        # The model-facing part of the step (sav_tpu/train/tasks.py): the
+        # registry entry names it; an externally built model may carry a
+        # ``task`` attribute, else it classifies images.
+        self.task = make_task(
+            model_task(config.model_name) if model is None
+            else getattr(model, "task", "image"),
+            config, self.compute_dtype,
+        )
         # The softmax dtype is a *model attribute*, not process state:
         # attention blocks resolve ``logits_dtype or dtype`` themselves, so
         # two trainers with different settings coexist structurally (no
@@ -309,8 +308,7 @@ class Trainer:
 
     # ------------------------------------------------------------------ init
 
-    def _dummy_shape(self) -> tuple:
-        s = self.config.image_size
+    def _dummy_batch(self) -> int:
         # Batch sized to the mesh's batch-axes product: init traces the
         # model once, and under sequence parallelism a batch that does
         # not divide the data axes takes the replication fallback — the
@@ -319,11 +317,10 @@ class Trainer:
         # real training batch. Shape only: the zeros materialize inside
         # the jitted init_fn (traced, never a host buffer), so a 256-way
         # data axis does not cost a concrete global-batch-sized array.
-        b = max(
+        return max(
             2,
             int(np.prod([self.mesh.shape[a] for a in batch_axes(self.mesh)])),
         )
-        return (b, s, s, 3)
 
     @in_phase("trainer/init_state")
     def init_state(self, seed: Optional[int] = None) -> TrainState:
@@ -334,10 +331,10 @@ class Trainer:
         single host buffer.
         """
         rng = jax.random.PRNGKey(self.config.seed if seed is None else seed)
-        dummy_shape = self._dummy_shape()
+        dummy_batch = self._dummy_batch()
 
         def init_fn(rng):
-            dummy = jnp.zeros(dummy_shape, self.compute_dtype)
+            dummy = self.task.dummy_input(dummy_batch)
             variables = self.model.init({"params": rng}, dummy, is_training=False)
             variables = dict(variables)
             params = variables.pop("params")
@@ -556,90 +553,18 @@ class Trainer:
 
     # ----------------------------------------------------------------- steps
 
-    def _prep_images(self, images: jax.Array) -> jax.Array:
-        if images.dtype == jnp.uint8:
-            # uint8 batches belong to device_preprocess=True (which
-            # normalizes on device); a plain astype here would silently
-            # train on unnormalized 0..255 values (ADVICE r3). Trace-time
-            # check — dtypes are static under jit.
-            raise ValueError(
-                "got uint8 images with device_preprocess=False; either set "
-                "TrainConfig.device_preprocess=True or feed normalized "
-                "float batches (load(device_preprocess=...) must match the "
-                "trainer)"
-            )
-        if self.config.transpose_images and images.ndim == 4:
-            # HWCN → NHWC (the reference's double-transpose trick lands the
-            # device-side transpose here, train.py:80).
-            images = jnp.transpose(images, (3, 0, 1, 2))
-        return images.astype(self.compute_dtype)
-
-    def _label_probs(self, batch: dict) -> jax.Array:
-        labels = batch["labels"]
-        onehot = jax.nn.one_hot(labels, self.config.num_classes, dtype=jnp.float32)
-        if "mix_labels" in batch:
-            ratio = batch["ratio"].astype(jnp.float32)[:, None]
-            mix = jax.nn.one_hot(
-                batch["mix_labels"], self.config.num_classes, dtype=jnp.float32
-            )
-            onehot = ratio * onehot + (1.0 - ratio) * mix
-        if self.config.label_smoothing > 0.0:
-            onehot = optax.smooth_labels(onehot, self.config.label_smoothing)
-        return onehot
-
-    def _device_preprocess(self, batch: dict, rng, training: bool) -> dict:
-        """uint8 host batch → mixed (train) + normalized compute-dtype
-        images, on device (TrainConfig.device_preprocess; see
-        sav_tpu/ops/preprocess.py for the host-parity contract)."""
-        from sav_tpu.ops import preprocess as pp
-
-        images = batch["images"]
-        if images.dtype != jnp.uint8:
-            # The device_preprocess contract ships post-augment 0..255
-            # uint8 (load(device_preprocess=True) / savrec
-            # normalize=False); an already-normalized float batch here
-            # would be normalized twice — silently wrong training
-            # (ADVICE r3). Trace-time check: dtypes are static under jit.
-            raise ValueError(
-                "device_preprocess=True expects uint8 batches from the "
-                f"matching pipeline mode, got {images.dtype}; feed "
-                "load(device_preprocess=True) / "
-                "savrec_train_iterator(normalize=False) batches, or turn "
-                "device_preprocess off"
-            )
-        if self.config.transpose_images and images.ndim == 4:
-            images = jnp.transpose(images, (3, 0, 1, 2))  # HWCN → NHWC
-        batch = dict(batch)
-        if training and self._mix_spec is not None and self._mix_spec.mixes:
-            images, mix_labels, ratio = pp.apply_mixes(
-                rng, images, batch["labels"], self._mix_spec
-            )
-            if mix_labels is not None:
-                batch["mix_labels"] = mix_labels
-                batch["ratio"] = ratio
-        batch["images"] = pp.normalize_images(images, self.compute_dtype)
-        return batch
-
     def _train_step_impl(self, state: TrainState, batch: dict, rng: jax.Array):
         # Four named scopes (preprocess, loss, optimizer, metrics) name the
         # device time that no flax module does; metadata only. None goes
         # around model.apply: the module paths must read as they are.
+        task = self.task
         step_rng = jax.random.fold_in(rng, state.step)
         with jax.named_scope("preprocess"):
-            if self.config.device_preprocess:
-                # Dedicated fold so the mix draws are independent of the
-                # dropout/stochastic-depth streams split from step_rng below.
-                batch = self._device_preprocess(
-                    batch, jax.random.fold_in(step_rng, 0x6D69), training=True
-                )
-                images = batch["images"]  # already NHWC, compute dtype
-            else:
-                images = self._prep_images(batch["images"])
-            label_probs = self._label_probs(batch)
+            inputs, targets = task.prepare(batch, step_rng, training=True)
         has_bn = bool(state.batch_stats)
 
         def loss_fn(
-            params, batch_stats, images, label_probs, dropout_rng, sd_rng,
+            params, batch_stats, inputs, targets, dropout_rng, sd_rng,
             quant_rng=None,
         ):
             variables = {"params": params}
@@ -658,12 +583,13 @@ class Trainer:
             # 'auto' attention resolves while the model is traced, and
             # promotes a Mosaic kernel only in a program of one device.
             with partitioned_over(self.mesh.size):
-                logits, new_vars = self.model.apply(
+                outputs, new_vars = self.model.apply(
                     variables,
-                    images,
+                    inputs,
                     is_training=True,
                     rngs=rngs,
                     mutable=mutable,
+                    **task.apply_kwargs(targets),
                 )
             new_batch_stats = new_vars["batch_stats"] if has_bn else batch_stats
             # Sown 'losses' are ready-to-sum penalties at their relative
@@ -677,10 +603,10 @@ class Trainer:
                 )
                 aux = jnp.asarray(aux, jnp.float32)
                 loss = (
-                    cross_entropy(logits, label_probs)
+                    task.loss(outputs, targets)
                     + self.config.aux_loss_weight * aux
                 )
-            return loss, (logits, new_batch_stats, aux)
+            return loss, (outputs, new_batch_stats, aux)
 
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
         accum = self.config.grad_accum_steps
@@ -696,8 +622,8 @@ class Trainer:
             else:
                 dropout_rng, sd_rng = jax.random.split(step_rng)
                 quant_rng = None
-            (loss, (logits, new_batch_stats, aux_loss)), grads = grad_fn(
-                state.params, state.batch_stats, images, label_probs,
+            (loss, (outputs, new_batch_stats, aux_loss)), grads = grad_fn(
+                state.params, state.batch_stats, inputs, targets,
                 dropout_rng, sd_rng, quant_rng,
             )
         else:
@@ -705,7 +631,7 @@ class Trainer:
             # grads/losses; one optimizer update. BatchNorm statistics
             # thread through the scan carry (each micro-batch sees the
             # previous micro-batch's running stats, like sequential steps).
-            b = images.shape[0]
+            b = inputs.shape[0]
             if b % accum:
                 raise ValueError(
                     f"batch size {b} not divisible by grad_accum_steps {accum}"
@@ -734,13 +660,15 @@ class Trainer:
                 state.batch_stats, zeros, jnp.float32(0.0), jnp.float32(0.0),
                 jnp.int32(0),
             )
-            (new_batch_stats, gsum, lsum, asum, _), logits_stack = jax.lax.scan(
-                micro, carry0, (split(images), split(label_probs))
+            (new_batch_stats, gsum, lsum, asum, _), outputs_stack = jax.lax.scan(
+                micro, carry0, jax.tree.map(split, (inputs, targets))
             )
             grads = jax.tree.map(lambda g: g / accum, gsum)
             loss = lsum / accum
             aux_loss = asum / accum
-            logits = logits_stack.reshape(b, *logits_stack.shape[2:])
+            outputs = jax.tree.map(
+                lambda x: x.reshape(b, *x.shape[2:]), outputs_stack
+            )
         with jax.named_scope("optimizer"):
             updates, new_opt_state = self.tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
@@ -751,11 +679,9 @@ class Trainer:
             batch_stats=new_batch_stats,
         )
         with jax.named_scope("metrics"):
-            acc = topk_correct(logits.astype(jnp.float32), batch["labels"])
             metrics = {
                 "loss": loss,
-                "top_1_acc": jnp.mean(acc["top_1_acc"]),
-                "top_5_acc": jnp.mean(acc["top_5_acc"]),
+                **task.train_metrics(outputs, batch),
                 "learning_rate": self.schedule(state.step),
                 "grad_norm": optax.global_norm(grads),
                 "aux_loss": aux_loss,
@@ -790,24 +716,20 @@ class Trainer:
         """Run ``K`` steps fused on-device; see ``_train_many_impl``."""
 
         def sharding_for(key, leaf):
-            # Leading [K, ...] steps axis shifts the batch dim to 1; the
-            # HWCN transpose puts it last. Specs come from the layout
+            # Leading [K, ...] steps axis shifts the batch dim by one (the
+            # HWCN transpose puts it last). Specs come from the layout
             # (batch_sharding(dim) — savlint SAV117 keeps ad-hoc
             # PartitionSpec construction out of this file).
-            if key == "images" and self.config.transpose_images and leaf.ndim == 5:
-                return self._blayout.batch_sharding(dim=4)
-            return self._blayout.batch_sharding(dim=1)
+            return self._blayout.batch_sharding(
+                dim=1 + self.task.batch_dim(key, leaf.ndim - 1)
+            )
 
         placed = {k: jax.device_put(v, sharding_for(k, v)) for k, v in batches.items()}
         return self._train_many(state, placed, rng)
 
     def _eval_step_impl(self, state: TrainState, batch: dict):
         with jax.named_scope("preprocess"):
-            if self.config.device_preprocess:
-                batch = self._device_preprocess(batch, None, training=False)
-                images = batch["images"]
-            else:
-                images = self._prep_images(batch["images"])
+            inputs, targets = self.task.prepare(batch, None, training=False)
         # Eval on the parameter EMA when configured (the DeiT/CaiT-recipe
         # standard: the averaged weights generalize better than the last
         # step's). The EMA tree lives in opt_state (optimizer.py
@@ -821,26 +743,12 @@ class Trainer:
         if state.batch_stats:
             variables["batch_stats"] = state.batch_stats
         with partitioned_over(self.mesh.size):
-            logits = self.model.apply(variables, images, is_training=False)
+            outputs = self.model.apply(
+                variables, inputs, is_training=False,
+                **self.task.apply_kwargs(targets),
+            )
         with jax.named_scope("metrics"):
-            logits = logits.astype(jnp.float32)
-            labels = batch["labels"]
-            onehot = jax.nn.one_hot(labels, self.config.num_classes, dtype=jnp.float32)
-            n = labels.shape[0]
-            # 'valid' marks real rows in a padded final batch (evaluate() pads
-            # remainders so every batch has one static, mesh-divisible shape).
-            valid = batch.get("valid")
-            if valid is None:
-                valid = jnp.ones((n,), jnp.float32)
-            acc = topk_correct(logits, labels)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            per_example_loss = -jnp.sum(onehot * logp, axis=-1)
-            return {
-                "loss_sum": jnp.sum(per_example_loss * valid),
-                "top_1_sum": jnp.sum(acc["top_1_acc"] * valid),
-                "top_5_sum": jnp.sum(acc["top_5_acc"] * valid),
-                "count": jnp.sum(valid),
-            }
+            return self.task.eval_sums(outputs, batch)
 
     # ------------------------------------------------------------- data flow
 
@@ -858,9 +766,9 @@ class Trainer:
         multiprocess = jax.process_count() > 1
 
         def sharding_for(key, leaf):
-            if key == "images" and self.config.transpose_images and leaf.ndim == 4:
-                return self._blayout.batch_sharding(dim=3)
-            return self._blayout.batch_sharding()
+            return self._blayout.batch_sharding(
+                dim=self.task.batch_dim(key, np.ndim(leaf))
+            )
 
         def place(key, leaf):
             sharding = sharding_for(key, leaf)
@@ -919,13 +827,12 @@ class Trainer:
         Keeps eval at one compiled shape and makes any eval size work on any
         mesh (the reference hard-errored on non-divisible eval batches,
         input_pipeline.py:150-152)."""
-        n = len(batch["labels"])
+        n = self.task.rows(batch)
         pad = target - n
-        transposed = self.config.transpose_images
 
         def pad_leaf(key, x):
             x = np.asarray(x)
-            axis = x.ndim - 1 if (key == "images" and transposed) else 0
+            axis = self.task.batch_dim(key, x.ndim)
             widths = [(0, 0)] * x.ndim
             widths[axis] = (0, pad)
             return np.pad(x, widths)
@@ -968,7 +875,7 @@ class Trainer:
             # single feeder worker processes batches in order, so the
             # first-batch shape fixing is race-free.
             nonlocal batch_size
-            n = len(batch["labels"])
+            n = self.task.rows(batch)
             if batch_size is None:
                 # First batch fixes the compiled shape: its size rounded up
                 # to a mesh-divisible multiple (so a tiny eval set shards).
@@ -1105,8 +1012,9 @@ class Trainer:
         """Run the training loop.
 
         Args:
-          train_iter: yields batches (dicts with 'images', 'labels', optional
-            'mix_labels'/'ratio').
+          train_iter: yields batches as the model's task reads them
+            (sav_tpu/train/tasks.py): dicts with 'images', 'labels' and
+            optional 'mix_labels'/'ratio', or with 'tokens'.
           num_steps: total steps (default: config.total_steps).
           eval_iter_fn: zero-arg callable returning a fresh eval iterator
             (fixes the reference's exhausted-generator eval bug,

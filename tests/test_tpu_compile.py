@@ -84,6 +84,10 @@ KERNEL_CASES = [
     ("flash-tnt_inner", "flash", (12544, 17, 4, 6)),
     ("fused-tnt_inner", "fused", (12544, 17, 4, 6)),
     ("relative_position-botnet_14x14", "botnet", (64, 196, 4, 128)),
+    # The looped language model's core: causal, head size 128, at the
+    # default blocks and at the tune cache's measured ones.
+    ("flash_causal-ouro_4k", "flash_causal", (2, 4096, 16, 128)),
+    ("flash_causal_tuned-ouro_4k", "flash_causal_tuned", (2, 4096, 16, 128)),
 ]
 
 
@@ -102,6 +106,17 @@ def _kernel_fn_and_args(kernel, shape, sharding):
     heads, dim = shape[2], shape[3]
     if kernel == "flash":
         return (lambda q, k, v: flash_attention(q, k, v, interpret=False)), qkv
+    if kernel == "flash_causal":
+        return (lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False)), qkv
+    if kernel == "flash_causal_tuned":
+        from sav_tpu.ops import attn_tuning
+
+        blocks = attn_tuning.block_config(
+            attn_tuning.lookup(*shape[:2], shape[1], *shape[2:], causal=True)
+        )
+        return (
+            lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False, **blocks)
+        ), qkv
     if kernel == "fused":
         return (lambda q, k, v: fused_attention(q, k, v, interpret=False)), qkv
     if kernel == "talking_heads":
@@ -236,6 +251,39 @@ def test_deit_s_train_step_compiles_and_fits_one_chip(
     assert _bytes_on_device(compiled) < HBM_BYTES
     has_kernel = "tpu_custom_call" in compiled.as_text()
     assert has_kernel == (backend is not None)
+
+
+def test_looped_lm_train_step_compiles_and_fits_one_chip(topo, compiled_kernels, monkeypatch):
+    """The token task's step at the benchmark cell's widths, length and
+    batch, one layer of its four (a quarter of the compile): every pass's
+    causal core is the flash kernel, forward, recomputed and backward."""
+    from sav_tpu.ops import attention
+    from sav_tpu.parallel import create_mesh
+    from sav_tpu.train import TrainConfig, Trainer
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    mesh = create_mesh({"data": 1}, devices=topo.devices[:1])
+    config = TrainConfig(
+        model_name="ouro_2_6b", num_classes=49152, compute_dtype="bfloat16",
+        global_batch_size=2, label_smoothing=0.0, transpose_images=False,
+        model_overrides={"num_layers": 1, "remat": True}, seed=0,
+    )
+    trainer = Trainer(config, mesh=mesh)
+    abstract = jax.eval_shape(trainer.init_state)
+    replicated = NamedSharding(mesh, P())
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated), abstract
+    )
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (2, 4097), jnp.int32, sharding=trainer._blayout.batch_sharding()
+    )}
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated)
+    compiled = trainer.compile_train_step(state, batch, rng)
+    assert _bytes_on_device(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    # 4 passes x (forward, recomputed forward, dq, dk/dv)
+    assert text.count('custom_call_target="tpu_custom_call"') == 16
+    assert " while(" not in text  # a loop's event would count its body twice in a trace
 
 
 def test_deit_s_sharded_train_step_compiles_for_four_chips(topo):

@@ -21,6 +21,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from sav_tpu.ops.attention import dot_product_attention
 from sav_tpu.ops.quant import (
@@ -284,6 +285,10 @@ class AttentionBlock(nn.Module):
                 sincos = fixed_positional_embedding(key.shape[1], head_ch)
             key = apply_rotary_pos_emb(key, sincos)
 
+        # Tags for a caller's remat policy (the identity without one): Q, K
+        # and V as the core receives them, and the merged output below.
+        query, key, value = (checkpoint_name(x, "attn_qkv") for x in (query, key, value))
+
         has_attn_dropout = self.attn_dropout_rate > 0.0 and is_training
         if self.seq_parallel:
             if self.talking_heads and self.seq_parallel != "ring":
@@ -428,6 +433,7 @@ class AttentionBlock(nn.Module):
             axis=(-2, -1),
             name="to_out",
         )(out)
+        out = checkpoint_name(out, "attn_out")
         out = nn.Dropout(rate=self.out_dropout_rate)(out, deterministic=not is_training)
         return out
 

@@ -13,6 +13,7 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from sav_tpu.models.layers.depthwise import DepthwiseConv2D
 from sav_tpu.ops.quant import QuantDense
@@ -69,15 +70,17 @@ class _GateUp(nn.Module):
     @nn.compact
     def __call__(self, inputs: jax.Array) -> jax.Array:
         dense = _bias_free_dense(self.quant, self.dtype)
-        gate = dense(self.hidden_ch, name="gate")(inputs)
-        up = dense(self.hidden_ch, name="up")(inputs)
+        gate = checkpoint_name(dense(self.hidden_ch, name="gate")(inputs), "ffn_gate")
+        up = checkpoint_name(dense(self.hidden_ch, name="up")(inputs), "ffn_up")
         return self.activation_fn(gate) * up
 
 
 class GatedFFBlock(nn.Module):
     """``W_down(act(W_gate x) * W_up x)``, no bias (SwiGLU with ``silu``).
     Scopes as :class:`FFBlock`'s: the input matmuls under ``fc1``, the down
-    projection ``fc2``."""
+    projection ``fc2``. The three matmuls' outputs carry ``checkpoint_name``
+    tags (``ffn_gate``, ``ffn_up``, ``ffn_out``) for a caller's remat policy;
+    without one they are the identity."""
 
     hidden_ch: int
     activation_fn: Callable = nn.silu
@@ -89,7 +92,8 @@ class GatedFFBlock(nn.Module):
         x = _GateUp(
             self.hidden_ch, self.activation_fn, self.quant, self.dtype, name="fc1"
         )(inputs)
-        return _bias_free_dense(self.quant, self.dtype)(inputs.shape[-1], name="fc2")(x)
+        out = _bias_free_dense(self.quant, self.dtype)(inputs.shape[-1], name="fc2")(x)
+        return checkpoint_name(out, "ffn_out")
 
 
 class LeFFBlock(nn.Module):

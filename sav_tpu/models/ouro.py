@@ -76,6 +76,18 @@ class DecoderBlock(nn.Module):
         return x + norm("mlp_norm_out")(m)
 
 
+# What the backward pass of a rematerialised layer application finds kept
+# (``jax.ad_checkpoint.checkpoint_name`` tags in the layers and in the flash
+# kernel's forward rule): the outputs of the projections and of the attention
+# kernel, whose recomputation is a matmul or a kernel call each. The norms,
+# SiLU and the gate's product, the residual adds and the casts are computed
+# again from them. Chosen by measurement on a v5e at Ouro-2.6B's widths and
+# 2 x 4,096 tokens, dearest first by milliseconds a byte, to fit 14.5 GB
+# (PERF.md section 7): ``attn_out`` is tagged too and left out, 40 MB a layer
+# application for the cheapest of the matmuls.
+KEPT_UNDER_REMAT = ("attn_qkv", "flash_out", "flash_lse", "ffn_gate", "ffn_up", "ffn_out")
+
+
 class LoopedStack(nn.Module):
     """``num_layers`` decoder layers and the final norm: one pass of the loop."""
 
@@ -85,7 +97,9 @@ class LoopedStack(nn.Module):
     mlp_ch: int
     rope_theta: float
     norm_eps: float
-    remat: bool = False  # as Encoder.remat, per layer application
+    # Rematerialise each layer application in the backward pass, but for
+    # KEPT_UNDER_REMAT; False keeps everything.
+    remat: bool = False
     backend: Optional[str] = None
     logits_dtype: Optional[Dtype] = None
     quant: Optional[str] = None
@@ -93,7 +107,10 @@ class LoopedStack(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        block_cls = nn.remat(DecoderBlock) if self.remat else DecoderBlock
+        block_cls = DecoderBlock
+        if self.remat:
+            kept = jax.checkpoint_policies.save_only_these_names(*KEPT_UNDER_REMAT)
+            block_cls = nn.remat(DecoderBlock, policy=kept)
         for i in range(self.num_layers):
             x = block_cls(
                 num_heads=self.num_heads,

@@ -12,8 +12,9 @@ iota comparison, forward and in both backward kernels.
 
 Differentiation: ``flash_attention`` is a ``jax.custom_vjp``. Without a
 bias, the backward is fully blocked Pallas too: the forward saves only the
-per-row logsumexp (broadcast across one 128-lane tile, the TPU-friendly
-layout), and two kernels recompute probabilities tile-by-tile to produce
+per-row logsumexp (one float32 a row between the passes; the kernels write
+and read it broadcast across one 128-lane tile, the TPU-friendly layout),
+and two kernels recompute probabilities tile-by-tile to produce
 dq (kv-innermost grid) and dk/dv (q-innermost grid) — the ``[B, H, Lq,
 Lk]`` probability matrix never exists in HBM in either direction. With an
 additive bias that requires a gradient, the backward falls back to an XLA
@@ -35,6 +36,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -638,7 +640,13 @@ def _flash_fwd(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block
             q, k, v, bias, scale, block_q, block_kv, interpret, with_lse=True,
             causal=causal, block_b=block_b,
         )
-        return out, (q, k, v, bias, out, lse)
+        # The residual is one float32 a row, not the kernel's 128-lane tile
+        # (the backward rebuilds it). A remat policy sees a custom_vjp's
+        # residuals only where the forward rule names them: with these two
+        # kept (and the caller's q, k, v) the backward pass runs no second
+        # forward kernel.
+        out, lse_row = checkpoint_name(out, "flash_out"), checkpoint_name(lse[..., 0], "flash_lse")
+        return out, (q, k, v, bias, out, lse_row)
     out = _flash_forward(
         q, k, v, bias, scale, block_q, block_kv, interpret,
         causal=causal, block_b=block_b,
@@ -650,8 +658,9 @@ def _flash_bwd(scale, block_q, block_kv, interpret, causal, block_b, residuals, 
     """Backward dispatch: blocked Pallas kernels when there is no bias;
     XLA flash-style recompute when a dbias is needed (the dense ``ds`` is
     unavoidable for the bias gradient)."""
-    q, k, v, bias, out, lse = residuals
+    q, k, v, bias, out, lse_row = residuals
     if bias is None:
+        lse = jnp.broadcast_to(lse_row[..., None], lse_row.shape + (128,))
         dq, dk, dv = _flash_backward_pallas(
             q, k, v, out, lse, g, scale, block_q, block_kv, interpret,
             causal=causal, block_b=block_b,

@@ -428,3 +428,31 @@ def test_fast_vjp_low_rank_bias_matches_autodiff(bias_shape):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-4, rtol=5e-4
         )
+
+
+def _pallas_calls(jaxpr) -> int:
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += eqn.primitive.name == "pallas_call"
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                total += _pallas_calls(inner)
+    return total
+
+
+@pytest.mark.parametrize("kept,calls", [(("flash_out", "flash_lse"), 3), (("flash_out",), 4), ((), 4)])
+def test_checkpoint_policy_keeps_the_forward_kernels_residuals(kept, calls):
+    """The forward rule names its output and its logsumexp: a
+    ``jax.checkpoint`` policy that lists both differentiates through one
+    forward kernel call (and dq, dk/dv); one that misses either runs the
+    forward a second time for it."""
+    q, k, v = _qkv(b=1, lq=64, h=2, d=32)
+
+    def loss(q, k, v):
+        out = flash_attention(jnp.sin(q), k, v, causal=True, block_q=32, block_kv=32)
+        return jnp.sum(jnp.cos(out))  # out is needed again: by cos, and as the backward's residual
+
+    policy = jax.checkpoint_policies.save_only_these_names(*kept)
+    jaxpr = jax.make_jaxpr(jax.grad(jax.checkpoint(loss, policy=policy), argnums=(0, 1, 2)))(q, k, v)
+    assert _pallas_calls(jaxpr.jaxpr) == calls

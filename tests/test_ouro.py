@@ -119,6 +119,113 @@ def test_loss_and_gradient_match_the_reference(params, tokens, reference_loss_an
         assert float(jnp.max(jnp.abs(got - w))) <= TIGHT * scale, jax.tree_util.keystr(path)
 
 
+def test_remat_changes_no_loss_and_no_gradient_leaf(params, tokens):
+    """What is kept and what is computed again is no part of the mathematics:
+    the same float32 operations on the same operands."""
+    (loss, grads), (want_loss, want) = (
+        jax.jit(jax.value_and_grad(lambda p: program_loss(build(remat=remat), p, tokens)))(params)
+        for remat in (True, False)
+    )
+    assert abs(float(loss) - float(want_loss)) <= 1e-6 * float(want_loss)
+    for (path, got), w in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(want)):
+        assert close(got, w, 1e-6), jax.tree_util.keystr(path)
+
+
+# One layer application's backward pass, read from its jaxpr: the equation
+# flax's nn.remat leaves there holds the recomputed forward and the backward.
+B, L, C, H, D, M = 2, 24, 64, 4, 8, 96
+# A forward matmul by its operands' shapes (the backward's have others).
+FORWARD_MATMULS = {
+    "to_qkv": ((B, L, C), (C, H, D)),
+    "to_out": ((B, L, H, D), (H, D, C)),
+    "fc1": ((B, L, C), (C, M)),  # gate and up
+    "fc2": ((B, L, M), (M, C)),
+}
+
+
+def _subjaxprs(eqn):
+    for value in eqn.params.values():
+        inner = getattr(value, "jaxpr", value)
+        if hasattr(inner, "eqns"):
+            yield inner
+
+
+def _count(jaxpr, counts):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            shapes = tuple(v.aval.shape for v in eqn.invars)
+            for name, want in FORWARD_MATMULS.items():
+                counts[name] += shapes == want
+        counts[eqn.primitive.name] += 1
+        for inner in _subjaxprs(eqn):
+            _count(inner, counts)
+    return counts
+
+
+def _recomputed_in_one_layer_application(kept, monkeypatch, backend="xla"):
+    """Counts over the remat equation of ``grad(LoopedStack)``'s jaxpr with
+    ``kept`` as the stack's policy."""
+    from collections import Counter
+
+    from sav_tpu.models import ouro
+
+    monkeypatch.setattr(ouro, "KEPT_UNDER_REMAT", kept)
+    stack = ouro.LoopedStack(
+        num_layers=1, num_heads=H, head_ch=D, mlp_ch=M, rope_theta=1e6, norm_eps=1e-6, remat=True,
+        backend=backend,
+    )
+    x = jnp.ones((B, L, C))
+    variables = jax.eval_shape(lambda: stack.init(jax.random.PRNGKey(0), x))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda v, x: jnp.sum(stack.apply(v, x))))(variables, x).jaxpr
+    remat = [e for e in jaxpr.eqns if e.primitive.name in ("remat2", "checkpoint", "remat")]
+    assert len(remat) == 1
+    return _count(next(_subjaxprs(remat[0])), Counter())
+
+
+def test_the_policy_keeps_the_named_projections_and_recomputes_the_rest(monkeypatch):
+    from sav_tpu.models.ouro import KEPT_UNDER_REMAT
+
+    assert set(KEPT_UNDER_REMAT) == {"attn_qkv", "flash_out", "flash_lse", "ffn_gate", "ffn_up", "ffn_out"}
+    kept = _recomputed_in_one_layer_application(KEPT_UNDER_REMAT, monkeypatch)
+    whole = _recomputed_in_one_layer_application((), monkeypatch)
+    # Whole-block remat runs every forward matmul of the layer a second time ...
+    assert [whole[k] for k in ("to_qkv", "to_out", "fc1", "fc2")] == [3, 1, 2, 1]
+    # ... the policy none of the named ones; to_out, whose output is tagged
+    # (attn_out) but not listed, runs again.
+    assert [kept[k] for k in ("to_qkv", "to_out", "fc1", "fc2")] == [0, 1, 0, 0]
+    assert whole["dot_general"] - kept["dot_general"] == 6
+    everything = _recomputed_in_one_layer_application(KEPT_UNDER_REMAT + ("attn_out",), monkeypatch)
+    assert everything["to_out"] == 0
+    # Both compute the four norms and the SiLU again: it is still remat per
+    # layer application.
+    assert kept["rsqrt"] == whole["rsqrt"] == 4
+    assert kept["logistic"] == whole["logistic"] == 1
+
+
+@pytest.mark.parametrize("name,matmul,runs", [
+    ("attn_qkv", "to_qkv", 3), ("ffn_gate", "fc1", 1), ("ffn_up", "fc1", 1), ("ffn_out", "fc2", 1),
+])
+def test_a_name_the_policy_does_not_list_is_recomputed(name, matmul, runs, monkeypatch):
+    from sav_tpu.models.ouro import KEPT_UNDER_REMAT
+
+    counts = _recomputed_in_one_layer_application(
+        tuple(n for n in KEPT_UNDER_REMAT if n != name), monkeypatch
+    )
+    assert {k: counts[k] for k in FORWARD_MATMULS} == {"to_qkv": 0, "to_out": 1, "fc1": 0, "fc2": 0, matmul: runs}
+
+
+@pytest.mark.parametrize("policy", ["kept", "whole"])
+def test_the_flash_forward_runs_once_under_the_policy(policy, monkeypatch):
+    from sav_tpu.models.ouro import KEPT_UNDER_REMAT
+
+    counts = _recomputed_in_one_layer_application(
+        KEPT_UNDER_REMAT if policy == "kept" else (), monkeypatch, backend="pallas"
+    )
+    # dq, and dk with dv; whole-block remat runs the forward kernel before them.
+    assert counts["pallas_call"] == (2 if policy == "kept" else 3)
+    assert counts["to_qkv"] == (0 if policy == "kept" else 3)
+
+
 def test_blocked_cross_entropy_is_the_full_logits_cross_entropy(params, tokens):
     model = build()
     full = model.apply({"params": params}, tokens[:, :-1], is_training=False)["logits"]

@@ -256,7 +256,8 @@ def test_deit_s_train_step_compiles_and_fits_one_chip(
 def test_looped_lm_train_step_compiles_and_fits_one_chip(topo, compiled_kernels, monkeypatch):
     """The token task's step at the benchmark cell's widths, length and
     batch, one layer of its four (a quarter of the compile): every pass's
-    causal core is the flash kernel, forward, recomputed and backward."""
+    causal core is the flash kernel, forward and backward, and the remat
+    policy keeps what would make the backward run the forward kernel again."""
     from sav_tpu.ops import attention
     from sav_tpu.parallel import create_mesh
     from sav_tpu.train import TrainConfig, Trainer
@@ -281,8 +282,11 @@ def test_looped_lm_train_step_compiles_and_fits_one_chip(topo, compiled_kernels,
     compiled = trainer.compile_train_step(state, batch, rng)
     assert _bytes_on_device(compiled) < HBM_BYTES
     text = compiled.as_text()
-    # 4 passes x (forward, recomputed forward, dq, dk/dv)
-    assert text.count('custom_call_target="tpu_custom_call"') == 16
+    # 4 passes x (forward, dq, dk/dv): no recomputed forward
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 12
+    assert not any("rematted_computation" in line for line in calls)
+    assert "rematted_computation" in text  # the norms and the gated product are computed again
     assert " while(" not in text  # a loop's event would count its body twice in a trace
 
 

@@ -37,10 +37,14 @@ HOT_FUNCTIONS = frozenset(
         "train_step",
         "eval_step",
         "train_step_placed",
-        "train_many_steps",
         "_train_step_impl",
-        "_train_many_impl",
         "_eval_step_impl",
+        # The seam's per-step and per-boundary events (obs/fit_observers.py):
+        # what listens to fit's loop is held to the loop's discipline.
+        "before_step",
+        "after_step",
+        "log",
+        "logged",
     }
 )
 

@@ -5,7 +5,6 @@ from sav_tpu.ops.attention import (
     resolve_attention_backend,
     snapshot_dispatch_log,
     xla_attention,
-    xla_attention_fast,
 )
 from sav_tpu.ops.flash_attention import flash_attention, flash_botnet_attention
 from sav_tpu.ops.fused_attention import fused_attention, fused_eligible
@@ -19,7 +18,6 @@ __all__ = [
     "resolve_attention_backend",
     "snapshot_dispatch_log",
     "xla_attention",
-    "xla_attention_fast",
     "flash_attention",
     "flash_botnet_attention",
     "fused_attention",

@@ -10,13 +10,7 @@ reference. The flash kernel transposes to ``[B*H, L, D]`` internally; the
 fused kernel reads ``[B, L, H*D]`` in place.
 
 ``backend``:
-  - ``'xla'``    — jnp/einsum path, plain autodiff backward. Measured faster
-                   than the hand-written bf16-residual VJP on v5e (PERF.md
-                   §5: the custom_vjp boundary blocks XLA fusions worth more
-                   than the residual-traffic saving); the hand VJP remains
-                   available as :func:`xla_attention_fast` for
-                   memory-constrained cases (bf16 residual halves the saved
-                   probabilities' HBM footprint).
+  - ``'xla'``    — jnp/einsum path, plain autodiff backward.
   - ``'fused'``  — single-pass fused short-sequence kernel
                    (:mod:`sav_tpu.ops.fused_attention`): the whole KV
                    sequence in one VMEM block, plain softmax (no online
@@ -60,7 +54,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import threading
 from typing import Optional
 
@@ -192,8 +185,7 @@ def causal_mask(q_len: int, kv_len: int) -> jax.Array:
 
 
 def _softmax_probs(q, k, bias, scale, logits_dtype, causal=False):
-    """Shared scaled-QK softmax — the single source of forward numerics for
-    both the autodiff reference path and the fast-VJP path."""
+    """Scaled-QK softmax: the dense path's forward numerics."""
     qs = q * jnp.asarray(scale, dtype=q.dtype)
     logits = jnp.einsum(
         "...qhd,...khd->...hqk", qs, k, preferred_element_type=logits_dtype
@@ -204,100 +196,6 @@ def _softmax_probs(q, k, bias, scale, logits_dtype, causal=False):
         mask = causal_mask(logits.shape[-2], logits.shape[-1])
         logits = jnp.where(mask, logits, jnp.asarray(-jnp.inf, logits.dtype))
     return jax.nn.softmax(logits, axis=-1)
-
-
-def _fast_fwd_impl(q, k, v, bias, scale):
-    probs = _softmax_probs(q, k, bias, scale, jnp.float32).astype(v.dtype)
-    out = jnp.einsum("...hqk,...khd->...qhd", probs, v)
-    return out, probs
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _fast_attention(q, k, v, bias, scale):
-    return _fast_fwd_impl(q, k, v, bias, scale)[0]
-
-
-def _fast_attention_fwd(q, k, v, bias, scale):
-    out, probs = _fast_fwd_impl(q, k, v, bias, scale)
-    # bias rides the residuals only to carry its (static) shape to the
-    # backward; the unused value is dead-code-eliminated there.
-    return out, (q, k, v, probs, bias)
-
-
-def _fast_attention_bwd(scale, residuals, g):
-    q, k, v, probs, bias = residuals
-    bias_shape = None if bias is None else bias.shape
-    # dV: both operands in the storage dtype — rides the MXU at bf16 rate.
-    dv = jnp.einsum("...hqk,...qhd->...khd", probs, g)
-    dp = jnp.einsum("...qhd,...khd->...hqk", g, v, preferred_element_type=jnp.float32)
-    p32 = probs.astype(jnp.float32)
-    # d(softmax): dS = P ⊙ (dP − Σ_k P·dP). Elementwise in f32; P itself is
-    # the bf16 residual, whose quantization (~2⁻⁸ relative) is the price for
-    # halving residual HBM traffic vs autodiff's saved f32 probabilities.
-    ds = p32 * (dp - jnp.sum(p32 * dp, axis=-1, keepdims=True))
-    ds_lo = ds.astype(q.dtype)  # bf16 operands → bf16-rate matmuls below
-    dq = jnp.einsum("...hqk,...khd->...qhd", ds_lo, k) * jnp.asarray(
-        scale, dtype=q.dtype
-    )
-    dk = jnp.einsum("...hqk,...qhd->...khd", ds_lo, q) * jnp.asarray(
-        scale, dtype=q.dtype
-    )
-    if bias_shape is None:
-        dbias = None
-    else:
-        # Sum dS over the dims the bias broadcast along. Broadcasting aligns
-        # shapes from the RIGHT: reduce any leading dims the bias lacks, plus
-        # right-aligned size-1 bias dims that dS expanded.
-        offset = ds.ndim - len(bias_shape)
-        reduce_axes = tuple(range(offset)) + tuple(
-            offset + i
-            for i, (b_dim, s_dim) in enumerate(zip(bias_shape, ds.shape[offset:]))
-            if b_dim == 1 and s_dim != 1
-        )
-        dbias = jnp.sum(ds, axis=reduce_axes, keepdims=True) if reduce_axes else ds
-        # custom_vjp cotangents must match the primal's dtype.
-        dbias = dbias.reshape(bias_shape).astype(bias.dtype)
-    return dq, dk, dv, dbias
-
-
-_fast_attention.defvjp(_fast_attention_fwd, _fast_attention_bwd)
-
-
-def xla_attention_fast(
-    query: jax.Array,
-    key: jax.Array,
-    value: jax.Array,
-    bias: Optional[jax.Array] = None,
-    *,
-    scale: Optional[float] = None,
-) -> jax.Array:
-    """``xla_attention`` with a hand-written VJP tuned for TPU training.
-
-    Forward numerics are identical to :func:`xla_attention` (f32 softmax,
-    probabilities cast to the value dtype before PV). The backward differs
-    from autodiff in two deliberate ways, both measured dominant in the
-    DeiT-S profile (PERF.md §1):
-
-    - the softmax residual is stored in the value dtype (bf16 in training)
-      instead of f32 — half the save/restore HBM traffic;
-    - every backward matmul (dV, dP, dQ, dK) takes low-precision operands
-      with f32 accumulation, instead of the f32-operand dots autodiff emits
-      (f32 matmuls run at ~1/4 MXU rate on v5e).
-
-    Gradient error vs the f32 chain is bounded by bf16 probability
-    quantization (~2⁻⁸ relative) — below bf16 training noise; verified
-    against :func:`xla_attention` autodiff in tests/test_flash_attention.py.
-    No attention-dropout support (training dropout uses the plain path).
-
-    Precondition: q/k/v leading batch dims must match (no cross-operand
-    batch broadcasting — the hand VJP does not sum cotangents over
-    broadcast batch dims the way autodiff's transpose does; a mismatch
-    fails at trace time under grad). A bias may still broadcast freely
-    against the logits. Use :func:`xla_attention` for broadcast batches.
-    """
-    if scale is None:
-        scale = query.shape[-1] ** -0.5
-    return _fast_attention(query, key, value, bias, scale)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -21,7 +21,6 @@ import math
 import os
 import sys
 import time
-import warnings
 from collections import deque
 from typing import Any, Iterator, Optional
 
@@ -34,7 +33,6 @@ from sav_tpu.models import create_model
 from sav_tpu.models.registry import model_task
 from sav_tpu.obs.diagnostics import diagnostics_metrics
 from sav_tpu.obs.goodput import GoodputLedger
-from sav_tpu.obs.memory import RetraceCounter, hbm_stats
 from sav_tpu.obs.spans import SpanTracer, in_phase
 from sav_tpu.ops.attention import partitioned_over
 from sav_tpu.parallel.layout import (
@@ -55,21 +53,6 @@ from sav_tpu.train.state import TrainState
 from sav_tpu.train.tasks import make_task
 from sav_tpu.utils import profiler
 from sav_tpu.utils.debug import assert_all_finite
-
-
-def _cost_note(cost, peak_flops, peak_source) -> dict:
-    """Manifest note for the step cost model (obs/costs.py) — the
-    machine-readable twin of the goodput flops/* gauges."""
-    return {
-        "source": cost.source,
-        "flops_per_device": cost.flops,
-        "bytes_accessed": cost.bytes_accessed,
-        "attribution": cost.attribution,
-        "groups": cost.groups,
-        "num_tokens": cost.num_tokens,
-        "peak_flops": peak_flops,
-        "peak_flops_source": peak_source,
-    }
 
 
 class Trainer:
@@ -304,7 +287,6 @@ class Trainer:
             ema_decay=self.config.ema_decay,
         )
         self._train_step = jax.jit(self._train_step_impl, donate_argnums=(0,))
-        self._train_many = jax.jit(self._train_many_impl, donate_argnums=(0,))
 
     # ------------------------------------------------------------------ init
 
@@ -697,36 +679,6 @@ class Trainer:
                 )
         return new_state, metrics
 
-    def _train_many_impl(self, state: TrainState, batches: dict, rng: jax.Array):
-        """K train steps in one compiled program via ``lax.scan``.
-
-        ``batches`` leaves carry a leading steps axis ``[K, ...]``. Keeping
-        the step loop on-device removes the per-step host dispatch round
-        trip — on TPU pods that overhead is µs, but the pattern also hides
-        host jitter and lets XLA overlap the inter-step boundary. Metrics
-        come back stacked ``[K]``.
-        """
-
-        def body(state, batch):
-            return self._train_step_impl(state, batch, rng)
-
-        return jax.lax.scan(body, state, batches)
-
-    def train_many_steps(self, state: TrainState, batches: dict, rng: jax.Array):
-        """Run ``K`` steps fused on-device; see ``_train_many_impl``."""
-
-        def sharding_for(key, leaf):
-            # Leading [K, ...] steps axis shifts the batch dim by one (the
-            # HWCN transpose puts it last). Specs come from the layout
-            # (batch_sharding(dim) — savlint SAV117 keeps ad-hoc
-            # PartitionSpec construction out of this file).
-            return self._blayout.batch_sharding(
-                dim=1 + self.task.batch_dim(key, leaf.ndim - 1)
-            )
-
-        placed = {k: jax.device_put(v, sharding_for(k, v)) for k, v in batches.items()}
-        return self._train_many(state, placed, rng)
-
     def _eval_step_impl(self, state: TrainState, batch: dict):
         with jax.named_scope("preprocess"):
             inputs, targets = self.task.prepare(batch, None, training=False)
@@ -1021,32 +973,26 @@ class Trainer:
             train.py:239-250 / SURVEY.md §2.9 #21).
           log_fn: callable(dict) for metrics (host-side, outside jit).
           manifest: optional :class:`~sav_tpu.obs.manifest.RunManifest`.
-            fit() accretes facts onto it (backend, cost model, goodput
-            metrics — on crash paths too, via the finally below) and hands
-            it to the hang watchdog (which finalizes ``outcome: "hang"``
-            before exit 4); the *caller* owns terminal ok/error
-            finalization, since a run may continue past fit().
+            The run's facts accrete onto it, on crash paths too; the
+            *caller* owns terminal ok/error finalization, since a run may
+            continue past fit().
 
-        Input feed (docs/input_pipeline.md): with ``config.async_feed``
-        (the default) batches are fetched and placed on device by a
-        background :class:`~sav_tpu.data.feeder.DeviceFeeder` — host fetch
-        and the sharded ``device_put`` of batch N+1 overlap the device's
-        step N, and the loop only blocks on the bounded queue.
-        ``config.async_feed=False`` restores the serial
-        fetch → put → dispatch loop.
-
-        Run telemetry (sav_tpu.obs, docs/observability.md): every run keeps
-        a goodput ledger (compile/step/input-wait/h2d/eval/checkpoint/stall
-        buckets plus ``feeder/*`` gauges, written to <log_dir>/goodput.json
-        and exposed as
-        ``self.last_goodput``); every phase is a ``sav:fit/<phase>`` span
-        (sav_tpu/obs/spans.py) that any running profiler session records
-        beside the device's operations, and ``config.trace_spans``
-        additionally writes them to a Perfetto-loadable
-        <log_dir>/spans.trace.json; ``config.watchdog_secs`` arms a
-        hang watchdog that aborts with exit 4 + stack dump when no step
-        completes in time.
+        The loop and nothing else: next batch (fetched and placed by a
+        background :class:`~sav_tpu.data.feeder.DeviceFeeder` under
+        ``config.async_feed``, the default; else fetch -> put inline;
+        docs/input_pipeline.md), dispatch, the run-ahead cap, the log
+        boundary every ``log_every_steps``, the save and eval cadences.
+        Each phase is a ``sav:fit/<phase>`` span (sav_tpu/obs/spans.py)
+        that books its bucket on the goodput ledger (``self.last_goodput``,
+        <log_dir>/goodput.json). Whatever else watches the run (incident
+        recording, anomaly profiling, the hang deadline, heartbeats, cost
+        and memory gauges, runtime guards, the manifest's notes) listens
+        behind one seam, sav_tpu/obs/fit_observers.py;
+        docs/observability.md has the table of events, listeners and the
+        exit order.
         """
+        from sav_tpu.obs.fit_observers import build_observers, fleet_identity
+
         cfg = self.config
         num_steps = num_steps if num_steps is not None else cfg.total_steps
         state = state if state is not None else self.restore_or_init()
@@ -1056,312 +1002,96 @@ class Trainer:
         rng = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), 1)
         history: list[dict] = []
         obs_dir = cfg.log_dir or cfg.checkpoint_dir
-        # Telemetry files are written by FLEET process 0 only — runs
-        # share --log-dir (the rsync/report workflow) and concurrent
-        # writers would clobber each other. Identity defaults to jax's
-        # process index; the SAV_FLEET_PROC/_PROCS override covers
-        # fleets not coordinated through jax.distributed (independent
-        # workers sharing a log dir), where every worker is jax process
-        # 0 and would otherwise clobber goodput.json/spans — only the
-        # per-process heartbeat streams below are written by everyone.
-        from sav_tpu.obs.fleet import resolve_identity as _fleet_identity
-
-        fleet_proc, fleet_procs = _fleet_identity(
-            jax.process_index(), jax.process_count()
-        )
+        # Shared telemetry files are written by FLEET process 0 only: runs
+        # share --log-dir and concurrent writers would clobber each other.
+        fleet_proc, fleet_procs = fleet_identity()
         obs_writer = fleet_proc == 0
         ledger = GoodputLedger()
-        # One span per phase (sav_tpu/obs/spans.py): each reaches any
-        # running profiler session as ``sav:fit/<phase>``, books its bucket
-        # on the ledger, and lands in the Chrome file under trace_spans.
+        # One span per phase: each reaches any running profiler session as
+        # ``sav:fit/<phase>``, books its bucket on the ledger, and lands in
+        # the Chrome file under trace_spans.
         tracer = SpanTracer(
             os.path.join(obs_dir or ".", "spans.trace.json")
             if cfg.trace_spans and obs_writer else None,
             ledger=ledger,
         )
-        retraces = RetraceCounter(self._train_step) if cfg.diagnostics else None
-        sanitizer = None
-        if cfg.sanitize:
-            # Runtime sanitizers (sav_tpu.analysis.sanitize): armed after
-            # the first completed step (compile + setup transfers exempt),
-            # torn down in the finally below. The sanitizer keeps its OWN
-            # RetraceCounter so diagnostics' delta() accounting above is
-            # undisturbed when both are on.
-            from sav_tpu.analysis.sanitize import StepSanitizer
-
-            sanitizer = StepSanitizer(self._train_step, tag="train-sanitize")
-        recorder = None
-        if cfg.record and obs_writer:
-            # Flight recorder (sav_tpu.obs.recorder; docs/incident_replay.md):
-            # host-side ring of step context + raw batches + periodic
-            # pre-step snapshots, dumped as a replayable incident bundle
-            # on nonfinite metrics / loss spikes / hangs / crashes. The
-            # per-step path is sync-free (SAV111); the periodic snapshot
-            # below is the one pipeline drain recording adds.
-            from sav_tpu.obs.recorder import FlightRecorder
-
-            recorder = FlightRecorder.from_config(
-                cfg, obs_dir or ".", manifest=manifest
-            )
-        fleet_hb = None
-        if cfg.fleet and obs_dir is not None:
-            # Fleet heartbeats (sav_tpu.obs.fleet; docs/fleet.md): EVERY
-            # process appends to its own fleet/proc_<i>.jsonl — unlike the
-            # other telemetry writers this is deliberately not process-0
-            # gated, because per-process streams ARE the product (the
-            # aggregator attributes stragglers/dead hosts across them).
-            # The per-beat path is host-only (savlint SAV112) and rides
-            # the existing log boundary.
-            from sav_tpu.obs.fleet import HeartbeatWriter
-
-            fleet_hb = HeartbeatWriter(
-                obs_dir,
-                process_index=fleet_proc,
-                process_count=fleet_procs,
-            )
-        autoprof = None
-        # Abstract (ShapeDtypeStruct) mirror of the step's arguments,
-        # captured once the first real shapes are known — the lazy HLO
-        # op-index source for post-capture trace attribution below.
-        autoprof_abstract = None
-        if cfg.autoprof and obs_dir is not None:
-            # Anomaly-triggered bounded jax.profiler windows
-            # (sav_tpu.obs.autoprof): armed by the ledger's stall
-            # anomaly, the per-window step-time spike gate, or the
-            # watchdog's soft stage; per-process (a straggler diagnosis
-            # needs the straggler's own trace), capture-budgeted like
-            # the recorder's incidents. Each finished capture is
-            # machine-read on the spot (obs/traceview.py): op time
-            # attributed onto the cost model's component keys via the
-            # compiled step's HLO metadata, summary onto the sidecar +
-            # manifest (docs/profiling.md).
-            from sav_tpu.obs.autoprof import AutoProfiler
-
-            _op_index_memo: list = []
-
-            def _autoprof_op_index():
-                # {hlo op -> metadata scope} from the step's compiled
-                # HLO. The AOT executable's text is free; the jit path
-                # lowers+compiles once from the abstract shapes —
-                # bounded post-capture side work (runs at most once per
-                # fit, only after an anomaly capture actually finished),
-                # never steady-state. Memoized including failure: a
-                # backend that cannot re-lower should not retry per
-                # capture.
-                if _op_index_memo:
-                    return _op_index_memo[0]
-                index = None
-                try:
-                    from sav_tpu.obs.traceview import parse_hlo_op_index
-
-                    if compiled_step is not None:
-                        text = compiled_step.as_text()
-                    elif autoprof_abstract is not None:
-                        a_state, a_batch, a_rng = autoprof_abstract
-                        text = self._train_step.lower(
-                            a_state, a_batch, a_rng
-                        ).compile().as_text()
-                    else:
-                        text = None
-                    if text:
-                        index = parse_hlo_op_index(text)
-                except Exception:
-                    index = None
-                _op_index_memo.append(index)
-                return index
-
-            autoprof = AutoProfiler(
-                obs_dir,
-                trace_steps=cfg.autoprof_steps,
-                max_captures=cfg.autoprof_max,
-                process_index=fleet_proc,
-                manifest=manifest,
-                op_index_fn=_autoprof_op_index,
-            )
-        watchdog = None
-        if cfg.watchdog_secs:
-            from sav_tpu.obs.watchdog import HangWatchdog
-
-            def _on_watchdog_soft(silent_s, _hb=fleet_hb, _ap=autoprof):
-                # Warning-stage evidence (watchdog thread, host-only):
-                # a fleet event marks WHEN this process stalled in the
-                # shared artifact layout, and the profiler arms so a
-                # stall that resumes slowly gets captured.
-                at_step = start_step + ledger.steps
-                if _hb is not None:
-                    _hb.fleet_event(
-                        "watchdog_soft", silent_s=round(silent_s, 1),
-                        at_step=at_step,
-                    )
-                if _ap is not None:
-                    _ap.request("watchdog_soft", at_step)
-
-            # NOTE: the deadline must exceed the longest legitimate gap
-            # between completed steps — an eval pass or checkpoint save
-            # counts one beat at its end, so size watchdog_secs above the
-            # slowest of those, not just above the step time. The soft
-            # stage (cfg.watchdog_soft_secs) warns + snapshots below it
-            # without aborting.
-            watchdog = HangWatchdog(
-                cfg.watchdog_secs, ledger=ledger, tag="train-watchdog",
-                manifest=manifest, recorder=recorder,
-                # Pre-exit drain of any in-flight async save (bounded):
-                # os._exit skips fit()'s finally, and a save abandoned
-                # mid-commit is work the next attempt re-pays.
-                checkpointer=self.checkpointer,
-                soft_deadline_s=cfg.watchdog_soft_secs,
-                on_soft=_on_watchdog_soft,
-            )
-        # Cost model (sav_tpu/obs/costs.py): an analytic per-layer-group
-        # FLOPs estimate exists up front on any backend; the total is
-        # upgraded to XLA's exact cost-analysis count when the AOT path
-        # compiles. Attribution gauges publish immediately so even a
-        # crashed run's manifest says where the FLOPs were going.
-        from sav_tpu.obs.costs import (
-            publish_cost_gauges,
-            publish_mfu_gauges,
-            train_step_cost,
-        )
-
         peak_flops, peak_source = self._resolve_peak()
-        cost = train_step_cost(
-            state.params,
-            batch_size=cfg.global_batch_size,
-            image_size=cfg.image_size,
-            n_devices=len(jax.devices()),
+        start_step = int(jax.device_get(state.step))  # savlint: disable=SAV101 -- one-time read before the loop, not per-step
+        observers = build_observers(
+            cfg, ledger=ledger, tracer=tracer, manifest=manifest,
+            obs_dir=obs_dir, identity=(fleet_proc, fleet_procs),
+            step_fn=self._train_step, checkpointer=self.checkpointer,
+            params=state.params, start_step=start_step,
+            peak=(peak_flops, peak_source),
+            layout=self.layout.describe(self.mesh),
         )
-        step_flops: Optional[float] = cost.flops or None
-        publish_cost_gauges(
-            ledger, cost, peak_flops=peak_flops, peak_source=peak_source
-        )
-        if autoprof is not None:
-            # The predicted side of every capture's measured-vs-predicted
-            # attribution table (attribution stays analytic even when the
-            # AOT path upgrades the total — same keys either way).
-            autoprof.set_predicted(cost.attribution)
-        # HBM watermark (sav_tpu.obs.memdump): peak device occupancy,
-        # observed at log boundaries (host-side counter read, no sync)
-        # and stamped into the manifest as a first-class field in the
-        # finally — OOM post-mortems and the sentinel read it without
-        # the goodput file.
-        from sav_tpu.obs.memdump import HbmWatermark
-
-        watermark = HbmWatermark()
-        if manifest is not None:
-            device0 = jax.devices()[0]
-            manifest.note("backend", {
-                "platform": device0.platform,
-                "device_kind": getattr(device0, "device_kind", None),
-                "n_devices": len(jax.devices()),
-                "process_count": jax.process_count(),
-            })
-            # Layout provenance: "which layout was this run" reads from
-            # this one note (rendered by run_report/fleet_status).
-            manifest.note("layout", self.layout.describe(self.mesh))
-            manifest.note(
-                "cost_model", _cost_note(cost, peak_flops, peak_source)
-            )
-        # The step is compiled ahead-of-time ONCE (and the loop calls the
-        # compiled executable — cost analysis comes from the same
-        # compilation, not a second one; AOT .compile() does not populate
-        # the jit dispatch cache) only when the peak is a real hardware
-        # number: under the CPU fake peak the loop keeps the plain jit
-        # dispatch path, whose retrace behavior the sanitizer/diagnostics
-        # contracts (and their tests) rely on.
+        # The step is compiled ahead of time ONCE, and the loop calls the
+        # executable (AOT .compile() does not populate the jit dispatch
+        # cache), only when the peak is a real hardware number: under the
+        # CPU fake peak the loop keeps the plain jit dispatch path, whose
+        # retrace behavior the --sanitize and --diagnostics contracts rely
+        # on (ROADMAP D14).
         use_aot = bool(peak_flops) and peak_source in (
             "device-table", "override"
         )
         compiled_step = None
-        # Sequence-parallel batch-replication fallback: surface the
-        # trace-time event ONCE per fit — a warning, a span-trace instant,
-        # a ledger gauge, and a manifest note — instead of a per-call
-        # UserWarning (degraded parallelism must be machine-visible, not
-        # log spam).
-        unsub_replication = None
-        if cfg.sequence_parallel:
-            from sav_tpu.parallel import seq_parallel as _seq_parallel
-
-            _replication_seen: list = []
-
-            def _on_replication(info):
-                if _replication_seen:
-                    return
-                _replication_seen.append(info)
-                warnings.warn(
-                    "sequence-parallel batch-replication fallback: batch "
-                    f"{info['batch']} does not divide the mesh's data-axis "
-                    f"product {info['data_axis_product']}; attention "
-                    "memory/compute is multiplied by that product for the "
-                    "whole fit (reported once; see manifest "
-                    "notes.seq_replication_fallback)",
-                    stacklevel=2,
-                )
-                tracer.instant("fit/seq_replication_fallback", **info)
-                # set_gauge coerces to float itself (info is a plain host
-                # dict — no device value anywhere near this path).
-                ledger.set_gauge("seq/replicated_batch", info["batch"])
-                if manifest is not None:
-                    manifest.note("seq_replication_fallback", info)
-
-            unsub_replication = _seq_parallel.on_batch_replication(
-                _on_replication
-            )
-        start_step = int(jax.device_get(state.step))  # savlint: disable=SAV101 -- one-time read before the loop, not per-step
         t_last = time.time()
         last_logged_step = start_step
         last_saved_step = None
-        # Wall anchor for the checkpoint_every_secs cadence; reset on
-        # every save so epoch/step-cadence saves push the timer out.
+        # Wall anchor of the checkpoint_every_secs cadence; every save
+        # pushes the timer out.
         t_last_ckpt = time.time()
-        # jax.profiler trace window (SURVEY.md §5): capture a few steady-state
-        # steps, skipping compile/warmup. Relative to start_step so resumed
-        # runs still profile.
+        # jax.profiler trace window (SURVEY.md §5): a few steady-state
+        # steps, relative to start_step so resumed runs still profile.
         prof_start = start_step + cfg.profile_start_step
         prof_stop = prof_start + max(cfg.profile_num_steps, 1)
         profiling = False
         # Wall time of the current logging window attributable to training
-        # compute (dispatch + log sync); attributed to the ledger's step /
-        # stall buckets at each log boundary (per-window anomaly flags).
+        # compute (dispatch + log sync); booked on the ledger's step /
+        # stall buckets window by window.
         window_s = 0.0
+
+        def book_window(upto: int) -> None:
+            nonlocal window_s, last_logged_step
+            if ledger.note_window(upto - last_logged_step, window_s, step=upto):
+                tracer.instant("fit/stall_anomaly", step=upto)
+                observers.stall(upto)
+            window_s = 0.0
+            last_logged_step = upto
+
+        def save(at: int) -> None:
+            nonlocal last_saved_step, t_last_ckpt
+            with tracer.span("fit/checkpoint", bucket="checkpoint", step=at):
+                self._save_with_stamp(at, state)
+            last_saved_step = at
+            t_last_ckpt = time.time()
+
         data_iter = iter(train_iter)
         feeder = None
         if cfg.async_feed:
             # Async double-buffered device feed (sav_tpu/data/feeder.py):
-            # a background thread fetches host batches and issues the
-            # sharded device_put, so transfer of batch N+1 overlaps the
-            # device's step N instead of preceding it. The loop below then
-            # only ever blocks on the bounded queue (booked as
-            # input_wait); the training thread issues no device_put.
-            # NOTE the feeder runs up to feed_depth + 1 batches ahead of
-            # the consumed step; on preemption the prefetched batches are
-            # dropped and re-produced by the resumable iterator (which
-            # replays from the checkpointed step, not iterator position).
+            # transfer of batch N+1 overlaps the device's step N, the loop
+            # only ever blocks on the bounded queue (booked as input_wait)
+            # and the training thread issues no device_put. The feeder runs
+            # up to feed_depth + 1 batches ahead; on preemption they are
+            # dropped and re-produced by the resumable iterator, which
+            # replays from the checkpointed step.
             from sav_tpu.data.feeder import DeviceFeeder
 
-            # With the recorder on, the place callback additionally
-            # fingerprints + retains the host batch on the feeder's
-            # thread — hashing overlaps device compute like the placement
-            # itself does.
-            place_fn = (
-                recorder.wrap_place(self.shard_batch)
-                if recorder is not None else self.shard_batch
-            )
             feeder = DeviceFeeder(
-                data_iter, place_fn, depth=cfg.feed_depth,
-                name="train-feeder", tracer=tracer,
+                data_iter, observers.wrap_place(self.shard_batch),
+                depth=cfg.feed_depth, name="train-feeder", tracer=tracer,
             )
-        # Dispatch run-ahead bound (see the step_dispatch block below);
-        # metrics are tiny device scalars, so the deque itself is free.
+        # Fed, placed batches arrive ready: the residual queue wait is all
+        # that is left on this thread.
+        batches = feeder if feeder is not None else data_iter
+        # Every dispatched-not-retired step holds its placed input batch in
+        # HBM; the metrics are tiny device scalars, so the deque is free.
         max_inflight = cfg.feed_depth + 1
         inflight_metrics: deque = deque()
         try:
             for step in range(start_step, num_steps):
-                if autoprof is not None:
-                    # Host-side state machine: starts an armed anomaly
-                    # capture at this step boundary, stops one whose
-                    # bounded window is over. No device syncs — the
-                    # window is approximate by design.
-                    autoprof.on_step(step)
+                observers.before_step(step, state)
                 if cfg.profile_dir is not None:
                     # Steps dispatch asynchronously: sync the device at both
                     # window edges so the trace covers exactly the intended
@@ -1374,65 +1104,29 @@ class Trainer:
                         jax.block_until_ready(state)  # savlint: disable=SAV101 -- profiler window edge: trace must cover exactly the intended steps
                         profiler.stop_trace()  # savlint: disable=SAV113 -- THE armed static window closing at its configured edge
                         profiling = False
+                with tracer.span(
+                    "fit/batch_wait", bucket="input_wait", step=step + 1
+                ):
+                    try:
+                        batch = next(batches)
+                    except StopIteration:
+                        break
                 if feeder is not None:
-                    # Placed batches arrive ready; the only critical-path
-                    # cost left is the residual queue wait.
-                    with tracer.span(
-                        "fit/batch_wait", bucket="input_wait", step=step + 1
-                    ):
-                        try:
-                            sharded = next(feeder)
-                        except StopIteration:
-                            break
+                    sharded = batch
                 else:
-                    with tracer.span(
-                        "fit/batch_wait", bucket="input_wait", step=step + 1
-                    ):
-                        try:
-                            batch = next(data_iter)
-                        except StopIteration:
-                            break
-                    if recorder is not None:
-                        recorder.observe_batch(batch)
+                    observers.host_batch(batch)
                     with tracer.span(
                         "fit/shard_batch", bucket="h2d", step=step + 1
                     ):
                         sharded = self.shard_batch(batch)  # savlint: disable=SAV106 -- the sanctioned serial fallback (async_feed=False)
-                if recorder is not None and recorder.wants_snapshot(step):
-                    # The one sync recording adds: a periodic pre-step state
-                    # copy (every record_snapshot_every steps) so an
-                    # incident bundle can replay from a nearby step.
-                    recorder.snapshot(step, jax.device_get(state))  # savlint: disable=SAV101 -- periodic pre-step recorder snapshot at the configured cadence, not a per-step sync
                 if use_aot and compiled_step is None:
-                    from sav_tpu.utils.flops import compiled_flops
-
                     with tracer.span(
                         "fit/compile", bucket="compile", in_timeline=True
                     ):
                         compiled_step = self._train_step.lower(
                             state, sharded, rng
                         ).compile()
-                        aot_flops = compiled_flops(compiled_step)
-                    if aot_flops:
-                        # Upgrade the analytic total to XLA's exact
-                        # per-device count; attribution fractions stay
-                        # analytic (the XLA total does not decompose).
-                        import dataclasses as _dc
-
-                        step_flops = aot_flops
-                        cost = _dc.replace(
-                            cost, flops=aot_flops,
-                            source="xla-cost-analysis",
-                        )
-                        publish_cost_gauges(
-                            ledger, cost, peak_flops=peak_flops,
-                            peak_source=peak_source,
-                        )
-                        if manifest is not None:
-                            manifest.note(
-                                "cost_model",
-                                _cost_note(cost, peak_flops, peak_source),
-                            )
+                        observers.compiled(compiled_step)
                     # Don't let compile time pollute the first throughput
                     # and MFU window.
                     t_last = time.time()
@@ -1446,59 +1140,29 @@ class Trainer:
                     else tracer.span("fit/dispatch", step=step + 1)
                 ):
                     state, metrics = step_fn(state, sharded, rng)
-                # Cap dispatch run-ahead the same way evaluate() does:
-                # every dispatched-not-retired step holds its placed input
-                # batch in HBM, and with the feeder keeping the host fast
-                # nothing else blocks before the log boundary (up to
-                # log_every_steps batches live). Waiting on the metrics of
-                # the step max_inflight back retires its inputs while the
-                # queue ahead stays full, so placed-batch exposure is
-                # feed_depth (queued) + max_inflight (dispatched). Booked
-                # into the step window: it is device-compute wait.
+                # Cap dispatch run-ahead the same way evaluate() does: with
+                # the feeder keeping the host fast nothing else blocks
+                # before the log boundary. Waiting on the metrics of the
+                # step max_inflight back retires its inputs while the queue
+                # ahead stays full, so placed-batch exposure is feed_depth
+                # (queued) + max_inflight (dispatched). Booked into the
+                # step window: it is device-compute wait.
                 inflight_metrics.append(metrics)
                 if len(inflight_metrics) > max_inflight:
                     with tracer.span("fit/run_ahead_wait", step=step + 1):
                         jax.block_until_ready(  # savlint: disable=SAV101 -- run-ahead cap: device-compute wait that retires placed inputs
                             inflight_metrics.popleft()
                         )
-                if recorder is not None:
-                    # Host-only bookkeeping (pairs the dispatched step with
-                    # its observed batch); never touches device values.
-                    recorder.on_step(step + 1)
+                observers.after_step(step + 1)
                 dispatch_s = time.perf_counter() - t_step
                 if first_jit:
                     # Bucketed as compile (it carries one step of device
-                    # time too — noise next to the compile).
+                    # time too, noise next to the compile).
                     ledger.account("compile", dispatch_s)
                 else:
                     window_s += dispatch_s
                 if step == start_step:
-                    if autoprof is not None and autoprof_abstract is None:
-                        # Shapes of the step's arguments (host metadata
-                        # only — no buffer retention of the donated
-                        # state): the lazy HLO op-index source when the
-                        # jit path has no AOT executable to read.
-                        autoprof_abstract = jax.tree.map(
-                            lambda x: jax.ShapeDtypeStruct(
-                                x.shape, x.dtype,
-                                sharding=getattr(x, "sharding", None),
-                            ),
-                            (state, sharded, rng),
-                        )
-                    if retraces is not None:
-                        # The first dispatch's trace is expected
-                        # compilation, not a re-trace; swallow it so
-                        # retraces=0 on a healthy run's first log window.
-                        retraces.delta()
-                    if sanitizer is not None:
-                        # Steady state starts now: implicit host->device
-                        # transfers and step retraces are hard errors
-                        # from the next iteration on.
-                        sanitizer.arm()
-                elif sanitizer is not None:
-                    # Tracing happens synchronously at dispatch, so a
-                    # retrace is attributable to exactly this step.
-                    sanitizer.check(step + 1)
+                    observers.first_step(state, sharded, rng)
                 if cfg.debug_nans:
                     assert_all_finite(metrics, f"metrics at step {step + 1}")
                 if (step + 1) % cfg.log_every_steps == 0 or step + 1 == num_steps:
@@ -1512,115 +1176,46 @@ class Trainer:
                             now = time.time()
                             m["step"] = step + 1
                             steps_since = step + 1 - last_logged_step
-                            if ledger.note_window(steps_since, window_s, step=step + 1):
-                                tracer.instant("fit/stall_anomaly", step=step + 1)
-                                if autoprof is not None:
-                                    autoprof.request("stall_anomaly", step + 1)
-                            if autoprof is not None:
-                                # Wall per-step (host view: includes input wait +
-                                # collective wait, unlike the ledger's dispatch
-                                # window) through the robust spike gate.
-                                autoprof.note_window(
-                                    step + 1,
-                                    (now - t_last) / max(steps_since, 1),
-                                )
-                            window_s = 0.0
+                            book_window(step + 1)
                             m["images_per_sec"] = (
                                 cfg.global_batch_size * steps_since / max(now - t_last, 1e-9)
                             )
-                            if step_flops and peak_flops:
-                                # Model-FLOPs utilization, per chip: cost_analysis
-                                # FLOPs are per-device (sav_tpu/utils/flops.py) —
-                                # the north star in its own unit (BASELINE.md).
-                                step_s = max(now - t_last, 1e-9) / max(steps_since, 1)
-                                m["mfu"] = step_flops / step_s / peak_flops
-                            if cfg.diagnostics:
-                                # Host-side telemetry sampled only at log boundaries:
-                                # HBM occupancy ({} on backends without memory_stats)
-                                # and silent-recompilation detection.
-                                hbm = hbm_stats()
-                                m.update(hbm)
-                                watermark.observe(hbm)
-                                if retraces is not None:
-                                    m["retraces"] = float(retraces.delta())
-                            else:
-                                # The watermark samples regardless of diagnostics
-                                # (a host-side counter read — no device sync; {}
-                                # on CPU, backfilled once at finalize).
-                                watermark.observe()
+                            observers.log(step + 1, m, steps_since, now - t_last)
                             t_last = now
-                            last_logged_step = step + 1
                             history.append(m)
                         if log_fn is not None:
                             with tracer.span("fit/log_fn", step=step + 1):
                                 log_fn(m)
-                        if recorder is not None:
-                            # Incident detection piggybacks on the metrics this
-                            # window already synced: nonfinite values or a loss
-                            # beyond the robust spike gate dump a bundle.
-                            trigger = recorder.note_metrics(step + 1, m)
-                            if trigger:
-                                incident = recorder.dump_incident(
-                                    trigger, step + 1
-                                )
-                                if incident is not None:
-                                    tracer.instant(
-                                        "fit/incident", step=step + 1,
-                                        trigger=trigger,
-                                    )
-                        if fleet_hb is not None:
-                            # Fleet heartbeat: one appended line from values
-                            # this window already holds on the host (the
-                            # synced metrics dict + the ledger's wall-clock
-                            # aggregates) — SAV112 pins the path sync-free.
-                            fleet_hb.beat(
-                                step + 1, ledger=ledger, metrics=m,
-                                incident=(
-                                    recorder.incidents[-1]["path"]
-                                    if recorder is not None
-                                    and recorder.incidents else None
-                                ),
-                            )
+                        observers.logged(step + 1, m)
                     if self.checkpointer is not None and (
                         step + 1
                     ) != last_saved_step:
-                        # Step-granular cadences (docs/elasticity.md):
-                        # piggyback on the log boundary — the metrics
-                        # sync above already drained the pipeline, and
-                        # Orbax's async path writes on the side, so the
-                        # cadence adds no step-time pause of its own.
-                        # Steps-since-last-save (NOT a step-number
-                        # modulo, which would only ever fire at
-                        # lcm(N, log_every_steps) when the cadences
-                        # misalign): the save lands at the first log
-                        # boundary >= N steps after the previous save.
+                        # Step-granular cadences (docs/elasticity.md) ride
+                        # the log boundary: its sync already drained the
+                        # pipeline and Orbax writes on the side. Steps
+                        # SINCE the last save, not a step-number modulo
+                        # (which would fire only at lcm(N, log_every_steps)
+                        # when the cadences misalign): the save lands at
+                        # the first boundary >= N steps after the last.
                         since_save = (step + 1) - (
                             last_saved_step
                             if last_saved_step is not None else start_step
                         )
-                        due = (
+                        if (
                             cfg.checkpoint_every_steps
                             and since_save >= cfg.checkpoint_every_steps
                         ) or (
                             cfg.checkpoint_every_secs is not None
-                            and now - t_last_ckpt
-                            >= cfg.checkpoint_every_secs
-                        )
-                        if due:
-                            with tracer.span(
-                                "fit/checkpoint", bucket="checkpoint",
-                                step=step + 1,
-                            ):
-                                self._save_with_stamp(step + 1, state)
-                            last_saved_step = step + 1
-                            t_last_ckpt = time.time()
-                epoch_done = (step + 1) % cfg.steps_per_epoch == 0
-                if epoch_done:
+                            and now - t_last_ckpt >= cfg.checkpoint_every_secs
+                        ):
+                            save(step + 1)
+                if (step + 1) % cfg.steps_per_epoch == 0:
                     epoch = (step + 1) // cfg.steps_per_epoch
                     if eval_iter_fn is not None and epoch % cfg.eval_every_epochs == 0:
                         with tracer.span("fit/eval", bucket="eval", epoch=epoch):
                             em = self.evaluate(
-                                state, eval_iter_fn(), recorder=recorder
+                                state, eval_iter_fn(),
+                                recorder=observers.recorder,
                             )
                         em["step"] = step + 1
                         history.append(em)
@@ -1631,233 +1226,33 @@ class Trainer:
                         and epoch % cfg.checkpoint_every_epochs == 0
                         and (step + 1) != last_saved_step
                     ):
-                        with tracer.span(
-                            "fit/checkpoint", bucket="checkpoint", step=step + 1
-                        ):
-                            self._save_with_stamp(step + 1, state)
-                        last_saved_step = step + 1
-                        t_last_ckpt = time.time()
+                        save(step + 1)
                     # Reset the throughput window so eval/checkpoint wall time
                     # doesn't deflate the next logged images_per_sec.
                     t_last = time.time()
                     if step + 1 != last_logged_step:
-                        # Steps since the last log boundary haven't been
-                        # noted yet (steps_per_epoch not a multiple of
-                        # log_every_steps): book their window now so the
-                        # ledger's per-step medians stay honest.
-                        if ledger.note_window(
-                            step + 1 - last_logged_step, window_s,
-                            step=step + 1,
-                        ):
-                            tracer.instant("fit/stall_anomaly", step=step + 1)
-                            if autoprof is not None:
-                                autoprof.request("stall_anomaly", step + 1)
-                        window_s = 0.0
-                        last_logged_step = step + 1
-                if watchdog is not None:
-                    # Armed only after the first completed step: compile
-                    # belongs to device_check's startup regime, steady
-                    # state is the watchdog's.
-                    if step == start_step:
-                        watchdog.start()
-                    else:
-                        watchdog.beat()
+                        # steps_per_epoch is not a multiple of
+                        # log_every_steps: book the steps since the last
+                        # boundary now, so the ledger's per-step medians
+                        # stay honest.
+                        book_window(step + 1)
             if window_s:
                 # StopIteration cut the run between log boundaries.
                 ledger.account("step", window_s)
-            if watchdog is not None:
-                # The step loop is done; the final save/wait below can
-                # legitimately exceed the steady-state deadline on slow
-                # storage, and firing there would corrupt the checkpoint.
-                watchdog.stop()
+            observers.loop_done()
             if self.checkpointer is not None:
                 if last_saved_step != num_steps:
-                    with tracer.span(
-                        "fit/checkpoint", bucket="checkpoint", step=num_steps
-                    ):
-                        self._save_with_stamp(num_steps, state)
+                    save(num_steps)
                 with tracer.span("fit/checkpoint_wait", bucket="checkpoint"):
-                    # The watchdog was stopped above precisely so this
-                    # final flush can take as long as the storage needs.
-                    self.checkpointer.wait()  # savlint: disable=SAV123 -- bounding the final checkpoint flush would truncate the save; watchdog already stopped
+                    # The hang deadline was disarmed at loop_done precisely so
+                    # this final flush can take as long as the storage needs.
+                    self.checkpointer.wait()  # savlint: disable=SAV123 -- bounding the final checkpoint flush would truncate the save; the hang deadline is already disarmed
         finally:
-            if recorder is not None:
-                exc = sys.exc_info()[1]
-                # Skip when the failure already dumped on the way out (a
-                # nonfinite mid-fit eval dumps 'eval_nonfinite' and THEN
-                # raises under debug_nans) — a second bundle at the same
-                # step would just burn the incident budget on a copy.
-                already_dumped = bool(recorder.incidents) and (
-                    recorder.incidents[-1]["step"]
-                    == (recorder.last_step or 0)
-                )
-                if (
-                    exc is not None
-                    and not isinstance(exc, StopIteration)
-                    and not already_dumped
-                ):
-                    # The crash path: dump whatever context the ring holds
-                    # so the failing step is reproducible even when nothing
-                    # upstream detected it (debug_nans raises per-step,
-                    # before the log-boundary detection ever sees it).
-                    recorder.dump_incident(
-                        "nonfinite"
-                        if isinstance(exc, FloatingPointError)
-                        else "exception",
-                        error=repr(exc),
-                    )
-                for k, v in recorder.stats().items():
-                    ledger.set_gauge(f"recorder/{k}", v)
-            if cfg.memdump and obs_dir is not None:
-                # Memory forensics on allocator exhaustion
-                # (sav_tpu.obs.memdump, docs/profiling.md): the state is
-                # still live HERE — by the time train.py's handler
-                # classifies the exception the buffers are gone, so the
-                # live-buffer ranking must be taken on the way out.
-                exc = sys.exc_info()[1]
-                if exc is not None and not isinstance(exc, StopIteration):
-                    from sav_tpu.obs.manifest import classify_exception
-                    from sav_tpu.obs.memdump import dump_memory_incident
-
-                    if classify_exception(exc) == "oom":
-                        dump_memory_incident(  # savlint: disable=SAV113 -- OOM incident path: the run is already dead, forensics cannot cost it anything
-                            obs_dir,
-                            step=start_step + ledger.steps,
-                            error=repr(exc),
-                            state=state,
-                            watermark=watermark,
-                            cost=cost,
-                            manifest=manifest,
-                        )
-            if feeder is not None:
-                # Publish the worker-side counters as ledger gauges (they
-                # are overlapped background time + queue depths, not
-                # training-thread wall time — see obs/goodput.py), then
-                # stop the worker so a mid-run exception can't leave it
-                # blocked holding placed device buffers.
-                for k, v in feeder.stats().items():
-                    ledger.set_gauge(f"feeder/{k}", v)
-                feeder.close()
-            if watchdog is not None:
-                watchdog.stop()
-            if self.checkpointer is not None:
-                # Abnormal exits must not abandon an in-flight async
-                # save: Orbax commits by atomic rename, so an un-awaited
-                # save is *lost* (re-paid by the next attempt), never
-                # torn — but draining it here keeps the newest step. The
-                # wait is BOUNDED (a crash escaping a wedged filesystem
-                # must not inherit the very hang it is escaping) and runs
-                # AFTER the watchdog disarms, so a slow drain on a crash
-                # path cannot be misclassified as a steady-state hang.
-                with tracer.span("fit/checkpoint_wait", bucket="checkpoint"):
-                    if not self.checkpointer.wait(timeout_s=120.0):
-                        print(
-                            "trainer: in-flight checkpoint save still "
-                            "unfinished after 120s; abandoning it (the "
-                            "previous committed step remains restorable)",
-                            file=sys.stderr,
-                        )
-            if autoprof is not None:
-                # A crash (or normal exit) inside a capture window still
-                # leaves a finished, manifest-stamped trace behind — at
-                # the CURRENT step, so the capture's step span (and the
-                # per_step_ms the analysis divides by) stays honest.
-                autoprof.finalize(start_step + ledger.steps)
-                for k, v in autoprof.stats().items():
-                    ledger.set_gauge(f"autoprof/{k}", v)
-            if fleet_hb is not None:
-                for k, v in fleet_hb.stats().items():
-                    ledger.set_gauge(f"fleet/{k}", v)
-                exc = sys.exc_info()[1]
-                fleet_hb.close(
-                    outcome="ok"
-                    if exc is None or isinstance(exc, StopIteration)
-                    else "error"
-                )
-                if fleet_hb.process_index == 0:
-                    # Merged fleet manifest (FLEET process 0's in-run
-                    # view — offline tools recompute over the final
-                    # streams): step skew, straggler ranking, dead-host
-                    # suspicion. Gated on the fleet identity, not
-                    # obs_writer, so identity-overridden fleets still
-                    # get exactly one writer.
-                    from sav_tpu.obs.fleet import (
-                        aggregate_fleet,
-                        write_fleet_manifest,
-                    )
-
-                    try:
-                        fleet_summary = aggregate_fleet(obs_dir)
-                        fleet_path = write_fleet_manifest(
-                            obs_dir, fleet_summary
-                        )
-                        if manifest is not None and fleet_path is not None:
-                            manifest.note("fleet", {
-                                "path": fleet_path,
-                                "processes": {
-                                    p: {
-                                        "heartbeats": v.get("heartbeats"),
-                                        "last_step": v.get("last_step"),
-                                        "outcome": v.get("outcome"),
-                                    }
-                                    for p, v in fleet_summary.get(
-                                        "processes", {}
-                                    ).items()
-                                },
-                                "step_skew": fleet_summary.get("step_skew"),
-                                "straggler": (
-                                    fleet_summary.get("straggler") or {}
-                                ).get("straggler"),
-                                "suspects": [
-                                    s.get("proc")
-                                    for s in fleet_summary.get(
-                                        "suspects", []
-                                    )
-                                ],
-                            })
-                    except Exception:
-                        pass  # fleet aggregation is telemetry, never fatal
-            if sanitizer is not None:
-                # Thread-local config context: must unwind on this (the
-                # entering) thread before fit returns.
-                sanitizer.close()
-            if unsub_replication is not None:
-                unsub_replication()
+            # In the finally so that crashed runs report too: the manifest
+            # carries whatever telemetry exists at the point of death.
+            observers.exit(sys.exc_info()[1], state, feeder)
             if profiling:
                 profiler.stop_trace()  # savlint: disable=SAV113 -- crash inside the armed static window: close it so the trace survives
-            # End-of-run roofline gauges (goodput/mfu, goodput/flops_per_s)
-            # from the ledger's own aggregates — no device sync involved.
-            # In the finally so crashed runs report too, and the manifest
-            # carries whatever telemetry exists at the point of death.
-            publish_mfu_gauges(
-                ledger,
-                step_flops=step_flops or 0.0,
-                peak_flops=peak_flops,
-                steps=ledger.steps,
-                step_seconds=ledger.bucket_seconds("step"),
-            )
-            # HBM watermark: one final sample (+ the live-arrays backfill
-            # on backends without memory stats) stamped as a first-class
-            # manifest field on every exit path — the sentinel and OOM
-            # post-mortems read it without the goodput file.
-            wm = watermark.finalize()
-            if wm["peak_bytes"]:
-                ledger.set_gauge("hbm/peak_bytes", wm["peak_bytes"])
-            if manifest is not None:
-                manifest.note("hbm", wm)
-                manifest.set_metrics({
-                    **ledger.flat_metrics(),
-                    "hbm_peak_bytes": wm["peak_bytes"],
-                })
-                # Attention-dispatch provenance: which backend + block
-                # config every traced attention shape resolved to (filled
-                # at trace time, so it exists once the step compiled —
-                # including on crash paths after the first trace).
-                from sav_tpu.ops.attention import snapshot_dispatch_log
-
-                dispatch = snapshot_dispatch_log()
-                if dispatch:
-                    manifest.note("attention_dispatch", dispatch)
             tracer.write()
         self.last_goodput = ledger.summary()
         if obs_dir is not None and obs_writer:

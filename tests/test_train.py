@@ -113,33 +113,6 @@ def test_batch_stats_model_trains(devices):
     assert np.isfinite(float(metrics["loss"]))
 
 
-@pytest.mark.slow
-def test_train_many_steps_matches_loop(devices):
-    """K scan-fused steps == K separate steps (same math, one dispatch)."""
-    it = synthetic_data_iterator(batch_size=16, image_size=32, num_classes=10, seed=5)
-    batches = [next(it) for _ in range(4)]
-    rng = jax.random.PRNGKey(0)
-
-    t1 = _trainer()
-    s1 = t1.init_state()
-    losses_loop = []
-    for b in batches:
-        s1, m = t1.train_step(s1, b, rng)
-        losses_loop.append(float(m["loss"]))
-
-    t2 = _trainer()
-    s2 = t2.init_state()
-    stacked = {k: np.stack([b[k] for b in batches]) for k in batches[0]}
-    s2, metrics = t2.train_many_steps(s2, stacked, rng)
-    losses_scan = [float(x) for x in np.asarray(jax.device_get(metrics["loss"]))]
-    np.testing.assert_allclose(losses_scan, losses_loop, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(
-        jax.device_get(jax.tree.leaves(s1.params)[0]),
-        jax.device_get(jax.tree.leaves(s2.params)[0]),
-        rtol=1e-5, atol=1e-6,
-    )
-
-
 def test_mixed_labels_loss(devices):
     trainer = _trainer()
     state = trainer.init_state()

@@ -4,8 +4,6 @@
 Variants (any comma list via --variants):
   base       — as-shipped defaults (plain-autodiff attention backward,
                f32 logits, fused-optimizer auto)
-  fastvjp    — route the dispatcher's XLA branch through the hand-written
-               bf16-residual VJP (`xla_attention_fast`)
   bf16logits — TrainConfig.attention_logits_dtype='bfloat16' (halved L²
                softmax HBM traffic)
   nofuse     — fused_optimizer=False
@@ -74,7 +72,7 @@ def make_batch(bs, image_size):
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--variants", default="base,fastvjp,bf16logits,nofuse")
+    p.add_argument("--variants", default="base,bf16logits,nofuse")
     p.add_argument("--batch-size", type=int, default=256)
     p.add_argument("--model", default="deit_s_patch16")
     args = p.parse_args()
@@ -84,7 +82,7 @@ def main():
 
     import jax.numpy as jnp
 
-    known = {"base", "fastvjp", "bf16logits", "nofuse", "nomax", "bhld",
+    known = {"base", "bf16logits", "nofuse", "nomax", "bhld",
              "noclip", "fused", "flash"}
     variants = args.variants.split(",")
     unknown = set(variants) - known
@@ -98,21 +96,7 @@ def main():
     for variant in variants:
         att.xla_attention = orig_xla
         att._softmax_probs = orig_softmax
-        if variant == "fastvjp":
-
-            def _fastvjp(q, k, v, bias=None, *, scale=None, dropout_rate=0.0,
-                         deterministic=True, **kw):
-                # xla_attention_fast has no dropout support — refuse rather
-                # than silently time a cheaper computation than base.
-                if dropout_rate > 0.0 and not deterministic:
-                    raise ValueError(
-                        "fastvjp A/B variant cannot benchmark attention "
-                        "dropout configs"
-                    )
-                return att.xla_attention_fast(q, k, v, bias, scale=scale)
-
-            att.xla_attention = _fastvjp
-        elif variant == "nomax":
+        if variant == "nomax":
 
             def _nomax_probs(q, k, bias, scale, logits_dtype):
                 qs = q * jnp.asarray(scale, dtype=q.dtype)
@@ -157,7 +141,7 @@ def main():
             attention_backend=(
                 {"fused": "fused", "flash": "pallas"}.get(variant, "xla")
             ),
-            # 'float32' explicitly for base/fastvjp/nofuse: None inherits
+            # 'float32' explicitly for base/nofuse: None inherits
             # the compute dtype (bf16), which would collapse base and
             # bf16logits into the same configuration. The round-4+ variants
             # (nomax/bhld/noclip/fused/flash) ride bf16 logits so their

@@ -106,11 +106,12 @@ class TrainConfig:
     warmup_epochs: int = 5
     weight_decay: float = 0.05
     clip_grad_norm: Optional[float] = 1.0
-    # Adam moment updates on one flat buffer (optax.flatten) — kills per-leaf
-    # kernel-launch overhead. None = auto: on for pure data-parallel meshes,
-    # off whenever a model/fsdp/expert axis exists (a flat moment vector
-    # cannot shard like its parameters). False also keeps the per-leaf
-    # opt-state layout of pre-round-3 checkpoints.
+    # Layout of Adam's moments. None = auto: per leaf on every mesh (one
+    # pass over each parameter), unless the checkpoint being restored holds
+    # flat moments, which rebuilds to match it. True = one flat vector
+    # (optax.flatten): the same arithmetic at about three times the bytes on
+    # the TPU, and it cannot shard like its parameters; kept for checkpoints
+    # written with it (PERF.md section 6, PR 29).
     fused_optimizer: Optional[bool] = None
     label_smoothing: float = 0.1
     # Parameter EMA (e.g. 0.9999): eval runs on the averaged weights (the

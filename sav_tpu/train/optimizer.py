@@ -106,19 +106,25 @@ def make_optimizer(
     *,
     weight_decay: float = 0.05,
     clip_grad_norm: Optional[float] = 1.0,
-    fused: bool = True,
+    fused: bool = False,
     ema_decay: Optional[float] = None,
 ) -> optax.GradientTransformation:
-    """Masked AdamW, by default with the Adam moment math on one flat vector.
+    """Masked AdamW, one pass over each parameter.
 
-    ``fused=True`` wraps ``scale_by_adam`` in ``optax.flatten`` so the
-    m/v/bias-correction updates run as a handful of fused kernels over one
-    contiguous buffer instead of ~10 small kernels per parameter leaf —
-    measured 9.3 ms/step of mostly launch overhead on the DeiT-S profile
-    (PERF.md §1/§5). Numerically identical (flatten is a reshape); the decay
-    mask and global-norm clip stay tree-wise (the mask needs parameter
-    paths). Changes the optimizer-state checkpoint layout — set
-    ``fused=False`` to restore pre-round-3 checkpoints.
+    Per leaf (the default) the TPU's compiler makes the whole update of a
+    parameter one fusion: it reads the gradient, both moments and the
+    parameter and writes the moments and the parameter, with the clip's
+    scale, the bias corrections, the masked decay, the rate and
+    ``apply_updates`` inside it (``tests/test_tpu_compile.py`` holds the
+    compiled step to that).
+
+    ``fused=True`` wraps ``scale_by_adam`` in ``optax.flatten``: the same
+    arithmetic element by element on one vector of all parameters. On the
+    TPU flattening a tiled matrix to 1-D is a copy, and so are the
+    concatenate and the split back, so that layout moves about three times
+    the bytes (PERF.md section 6, PR 29). It stays only for checkpoints
+    whose moments are flat (it changes the optimizer state's layout); the
+    decay mask and the global-norm clip are tree-wise in both.
     """
     chain = []
     if clip_grad_norm is not None:

@@ -254,12 +254,10 @@ class Trainer:
             num_epochs=config.num_epochs,
             end_lr=config.end_lr,
         )
-        fused_opt = config.fused_optimizer
-        if fused_opt is None:
-            # Flat Adam moments can't be sharded like their parameters —
-            # auto-enable only when params are replicated (no non-data axis).
-            fused_opt = all(name == "data" for name in self.mesh.axis_names)
-        self._build_optimizer(fused_opt)
+        # Auto (None) is the per-leaf chain on every mesh: one pass over
+        # each parameter. The flat layout is built only when asked for by
+        # name, or by restore_or_init for a checkpoint that holds it.
+        self._build_optimizer(bool(config.fused_optimizer))
         self.checkpointer = checkpointer
         if checkpointer is None and config.checkpoint_dir:
             self.checkpointer = Checkpointer(
@@ -275,8 +273,9 @@ class Trainer:
         Split out of ``__init__`` so :meth:`restore_or_init` can swap the
         optimizer *layout* (per-leaf vs flat Adam moments) to match a
         probed checkpoint before building the restore template — the
-        numerics are identical (``optax.flatten`` is a reshape), only the
-        opt-state pytree structure changes.
+        arithmetic is the same element by element, the opt-state pytree
+        structure and the bytes the update moves are not (the flat layout's
+        ravel, concatenate and split are copies on the TPU).
         """
         self.fused_optimizer = fused
         self.tx = make_optimizer(
@@ -437,13 +436,14 @@ class Trainer:
         """Probe the saved opt-state layout and pick the matching
         optimizer build (docs/elasticity.md).
 
-        Resuming a pre-round-3 checkpoint used to require a hand-passed
-        ``--no-fused-optimizer``; the checkpoint itself already knows its
-        layout, so when ``config.fused_optimizer`` is None (auto) the
-        probe's answer wins and the optimizer is rebuilt to match. An
-        *explicit* config that contradicts the checkpoint is kept — the
-        user overrode auto on purpose — but warned about, because the
-        restore is then going to fail with a structure mismatch.
+        The checkpoint knows its layout, so when
+        ``config.fused_optimizer`` is None (auto) the probe's answer wins:
+        auto starts per leaf, and a checkpoint whose moments are flat
+        rebuilds the optimizer to the flat layout and says once that this
+        is the slower one. An *explicit* config that contradicts the
+        checkpoint is kept — the user overrode auto on purpose — but
+        warned about, because the restore is then going to fail with a
+        structure mismatch.
         """
         import logging
 
@@ -453,11 +453,10 @@ class Trainer:
             pure_data = all(name == "data" for name in self.mesh.axis_names)
             if self.config.fused_optimizer is None:
                 if detected and not pure_data:
-                    # Auto-detect must not override the __init__ mesh
-                    # guard: flat Adam moments cannot take non-data
-                    # parameter shardings, so a fused-layout checkpoint
-                    # cannot be resumed onto this mesh either way —
-                    # keep per-leaf and let the restore fail loudly.
+                    # Flat Adam moments cannot take non-data parameter
+                    # shardings, so a flat-layout checkpoint cannot be
+                    # resumed onto this mesh either way — keep per-leaf
+                    # and let the restore fail loudly.
                     logging.warning(
                         "checkpoint uses the flat-buffer optimizer-state "
                         "layout but the mesh has non-data axes %s (flat "
@@ -467,13 +466,18 @@ class Trainer:
                         list(self.mesh.axis_names),
                     )
                     return
-                logging.warning(
-                    "checkpoint uses the %s optimizer-state layout; "
-                    "rebuilding the optimizer to match (auto-detected — "
-                    "pass --%sfused-optimizer to silence)",
-                    "flat-buffer" if detected else "per-leaf",
-                    "" if detected else "no-",
-                )
+                if detected:
+                    # Auto starts per leaf: said once, where the operator
+                    # who wonders at the slower step will look.
+                    logging.warning(
+                        "checkpoint holds flat Adam moments "
+                        "(fused_optimizer=True, or auto on a data-parallel "
+                        "mesh before PR 29); rebuilding the optimizer to "
+                        "that layout to resume it. optimizer_layout: flat "
+                        "is the slower one on a TPU (its update moves "
+                        "about three times the bytes of the per-leaf "
+                        "layout that fresh runs take)"
+                    )
                 self._build_optimizer(detected)
             else:
                 logging.warning(
@@ -523,10 +527,11 @@ class Trainer:
                         "layout and must match the checkpoint: (a) "
                         "--ema-decay (TrainConfig.ema_decay) adds an EMA "
                         "tree — set it iff the checkpointed run had it; "
-                        "(b) checkpoints predating the flat-buffer "
-                        "optimizer (round 3) need --no-fused-optimizer "
-                        "(TrainConfig.fused_optimizer=False) for the "
-                        "per-leaf Adam state layout"
+                        "(b) --fused-optimizer/--no-fused-optimizer "
+                        "(TrainConfig.fused_optimizer), where given by "
+                        "name, must be the layout of the checkpoint's "
+                        "Adam moments — leave it unset to follow the "
+                        "checkpoint"
                     ) from e
                 raise
             if restored is not None:
@@ -1023,7 +1028,10 @@ class Trainer:
             step_fn=self._train_step, checkpointer=self.checkpointer,
             params=state.params, start_step=start_step,
             peak=(peak_flops, peak_source),
-            layout=self.layout.describe(self.mesh),
+            layout={
+                **self.layout.describe(self.mesh),
+                "optimizer_layout": "flat" if self.fused_optimizer else "per_leaf",
+            },
         )
         # The step is compiled ahead of time ONCE, and the loop calls the
         # executable (AOT .compile() does not populate the jit dispatch

@@ -17,6 +17,7 @@ back without a chip and would only produce warnings).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +69,75 @@ def _bytes_on_device(compiled) -> int:
         m.argument_size_in_bytes + m.output_size_in_bytes
         + m.temp_size_in_bytes - m.alias_size_in_bytes
     )
+
+
+# What the optimizer's update moves, read from a compiled step's text: the
+# entry computation's instructions whose ``op_name`` lies under the step's
+# ``optimizer`` scope (a fusion carries its root's), each charged its
+# operands and its output whole.
+_ITEMSIZE = {"f64": 8, "s64": 8, "u64": 8, "f32": 4, "s32": 4, "u32": 4,
+             "bf16": 2, "f16": 2, "s16": 2, "u16": 2, "s8": 1, "u8": 1, "pred": 1}
+_ARRAY = re.compile(r"\b(%s)\[([0-9,]*)\]" % "|".join(_ITEMSIZE))
+_MOVES_NOTHING = ("get-tuple-element", "bitcast", "tuple", "parameter", "constant")
+
+
+def _shape_bytes(shape: str) -> int:
+    return sum(
+        _ITEMSIZE[dtype] * int(np.prod([int(d) for d in dims.split(",") if d]))
+        for dtype, dims in _ARRAY.findall(shape)
+    )
+
+
+def _closing(text: str, start: int) -> int:
+    depth = 0
+    for i in range(start, len(text)):
+        depth += (text[i] == "(") - (text[i] == ")")
+        if depth == 0:
+            return i
+    raise ValueError(text[:200])
+
+
+def _optimizer_scope(hlo_text: str) -> list:
+    """``(opcode, bytes)`` of every entry-computation instruction under the
+    ``optimizer`` scope that moves something."""
+    lines = hlo_text.splitlines()
+    entry = next(i for i, line in enumerate(lines) if line.startswith("ENTRY "))
+    sizes, found = {}, []
+    for line in lines[entry + 1:]:
+        if line.startswith("}"):
+            break
+        head = re.match(r"\s*(?:ROOT )?%(\S+) = ", line)
+        if not head:
+            continue
+        rest = line[head.end():]
+        if rest.startswith("("):  # a tuple's shape
+            end = _closing(rest, 0)
+            shape, rest = rest[: end + 1], rest[end + 2:]
+        else:
+            shape, _, rest = rest.partition(" ")
+        paren = rest.index("(")
+        end = _closing(rest, paren)
+        opcode, operands = rest[:paren], re.findall(r"%([\w.\-]+)", rest[paren: end + 1])
+        sizes[head.group(1)] = _shape_bytes(shape)
+        scope = re.search(r'op_name="([^"]*)"', rest[end + 1:])
+        if scope and "/optimizer/" in scope.group(1) and opcode not in _MOVES_NOTHING:
+            found.append((opcode, sizes[head.group(1)] + sum(sizes.get(o, 0) for o in operands)))
+    return found
+
+
+def _assert_one_pass_optimizer(compiled, params) -> None:
+    """The AdamW update is one pass over each parameter: no data movement
+    under the scope (the flat layout's ravel, concatenate and split are
+    ``reshape``, ``concatenate`` and ``copy`` there), and at most 8 times
+    the parameters' bytes moved: gradient, two moments and the parameter
+    read, the moments and the parameter written, and the norm's read."""
+    scope = _optimizer_scope(compiled.as_text())
+    opcodes = {opcode for opcode, _ in scope}
+    assert "fusion" in opcodes  # the scope was found
+    assert not opcodes & {"concatenate", "reshape", "copy", "slice"}, sorted(opcodes)
+    param_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    passes = sum(moved for _, moved in scope) / param_bytes
+    assert passes <= 8, passes
 
 
 # ------------------------------------------------------------- the kernels
@@ -239,25 +309,42 @@ def _deit_s_trainer(mesh_axes, devices, backend=None):
     return Trainer(config, mesh=mesh), mesh
 
 
+# One compile a step program, whichever test of it runs first.
+_STEPS = {}
+
+
+def _deit_s_step(topo, backend):
+    if ("deit_s", backend) not in _STEPS:
+        trainer, mesh = _deit_s_trainer({"data": 1}, topo.devices[:1], backend)
+        state, batch, rng = _abstract_train_args(trainer, 256, 224, mesh)
+        _STEPS["deit_s", backend] = (
+            trainer.compile_train_step(state, batch, rng), state.params
+        )
+    return _STEPS["deit_s", backend]
+
+
 @pytest.mark.parametrize("backend", [None, "fused", "pallas"])
 def test_deit_s_train_step_compiles_and_fits_one_chip(
     topo, compiled_kernels, backend
 ):
     """chip_smoke.py's train phase: the whole DeiT-S step at batch 256."""
-    trainer, mesh = _deit_s_trainer({"data": 1}, topo.devices[:1], backend)
-    compiled = trainer.compile_train_step(
-        *_abstract_train_args(trainer, 256, 224, mesh)
-    )
+    compiled, _ = _deit_s_step(topo, backend)
     assert _bytes_on_device(compiled) < HBM_BYTES
     has_kernel = "tpu_custom_call" in compiled.as_text()
     assert has_kernel == (backend is not None)
 
 
-def test_looped_lm_train_step_compiles_and_fits_one_chip(topo, compiled_kernels, monkeypatch):
+def test_deit_s_optimizer_is_one_pass_over_each_parameter(topo, compiled_kernels):
+    """128 leaves, 76 of them vectors: the per-leaf update did not become
+    copies or second passes at the size where leaves are many and small."""
+    _assert_one_pass_optimizer(*_deit_s_step(topo, None))
+
+
+def _looped_lm_step(topo, monkeypatch):
     """The token task's step at the benchmark cell's widths, length and
-    batch, one layer of its four (a quarter of the compile): every pass's
-    causal core is the flash kernel, forward and backward, and the remat
-    policy keeps what would make the backward run the forward kernel again."""
+    batch, one layer of its four (a quarter of the compile)."""
+    if "looped_lm" in _STEPS:
+        return _STEPS["looped_lm"]
     from sav_tpu.ops import attention
     from sav_tpu.parallel import create_mesh
     from sav_tpu.train import TrainConfig, Trainer
@@ -279,7 +366,15 @@ def test_looped_lm_train_step_compiles_and_fits_one_chip(topo, compiled_kernels,
         (2, 4097), jnp.int32, sharding=trainer._blayout.batch_sharding()
     )}
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated)
-    compiled = trainer.compile_train_step(state, batch, rng)
+    _STEPS["looped_lm"] = (trainer.compile_train_step(state, batch, rng), state.params)
+    return _STEPS["looped_lm"]
+
+
+def test_looped_lm_train_step_compiles_and_fits_one_chip(topo, compiled_kernels, monkeypatch):
+    """Every pass's causal core is the flash kernel, forward and backward,
+    and the remat policy keeps what would make the backward run the forward
+    kernel again."""
+    compiled, _ = _looped_lm_step(topo, monkeypatch)
     assert _bytes_on_device(compiled) < HBM_BYTES
     text = compiled.as_text()
     # 4 passes x (forward, dq, dk/dv): no recomputed forward
@@ -288,6 +383,12 @@ def test_looped_lm_train_step_compiles_and_fits_one_chip(topo, compiled_kernels,
     assert not any("rematted_computation" in line for line in calls)
     assert "rematted_computation" in text  # the norms and the gated product are computed again
     assert " while(" not in text  # a loop's event would count its body twice in a trace
+
+
+def test_looped_lm_optimizer_is_one_pass_over_each_parameter(topo, compiled_kernels, monkeypatch):
+    """Leaves of up to 403 MB (the embedding, the head), and gradients that
+    are sums over four passes: each sum is taken inside the leaf's update."""
+    _assert_one_pass_optimizer(*_looped_lm_step(topo, monkeypatch))
 
 
 def test_deit_s_sharded_train_step_compiles_for_four_chips(topo):

@@ -230,9 +230,10 @@ import click
 )
 @click.option(
     "--fused-optimizer/--no-fused-optimizer", default=None,
-    help="Adam moments on one flat buffer (default: auto — on for pure "
-    "data-parallel meshes). Pass --no-fused-optimizer to resume checkpoints "
-    "written with the per-leaf optimizer-state layout (pre-round-3).",
+    help="Layout of Adam's moments (default: auto — per leaf on every mesh, "
+    "or whatever layout the checkpoint being resumed holds). "
+    "--fused-optimizer keeps them on one flat buffer: the same arithmetic, "
+    "slower on a TPU; only for checkpoints written with that layout.",
 )
 @click.option(
     "--log-dir", type=str, default=None,

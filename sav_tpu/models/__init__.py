@@ -4,6 +4,7 @@ from sav_tpu.models.botnet import BoTNet
 from sav_tpu.models.cait import CaiT
 from sav_tpu.models.ceit import CeiT
 from sav_tpu.models.cvt import CvT
+from sav_tpu.models.joyai import JoyAILM
 from sav_tpu.models.mlp_mixer import MLPMixer
 from sav_tpu.models.ouro import OuroLM
 from sav_tpu.models.registry import (
@@ -28,6 +29,7 @@ __all__ = [
     "TNT",
     "MLPMixer",
     "OuroLM",
+    "JoyAILM",
     "create_model",
     "model_names",
     "model_supports",

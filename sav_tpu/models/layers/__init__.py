@@ -15,8 +15,9 @@ from sav_tpu.models.layers.cvt_attention import (
     CvTAttentionBlock,
     CvTSelfAttentionBlock,
 )
+from sav_tpu.models.layers.latent_attention import LatentSelfAttentionBlock
 from sav_tpu.models.layers.feedforward import FFBlock, GatedFFBlock, LeFFBlock
-from sav_tpu.models.layers.moe import MoEFFBlock
+from sav_tpu.models.layers.moe import MoEFFBlock, SparseMoEBlock
 from sav_tpu.models.layers.normalization import LayerScaleBlock, RMSNorm
 from sav_tpu.models.layers.position_embed import (
     AddAbsPosEmbed,
@@ -40,7 +41,9 @@ __all__ = [
     "FFBlock",
     "GatedFFBlock",
     "LeFFBlock",
+    "LatentSelfAttentionBlock",
     "MoEFFBlock",
+    "SparseMoEBlock",
     "LayerScaleBlock",
     "RMSNorm",
     "AddAbsPosEmbed",

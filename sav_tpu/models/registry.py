@@ -22,6 +22,7 @@ from sav_tpu.models.botnet import BoTNet
 from sav_tpu.models.cait import CaiT
 from sav_tpu.models.ceit import CeiT
 from sav_tpu.models.cvt import CvT
+from sav_tpu.models.joyai import JoyAILM
 from sav_tpu.models.mlp_mixer import MLPMixer
 from sav_tpu.models.ouro import OuroLM
 from sav_tpu.models.tnt import TNT
@@ -164,6 +165,21 @@ register(
     ut_steps=4, rope_theta=1e6, norm_eps=1e-6,
 )
 
+# --- JoyAI-LLM-Flash (latent attention, routed + shared experts, MTP) -------
+# Sizes of https://huggingface.co/jdopensource/JoyAI-LLM-Flash/blob/main/
+# config.json; ``num_classes`` is the vocabulary (129,280 there). 48.9 B
+# parameters: one chip holds a cut in depth and its share of every expert
+# layer (model_overrides={"num_layers": 5, "experts_held": (0, 16), ...}).
+register(
+    "joyai_llm_flash",
+    JoyAILM,
+    task="tokens_mtp",
+    embed_dim=2048, num_layers=40, num_heads=32, q_rank=1536, kv_rank=512,
+    nope_ch=128, rope_ch=64, v_ch=128, mlp_ch=7168, expert_ch=768,
+    num_experts=256, top_k=8, routed_scale=2.5, first_dense=1,
+    rope_theta=32e6, norm_eps=1e-6,
+)
+
 
 def model_names() -> list[str]:
     return sorted(_REGISTRY)
@@ -249,7 +265,8 @@ def create_model(
 
 
 def model_task(model_name: str) -> str:
-    """The task the named model trains on: ``"image"`` or ``"tokens"``."""
+    """The task the named model trains on, a key of
+    ``sav_tpu.train.tasks.TASKS``."""
     if model_name not in _REGISTRY:
         raise ValueError(
             f"unknown model {model_name!r}; available: {', '.join(model_names())}"

@@ -259,6 +259,7 @@ def resolve_attention_backend(
     on_tpu: Optional[bool] = None,
     num_devices: Optional[int] = None,
     causal: bool = False,
+    value_dim: Optional[int] = None,
 ) -> AttentionDispatch:
     """The three-way ``auto`` rule on static shapes (see module docstring).
 
@@ -269,13 +270,16 @@ def resolve_attention_backend(
     (tests/test_attn_dispatch.py). Explicit ``requested`` backends pass
     through, picking up any tuned block config for the shape. A ``causal``
     core reads the cache's ``.causal`` entries only, and is never ``fused``
-    (the single-pass kernel has no mask).
+    (the single-pass kernel has no mask); nor is a core whose value head
+    (``value_dim``) is not the query's, which has cache entries of its own.
     """
     if on_tpu is None:
         on_tpu = _on_tpu()
     if num_devices is None:
         num_devices = getattr(_TRACE, "devices", 1)
-    entry = attn_tuning.lookup(batch, q_len, kv_len, heads, dim, dtype, causal=causal)
+    entry = attn_tuning.lookup(
+        batch, q_len, kv_len, heads, dim, dtype, causal=causal, value_dim=value_dim
+    )
     tuned_cfg = attn_tuning.block_config(entry)
     if requested and requested != "auto":
         cfg = tuned_cfg if (entry and entry["backend"] == requested) else None
@@ -306,7 +310,7 @@ def resolve_attention_backend(
             source="threshold",
             block_config=cfg,
         )
-    short = not causal and _fused.fused_eligible(
+    short = not causal and value_dim in (None, dim) and _fused.fused_eligible(
         q_len, kv_len, dim, heads=heads, itemsize=itemsize
     )
     if entry:
@@ -382,7 +386,7 @@ def dot_product_attention(
         dispatch = resolve_attention_backend(
             b, lq, key.shape[1], h, d,
             dtype=query.dtype, requested=requested, kernels_ok=True,
-            causal=causal,
+            causal=causal, value_dim=value.shape[-1],
         )
         _log_dispatch(tuple(query.shape), key.shape[1], requested, dispatch)
         backend = dispatch.backend
@@ -396,8 +400,8 @@ def dot_product_attention(
             )
         backend, cfg = "xla", {}
     if backend == "fused":
-        if causal:
-            raise ValueError("the fused attention kernel has no causal arm")
+        if causal or value.shape[-1] != query.shape[-1]:
+            raise ValueError("the fused attention kernel has no causal arm and one head size")
         # Shape ineligibility (kv_len over the single-block VMEM budget)
         # raises inside fused_attention with the budget numbers.
         kw = {k: cfg[k] for k in ("block_q", "block_b") if k in cfg}

@@ -74,13 +74,15 @@ _loaded: dict = {}
 
 def shape_key(
     batch, q_len: int, kv_len: int, heads: int, dim: int, dtype="bfloat16",
-    causal: bool = False,
+    causal: bool = False, value_dim: Optional[int] = None,
 ) -> str:
     """Canonical cache key. ``batch`` may be ``'*'`` for the wildcard; a
     causal core does half the work of the same shape unmasked and has
-    entries of its own (``....causal``)."""
+    entries of its own (``....causal``); a value head of another size than
+    the query's is part of the head's name (``D192v128``)."""
     dt = jnp.dtype(dtype).name
-    key = f"B{batch}.Lq{q_len}.Lkv{kv_len}.H{heads}.D{dim}.{dt}"
+    head = f"D{dim}" if value_dim in (None, dim) else f"D{dim}v{value_dim}"
+    key = f"B{batch}.Lq{q_len}.Lkv{kv_len}.H{heads}.{head}.{dt}"
     return key + ".causal" if causal else key
 
 
@@ -134,6 +136,7 @@ def lookup(
     dtype="bfloat16",
     *,
     causal: bool = False,
+    value_dim: Optional[int] = None,
     path: Optional[str] = None,
 ) -> Optional[dict]:
     """Measured entry for a shape (exact batch, then batch-wildcard);
@@ -141,7 +144,7 @@ def lookup(
     backend name are ignored rather than dispatched on."""
     entries = load_cache(path).get("entries", {})
     for b in (batch, "*"):
-        entry = entries.get(shape_key(b, q_len, kv_len, heads, dim, dtype, causal))
+        entry = entries.get(shape_key(b, q_len, kv_len, heads, dim, dtype, causal, value_dim))
         if not isinstance(entry, dict) or entry.get("backend") not in _BACKENDS:
             continue
         if b == "*" and batch < entry.get("min_batch", 0):

@@ -49,6 +49,19 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def _pad_head(dim: int) -> int:
+    """The head size the kernels see: a whole number of 128-lane tiles, but a
+    head wider than one tile that is a multiple of 64 (latent attention's
+    192) stays as it is. Mosaic takes a block whose last dimension is the
+    array's own, and the half-filled second tile costs less than padding
+    every query and key row in HBM: at ``[2, 4096, 32, 192 / 128]`` causal
+    4.44 / 18.20 ms forward / forward + backward against 5.26 / 19.01 padded
+    to 256 (PERF.md section 6, PR 30)."""
+    if dim > 128 and dim % 64 == 0:
+        return dim
+    return _round_up(dim, 128)
+
+
 def _pick_block_b(bh: int, *, force_one: bool = False) -> int:
     """Batch·head slices per grid cell. Grid-cell issue overhead on TPU is
     ~µs-scale, so short-sequence shapes (few kv blocks per cell) want several
@@ -231,7 +244,7 @@ def _flash_forward(
     the residual the blocked backward consumes as-is.
     """
     batch, q_len, heads, dim = q.shape
-    kv_len = k.shape[1]
+    kv_len, dim_v = k.shape[1], v.shape[-1]
     if interpret is None:
         interpret = _backend.default_interpret()
 
@@ -242,16 +255,19 @@ def _flash_forward(
 
     qf, kf, vf = to_bhld(q), to_bhld(k), to_bhld(v)
 
-    dim_p = _round_up(dim, 128)
+    # The query/key head and the value head are padded each to its own lane
+    # multiple: a value head (and the output) narrower than the query's is
+    # never widened to it.
+    dim_p, dim_v_p = _pad_head(dim), _pad_head(dim_v)
     block_q = min(block_q, _round_up(q_len, 16))
     block_kv = min(block_kv, _round_up(kv_len, 16))
     q_len_p = _round_up(q_len, block_q)
     kv_len_p = _round_up(kv_len, block_kv)
 
-    def pad3(x, lp):
-        return jnp.pad(x, ((0, 0), (0, lp - x.shape[1]), (0, dim_p - x.shape[2])))
+    def pad3(x, lp, dp):
+        return jnp.pad(x, ((0, 0), (0, lp - x.shape[1]), (0, dp - x.shape[2])))
 
-    qf, kf, vf = pad3(qf, q_len_p), pad3(kf, kv_len_p), pad3(vf, kv_len_p)
+    qf, kf, vf = pad3(qf, q_len_p, dim_p), pad3(kf, kv_len_p, dim_p), pad3(vf, kv_len_p, dim_v_p)
 
     shared_bias = False
     if bias is not None:
@@ -278,7 +294,7 @@ def _flash_forward(
     in_specs = [
         pl.BlockSpec((block_b, block_q, dim_p), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((block_b, block_kv, dim_p), kv_index),
-        pl.BlockSpec((block_b, block_kv, dim_p), kv_index),
+        pl.BlockSpec((block_b, block_kv, dim_v_p), kv_index),
     ]
     args = [qf, kf, vf]
     if bias is not None:
@@ -307,9 +323,9 @@ def _flash_forward(
     )
 
     out_specs = [
-        pl.BlockSpec((block_b, block_q, dim_p), lambda b, i, j: (b, i, 0))
+        pl.BlockSpec((block_b, block_q, dim_v_p), lambda b, i, j: (b, i, 0))
     ]
-    out_shape = [jax.ShapeDtypeStruct((batch * heads, q_len_p, dim_p), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((batch * heads, q_len_p, dim_v_p), q.dtype)]
     if with_lse:
         out_specs.append(
             pl.BlockSpec((block_b, block_q, 128), lambda b, i, j: (b, i, 0))
@@ -327,13 +343,13 @@ def _flash_forward(
         scratch_shapes=[
             pltpu.VMEM((block_b, block_q, 128), jnp.float32),
             pltpu.VMEM((block_b, block_q, 128), jnp.float32),
-            pltpu.VMEM((block_b, block_q, dim_p), jnp.float32),
+            pltpu.VMEM((block_b, block_q, dim_v_p), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
 
-    out = outs[0][:, :q_len, :dim]
-    out = out.reshape(batch, heads, q_len, dim)
+    out = outs[0][:, :q_len, :dim_v]
+    out = out.reshape(batch, heads, q_len, dim_v)
     out = jnp.transpose(out, (0, 2, 1, 3))
     if with_lse:
         return out, outs[1]
@@ -382,10 +398,15 @@ class _BwdGeom(NamedTuple):
     block_kv: int
     q_len_p: int
     kv_len_p: int
+    # The value head (v, dO, dv) where it differs from the query/key head.
+    dim_v: int
+    dim_v_p: int
 
-    def unprep(self, x: jax.Array, l: int) -> jax.Array:
-        """Padded ``[B·H, L_p, D_p]`` → ``[B, L, H, D]``."""
-        x = x[:, :l, : self.dim].reshape(self.batch, self.heads, l, self.dim)
+    def unprep(self, x: jax.Array, l: int, dim: Optional[int] = None) -> jax.Array:
+        """Padded ``[B·H, L_p, D_p]`` → ``[B, L, H, D]`` (``D`` the
+        query/key head unless ``dim`` says the value head)."""
+        dim = self.dim if dim is None else dim
+        x = x[:, :l, :dim].reshape(self.batch, self.heads, l, dim)
         return jnp.transpose(x, (0, 2, 1, 3))
 
 
@@ -408,8 +429,8 @@ def _bwd_prep(q, k, v, out, g, block_q, block_kv) -> _BwdGeom:
     across one lane tile (same layout as lse, so kernels read both with no
     relayout). Single source for block clamping and padding geometry."""
     batch, q_len, heads, dim = q.shape
-    kv_len = k.shape[1]
-    dim_p = _round_up(dim, 128)
+    kv_len, dim_v = k.shape[1], v.shape[-1]
+    dim_p, dim_v_p = _pad_head(dim), _pad_head(dim_v)
     block_q = min(block_q, _round_up(q_len, 16))
     block_kv = min(block_kv, _round_up(kv_len, 16))
     q_len_p = _round_up(q_len, block_q)
@@ -419,18 +440,18 @@ def _bwd_prep(q, k, v, out, g, block_q, block_kv) -> _BwdGeom:
         b, l, h, d = x.shape
         return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, l, d)
 
-    def pad3(x, lp):
-        return jnp.pad(x, ((0, 0), (0, lp - x.shape[1]), (0, dim_p - x.shape[2])))
+    def pad3(x, lp, dp):
+        return jnp.pad(x, ((0, 0), (0, lp - x.shape[1]), (0, dp - x.shape[2])))
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     delta = jnp.transpose(delta, (0, 2, 1)).reshape(batch * heads, q_len)
     delta = jnp.pad(delta, ((0, 0), (0, q_len_p - q_len)))
     delta = jnp.broadcast_to(delta[:, :, None], delta.shape + (128,))
     return _BwdGeom(
-        qf=pad3(to_bhld(q), q_len_p),
-        kf=pad3(to_bhld(k), kv_len_p),
-        vf=pad3(to_bhld(v), kv_len_p),
-        dof=pad3(to_bhld(g), q_len_p),
+        qf=pad3(to_bhld(q), q_len_p, dim_p),
+        kf=pad3(to_bhld(k), kv_len_p, dim_p),
+        vf=pad3(to_bhld(v), kv_len_p, dim_v_p),
+        dof=pad3(to_bhld(g), q_len_p, dim_v_p),
         delta=delta,
         batch=batch,
         heads=heads,
@@ -442,6 +463,8 @@ def _bwd_prep(q, k, v, out, g, block_q, block_kv) -> _BwdGeom:
         block_kv=block_kv,
         q_len_p=q_len_p,
         kv_len_p=kv_len_p,
+        dim_v=dim_v,
+        dim_v_p=dim_v_p,
     )
 
 
@@ -554,6 +577,7 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
     qf, kf, vf, dof, delta = geom.qf, geom.kf, geom.vf, geom.dof, geom.delta
     q_len, kv_len = geom.q_len, geom.kv_len
     dim_p, block_q, block_kv = geom.dim_p, geom.block_q, geom.block_kv
+    dim_v_p = geom.dim_v_p
     q_len_p, kv_len_p = geom.q_len_p, geom.kv_len_p
 
     num_q_blocks = q_len_p // block_q
@@ -569,8 +593,11 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
     else:
         kv_index = lambda b, i, j: (b, j, 0)
         q_index2 = lambda b, j, i: (b, i, 0)
+    # q, k, dq, dk at the query/key head; v, dO, dv at the value head.
     qspec = pl.BlockSpec((block_b, block_q, dim_p), lambda b, i, j: (b, i, 0))
+    dospec = pl.BlockSpec((block_b, block_q, dim_v_p), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((block_b, block_kv, dim_p), kv_index)
+    vspec = pl.BlockSpec((block_b, block_kv, dim_v_p), kv_index)
     rowq = pl.BlockSpec((block_b, block_q, 128), lambda b, i, j: (b, i, 0))
 
     dq = pl.pallas_call(
@@ -586,7 +613,7 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
             causal=causal,
         ),
         grid=(bh // block_b, num_q_blocks, num_kv_blocks),
-        in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
+        in_specs=[qspec, kspec, vspec, dospec, rowq, rowq],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, q_len_p, dim_p), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_b, block_q, dim_p), jnp.float32)],
@@ -596,7 +623,9 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
     # q-innermost grid for dk/dv: block index 1 is the kv block, index 2
     # sweeps q blocks into the accumulators.
     qspec2 = pl.BlockSpec((block_b, block_q, dim_p), q_index2)
+    dospec2 = pl.BlockSpec((block_b, block_q, dim_v_p), q_index2)
     kspec2 = pl.BlockSpec((block_b, block_kv, dim_p), lambda b, j, i: (b, j, 0))
+    vspec2 = pl.BlockSpec((block_b, block_kv, dim_v_p), lambda b, j, i: (b, j, 0))
     rowq2 = pl.BlockSpec((block_b, block_q, 128), q_index2)
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -610,20 +639,24 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
             causal=causal,
         ),
         grid=(bh // block_b, num_kv_blocks, num_q_blocks),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2],
-        out_specs=[kspec2, kspec2],
+        in_specs=[qspec2, kspec2, vspec2, dospec2, rowq2, rowq2],
+        out_specs=[kspec2, vspec2],
         out_shape=[
             jax.ShapeDtypeStruct((bh, kv_len_p, dim_p), k.dtype),
-            jax.ShapeDtypeStruct((bh, kv_len_p, dim_p), v.dtype),
+            jax.ShapeDtypeStruct((bh, kv_len_p, dim_v_p), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_b, block_kv, dim_p), jnp.float32),
-            pltpu.VMEM((block_b, block_kv, dim_p), jnp.float32),
+            pltpu.VMEM((block_b, block_kv, dim_v_p), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
 
-    return geom.unprep(dq, q_len), geom.unprep(dk, kv_len), geom.unprep(dv, kv_len)
+    return (
+        geom.unprep(dq, q_len),
+        geom.unprep(dk, kv_len),
+        geom.unprep(dv, kv_len, geom.dim_v),
+    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
@@ -732,7 +765,10 @@ def flash_attention(
 
     Args:
       query: ``[B, q_len, heads, head_dim]``.
-      key, value: ``[B, kv_len, heads, head_dim]``.
+      key: ``[B, kv_len, heads, head_dim]``.
+      value: ``[B, kv_len, heads, value_dim]``; ``value_dim`` may differ
+        from ``head_dim`` (latent attention: 192 / 128) on the unbiased
+        path, and the output then has the value's head size.
       bias: optional additive logits bias, broadcastable to
         ``[B, heads, q_len, kv_len]`` (e.g. BoTNet relative-position logits).
       scale: logit scale, default ``head_dim ** -0.5``.
@@ -748,10 +784,12 @@ def flash_attention(
         ``block_b`` tiles of everything.
 
     Returns:
-      ``[B, q_len, heads, head_dim]`` in the query dtype.
+      ``[B, q_len, heads, value_dim]`` in the query dtype.
     """
     if query.ndim != 4:
         raise ValueError(f"expected [B, L, H, D] inputs, got {query.shape}")
+    if key.shape[-1] != query.shape[-1]:
+        raise ValueError(f"query and key heads differ: {query.shape[-1]} != {key.shape[-1]}")
     if scale is None:
         scale = query.shape[-1] ** -0.5
     if bias is not None and bias.ndim != 4:

@@ -9,7 +9,9 @@ Frequencies here follow the standard RoPE formulation
 Two pairings of the lanes: the vision zoo's :func:`rotate_every_two` pairs
 lane ``2i`` with ``2i + 1``; the decoder family's :func:`rotate_half` pairs
 lane ``i`` with ``i + dim/2`` (the layout of the public language-model
-checkpoints), with tables from :func:`half_split_tables`.
+checkpoints), with tables from :func:`half_split_tables`. Latent attention
+rotates adjacent pairs of a slice of the head at its own base, in float32,
+on a key that may have no head axis: :func:`apply_rotary_interleaved`.
 """
 
 from __future__ import annotations
@@ -85,3 +87,16 @@ def apply_rotary_half(x: jax.Array, sincos) -> jax.Array:
     sin, cos = sin[None, :, None, :], cos[None, :, None, :]
     x32 = x.astype(jnp.float32)
     return (x32 * cos + rotate_half(x32) * sin).astype(x.dtype)
+
+
+def apply_rotary_interleaved(x: jax.Array, base: float) -> jax.Array:
+    """RoPE with the adjacent-pairs pairing ``(2i, 2i + 1)`` at ``base`` on the
+    whole last axis of ``x: [B, seq_len, dim]`` or ``[B, seq_len, heads, dim]``
+    (pass the slice of the head that rotates). Float32 inside, cast back, for
+    :func:`apply_rotary_half`'s reason."""
+    freqs = jnp.repeat(_angles(x.shape[1], x.shape[-1], base), 2, axis=-1)  # [L, dim]
+    sin, cos = jnp.sin(freqs), jnp.cos(freqs)
+    if x.ndim == 4:
+        sin, cos = sin[:, None, :], cos[:, None, :]
+    x32 = x.astype(jnp.float32)
+    return (x32 * cos + rotate_every_two(x32) * sin).astype(x.dtype)

@@ -9,7 +9,9 @@ registry entry names its task (``sav_tpu.models.registry.model_task``):
 - ``image``: uint8 or float images ``[B, S, S, 3]`` with integer labels,
   label-smoothed (and mixed) cross-entropy, top-1 / top-5;
 - ``tokens``: int32 ids ``[B, S + 1]``, next-token loss at every position
-  of a looped language model (:func:`looped_lm_loss`).
+  of a looped language model (:func:`looped_lm_loss`);
+- ``tokens_mtp``: the same batches, next-token loss plus a multi-token-
+  prediction module's (:func:`mtp_lm_loss`).
 """
 
 from __future__ import annotations
@@ -189,17 +191,14 @@ def looped_lm_loss(ce: jax.Array, exit_logit: jax.Array, beta: float):
     return jnp.mean(per_position), p, entropy
 
 
-class LoopedTokenPrediction:
+class TokenPrediction:
     """Batches ``{"tokens": int32 [B, S + 1]}``: the model reads the first
-    ``S`` ids and predicts ids 1..S, every pass of its loop at every
-    position. The model (``sav_tpu/models/ouro.py``) returns each pass's
-    cross-entropy and exit-gate logit; the loss is :func:`looped_lm_loss`.
-    Documents are concatenated without a boundary mask."""
+    ``S`` ids and is given ids 1..S as ``targets``; it scores every position
+    itself and returns cross-entropies, never the full logits. Documents are
+    concatenated without a boundary mask. What the token tasks share; a
+    subclass adds ``loss``, ``train_metrics`` and ``eval_sums``."""
 
-    # H(p)'s weight (arXiv:2510.25741's pre-training objective; the value is
-    # assumed: benchmark/configs/ouro_2.6b.json).
-    entropy_weight = 0.1
-    # Any length traces the same parameters: the family has no position table.
+    # Any length traces the same parameters: the families have no position table.
     dummy_length = 16
 
     def __init__(self, config, compute_dtype):
@@ -228,6 +227,24 @@ class LoopedTokenPrediction:
     def apply_kwargs(self, targets) -> dict:
         return {"targets": targets}
 
+    @staticmethod
+    def _weighted_sums(per_position: jax.Array, batch: dict) -> dict:
+        valid = batch.get("valid")
+        if valid is None:
+            valid = jnp.ones((per_position.shape[0],), jnp.float32)
+        weights = jnp.broadcast_to(valid[:, None], per_position.shape)
+        return {"loss_sum": jnp.sum(per_position * weights), "count": jnp.sum(weights)}
+
+
+class LoopedTokenPrediction(TokenPrediction):
+    """Next-token loss of every pass of a looped language model at every
+    position. The model (``sav_tpu/models/ouro.py``) returns each pass's
+    cross-entropy and exit-gate logit; the loss is :func:`looped_lm_loss`."""
+
+    # H(p)'s weight (arXiv:2510.25741's pre-training objective; the value is
+    # assumed: benchmark/configs/ouro_2.6b.json).
+    entropy_weight = 0.1
+
     def loss(self, outputs: dict, targets) -> jax.Array:
         del targets  # the model has already scored every position
         return looped_lm_loss(outputs["ce"], outputs["exit_logit"], self.entropy_weight)[0]
@@ -245,15 +262,54 @@ class LoopedTokenPrediction:
 
     def eval_sums(self, outputs: dict, batch: dict) -> dict:
         _, p, _ = looped_lm_loss(outputs["ce"], outputs["exit_logit"], self.entropy_weight)
-        per_position = jnp.sum(p * outputs["ce"], axis=-1)
-        valid = batch.get("valid")
-        if valid is None:
-            valid = jnp.ones((per_position.shape[0],), jnp.float32)
-        weights = jnp.broadcast_to(valid[:, None], per_position.shape)
-        return {"loss_sum": jnp.sum(per_position * weights), "count": jnp.sum(weights)}
+        return self._weighted_sums(jnp.sum(p * outputs["ce"], axis=-1), batch)
 
 
-TASKS = {"image": ImageClassification, "tokens": LoopedTokenPrediction}
+def mtp_lm_loss(ce: jax.Array, ce_mtp: jax.Array, mtp_weight: float):
+    """``mean CE_main + lambda mean CE_mtp`` (arXiv:2412.19437 eq. 25): the
+    main head over all ``S`` positions, the multi-token-prediction module
+    over the ``S - 1`` that have a next-but-one token (the model leaves the
+    last one's term at 0). Returns ``(loss, main, mtp)``."""
+    main = jnp.mean(ce)
+    mtp = jnp.sum(ce_mtp) / (ce_mtp.shape[0] * (ce_mtp.shape[1] - 1))
+    return main + mtp_weight * mtp, main, mtp
+
+
+class MTPTokenPrediction(TokenPrediction):
+    """Next-token loss plus the multi-token-prediction module's, of a decoder
+    with routed experts (``sav_tpu/models/joyai.py``). The model returns
+    ``ce``, ``ce_mtp`` and each sequence's routing counts (all, and those on
+    the experts it holds)."""
+
+    # lambda of the MTP loss (arXiv:2412.19437 section 4.2's first phase;
+    # assumed: benchmark/configs/joyai_llm_flash.json).
+    mtp_weight = 0.3
+
+    def loss(self, outputs: dict, targets) -> jax.Array:
+        del targets  # the model has already scored every position
+        return mtp_lm_loss(outputs["ce"], outputs["ce_mtp"], self.mtp_weight)[0]
+
+    def train_metrics(self, outputs: dict, batch: dict) -> dict:
+        _, main, mtp = mtp_lm_loss(outputs["ce"], outputs["ce_mtp"], self.mtp_weight)
+        load = jnp.sum(outputs["moe_counts"], axis=0)  # [R, E]: the step's routings
+        return {
+            "loss_main": main,
+            "loss_mtp": mtp,
+            "moe_held_share": jnp.sum(outputs["moe_held"]) / jnp.sum(load),
+            "moe_load_max_over_mean": jnp.max(jnp.max(load, axis=-1) / jnp.mean(load, axis=-1)),
+            "moe_bias_abs_max": jnp.max(outputs["moe_bias_abs_max"]),
+            "tokens": jnp.float32(outputs["ce"].size),
+        }
+
+    def eval_sums(self, outputs: dict, batch: dict) -> dict:
+        return self._weighted_sums(outputs["ce"], batch)
+
+
+TASKS = {
+    "image": ImageClassification,
+    "tokens": LoopedTokenPrediction,
+    "tokens_mtp": MTPTokenPrediction,
+}
 
 
 def make_task(name: str, config, compute_dtype):

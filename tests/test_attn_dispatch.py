@@ -171,6 +171,43 @@ def test_checked_in_cache_promotes_only_what_was_measured(shape, backend, source
     assert resolve_attention_backend(*shape, on_tpu=False).backend == "xla"
 
 
+# What the checked-in table holds for the latent attention core (a query/key
+# head of 192 beside a value head of 128): tools/attn_tune.py on the chip.
+LATENT_BLOCKS = {"block_q": 1024, "block_kv": 1024, "block_b": 1}
+
+
+def test_the_latent_cores_entry_is_keyed_by_both_head_sizes_and_dispatches_flash():
+    attn_tuning.set_cache_path(None)
+    key = attn_tuning.shape_key("*", 4096, 4096, 32, 192, "bfloat16", True, 128)
+    assert key == "B*.Lq4096.Lkv4096.H32.D192v128.bfloat16.causal"
+    assert attn_tuning.shape_key(2, 4096, 4096, 16, 128, "bfloat16", True, 128).endswith(".H16.D128.bfloat16.causal")
+    entry = attn_tuning.lookup(2, 4096, 4096, 32, 192, causal=True, value_dim=128)
+    assert entry["backend"] == "pallas" and entry["fwd_bwd_ms"] > entry["fwd_ms"] > 0
+    cell = resolve_attention_backend(2, 4096, 4096, 32, 192, on_tpu=True, causal=True, value_dim=128)
+    assert cell.backend == "pallas" and cell.block_config == LATENT_BLOCKS
+    # The entry is this core's alone: without the mask, at one head size, or
+    # at the other family's heads it is not read.
+    assert attn_tuning.lookup(2, 4096, 4096, 32, 192, causal=False, value_dim=128) is None
+    assert attn_tuning.lookup(2, 4096, 4096, 32, 192, causal=True) is None
+    assert attn_tuning.lookup(2, 4096, 4096, 16, 192, causal=True, value_dim=128) is None
+    assert resolve_attention_backend(2, 4096, 4096, 32, 192, on_tpu=False, causal=True, value_dim=128).backend == "xla"
+    # Two head sizes never resolve to the single-pass kernel, whatever a table says.
+    short = resolve_attention_backend(256, 197, 197, 6, 64, on_tpu=True, value_dim=32)
+    assert short.backend == "xla"
+
+
+@pytest.mark.parametrize("shape, causal, backend, blocks", [
+    ((256, 197, 197, 6, 64), False, "fused", {"block_b": 1}),  # deit_s.train_resident, deit_s.train_dp4's chip
+    ((128, 197, 197, 12, 64), False, "fused", {"block_b": 2}),  # vit_b.train_resident
+    ((2, 4096, 4096, 16, 128), True, "pallas", {"block_q": 1024, "block_kv": 1024, "block_b": 1}),  # ouro
+])
+def test_auto_at_the_accepted_cells_shapes_resolves_as_before(shape, causal, backend, blocks):
+    attn_tuning.set_cache_path(None)
+    for value_dim in (None, shape[-1]):  # a value head of the query's size is no new key
+        d = resolve_attention_backend(*shape, on_tpu=True, causal=causal, value_dim=value_dim)
+        assert d.backend == backend and d.block_config == blocks
+
+
 @pytest.mark.parametrize("num_devices", [2, 4, 8])
 def test_partitioned_program_resolves_as_before_the_fused_entries(num_devices):
     """A Mosaic call cannot be partitioned automatically: in a program over

@@ -158,6 +158,8 @@ KERNEL_CASES = [
     # default blocks and at the tune cache's measured ones.
     ("flash_causal-ouro_4k", "flash_causal", (2, 4096, 16, 128)),
     ("flash_causal_tuned-ouro_4k", "flash_causal_tuned", (2, 4096, 16, 128)),
+    # Latent attention: a query/key head of 192 beside a value head of 128.
+    ("flash_latent_tuned-joyai_4k", "flash_latent_tuned", (2, 4096, 32, 192, 128)),
 ]
 
 
@@ -172,7 +174,7 @@ def _kernel_fn_and_args(kernel, shape, sharding):
     def spec(s, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
 
-    qkv = (spec(shape),) * 3
+    qkv = (spec(shape[:4]),) * 3
     heads, dim = shape[2], shape[3]
     if kernel == "flash":
         return (lambda q, k, v: flash_attention(q, k, v, interpret=False)), qkv
@@ -187,6 +189,16 @@ def _kernel_fn_and_args(kernel, shape, sharding):
         return (
             lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False, **blocks)
         ), qkv
+    if kernel == "flash_latent_tuned":
+        from sav_tpu.ops import attn_tuning
+
+        blocks = attn_tuning.block_config(attn_tuning.lookup(
+            *shape[:2], shape[1], *shape[2:4], causal=True, value_dim=shape[4]
+        ))
+        value = spec(shape[:3] + (shape[4],))
+        return (
+            lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False, **blocks)
+        ), qkv[:2] + (value,)
     if kernel == "fused":
         return (lambda q, k, v: fused_attention(q, k, v, interpret=False)), qkv
     if kernel == "talking_heads":

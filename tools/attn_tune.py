@@ -89,6 +89,8 @@ def grad_wrap(fn, cot):
         out, vjp = jax.vjp(fn, q, k, v)
         g = (cot + jnp.sum(out.astype(jnp.float32)) * 1e-30).astype(out.dtype)
         dq, dk, dv = vjp(g)
+        if dv.shape != dq.shape:  # a value head of its own size
+            return jnp.concatenate([dq + dk, dv], axis=-1)
         return dq + dk + dv
 
     return run
@@ -99,22 +101,25 @@ def _parse_shape(spec: str):
     if len(parts) == 4:
         b, l, h, d = parts
         return b, l, l, h, d
-    if len(parts) == 5:
+    if len(parts) in (5, 6):  # the sixth is the value head where it differs
         return tuple(parts)
-    raise ValueError(f"shape must be B,L,H,D or B,Lq,Lkv,H,D — got {spec!r}")
+    raise ValueError(f"shape must be B,L,H,D, B,Lq,Lkv,H,D or B,Lq,Lkv,H,D,Dv — got {spec!r}")
 
 
-def variant_specs(b, lq, lkv, h, d, *, blocks, block_bs, backends, itemsize):
+def variant_specs(b, lq, lkv, h, d, *, blocks, block_bs, backends, itemsize,
+                  causal=False, one_head_size=True):
     """Yield (name, backend, config, builder) for every candidate; builder
     returns the (q, k, v) -> out callable. Configs the VMEM estimator
     rules out are yielded with builder=None (recorded infeasible for free).
+    The fused kernel has no causal arm and one head size: it is left out
+    of a sweep that asks for either.
     """
     bh = b * h
     if "xla" in backends:
         yield "xla", "xla", None, lambda: (
-            lambda q, k, v: att.xla_attention(q, k, v)
+            lambda q, k, v: att.xla_attention(q, k, v, causal=causal)
         )
-    if "fused" in backends:
+    if "fused" in backends and not causal and one_head_size:
         for bq, _ in blocks:
             for bb in block_bs:
                 if b % bb != 0:  # the fused kernel's cells hold batch elements
@@ -144,7 +149,7 @@ def variant_specs(b, lq, lkv, h, d, *, blocks, block_bs, backends, itemsize):
                 name = f"pallas bq={bq} bkv={bkv} bb={bb}"
                 yield name, "pallas", cfg, (
                     lambda bq=bq, bkv=bkv: lambda q, k, v: flmod.flash_attention(
-                        q, k, v, block_q=bq, block_kv=bkv
+                        q, k, v, block_q=bq, block_kv=bkv, causal=causal
                     )
                 )
 
@@ -173,19 +178,21 @@ class _pin_flash_block_b:
 
 
 def sweep_shape(shape, *, blocks, block_bs, backends, iters, rounds,
-                dtype=jnp.bfloat16, bwd=True, log=print):
+                dtype=jnp.bfloat16, bwd=True, log=print, causal=False):
     """Measure one shape; returns (results, infeasible) lists."""
-    b, lq, lkv, h, d = shape
+    b, lq, lkv, h, d = shape[:5]
+    dv = shape[5] if len(shape) == 6 else d
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((b, lq, h, d)), dtype=dtype)
     k = jnp.asarray(rng.standard_normal((b, lkv, h, d)), dtype=dtype)
-    v = jnp.asarray(rng.standard_normal((b, lkv, h, d)), dtype=dtype)
-    cot = jnp.asarray(rng.standard_normal((b, lq, h, d)), dtype=jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, lkv, h, dv)), dtype=dtype)
+    cot = jnp.asarray(rng.standard_normal((b, lq, h, dv)), dtype=jnp.float32)
 
     results, infeasible, loops = [], [], {}
     for name, backend, cfg, build in variant_specs(
         b, lq, lkv, h, d, blocks=blocks, block_bs=block_bs,
         backends=backends, itemsize=jnp.dtype(dtype).itemsize,
+        causal=causal, one_head_size=dv == d,
     ):
         if build is None:
             infeasible.append({
@@ -292,6 +299,8 @@ def main(argv=None):
     p.add_argument("--fwd-only", action="store_true",
                    help="skip the fwd+bwd loops (winner then picked on fwd)")
     p.add_argument("--dtype", default="bfloat16")
+    p.add_argument("--causal", action="store_true",
+                   help="sweep the causal core (its entries are keyed .causal)")
     p.add_argument(
         "--out", default=".tpu_results/attn_tune_cache.json",
         help="shape→config cache to write (the dispatcher-consumable JSON)",
@@ -325,15 +334,16 @@ def main(argv=None):
     entries, infeasible_all = {}, {}
     for spec in args.shapes.split(";"):
         shape = _parse_shape(spec)
-        b, lq, lkv, h, d = shape
-        print(f"== shape B={b} Lq={lq} Lkv={lkv} H={h} D={d} ({dtype.name})",
-              flush=True)
+        b, lq, lkv, h, d = shape[:5]
+        dv = shape[5] if len(shape) == 6 else d
+        print(f"== shape B={b} Lq={lq} Lkv={lkv} H={h} D={d} Dv={dv} causal={args.causal} "
+              f"({dtype.name})", flush=True)
         results, infeasible = sweep_shape(
             shape, blocks=blocks, block_bs=block_bs, backends=backends,
             iters=args.iters, rounds=args.rounds, dtype=dtype,
-            bwd=not args.fwd_only,
+            bwd=not args.fwd_only, causal=args.causal,
         )
-        key = attn_tuning.shape_key(b, lq, lkv, h, d, dtype)
+        key = attn_tuning.shape_key(b, lq, lkv, h, d, dtype, args.causal, dv)
         if infeasible:
             infeasible_all[key] = infeasible
         winner = pick_winner(results, bwd=not args.fwd_only)
@@ -347,7 +357,7 @@ def main(argv=None):
         )
         entries[key] = winner_entry(winner, src)
         if args.star_batch:
-            entries[attn_tuning.shape_key("*", lq, lkv, h, d, dtype)] = (
+            entries[attn_tuning.shape_key("*", lq, lkv, h, d, dtype, args.causal, dv)] = (
                 winner_entry(winner, src + f" at B={b}")
             )
         print(f"  -> winner: {winner['name']}", flush=True)

@@ -54,6 +54,7 @@ from sav_tpu.models.layers import (
     RMSNorm,
     SparseMoEBlock,
 )
+from sav_tpu.models.layers.moe import rows_over_bound
 from sav_tpu.models.ouro import LMHead
 
 Dtype = Any
@@ -62,8 +63,10 @@ Dtype = Any
 # (tags in the layers and in the flash kernel's forward rule); everything
 # else is computed again: norms, rotary, SiLU and the gates' products, the
 # down projections, and the routed experts' gather of rows with the grouped
-# matmuls that read them (268 MB of rows a routed layer: keeping them would
-# pass 15.0 GB). Chosen on a v5e at the published widths, 2 x 4,096 tokens and
+# matmuls that read them (268 MB of rows a routed layer when this was chosen:
+# keeping them would have passed 15.0 GB; 34 MB since the buffers are
+# bounded, which reopens the choice: ROADMAP S5d). Chosen on a v5e at the
+# published widths, 2 x 4,096 tokens and
 # 16 of 256 experts held: 406.4 ms a step in 13.55 GB, against 419.0 ms in
 # 12.78 GB with the first three alone and 471.1 ms in 11.54 GB with nothing
 # kept (PERF.md section 6, PR 30).
@@ -139,7 +142,10 @@ class JoyAILM(nn.Module):
       ``{"ce": [B, S], "ce_mtp": [B, S]}`` (the last ``ce_mtp`` is 0),
       ``"moe_counts" [B, R, E]`` (each sequence's routings by routed layer,
       the module's last, and expert), ``"moe_held" [B]`` (those of them on
-      the experts held) and ``"moe_bias_abs_max" [B]``.
+      the experts held), ``"moe_rows_over_bound" [B, R]`` (each routed
+      layer's rows on the experts held over the rows its buffers hold, the
+      same in every row: above 1 it took the overflow pass) and
+      ``"moe_bias_abs_max" [B]``.
     """
 
     num_classes: int  # the vocabulary held here
@@ -245,11 +251,14 @@ class JoyAILM(nn.Module):
             select_bias.value = select_bias.value + self.bias_update_rate * step
         bias_max = jnp.max(jnp.abs(select_bias.value))
         offset, held = self.experts_held or (0, self.num_experts)
+        on_held = counts[..., offset:offset + held]
+        over_bound = rows_over_bound(counts, tokens.size * self.top_k, self.experts_held)
         return {
             "ce": main,
             "ce_mtp": ce_mtp,
             "moe_counts": counts,
-            "moe_held": jnp.sum(counts[..., offset:offset + held], axis=(1, 2)),
+            "moe_held": jnp.sum(on_held, axis=(1, 2)),
+            "moe_rows_over_bound": jnp.broadcast_to(over_bound, counts.shape[:2]),
             "moe_bias_abs_max": jnp.broadcast_to(bias_max, tokens.shape[:1]),
         }
 

@@ -15,13 +15,16 @@ to pick up.
 
 :class:`SparseMoEBlock` is the language family's expert layer: dropless,
 sigmoid-scored, with a shared expert, told which experts of the router's
-range it holds. It sorts the routings that land on them, runs grouped
-matmuls over the ragged groups and gathers the weighted results back; it
-builds nothing of size tokens x experts beyond the ``[T, E]`` scores.
+range it holds. It sorts the routings, runs grouped matmuls over the ragged
+groups of those that land on the held experts, in buffers bounded by twice
+their expected number (an exact overflow pass takes the rest, a buffer's
+worth at a time), and sums the weighted results by token; it builds nothing
+of size tokens x experts beyond the ``[T, E]`` scores.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Optional
 
@@ -168,47 +171,42 @@ class MoEFFBlock(nn.Module):
 
 
 @jax.custom_vjp
-def _rows_of_tokens(x, order, inverse, held):
-    """``x [T, D]`` -> one row a routing in sorted order, ``[T k, D]``: row
-    ``r`` is the token of routing ``order[r]`` (routings count token-major,
-    ``k`` a token). The transpose is a gather too (``inverse`` is
-    ``order``'s inverse permutation), never a scatter-add; rows of routings
-    that are not ``held`` ``[T, k]`` give nothing back."""
-    del inverse, held
-    return jnp.take(x, order // (order.shape[0] // x.shape[0]), axis=0)
+def _rows_of_tokens(x, token, live):
+    """``x [T, D]`` -> ``[C, D]``: row ``r`` is token ``token[r]``'s where
+    ``live[r]``, zeros elsewhere. Its transpose is :func:`_tokens_of_rows`."""
+    return jnp.where(live[:, None], jnp.take(x, token, axis=0), 0)
 
 
-def _rows_of_tokens_fwd(x, order, inverse, held):
-    return _rows_of_tokens(x, order, inverse, held), (order, inverse, held, x.shape[0])
+def _rows_of_tokens_fwd(x, token, live):
+    return _rows_of_tokens(x, token, live), (token, live, x.shape[0])
 
 
 def _rows_of_tokens_bwd(res, g):
-    order, inverse, held, tokens = res
-    by_slot = jnp.take(g, inverse, axis=0).reshape(tokens, -1, g.shape[-1])
-    by_slot = jnp.where(held[..., None], by_slot.astype(jnp.float32), 0.0)
-    return jnp.sum(by_slot, axis=1).astype(g.dtype), None, None, None
+    token, live, tokens = res
+    return _tokens_of_rows(g.astype(jnp.float32), token, live, tokens).astype(g.dtype), None, None
 
 
 _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
-@jax.custom_vjp
-def _rows_by_slot(rows, order, inverse):
-    """Sorted rows ``[T k, D]`` back in routing order (the inverse of the
-    sort); its transpose is the sort's gather."""
-    del order
-    return jnp.take(rows, inverse, axis=0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tokens_of_rows(rows, token, live, tokens):
+    """``rows [C, D]`` float32 -> ``[tokens, D]``: the sum of the ``live`` rows
+    of each token (what stands in the others is not read: they are handed an
+    index past the end, which a scatter drops). Its transpose is
+    :func:`_rows_of_tokens`."""
+    return jax.ops.segment_sum(rows, jnp.where(live, token, tokens), num_segments=tokens)
 
 
-def _rows_by_slot_fwd(rows, order, inverse):
-    return _rows_by_slot(rows, order, inverse), (order,)
+def _tokens_of_rows_fwd(rows, token, live, tokens):
+    return _tokens_of_rows(rows, token, live, tokens), (token, live)
 
 
-def _rows_by_slot_bwd(res, g):
-    return jnp.take(g, res[0], axis=0), None, None
+def _tokens_of_rows_bwd(tokens, res, g):
+    return _rows_of_tokens(g, *res), None, None
 
 
-_rows_by_slot.defvjp(_rows_by_slot_fwd, _rows_by_slot_bwd)
+_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
 
 
 def _fake_int8(a: jax.Array, contract_axes) -> jax.Array:
@@ -223,6 +221,37 @@ def _fake_int8(a: jax.Array, contract_axes) -> jax.Array:
 # on a v5e at [65,536, 2,048] x [16, 2,048, 768] with about 256 rows a group
 # (tools/moe_micro.py; PERF.md section 6).
 GMM_TILING = (256, 1024, 768)
+
+# Rows the routed buffers hold, over the rows uniform routing would send to
+# the experts held. The seeded layers of the published widths hold 0.78 to
+# 1.19 of that expectation (the fullest seen 4,857 rows of 4,096; PERF.md
+# section 5), so twice it holds them all; routing that leans onto the held
+# experts (the benchmark cell's does within ten updates: up to 6 times the
+# bound in its fullest layer) pays one more pass for every further buffer's
+# worth of rows, so the factor trades rows moved in every step for passes
+# taken in some. Read in that cell on a v5e at 1, 2, 4 and 8 (PERF.md
+# section 6, PR 31): 5.81, 5.84, 5.71 and 5.25 sequences/s, the step's
+# memory 14.53, 14.54, 14.84 and 15.23 GB.
+ROWS_OVER_EXPECTED = 2
+
+
+def routed_row_bound(routings: int, held: int, experts: int) -> int:
+    """Rows of the routed buffers for ``routings`` (tokens x top_k) of which
+    ``held`` of ``experts`` experts are here: ``ROWS_OVER_EXPECTED`` times the
+    expected rows, whole tiles of the grouped matmul, never more than all of
+    them (which is what holding every expert gives)."""
+    tile = GMM_TILING[0]
+    return min(routings, tile * -(-ROWS_OVER_EXPECTED * routings * held // (experts * tile)))
+
+
+def rows_over_bound(counts: jax.Array, routings: int, experts_held) -> jax.Array:
+    """``counts [B, ..., E]`` (what :class:`SparseMoEBlock` returns, of layer
+    applications of ``routings`` each) -> ``[...]``: the rows that landed on
+    the experts held over the rows the routed buffers hold. Past 1 the
+    overflow pass ran."""
+    offset, held = experts_held or (0, counts.shape[-1])
+    bound = routed_row_bound(routings, held, counts.shape[-1])
+    return jnp.sum(counts[..., offset:offset + held], axis=(0, -1)) / bound
 
 
 def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array) -> jax.Array:
@@ -243,48 +272,27 @@ def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array) 
     )
 
 
-class _ExpertGateUp(nn.Module):
-    """The routed experts' input matmuls, ``silu(rows W_gate[e]) * rows
-    W_up[e]``: one leaf each with a leading axis of the experts held."""
+class _StackedKernels(nn.Module):
+    """Leaves with a leading axis of the experts held, as the grouped matmuls
+    read them: in the compute dtype, on the int8 grid of their channel where
+    ``quant``."""
 
-    held: int
-    hidden_ch: int
+    names: tuple
+    shape: tuple
     quant: Optional[str]
     dtype: Dtype
 
     @nn.compact
-    def __call__(self, rows: jax.Array, group_sizes: jax.Array) -> jax.Array:
-        shape = (self.held, rows.shape[-1], self.hidden_ch)
+    def __call__(self):
         init = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=1, out_axis=2)
-        out = []
-        for name in ("gate", "up"):
-            kernel = self.param(f"{name}_experts_w1", init, shape).astype(self.dtype)
-            if self.quant:
-                kernel = _fake_int8(kernel, (1,))
-            out.append(grouped_matmul(rows, kernel, group_sizes))
-        return nn.silu(out[0]) * out[1]
-
-
-class _ExpertDown(nn.Module):
-    held: int
-    out_ch: int
-    quant: Optional[str]
-    dtype: Dtype
-
-    @nn.compact
-    def __call__(self, rows: jax.Array, group_sizes: jax.Array) -> jax.Array:
-        init = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=1, out_axis=2)
-        kernel = self.param(
-            "experts_w2", init, (self.held, rows.shape[-1], self.out_ch)
-        ).astype(self.dtype)
-        if self.quant:
-            rows, kernel = _fake_int8(rows, (1,)), _fake_int8(kernel, (1,))
-        return grouped_matmul(rows, kernel, group_sizes)
+        kernels = (self.param(name, init, self.shape).astype(self.dtype) for name in self.names)
+        return tuple(_fake_int8(kernel, (1,)) if self.quant else kernel for kernel in kernels)
 
 
 class _RoutedExperts(nn.Module):
-    """``E_e(x) = W_down[e](silu(W_gate[e] x) * W_up[e] x)`` on sorted rows
-    (GatedFFBlock's formula and scopes: ``fc1``, ``fc2``)."""
+    """The routed experts' three stacked kernels ``(gate, up, down)``, under
+    GatedFFBlock's scopes (``fc1``, ``fc2``); :func:`_expert_ffn` multiplies
+    by them, in the common pass and in the overflow loops alike."""
 
     held: int
     hidden_ch: int
@@ -292,15 +300,104 @@ class _RoutedExperts(nn.Module):
     dtype: Dtype
 
     @nn.compact
-    def __call__(self, rows: jax.Array, group_sizes: jax.Array) -> jax.Array:
-        if self.quant:
-            rows = _fake_int8(rows, (1,))
-        hidden = _ExpertGateUp(self.held, self.hidden_ch, self.quant, self.dtype, name="fc1")(
-            rows, group_sizes
-        )
-        return _ExpertDown(self.held, rows.shape[-1], self.quant, self.dtype, name="fc2")(
-            hidden, group_sizes
-        )
+    def __call__(self, dim: int):
+        gate, up = _StackedKernels(
+            ("gate_experts_w1", "up_experts_w1"), (self.held, dim, self.hidden_ch), self.quant, self.dtype, name="fc1"
+        )()
+        (down,) = _StackedKernels(
+            ("experts_w2",), (self.held, self.hidden_ch, dim), self.quant, self.dtype, name="fc2"
+        )()
+        return gate, up, down
+
+
+def _expert_ffn(rows, kernels, group_sizes, quant):
+    """``E_e(x) = W_down[e](silu(W_gate[e] x) * W_up[e] x)`` on sorted rows, in
+    groups of ``group_sizes`` (GatedFFBlock's formula and scopes)."""
+    gate, up, down = kernels
+    if quant:
+        rows = _fake_int8(rows, (1,))
+    with jax.named_scope("fc1"):
+        hidden = nn.silu(grouped_matmul(rows, gate, group_sizes)) * grouped_matmul(rows, up, group_sizes)
+    with jax.named_scope("fc2"):
+        if quant:
+            hidden = _fake_int8(hidden, (1,))
+        return grouped_matmul(hidden, down, group_sizes)
+
+
+def _sorted_chunk(order, group_sizes, index, rows):
+    """Sorted positions ``[index rows, (index + 1) rows)`` of ``order``: the
+    routings there, which of them land on a held expert (the first
+    ``sum(group_sizes)`` positions do) and how many of each held expert's."""
+    start = index * rows
+    routing = jax.lax.dynamic_slice_in_dim(order, start, rows)
+    live = start + jnp.arange(rows, dtype=jnp.int32) < jnp.sum(group_sizes)
+    ends = jnp.clip(jnp.cumsum(group_sizes) - start, 0, rows)
+    return routing, live, jnp.diff(ends, prepend=0)
+
+
+def _routed_pass(quant, k, rows, index, x, kernels, weights, order, group_sizes):
+    """``[T, D]`` float32: what the sorted routings ``[index rows, (index + 1)
+    rows)`` add to their tokens (scopes ``dispatch``, ``experts``,
+    ``combine``). Their tokens' rows gathered, the held experts on their
+    ragged groups, the weighted results summed by token. Rows past the last
+    group are written by nobody: masked before the multiply (zero times what
+    stands there is not zero)."""
+    with jax.named_scope("dispatch"):
+        routing, live, sizes = _sorted_chunk(order, group_sizes, index, rows)
+        token = routing // k
+        taken = _rows_of_tokens(x, token, live)
+    with jax.named_scope("experts"):
+        out = _expert_ffn(taken, kernels, sizes, quant)
+    with jax.named_scope("combine"):
+        weighted = jnp.where(live[:, None], out.astype(jnp.float32), 0.0) * jnp.take(weights, routing)[:, None]
+        return _tokens_of_rows(weighted, token, live, x.shape[0])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
+def _overflow_pass(quant, k, rows, layer, chunks, x, kernels, weights, order, group_sizes):
+    """``[T, D]`` float32: what passes ``1 .. chunks - 1`` over the sorted
+    routings add (pass 0 is the common one), one :func:`_routed_pass` at a
+    time in a loop of as many trips as there are such passes: none where the
+    held experts' rows fit the common pass. The backward pass is a loop of
+    the same trips over the same operands, so what is not taken costs
+    nothing in either direction but the zeros its cotangents start from.
+    Both loops are jitted and inlined: a model's layers of one shape trace
+    them once, and each call's operations keep its own scope. A loop puts
+    ``while/body`` between its caller's scope and its body's, so the body
+    opens the layer's own label ``layer`` again: a reader of a trace finds
+    ``<layer>/dispatch|experts|combine`` in a trip as in the common pass."""
+    def add(carry):
+        index, y = carry
+        with jax.named_scope(layer):
+            added = _routed_pass(quant, k, rows, index, x, kernels, weights, order, group_sizes)
+        return index + 1, y + added
+
+    return jax.lax.while_loop(lambda c: c[0] < chunks, add, (1, jnp.zeros(x.shape, jnp.float32)))[1]
+
+
+def _overflow_pass_fwd(quant, k, rows, layer, *operands):
+    return _overflow_pass(quant, k, rows, layer, *operands), operands
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
+def _overflow_pass_bwd(quant, k, rows, layer, res, g):
+    chunks, x, kernels, weights, order, group_sizes = res
+
+    def add(carry):
+        index, grads = carry
+        with jax.named_scope(layer):
+            added = jax.vjp(
+                lambda *a: _routed_pass(quant, k, rows, index, *a, order, group_sizes), x, kernels, weights
+            )[1](g)
+        return index + 1, jax.tree.map(jnp.add, grads, added)
+
+    zeros = jax.tree.map(jnp.zeros_like, (x, kernels, weights))
+    grads = jax.lax.while_loop(lambda c: c[0] < chunks, add, (1, zeros))[1]
+    return (None, *grads, None, None)
+
+
+_overflow_pass.defvjp(_overflow_pass_fwd, _overflow_pass_bwd)
 
 
 class _Router(nn.Module):
@@ -337,14 +434,21 @@ class SparseMoEBlock(nn.Module):
     layer; ``None`` holds all): the router, the counts and the balance loss
     stay ``num_experts`` wide, the routed leaves have a leading axis of
     ``count``, and what the absent experts would add is left out. No token
-    is dropped and nothing has a capacity.
+    is dropped at any routing: rows beyond the bound take the overflow pass.
 
     The path (scopes ``route``, ``dispatch``, ``experts``, ``combine``,
-    ``shared``): one stable sort of the ``T k`` routings by (expert, sequence)
-    with the held experts first; the rows of the sorted routings gathered;
-    grouped matmuls over the held experts' ragged groups (rows past them are
-    not read back); the results gathered back by routing and summed by token
-    with their weights. The largest tensors are ``[T k, D]``.
+    ``overflow``, ``shared``): one stable sort of the ``T k`` routings by
+    (expert, sequence) with the held experts first. The common pass works on
+    the first ``C`` of them (:func:`routed_row_bound`: twice the rows uniform
+    routing sends to the held experts, all ``T k`` where every expert is
+    held): their tokens' rows gathered, grouped matmuls over the held
+    experts' ragged groups cut off at ``C`` (rows past them are not read
+    back), the weighted results summed by token. The largest tensors are
+    ``[C, D]``. Where more than ``C`` routings land on the held experts, and
+    only then, the overflow pass adds the others exactly: the same pass on
+    the next ``C`` sorted routings, in a loop of as many trips as the rows
+    need (none, as a rule); ``train/tasks.py`` logs how often
+    (``moe_overflow_share``).
 
     Returns ``(y, counts, balance)``: ``counts [B, num_experts]`` float32,
     each sequence's routings by expert (no gradient: what the caller steps
@@ -373,38 +477,38 @@ class SparseMoEBlock(nn.Module):
         scores, chosen, weights = _Router(experts, k, self.routed_scale, name="route")(
             x, select_bias
         )
+        x = x.astype(self.dtype)
+        weights = weights.reshape(-1)  # one a routing, token-major like ``order``
 
+        total = batch * seq * k
+        bound = routed_row_bound(total, held, experts)
         with jax.named_scope("dispatch"):
             # Held experts become 0..held-1, so their routings sort first.
             local = (chosen - offset) % experts  # [T, k]
             sequence = jnp.arange(batch * seq, dtype=jnp.int32)[:, None] // seq
             keys = (local * batch + sequence).reshape(-1)
             order = jnp.argsort(keys, stable=True).astype(jnp.int32)
-            inverse = jnp.argsort(order).astype(jnp.int32)
             edges = jnp.searchsorted(
                 jnp.take(keys, order), jnp.arange(experts * batch + 1, dtype=keys.dtype)
             )
             by_local = jnp.diff(edges).reshape(experts, batch)  # [E, B], local order
-            # The sort's result, for a caller's remat policy (three int32 vectors).
-            order, inverse, by_local = (checkpoint_name(t, "moe_order") for t in (order, inverse, by_local))
+            # The sort's result, for a caller's remat policy (two int32 vectors).
+            order, by_local = (checkpoint_name(t, "moe_order") for t in (order, by_local))
             group_sizes = jnp.sum(by_local[:held], axis=1).astype(jnp.int32)
-            is_held = local < held
-            rows = _rows_of_tokens(x.astype(self.dtype), order, inverse, is_held)
 
-        out_rows = _RoutedExperts(held, self.hidden_ch, self.quant, self.dtype, name="experts")(
-            rows, group_sizes
-        )
+        kernels = _RoutedExperts(held, self.hidden_ch, self.quant, self.dtype, name="experts")(dim)
+        # The common pass: the first ``bound`` sorted routings.
+        routed = _routed_pass(self.quant, k, bound, 0, x, kernels, weights, order, group_sizes)
+        if bound < total:  # else every routing is in the buffers
+            with jax.named_scope("overflow"):
+                whole_passes = -(-total // bound) * bound
+                routed = routed + _overflow_pass(
+                    self.quant, k, bound, self.name or type(self).__name__,
+                    -(-jnp.sum(group_sizes) // bound), x, kernels, weights,
+                    jnp.pad(order, (0, whole_passes - total)), group_sizes,
+                )
 
-        with jax.named_scope("combine"):
-            by_slot = _rows_by_slot(out_rows, order, inverse).reshape(batch * seq, k, dim)
-            gate = jnp.where(is_held, weights, 0.0)[..., None]
-            routed = jnp.sum(
-                jnp.where(is_held[..., None], by_slot.astype(jnp.float32), 0.0) * gate, axis=1
-            )
-
-        shared = GatedFFBlock(hidden_ch=self.hidden_ch, quant=self.quant, dtype=self.dtype, name="shared")(
-            x.astype(self.dtype)
-        )
+        shared = GatedFFBlock(hidden_ch=self.hidden_ch, quant=self.quant, dtype=self.dtype, name="shared")(x)
         y = (routed + shared.astype(jnp.float32)).astype(self.dtype)
 
         with jax.named_scope("route"):

@@ -278,8 +278,9 @@ def mtp_lm_loss(ce: jax.Array, ce_mtp: jax.Array, mtp_weight: float):
 class MTPTokenPrediction(TokenPrediction):
     """Next-token loss plus the multi-token-prediction module's, of a decoder
     with routed experts (``sav_tpu/models/joyai.py``). The model returns
-    ``ce``, ``ce_mtp`` and each sequence's routing counts (all, and those on
-    the experts it holds)."""
+    ``ce``, ``ce_mtp``, each sequence's routing counts (all, and those on
+    the experts it holds) and each routed layer's fill of its bounded
+    buffers."""
 
     # lambda of the MTP loss (arXiv:2412.19437 section 4.2's first phase;
     # assumed: benchmark/configs/joyai_llm_flash.json).
@@ -296,6 +297,11 @@ class MTPTokenPrediction(TokenPrediction):
             "loss_main": main,
             "loss_mtp": mtp,
             "moe_held_share": jnp.sum(outputs["moe_held"]) / jnp.sum(load),
+            # Routed layer applications that took the exact overflow pass
+            # (their rows on the held experts passed the buffers' bound), and
+            # the fullest one's rows over that bound.
+            "moe_overflow_share": jnp.mean(outputs["moe_rows_over_bound"][0] > 1.0),
+            "moe_rows_over_bound": jnp.max(outputs["moe_rows_over_bound"]),
             "moe_load_max_over_mean": jnp.max(jnp.max(load, axis=-1) / jnp.mean(load, axis=-1)),
             "moe_bias_abs_max": jnp.max(outputs["moe_bias_abs_max"]),
             "tokens": jnp.float32(outputs["ce"].size),

@@ -206,13 +206,13 @@ def test_the_last_positions_mtp_term_is_exactly_zero_in_value_and_gradient(param
 # ------------------------------------------------- the task through the trainer
 
 
-def _trainer(compute_dtype="float32", **overrides):
+def _trainer(compute_dtype="float32", held=(4, 8), **overrides):
     from sav_tpu.parallel import create_mesh
     from sav_tpu.train import TrainConfig, Trainer
 
     cfg = TrainConfig(
         model_name="joyai_llm_flash", num_classes=VOCAB, compute_dtype=compute_dtype,
-        global_batch_size=BATCH, model_overrides={**SIZES, "remat": True, "experts_held": [4, 8]},
+        global_batch_size=BATCH, model_overrides={**SIZES, "remat": True, "experts_held": list(held)},
         label_smoothing=0.0, warmup_epochs=0, base_lr=3e-4, lr_scaling_divisor=BATCH,
         weight_decay=0.1, aux_loss_weight=ALPHA, log_every_steps=1, fleet=False,
         transpose_images=False, **overrides,
@@ -260,12 +260,43 @@ def test_fit_runs_the_mtp_task_and_three_updates_match_the_reference(held_params
         assert m["tokens"] == BATCH * SEQ
         assert m["loss"] == pytest.approx(m["loss_main"] + LAMBDA * m["loss_mtp"] + ALPHA * m["aux_loss"], rel=1e-5)
         assert 0.3 < m["moe_held_share"] < 0.7 and m["moe_load_max_over_mean"] >= 1.0
+        assert m["moe_overflow_share"] == 0.0 and 0.3 < m["moe_rows_over_bound"] < 0.7
     assert logged[-1]["moe_bias_abs_max"] == pytest.approx(3 * GAMMA, rel=1e-5)
     change = [np.asarray(a) - np.asarray(b) for a, b in zip(jax.tree.leaves(state.params), jax.tree.leaves(held_params))]
     scale = max(float(np.max(np.abs(c))) for c in reference_steps["change"])
     assert scale > 1e-4  # the weights moved
     for got, want in zip(change, reference_steps["change"]):
         assert float(np.max(np.abs(got - want))) <= 2e-3 * scale
+
+
+@pytest.fixture(scope="module")
+def long_sequences():
+    """512 tokens x 4 routings a layer with experts 4..7 of 16 held: the routed
+    buffers hold 1,024 of the 2,048 rows (at ``SEQ`` one tile holds them all)."""
+    held, batch = (4, 4), jax.random.randint(jax.random.PRNGKey(30), (BATCH, 257), 0, VOCAB, jnp.int32)
+    return _trainer(held=held), draw(build(experts_held=held), batch), batch, held
+
+
+@pytest.mark.parametrize("routing", ["seeded", "every_token_onto_the_held_experts"])
+def test_fit_logs_how_often_the_overflow_pass_ran_and_drops_nothing(long_sequences, routing):
+    trainer, held_params, batch, held = long_sequences
+    onto_held = (np.arange(EXPERTS) >= held[0]) & (np.arange(EXPERTS) < sum(held))
+    bias = np.zeros((3, EXPERTS), np.float32) + (10.0 * onto_held if routing != "seeded" else 0.0)
+    state = trainer.init_state(0).replace(params=jax.tree.map(jnp.array, held_params))
+    state = state.replace(batch_stats={"select_bias": jnp.array(bias)})  # the step donates its state
+    _, history = trainer.fit(iter([{"tokens": np.asarray(batch)}]), num_steps=1, state=state)
+    (m,) = [h for h in history if "loss" in h]
+    with jax.default_matmul_precision("highest"):
+        want = float(sum(reference.sequence_loss(held_params, bias, row, model_file(held), BATCH)[0] for row in batch))
+    assert abs(m["loss"] - want) <= TIGHT * want  # exact at either routing: no token is dropped
+    if routing == "seeded":
+        assert m["moe_overflow_share"] == 0.0 and 0.3 < m["moe_rows_over_bound"] < 0.7
+        assert 0.15 < m["moe_held_share"] < 0.35
+    else:
+        # Two routed layers and the module's: all three took the overflow
+        # pass, each with all 2,048 routings on the four held experts.
+        assert m["moe_overflow_share"] == 1.0 and m["moe_rows_over_bound"] == 2.0
+        assert m["moe_held_share"] == 1.0
 
 
 def test_the_selection_bias_after_three_steps_is_the_references(fitted, reference_steps):

@@ -5,8 +5,10 @@ kernel with a value head narrower than the query's (interpret mode), the
 dropless sorted expert path against a loop (uneven routing, an expert with
 no token, every token on the same experts), and one chip's share of an
 expert-parallel layer (sixteen shares and the shared expert once are the uncut
-layer). ``test_joyai.py`` holds the model, the task and the trainer; the toy
-sizes and the tolerance are its."""
+layer; with a share held, the bounded buffers and the overflow pass against
+the loop, the sizes of what the layer builds and the scopes its operations
+lie under). ``test_joyai.py`` holds the model, the task and the trainer; the
+toy sizes and the tolerance are its."""
 
 import gc
 
@@ -22,6 +24,7 @@ from test_joyai import (  # noqa: F401  (fixtures are used by name)
 from benchmark import weights
 from benchmark.reference import joyai as reference
 from sav_tpu.models.layers import LatentSelfAttentionBlock, SparseMoEBlock
+from sav_tpu.models.layers.moe import routed_row_bound
 from sav_tpu.ops.attention import xla_attention
 from sav_tpu.ops.flash_attention import flash_attention
 from sav_tpu.ops.rotary import apply_rotary_interleaved
@@ -210,6 +213,14 @@ def test_holding_every_expert_is_the_uncut_layer(moe_params):
         _moe((8, 12)).init(jax.random.PRNGKey(0), x, bias)
 
 
+def _shapes(jaxpr):
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield var.aval.shape
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _shapes(sub)
+
+
 def test_the_expert_path_builds_nothing_of_tokens_by_experts_by_more(moe_params):
     """No dispatch tensor: beyond the [T, E] scores nothing has both a token
     axis and an expert axis, and the largest value is [T k, D]."""
@@ -217,17 +228,227 @@ def test_the_expert_path_builds_nothing_of_tokens_by_experts_by_more(moe_params)
     jaxpr = jax.make_jaxpr(lambda p, x: _moe().apply({"params": p}, x, jnp.zeros((EXPERTS,))))(moe_params, x)
     tokens, largest = BATCH * SEQ, BATCH * SEQ * TOP_K * 64
 
-    def shapes(jaxpr):
-        for eqn in jaxpr.eqns:
-            for var in eqn.outvars:
-                yield var.aval.shape
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from shapes(sub)
-
-    for shape in shapes(jaxpr.jaxpr):
+    for shape in _shapes(jaxpr.jaxpr):
         assert int(np.prod(shape)) <= largest, shape
         if EXPERTS in shape and len(shape) >= 2 and tokens in shape:
             assert shape == (tokens, EXPERTS), shape
+
+
+# ----------------------------------------- a share held: bound and overflow
+
+HELD = (4, 4)  # experts 4..7 of 16; 256 tokens x 4 routings, so the buffers hold 512 of 1,024 rows
+WIDE_BATCH, WIDE_SEQ = 2, 128
+BOUND = 512
+
+
+def _held_params(held=HELD, seed=5):
+    layer = _moe(held)
+    x = jnp.zeros((WIDE_BATCH, WIDE_SEQ, 64))
+    abstract = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x, jnp.zeros((EXPERTS,))))["params"]
+    return _routed_at_fan_in_scale(weights.draw_params(abstract, seed))
+
+
+def _steered(on_all_held: int, on_one_held: int):
+    """Input ``[2, 128, 64]`` and parameters whose router reads the first two
+    features: ``on_all_held`` tokens (scattered over both sequences) select
+    the four held experts, ``on_one_held`` the first of them beside three
+    absent ones, the others absent experts only: ``4 on_all_held +
+    on_one_held`` rows on the held experts."""
+    tokens = WIDE_BATCH * WIDE_SEQ
+    kind = np.zeros(tokens, np.int32)
+    kind[: on_all_held + on_one_held] = np.r_[np.ones(on_all_held, np.int32), np.full(on_one_held, 2, np.int32)]
+    kind = np.random.default_rng(0).permutation(kind)
+    x = 0.1 * np.asarray(jax.random.normal(jax.random.PRNGKey(7), (tokens, 64)))
+    x[:, 0] = np.where(kind == 1, 3.0, -3.0)
+    x[:, 1] = np.where(kind == 2, 3.0, 0.0)
+    p = _held_params()
+    held = (np.arange(EXPERTS) >= HELD[0]) & (np.arange(EXPERTS) < sum(HELD))
+    router = np.array(p["route"]["kernel"])
+    router[0] = np.where(held, 1.0, -1.0)
+    router[1] = np.where(np.arange(EXPERTS) == HELD[0], 3.0, 0.0)
+    p = {**p, "route": {"kernel": jnp.asarray(router)}}
+    return jnp.asarray(x, jnp.float32).reshape(WIDE_BATCH, WIDE_SEQ, 64), p
+
+
+ONTO_HELD = np.where((np.arange(EXPERTS) >= HELD[0]) & (np.arange(EXPERTS) < sum(HELD)), 10.0, 0.0)
+# name -> (tokens on all four held experts, tokens on one, selection bias, rows on the held experts)
+OVERFLOWS = {
+    "below_the_bound": (100, 3, 0.0, 403),
+    "exactly_the_bound": (128, 0, 0.0, BOUND),
+    "one_row_over": (128, 1, 0.0, BOUND + 1),
+    "every_routing_on_held_experts": (10, 5, ONTO_HELD, 1024),
+}
+
+
+def test_three_chunks_the_last_partly_filled_match_the_loop():
+    """320 tokens x 2 routings of which 2 of 16 experts are held: buffers of
+    one tile (256 rows) for 640 routings, all of them on the held experts:
+    the common pass, a whole chunk of the overflow pass and half a chunk."""
+    held, k, tokens = (4, 2), 2, 320
+    assert routed_row_bound(tokens * k, held[1], EXPERTS) == 256
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, tokens // 2, 64))
+    layer = _moe(held, k=k)
+    abstract = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x, jnp.zeros((EXPERTS,))))["params"]
+    p = _routed_at_fan_in_scale(weights.draw_params(abstract, 5))
+    bias = jnp.asarray(np.where((np.arange(EXPERTS) >= 4) & (np.arange(EXPERTS) < 6), 10.0, 0.0), jnp.float32)
+    g = jax.random.normal(jax.random.PRNGKey(8), x.shape)
+
+    def run(p, x):
+        y, counts, _ = layer.apply({"params": p}, x, bias)
+        return jnp.sum(y * g), counts
+
+    def loop(p, x):
+        return sum(jnp.sum(o[0] * g[b]) for b, o in enumerate(_moe_reference(x, p, bias, held=held, k=k)))
+
+    (value, counts), got = jax.jit(jax.value_and_grad(run, (0, 1), has_aux=True))(p, x)
+    assert float(jnp.sum(counts[:, 4:6])) == tokens * k
+    want_value, want = jax.jit(jax.value_and_grad(loop, (0, 1)))(p, x)
+    assert abs(float(value) - float(want_value)) <= TIGHT * abs(float(want_value))
+    scale = max(float(jnp.max(jnp.abs(w))) for w in jax.tree.leaves(want))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(a - b))) <= TIGHT * scale, jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("case", sorted(OVERFLOWS))
+def test_a_held_share_matches_the_loop_at_and_beyond_the_bound(case):
+    """Nothing is dropped at any routing: rows within the bound go through
+    the common pass, rows beyond it through the overflow pass's chunks, and
+    the sum is the reference's loop in values and in gradients."""
+    on_all, on_one, bias, held_rows = OVERFLOWS[case]
+    assert routed_row_bound(WIDE_BATCH * WIDE_SEQ * TOP_K, HELD[1], EXPERTS) == BOUND
+    x, p = _steered(on_all, on_one)
+    bias = jnp.broadcast_to(jnp.asarray(bias, jnp.float32), (EXPERTS,))
+
+    def run(p, x):
+        return _moe(HELD).apply({"params": p}, x, bias)
+
+    (y, counts, balance), want = run(p, x), _moe_reference(x, p, bias, held=HELD)
+    assert float(jnp.sum(counts[:, HELD[0]:sum(HELD)])) == held_rows
+    for b in range(WIDE_BATCH):
+        assert close(y[b], want[b][0]) and np.array_equal(np.asarray(counts[b]), np.asarray(want[b][1]))
+    assert float(balance) == pytest.approx(float(np.mean([w[2] for w in want])), rel=1e-5)
+    g = jax.random.normal(jax.random.PRNGKey(8), y.shape)
+    got = jax.jit(jax.grad(lambda p, x: jnp.sum(run(p, x)[0] * g) + run(p, x)[2], (0, 1)))(p, x)
+
+    def loop(p, x):
+        out = _moe_reference(x, p, bias, held=HELD)
+        return sum(jnp.sum(o[0] * g[b]) for b, o in enumerate(out)) + sum(o[2] for o in out) / WIDE_BATCH
+
+    want = jax.jit(jax.grad(loop, (0, 1)))(p, x)
+    scale = max(float(jnp.max(jnp.abs(w))) for w in jax.tree.leaves(want))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(a - b))) <= TIGHT * scale, jax.tree_util.keystr(path)
+    # The routed branch is not a rounding error of the comparison.
+    assert float(jnp.max(jnp.abs(got[0]["experts"]["fc2"]["experts_w2"]))) > 1e-2 * scale
+
+
+def test_the_int8_arm_takes_the_overflow_pass_with_the_same_rounding(monkeypatch):
+    """Beyond the bound the int8 arm rounds rows, kernels and hidden rows as
+    within it (per row and per channel), so which pass computes a row does
+    not change it: the layer with one row over the bound against the same
+    layer with buffers that hold every routing."""
+    from sav_tpu.models.layers import moe
+
+    x, p = _steered(128, 1)
+    bias = jnp.zeros((EXPERTS,))
+    rounded = _moe(HELD, quant="int8").apply({"params": p}, x, bias)[0]
+    plain = _moe(HELD).apply({"params": p}, x, bias)[0]
+    assert 1e-4 < float(jnp.max(jnp.abs(plain - rounded))) / float(jnp.max(jnp.abs(plain))) < 0.1
+    monkeypatch.setattr(moe, "ROWS_OVER_EXPECTED", EXPERTS // HELD[1])
+    assert routed_row_bound(WIDE_BATCH * WIDE_SEQ * TOP_K, HELD[1], EXPERTS) == WIDE_BATCH * WIDE_SEQ * TOP_K
+    assert close(rounded, _moe(HELD, quant="int8").apply({"params": p}, x, bias)[0], 1e-5)
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_with_a_sixteenth_held_nothing_is_larger_than_the_bound(what):
+    """One of 16 experts held, 256 tokens x 4 routings: the buffers are one
+    tile of 256 rows, and no value, the overflow pass's loop included, has
+    more than 256 x D elements (the input's own size): none of ``T k x D``,
+    none of ``T x k x D``."""
+    held, dim = (3, 1), 64
+    x = jax.random.normal(jax.random.PRNGKey(7), (WIDE_BATCH, WIDE_SEQ, dim))
+    p = _held_params(held)
+    tokens = WIDE_BATCH * WIDE_SEQ
+    bound = routed_row_bound(tokens * TOP_K, 1, EXPERTS)
+    assert bound == 256 < tokens * TOP_K
+
+    def forward(p, x):
+        return _moe(held).apply({"params": p}, x, jnp.zeros((EXPERTS,)))[0]
+
+    fn = forward if what == "forward" else jax.grad(lambda p, x: jnp.sum(forward(p, x)), (0, 1))
+    jaxpr = jax.make_jaxpr(fn)(p, x)
+    assert "while" in str(jaxpr)
+    largest = 0
+    for shape in _shapes(jaxpr.jaxpr):
+        largest = max(largest, int(np.prod(shape)))
+        assert int(np.prod(shape)) <= bound * dim, shape
+    assert largest == bound * dim == tokens * dim
+
+
+def test_the_common_pass_lies_under_its_scopes_and_in_no_loop_of_the_overflow_pass():
+    """What the benchmark's share and roofline readers match, on the CPU
+    lowering of a held layer's gradient: every operation of the layer lies
+    under ``moe`` directly followed by ``route``, ``dispatch``, ``experts``
+    (its matmuls then under ``fc1`` or ``fc2``), ``combine``, ``overflow`` or
+    ``shared``; the common pass is in no conditional and its only loop is
+    ``searchsorted``'s, and the overflow pass's loops lie under ``overflow``,
+    their bodies under the layer's label and the common pass's scopes again."""
+    import flax.linen as nn
+
+    from benchmark import tracered
+    from benchmark.stepscopes import scopes_of
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, x, bias):
+            return SparseMoEBlock(
+                num_experts=EXPERTS, top_k=TOP_K, hidden_ch=32, routed_scale=2.5, experts_held=HELD, name="moe",
+            )(x, bias)
+
+    x, p = _steered(100, 3)
+    bias = jnp.zeros((EXPERTS,))
+    # (With its value: a gradient alone leaves the forward overflow loop dead code.)
+    grad = jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(Layer().apply({"params": {"moe": p}}, x, bias)[0]), (0, 1)))
+    op_names = set(tracered.scopes_of_hlo(grad.lower(p, x).compile().as_text()).values())
+    seen = {}
+    for op_name in op_names:
+        labels = scopes_of(op_name)
+        if "moe" not in labels:
+            continue
+        inside = labels[labels.index("moe") + 1:]
+        if not inside:
+            assert op_name.rsplit("/", 1)[-1] == "reshape", op_name  # the module's own [B, S, D] <-> [T, D]
+            continue
+        assert inside[0] in ("route", "dispatch", "experts", "combine", "overflow", "shared"), op_name
+        seen.setdefault(inside[0], set()).add(op_name)
+        if inside[0] == "overflow":
+            continue
+        assert not any(label.startswith("branch_") for label in inside), op_name
+        if "while" in inside or op_name.endswith("/while"):
+            assert "jit(searchsorted)" in op_name, op_name
+        if inside[0] == "experts":
+            assert inside[1] in ("fc1", "fc2"), op_name
+    assert set(seen) == {"route", "dispatch", "experts", "combine", "overflow", "shared"}
+    primitives = lambda scope: {name.rsplit("/", 1)[-1] for name in seen[scope]}
+    assert "gather" in primitives("dispatch") and "scatter-add" in primitives("dispatch")  # rows out, and its transpose
+    assert "scatter-add" in primitives("combine") and "gather" in primitives("combine")  # the sum back, and its transpose
+    assert "dot_general" in primitives("experts")
+    # The overflow pass: a loop each way, the grouped matmuls and the sums inside it.
+    in_loop = {name.split("/while/body/", 1)[1] for name in seen["overflow"] if "/while/body/" in name}
+    assert any(name.startswith("jvp(Layer)/moe/overflow/while") for name in (n.split("/", 1)[1] for n in seen["overflow"]))
+    assert any("transpose(jvp(Layer))/moe/overflow/while" in name for name in seen["overflow"])
+    assert {"dot_general", "scatter-add"} <= {name.rsplit("/", 1)[-1] for name in in_loop}
+    # A trip is the common pass's work and is named as it: the body opens the layer's label
+    # again, so whoever reads ``moe`` followed by ``dispatch|experts|combine`` counts a trip too.
+    for name in in_loop:
+        labels = scopes_of(name)
+        if not labels:  # the carry's own additions
+            continue
+        assert labels[0] == "moe", name
+        inner = [label for label in labels if label != "moe"]
+        assert inner[0] in ("dispatch", "experts", "combine"), name
+        if inner[0] == "experts":
+            assert inner[1] in ("fc1", "fc2"), name
 
 
 def test_the_routed_leaves_are_what_the_expert_rule_places(params):

@@ -403,6 +403,47 @@ def test_looped_lm_optimizer_is_one_pass_over_each_parameter(topo, compiled_kern
     _assert_one_pass_optimizer(*_looped_lm_step(topo, monkeypatch))
 
 
+def test_a_sixteenth_of_the_experts_held_compiles_with_bounded_buffers(one_chip, monkeypatch):
+    """The expert layer at the expert cell's shapes (8,192 tokens x 8 of 256
+    experts, 16 held), forward and backward: the grouped matmuls are Mosaic
+    calls on the 8,192 rows of the bound, nothing has the 65,536 rows of
+    every routing, and the overflow pass is one loop each way, whose body
+    holds the same nine calls."""
+    from sav_tpu.models.layers import SparseMoEBlock
+    from sav_tpu.models.layers.moe import routed_row_bound
+    from sav_tpu.ops import _backend
+
+    monkeypatch.setattr(_backend, "default_interpret", lambda: False)
+    assert routed_row_bound(8192 * 8, 16, 256) == 8192
+    layer = SparseMoEBlock(
+        num_experts=256, top_k=8, hidden_ch=768, routed_scale=2.5, experts_held=(0, 16), dtype=jnp.bfloat16
+    )
+    x = jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((256,), jnp.float32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048), jnp.bfloat16), jnp.zeros((256,)))),
+    )
+
+    def loss(params, x, bias):
+        return jnp.sum(layer.apply(params, x, bias)[0].astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(params, x, bias).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    common = [line for line in calls if "/moe/overflow/" not in line and "/overflow/while" not in line]
+    assert len(common) == 9  # gate, up, down: forward, and two transposes each
+    assert all("/experts/fc" in line for line in common)
+    # The loop's: three in the forward loop; in the backward loop the three again and their six transposes.
+    # They are named as the common pass's, under the layer's label opened again inside the body.
+    looped = set(calls) - set(common)
+    assert len(calls) - len(common) == 12 and all("overflow/while/body/SparseMoEBlock/" in line for line in looped)
+    assert all(re.search(r"experts\)*/fc[12]/", line) for line in looped)
+    assert "[8192,2048]" in text and "[65536,2048]" not in text and "[8192,8,2048]" not in text
+    assert " conditional(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 def test_deit_s_sharded_train_step_compiles_for_four_chips(topo):
     """chip_smoke.py --chips 4: data x model over the 2x2 host."""
     trainer, mesh = _deit_s_trainer({"data": 2, "model": 2}, topo.devices)

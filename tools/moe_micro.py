@@ -3,10 +3,11 @@
 grouped matmul to run, and what the sorted path costs beside a dense loop.
 
     python tools/moe_micro.py [--tokens 8192] [--dim 2048] [--width 768]
-        [--experts 256] [--held 16] [--top-k 8] [--out chiprun_out/moe_micro.json]
+        [--experts 256] [--held 16] [--top-k 8] [--skip-tilings] [--out chiprun_out/moe_micro.json]
 
-Three readings, each forward and forward + backward, minimum over rounds of
-the mean of ``--iters`` calls (host clock to ``block_until_ready``):
+Five readings, each forward and forward + backward (the fifth forward
+alone), minimum over rounds of the mean of ``--iters`` calls (host clock to
+``block_until_ready``):
 
 1. the grouped matmul alone on ``[tokens x k, dim] x [held, dim, width]``
    with uniform groups (``tokens x k / experts`` rows each, the rest of the
@@ -15,7 +16,17 @@ the mean of ``--iters`` calls (host clock to ``block_until_ready``):
 2. ``SparseMoEBlock`` (router, sort, gathers, grouped matmuls, combine,
    shared expert) as the program runs it;
 3. the same layer as a dense loop: every held expert on every token, weighted
-   by a ``[T]`` vector (the reference's way), with the shared expert.
+   by a ``[T]`` vector (the reference's way), with the shared expert;
+4. the layer at the seeded routing with the bound's factor at 2, 4, 8 and the
+   worst case (``tokens x k`` rows), and with every routing on the held
+   experts (the overflow pass at its most trips); on that routing, the
+   largest difference in the output and in every gradient between the
+   overflow loops and buffers that hold every row;
+5. the candidates for the sum of ``[bound, dim]`` weighted rows back into
+   ``[tokens, dim]`` float32, on ids as the sort leaves them (ascending
+   within each expert's group, half of the buffer live): a scatter-add, a
+   sort of the ids with a sorted segment sum, a Mosaic kernel that adds one
+   row a grid step; and the gather of as many rows, their transpose.
 
 Not a benchmark: numbers for PERF.md's findings and for the tiling constant
 in ``sav_tpu/models/layers/moe.py``.
@@ -65,6 +76,58 @@ def both(fn, args, iters, rounds):
     return {"fwd_ms": timed(jax.jit(fn), args, iters, rounds), "fwd_bwd_ms": timed(grad, args, iters, rounds)}
 
 
+def sorted_segment_sum(rows, ids, live, tokens):
+    """The ids sorted (dead rows last), the rows gathered in that order, a
+    segment sum that is told so."""
+    ids = jnp.where(live, ids, tokens)
+    by_token = jnp.argsort(ids, stable=True)
+    return jax.ops.segment_sum(
+        jnp.take(rows, by_token, axis=0), jnp.take(ids, by_token), num_segments=tokens, indices_are_sorted=True
+    )
+
+
+def mosaic_sum(rows, ids, live, tokens):
+    """One row a grid step in token order: the output block is the row's
+    token's, kept in VMEM while the token stays the same. Dead rows go to a
+    row past the end; tokens with no row keep the zeros the output starts as."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    ids = jnp.where(live, ids, tokens)
+    by_token = jnp.argsort(ids, stable=True).astype(jnp.int32)
+    sorted_ids = jnp.take(ids, by_token)
+    dim = rows.shape[-1]
+
+    def kernel(by_token_ref, ids_ref, row_ref, zeros_ref, out_ref):
+        del by_token_ref, zeros_ref
+        r = pl.program_id(0)
+        first = jnp.logical_or(r == 0, ids_ref[r] != ids_ref[jnp.maximum(r - 1, 0)])
+
+        @pl.when(first)
+        def _():
+            out_ref[...] = row_ref[...]
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            out_ref[...] += row_ref[...]
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows.shape[0],),
+            in_specs=[
+                pl.BlockSpec((None, 1, dim), lambda r, by_token, ids: (by_token[r], 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, 1, dim), lambda r, by_token, ids: (ids[r], 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((tokens + 1, 1, dim), jnp.float32),
+        input_output_aliases={3: 0},
+    )(by_token, sorted_ids, rows[:, None, :], jnp.zeros((tokens + 1, 1, dim), jnp.float32))
+    return out[:tokens, 0]
+
+
 def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--tokens", type=int, default=8192)
@@ -75,6 +138,7 @@ def main(argv=None):
     p.add_argument("--top-k", type=int, default=8)
     p.add_argument("--iters", type=int, default=10)
     p.add_argument("--rounds", type=int, default=3)
+    p.add_argument("--skip-tilings", action="store_true", help="leave out reading 1's megablox tilings")
     p.add_argument("--out", default="chiprun_out/moe_micro.json")
     args = p.parse_args(argv)
 
@@ -86,7 +150,7 @@ def main(argv=None):
     report = {"device": jax.devices()[0].device_kind, "shapes": vars(args), "grouped_matmul": {}}
 
     variants = {"ragged_dot": lambda r, k: jax.lax.ragged_dot(r, k, group_sizes)}
-    for tiling in TILINGS:
+    for tiling in () if args.skip_tilings else TILINGS:
         variants["gmm " + "x".join(map(str, tiling))] = (
             lambda r, k, tiling=tiling: megablox.gmm(r, k, group_sizes, r.dtype, tiling, None, None, False, False)
         )
@@ -123,6 +187,79 @@ def main(argv=None):
     for name, fn in (("sorted_path", sorted_path), ("dense_loop", dense_loop)):
         report[name] = both(fn, (x, params), args.iters, args.rounds)
         print(name, report[name], flush=True)
+
+    # 4. The bound's factor at the seeded routing, the worst case, and the overflow pass taken.
+    onto_held = jnp.where(jnp.arange(args.experts) < args.held, 10.0, 0.0)
+    probe = jax.random.normal(keys[0], x.shape, jnp.float32)
+
+    def with_factor(factor, fn):
+        """``fn()`` with the bound's factor at ``factor`` (the constant is read
+        when a function is traced: hand ``fn`` new functions to trace)."""
+        kept, moe.ROWS_OVER_EXPECTED = moe.ROWS_OVER_EXPECTED, factor
+        try:
+            return fn()
+        finally:
+            moe.ROWS_OVER_EXPECTED = kept
+
+    def overflowing(x, p):
+        return block.apply({"params": p}, x, onto_held)[0]
+
+    def value_and_grads(x, p):
+        """Every routing on the held experts: the output, and the gradients of
+        its product with a fixed probe by the input and every leaf."""
+        loss = lambda x, p: jnp.sum(overflowing(x, p).astype(jnp.float32) * probe)
+        return jax.jit(lambda x, p: (overflowing(x, p), jax.grad(loss, (0, 1))(x, p)))(x, p)
+
+    worst = args.experts // args.held
+    report["sorted_path_by_factor"] = {}
+    for factor in sorted({moe.ROWS_OVER_EXPECTED, 4, 8, worst}):
+        entry = with_factor(factor, lambda: both(lambda x, p: sorted_path(x, p), (x, params), args.iters, args.rounds))
+        report["sorted_path_by_factor"][str(factor)] = entry
+        print("sorted_path at factor", factor, entry, flush=True)
+    report["sorted_path_worst_case_buffers"] = report["sorted_path_by_factor"][str(worst)]
+    report["sorted_path_overflow_taken"] = both(overflowing, (x, params), args.iters, args.rounds)
+    print("sorted_path_overflow_taken", report["sorted_path_overflow_taken"], flush=True)
+    # The overflow loops against buffers that hold every row, on the same routing: what the
+    # chip's grouped matmul, dynamic slices and scatter-adds give inside the loops.
+    taken, held_all = value_and_grads(x, params), with_factor(worst, lambda: value_and_grads(x, params))
+    gaps = jax.tree.map(
+        lambda a, b: {
+            "max_abs_diff": float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))),
+            "max_abs": float(jnp.max(jnp.abs(b.astype(jnp.float32)))),
+        },
+        taken, held_all,
+    )
+    report["overflow_against_worst_case_buffers"] = {
+        "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path): gap
+        for path, gap in jax.tree_util.tree_flatten_with_path(gaps, is_leaf=lambda g: "max_abs" in g)[0]
+    }
+    print("overflow_against_worst_case_buffers", json.dumps(report["overflow_against_worst_case_buffers"]), flush=True)
+
+    # 5. Back to tokens.
+    bound = moe.routed_row_bound(rows_n, args.held, args.experts)
+    report["bound"] = bound
+    group = bound // (2 * args.held)
+    ids = jnp.concatenate([
+        jnp.sort(jax.random.permutation(k, args.tokens)[:group])
+        for k in jax.random.split(keys[0], args.held)
+    ] + [jnp.zeros((bound - group * args.held,), jnp.int32)]).astype(jnp.int32)
+    live = jnp.arange(bound) < group * args.held
+    weighted = jax.random.normal(keys[1], (bound, args.dim), jnp.float32)
+    candidates = {
+        "scatter_add": jax.jit(lambda rows: moe._tokens_of_rows(rows, ids, live, args.tokens)),
+        "sort_then_sorted_segment_sum": jax.jit(lambda rows: sorted_segment_sum(rows, ids, live, args.tokens)),
+        "mosaic_row_a_step": jax.jit(lambda rows: mosaic_sum(rows, ids, live, args.tokens)),
+        "gather_of_as_many_rows": jax.jit(lambda rows: moe._rows_of_tokens(rows[: args.tokens], ids, live)),
+    }
+    want = candidates["scatter_add"](weighted)
+    report["back_to_tokens"] = {}
+    for name, fn in candidates.items():
+        ms = timed(fn, (weighted,), args.iters, args.rounds)
+        entry = {"fwd_ms": ms}
+        if isinstance(ms, float) and name != "gather_of_as_many_rows":
+            entry["max_abs_diff"] = float(jnp.max(jnp.abs(fn(weighted) - want)))
+        report["back_to_tokens"][name] = entry
+        print(name, entry, flush=True)
 
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "w") as f:

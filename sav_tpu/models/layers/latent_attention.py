@@ -12,6 +12,9 @@ the value head (``v_ch``) is narrower than the query/key head::
     q = [q_nope | rot(q_rope)];   k = [k_nope | rot(k_rope) for every head]
     out = softmax(q k^T / sqrt(nope + rope), causal) v;   y = concat(out) W_o
 
+``rope_scaling`` (the public config's group, YaRN) blends the rotary
+frequencies and multiplies the softmax scale (``sav_tpu/ops/rotary.py``).
+
 Training materialises ``k`` and ``v`` a head; the absorbed form (``W_kvb``
 folded into the query and the output) is decoding's and is not here.
 
@@ -34,7 +37,7 @@ from sav_tpu.models.layers.feedforward import _bias_free_dense
 from sav_tpu.models.layers.normalization import RMSNorm
 from sav_tpu.ops.attention import dot_product_attention
 from sav_tpu.ops.quant import QuantDenseGeneral
-from sav_tpu.ops.rotary import apply_rotary_interleaved
+from sav_tpu.ops.rotary import apply_rotary_interleaved, yarn_softmax_scale
 
 Dtype = Any
 
@@ -51,6 +54,7 @@ class _LatentQKVProj(nn.Module):
     rope_ch: int
     v_ch: int
     rope_theta: float
+    rope_scaling: Optional[Any]
     norm_eps: float
     quant: Optional[str]
     dtype: Dtype
@@ -74,8 +78,8 @@ class _LatentQKVProj(nn.Module):
 
         q = dense(h * (nope + rope), name="q_b")(c_q).reshape(b, s, h, nope + rope)
         kv = dense(h * (nope + self.v_ch), name="kv_b")(c_kv).reshape(b, s, h, nope + self.v_ch)
-        q_rope = apply_rotary_interleaved(q[..., nope:], self.rope_theta)
-        k_rope = apply_rotary_interleaved(k_rope, self.rope_theta)  # [B, S, rope]: one head
+        q_rope = apply_rotary_interleaved(q[..., nope:], self.rope_theta, self.rope_scaling)
+        k_rope = apply_rotary_interleaved(k_rope, self.rope_theta, self.rope_scaling)  # [B, S, rope]: one head
         query = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
         key = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, rope))], axis=-1
@@ -93,6 +97,7 @@ class LatentSelfAttentionBlock(nn.Module):
     rope_ch: int
     v_ch: int
     rope_theta: float = 10000.0
+    rope_scaling: Optional[Any] = None  # the public config's group (YaRN)
     norm_eps: float = 1e-6
     backend: Optional[str] = None
     logits_dtype: Optional[Dtype] = None
@@ -109,6 +114,7 @@ class LatentSelfAttentionBlock(nn.Module):
             rope_ch=self.rope_ch,
             v_ch=self.v_ch,
             rope_theta=self.rope_theta,
+            rope_scaling=self.rope_scaling,
             norm_eps=self.norm_eps,
             quant=self.quant,
             dtype=self.dtype,
@@ -119,7 +125,7 @@ class LatentSelfAttentionBlock(nn.Module):
             query,
             key,
             value,
-            scale=(self.nope_ch + self.rope_ch) ** -0.5,
+            scale=(self.nope_ch + self.rope_ch) ** -0.5 * yarn_softmax_scale(self.rope_scaling),
             backend=self.backend,
             logits_dtype=self.logits_dtype or self.dtype,
             causal=True,

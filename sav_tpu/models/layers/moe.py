@@ -217,10 +217,36 @@ def _fake_int8(a: jax.Array, contract_axes) -> jax.Array:
     return a + jax.lax.stop_gradient(rounded - a)
 
 
-# (rows, contraction, columns) of a tile of the Mosaic grouped matmul; chosen
-# on a v5e at [65,536, 2,048] x [16, 2,048, 768] with about 256 rows a group
-# (tools/moe_micro.py; PERF.md section 6).
-GMM_TILING = (256, 1024, 768)
+# Rows of a tile of the Mosaic grouped matmul, and the most a tile takes of
+# either width.
+GMM_ROW_TILE = 256
+GMM_WIDTH_TILE = 1024
+
+
+def gmm_tiling(dim: int, hidden: int) -> tuple:
+    """(rows, contraction, columns) of a tile of the Mosaic grouped matmul for
+    an expert of ``dim`` x ``hidden``: of each width the largest multiple of
+    128 up to 1,024 that divides it (the width itself where none does). The
+    tuple is fc1's, ``[rows, dim] x [dim, hidden]``, which it cuts into whole
+    tiles; the layer's other matmuls and the transposes take the same tuple and
+    meet the two widths in the other place, so fc2 ``[rows, hidden] x [hidden,
+    dim]`` runs in partial tiles wherever the two tiles differ and do not
+    divide each other's width (the kernel masks them). ``(256, 1024, 768)`` at
+    2,048 x 768 was measured on a v5e at [65,536, 2,048] x [16, 2,048, 768]
+    with about 256 rows a group (tools/moe_micro.py; PERF.md section 6, PR
+    30). At 3,584 x 1,024 the rule gives ``(256, 896, 1024)``. Its 256 rows
+    are measured there: one matmul of [8,192, 3,584] x [8, 3,584, 1,024] with
+    every group in whole tiles reads 0.28 / 0.71 ms forward / with backward
+    against 0.23 / 0.65 at a row tile of 512, but the layer (8 of 64 experts
+    held, top-4, 8,192 tokens, groups of about 500 ragged rows) reads 7.55 /
+    14.63 ms at 256 rows and 7.68 / 15.16 at 512, whose tiles meet two groups
+    nearly everywhere. Its two width tiles are the rule's guess, not a tuned
+    choice (PERF.md sections 6 and 7, PR 32)."""
+    def tile(width: int) -> int:
+        whole = [t for t in range(128, GMM_WIDTH_TILE + 1, 128) if width % t == 0]
+        return max(whole, default=width)
+
+    return GMM_ROW_TILE, tile(dim), tile(hidden)
 
 # Rows the routed buffers hold, over the rows uniform routing would send to
 # the experts held. The seeded layers of the published widths hold 0.78 to
@@ -240,7 +266,7 @@ def routed_row_bound(routings: int, held: int, experts: int) -> int:
     ``held`` of ``experts`` experts are here: ``ROWS_OVER_EXPECTED`` times the
     expected rows, whole tiles of the grouped matmul, never more than all of
     them (which is what holding every expert gives)."""
-    tile = GMM_TILING[0]
+    tile = GMM_ROW_TILE
     return min(routings, tile * -(-ROWS_OVER_EXPECTED * routings * held // (experts * tile)))
 
 
@@ -254,10 +280,11 @@ def rows_over_bound(counts: jax.Array, routings: int, experts_held) -> jax.Array
     return jnp.sum(counts[..., offset:offset + held], axis=(0, -1)) / bound
 
 
-def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array) -> jax.Array:
+def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array, tiling: tuple) -> jax.Array:
     """``rows [R, K]`` in groups of consecutive rows, group ``e`` times
-    ``kernels[e] [K, N]``. Rows past the last group belong to no held expert:
-    no tile of theirs is computed and what stands there is not read.
+    ``kernels[e] [K, N]``, in tiles of ``tiling`` (:func:`gmm_tiling`). Rows
+    past the last group belong to no held expert: no tile of theirs is
+    computed and what stands there is not read.
 
     On a TPU a Mosaic grouped matmul (jax's megablox kernel, forward and both
     transposes), which keeps its caller's scope in the compiled step's
@@ -265,10 +292,10 @@ def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array) 
     but names its calls ``ragged-dot-none``, and no reader of a trace could
     tell whose time that is. Elsewhere, and for a handful of rows that fill no
     tile (``init``'s trace), ``jax.lax.ragged_dot``."""
-    if _backend.default_interpret() or rows.shape[0] % GMM_TILING[0]:
+    if _backend.default_interpret() or rows.shape[0] % GMM_ROW_TILE:
         return jax.lax.ragged_dot(rows, kernels, group_sizes)
     return megablox.gmm(
-        rows, kernels, group_sizes, rows.dtype, GMM_TILING, None, None, False, False
+        rows, kernels, group_sizes, rows.dtype, tiling, None, None, False, False
     )
 
 
@@ -314,14 +341,15 @@ def _expert_ffn(rows, kernels, group_sizes, quant):
     """``E_e(x) = W_down[e](silu(W_gate[e] x) * W_up[e] x)`` on sorted rows, in
     groups of ``group_sizes`` (GatedFFBlock's formula and scopes)."""
     gate, up, down = kernels
+    tiling = gmm_tiling(*gate.shape[1:])
     if quant:
         rows = _fake_int8(rows, (1,))
     with jax.named_scope("fc1"):
-        hidden = nn.silu(grouped_matmul(rows, gate, group_sizes)) * grouped_matmul(rows, up, group_sizes)
+        hidden = nn.silu(grouped_matmul(rows, gate, group_sizes, tiling)) * grouped_matmul(rows, up, group_sizes, tiling)
     with jax.named_scope("fc2"):
         if quant:
             hidden = _fake_int8(hidden, (1,))
-        return grouped_matmul(hidden, down, group_sizes)
+        return grouped_matmul(hidden, down, group_sizes, tiling)
 
 
 def _sorted_chunk(order, group_sizes, index, rows):

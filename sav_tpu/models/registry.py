@@ -22,7 +22,7 @@ from sav_tpu.models.botnet import BoTNet
 from sav_tpu.models.cait import CaiT
 from sav_tpu.models.ceit import CeiT
 from sav_tpu.models.cvt import CvT
-from sav_tpu.models.joyai import JoyAILM
+from sav_tpu.models.joyai import KEPT_UNDER_REMAT_BESIDE_STREAMS, JoyAILM
 from sav_tpu.models.mlp_mixer import MLPMixer
 from sav_tpu.models.ouro import OuroLM
 from sav_tpu.models.tnt import TNT
@@ -178,6 +178,31 @@ register(
     nope_ch=128, rope_ch=64, v_ch=128, mlp_ch=7168, expert_ch=768,
     num_experts=256, top_k=8, routed_scale=2.5, first_dense=1,
     rope_theta=32e6, norm_eps=1e-6,
+)
+
+# --- Xing4.0-29B-A4B (the same sublayers between hyper-connected streams) ---
+# Sizes of https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B/blob/main/
+# config.json; ``num_classes`` is the vocabulary (131,072 there). 29.5 B
+# parameters (3.9 B active a token) + 0.77 B in the MTP module: one chip holds
+# a cut in depth and its share of every expert layer (model_overrides=
+# {"num_layers": 5, "first_dense": 1, "experts_held": (0, 8), "mtp_modules": 0}).
+# ``kept_under_remat`` is what fits that cut's step into one v5e's 16 GB (all
+# of KEPT_UNDER_REMAT compiles to 15.53 GB, this choice to 14.47 GB), not a
+# property of the architecture: a chip with more room passes KEPT_UNDER_REMAT.
+register(
+    "xing4_0_29b_a4b",
+    JoyAILM,
+    task="tokens_mtp",
+    embed_dim=3584, num_layers=40, num_heads=32, q_rank=768, kv_rank=512,
+    nope_ch=128, rope_ch=64, v_ch=128, mlp_ch=9216, expert_ch=1024,
+    num_experts=64, top_k=4, routed_scale=2.0, first_dense=2, mtp_modules=1,
+    rope_theta=1e4, norm_eps=1e-6,
+    rope_scaling={
+        "type": "yarn", "factor": 64, "original_max_position_embeddings": 4096,
+        "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+    },
+    hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=(-30.0, 30.0),
+    kept_under_remat=KEPT_UNDER_REMAT_BESIDE_STREAMS,
 )
 
 

@@ -11,20 +11,62 @@ lane ``2i`` with ``2i + 1``; the decoder family's :func:`rotate_half` pairs
 lane ``i`` with ``i + dim/2`` (the layout of the public language-model
 checkpoints), with tables from :func:`half_split_tables`. Latent attention
 rotates adjacent pairs of a slice of the head at its own base, in float32,
-on a key that may have no head axis: :func:`apply_rotary_interleaved`.
+on a key that may have no head axis: :func:`apply_rotary_interleaved`, with
+YaRN's blended frequencies (arXiv:2309.00071) where the public config's
+``rope_scaling`` group is given.
 """
 
 from __future__ import annotations
+
+import math
+from typing import Mapping, Optional
 
 import jax
 import jax.numpy as jnp
 
 
-def _angles(seq_len: int, dim: int, base) -> jax.Array:
-    """``[seq_len, dim/2]`` float32: position times ``base ** (-2i / dim)``."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature for a context ``factor`` times the
+    original: ``0.1 mscale ln(factor) + 1`` (1 at ``factor`` <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_softmax_scale(scaling: Optional[Mapping]) -> float:
+    """What the softmax scale of a head is multiplied by under
+    ``scaling``: ``yarn_mscale(factor, mscale_all_dim) ** 2``."""
+    return yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]) ** 2 if scaling else 1.0
+
+
+def yarn_inv_freq(dim: int, base, scaling: Mapping) -> jax.Array:
+    """``[dim/2]`` float32 inverse frequencies under YaRN: pair ``i`` turns
+    ``original_max_position_embeddings base ** (-2i / dim) / 2 pi`` times over
+    the original context. Pairs that turn ``beta_fast`` times or more keep the
+    base's frequency, those that turn ``beta_slow`` times or fewer take it
+    over ``factor``, and between the two pair indices (floor of the first,
+    ceiling of the second) the two are blended linearly in the index."""
+    if scaling.get("type", "yarn") != "yarn":
+        raise ValueError(f"rotary scaling {scaling.get('type')!r} is not implemented: yarn alone")
+    original = scaling["original_max_position_embeddings"]
+
+    def pair_index(rotations: float) -> float:
+        return dim * math.log(original / (rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(pair_index(scaling["beta_fast"])), 0)
+    high = min(math.ceil(pair_index(scaling["beta_slow"])), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0)
+    plain = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    return plain / scaling["factor"] * ramp + plain * (1.0 - ramp)
+
+
+def _angles(seq_len: int, dim: int, base, scaling: Optional[Mapping] = None) -> jax.Array:
+    """``[seq_len, dim/2]`` float32: position times ``base ** (-2i / dim)``
+    (times :func:`yarn_inv_freq` under ``scaling``)."""
     if dim % 2 != 0:
         raise ValueError(f"rotary dim must be even, got {dim}")
-    inv_freq = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling:
+        inv_freq = yarn_inv_freq(dim, base, scaling)
+    else:
+        inv_freq = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
     t = jnp.arange(seq_len, dtype=jnp.float32)
     return jnp.einsum("i,j->ij", t, inv_freq)
 
@@ -89,13 +131,21 @@ def apply_rotary_half(x: jax.Array, sincos) -> jax.Array:
     return (x32 * cos + rotate_half(x32) * sin).astype(x.dtype)
 
 
-def apply_rotary_interleaved(x: jax.Array, base: float) -> jax.Array:
+def apply_rotary_interleaved(x: jax.Array, base: float, scaling: Optional[Mapping] = None) -> jax.Array:
     """RoPE with the adjacent-pairs pairing ``(2i, 2i + 1)`` at ``base`` on the
     whole last axis of ``x: [B, seq_len, dim]`` or ``[B, seq_len, heads, dim]``
     (pass the slice of the head that rotates). Float32 inside, cast back, for
-    :func:`apply_rotary_half`'s reason."""
-    freqs = jnp.repeat(_angles(x.shape[1], x.shape[-1], base), 2, axis=-1)  # [L, dim]
+    :func:`apply_rotary_half`'s reason. ``scaling`` is the public config's
+    ``rope_scaling`` group (YaRN): blended frequencies, and cos and sin times
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``."""
+    freqs = jnp.repeat(_angles(x.shape[1], x.shape[-1], base, scaling), 2, axis=-1)  # [L, dim]
     sin, cos = jnp.sin(freqs), jnp.cos(freqs)
+    if scaling:
+        amplitude = yarn_mscale(scaling["factor"], scaling["mscale"]) / yarn_mscale(
+            scaling["factor"], scaling["mscale_all_dim"]
+        )
+        if amplitude != 1.0:
+            sin, cos = sin * amplitude, cos * amplitude
     if x.ndim == 4:
         sin, cos = sin[:, None, :], cos[:, None, :]
     x32 = x.astype(jnp.float32)

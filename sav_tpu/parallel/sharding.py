@@ -25,6 +25,12 @@ through the computation and inserts the right collectives over ICI.
 The default transformer TP layout (Megatron-style, over ``model``):
 QKV heads column-split, output merge row-split, MLP hidden split — one
 AllReduce per block (docs/parallelism.md has the full layer-role table).
+
+Replicated by falling through every rule (a path no rule matches gets
+``P()``): norm scales, the routers, and a hyper-connection's maps
+(``hc_attn|hc_ffn/{kernel,scale,bias}``: every chip mixes its own tokens'
+streams by the same maps; tests/test_xing.py pins it under the TP and the
+expert rules). The rule lists themselves stay the pinned historical ones.
 """
 
 from __future__ import annotations

@@ -16,6 +16,8 @@ registry entry names its task (``sav_tpu.models.registry.model_task``):
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import optax
@@ -265,12 +267,15 @@ class LoopedTokenPrediction(TokenPrediction):
         return self._weighted_sums(jnp.sum(p * outputs["ce"], axis=-1), batch)
 
 
-def mtp_lm_loss(ce: jax.Array, ce_mtp: jax.Array, mtp_weight: float):
+def mtp_lm_loss(ce: jax.Array, ce_mtp: Optional[jax.Array], mtp_weight: float):
     """``mean CE_main + lambda mean CE_mtp`` (arXiv:2412.19437 eq. 25): the
     main head over all ``S`` positions, the multi-token-prediction module
     over the ``S - 1`` that have a next-but-one token (the model leaves the
-    last one's term at 0). Returns ``(loss, main, mtp)``."""
+    last one's term at 0). Returns ``(loss, main, mtp)``; a model built
+    without the module gives no ``ce_mtp``, and then ``(main, main, None)``."""
     main = jnp.mean(ce)
+    if ce_mtp is None:
+        return main, main, None
     mtp = jnp.sum(ce_mtp) / (ce_mtp.shape[0] * (ce_mtp.shape[1] - 1))
     return main + mtp_weight * mtp, main, mtp
 
@@ -278,9 +283,11 @@ def mtp_lm_loss(ce: jax.Array, ce_mtp: jax.Array, mtp_weight: float):
 class MTPTokenPrediction(TokenPrediction):
     """Next-token loss plus the multi-token-prediction module's, of a decoder
     with routed experts (``sav_tpu/models/joyai.py``). The model returns
-    ``ce``, ``ce_mtp``, each sequence's routing counts (all, and those on
-    the experts it holds) and each routed layer's fill of its bounded
-    buffers."""
+    ``ce``, ``ce_mtp`` where it has the module, each sequence's routing
+    counts (all, and those on the experts it holds), each routed layer's
+    fill of its bounded buffers and, where its residual path is
+    hyper-connected streams, how far its mixing matrices are from doubly
+    stochastic and what they do to the streams' norm."""
 
     # lambda of the MTP loss (arXiv:2412.19437 section 4.2's first phase;
     # assumed: benchmark/configs/joyai_llm_flash.json).
@@ -288,14 +295,21 @@ class MTPTokenPrediction(TokenPrediction):
 
     def loss(self, outputs: dict, targets) -> jax.Array:
         del targets  # the model has already scored every position
-        return mtp_lm_loss(outputs["ce"], outputs["ce_mtp"], self.mtp_weight)[0]
+        return mtp_lm_loss(outputs["ce"], outputs.get("ce_mtp"), self.mtp_weight)[0]
 
     def train_metrics(self, outputs: dict, batch: dict) -> dict:
-        _, main, mtp = mtp_lm_loss(outputs["ce"], outputs["ce_mtp"], self.mtp_weight)
+        _, main, mtp = mtp_lm_loss(outputs["ce"], outputs.get("ce_mtp"), self.mtp_weight)
         load = jnp.sum(outputs["moe_counts"], axis=0)  # [R, E]: the step's routings
+        optional = {} if mtp is None else {"loss_mtp": mtp}
+        # The largest |row or column sum - 1| of any mixing matrix of the
+        # step, and the largest norm of a sublayer's mixed streams over the
+        # norm of the streams it mixed (the constraint holds it at 1 or under).
+        for name in ("hc_doubly_stochastic_err", "hc_stream_gain"):
+            if name in outputs:
+                optional[name] = jnp.max(outputs[name])
         return {
             "loss_main": main,
-            "loss_mtp": mtp,
+            **optional,
             "moe_held_share": jnp.sum(outputs["moe_held"]) / jnp.sum(load),
             # Routed layer applications that took the exact overflow pass
             # (their rows on the held experts passed the buffers' bound), and

@@ -38,15 +38,15 @@ def _self_lint():
     Half the tests here examine different properties of the same
     repo-wide run; re-linting (and re-running the whole-program
     concurrency pass) per test was the suite's own wall-time hotspot.
-    The result is read-only; the first call times itself for the
-    wall-time budget test below.
+    The result is read-only; the first call times itself, in the
+    process's own CPU seconds, for the budget test below.
     """
     if not _SELF_LINT:
-        t0 = time.perf_counter()
+        t0 = time.process_time()
         _SELF_LINT["result"] = lint_paths(
             SELF_PATHS, root=ROOT, baseline=DEFAULT_BASELINE
         )
-        _SELF_LINT["elapsed_s"] = time.perf_counter() - t0
+        _SELF_LINT["cpu_s"] = time.process_time() - t0
     return _SELF_LINT["result"]
 
 
@@ -262,11 +262,14 @@ def test_repo_lint_wall_time_stays_bounded():
     observed on a cold CI core — but a quadratic regression (a rule
     re-walking per rule, the project pass re-running per rule) blows
     through it immediately. Measured on the suite's one shared run —
-    the measurement itself must not double the suite's cost."""
+    the measurement itself must not double the suite's cost — and in
+    the process's CPU seconds (``time.process_time``), not on the wall
+    clock: under six xdist workers on a loaded machine the wall clock
+    read what the neighbours were doing (the linter is one thread)."""
     result = _self_lint()
-    elapsed = _SELF_LINT["elapsed_s"]
+    cpu_s = _SELF_LINT["cpu_s"]
     assert result.files > 80
-    assert elapsed < 8.0, f"repo lint took {elapsed:.2f}s (budget 8s)"
+    assert cpu_s < 8.0, f"repo lint took {cpu_s:.2f} CPU-s (budget 8s)"
 
 
 # ------------------------------------------------- the gate actually bites

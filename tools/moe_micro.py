@@ -52,7 +52,9 @@ from jax.experimental.pallas.ops.tpu import megablox  # noqa: E402
 from sav_tpu.models.layers import moe  # noqa: E402
 
 TILINGS = [(128, 128, 128), (256, 512, 256), (512, 512, 256), (512, 1024, 256), (512, 2048, 256),
-           (512, 1024, 384), (256, 1024, 768), (512, 768, 512)]
+           (512, 1024, 384), (256, 1024, 768), (512, 768, 512),
+           # candidates at 3,584 x 1,024 (gmm_tiling gives the first)
+           (256, 896, 1024), (512, 896, 1024), (256, 1792, 1024), (256, 512, 512), (256, 896, 512)]
 
 
 def timed(fn, args, iters, rounds):

@@ -232,7 +232,7 @@ def snapshot_dispatch_log() -> list:
         return [dict(v) for v in _DISPATCH_LOG.values()]
 
 
-def _log_dispatch(shape, kv_len, requested, dispatch: AttentionDispatch) -> None:
+def _log_dispatch(shape, kv_len, requested, dispatch: AttentionDispatch, backward=None) -> None:
     # kv_len is part of the identity: cross-attention sites share a query
     # shape with self-attention ones but can resolve differently.
     key = (shape, kv_len, requested)
@@ -243,6 +243,8 @@ def _log_dispatch(shape, kv_len, requested, dispatch: AttentionDispatch) -> None
                 "kv_len": kv_len,
                 "requested": requested or "auto",
                 **dispatch.as_note(),
+                # The unbiased flash kernel's backward: 'one_kernel' | 'two_kernels'.
+                **({"backward": backward} if backward else {}),
             }
 
 
@@ -388,9 +390,16 @@ def dot_product_attention(
             dtype=query.dtype, requested=requested, kernels_ok=True,
             causal=causal, value_dim=value.shape[-1],
         )
-        _log_dispatch(tuple(query.shape), key.shape[1], requested, dispatch)
         backend = dispatch.backend
         cfg = dispatch.block_config or {}
+        flash_blocks = {k: cfg[k] for k in ("block_q", "block_kv", "block_b") if k in cfg}
+        backward = None
+        if backend == "pallas" and bias is None:
+            backward = _flash.backward_form(
+                lq, key.shape[1], d, value.shape[-1], batch_heads=b * h,
+                itemsize=query.dtype.itemsize, **flash_blocks,
+            )
+        _log_dispatch(tuple(query.shape), key.shape[1], requested, dispatch, backward)
     else:
         if backend in ("pallas", "fused"):
             raise ValueError(
@@ -398,7 +407,7 @@ def dot_product_attention(
                 "inputs and deterministic mode (attention dropout runs on "
                 "the XLA path)"
             )
-        backend, cfg = "xla", {}
+        backend, cfg, flash_blocks = "xla", {}, {}
     if backend == "fused":
         if causal or value.shape[-1] != query.shape[-1]:
             raise ValueError("the fused attention kernel has no causal arm and one head size")
@@ -407,9 +416,8 @@ def dot_product_attention(
         kw = {k: cfg[k] for k in ("block_q", "block_b") if k in cfg}
         return _fused.fused_attention(query, key, value, bias, scale=scale, **kw)
     if backend == "pallas":
-        kw = {k: cfg[k] for k in ("block_q", "block_kv", "block_b") if k in cfg}
         return _flash.flash_attention(
-            query, key, value, bias, scale=scale, causal=causal, **kw
+            query, key, value, bias, scale=scale, causal=causal, **flash_blocks
         )
     return xla_attention(
         query,

@@ -35,7 +35,9 @@ Cache schema (version 1)::
       },
       "infeasible": {
         "<key>": [{"backend": ..., "block_q": ..., "block_kv": ...,
-                    "block_b": ..., "error": "<Mosaic message>"}]
+                    "block_b": ..., "error": "<Mosaic message>",
+                    "backward": "one_kernel"|"two_kernels" (optional: the
+                    flash backward the record was compiled with)}]
       }
     }
 

@@ -8,14 +8,21 @@ in HBM. An optional additive bias input carries 2-D relative-position logits
 (BoTNet) or masks through the fused softmax. ``causal=True`` (decoder
 self-attention) needs no bias: a block wholly above the diagonal is neither
 fetched nor computed, a block the diagonal crosses is masked in VMEM by an
-iota comparison, forward and in both backward kernels.
+iota comparison, forward and backward.
 
 Differentiation: ``flash_attention`` is a ``jax.custom_vjp``. Without a
 bias, the backward is fully blocked Pallas too: the forward saves only the
 per-row logsumexp (one float32 a row between the passes; the kernels write
 and read it broadcast across one 128-lane tile, the TPU-friendly layout),
-and two kernels recompute probabilities tile-by-tile to produce
-dq (kv-innermost grid) and dk/dv (q-innermost grid) — the ``[B, H, Lq,
+and ONE kernel recomputes a ``(q block, kv block)`` pair's probabilities
+once and feeds dq, dk and dv from them: a q-innermost grid sums dk/dv over
+the q sweep of a kv block, and the float32 dq of a whole batch·head cell
+stays in VMEM from the cell's first grid step to its last (3 MiB at L 4096
+and a 192-lane head), under a VMEM limit of its own. Where that dq does not
+fit beside the tiles (:func:`backward_form`: a rule on the padded sizes,
+the blocks and ``block_b``, nothing a caller sets), two kernels run, dq
+(kv-innermost grid) and dk/dv (q-innermost grid), each rebuilding the
+probabilities: seven matmuls a pair of tiles for five. The ``[B, H, Lq,
 Lk]`` probability matrix never exists in HBM in either direction. With an
 additive bias that requires a gradient, the backward falls back to an XLA
 flash-style recompute (the dbias reduction needs the dense ``ds``).
@@ -43,6 +50,9 @@ from jax.experimental.pallas import tpu as pltpu
 from sav_tpu.ops import _backend
 
 _NEG_INF = float("-inf")
+# The default q and kv tile: the v5e block sweep (now tools/attn_tune.py,
+# PERF.md §5) measured 256/256 ~1.6x faster than 128/128 at model-zoo shapes.
+DEFAULT_BLOCK = 256
 
 
 def _round_up(x: int, m: int) -> int:
@@ -363,10 +373,12 @@ def _flash_forward(
 # delta_i = Σ_d dO_id · O_id the gradients are
 #   ds = p ⊙ (dO·Vᵀ − delta),  dq = scale·ds·K,  dk = scale·dsᵀ·Q,
 #   dv = pᵀ·dO.
-# dq uses a kv-innermost grid (accumulator indexed by q block); dk/dv use a
-# q-innermost grid (accumulators indexed by kv block). All matmuls run
-# bf16-in/f32-accumulate on the MXU — feeding fp32 operands to the MXU would
-# run it at a fraction of peak for no accuracy gain (same policy as the XLA
+# One kernel on a q-innermost grid produces all three where the whole float32
+# dq of a batch·head cell fits VMEM (backward_form); else dq uses a
+# kv-innermost grid (accumulator indexed by q block) and dk/dv a q-innermost
+# one (accumulators indexed by kv block). All matmuls run bf16-in/
+# f32-accumulate on the MXU — feeding fp32 operands to the MXU would run it
+# at a fraction of peak for no accuracy gain (same policy as the XLA
 # recompute path below).
 # ---------------------------------------------------------------------------
 
@@ -468,6 +480,32 @@ def _bwd_prep(q, k, v, out, g, block_q, block_kv) -> _BwdGeom:
     )
 
 
+def _bwd_tile(q, k, v, do, lse, delta, *, scale, qi, ki, q_len, kv_len,
+              block_q, block_kv, masked):
+    """One ``(q block, kv block)`` pair's ``(p, ds)``, float32: the
+    probabilities rebuilt from the logsumexp, zero on padded rows and
+    columns and above the diagonal, and ``ds = p (dO v^T - delta)``."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale  # [block_q, block_kv]
+    p = jnp.exp(s - _lanes(lse, s.shape[1]))
+    if kv_len % block_kv != 0:
+        col = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        p = jnp.where(col < kv_len, p, 0.0)
+    if q_len % block_q != 0:
+        # Padded (zero) q rows carry a finite lse ≈ log(kv_len), so p is
+        # finite garbage, not NaN: they must not reach the dk/dv sums, and
+        # their dq rows (sliced off outside) cost nothing as zeros.
+        row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        p = jnp.where(row < q_len, p, 0.0)
+    if masked:
+        p = jnp.where(_causal_keep(qi, ki, block_q, block_kv), p, 0.0)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return p, p * (dp - _lanes(delta, s.shape[1]))
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_acc, *, scale: float, q_len: int, kv_len: int,
                    block_b: int, block_q: int, block_kv: int,
@@ -480,32 +518,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     def fold(masked: bool):
         for bi in range(block_b):
-            q, k, v, do = q_ref[bi], k_ref[bi], v_ref[bi], do_ref[bi]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale
-            p = jnp.exp(s - _lanes(lse_ref[bi], s.shape[1]))
-            if kv_len % block_kv != 0:
-                col = ki * block_kv + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1
-                )
-                p = jnp.where(col < kv_len, p, 0.0)
-            if q_len % block_q != 0:
-                # Padded (zero) q rows carry a finite lse ≈ log(kv_len), so p
-                # is finite garbage, not NaN; their dq rows are sliced off
-                # outside. Zero them anyway so the padded rows cost nothing
-                # downstream and the invariant "p == 0 outside the real
-                # block" holds in both backward kernels.
-                row = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 0
-                )
-                p = jnp.where(row < q_len, p, 0.0)
-            if masked:
-                p = jnp.where(_causal_keep(qi, ki, block_q, block_kv), p, 0.0)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            k = k_ref[bi]
+            _, ds = _bwd_tile(
+                q_ref[bi], k, v_ref[bi], do_ref[bi], lse_ref[bi], delta_ref[bi],
+                scale=scale, qi=qi, ki=ki, q_len=q_len, kv_len=kv_len,
+                block_q=block_q, block_kv=block_kv, masked=masked,
             )
-            ds = p * (dp - _lanes(delta_ref[bi], s.shape[1]))
             dq_acc[bi] += jax.lax.dot_general(
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -518,11 +536,28 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                    dv_ref, dk_acc, dv_acc, *, scale: float, q_len: int,
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                    with_dq: bool, scale: float, q_len: int, kv_len: int,
                     block_b: int, block_q: int, block_kv: int,
-                    num_q_blocks: int, causal: bool):
+                    num_q_blocks: int, num_kv_blocks: int, causal: bool):
+    """dk and dv of one kv block, summed over its q sweep (q innermost);
+    ``rest`` = ([dq_ref], dk_ref, dv_ref, [dq_acc], dk_acc, dv_acc).
+
+    ``with_dq``: dq too, from the same recomputation of a pair's logits. A q
+    block is met once per kv block, so the float32 dq of the whole
+    batch·head cell, ``[num_q_blocks, block_q, dim_p]`` a slice (a q block
+    is named by its leading index), stays in VMEM from the cell's first
+    grid step to its last."""
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = rest
     ki, qi = pl.program_id(1), pl.program_id(2)
+
+    if with_dq:
+        @pl.when(jnp.logical_and(ki == 0, qi == 0))
+        def _init_dq():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     @pl.when(qi == 0)
     def _init():
@@ -531,31 +566,24 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
     def fold(masked: bool):
         for bi in range(block_b):
-            q, k, v, do = q_ref[bi], k_ref[bi], v_ref[bi], do_ref[bi]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale  # [block_q, block_kv]
-            p = jnp.exp(s - _lanes(lse_ref[bi], s.shape[1]))
-            if q_len % block_q != 0:
-                # Padded q rows must not contribute to the dk/dv sums.
-                row = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 0
-                )
-                p = jnp.where(row < q_len, p, 0.0)
-            if masked:
-                p = jnp.where(_causal_keep(qi, ki, block_q, block_kv), p, 0.0)
+            q, k, do = q_ref[bi], k_ref[bi], do_ref[bi]
+            p, ds = _bwd_tile(
+                q, k, v_ref[bi], do, lse_ref[bi], delta_ref[bi], scale=scale,
+                qi=qi, ki=ki, q_len=q_len, kv_len=kv_len, block_q=block_q,
+                block_kv=block_kv, masked=masked,
+            )
             dv_acc[bi] += jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            ds = p * (dp - _lanes(delta_ref[bi], s.shape[1]))
+            ds = ds.astype(q.dtype)
             dk_acc[bi] += jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
+                ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
             ) * scale
+            if with_dq:
+                dq_acc[bi, qi] += jax.lax.dot_general(
+                    ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+                ) * scale
 
     _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
 
@@ -564,17 +592,75 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
+    if with_dq:
+        @pl.when(jnp.logical_and(ki == num_kv_blocks - 1, qi == num_q_blocks - 1))
+        def _write_dq():
+            for i in range(num_q_blocks):
+                dq_ref[:, i * block_q:(i + 1) * block_q, :] = dq_acc[:, i].astype(dq_ref.dtype)
+
+
+# The one-kernel backward keeps a batch·head cell's whole float32 dq in
+# VMEM beside its tiles: over Mosaic's default 16 MiB of scoped VMEM at the
+# token cells' shapes (a v5e core has 128 MiB). The call is compiled under
+# the limit; the estimate has to fit the budget, and the rest of the limit
+# is left to what the estimate cannot see.
+ONE_KERNEL_VMEM_BUDGET = 48 * 2**20
+_ONE_KERNEL_VMEM_LIMIT = 64 * 2**20
+
+
+def one_kernel_backward_vmem_bytes(
+    q_len_p: int, dim_p: int, dim_v_p: int, *, block_q: int, block_kv: int,
+    block_b: int = 1, itemsize: int = 2,
+) -> int:
+    """Estimated per-grid-cell VMEM working set of :func:`_bwd_dkv_kernel`
+    with dq:
+    q, k, v, dO and the 128-lane lse and delta tiles in and dk, dv out at
+    their blocks, dq out at the whole padded sequence, every block
+    double-buffered; the float32 dk, dv and dq accumulators; and two
+    float32 logits tiles of one slice (Mosaic keeps about one and a half of
+    ``s``, ``p``, ``dp``, ``ds`` alive). Every term but the last holds
+    ``block_b`` slices, and a 192-lane head fills two lane tiles in VMEM.
+    Conservative: compiled for a v5e at head sizes 64 to 192, lengths 200
+    to 16,384, tiles 128 to 2,048 and ``block_b`` 1 to 8, Mosaic reports
+    using from half of this (a long sequence: it holds the dq block once)
+    to 94% of it, and the budget leaves a quarter of the limit besides."""
+    dim_p, dim_v_p = _round_up(dim_p, 128), _round_up(dim_v_p, 128)
+    heads = dim_p + dim_v_p
+    blocks = (block_q + 2 * block_kv) * heads * itemsize  # q, dO; k, v; dk, dv
+    blocks += 2 * block_q * 128 * 4  # lse, delta
+    blocks += q_len_p * dim_p * itemsize  # dq
+    scratch = (block_kv * heads + q_len_p * dim_p) * 4
+    return block_b * (2 * blocks + scratch) + 2 * block_q * block_kv * 4
+
+
+def backward_form(q_len: int, kv_len: int, dim: int, dim_v: int, *,
+                  batch_heads: int, block_q: int = DEFAULT_BLOCK,
+                  block_kv: int = DEFAULT_BLOCK,
+                  block_b: Optional[int] = None, itemsize: int = 2) -> str:
+    """Which blocked backward an unbiased :func:`flash_attention` of these
+    shapes and blocks runs: ``one_kernel`` where its working set (at the
+    geometry :func:`_bwd_prep` pads to) fits the budget, else
+    ``two_kernels`` (dq apart from dk/dv, each rebuilding the
+    probabilities, neither holding more than its tiles)."""
+    block_q = min(block_q, _round_up(q_len, 16))
+    fits = one_kernel_backward_vmem_bytes(
+        _round_up(q_len, block_q), _pad_head(dim), _pad_head(dim_v),
+        block_q=block_q, block_kv=min(block_kv, _round_up(kv_len, 16)),
+        block_b=_resolve_block_b(block_b, batch_heads), itemsize=itemsize,
+    ) <= ONE_KERNEL_VMEM_BUDGET
+    return "one_kernel" if fits else "two_kernels"
+
 
 def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
                            interpret, *, causal: bool = False,
                            block_b: Optional[int] = None):
     """Blocked backward; q/k/v/out/g are ``[B, L, H, D]``, lse is the padded
-    ``[B·H, q_len_p, 128]`` forward residual."""
+    ``[B·H, q_len_p, 128]`` forward residual. One Mosaic call where
+    :func:`backward_form` says its working set fits, else two."""
     if interpret is None:
         interpret = _backend.default_interpret()
 
     geom = _bwd_prep(q, k, v, out, g, block_q, block_kv)
-    qf, kf, vf, dof, delta = geom.qf, geom.kf, geom.vf, geom.dof, geom.delta
     q_len, kv_len = geom.q_len, geom.kv_len
     dim_p, block_q, block_kv = geom.dim_p, geom.block_q, geom.block_kv
     dim_v_p = geom.dim_v_p
@@ -584,6 +670,11 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
     num_kv_blocks = kv_len_p // block_kv
     bh = geom.batch * geom.heads
     block_b = _resolve_block_b(block_b, bh)
+    operands = (geom.qf, geom.kf, geom.vf, geom.dof, lse, geom.delta)
+    static = dict(
+        scale=scale, q_len=q_len, kv_len=kv_len, block_b=block_b,
+        block_q=block_q, block_kv=block_kv, causal=causal,
+    )
 
     # Under the causal mask a skipped cell names the block its neighbour
     # held, so nothing is fetched for it (see _flash_forward).
@@ -593,51 +684,15 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
     else:
         kv_index = lambda b, i, j: (b, j, 0)
         q_index2 = lambda b, j, i: (b, i, 0)
-    # q, k, dq, dk at the query/key head; v, dO, dv at the value head.
-    qspec = pl.BlockSpec((block_b, block_q, dim_p), lambda b, i, j: (b, i, 0))
-    dospec = pl.BlockSpec((block_b, block_q, dim_v_p), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((block_b, block_kv, dim_p), kv_index)
-    vspec = pl.BlockSpec((block_b, block_kv, dim_v_p), kv_index)
-    rowq = pl.BlockSpec((block_b, block_q, 128), lambda b, i, j: (b, i, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel,
-            scale=scale,
-            q_len=q_len,
-            kv_len=kv_len,
-            block_b=block_b,
-            block_q=block_q,
-            block_kv=block_kv,
-            num_kv_blocks=num_kv_blocks,
-            causal=causal,
-        ),
-        grid=(bh // block_b, num_q_blocks, num_kv_blocks),
-        in_specs=[qspec, kspec, vspec, dospec, rowq, rowq],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((bh, q_len_p, dim_p), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_b, block_q, dim_p), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lse, delta)
-
-    # q-innermost grid for dk/dv: block index 1 is the kv block, index 2
-    # sweeps q blocks into the accumulators.
+    # q-innermost grid: block index 1 is the kv block, index 2 sweeps q
+    # blocks into the dk/dv accumulators. q, k, dq, dk at the query/key
+    # head; v, dO, dv at the value head.
     qspec2 = pl.BlockSpec((block_b, block_q, dim_p), q_index2)
     dospec2 = pl.BlockSpec((block_b, block_q, dim_v_p), q_index2)
     kspec2 = pl.BlockSpec((block_b, block_kv, dim_p), lambda b, j, i: (b, j, 0))
     vspec2 = pl.BlockSpec((block_b, block_kv, dim_v_p), lambda b, j, i: (b, j, 0))
     rowq2 = pl.BlockSpec((block_b, block_q, 128), q_index2)
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel,
-            scale=scale,
-            q_len=q_len,
-            block_b=block_b,
-            block_q=block_q,
-            block_kv=block_kv,
-            num_q_blocks=num_q_blocks,
-            causal=causal,
-        ),
+    dkv = dict(
         grid=(bh // block_b, num_kv_blocks, num_q_blocks),
         in_specs=[qspec2, kspec2, vspec2, dospec2, rowq2, rowq2],
         out_specs=[kspec2, vspec2],
@@ -650,7 +705,52 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
             pltpu.VMEM((block_b, block_kv, dim_v_p), jnp.float32),
         ],
         interpret=interpret,
-    )(qf, kf, vf, dof, lse, delta)
+    )
+
+    form = backward_form(
+        q_len, kv_len, geom.dim, geom.dim_v, batch_heads=bh, block_q=block_q,
+        block_kv=block_kv, block_b=block_b, itemsize=q.dtype.itemsize,
+    )
+    dkv_kernel = functools.partial(
+        _bwd_dkv_kernel, num_q_blocks=num_q_blocks, num_kv_blocks=num_kv_blocks, **static
+    )
+    if form == "one_kernel":
+        # dq's block is the batch·head cell's whole sequence: its map reads
+        # the cell alone, and it is written back when the cell ends. Each
+        # gradient takes the HBM buffer of the padded operand it is the
+        # gradient of (the kernel has read a region for the last time
+        # before it writes it: q's cell when the cell ends, k's and v's
+        # block when its q sweep ends), so the three outputs of the one
+        # call add nothing to the step's live bytes.
+        one = dict(
+            dkv,
+            out_specs=[pl.BlockSpec((block_b, q_len_p, dim_p), lambda b, j, i: (b, 0, 0))] + dkv["out_specs"],
+            out_shape=[jax.ShapeDtypeStruct((bh, q_len_p, dim_p), q.dtype)] + dkv["out_shape"],
+            scratch_shapes=[pltpu.VMEM((block_b, num_q_blocks, block_q, dim_p), jnp.float32)]
+            + dkv["scratch_shapes"],
+        )
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(dkv_kernel, with_dq=True),
+            **one,
+            input_output_aliases={0: 0, 1: 1, 2: 2},
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_ONE_KERNEL_VMEM_LIMIT),
+        )(*operands)
+    else:
+        qspec = pl.BlockSpec((block_b, block_q, dim_p), lambda b, i, j: (b, i, 0))
+        dospec = pl.BlockSpec((block_b, block_q, dim_v_p), lambda b, i, j: (b, i, 0))
+        kspec = pl.BlockSpec((block_b, block_kv, dim_p), kv_index)
+        vspec = pl.BlockSpec((block_b, block_kv, dim_v_p), kv_index)
+        rowq = pl.BlockSpec((block_b, block_q, 128), lambda b, i, j: (b, i, 0))
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, num_kv_blocks=num_kv_blocks, **static),
+            grid=(bh // block_b, num_q_blocks, num_kv_blocks),
+            in_specs=[qspec, kspec, vspec, dospec, rowq, rowq],
+            out_specs=qspec,
+            out_shape=jax.ShapeDtypeStruct((bh, q_len_p, dim_p), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_b, block_q, dim_p), jnp.float32)],
+            interpret=interpret,
+        )(*operands)
+        dk, dv = pl.pallas_call(functools.partial(dkv_kernel, with_dq=False), **dkv)(*operands)
 
     return (
         geom.unprep(dq, q_len),
@@ -755,8 +855,8 @@ def flash_attention(
     bias: Optional[jax.Array] = None,
     *,
     scale: Optional[float] = None,
-    block_q: int = 256,
-    block_kv: int = 256,
+    block_q: int = DEFAULT_BLOCK,
+    block_kv: int = DEFAULT_BLOCK,
     interpret: Optional[bool] = None,
     causal: bool = False,
     block_b: Optional[int] = None,
@@ -772,9 +872,8 @@ def flash_attention(
       bias: optional additive logits bias, broadcastable to
         ``[B, heads, q_len, kv_len]`` (e.g. BoTNet relative-position logits).
       scale: logit scale, default ``head_dim ** -0.5``.
-      block_q / block_kv: VMEM tile sizes (clamped for short sequences).
-        Default 256: the v5e block sweep (now tools/attn_tune.py, PERF.md §5)
-        measured 256/256 ~1.6x faster than 128/128 at model-zoo shapes.
+      block_q / block_kv: VMEM tile sizes (clamped for short sequences),
+        :data:`DEFAULT_BLOCK` unless a measured entry says otherwise.
       interpret: force Pallas interpreter mode; default = auto (on for non-TPU).
       causal: position ``i`` attends to ``j <= i`` (``q_len == kv_len``);
         blocks above the diagonal are skipped, forward and backward.

@@ -113,6 +113,31 @@ def test_sweep_pins_block_b_through_backward_trace(attn_tune, monkeypatch):
     assert all(v == 4 for v in observed), observed
 
 
+def test_sweep_times_both_backward_forms_and_names_them(attn_tune, monkeypatch):
+    """A pallas configuration is timed once with the backward its own rule
+    picks and once with the two kernels, the form pinned while the backward
+    traces; each row and each record says which it ran."""
+    flmod = attn_tune.flmod
+    budgets = []
+    real_bwd = flmod._flash_backward_pallas
+
+    def spy(*a, **kw):
+        budgets.append(flmod.ONE_KERNEL_VMEM_BUDGET)
+        return real_bwd(*a, **kw)
+
+    monkeypatch.setattr(flmod, "_flash_backward_pallas", spy)
+    budget = flmod.ONE_KERNEL_VMEM_BUDGET
+    results, infeasible = attn_tune.sweep_shape(
+        (2, 24, 24, 2, 16),
+        blocks=[(16, 16)], block_bs=[1], backends=["pallas"],
+        iters=2, rounds=1, bwd=True, log=lambda *_: None,
+    )
+    assert not infeasible
+    assert [r["config"]["backward"] for r in results] == ["one_kernel", "two_kernels"]
+    assert [r["name"].rsplit("bwd=", 1)[1] for r in results] == ["one_kernel", "two_kernels"]
+    assert budgets == [budget, 0] and flmod.ONE_KERNEL_VMEM_BUDGET == budget
+
+
 def test_sweep_precheck_skips_over_budget_without_compiling(attn_tune):
     """Configs the VMEM estimator rules out are recorded infeasible
     without paying a compile (block_b=8 at a deliberately fat shape)."""

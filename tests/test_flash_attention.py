@@ -6,11 +6,26 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import importlib
+
 from sav_tpu.ops import flash_attention, xla_attention, relative_logits_2d
 from sav_tpu.ops.attention import dot_product_attention
 from sav_tpu.ops.relative import rel_to_abs
 
+# sav_tpu.ops re-exports the function under the module's name.
+flmod = importlib.import_module("sav_tpu.ops.flash_attention")
 
+
+
+def _pallas_calls(jaxpr) -> int:
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += eqn.primitive.name == "pallas_call"
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                total += _pallas_calls(inner)
+    return total
 
 
 def _qkv(b=2, lq=197, lk=None, h=4, d=64, dtype=jnp.float32, seed=0):
@@ -73,6 +88,20 @@ def test_flash_gradients_match_xla():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-4)
 
 
+def _grad_loss(fn, **kw):
+    return jax.grad(lambda q, k, v: jnp.sum(jnp.square(fn(q, k, v, **kw))), argnums=(0, 1, 2))
+
+
+@pytest.fixture(params=["one_kernel", "two_kernels"])
+def backward_form(request, monkeypatch):
+    """Run a case through each form of the blocked backward: the rule's own
+    choice at these sizes (one kernel), and the two kernels it falls back to
+    (a budget nothing fits: the program has no option for the form)."""
+    if request.param == "two_kernels":
+        monkeypatch.setattr(flmod, "ONE_KERNEL_VMEM_BUDGET", 0)
+    return request.param
+
+
 @pytest.mark.parametrize(
     "lq,lk,d,blk",
     [
@@ -88,37 +117,128 @@ def test_flash_gradients_match_xla():
     ],
 )
 @pytest.mark.slow
-def test_flash_blocked_backward_matches_xla(lq, lk, d, blk):
+def test_flash_blocked_backward_matches_xla(backward_form, lq, lk, d, blk):
     """No-bias gradients run the blocked Pallas backward kernels."""
     q, k, v = _qkv(lq=lq, lk=lk, d=d)
     kw = {} if blk is None else {"block_q": blk, "block_kv": blk}
-
-    def loss_f(fn):
-        return lambda q, k, v: jnp.sum(jnp.square(fn(q, k, v, **kw)))
-
-    gf = jax.grad(loss_f(flash_attention), argnums=(0, 1, 2))(q, k, v)
-    gx = jax.grad(loss_f(lambda q, k, v, **_: xla_attention(q, k, v)),
-                  argnums=(0, 1, 2))(q, k, v)
+    gf = _grad_loss(flash_attention, **kw)(q, k, v)
+    gx = _grad_loss(xla_attention)(q, k, v)
     for a, b in zip(gf, gx):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-4, rtol=5e-4
         )
 
 
-@pytest.mark.slow
-def test_flash_blocked_backward_bf16_finite_and_close():
+# (lq, d, dv, causal, block_q, block_kv, block_b): every case has several q
+# and several kv blocks, so the one-kernel form comes back to each q block's
+# resident dq once per kv block (and skips the blocks above the diagonal).
+BOTH_FORMS_CASES = [
+    (256, 128, 128, True, 64, 64, 1),  # the looped model's head size
+    (256, 128, 128, False, 64, 64, 1),
+    (192, 192, 128, True, 64, 64, 1),  # the latent pair: a 192-lane q/k head left at 192
+    (192, 192, 128, False, 64, 64, 1),
+    (200, 64, 64, True, 64, 64, 1),  # padded q rows and kv columns
+    (200, 64, 64, False, 64, 128, 1),  # padded, and a kv block of two q blocks
+    (200, 48, 32, True, 128, 64, 2),  # block_b 2, a q block of two kv blocks, heads padded apart
+    (256, 128, 128, True, 64, 64, 2),
+]
+
+
+@pytest.mark.parametrize("lq,d,dv,causal,block_q,block_kv,block_b", BOTH_FORMS_CASES)
+def test_flash_blocked_backward_matches_xla_in_both_forms(
+    backward_form, lq, d, dv, causal, block_q, block_kv, block_b
+):
+    """dq, dk, dv of the one-kernel backward and of the two kernels against
+    the dense path's, to the tolerances of the cases above."""
+    q, k, _ = _qkv(b=1, lq=lq, h=2, d=d)
+    v = jax.random.normal(jax.random.PRNGKey(3), (1, lq, 2, dv))
+    grad = _grad_loss(
+        flash_attention, causal=causal, block_q=block_q, block_kv=block_kv, block_b=block_b
+    )
+    jaxpr = jax.make_jaxpr(grad)(q, k, v)
+    assert _pallas_calls(jaxpr.jaxpr) == (2 if backward_form == "one_kernel" else 3)
+    gx = _grad_loss(xla_attention, causal=causal)(q, k, v)
+    for a, b in zip(grad(q, k, v), gx):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=5e-4)
+
+
+def _bf16_backward_close(**kw):
     q, k, v = _qkv(lq=197, lk=197, d=64, dtype=jnp.bfloat16)
 
     def loss(fn, q, k, v):
         return jnp.sum(jnp.square(fn(q, k, v).astype(jnp.float32)))
 
-    gf = jax.grad(lambda *a: loss(flash_attention, *a), argnums=(0, 1, 2))(q, k, v)
+    gf = jax.grad(lambda *a: loss(lambda *b: flash_attention(*b, **kw), *a), argnums=(0, 1, 2))(q, k, v)
     gx = jax.grad(lambda *a: loss(xla_attention, *a), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gx):
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
         assert np.isfinite(a).all()
         # bf16 tolerance: both paths quantize differently.
         np.testing.assert_allclose(a, b, atol=0.15, rtol=0.15)
+
+
+@pytest.mark.slow
+def test_flash_blocked_backward_bf16_finite_and_close():
+    _bf16_backward_close()
+
+
+def test_flash_blocked_backward_bf16_finite_and_close_in_both_forms(backward_form):
+    """bf16 operands, several blocks each way: ``p`` and ``ds`` go to the
+    three output matmuls in bf16 and every sum stays float32, in both forms."""
+    _bf16_backward_close(block_q=64, block_kv=64)
+
+
+def test_backward_form_follows_what_the_resident_dq_needs():
+    """The rule's two sides, from sizes alone: the token cells' shapes at
+    their tuned tiles fit (the whole float32 dq of a batch·head cell beside
+    the tiles), a ring shard's 65,536 rows do not, and ``block_b`` counts."""
+    cell = dict(batch_heads=64, block_q=1024, block_kv=1024, block_b=1)
+    assert flmod.backward_form(4096, 4096, 192, 128, **cell) == "one_kernel"
+    assert flmod.backward_form(4096, 4096, 128, 128, **cell) == "one_kernel"
+    assert flmod.backward_form(65536, 65536, 128, 128, **cell) == "two_kernels"
+    assert flmod.backward_form(16384, 16384, 64, 64, batch_heads=12, block_b=1) == "one_kernel"
+    assert flmod.backward_form(16384, 16384, 64, 64, batch_heads=12) == "two_kernels"  # block_b 4 by default
+    # dq alone: 65,536 rows x 128 lanes x (4 bytes resident + 2 x 2 out) is 64 MiB.
+    small = dict(block_q=128, block_kv=128, block_b=1)
+    assert flmod.one_kernel_backward_vmem_bytes(65536, 128, 128, **small) > 64 * 2**20 > flmod.ONE_KERNEL_VMEM_BUDGET
+    assert flmod.one_kernel_backward_vmem_bytes(4096, 192, 128, block_q=1024, block_kv=1024) < flmod.ONE_KERNEL_VMEM_BUDGET
+    assert flmod.ONE_KERNEL_VMEM_BUDGET < flmod._ONE_KERNEL_VMEM_LIMIT <= 128 * 2**20
+
+
+def test_a_dq_that_does_not_fit_runs_the_two_kernels(monkeypatch):
+    """The same call on each side of the rule: a budget between the two
+    lengths' needs sends the longer one to two calls, with the same
+    gradients to float32 rounding."""
+    need = lambda lq: flmod.one_kernel_backward_vmem_bytes(lq, 128, 128, block_q=64, block_kv=64, itemsize=4)
+    monkeypatch.setattr(flmod, "ONE_KERNEL_VMEM_BUDGET", (need(128) + need(512)) // 2)
+    grad = _grad_loss(flash_attention, causal=True, block_q=64, block_kv=64, block_b=1)
+    calls = {}
+    for lq in (128, 512):
+        q, k, v = _qkv(b=1, lq=lq, h=1, d=128)
+        calls[lq] = _pallas_calls(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+    assert calls == {128: 2, 512: 3}
+    gx = _grad_loss(xla_attention, causal=True)(q, k, v)
+    for a, b in zip(grad(q, k, v), gx):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=5e-4)
+
+
+def test_dispatch_log_names_the_backward_the_pallas_entry_runs(monkeypatch):
+    from sav_tpu.ops import attention as att
+
+    q, k, v = _qkv(lq=64, lk=64, d=32)
+    bias = jnp.zeros((1, 1, 64, 64))
+    att.clear_dispatch_log()
+    dot_product_attention(q, k, v, backend="pallas")
+    dot_product_attention(q[:, :32], k, v, bias[:, :, :32], backend="pallas")  # biased: the dense recompute
+    dot_product_attention(q, k, v, backend="xla")
+    monkeypatch.setattr(flmod, "ONE_KERNEL_VMEM_BUDGET", 0)
+    dot_product_attention(q, k[:, :48], v[:, :48], backend="pallas")
+    log = {(e["shape"][1], e["kv_len"], e["backend"]): e.get("backward") for e in att.snapshot_dispatch_log()}
+    att.clear_dispatch_log()
+    assert log == {
+        (64, 64, "pallas"): "one_kernel", (32, 64, "pallas"): None,
+        (64, 64, "xla"): None, (64, 48, "pallas"): "two_kernels",
+    }
 
 
 @pytest.mark.slow
@@ -321,22 +441,11 @@ def test_logits_dtype_default_knob():
     np.testing.assert_allclose(hi, ref, atol=2e-5, rtol=2e-5)
 
 
-def _pallas_calls(jaxpr) -> int:
-    total = 0
-    for eqn in jaxpr.eqns:
-        total += eqn.primitive.name == "pallas_call"
-        for value in eqn.params.values():
-            inner = getattr(value, "jaxpr", value)
-            if hasattr(inner, "eqns"):
-                total += _pallas_calls(inner)
-    return total
-
-
-@pytest.mark.parametrize("kept,calls", [(("flash_out", "flash_lse"), 3), (("flash_out",), 4), ((), 4)])
+@pytest.mark.parametrize("kept,calls", [(("flash_out", "flash_lse"), 2), (("flash_out",), 3), ((), 3)])
 def test_checkpoint_policy_keeps_the_forward_kernels_residuals(kept, calls):
     """The forward rule names its output and its logsumexp: a
     ``jax.checkpoint`` policy that lists both differentiates through one
-    forward kernel call (and dq, dk/dv); one that misses either runs the
+    forward kernel call (and the one backward call); one that misses either runs the
     forward a second time for it."""
     q, k, v = _qkv(b=1, lq=64, h=2, d=32)
 

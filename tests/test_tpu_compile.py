@@ -241,6 +241,78 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shape, direction):
     assert _bytes_on_device(compiled) < HBM_BYTES
 
 
+def _kernel_vmem(compiled, which: str) -> list:
+    """Bytes of scoped VMEM each Mosaic call of a compiled program was
+    given (``scoped_memory_configs``: none where the call sets no limit of
+    its own) or used (``used_scoped_memory_configs``)."""
+    pattern = re.compile(r'"%s":\[\{"memory_space":"1","offset":"0","size":"(\d+)"' % which)
+    calls = [
+        line for line in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    return [int(found.group(1)) if (found := pattern.search(line)) else None for line in calls]
+
+
+# (name, [B, L, H, D(, Dv)], flash_attention's blocks, the form the rule picks)
+BACKWARD_FORM_CASES = [
+    ("ouro_4k", (2, 4096, 16, 128), dict(block_q=1024, block_kv=1024, block_b=1), "one_kernel"),
+    ("joyai_4k", (2, 4096, 32, 192, 128), dict(block_q=1024, block_kv=1024, block_b=1), "one_kernel"),
+    ("deit_s", (256, 197, 6, 64), {}, "one_kernel"),
+    # A ring shard's length at the default blocks: block_b 4 whole float32
+    # dq of 16,384 rows do not fit beside their tiles.
+    ("ring_shard_16k", (2, 16384, 6, 64), {}, "two_kernels"),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,blocks,form", [case[1:] for case in BACKWARD_FORM_CASES],
+    ids=[case[0] for case in BACKWARD_FORM_CASES],
+)
+def test_flash_backward_compiles_in_the_form_its_rule_picks(one_chip, shape, blocks, form):
+    """The blocked backward alone: one Mosaic call under its own VMEM limit
+    where the rule says the resident dq fits, within the rule's estimate of
+    what it uses and with the gradients in the operands' buffers; else the
+    two calls under the default limit."""
+    import importlib
+
+    flmod = importlib.import_module("sav_tpu.ops.flash_attention")
+    batch, length, heads, dim = shape[:4]
+    dim_v = shape[4] if len(shape) == 5 else dim
+    assert form == flmod.backward_form(
+        length, length, dim, dim_v, batch_heads=batch * heads, **blocks
+    )
+
+    def spec(d, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((batch, length, heads, d), dtype, sharding=one_chip)
+
+    block_q = min(blocks.get("block_q", 256), -(-length // 16) * 16)
+    length_p = -(-length // block_q) * block_q
+    lse = jax.ShapeDtypeStruct((batch * heads, length_p, 128), jnp.float32, sharding=one_chip)
+
+    def backward(q, k, v, out, g, lse):
+        return flmod._flash_backward_pallas(
+            q, k, v, out, lse, g, dim ** -0.5, blocks.get("block_q", 256),
+            blocks.get("block_kv", 256), False, causal=True, block_b=blocks.get("block_b"),
+        )
+
+    compiled = jax.jit(backward).lower(
+        spec(dim), spec(dim), spec(dim_v), spec(dim_v), spec(dim_v), lse
+    ).compile()
+    limits, used = _kernel_vmem(compiled, "scoped_memory_configs"), _kernel_vmem(compiled, "used_scoped_memory_configs")
+    if form == "two_kernels":
+        assert limits == [None, None] and max(used) <= 16 * 2**20
+        return
+    assert limits == [flmod._ONE_KERNEL_VMEM_LIMIT]
+    # dq, dk, dv are written over the padded q, k, v: no HBM of their own.
+    assert "output_to_operand_aliasing={{0}: (0, {}), {1}: (1, {}), {2}: (2, {})}" in compiled.as_text()
+    estimate = flmod.one_kernel_backward_vmem_bytes(
+        length_p, flmod._pad_head(dim), flmod._pad_head(dim_v), block_q=block_q,
+        block_kv=min(blocks.get("block_kv", 256), length_p),
+        block_b=flmod._resolve_block_b(blocks.get("block_b"), batch * heads),
+    )
+    assert 0.45 * estimate <= used[0] <= estimate <= flmod.ONE_KERNEL_VMEM_BUDGET
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 def test_ring_attention_compiles_for_four_chips(topo, backend, direction):
@@ -389,9 +461,9 @@ def test_looped_lm_train_step_compiles_and_fits_one_chip(topo, compiled_kernels,
     compiled, _ = _looped_lm_step(topo, monkeypatch)
     assert _bytes_on_device(compiled) < HBM_BYTES
     text = compiled.as_text()
-    # 4 passes x (forward, dq, dk/dv): no recomputed forward
+    # 4 passes x (forward, backward): no recomputed forward
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 12
+    assert len(calls) == 8
     assert not any("rematted_computation" in line for line in calls)
     assert "rematted_computation" in text  # the norms and the gated product are computed again
     assert " while(" not in text  # a loop's event would count its body twice in a trace

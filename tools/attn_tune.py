@@ -107,7 +107,7 @@ def _parse_shape(spec: str):
 
 
 def variant_specs(b, lq, lkv, h, d, *, blocks, block_bs, backends, itemsize,
-                  causal=False, one_head_size=True):
+                  causal=False, dv=None):
     """Yield (name, backend, config, builder) for every candidate; builder
     returns the (q, k, v) -> out callable. Configs the VMEM estimator
     rules out are yielded with builder=None (recorded infeasible for free).
@@ -115,11 +115,12 @@ def variant_specs(b, lq, lkv, h, d, *, blocks, block_bs, backends, itemsize,
     of a sweep that asks for either.
     """
     bh = b * h
+    dv = d if dv is None else dv
     if "xla" in backends:
         yield "xla", "xla", None, lambda: (
             lambda q, k, v: att.xla_attention(q, k, v, causal=causal)
         )
-    if "fused" in backends and not causal and one_head_size:
+    if "fused" in backends and not causal and dv == d:
         for bq, _ in blocks:
             for bb in block_bs:
                 if b % bb != 0:  # the fused kernel's cells hold batch elements
@@ -145,35 +146,48 @@ def variant_specs(b, lq, lkv, h, d, *, blocks, block_bs, backends, itemsize,
             for bb in block_bs:
                 if bh % bb != 0:
                     continue
-                cfg = {"block_q": bq, "block_kv": bkv, "block_b": bb}
-                name = f"pallas bq={bq} bkv={bkv} bb={bb}"
-                yield name, "pallas", cfg, (
+                build = (
                     lambda bq=bq, bkv=bkv: lambda q, k, v: flmod.flash_attention(
                         q, k, v, block_q=bq, block_kv=bkv, causal=causal
                     )
                 )
+                # Each form of the blocked backward the shape can run: the
+                # one the kernel's own rule picks, and where that is the
+                # one-kernel form, the two kernels as well (pinned for the
+                # variant's compile, as block_b is).
+                form = flmod.backward_form(
+                    lq, lkv, d, dv, batch_heads=bh, block_q=bq, block_kv=bkv,
+                    block_b=bb, itemsize=itemsize,
+                )
+                for bwd_form in dict.fromkeys((form, "two_kernels")):
+                    cfg = {"block_q": bq, "block_kv": bkv, "block_b": bb, "backward": bwd_form}
+                    yield f"pallas bq={bq} bkv={bkv} bb={bb} bwd={bwd_form}", "pallas", cfg, build
 
 
-class _pin_flash_block_b:
-    """Pin the flash kernel's internal block_b choice for the duration of
-    a variant's COMPILE (make_loop traces fwd AND bwd inside this scope —
-    the backward's own _pick_block_b call at vjp-trace time must see the
-    swept value too, not the default). A no-op for block_b=None."""
+class _pin_flash:
+    """Pin the flash kernel's internal block_b choice, and its backward's
+    two-kernel form where the variant asks for it, for the duration of a
+    variant's COMPILE (make_loop traces fwd AND bwd inside this scope —
+    the backward's own _pick_block_b call and its choice of form at
+    vjp-trace time must see the swept values too, not the defaults).
+    A no-op for block_b=None and the rule's own form."""
 
-    def __init__(self, bb):
-        self.bb = bb
+    def __init__(self, bb, two_kernels=False):
+        self.bb, self.two_kernels = bb, two_kernels
 
     def __enter__(self):
-        self.orig = flmod._pick_block_b
+        self.orig = flmod._pick_block_b, flmod.ONE_KERNEL_VMEM_BUDGET
         if self.bb is not None:
             bb = self.bb
             flmod._pick_block_b = (
                 lambda bh_, *, force_one=False: 1 if force_one else bb
             )
+        if self.two_kernels:
+            flmod.ONE_KERNEL_VMEM_BUDGET = 0
         return self
 
     def __exit__(self, *exc):
-        flmod._pick_block_b = self.orig
+        flmod._pick_block_b, flmod.ONE_KERNEL_VMEM_BUDGET = self.orig
         return False
 
 
@@ -192,20 +206,21 @@ def sweep_shape(shape, *, blocks, block_bs, backends, iters, rounds,
     for name, backend, cfg, build in variant_specs(
         b, lq, lkv, h, d, blocks=blocks, block_bs=block_bs,
         backends=backends, itemsize=jnp.dtype(dtype).itemsize,
-        causal=causal, one_head_size=dv == d,
+        causal=causal, dv=dv,
     ):
         if build is None:
             infeasible.append({
                 "backend": backend, **(cfg or {}),
                 "error": "VMEM estimate over budget (fused_vmem_bytes)",
             })
-            log(f"  {name:28s} INFEASIBLE (vmem estimate)")
+            log(f"  {name:46s} INFEASIBLE (vmem estimate)")
             continue
         pin_bb = (cfg or {}).get("block_b") if backend == "pallas" else None
+        two_kernels = (cfg or {}).get("backward") == "two_kernels"
         try:
             fn = build()
             entry = {"name": name, "backend": backend, "config": cfg}
-            with _pin_flash_block_b(pin_bb):
+            with _pin_flash(pin_bb, two_kernels):
                 entry["_fwd"] = make_loop(fn, (q, k, v), iters)
                 if bwd:
                     entry["_bwd"] = make_loop(
@@ -217,7 +232,7 @@ def sweep_shape(shape, *, blocks, block_bs, backends, iters, rounds,
                 "backend": backend, **(cfg or {}),
                 "error": f"{type(e).__name__}: {e}"[:300],
             })
-            log(f"  {name:28s} INFEASIBLE ({type(e).__name__})")
+            log(f"  {name:46s} INFEASIBLE ({type(e).__name__})")
 
     # Round-robin interleave with rotated start (Trap 3); per-variant minima.
     keys = [
@@ -246,7 +261,7 @@ def sweep_shape(shape, *, blocks, block_bs, backends, iters, rounds,
         }
         results.append(res)
         log(
-            f"  {name:28s} fwd {res['fwd_ms']:8.3f} ms"
+            f"  {name:46s} fwd {res['fwd_ms']:8.3f} ms"
             + (
                 f"   fwd+bwd {res['fwd_bwd_ms']:8.3f} ms"
                 if res["fwd_bwd_ms"] is not None

@@ -32,8 +32,8 @@ a ``{"data": 2, "model": 2}`` mesh against the same three steps on a
 one-device mesh.
 
 Every phase prints one JSON line naming it (device kind, compile seconds,
-compile-cache entries before and after, step time, HBM statistics, served p50,
-agreement errors). None of those numbers is a benchmark result. The last
+what the backend compiled and what the compile cache answered, step time,
+HBM statistics, served p50, agreement errors). None of those numbers is a benchmark result. The last
 line of stdout is the verdict, and only on success:
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
@@ -134,14 +134,23 @@ def _hbm_stats():
     return {k: top[k] for k in keys if k in top}
 
 
-def _cache_state() -> dict:
-    from sav_tpu.utils.compile_cache import (
-        count_cache_entries,
-        resolve_cache_dir,
-    )
+def _cache_dir():
+    from sav_tpu.utils.compile_cache import resolve_cache_dir
 
-    cache_dir = resolve_cache_dir()
-    return {"dir": cache_dir, "entries": count_cache_entries(cache_dir)}
+    return resolve_cache_dir()
+
+
+def _compiles(since: float, until=None) -> dict:
+    """What the process traced, compiled and loaded from the cache between
+    two readings of ``time.perf_counter``, from its compile log."""
+    from sav_tpu.obs import compile_log
+
+    summary = compile_log.summary(since, until)
+    return {
+        key: round(summary[key], 2) if key.endswith("_s") else summary[key]
+        for key in ("trace_lower_s", "backend_compile_s", "cache_load_s",
+                    "cache_hits", "cache_misses", "cache_off")
+    }
 
 
 def _read_json(path: str) -> dict:
@@ -173,7 +182,6 @@ def phase_train(size: dict, workdir: str) -> str:
     log_dir = os.path.join(workdir, "train")
     runs = []
     for steps in (size["first_steps"], size["total_steps"]):
-        cache_before = _cache_state()
         argv = [
             "--synth-data",
             "-m", size["model"],
@@ -214,8 +222,7 @@ def phase_train(size: dict, workdir: str) -> str:
             "wall_s": round(wall_s, 2),
             "compile_s": metrics.get("goodput/compile_s"),
             "input_wait_s": metrics.get("goodput/input_wait_s"),
-            "cache_entries_before": cache_before["entries"],
-            "cache_entries_after": _cache_state()["entries"],
+            "compiles": _compiles(t0),
         })
     first, second = runs
     check(first["resumed_from"] == 0, f"first run resumed from {first['resumed_from']}")
@@ -247,7 +254,7 @@ def phase_train(size: dict, workdir: str) -> str:
         loss_first_run=first["loss"],
         loss_second_run=second["loss"],
         checkpoints=saved,
-        cache_dir=_cache_state()["dir"],
+        cache_dir=_cache_dir(),
         runs=runs,
         hbm=_hbm_stats(),
     )
@@ -274,7 +281,7 @@ def phase_serve(size: dict, workdir: str, ckpt_dir: str) -> None:
         "--deadline-ms", str(size["deadline_ms"]),
         "--manifest", os.path.join(serve_dir, "manifest.json"),
     ]
-    cache_before = _cache_state()
+    t0 = time.perf_counter()
     captured = io.StringIO()
     with contextlib.redirect_stdout(captured):
         rc = serve_bench.main(argv)
@@ -317,8 +324,7 @@ def phase_serve(size: dict, workdir: str, ckpt_dir: str) -> None:
         compile_s=startup["compile_s"],
         compiled_from_scratch=startup["compiled_from_scratch"],
         cache_hits=startup["cache_hits"],
-        cache_entries_before=cache_before["entries"],
-        cache_entries_after=_cache_state()["entries"],
+        compiles=_compiles(t0),
         bucket_hbm_bytes=startup["bucket_hbm_bytes"],
         hbm=_hbm_stats(),
     )
@@ -405,12 +411,11 @@ def phase_kernels(size: dict, *, expect_custom_call: bool = True) -> None:
             return jnp.mean(logits * cotangent), logits
 
         step = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))
-        cache_before = _cache_state()
-        t0 = time.perf_counter()
+        compile_t0 = time.perf_counter()
         lowered = step.lower(params, x, cotangent)
         custom_calls = lowered.as_text().count("tpu_custom_call")
         compiled = lowered.compile()
-        compile_s = time.perf_counter() - t0
+        compile_s = time.perf_counter() - compile_t0
         (_, logits), grads = compiled(params, x, cotangent)
         leaf_name, leaf = leaf_of(grads)
         results[backend] = (np.asarray(logits), np.asarray(leaf))
@@ -428,8 +433,7 @@ def phase_kernels(size: dict, *, expect_custom_call: bool = True) -> None:
             "tpu_custom_calls": custom_calls,
             "compile_s": round(compile_s, 2),
             "fwd_bwd_ms": round((time.perf_counter() - t0) * 1e3, 2),
-            "cache_entries_before": cache_before["entries"],
-            "cache_entries_after": _cache_state()["entries"],
+            "compiles": _compiles(compile_t0),
         }
     check(
         float(np.abs(results["xla"][0]).max()) > 0.0,
@@ -547,7 +551,7 @@ def phase_sharded_train(size: dict) -> None:
             losses.append(float(jax.device_get(metrics["loss"])))
         return state, step, losses, compile_s
 
-    cache_before = _cache_state()
+    sharded_t0 = time.perf_counter()
     sharded_trainer = make_trainer({"data": 2, "model": 2})
     state, step, sharded_losses, sharded_compile_s = run(sharded_trainer)
     text = step.as_text()
@@ -604,7 +608,7 @@ def phase_sharded_train(size: dict) -> None:
             all(memory[d.id] for d in devices),
             f"bytes_in_use per device {memory}: a device holds nothing",
         )
-    sharded_cache_after = _cache_state()
+    single_t0 = time.perf_counter()
     del state, step
 
     single_trainer = make_trainer(
@@ -647,9 +651,8 @@ def phase_sharded_train(size: dict) -> None:
         bytes_in_use_per_device=memory,
         sharded_compile_s=round(sharded_compile_s, 2),
         single_compile_s=round(single_compile_s, 2),
-        cache_entries_before=cache_before["entries"],
-        cache_entries_after_sharded=sharded_cache_after["entries"],
-        cache_entries_after=_cache_state()["entries"],
+        compiles_sharded=_compiles(sharded_t0, single_t0),
+        compiles_single=_compiles(single_t0),
         hbm=_hbm_stats(),
     )
 
@@ -660,13 +663,15 @@ def phase_sharded_train(size: dict) -> None:
 def run_smoke(chips: int, size: dict, workdir: str) -> dict:
     """Every phase for ``chips``; returns the device for the verdict."""
     device = require_tpu(chips)
+    from sav_tpu.obs import compile_log
     from sav_tpu.utils.compile_cache import enable_persistent_cache
 
     # Before the first compile of the process. The Trainer and the serve
-    # engine apply the same rule themselves; the kernels phase compiles
-    # outside both.
+    # engine apply the same rule and start the compile log themselves; the
+    # kernels phase compiles outside both.
     enable_persistent_cache()
-    emit("device", **device, cache=_cache_state())
+    compile_log.listen()
+    emit("device", **device, cache_dir=_cache_dir())
     shutil.rmtree(workdir, ignore_errors=True)
     os.makedirs(workdir)
     if chips == 4:

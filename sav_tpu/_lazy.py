@@ -14,7 +14,9 @@ Every import resolved here is a phase span of the process timeline
 (``sav:startup/import:<module>``, :mod:`sav_tpu.obs.spans`): the seam
 through which the package's heavy modules are first loaded is where
 start-up's import seconds are measured. Outermost only: a lazy import made
-while another resolves is part of that one's span.
+while another resolves is part of that one's span. While it resolves the
+span counts as open on its thread, so what a module compiles as it is
+imported is put down to its import (``obs/compile_log.py``).
 
 Stdlib-only, and importing it only executes ``sav_tpu/__init__``'s
 docstring — free on every path.
@@ -37,15 +39,19 @@ def _import(target: str):
     with a bare clock pair: nothing else runs before or around the import."""
     if target in sys.modules or getattr(_resolving, "active", False):
         return importlib.import_module(target)
+    # Stdlib-only, like this module; by its full name, so that the package's
+    # own lazy ``__getattr__`` is not what resolves it.
+    from sav_tpu.obs.spans import pop_open, push_open, record_phase
+
     _resolving.active = True
+    push_open("startup/import:" + target)
     start = time.perf_counter()
     try:
         return importlib.import_module(target)
     finally:
         end = time.perf_counter()
+        pop_open()
         _resolving.active = False
-        from sav_tpu.obs.spans import record_phase  # stdlib-only, like this module
-
         record_phase("startup/import:" + target, start, end)
 
 

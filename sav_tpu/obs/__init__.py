@@ -9,6 +9,9 @@ Five signals, one design rule each:
 - :mod:`sav_tpu.obs.spans` — **host-side** span tracer emitting
   Chrome-trace-event JSON (Perfetto-loadable) around ``fit()``'s phases,
   so input-bound vs compute-bound is diagnosable without an XPlane capture.
+- :mod:`sav_tpu.obs.compile_log` — what jax traced, lowered, compiled or
+  loaded from the persistent cache, on the spans' clock, each record with
+  the phase span that caused it (fed by jax's own monitoring events).
 - :mod:`sav_tpu.obs.goodput` — wall-time ledger splitting a run into
   compile / step / input-wait / eval / checkpoint / stall buckets, with
   per-window anomaly flags for transient slowdowns.
@@ -37,8 +40,8 @@ Five signals, one design rule each:
   incidents and stamped into the run manifest.
 
 Re-exports are lazy (PEP 562, same pattern as :mod:`sav_tpu.utils`):
-:mod:`spans`, :mod:`goodput`, and :mod:`watchdog` are stdlib-only and must
-stay importable without dragging ``jax`` into the process.
+:mod:`spans`, :mod:`compile_log`, :mod:`goodput`, and :mod:`watchdog` are
+stdlib-only and must stay importable without dragging ``jax`` into the process.
 """
 
 from __future__ import annotations
@@ -75,6 +78,6 @@ __all__ = list(_EXPORTS)
 __getattr__, __dir__ = install_lazy_exports(
     globals(),
     _EXPORTS,
-    {"diagnostics", "spans", "goodput", "memory", "watchdog", "costs",
-     "manifest", "recorder"},
+    {"diagnostics", "spans", "compile_log", "goodput", "memory", "watchdog",
+     "costs", "manifest", "recorder"},
 )

@@ -19,7 +19,10 @@ also enter the process timeline: a bounded, always-on list of finished
 spans on ``time.perf_counter``, for what happens before any profiler can
 run. :func:`timeline` reads it. Per-step spans never enter it. The lazy
 imports of ``sav_tpu/_lazy.py`` enter it through :func:`record_phase`,
-timed there with a bare clock pair.
+timed there with a bare clock pair. While a phase span is open its name is
+on its thread's stack (:func:`open_phase`): the compile log
+(``obs/compile_log.py``) names it as the cause of what jax compiles
+meanwhile. Per-step spans never touch the stack either.
 
 Stdlib-only at import: the supervisor and the serve pool import this
 package without ``jax``. The annotation class is looked up only once
@@ -43,6 +46,7 @@ TIMELINE_MAX = 256
 
 _timeline: deque = deque(maxlen=TIMELINE_MAX)
 _timeline_lock = threading.Lock()
+_open = threading.local()
 _annotation_cls = None
 
 
@@ -62,6 +66,30 @@ def record_phase(name: str, start: float, end: float) -> None:
     """Enter a finished phase span, timed by the caller on
     ``time.perf_counter``, in the process timeline as ``sav:<name>``."""
     _keep(PREFIX + name, start, end)
+
+
+def _push(name: str) -> None:
+    try:
+        _open.stack.append(name)
+    except AttributeError:
+        _open.stack = [name]
+
+
+def push_open(name: str) -> None:
+    """``sav:<name>`` is open on this thread from now until :func:`pop_open`:
+    for a phase that is timed by its caller and entered by
+    :func:`record_phase`."""
+    _push(PREFIX + name)
+
+
+def pop_open() -> None:
+    _open.stack.pop()
+
+
+def open_phase() -> Optional[str]:
+    """The innermost phase span open on this thread, None where none is."""
+    stack = getattr(_open, "stack", None)
+    return stack[-1] if stack else None
 
 
 def _annotation(name: str):
@@ -109,10 +137,27 @@ class _Span:
         return False
 
 
+class _Phase(_Span):
+    """A span of the process timeline: open on its thread's stack while it
+    runs. A few dozen a run; the loop's spans stay plain ``_Span``s."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        _push(self.name)
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            return super().__exit__(*exc)
+        finally:
+            pop_open()
+
+
 def phase(name: str) -> _Span:
     """A phase span with no tracer behind it (start-up, constructors): the
     annotation and the timeline only."""
-    return _Span(None, name, None, True, None)
+    return _Phase(None, name, None, True, None)
 
 
 def in_phase(name: str):
@@ -145,6 +190,7 @@ class SpanTracer:
         self.ledger = ledger
         self._events: list[dict] = []
         self._lock = threading.Lock()
+        self._made = time.perf_counter()
         if self.enabled:
             # Metadata event names the process row in the Perfetto UI.
             self._events.append({
@@ -161,7 +207,7 @@ class SpanTracer:
         process timeline; ``args`` go to the Chrome event."""
         if bucket is not None and self.ledger is None:
             raise ValueError(f"span {name!r} names bucket {bucket!r} but the tracer has no ledger")
-        return _Span(self, name, bucket, in_timeline, args)
+        return (_Phase if in_timeline else _Span)(self, name, bucket, in_timeline, args)
 
     def _append(self, event: dict, args) -> None:
         event.update(pid=os.getpid(), tid=threading.get_ident())
@@ -194,6 +240,18 @@ class SpanTracer:
             return None
         with self._lock:
             events = list(self._events)
+        # What jax traced, lowered and compiled since this tracer was made,
+        # by name beside the spans, on their clock.
+        from sav_tpu.obs import compile_log
+
+        pid = os.getpid()
+        for record in compile_log.log(since=self._made):
+            args = {k: record[k] for k in ("cause", "cache") if k in record}
+            events.append({
+                "name": f"{PREFIX}compile/{record['kind']}:{record['fun_name']}", "ph": "X",
+                "ts": record["start"] * 1e6, "dur": (record["end"] - record["start"]) * 1e6,
+                "pid": pid, "tid": record["thread"], "args": args,
+            })
         parent = os.path.dirname(self.path)
         if parent:
             os.makedirs(parent, exist_ok=True)

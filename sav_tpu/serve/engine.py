@@ -234,10 +234,8 @@ class ServeEngine:
         self.ladder = config.ladder()
         self.place_hook = place_hook
         self.execute_hook = execute_hook
-        from sav_tpu.utils.compile_cache import (
-            count_cache_entries,
-            enable_persistent_cache,
-        )
+        from sav_tpu.obs import compile_log
+        from sav_tpu.utils.compile_cache import enable_persistent_cache
 
         # min_compile_time 0: jax's 1 s default floor is tuned for
         # training (don't litter the cache with trivial programs), but a
@@ -246,7 +244,7 @@ class ServeEngine:
         cache_dir = enable_persistent_cache(
             config.compilation_cache_dir, min_compile_time_secs=0.0
         )
-        cache_before = count_cache_entries(cache_dir)
+        compile_log.listen()
         if config.attention_tune_cache:
             from sav_tpu.ops.attn_tuning import set_cache_path
 
@@ -390,7 +388,6 @@ class ServeEngine:
         )
         # ---- AOT: one executable per bucket, warmed from the cache ----
         compile_t0 = time.perf_counter()
-        cache_pre_aot = count_cache_entries(cache_dir)
         self._executables: dict = {}
         from sav_tpu.ops.attention import partitioned_over
 
@@ -403,7 +400,9 @@ class ServeEngine:
                 )
                 self._executables[bucket] = lowered.compile()
         compile_s = time.perf_counter() - compile_t0
-        cache_after = count_cache_entries(cache_dir)
+        # The loop's backend compiles by the cache's answer, from the
+        # process's compile log.
+        compiled = compile_log.summary(since=compile_t0)
         # Per-bucket executable HBM estimate (ride-along fix: the report
         # used to say nothing about how much device memory each rung
         # costs, so a ladder that barely fit was invisible until the
@@ -458,11 +457,6 @@ class ServeEngine:
                 )
             )
             self._step_est[bucket] = time.perf_counter() - t
-        scratch = (
-            cache_after - cache_pre_aot
-            if (cache_after is not None and cache_pre_aot is not None)
-            else None
-        )
         self.startup_report = {
             "model": config.model_name,
             "layout": self.layout.name,
@@ -479,15 +473,12 @@ class ServeEngine:
                 str(b): round(s, 5) for b, s in self._step_est.items()
             },
             "cache_dir": cache_dir,
-            "cache_entries_before": cache_before,
-            "cache_entries_after": cache_after,
-            # The warm-start proof: from-scratch compiles this startup
-            # (persistent-cache writes during the AOT loop) vs hits.
-            "compiled_from_scratch": scratch,
-            "cache_hits": (
-                len(self.ladder.buckets) - scratch
-                if scratch is not None else None
+            # The warm-start proof: what the backend compiled in the AOT
+            # loop (the cache had no answer, or is off) vs what it loaded.
+            "compiled_from_scratch": (
+                compiled["cache_misses"] + compiled["cache_off"]
             ),
+            "cache_hits": compiled["cache_hits"],
         }
         if self._quant_report is not None:
             # The HBM-density proof: int8 serving bytes vs what the same

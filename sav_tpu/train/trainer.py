@@ -31,6 +31,7 @@ import optax
 
 from sav_tpu.models import create_model
 from sav_tpu.models.registry import model_task
+from sav_tpu.obs import compile_log
 from sav_tpu.obs.diagnostics import diagnostics_metrics
 from sav_tpu.obs.goodput import GoodputLedger
 from sav_tpu.obs.spans import SpanTracer, in_phase
@@ -76,6 +77,7 @@ class Trainer:
         self.compile_cache_dir = enable_persistent_cache(
             config.compilation_cache_dir
         )
+        compile_log.listen()
         if config.attention_tune_cache:
             # Trace-time-only process state: the 'auto' dispatcher reads
             # the shape→config table while tracing (sav_tpu/ops/
@@ -998,6 +1000,7 @@ class Trainer:
         """
         from sav_tpu.obs.fit_observers import build_observers, fleet_identity
 
+        fit_t0 = time.perf_counter()
         cfg = self.config
         num_steps = num_steps if num_steps is not None else cfg.total_steps
         state = state if state is not None else self.restore_or_init()
@@ -1262,7 +1265,17 @@ class Trainer:
             if profiling:
                 profiler.stop_trace()  # savlint: disable=SAV113 -- crash inside the armed static window: close it so the trace survives
             tracer.write()
-        self.last_goodput = ledger.summary()
+        # What this call traced, lowered, compiled and loaded, beside the
+        # ledger's compile bucket: the seconds split, and the cache's state.
+        compiled = compile_log.summary(since=fit_t0)
+        compiled = {
+            "trace_lower_s": compiled["trace_lower_s"],
+            "backend_compile_s": compiled["backend_compile_s"],
+            "cache_load_s": compiled["cache_load_s"],
+            "cache_hits": compiled["cache_hits"],
+            "cache_misses": compiled["cache_misses"] + compiled["cache_off"],
+        }
+        self.last_goodput = {**ledger.summary(), "compile": compiled}
         if obs_dir is not None and obs_writer:
             os.makedirs(obs_dir, exist_ok=True)
             with open(os.path.join(obs_dir, "goodput.json"), "w") as f:
@@ -1270,6 +1283,7 @@ class Trainer:
         goodput_record = {
             "step": int(jax.device_get(state.step)),  # savlint: disable=SAV101 -- post-loop summary read
             **ledger.flat_metrics(),
+            **{"compile/" + k: v for k, v in compiled.items()},
         }
         history.append(goodput_record)
         if log_fn is not None:

@@ -110,19 +110,3 @@ def disable_persistent_cache() -> None:
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     _set_cache_dir(None)
-
-
-def count_cache_entries(cache_dir: Optional[str]) -> Optional[int]:
-    """Executable entries under ``cache_dir`` (None when the cache is
-    off). The before/after delta across a compile is the number compiled
-    from scratch. jax writes a ``*-cache`` payload plus a ``*-atime``
-    access stamp per entry; only payloads count (the stamps are rewritten
-    on hits, so counting them would book a warm start as a recompile)."""
-    if not cache_dir:
-        return None
-    if not os.path.isdir(cache_dir):
-        return 0
-    total = 0
-    for _, _, files in os.walk(cache_dir):
-        total += sum(1 for f in files if not f.endswith("-atime"))
-    return total

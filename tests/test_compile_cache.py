@@ -59,7 +59,6 @@ def test_unset_on_the_cpu_keeps_the_cache_off(monkeypatch, config_updates):
     assert cc.resolve_cache_dir() is None
     assert cc.enable_persistent_cache() is None
     assert config_updates == []
-    assert cc.count_cache_entries(None) is None
 
 
 def test_override_is_used_only_without_the_variable(
@@ -73,7 +72,6 @@ def test_override_is_used_only_without_the_variable(
     assert os.path.isdir(override)
     assert ("jax_compilation_cache_dir", override) in config_updates
     assert ("jax_persistent_cache_min_compile_time_secs", 0.0) in config_updates
-    assert cc.count_cache_entries(override) == 0
 
 
 def test_default_path_is_fixed_and_ignored_by_git():
@@ -113,11 +111,3 @@ def test_one_place_sets_the_cache_directory():
     guard = source.index("if not os.environ.get(CACHE_DIR_ENV):")
     setter = source.index('jax.config.update("jax_compilation_cache_dir"')
     assert guard < setter < guard + 120
-
-
-def test_count_cache_entries_ignores_access_stamps(tmp_path):
-    (tmp_path / "abc-cache").write_bytes(b"x")
-    (tmp_path / "abc-atime").write_bytes(b"x")
-    (tmp_path / "def-cache").write_bytes(b"x")
-    assert cc.count_cache_entries(str(tmp_path)) == 2
-    assert cc.count_cache_entries(str(tmp_path / "missing")) == 0

@@ -232,7 +232,7 @@ def snapshot_dispatch_log() -> list:
         return [dict(v) for v in _DISPATCH_LOG.values()]
 
 
-def _log_dispatch(shape, kv_len, requested, dispatch: AttentionDispatch, backward=None) -> None:
+def _log_dispatch(shape, kv_len, requested, dispatch: AttentionDispatch, flash_forms=None) -> None:
     # kv_len is part of the identity: cross-attention sites share a query
     # shape with self-attention ones but can resolve differently.
     key = (shape, kv_len, requested)
@@ -243,8 +243,10 @@ def _log_dispatch(shape, kv_len, requested, dispatch: AttentionDispatch, backwar
                 "kv_len": kv_len,
                 "requested": requested or "auto",
                 **dispatch.as_note(),
-                # The unbiased flash kernel's backward: 'one_kernel' | 'two_kernels'.
-                **({"backward": backward} if backward else {}),
+                # The flash kernel's forms: 'backward' ('one_kernel' |
+                # 'two_kernels', the unbiased path's) and 'layout'
+                # ('in_place' | 'head_major').
+                **(flash_forms or {}),
             }
 
 
@@ -393,13 +395,14 @@ def dot_product_attention(
         backend = dispatch.backend
         cfg = dispatch.block_config or {}
         flash_blocks = {k: cfg[k] for k in ("block_q", "block_kv", "block_b") if k in cfg}
-        backward = None
-        if backend == "pallas" and bias is None:
-            backward = _flash.backward_form(
-                lq, key.shape[1], d, value.shape[-1], batch_heads=b * h,
-                itemsize=query.dtype.itemsize, **flash_blocks,
-            )
-        _log_dispatch(tuple(query.shape), key.shape[1], requested, dispatch, backward)
+        flash_forms = None
+        if backend == "pallas":
+            lengths = (lq, key.shape[1], d, value.shape[-1])
+            sizes = dict(batch_heads=b * h, itemsize=query.dtype.itemsize, **flash_blocks)
+            flash_forms = {"layout": _flash.layout_form(*lengths, biased=bias is not None, **sizes)}
+            if bias is None:
+                flash_forms["backward"] = _flash.backward_form(*lengths, **sizes)
+        _log_dispatch(tuple(query.shape), key.shape[1], requested, dispatch, flash_forms)
     else:
         if backend in ("pallas", "fused"):
             raise ValueError(

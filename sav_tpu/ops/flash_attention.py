@@ -10,10 +10,27 @@ self-attention) needs no bias: a block wholly above the diagonal is neither
 fetched nor computed, a block the diagonal crosses is masked in VMEM by an
 iota comparison, forward and backward.
 
+Layout: where every block can be one head's rows of the caller's own
+arrays, the unbiased kernels read and write those in place
+(:func:`layout_form`: heads of whole 128-lane tiles, sequences of whole
+blocks, one slice a grid cell, the one-kernel backward; a rule on what the
+call can see, nothing a caller sets). q, k, v and dO are read as
+``[B, H·D, L]``, the order in which XLA holds what a projection's matmul and
+the rotary write (the sequence on the lanes), so the transpose to it is
+answered with a layout; out, dq, dk and dv are written ``[B, L, H·D]``,
+which the next matmul reads as it is. The logsumexp leaves the forward as
+one float32 a row, the backward computes its tiles transposed so that the
+row and ``delta`` (computed in the kernel from the dO and output blocks it
+holds) broadcast down a tile, and XLA transposes, pads and broadcasts
+nothing around the calls. Everything else (a 64- or 192-lane head, ragged
+lengths, a bias, several slices a cell, the two-kernel backward, and
+:mod:`sav_tpu.parallel.ring_attention`'s direct calls) runs the same tiles
+on padded head-major ``[B·H, L_p, D_p]`` copies.
+
 Differentiation: ``flash_attention`` is a ``jax.custom_vjp``. Without a
 bias, the backward is fully blocked Pallas too: the forward saves only the
-per-row logsumexp (one float32 a row between the passes; the kernels write
-and read it broadcast across one 128-lane tile, the TPU-friendly layout),
+per-row logsumexp (one float32 a row between the passes; the head-major
+kernels write and read it broadcast across one 128-lane tile),
 and ONE kernel recomputes a ``(q block, kv block)`` pair's probabilities
 once and feeds dq, dk and dv from them: a q-innermost grid sums dk/dv over
 the q sweep of a kv block, and the float32 dq of a whole batch·head cell
@@ -59,6 +76,12 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def _clamp_block(block: int, length: int) -> int:
+    """A tile no longer than its sequence (rounded up to whole sublane
+    tiles): the one clamp every driver and both rules apply."""
+    return min(block, _round_up(length, 16))
+
+
 def _pad_head(dim: int) -> int:
     """The head size the kernels see: a whole number of 128-lane tiles, but a
     head wider than one tile that is a multiple of 64 (latent attention's
@@ -97,11 +120,12 @@ def _causal_blocks(qi, ki, block_q: int, block_kv: int):
     return visible, crossed
 
 
-def _causal_keep(qi, ki, block_q: int, block_kv: int):
-    """``[block_q, block_kv]`` bool: ``col <= row`` in global positions."""
-    shape = (block_q, block_kv)
-    row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    col = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+def _causal_keep(qi, ki, block_q: int, block_kv: int, *, transposed: bool = False):
+    """``[block_q, block_kv]`` bool: ``col <= row`` in global positions
+    (``transposed``: of a ``[block_kv, block_q]`` tile, rows on its lanes)."""
+    shape = (block_kv, block_q) if transposed else (block_q, block_kv)
+    row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transposed else 0)
+    col = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, shape, 0 if transposed else 1)
     return col <= row
 
 
@@ -269,8 +293,8 @@ def _flash_forward(
     # multiple: a value head (and the output) narrower than the query's is
     # never widened to it.
     dim_p, dim_v_p = _pad_head(dim), _pad_head(dim_v)
-    block_q = min(block_q, _round_up(q_len, 16))
-    block_kv = min(block_kv, _round_up(kv_len, 16))
+    block_q = _clamp_block(block_q, q_len)
+    block_kv = _clamp_block(block_kv, kv_len)
     q_len_p = _round_up(q_len, block_q)
     kv_len_p = _round_up(kv_len, block_kv)
 
@@ -427,7 +451,7 @@ def lse_padded_layout(lse: jax.Array, q_len: int, block_q: int) -> jax.Array:
     residual layout the blocked backward kernels read. Uses the same block
     clamping as :func:`_bwd_prep`, so external callers (e.g. the flash-mode
     ring backward) stay in sync with the drivers' padding geometry."""
-    block_q = min(block_q, _round_up(q_len, 16))
+    block_q = _clamp_block(block_q, q_len)
     q_len_p = _round_up(q_len, block_q)
     b, h, lq = lse.shape
     flat = lse.reshape(b * h, lq)
@@ -443,8 +467,8 @@ def _bwd_prep(q, k, v, out, g, block_q, block_kv) -> _BwdGeom:
     batch, q_len, heads, dim = q.shape
     kv_len, dim_v = k.shape[1], v.shape[-1]
     dim_p, dim_v_p = _pad_head(dim), _pad_head(dim_v)
-    block_q = min(block_q, _round_up(q_len, 16))
-    block_kv = min(block_kv, _round_up(kv_len, 16))
+    block_q = _clamp_block(block_q, q_len)
+    block_kv = _clamp_block(block_kv, kv_len)
     q_len_p = _round_up(q_len, block_q)
     kv_len_p = _round_up(kv_len, block_kv)
 
@@ -642,13 +666,57 @@ def backward_form(q_len: int, kv_len: int, dim: int, dim_v: int, *,
     geometry :func:`_bwd_prep` pads to) fits the budget, else
     ``two_kernels`` (dq apart from dk/dv, each rebuilding the
     probabilities, neither holding more than its tiles)."""
-    block_q = min(block_q, _round_up(q_len, 16))
+    block_q = _clamp_block(block_q, q_len)
     fits = one_kernel_backward_vmem_bytes(
         _round_up(q_len, block_q), _pad_head(dim), _pad_head(dim_v),
-        block_q=block_q, block_kv=min(block_kv, _round_up(kv_len, 16)),
+        block_q=block_q, block_kv=_clamp_block(block_kv, kv_len),
         block_b=_resolve_block_b(block_b, batch_heads), itemsize=itemsize,
     ) <= ONE_KERNEL_VMEM_BUDGET
     return "one_kernel" if fits else "two_kernels"
+
+
+def layout_form(q_len: int, kv_len: int, dim: int, dim_v: int, *,
+                batch_heads: int, biased: bool = False,
+                block_q: int = DEFAULT_BLOCK, block_kv: int = DEFAULT_BLOCK,
+                block_b: Optional[int] = None, itemsize: int = 2) -> str:
+    """Where a :func:`flash_attention` of these shapes and blocks meets its
+    operands in HBM. ``in_place``: the kernels' blocks are one head's rows
+    of the caller's own ``[B, L, H, D]`` arrays (read with the sequence on
+    the lanes, written with the heads' columns side by side: the section
+    before :func:`_in_place_forward`), and XLA transposes, pads and
+    broadcasts nothing around the calls. That takes an unbiased call whose
+    heads are whole 128-lane tiles (out, dq, dk and dv leave as one head's
+    columns of ``[B, L, H·D]``, and Mosaic takes no 192-lane block out of a
+    wider array), whose sequences are whole numbers of the clamped
+    blocks, themselves whole lane tiles (a block's rows lie on the lanes),
+    one slice a grid cell, and the one-kernel backward. Everything else is
+    ``head_major``: padded ``[B·H, L_p, D_p]`` copies, the form the ring
+    path also calls by name.
+
+    The in-place forward holds the backward's tiles and no resident dq, and
+    both calls are compiled under the one-kernel backward's VMEM limit, so
+    the backward's estimate (read through :func:`backward_form`) bounds
+    both. What the form is worth depends on who feeds it: a model whose
+    projections write the sequence on the lanes pays no copy at all, while
+    a caller whose operands lie in their default order (a jit's own
+    parameters: ``tools/attn_tune.py``, ``parallel/ulysses.py``,
+    ``tools/flash_memory_win.py``) pays one transposing copy an operand, as
+    the head-major form does: there the forward alone gains nothing, and
+    the gain is the backward's (PERF.md section 6, PR 36)."""
+    block_q = _clamp_block(block_q, q_len)
+    block_kv = _clamp_block(block_kv, kv_len)
+    whole = (
+        dim % 128 == 0 and dim_v % 128 == 0 and block_q % 128 == 0 and block_kv % 128 == 0
+        and q_len % block_q == 0 and kv_len % block_kv == 0
+    )
+    in_place = (
+        not biased and whole and _resolve_block_b(block_b, batch_heads) == 1
+        and backward_form(
+            q_len, kv_len, dim, dim_v, batch_heads=batch_heads, block_q=block_q,
+            block_kv=block_kv, block_b=block_b, itemsize=itemsize,
+        ) == "one_kernel"
+    )
+    return "in_place" if in_place else "head_major"
 
 
 def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
@@ -759,8 +827,279 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
     )
 
 
+# ---------------------------------------------------------------------------
+# The in-place form (layout_form): the kernels address the operands where
+# XLA lays them. A model's q, k, v and the cotangent of the output come out
+# of a projection's matmul (and the rotary after it) with the sequence on
+# the lanes: what XLA holds for a ``[B, L, H, D]`` array there is
+# ``[B, H, D, L]``, so its transpose to that order moves nothing and a
+# ``(1, D, block)`` block at ``(b, h, i)`` of the ``[B, H·D, L]`` view is one
+# head's rows. The outputs (out, dq, dk, dv) are written ``[B, L, H·D]``,
+# which reshapes to ``[B, L, H, D]`` for the matmul that takes them next.
+# Same tiles, maps, accumulators and arithmetic as the head-major form, each
+# direction a kernel of its own: the forward turns its q block once a kv
+# sweep, the backward computes its tiles transposed, ``[block_kv, block_q]``,
+# so that the logsumexp and ``delta`` are rows and no operand is turned per
+# tile.
+# ---------------------------------------------------------------------------
+
+
+def _sequence_on_lanes(x: jax.Array) -> jax.Array:
+    """``[B, L, H, D]`` -> ``[B, H·D, L]``."""
+    batch, length, heads, dim = x.shape
+    return jnp.transpose(x, (0, 2, 3, 1)).reshape(batch, heads * dim, length)
+
+
+def _in_place_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, with_lse: bool, scale: float,
+                         block_q: int, block_kv: int, num_kv_blocks: int, causal: bool):
+    """The online softmax of :func:`_kernel` for one head's ``[d, block]``
+    blocks of q, k and v; ``rest`` = ([lse_ref], m, l, acc, q_scr). The q
+    block is turned to ``[block_q, d]`` once, when its kv sweep starts;
+    ``q k`` and ``p v^T`` then take k and v as they lie, the output is
+    summed as it is written, ``[block_q, d_v]``, and the logsumexp tile
+    leaves as a row. (Tiles computed transposed, as the backward's are,
+    need no turn of q or of the logsumexp but one of the k block every grid
+    step: 5.8% slower on the chip, PERF.md section 6, PR 36.)"""
+    if with_lse:
+        lse_ref, m_scr, l_scr, acc_scr, q_scr = rest
+    else:
+        (m_scr, l_scr, acc_scr, q_scr), lse_ref = rest, None
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        q_scr[...] = q_ref[0].T
+
+    def fold(masked: bool):
+        k, v = k_ref[0], v_ref[0]  # [d, block_kv], [d_v, block_kv]
+        s = jax.lax.dot_general(
+            q_scr[...], k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [block_q, block_kv]
+        if masked:
+            # As in :func:`_kernel`: a finite mask value, and a row's first
+            # visited block always shows it a column.
+            s = jnp.where(_causal_keep(qi, ki, block_q, block_kv), s, _NEG_INF)
+        # The running max and sum are kept broadcast across a lane tile.
+        m_prev, l_prev = m_scr[:, 0:1], l_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
+
+    @pl.when(ki == num_kv_blocks - 1)
+    def _finalize():
+        o_ref[0] = (acc_scr[...] / l_scr[:, 0:1]).astype(o_ref.dtype)
+        if lse_ref is not None:
+            # The tile's rows go onto the lanes through the transpose unit,
+            # and its first lane is the row.
+            lse_ref[0, 0] = (m_scr[...] + jnp.log(l_scr[...])).T[0:1, :]
+
+
+def _in_place_forward(q, k, v, scale, block_q, block_kv, interpret,
+                      with_lse: bool = False, *, causal: bool = False):
+    """The forward kernel on ``[B, L, H, D]`` operands where they lie; the
+    output as the kernel writes it, ``[B, Lq, H·D_v]``. With ``with_lse``
+    also the logsumexp, one float32 a row as ``[B, H, 1, Lq]`` (a block's
+    last two dimensions are then one whole and one of lane tiles)."""
+    batch, q_len, heads, dim = q.shape
+    kv_len, dim_v = k.shape[1], v.shape[-1]
+    if interpret is None:
+        interpret = _backend.default_interpret()
+    if causal and q_len != kv_len:
+        raise ValueError(f"causal attention is self-attention: q_len {q_len} != kv_len {kv_len}")
+    block_q, block_kv = _clamp_block(block_q, q_len), _clamp_block(block_kv, kv_len)
+    num_kv_blocks = kv_len // block_kv
+
+    if causal:
+        kv_block = lambda i, j: jnp.minimum(j, _last_kv_block(i, block_q, block_kv))
+    else:
+        kv_block = lambda i, j: j
+    kv_index = lambda b, h, i, j: (b, h, kv_block(i, j))
+    out_specs = [pl.BlockSpec((1, block_q, dim_v), lambda b, h, i, j: (b, i, h))]
+    out_shape = [jax.ShapeDtypeStruct((batch, q_len, heads * dim_v), q.dtype)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((1, 1, 1, block_q), lambda b, h, i, j: (b, h, 0, i)))
+        out_shape.append(jax.ShapeDtypeStruct((batch, heads, 1, q_len), jnp.float32))
+    outs = pl.pallas_call(
+        functools.partial(
+            _in_place_fwd_kernel, with_lse=with_lse, scale=scale, block_q=block_q,
+            block_kv=block_kv, num_kv_blocks=num_kv_blocks, causal=causal,
+        ),
+        grid=(batch, heads, q_len // block_q, num_kv_blocks),
+        in_specs=[
+            pl.BlockSpec((1, dim, block_q), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, dim, block_kv), kv_index),
+            pl.BlockSpec((1, dim_v, block_kv), kv_index),
+        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, dim_v), jnp.float32),
+            pltpu.VMEM((block_q, dim), q.dtype),
+        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_ONE_KERNEL_VMEM_LIMIT),
+        interpret=interpret,
+    )(_sequence_on_lanes(q), _sequence_on_lanes(k), _sequence_on_lanes(v))
+    return tuple(outs) if with_lse else outs[0]
+
+
+def _in_place_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref, dk_ref, dv_ref,
+                         dq_acc, dk_acc, dv_acc, k_scr, v_scr, delta_scr, *,
+                         scale: float, block_q: int, block_kv: int,
+                         num_q_blocks: int, num_kv_blocks: int, causal: bool):
+    """dq, dk and dv of one batch·head cell, as :func:`_bwd_dkv_kernel`
+    with ``with_dq`` computes them, on ``[d, block]`` blocks of q, k, v and
+    dO. A pair of tiles is computed transposed, ``[block_kv, block_q]``:
+    the q block's logsumexp and ``delta`` are then rows that broadcast down
+    the tile, k and v are turned to ``[block_kv, d]`` once a kv block, and
+    every matmul takes its operands as they are. ``delta = sum_d dO·O`` of a
+    q block is computed when the cell first meets it (kv block 0, which
+    every q block sees) and kept; the float32 dq of the cell is resident as
+    ``[num_q_blocks, d, block_q]`` and turned as it is written out."""
+    ki, qi = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(jnp.logical_and(ki == 0, qi == 0))
+    def _init_dq():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+        k_scr[...] = k_ref[0].T
+        v_scr[...] = v_ref[0].T
+
+    @pl.when(ki == 0)
+    def _delta():
+        delta_scr[qi] = jnp.sum(
+            do_ref[0].astype(jnp.float32) * o_ref[0].T.astype(jnp.float32), axis=0, keepdims=True
+        )
+
+    def fold(masked: bool):
+        q, do = q_ref[0], do_ref[0]  # [d, block_q], [d_v, block_q]
+        s = jax.lax.dot_general(
+            k_scr[...], q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [block_kv, block_q]
+        p = jnp.exp(s - lse_ref[0, 0])
+        if masked:
+            p = jnp.where(_causal_keep(qi, ki, block_q, block_kv, transposed=True), p, 0.0)
+        dp = jax.lax.dot_general(
+            v_scr[...], do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        ds = (p * (dp - delta_scr[qi])).astype(q.dtype)
+        dv_acc[...] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        dk_acc[...] += jax.lax.dot_general(
+            ds, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        ) * scale
+        dq_acc[qi] += jax.lax.dot_general(
+            k_ref[0], ds, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        ) * scale
+
+    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
+
+    @pl.when(qi == num_q_blocks - 1)
+    def _write():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(ki == num_kv_blocks - 1, qi == num_q_blocks - 1))
+    def _write_dq():
+        for i in range(num_q_blocks):
+            dq_ref[0, i * block_q:(i + 1) * block_q, :] = dq_acc[i].T.astype(dq_ref.dtype)
+
+
+def _in_place_backward(q, k, v, out, lse, g, scale, block_q, block_kv,
+                       interpret, *, causal: bool = False):
+    """The one-kernel backward on ``[B, L, H, D]`` operands where they lie;
+    ``out`` and ``lse`` are the forward's ``[B, Lq, H·D_v]`` and
+    ``[B, H, 1, Lq]``. dq, dk and dv are written as ``[B, L, H·D]`` arrays
+    of their own (the operands' buffers hold another order)."""
+    batch, q_len, heads, dim = q.shape
+    kv_len, dim_v = k.shape[1], v.shape[-1]
+    if interpret is None:
+        interpret = _backend.default_interpret()
+    block_q, block_kv = _clamp_block(block_q, q_len), _clamp_block(block_kv, kv_len)
+    num_q_blocks, num_kv_blocks = q_len // block_q, kv_len // block_kv
+
+    # Under the causal mask a skipped cell names the block its neighbour
+    # held, so nothing is fetched for it; the output block is read while the
+    # cell's first kv block computes ``delta`` and stays where it is after.
+    if causal:
+        q_block = lambda j, i: jnp.maximum(i, _first_q_block(j, block_q, block_kv))
+    else:
+        q_block = lambda j, i: i
+    q_index = lambda b, h, j, i: (b, h, q_block(j, i))
+    kv_index = lambda b, h, j, i: (b, h, j)
+    dkv_index = lambda b, h, j, i: (b, j, h)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _in_place_bwd_kernel, scale=scale, block_q=block_q, block_kv=block_kv,
+            num_q_blocks=num_q_blocks, num_kv_blocks=num_kv_blocks, causal=causal,
+        ),
+        grid=(batch, heads, num_kv_blocks, num_q_blocks),
+        in_specs=[
+            pl.BlockSpec((1, dim, block_q), q_index),
+            pl.BlockSpec((1, dim, block_kv), kv_index),
+            pl.BlockSpec((1, dim_v, block_kv), kv_index),
+            pl.BlockSpec((1, dim_v, block_q), q_index),
+            pl.BlockSpec((1, 1, 1, block_q), lambda b, h, j, i: (b, h, 0, q_block(j, i))),
+            pl.BlockSpec(
+                (1, block_q, dim_v),
+                lambda b, h, j, i: (b, jnp.where(j == 0, i, num_q_blocks - 1), h),
+            ),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, q_len, dim), lambda b, h, j, i: (b, 0, h)),
+            pl.BlockSpec((1, block_kv, dim), dkv_index),
+            pl.BlockSpec((1, block_kv, dim_v), dkv_index),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, q_len, heads * dim), q.dtype),
+            jax.ShapeDtypeStruct((batch, kv_len, heads * dim), k.dtype),
+            jax.ShapeDtypeStruct((batch, kv_len, heads * dim_v), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((num_q_blocks, dim, block_q), jnp.float32),
+            pltpu.VMEM((block_kv, dim), jnp.float32),
+            pltpu.VMEM((block_kv, dim_v), jnp.float32),
+            pltpu.VMEM((block_kv, dim), k.dtype),
+            pltpu.VMEM((block_kv, dim_v), v.dtype),
+            pltpu.VMEM((num_q_blocks, 1, block_q), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_ONE_KERNEL_VMEM_LIMIT),
+        interpret=interpret,
+    )(_sequence_on_lanes(q), _sequence_on_lanes(k), _sequence_on_lanes(v), _sequence_on_lanes(g), lse, out)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+def _runs_in_place(q, k, v, bias, block_q, block_kv, block_b) -> bool:
+    return layout_form(
+        q.shape[1], k.shape[1], q.shape[-1], v.shape[-1],
+        batch_heads=q.shape[0] * q.shape[2], biased=bias is not None,
+        block_q=block_q, block_kv=block_kv, block_b=block_b,
+        itemsize=q.dtype.itemsize,
+    ) == "in_place"
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b):
+    if _runs_in_place(q, k, v, bias, block_q, block_kv, block_b):
+        out = _in_place_forward(q, k, v, scale, block_q, block_kv, interpret, causal=causal)
+        return out.reshape(q.shape[:3] + v.shape[3:])
     return _flash_forward(
         q, k, v, bias, scale, block_q, block_kv, interpret,
         causal=causal, block_b=block_b,
@@ -768,31 +1107,48 @@ def _flash(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b):
 
 
 def _flash_fwd(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b):
-    if bias is None:
+    if bias is not None:
+        out = _flash_forward(
+            q, k, v, bias, scale, block_q, block_kv, interpret,
+            causal=causal, block_b=block_b,
+        )
+        return out, (q, k, v, bias, None, None)
+    # The residuals are the output and one float32 a row of logsumexp. A
+    # remat policy sees a custom_vjp's residuals only where the forward rule
+    # names them: with these two kept (and the caller's q, k, v) the
+    # backward pass runs no second forward kernel.
+    if _runs_in_place(q, k, v, bias, block_q, block_kv, block_b):
+        # Both as the kernel wrote them and as the backward kernel reads
+        # them: a kept [B, L, H, D] would be laid out anew for every read.
+        kept, lse_row = _in_place_forward(
+            q, k, v, scale, block_q, block_kv, interpret, with_lse=True, causal=causal
+        )
+        kept = checkpoint_name(kept, "flash_out")
+        out = kept.reshape(q.shape[:3] + v.shape[3:])
+    else:
+        # The head-major kernel writes a 128-lane tile a row, which its
+        # backward rebuilds.
         out, lse = _flash_forward(
             q, k, v, bias, scale, block_q, block_kv, interpret, with_lse=True,
             causal=causal, block_b=block_b,
         )
-        # The residual is one float32 a row, not the kernel's 128-lane tile
-        # (the backward rebuilds it). A remat policy sees a custom_vjp's
-        # residuals only where the forward rule names them: with these two
-        # kept (and the caller's q, k, v) the backward pass runs no second
-        # forward kernel.
-        out, lse_row = checkpoint_name(out, "flash_out"), checkpoint_name(lse[..., 0], "flash_lse")
-        return out, (q, k, v, bias, out, lse_row)
-    out = _flash_forward(
-        q, k, v, bias, scale, block_q, block_kv, interpret,
-        causal=causal, block_b=block_b,
-    )
-    return out, (q, k, v, bias, None, None)
+        out = kept = checkpoint_name(out, "flash_out")
+        lse_row = lse[..., 0]
+    return out, (q, k, v, bias, kept, checkpoint_name(lse_row, "flash_lse"))
 
 
 def _flash_bwd(scale, block_q, block_kv, interpret, causal, block_b, residuals, g):
-    """Backward dispatch: blocked Pallas kernels when there is no bias;
-    XLA flash-style recompute when a dbias is needed (the dense ``ds`` is
+    """Backward dispatch: blocked Pallas kernels when there is no bias, in
+    the layout the forward ran in (the rule reads the same shapes); XLA
+    flash-style recompute when a dbias is needed (the dense ``ds`` is
     unavoidable for the bias gradient)."""
     q, k, v, bias, out, lse_row = residuals
     if bias is None:
+        if _runs_in_place(q, k, v, bias, block_q, block_kv, block_b):
+            dq, dk, dv = _in_place_backward(
+                q, k, v, out, lse_row, g, scale, block_q, block_kv, interpret, causal=causal
+            )
+            return dq, dk, dv, None
         lse = jnp.broadcast_to(lse_row[..., None], lse_row.shape + (128,))
         dq, dk, dv = _flash_backward_pallas(
             q, k, v, out, lse, g, scale, block_q, block_kv, interpret,
@@ -1009,8 +1365,8 @@ def _rel_forward(q, k, v, rw_abs, rh_abs, height, width, scale, block_q,
 
     qf, kf, vf = to_bhld(q), to_bhld(k), to_bhld(v)
     dim_p = _round_up(dim, 128)
-    block_q = min(block_q, _round_up(q_len, 16))
-    block_kv = min(block_kv, _round_up(kv_len, 16))
+    block_q = _clamp_block(block_q, q_len)
+    block_kv = _clamp_block(block_kv, kv_len)
     q_len_p = _round_up(q_len, block_q)
     kv_len_p = _round_up(kv_len, block_kv)
 

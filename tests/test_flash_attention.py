@@ -227,18 +227,148 @@ def test_dispatch_log_names_the_backward_the_pallas_entry_runs(monkeypatch):
 
     q, k, v = _qkv(lq=64, lk=64, d=32)
     bias = jnp.zeros((1, 1, 64, 64))
+    whole = _qkv(b=1, lq=128, h=1, d=128)  # one slice a cell, a head of one lane tile, whole blocks
     att.clear_dispatch_log()
     dot_product_attention(q, k, v, backend="pallas")
     dot_product_attention(q[:, :32], k, v, bias[:, :, :32], backend="pallas")  # biased: the dense recompute
     dot_product_attention(q, k, v, backend="xla")
+    dot_product_attention(*whole, backend="pallas", causal=True)
     monkeypatch.setattr(flmod, "ONE_KERNEL_VMEM_BUDGET", 0)
     dot_product_attention(q, k[:, :48], v[:, :48], backend="pallas")
-    log = {(e["shape"][1], e["kv_len"], e["backend"]): e.get("backward") for e in att.snapshot_dispatch_log()}
+    dot_product_attention(*whole, backend="pallas")
+    log = {
+        (e["shape"][1], e["kv_len"], e["backend"], e["requested"] + str(e["shape"][3])): (e.get("backward"), e.get("layout"))
+        for e in att.snapshot_dispatch_log()
+    }
     att.clear_dispatch_log()
     assert log == {
-        (64, 64, "pallas"): "one_kernel", (32, 64, "pallas"): None,
-        (64, 64, "xla"): None, (64, 48, "pallas"): "two_kernels",
+        (64, 64, "pallas", "pallas32"): ("one_kernel", "head_major"),
+        (32, 64, "pallas", "pallas32"): (None, "head_major"),
+        (64, 64, "xla", "xla32"): (None, None),
+        (128, 128, "pallas", "pallas128"): ("one_kernel", "in_place"),
+        (64, 48, "pallas", "pallas32"): ("two_kernels", "head_major"),
     }
+
+
+# (what differs from a [B 2, L 512, H 1, D 128 / 128] call at blocks of 128 with one slice a cell) -> the layout
+LAYOUT_CASES = [
+    ({}, "in_place"),
+    ({"dim": 256, "dim_v": 256}, "in_place"),
+    ({"dim": 256}, "in_place"),  # a value head of its own size, both whole lane tiles
+    ({"block_q": 1024, "block_kv": 1024}, "in_place"),  # blocks clamped to the sequence: one whole block
+    ({"q_len": 4096, "kv_len": 4096, "batch_heads": 32, "block_q": 1024, "block_kv": 1024}, "in_place"),  # the looped model's cell
+    ({"q_len": 4096, "kv_len": 4096, "batch_heads": 32, "block_q": 2048, "block_kv": 1024}, "in_place"),  # and a tile over Mosaic's default VMEM: both calls carry the limit
+    ({"dim": 64, "dim_v": 64}, "head_major"),  # half a lane tile
+    ({"dim": 192}, "head_major"),  # the latent pair: no 192-lane block out of a wider array
+    ({"q_len": 200, "kv_len": 200}, "head_major"),  # rows to pad
+    ({"kv_len": 448}, "head_major"),  # kv columns to pad
+    ({"q_len": 64, "kv_len": 64}, "head_major"),  # blocks that are no whole lane tiles (their rows lie on the lanes)
+    ({"kv_len": 192, "block_kv": 64}, "head_major"),
+    ({"biased": True}, "head_major"),
+    ({"block_b": None}, "head_major"),  # two slices a cell by default
+    ({"block_b": 2}, "head_major"),
+    ({"q_len": 65536, "kv_len": 65536}, "head_major"),  # a ring shard: the two-kernel backward
+]
+
+
+@pytest.mark.parametrize("change,layout", LAYOUT_CASES, ids=[",".join(f"{k}={v}" for k, v in c.items()) or "base" for c, _ in LAYOUT_CASES])
+def test_layout_form_follows_what_a_block_can_address(change, layout):
+    """The rule's two sides, from sizes, blocks and whether there is a bias
+    alone: in place where every block is whole lane tiles of the caller's
+    arrays and the one-kernel backward runs, head-major everywhere else."""
+    call = dict(q_len=512, kv_len=512, dim=128, dim_v=128, batch_heads=2, block_q=128, block_kv=128, block_b=1)
+    call.update(change)
+    lengths = [call.pop(name) for name in ("q_len", "kv_len", "dim", "dim_v")]
+    assert flmod.layout_form(*lengths, **call) == layout
+    if change.get("q_len") == 65536:
+        call.pop("biased", None)
+        assert flmod.backward_form(*lengths, **call) == "two_kernels"
+
+
+# (d, dv, block_q, block_kv) at [B 2, L 512, H 2]: several q and kv blocks
+# each way, so the causal diagonal crosses blocks and skips others.
+IN_PLACE_CASES = [(128, 128, 128, 128), (128, 128, 256, 256), (128, 128, 256, 128), (256, 256, 128, 128), (256, 128, 128, 256)]
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "unmasked"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("d,dv,block_q,block_kv", IN_PLACE_CASES)
+def test_in_place_form_matches_xla_and_the_head_major_form(monkeypatch, d, dv, block_q, block_kv, dtype, causal):
+    """Forward and the three gradients of the in-place form against the
+    dense path's, and against the head-major form's of the same call (the
+    rule pinned to it: the program has no option for the layout): float32
+    to rounding, bf16 to the file's tolerances."""
+    q, k, _ = _qkv(lq=512, h=2, d=d, dtype=dtype)
+    v = jax.random.normal(jax.random.PRNGKey(3), (2, 512, 2, dv), dtype)
+    blocks = dict(block_q=block_q, block_kv=block_kv, block_b=1)
+    assert flmod.layout_form(512, 512, d, dv, batch_heads=4, itemsize=q.dtype.itemsize, **blocks) == "in_place"
+
+    def both(fn, **kw):
+        def loss(q, k, v):
+            out = fn(q, k, v, causal=causal, **kw)
+            return jnp.sum(jnp.square(out.astype(jnp.float32))), out
+        grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return [np.asarray(a, np.float32) for a in (out, *grads)]
+
+    in_place = both(flash_attention, **blocks)
+    dense = both(xla_attention)
+    monkeypatch.setattr(flmod, "layout_form", lambda *a, **kw: "head_major")
+    head_major = both(flash_attention, **blocks)
+    exact = dtype == jnp.float32
+    for name, got, other, ref in zip(("out", "dq", "dk", "dv"), in_place, head_major, dense):
+        assert np.isfinite(got).all(), name
+        if name == "out":
+            tol = dict(atol=2e-5, rtol=2e-5) if exact else dict(atol=3e-2, rtol=3e-2)
+        else:
+            tol = dict(atol=1e-4, rtol=5e-4) if exact else dict(atol=0.15, rtol=0.15)
+        np.testing.assert_allclose(got, ref, err_msg=name, **tol)
+        np.testing.assert_allclose(got, other, err_msg=name, **tol)
+
+
+def _equations(jaxpr):
+    """Every equation outside the kernels' bodies."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _equations(inner)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "unmasked"])
+def test_in_place_form_leaves_xla_nothing_to_copy(causal):
+    """Around the in-place form's two calls the traced program pads nothing,
+    broadcasts nothing (the cotangent's sum is the test's own) and holds no
+    float32 array with a lane tile a row, inside or outside a call's
+    operands. Its only transposes say that q, k, v and dO have the sequence
+    on the lanes, ``(0, 2, 3, 1)``: the order XLA holds a projection's
+    output in, so it answers them with a layout and moves nothing
+    (``tests/test_tpu_compile.py`` holds that of the compiled step). The
+    head-major form of the same call does all three."""
+    # A head of two lane tiles: a trailing axis of 128 is then no head.
+    q, k, v = _qkv(b=1, lq=256, h=2, d=256, dtype=jnp.bfloat16)
+    grad = _grad_loss(flash_attention, causal=causal, block_q=128, block_kv=128, block_b=1)
+
+    def moved(jaxpr):
+        copies, tiles = [], []
+        for eqn in _equations(jaxpr.jaxpr):
+            avals = [var.aval for var in (*eqn.invars, *eqn.outvars) if hasattr(var.aval, "shape")]
+            tiles += [a.shape for a in avals if a.dtype == jnp.float32 and a.ndim > 1 and a.shape[-1] == 128]
+            if eqn.primitive.name in ("transpose", "pad", "broadcast_in_dim") and avals[-1].size >= q.size:
+                copies.append((eqn.primitive.name, tuple(eqn.params.get("permutation", ()))))
+        return copies, tiles
+
+    jaxpr = jax.make_jaxpr(grad)(q, k, v)
+    assert _pallas_calls(jaxpr.jaxpr) == 2
+    copies, tiles = moved(jaxpr)
+    # q, k, v forward; q, k, v, dO backward; d(sum): the loss's own cotangent.
+    assert sorted(copies) == [("broadcast_in_dim", ())] + [("transpose", (0, 2, 3, 1))] * 7, copies
+    assert not tiles, tiles
+    padded = jax.make_jaxpr(_grad_loss(flash_attention, causal=causal, block_q=128, block_kv=128, block_b=2))(q, k, v)
+    copies, tiles = moved(padded)
+    assert copies.count(("transpose", (0, 2, 1, 3))) == 11 and tiles, (copies, tiles)
 
 
 @pytest.mark.slow
@@ -441,16 +571,19 @@ def test_logits_dtype_default_knob():
     np.testing.assert_allclose(hi, ref, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("layout", ["head_major", "in_place"])
 @pytest.mark.parametrize("kept,calls", [(("flash_out", "flash_lse"), 2), (("flash_out",), 3), ((), 3)])
-def test_checkpoint_policy_keeps_the_forward_kernels_residuals(kept, calls):
+def test_checkpoint_policy_keeps_the_forward_kernels_residuals(kept, calls, layout):
     """The forward rule names its output and its logsumexp: a
     ``jax.checkpoint`` policy that lists both differentiates through one
     forward kernel call (and the one backward call); one that misses either runs the
-    forward a second time for it."""
-    q, k, v = _qkv(b=1, lq=64, h=2, d=32)
+    forward a second time for it. In both layouts."""
+    d, blocks = (32, dict(block_q=32, block_kv=32)) if layout == "head_major" else (128, dict(block_q=128, block_kv=128, block_b=1))
+    q, k, v = _qkv(b=1, lq=4 * blocks["block_q"] // 2, h=2, d=d)
+    assert flmod.layout_form(q.shape[1], q.shape[1], d, d, batch_heads=2, itemsize=4, **blocks) == layout
 
     def loss(q, k, v):
-        out = flash_attention(jnp.sin(q), k, v, causal=True, block_q=32, block_kv=32)
+        out = flash_attention(jnp.sin(q), k, v, causal=True, **blocks)
         return jnp.sum(jnp.cos(out))  # out is needed again: by cos, and as the backward's residual
 
     policy = jax.checkpoint_policies.save_only_these_names(*kept)

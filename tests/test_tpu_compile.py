@@ -71,10 +71,9 @@ def _bytes_on_device(compiled) -> int:
     )
 
 
-# What the optimizer's update moves, read from a compiled step's text: the
-# entry computation's instructions whose ``op_name`` lies under the step's
-# ``optimizer`` scope (a fusion carries its root's), each charged its
-# operands and its output whole.
+# What a scope of a compiled step moves, read from the step's text: the
+# entry computation's instructions whose ``op_name`` lies under it (a fusion
+# carries its root's).
 _ITEMSIZE = {"f64": 8, "s64": 8, "u64": 8, "f32": 4, "s32": 4, "u32": 4,
              "bf16": 2, "f16": 2, "s16": 2, "u16": 2, "s8": 1, "u8": 1, "pred": 1}
 _ARRAY = re.compile(r"\b(%s)\[([0-9,]*)\]" % "|".join(_ITEMSIZE))
@@ -97,9 +96,10 @@ def _closing(text: str, start: int) -> int:
     raise ValueError(text[:200])
 
 
-def _optimizer_scope(hlo_text: str) -> list:
-    """``(opcode, bytes)`` of every entry-computation instruction under the
-    ``optimizer`` scope that moves something."""
+def _entry_scope(hlo_text: str, in_scope) -> list:
+    """``(opcode, output bytes, output and operand bytes)`` of every
+    entry-computation instruction whose ``op_name`` satisfies ``in_scope``
+    and that moves something."""
     lines = hlo_text.splitlines()
     entry = next(i for i, line in enumerate(lines) if line.startswith("ENTRY "))
     sizes, found = {}, []
@@ -120,9 +120,17 @@ def _optimizer_scope(hlo_text: str) -> list:
         opcode, operands = rest[:paren], re.findall(r"%([\w.\-]+)", rest[paren: end + 1])
         sizes[head.group(1)] = _shape_bytes(shape)
         scope = re.search(r'op_name="([^"]*)"', rest[end + 1:])
-        if scope and "/optimizer/" in scope.group(1) and opcode not in _MOVES_NOTHING:
-            found.append((opcode, sizes[head.group(1)] + sum(sizes.get(o, 0) for o in operands)))
+        if scope and in_scope(scope.group(1)) and opcode not in _MOVES_NOTHING:
+            out = sizes[head.group(1)]
+            found.append((opcode, out, out + sum(sizes.get(o, 0) for o in operands)))
     return found
+
+
+def _optimizer_scope(hlo_text: str) -> list:
+    """``(opcode, bytes)`` of every entry-computation instruction under the
+    ``optimizer`` scope that moves something, each charged its operands and
+    its output whole."""
+    return [(opcode, moved) for opcode, _, moved in _entry_scope(hlo_text, lambda scope: "/optimizer/" in scope)]
 
 
 def _assert_one_pass_optimizer(compiled, params) -> None:
@@ -241,46 +249,55 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shape, direction):
     assert _bytes_on_device(compiled) < HBM_BYTES
 
 
-def _kernel_vmem(compiled, which: str) -> list:
+def _kernel_vmem(compiled, which: str, field: str = "size") -> list:
     """Bytes of scoped VMEM each Mosaic call of a compiled program was
     given (``scoped_memory_configs``: none where the call sets no limit of
-    its own) or used (``used_scoped_memory_configs``)."""
-    pattern = re.compile(r'"%s":\[\{"memory_space":"1","offset":"0","size":"(\d+)"' % which)
+    its own) or used (``used_scoped_memory_configs``); ``field`` ``offset``:
+    where the call's share starts (XLA's own scoped buffers lie under it,
+    and ``used`` counts from 0)."""
+    pattern = re.compile(r'"%s":\[\{"memory_space":"1","offset":"(?P<offset>\d+)","size":"(?P<size>\d+)"' % which)
     calls = [
         line for line in compiled.as_text().splitlines()
         if 'custom_call_target="tpu_custom_call"' in line
     ]
-    return [int(found.group(1)) if (found := pattern.search(line)) else None for line in calls]
+    return [int(found.group(field)) if (found := pattern.search(line)) else None for line in calls]
 
 
-# (name, [B, L, H, D(, Dv)], flash_attention's blocks, the form the rule picks)
+# (name, [B, L, H, D(, Dv)], flash_attention's blocks, the forms the rules pick)
 BACKWARD_FORM_CASES = [
-    ("ouro_4k", (2, 4096, 16, 128), dict(block_q=1024, block_kv=1024, block_b=1), "one_kernel"),
-    ("joyai_4k", (2, 4096, 32, 192, 128), dict(block_q=1024, block_kv=1024, block_b=1), "one_kernel"),
-    ("deit_s", (256, 197, 6, 64), {}, "one_kernel"),
+    ("ouro_4k", (2, 4096, 16, 128), dict(block_q=1024, block_kv=1024, block_b=1), "one_kernel", "in_place"),
+    # A tile the sweep also reads: its forward's working set is over Mosaic's
+    # default 16 MiB in either layout's transposes, so both in-place calls
+    # carry the limit.
+    ("ouro_4k_2048x1024", (2, 4096, 16, 128), dict(block_q=2048, block_kv=1024, block_b=1), "one_kernel", "in_place"),
+    ("joyai_4k", (2, 4096, 32, 192, 128), dict(block_q=1024, block_kv=1024, block_b=1), "one_kernel", "head_major"),
+    ("deit_s", (256, 197, 6, 64), {}, "one_kernel", "head_major"),
     # A ring shard's length at the default blocks: block_b 4 whole float32
     # dq of 16,384 rows do not fit beside their tiles.
-    ("ring_shard_16k", (2, 16384, 6, 64), {}, "two_kernels"),
+    ("ring_shard_16k", (2, 16384, 6, 64), {}, "two_kernels", "head_major"),
 ]
 
 
 @pytest.mark.parametrize(
-    "shape,blocks,form", [case[1:] for case in BACKWARD_FORM_CASES],
+    "shape,blocks,form,layout", [case[1:] for case in BACKWARD_FORM_CASES],
     ids=[case[0] for case in BACKWARD_FORM_CASES],
 )
-def test_flash_backward_compiles_in_the_form_its_rule_picks(one_chip, shape, blocks, form):
-    """The blocked backward alone: one Mosaic call under its own VMEM limit
-    where the rule says the resident dq fits, within the rule's estimate of
-    what it uses and with the gradients in the operands' buffers; else the
-    two calls under the default limit."""
+def test_flash_backward_compiles_in_the_form_its_rule_picks(one_chip, shape, blocks, form, layout):
+    """The blocked backward alone, through the head-major internals that
+    the ring path also calls by name: one Mosaic call under its own VMEM
+    limit where the rule says the resident dq fits, within the rule's
+    estimate of what it uses and with the gradients in the operands'
+    buffers; else the two calls under the default limit. Where the layout
+    rule runs the shape in place, that form's one call as well, on the
+    caller's own arrays, under the same limit and within the same estimate."""
     import importlib
 
     flmod = importlib.import_module("sav_tpu.ops.flash_attention")
     batch, length, heads, dim = shape[:4]
     dim_v = shape[4] if len(shape) == 5 else dim
-    assert form == flmod.backward_form(
-        length, length, dim, dim_v, batch_heads=batch * heads, **blocks
-    )
+    sizes = (length, length, dim, dim_v)
+    assert form == flmod.backward_form(*sizes, batch_heads=batch * heads, **blocks)
+    assert layout == flmod.layout_form(*sizes, batch_heads=batch * heads, **blocks)
 
     def spec(d, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct((batch, length, heads, d), dtype, sharding=one_chip)
@@ -288,12 +305,10 @@ def test_flash_backward_compiles_in_the_form_its_rule_picks(one_chip, shape, blo
     block_q = min(blocks.get("block_q", 256), -(-length // 16) * 16)
     length_p = -(-length // block_q) * block_q
     lse = jax.ShapeDtypeStruct((batch * heads, length_p, 128), jnp.float32, sharding=one_chip)
+    call = (dim ** -0.5, blocks.get("block_q", 256), blocks.get("block_kv", 256), False)
 
     def backward(q, k, v, out, g, lse):
-        return flmod._flash_backward_pallas(
-            q, k, v, out, lse, g, dim ** -0.5, blocks.get("block_q", 256),
-            blocks.get("block_kv", 256), False, causal=True, block_b=blocks.get("block_b"),
-        )
+        return flmod._flash_backward_pallas(q, k, v, out, lse, g, *call, causal=True, block_b=blocks.get("block_b"))
 
     compiled = jax.jit(backward).lower(
         spec(dim), spec(dim), spec(dim_v), spec(dim_v), spec(dim_v), lse
@@ -304,13 +319,43 @@ def test_flash_backward_compiles_in_the_form_its_rule_picks(one_chip, shape, blo
         return
     assert limits == [flmod._ONE_KERNEL_VMEM_LIMIT]
     # dq, dk, dv are written over the padded q, k, v: no HBM of their own.
-    assert "output_to_operand_aliasing={{0}: (0, {}), {1}: (1, {}), {2}: (2, {})}" in compiled.as_text()
+    aliased = "output_to_operand_aliasing={{0}: (0, {}), {1}: (1, {}), {2}: (2, {})}"
+    assert aliased in compiled.as_text()
     estimate = flmod.one_kernel_backward_vmem_bytes(
         length_p, flmod._pad_head(dim), flmod._pad_head(dim_v), block_q=block_q,
         block_kv=min(blocks.get("block_kv", 256), length_p),
         block_b=flmod._resolve_block_b(blocks.get("block_b"), batch * heads),
     )
     assert 0.45 * estimate <= used[0] <= estimate <= flmod.ONE_KERNEL_VMEM_BUDGET
+    if layout == "head_major":
+        return
+
+    def in_place(q, k, v, out, g, lse):
+        return flmod._in_place_backward(q, k, v, out, lse, g, *call, causal=True)
+
+    def in_place_forward(q, k, v):
+        return flmod._in_place_forward(q, k, v, *call, True, causal=True)
+
+    def own_vmem(compiled):
+        """What Mosaic used of the one call's share: where XLA keeps scoped
+        buffers of its own they lie under the call's."""
+        assert _kernel_vmem(compiled, "scoped_memory_configs") == [flmod._ONE_KERNEL_VMEM_LIMIT]
+        return _kernel_vmem(compiled, "used_scoped_memory_configs")[0] - _kernel_vmem(compiled, "scoped_memory_configs", "offset")[0]
+
+    flat_out = jax.ShapeDtypeStruct((batch, length, heads * dim_v), jnp.bfloat16, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((batch, heads, 1, length), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(in_place).lower(spec(dim), spec(dim), spec(dim_v), flat_out, spec(dim_v), rows).compile()
+    assert f"f32[{batch * heads},{length_p},128]" not in compiled.as_text()
+    in_place_used = own_vmem(compiled)
+    assert 0.45 * estimate <= in_place_used <= estimate
+    # The forward holds the same tiles and no resident dq: the backward's
+    # estimate bounds it, which is why the layout rule reads that one alone.
+    forward_used = own_vmem(jax.jit(in_place_forward).lower(spec(dim), spec(dim), spec(dim_v)).compile())
+    assert forward_used <= in_place_used
+    print(
+        f"Mosaic VMEM used at {shape} {blocks}: head-major backward {used[0] / 2**20:.1f} MiB, "
+        f"in place backward {in_place_used / 2**20:.1f} MiB, forward {forward_used / 2**20:.1f} MiB"
+    )
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
@@ -467,6 +512,21 @@ def test_looped_lm_train_step_compiles_and_fits_one_chip(topo, compiled_kernels,
     assert not any("rematted_computation" in line for line in calls)
     assert "rematted_computation" in text  # the norms and the gated product are computed again
     assert " while(" not in text  # a loop's event would count its body twice in a trace
+    # The calls run in place: q, k, v and dO read with the sequence on the
+    # lanes, the outputs written [B, L, H·D], the logsumexp a row, and no
+    # head-major array or 128-lane float32 tile anywhere in the step.
+    assert all("bf16[2,2048,4096]{2,1,0}, bf16[2,2048,4096]{2,1,0}" in line for line in calls)
+    assert all("bf16[2,4096,2048]" in line for line in calls) and "f32[2,16,1,4096]" in text
+    assert not re.search(r"(bf16|f32)\[32,4096,128\]|bf16\[2,16,4096,128\]", text)
+    # And XLA moves nothing beside them: the matmuls and the rotary write q,
+    # k, v and dO in the order the calls read (a transpose answered with a
+    # layout), and read the calls' outputs as they are written. No copy,
+    # transpose or pad of any size is left under the core's scope, where the
+    # head-major form had eleven transposes of q's 33.5 MB and two 67 MB
+    # broadcasts an application.
+    in_core = lambda scope: "SelfAttentionBlock" in scope and "to_qkv" not in scope and "to_out" not in scope
+    moved = [(opcode, out) for opcode, out, _ in _entry_scope(text, in_core) if opcode in ("copy", "transpose", "pad")]
+    assert not moved, moved
 
 
 def test_looped_lm_optimizer_is_one_pass_over_each_parameter(topo, compiled_kernels, monkeypatch):

@@ -1,8 +1,10 @@
-"""The decoder of the expert families: a pre-norm stack with latent
-attention, routed and shared experts and multi-token-prediction modules,
-whose residual path is plain or a set of hyper-connected streams.
+"""The decoder of the expert families: a pre-norm stack of token mixers
+(latent attention; or gated delta-rule blocks with a gated grouped-query
+attention block every few layers), routed and shared experts and
+multi-token-prediction modules, whose residual path is plain or a set of
+hyper-connected streams.
 
-Two registry entries build it. ``joyai_llm_flash``: sizes of
+Three registry entries build it. ``joyai_llm_flash``: sizes of
 ``https://huggingface.co/jdopensource/JoyAI-LLM-Flash/blob/main/config.json``,
 the layer equations of the family its config names (arXiv:2412.19437 sections
 2.1, 2.2 and 4.2)::
@@ -21,7 +23,20 @@ the final norm reads their sum, and the module's layer does the same with
 ``eh_proj``'s result. At ``hc_mult`` 1 the state is one array and a sublayer
 is ``h + F(RMSNorm(h))``, the same program as before the streams existed.
 
-``Attn`` is :class:`~sav_tpu.models.layers.LatentSelfAttentionBlock`. ``FFN``
+``qwen3_next_80b_a3b``: sizes of ``https://huggingface.co/Qwen/
+Qwen3-Next-80B-A3B-Instruct/blob/main/config.json``. Its token mixers come
+from a per-layer list of kinds (``mixers``; :func:`hybrid_mixers` builds it
+from the config's ``full_attention_interval``): ``gated_delta`` is
+:class:`~sav_tpu.models.layers.gated_delta.GatedDeltaNetBlock`, a recurrence
+over the sequence, ``gated_attention`` is :class:`~sav_tpu.models.layers.
+gated_attention.GatedSelfAttentionBlock`; its norms store their weight as the
+offset from 1 (``norm_offset``), its router scores by a softmax over all
+experts without a selection bias (``scoring``, ``bias_update_rate`` 0), its
+shared expert sits behind a sigmoid gate (``shared_gate``), every layer is an
+expert layer and there is no MTP module.
+
+``Attn`` is :class:`~sav_tpu.models.layers.LatentSelfAttentionBlock` unless
+``mixers`` says otherwise. ``FFN``
 is SwiGLU at ``mlp_ch`` in the first ``first_dense`` layers and
 :class:`~sav_tpu.models.layers.SparseMoEBlock` after them: sigmoid scores,
 the top ``top_k`` of score plus a selection bias, a shared expert, no
@@ -46,7 +61,9 @@ of an expert-parallel deployment (see ``SparseMoEBlock``); nothing here
 stands in for the chips that hold the rest.
 
 Scopes, for the readers of a trace: layers ``layer_<i>``; in a layer the
-attention block is ``LatentSelfAttentionBlock_0`` (``to_qkv``, ``to_out``),
+token mixer is ``LatentSelfAttentionBlock_0``, ``GatedSelfAttentionBlock_0``
+or ``GatedDeltaNetBlock_0`` (``to_qkv``, ``to_out`` in each; the last also
+``gdn/conv``, ``gdn/rule``, ``gdn/gate_norm``),
 the dense MLP ``GatedFFBlock_0`` (``fc1``, ``fc2``), the expert layer
 ``moe`` (``route``, ``dispatch``, ``experts/fc1|fc2``, ``combine``,
 ``shared/fc1|fc2``); a hyper-connection's maps under ``hc_attn`` and
@@ -69,6 +86,8 @@ from sav_tpu.models.layers import (
     RMSNorm,
     SparseMoEBlock,
 )
+from sav_tpu.models.layers.gated_attention import GatedSelfAttentionBlock
+from sav_tpu.models.layers.gated_delta import GatedDeltaNetBlock
 from sav_tpu.models.layers.hyper_connection import HyperConnection, fan_in, fan_out
 from sav_tpu.models.layers.moe import rows_over_bound
 from sav_tpu.models.ouro import LMHead
@@ -101,29 +120,70 @@ KEPT_UNDER_REMAT = (
 # pass the maps' projection and the twenty Sinkhorn iterations.
 KEPT_UNDER_REMAT_BESIDE_STREAMS = tuple(name for name in KEPT_UNDER_REMAT if name != "attn_qkv")
 
+# The hybrid family's choice: the names above (``mla_latent`` and ``hc_maps``
+# tag nothing here) and three of the delta-rule block's own, a layer at 4 x
+# 4,096 tokens: ``gdn_solved`` (the rule's ``T beta`` and masked ``Q K^T`` a
+# chunk, 134 MB: with them the layer's recomputation runs the scan alone and
+# not the triangular inverses a second time), ``gdn_conv`` (q, k, v after the
+# convolution, 268 MB: spares the input projections and the convolution) and
+# ``gdn_out`` (the rule's output, 134 MB). Chosen on a v5e at the published
+# widths, 4 x 4,096 tokens and 32 of 512 experts held (sequences/s,
+# ``memory_program_bytes``; my chip run, PR 37, call 3): ``gdn_out`` alone
+# 6.195 in 15.00 GB, with ``gdn_solved`` 6.689 in 14.88 GB, with ``gdn_conv``
+# too **6.755 in 15.19 GB**; the projections' results as well (402 MB a layer)
+# compile to 15.60 GB for a described v5e, over the cell's 15.5, and carry no
+# tag.
+KEPT_UNDER_REMAT_BESIDE_RECURRENCE = KEPT_UNDER_REMAT + ("gdn_solved", "gdn_conv", "gdn_out")
+
+
+# How a step's per-layer ``stats`` become one number: by key.
+STAT_REDUCTIONS = {
+    "hc_doubly_stochastic_err": jnp.max, "hc_stream_gain": jnp.max,
+    "gdn_decay_min": jnp.min, "gdn_state_rms_max": jnp.max, "attn_gate_mean": jnp.mean,
+}
+
+
+def hybrid_mixers(num_layers: int, full_attention_interval: int) -> tuple:
+    """The token mixer of each layer of a hybrid decoder: layer ``i`` is
+    ``gated_attention`` where ``(i + 1) % full_attention_interval == 0`` and
+    ``gated_delta`` otherwise (the public config's rule)."""
+    return tuple(
+        "gated_attention" if (i + 1) % full_attention_interval == 0 else "gated_delta"
+        for i in range(num_layers)
+    )
+
 
 class LatentDecoderBlock(nn.Module):
     """One pre-norm layer; ``num_experts`` 0 makes its FFN the dense SwiGLU.
-    ``hc`` holds :class:`HyperConnection`'s sizes (``streams`` 1: the state is
-    one array and a sublayer is ``h + F(RMSNorm(h))``). Returns ``(state,
-    counts, balance, hc_stats)``: the two in the middle ``None`` for a dense
-    layer, the last ``None`` at one stream, else the larger of its two
-    sublayers' ``stats``."""
+    ``mixer`` is the token mixer's kind (``latent``: latent attention at the
+    sizes of the fields below; ``gated_attention`` or ``gated_delta``: that
+    block at ``mixer_sizes``). ``hc`` holds :class:`HyperConnection`'s sizes
+    (``streams`` 1: the state is one array and a sublayer is ``h +
+    F(RMSNorm(h))``). Returns ``(state, counts, balance, stats)``: the two in
+    the middle ``None`` for a dense layer; ``stats`` a dict of float32 scalars
+    under the keys of :data:`STAT_REDUCTIONS` (the hyper-connections' two, the
+    larger of the two sublayers'; the delta-rule block's two; the gated
+    attention's one), ``None`` where the layer has none."""
 
-    num_heads: int
-    q_rank: int
-    kv_rank: int
-    nope_ch: int
-    rope_ch: int
-    v_ch: int
     mlp_ch: int
     num_experts: int
     top_k: int
     routed_scale: float
     experts_held: Optional[Any]
-    rope_theta: float
     norm_eps: float
+    num_heads: int = 0
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_ch: int = 0
+    rope_ch: int = 0
+    v_ch: int = 0
+    rope_theta: float = 1e4
     rope_scaling: Optional[Any] = None
+    mixer: str = "latent"
+    mixer_sizes: Optional[Any] = None  # the gated blocks' sizes as a dict
+    norm_offset: bool = False  # the norms' weights are offsets from 1
+    scoring: str = "sigmoid"
+    shared_gate: bool = False
     hc: Optional[Any] = None  # HyperConnection's sizes as a dict; None = one stream
     backend: Optional[str] = None
     logits_dtype: Optional[Dtype] = None
@@ -133,27 +193,37 @@ class LatentDecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, inputs, select_bias: Optional[jax.Array]):
         def norm(name):
-            return RMSNorm(eps=self.norm_eps, dtype=self.dtype, name=name)
+            return RMSNorm(eps=self.norm_eps, offset=self.norm_offset, dtype=self.dtype, name=name)
 
         def residual(name):
             return HyperConnection(**(self.hc or {"streams": 1}), norm_eps=self.norm_eps, dtype=self.dtype, name=name)
 
+        def mix(x):
+            """``(the token mixer's result, its stats or None)``."""
+            shared = dict(norm_eps=self.norm_eps, quant=self.quant, dtype=self.dtype)
+            attention = dict(rope_theta=self.rope_theta, backend=self.backend, logits_dtype=self.logits_dtype)
+            if self.mixer == "gated_delta":
+                out, stats = GatedDeltaNetBlock(**self.mixer_sizes, **shared)(x)
+                return out, {"gdn_" + k: v for k, v in stats.items()}
+            if self.mixer == "gated_attention":
+                out, stats = GatedSelfAttentionBlock(**self.mixer_sizes, **attention, **shared)(x)
+                return out, {"attn_" + k: v for k, v in stats.items()}
+            if self.mixer != "latent":
+                raise ValueError(f"token mixer {self.mixer!r}: latent, gated_attention or gated_delta")
+            return LatentSelfAttentionBlock(
+                num_heads=self.num_heads,
+                q_rank=self.q_rank,
+                kv_rank=self.kv_rank,
+                nope_ch=self.nope_ch,
+                rope_ch=self.rope_ch,
+                v_ch=self.v_ch,
+                rope_scaling=self.rope_scaling,
+                **attention,
+                **shared,
+            )(x), None
+
         u, merge = residual("hc_attn")(inputs)
-        a = LatentSelfAttentionBlock(
-            num_heads=self.num_heads,
-            q_rank=self.q_rank,
-            kv_rank=self.kv_rank,
-            nope_ch=self.nope_ch,
-            rope_ch=self.rope_ch,
-            v_ch=self.v_ch,
-            rope_theta=self.rope_theta,
-            rope_scaling=self.rope_scaling,
-            norm_eps=self.norm_eps,
-            backend=self.backend,
-            logits_dtype=self.logits_dtype,
-            quant=self.quant,
-            dtype=self.dtype,
-        )(norm("attn_norm")(u))
+        a, stats = mix(norm("attn_norm")(u))
         x, attn_stats = merge(a)
         u, merge = residual("hc_ffn")(x)
         y = norm("ffn_norm")(u)
@@ -166,12 +236,16 @@ class LatentDecoderBlock(nn.Module):
                 hidden_ch=self.mlp_ch,
                 routed_scale=self.routed_scale,
                 experts_held=self.experts_held,
+                scoring=self.scoring,
+                shared_gate=self.shared_gate,
                 quant=self.quant,
                 dtype=self.dtype,
                 name="moe",
             )(y, select_bias)
         x, ffn_stats = merge(m)
-        stats = attn_stats and jax.tree.map(jnp.maximum, attn_stats, ffn_stats)
+        if attn_stats:
+            err, gain = jax.tree.map(jnp.maximum, attn_stats, ffn_stats)
+            stats = dict(stats or {}, hc_doubly_stochastic_err=err, hc_stream_gain=gain)
         return x, counts, balance, stats
 
 
@@ -186,26 +260,40 @@ class JoyAILM(nn.Module):
       the experts held), ``"moe_rows_over_bound" [B, R]`` (each routed
       layer's rows on the experts held over the rows its buffers hold, the
       same in every row: above 1 it took the overflow pass),
-      ``"moe_bias_abs_max" [B]`` and, at ``hc_mult`` > 1,
-      ``"hc_doubly_stochastic_err" [B]`` and ``"hc_stream_gain" [B]`` (the
-      largest of any sublayer's :class:`HyperConnection` ``stats``, the same
-      in every row).
+      ``"moe_bias_abs_max" [B]`` and the layers' ``stats`` reduced over the
+      layers that have them (:data:`STAT_REDUCTIONS`), each ``[B]`` with the
+      same number in every row: at ``hc_mult`` > 1
+      ``"hc_doubly_stochastic_err"`` and ``"hc_stream_gain"`` (the largest of
+      any sublayer's :class:`HyperConnection` ``stats``); with delta-rule
+      layers ``"gdn_decay_min"`` (the smallest ``exp(g_t)`` of the step) and
+      ``"gdn_state_rms_max"`` (the largest RMS of any head's final state);
+      with gated attention layers ``"attn_gate_mean"``.
     """
 
     num_classes: int  # the vocabulary held here
     embed_dim: int
     num_layers: int
-    num_heads: int
-    q_rank: int
-    kv_rank: int
-    nope_ch: int
-    rope_ch: int
-    v_ch: int
     mlp_ch: int
     expert_ch: int
     num_experts: int
     top_k: int
     routed_scale: float
+    # Latent attention's sizes, where that is the token mixer.
+    num_heads: int = 0
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_ch: int = 0
+    rope_ch: int = 0
+    v_ch: int = 0
+    # A hybrid decoder's token mixers (the public config's
+    # full_attention_interval; 0 = latent attention in every layer) and the
+    # two gated blocks' sizes, as dicts of their constructors' arguments.
+    full_attention_interval: int = 0
+    gated_attention: Optional[Any] = None
+    gated_delta: Optional[Any] = None
+    norm_offset: bool = False  # RMSNorm weights stored as offsets from 1
+    scoring: str = "sigmoid"  # the router's: sigmoid | softmax
+    shared_gate: bool = False  # the shared expert behind sigmoid(x w_s)
     first_dense: int = 1
     mtp_modules: int = 1  # the public configs' num_nextn_predict_layers: 0 or 1
     bias_update_rate: float = 1e-3
@@ -246,7 +334,14 @@ class JoyAILM(nn.Module):
             hc = {"streams": self.hc_mult, "sinkhorn_iters": self.hc_sinkhorn_iters,
                   "sinkhorn_eps": self.hc_eps, "res_clamp": tuple(self.hc_res_clamp)}
 
-        def block(name: str, routed: bool):
+        mixers = ("latent",) * (self.num_layers + self.mtp_modules)
+        if self.full_attention_interval:
+            if self.mtp_modules:
+                raise ValueError("a hybrid decoder has no multi-token-prediction module here")
+            mixers = hybrid_mixers(self.num_layers, self.full_attention_interval)
+        sizes_of = {"latent": None, "gated_attention": self.gated_attention, "gated_delta": self.gated_delta}
+
+        def block(name: str, routed: bool, mixer: str = "latent"):
             return block_cls(
                 num_heads=self.num_heads,
                 q_rank=self.q_rank,
@@ -254,6 +349,11 @@ class JoyAILM(nn.Module):
                 nope_ch=self.nope_ch,
                 rope_ch=self.rope_ch,
                 v_ch=self.v_ch,
+                mixer=mixer,
+                mixer_sizes=sizes_of[mixer] and dict(sizes_of[mixer]),
+                norm_offset=self.norm_offset,
+                scoring=self.scoring,
+                shared_gate=self.shared_gate,
                 mlp_ch=self.expert_ch if routed else self.mlp_ch,
                 num_experts=self.num_experts if routed else 0,
                 top_k=self.top_k,
@@ -281,17 +381,18 @@ class JoyAILM(nn.Module):
         if targets is None and self.is_initializing():
             targets = tokens  # init's trace makes every parameter, the MTP module's too
         h = fan_out(embed(tokens), self.hc_mult)
-        counts, balances, hc_stats = [], [], []
+        counts, balances, layer_stats = [], [], []
         for i in range(self.num_layers):
             routed = i >= self.first_dense
             bias = select_bias.value[i - self.first_dense] if routed else None
-            h, c, b, stats = block(f"layer_{i}", routed)(h, bias)
-            hc_stats.append(stats)
+            h, c, b, stats = block(f"layer_{i}", routed, mixers[i])(h, bias)
+            layer_stats.append(stats)
             if routed:
                 counts.append(c)
                 balances.append(b)
         h = fan_in(h)
-        main = head(RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="final_norm")(h), targets)
+        final_norm = RMSNorm(eps=self.norm_eps, offset=self.norm_offset, dtype=self.dtype, name="final_norm")
+        main = head(final_norm(h), targets)
         if targets is None:
             return {"logits": main}
         out = {"ce": main}
@@ -304,7 +405,7 @@ class JoyAILM(nn.Module):
             h_mtp, c, b, stats = mtp(h, embed(targets), select_bias.value[-1])
             counts.append(c)
             balances.append(b)
-            hc_stats.append(stats)
+            layer_stats.append(stats)
             with jax.named_scope("mtp"):
                 mtp_targets = jnp.concatenate([targets[:, 1:], jnp.zeros_like(targets[:, :1])], axis=1)
                 has_target = jnp.arange(targets.shape[1]) < targets.shape[1] - 1
@@ -326,10 +427,10 @@ class JoyAILM(nn.Module):
             "moe_rows_over_bound": jnp.broadcast_to(over_bound, counts.shape[:2]),
             "moe_bias_abs_max": jnp.broadcast_to(bias_max, tokens.shape[:1]),
         })
-        if hc:
-            err, gain = (jnp.max(jnp.stack(column)) for column in zip(*hc_stats))
-            out["hc_doubly_stochastic_err"] = jnp.broadcast_to(err, tokens.shape[:1])
-            out["hc_stream_gain"] = jnp.broadcast_to(gain, tokens.shape[:1])
+        for key, reduce in STAT_REDUCTIONS.items():
+            column = [stats[key] for stats in layer_stats if stats and key in stats]
+            if column:
+                out[key] = jnp.broadcast_to(reduce(jnp.stack(column)), tokens.shape[:1])
         return out
 
 

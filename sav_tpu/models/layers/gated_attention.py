@@ -1,0 +1,121 @@
+"""Gated grouped-query softmax attention, the hybrid decoders' full layer.
+
+``H`` query heads of ``D`` read ``H_kv`` key/value heads (``H_kv`` divides
+``H``; query head ``h`` reads key/value head ``h // (H / H_kv)``)::
+
+    [query | gate] = x W_q          # by head: [query D | gate D]
+    k = x W_k;  v = x W_v           # H_kv heads
+    query, k = RMSNorm_D(query), RMSNorm_D(k)       # per head, offset weights
+    the first ``rotary_ch`` lanes of query and k rotate (lane i with i +
+    rotary_ch / 2), the others pass
+    out = softmax(query k^T D^-0.5, causal) v
+    y = W_o (out sigmoid(gate))
+
+No bias anywhere. Scopes: the module's own name holds ``SelfAttentionBlock``;
+the three input projections, the two norms and the rotary lie under
+``to_qkv``, the output merge is ``to_out``, so the readers of a trace see it
+as any other attention block.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from sav_tpu.models.layers.feedforward import _bias_free_dense
+from sav_tpu.models.layers.normalization import RMSNorm
+from sav_tpu.ops.attention import dot_product_attention
+from sav_tpu.ops.quant import QuantDenseGeneral
+from sav_tpu.ops.rotary import apply_rotary_half, half_split_tables
+
+Dtype = Any
+
+
+def rotate_leading_lanes(x: jax.Array, rotary_ch: int, theta: float) -> jax.Array:
+    """Rotary on the first ``rotary_ch`` lanes of ``x [B, S, H, D]`` with the
+    rotate-halves pairing inside them; the other lanes pass."""
+    tables = half_split_tables(x.shape[1], rotary_ch, theta)
+    return jnp.concatenate([apply_rotary_half(x[..., :rotary_ch], tables), x[..., rotary_ch:]], axis=-1)
+
+
+class _GatedQKVProj(nn.Module):
+    """``x -> (query [B, S, H, D], k [B, S, H_kv, D], v the same, gate [B, S,
+    H, D])``, normed and rotated."""
+
+    num_heads: int
+    kv_heads: int
+    head_ch: int
+    rotary_ch: int
+    rope_theta: float
+    norm_eps: float
+    quant: Optional[str]
+    dtype: Dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        b, s, _ = x.shape
+        h, kv, d = self.num_heads, self.kv_heads, self.head_ch
+        dense = _bias_free_dense(self.quant, self.dtype)
+
+        def norm(name):
+            return RMSNorm(eps=self.norm_eps, offset=True, dtype=self.dtype, name=name)
+
+        query, gate = jnp.split(dense(h * 2 * d, name="q")(x).reshape(b, s, h, 2 * d), 2, axis=-1)
+        key = dense(kv * d, name="k")(x).reshape(b, s, kv, d)
+        value = dense(kv * d, name="v")(x).reshape(b, s, kv, d)
+        query = rotate_leading_lanes(norm("q_norm")(query), self.rotary_ch, self.rope_theta)
+        key = rotate_leading_lanes(norm("k_norm")(key), self.rotary_ch, self.rope_theta)
+        return query, key, value, gate
+
+
+class GatedSelfAttentionBlock(nn.Module):
+    """Causal grouped-query self-attention with a sigmoid gate on its output;
+    see the module docstring. Returns ``(y, stats)``; ``stats`` holds the mean
+    of the gate (``gate_mean``), a float32 scalar without a gradient."""
+
+    num_heads: int
+    kv_heads: int
+    head_ch: int
+    rotary_ch: int
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    backend: Optional[str] = None
+    logits_dtype: Optional[Dtype] = None
+    quant: Optional[str] = None
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, inputs: jax.Array):
+        query, key, value, gate = _GatedQKVProj(
+            num_heads=self.num_heads,
+            kv_heads=self.kv_heads,
+            head_ch=self.head_ch,
+            rotary_ch=self.rotary_ch,
+            rope_theta=self.rope_theta,
+            norm_eps=self.norm_eps,
+            quant=self.quant,
+            dtype=self.dtype,
+            name="to_qkv",
+        )(inputs)
+        query, key, value, gate = (checkpoint_name(t, "attn_qkv") for t in (query, key, value, gate))
+        out = dot_product_attention(
+            query,
+            key,
+            value,
+            scale=self.head_ch ** -0.5,
+            backend=self.backend,
+            logits_dtype=self.logits_dtype or self.dtype,
+            causal=True,
+        )
+        opened = jax.nn.sigmoid(gate.astype(jnp.float32))
+        out = (out.astype(jnp.float32) * opened).astype(self.dtype)
+        dense = functools.partial(QuantDenseGeneral, mode=self.quant) if self.quant else nn.DenseGeneral
+        out = dense(
+            features=inputs.shape[-1], axis=(-2, -1), use_bias=False, dtype=self.dtype, name="to_out"
+        )(out)
+        return checkpoint_name(out, "attn_out"), {"gate_mean": jax.lax.stop_gradient(jnp.mean(opened))}

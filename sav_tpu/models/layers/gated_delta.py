@@ -1,0 +1,217 @@
+"""The gated delta-rule block: linear attention with a recurrent state
+(arXiv:2412.06464), as the hybrid decoders interleave it with softmax
+attention.
+
+For ``H_k`` key heads of ``d_k`` feeding ``H`` value heads of ``d_v``
+(``H_k`` divides ``H``)::
+
+    [q | k | v | z] = x W_qkvz     # laid out BY KEY HEAD: for each of the H_k,
+                                   # [q d_k | k d_k | v (H/H_k) d_v | z (H/H_k) d_v]
+    [b | a] = x W_ba               # by key head too: [b H/H_k | a H/H_k]
+    [q | k | v] = silu(conv([q | k | v]))   # causal, depthwise, width 4, no bias
+    q, k = l2norm(q), l2norm(k);  q = q d_k^-0.5
+    beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)        # float32
+    o = gated_delta_rule(q, k, v, g, beta)          # sav_tpu/ops/gated_delta.py
+    y = W_o (RMSNorm_{d_v}(o) w silu(z))            # per value head; w is plain
+
+Scopes, for the readers of a trace: the two input projections under
+``to_qkv`` (``qkvz``, ``ba``), the output merge ``to_out``; the work between
+them under ``gdn/conv`` (the convolution and its SiLU), ``gdn/rule`` (the
+normalisation of q and k, the gates and the rule) and ``gdn/gate_norm``. The
+module's name holds no ``SelfAttentionBlock``: the attention readers pass it
+by.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from sav_tpu.models.layers.feedforward import _bias_free_dense
+from sav_tpu.ops.gated_delta import CHUNK, gated_delta_rule
+from sav_tpu.ops.quant import QuantDenseGeneral
+
+Dtype = Any
+
+
+def split_by_key_head(qkvz: jax.Array, ba: jax.Array, key_heads: int, key_ch: int, heads: int, value_ch: int):
+    """The fused projections' outputs ``[..., H_k (2 d_k + 2 r d_v)]`` and
+    ``[..., H_k 2 r]`` (``r = H / H_k``) -> ``q, k [..., H_k, d_k]``, ``v, z
+    [..., H, d_v]``, ``b, a [..., H]``: value heads ``j r .. (j + 1) r - 1``
+    are key head ``j``'s."""
+    group = heads // key_heads
+    lead = qkvz.shape[:-1]
+    qkvz = qkvz.reshape(lead + (key_heads, 2 * key_ch + 2 * group * value_ch))
+    q, k, v, z = jnp.split(qkvz, [key_ch, 2 * key_ch, 2 * key_ch + group * value_ch], axis=-1)
+    v, z = (t.reshape(lead + (heads, value_ch)) for t in (v, z))
+    b, a = jnp.split(ba.reshape(lead + (key_heads, 2 * group)), 2, axis=-1)
+    return q, k, v, z, b.reshape(lead + (heads,)), a.reshape(lead + (heads,))
+
+
+def causal_depthwise_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
+    """``y_t = sum_i kernel[i] x_{t - (W - 1) + i}`` a channel, on ``x [B, S,
+    C]`` with ``kernel [W, C]``: position ``t`` reads ``t - W + 1 .. t`` and
+    zeros before the sequence starts. Summed in float32 from taps that are
+    slices of ``x`` padded once, in its own dtype."""
+    width, seq = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    taps = (padded[:, i:i + seq].astype(jnp.float32) * kernel[i].astype(jnp.float32) for i in range(width))
+    return functools.reduce(jnp.add, taps)
+
+
+@jax.custom_vjp
+def causal_conv_silu(x: jax.Array, kernel: jax.Array) -> jax.Array:
+    """``silu(causal_depthwise_conv(x, kernel))`` in ``x``'s dtype, with the
+    backward pass written out: it computes the float32 sums again from ``x``
+    (SiLU's derivative reads them), ``dx_s = sum_i kernel[i] dy_{s + W - 1 -
+    i}`` from ``dy`` padded once at its end, and ``dkernel[i] = sum dy_t x_{t
+    - W + 1 + i}``, each one fusion over the operands. What JAX transposes
+    from the forward is a padded float32 copy a tap: compiled for a v5e at
+    ``[4, 4096, 8192]`` the pass and its gradient move 1.6 GB and hold 0.54 GB
+    beside their operands this way, 7.3 GB and 1.34 GB that way."""
+    return nn.silu(causal_depthwise_conv(x, kernel)).astype(x.dtype)
+
+
+def _causal_conv_silu_fwd(x, kernel):
+    return causal_conv_silu(x, kernel), (x, kernel)
+
+
+def _causal_conv_silu_bwd(residuals, g):
+    x, kernel = residuals
+    width, seq = kernel.shape[0], x.shape[1]
+    y = causal_depthwise_conv(x, kernel)
+    gate = jax.nn.sigmoid(y)
+    dy = g.astype(jnp.float32) * gate * (1.0 + y * (1.0 - gate))  # d silu(y) / dy
+    ahead = jnp.pad(dy, ((0, 0), (0, width - 1), (0, 0)))
+    dx = functools.reduce(
+        jnp.add,
+        (ahead[:, width - 1 - i:width - 1 - i + seq] * kernel[i].astype(jnp.float32) for i in range(width)),
+    )
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    dkernel = jnp.stack([jnp.sum(dy * padded[:, i:i + seq].astype(jnp.float32), axis=(0, 1)) for i in range(width)])
+    return dx.astype(x.dtype), dkernel.astype(kernel.dtype)
+
+
+causal_conv_silu.defvjp(_causal_conv_silu_fwd, _causal_conv_silu_bwd)
+
+
+def l2_normalise(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+class _InputProj(nn.Module):
+    key_heads: int
+    key_ch: int
+    heads: int
+    value_ch: int
+    quant: Optional[str]
+    dtype: Dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        dense = _bias_free_dense(self.quant, self.dtype)
+        group = self.heads // self.key_heads
+        qkvz = dense(self.key_heads * 2 * (self.key_ch + group * self.value_ch), name="qkvz")(x)
+        ba = dense(2 * self.heads, name="ba")(x)
+        return split_by_key_head(qkvz, ba, self.key_heads, self.key_ch, self.heads, self.value_ch)
+
+
+class _CausalConv(nn.Module):
+    """:func:`causal_conv_silu` with its ``[W, C]`` kernel (no bias)."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        kernel = self.param(
+            "kernel", nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=0, out_axis=1),
+            (self.width, x.shape[-1]),
+        )
+        return causal_conv_silu(x, kernel)
+
+
+class _GatedNorm(nn.Module):
+    """``RMSNorm(o) w silu(z)`` over a value head's lanes, float32 inside."""
+
+    eps: float
+    dtype: Dtype
+
+    @nn.compact
+    def __call__(self, o: jax.Array, z: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones, (o.shape[-1],))
+
+        @jax.checkpoint  # float32 inside; the backward pass starts from o and z as they came
+        def gated(o, z, scale):
+            o = o.astype(jnp.float32)
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + self.eps)
+            return (o * scale * nn.silu(z.astype(jnp.float32))).astype(self.dtype)
+
+        return gated(o, z, scale)
+
+
+def _decay_rates(key, shape):
+    """``A_log`` at the start: ``log A``, ``A`` uniform in (0, 16] (the
+    published modelling code's)."""
+    return jnp.log(jax.random.uniform(key, shape, minval=1e-4, maxval=16.0))
+
+
+class GatedDeltaNetBlock(nn.Module):
+    """See the module docstring. Returns ``(y, stats)``; ``stats`` holds the
+    smallest ``exp(g_t)`` of the call (``decay_min``) and the largest RMS of
+    any head's final state (``state_rms_max``), float32 scalars without a
+    gradient."""
+
+    key_heads: int
+    heads: int  # value heads
+    key_ch: int
+    value_ch: int
+    conv_width: int = 4
+    norm_eps: float = 1e-6
+    chunk: int = CHUNK
+    quant: Optional[str] = None
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, inputs: jax.Array):
+        batch, seq, _ = inputs.shape
+        q, k, v, z, b, a = _InputProj(
+            self.key_heads, self.key_ch, self.heads, self.value_ch, self.quant, self.dtype, name="to_qkv"
+        )(inputs)
+        a_log = self.param("A_log", _decay_rates, (self.heads,))
+        dt_bias = self.param("dt_bias", nn.initializers.ones, (self.heads,))
+
+        with jax.named_scope("gdn/conv"):
+            widths = (self.key_heads * self.key_ch,) * 2 + (self.heads * self.value_ch,)
+            mixed = jnp.concatenate([t.reshape(batch, seq, -1) for t in (q, k, v)], axis=-1)
+            mixed = checkpoint_name(_CausalConv(self.conv_width, name="conv")(mixed), "gdn_conv")
+            q, k, v = jnp.split(mixed, [widths[0], widths[0] + widths[1]], axis=-1)
+            q, k = (t.reshape(batch, seq, self.key_heads, self.key_ch) for t in (q, k))
+            v = v.reshape(batch, seq, self.heads, self.value_ch)
+        with jax.named_scope("gdn/rule"):
+            @jax.checkpoint  # float32 inside; the backward pass starts from the operands in the compute dtype
+            def operands(q, k, b, a, a_log, dt_bias):
+                q = (l2_normalise(q) * self.key_ch ** -0.5).astype(self.dtype)
+                beta = jax.nn.sigmoid(b.astype(jnp.float32))
+                g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
+                return q, l2_normalise(k).astype(self.dtype), g, beta
+
+            q, k, g, beta = operands(q, k, b, a, a_log, dt_bias)
+            out, state = gated_delta_rule(q, k, v, g, beta, self.chunk)
+            out = checkpoint_name(out, "gdn_out")
+            stats = jax.lax.stop_gradient({
+                "decay_min": jnp.exp(jnp.min(g)),
+                "state_rms_max": jnp.sqrt(jnp.max(jnp.mean(jnp.square(state), axis=(-2, -1)))),
+            })
+        with jax.named_scope("gdn/gate_norm"):
+            out = _GatedNorm(self.norm_eps, self.dtype, name="gate_norm")(out, z)
+        dense = functools.partial(QuantDenseGeneral, mode=self.quant) if self.quant else nn.DenseGeneral
+        out = dense(
+            features=inputs.shape[-1], axis=(-2, -1), use_bias=False, dtype=self.dtype, name="to_out"
+        )(out)
+        return checkpoint_name(out, "attn_out"), stats

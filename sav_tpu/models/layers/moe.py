@@ -428,21 +428,25 @@ def _overflow_pass_bwd(quant, k, rows, layer, res, g):
 _overflow_pass.defvjp(_overflow_pass_fwd, _overflow_pass_bwd)
 
 
+SCORINGS = {"sigmoid": jax.nn.sigmoid, "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
 class _Router(nn.Module):
-    """Sigmoid scores over all ``num_experts``, the top ``top_k`` of score
-    plus selection bias, and the selected scores (without the bias)
-    normalised to ``routed_scale``."""
+    """Scores over all ``num_experts`` (``scoring``: a sigmoid an expert, or
+    a softmax over them), the top ``top_k`` of score plus selection bias, and
+    the selected scores (without the bias) normalised to ``routed_scale``."""
 
     num_experts: int
     top_k: int
     routed_scale: float
+    scoring: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x: jax.Array, select_bias: jax.Array):
         kernel = self.param(
             "kernel", nn.initializers.lecun_normal(), (x.shape[-1], self.num_experts)
         )
-        scores = jax.nn.sigmoid(router_scores(x, kernel))  # [T, E] float32
+        scores = SCORINGS[self.scoring](router_scores(x, kernel))  # [T, E] float32
         _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias), self.top_k)
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
         weights = self.routed_scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
@@ -455,10 +459,12 @@ class SparseMoEBlock(nn.Module):
     """Dropless mixture of experts with a shared expert, on ``[B, S, D]``.
 
     ``y = sum_{i selected and held} g_i E_i(x) + E_shared(x)``: every token
-    scores all ``num_experts`` (sigmoid), the ``top_k`` of score plus
-    ``select_bias`` are selected, ``g`` is the selected scores over their sum
-    times ``routed_scale``. ``experts_held = (offset, count)`` names the
-    experts whose weights live here (one chip's share of an expert-parallel
+    scores all ``num_experts`` (``scoring``: ``sigmoid``, or ``softmax`` over
+    all of them), the ``top_k`` of score plus ``select_bias`` are selected,
+    ``g`` is the selected scores over their sum times ``routed_scale``. With
+    ``shared_gate`` the shared expert's result is multiplied by ``sigmoid(x
+    w_s)``, one number a token (leaf ``shared_gate/kernel``).
+    ``experts_held = (offset, count)`` names the experts whose weights live here (one chip's share of an expert-parallel
     layer; ``None`` holds all): the router, the counts and the balance loss
     stay ``num_experts`` wide, the routed leaves have a leading axis of
     ``count``, and what the absent experts would add is left out. No token
@@ -490,6 +496,8 @@ class SparseMoEBlock(nn.Module):
     hidden_ch: int
     routed_scale: float = 1.0
     experts_held: Optional[Any] = None  # (offset, count); None = all
+    scoring: str = "sigmoid"
+    shared_gate: bool = False
     quant: Optional[str] = None
     dtype: Dtype = jnp.float32
 
@@ -502,9 +510,14 @@ class SparseMoEBlock(nn.Module):
             raise ValueError(f"experts_held {self.experts_held} / top_k {k} do not fit {experts} experts")
         x = inputs.reshape(batch * seq, dim)
 
-        scores, chosen, weights = _Router(experts, k, self.routed_scale, name="route")(
+        scores, chosen, weights = _Router(experts, k, self.routed_scale, self.scoring, name="route")(
             x, select_bias
         )
+        if self.shared_gate:
+            # From the layer's input as the router reads it, in float32.
+            opened = jax.nn.sigmoid(
+                nn.Dense(1, use_bias=False, dtype=jnp.float32, name="shared_gate")(x.astype(jnp.float32))
+            )
         x = x.astype(self.dtype)
         weights = weights.reshape(-1)  # one a routing, token-major like ``order``
 
@@ -537,7 +550,10 @@ class SparseMoEBlock(nn.Module):
                 )
 
         shared = GatedFFBlock(hidden_ch=self.hidden_ch, quant=self.quant, dtype=self.dtype, name="shared")(x)
-        y = (routed + shared.astype(jnp.float32)).astype(self.dtype)
+        shared = shared.astype(jnp.float32)
+        if self.shared_gate:
+            shared = shared * opened
+        y = (routed + shared).astype(self.dtype)
 
         with jax.named_scope("route"):
             counts = jnp.roll(by_local, offset, axis=0).T.astype(jnp.float32)  # [B, E]

@@ -27,14 +27,20 @@ class LayerScaleBlock(nn.Module):
 class RMSNorm(nn.Module):
     """``x / sqrt(mean(x^2) + eps) * scale``: no mean subtraction, no bias.
     Statistics in float32 whatever the input's dtype; the result is cast to
-    ``dtype``."""
+    ``dtype``. With ``offset`` the stored weight is the scale's distance from
+    1 (leaf ``offset``, zero at the start): ``x / rms(x) * (1 + w)``, the form
+    of the checkpoints whose weight decay pulls a norm's scale toward 1."""
 
     eps: float = 1e-6
+    offset: bool = False
     dtype: Dtype = jnp.float32
 
     @nn.compact
     def __call__(self, inputs: jax.Array) -> jax.Array:
-        scale = self.param("scale", nn.initializers.ones, (inputs.shape[-1],))
+        if self.offset:
+            scale = 1.0 + self.param("offset", nn.initializers.zeros, (inputs.shape[-1],))
+        else:
+            scale = self.param("scale", nn.initializers.ones, (inputs.shape[-1],))
         x = inputs.astype(jnp.float32)
         x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps)
         return (x * scale).astype(self.dtype)

@@ -22,7 +22,11 @@ from sav_tpu.models.botnet import BoTNet
 from sav_tpu.models.cait import CaiT
 from sav_tpu.models.ceit import CeiT
 from sav_tpu.models.cvt import CvT
-from sav_tpu.models.joyai import KEPT_UNDER_REMAT_BESIDE_STREAMS, JoyAILM
+from sav_tpu.models.joyai import (
+    KEPT_UNDER_REMAT_BESIDE_RECURRENCE,
+    KEPT_UNDER_REMAT_BESIDE_STREAMS,
+    JoyAILM,
+)
 from sav_tpu.models.mlp_mixer import MLPMixer
 from sav_tpu.models.ouro import OuroLM
 from sav_tpu.models.tnt import TNT
@@ -203,6 +207,28 @@ register(
     },
     hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=(-30.0, 30.0),
     kept_under_remat=KEPT_UNDER_REMAT_BESIDE_STREAMS,
+)
+
+# --- Qwen3-Next-80B-A3B (delta-rule layers with a softmax layer every fourth) -
+# Sizes of https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/
+# config.json; ``num_classes`` is the vocabulary (151,936 there). 79.7 B
+# parameters (3 B active a token): one chip holds one period of the layer
+# pattern and its share of every expert layer (model_overrides={"num_layers":
+# 4, "experts_held": (0, 32)}). No dense layer (``mlp_only_layers`` empty:
+# ``mlp_ch``, the config's ``intermediate_size``, is unused), no selection
+# bias (``bias_update_rate`` 0: the row stays zero), no MTP module.
+register(
+    "qwen3_next_80b_a3b",
+    JoyAILM,
+    task="tokens_mtp",
+    embed_dim=2048, num_layers=48, mlp_ch=5120, expert_ch=512,
+    num_experts=512, top_k=10, routed_scale=1.0, first_dense=0, mtp_modules=0,
+    bias_update_rate=0.0, scoring="softmax", shared_gate=True, norm_offset=True,
+    full_attention_interval=4,
+    gated_attention={"num_heads": 16, "kv_heads": 2, "head_ch": 256, "rotary_ch": 64},
+    gated_delta={"key_heads": 16, "heads": 32, "key_ch": 128, "value_ch": 128, "conv_width": 4},
+    rope_theta=1e7, norm_eps=1e-6,
+    kept_under_remat=KEPT_UNDER_REMAT_BESIDE_RECURRENCE,
 )
 
 

@@ -146,7 +146,9 @@ def xla_attention(
 
     Args:
       query: ``[..., q_len, heads, head_dim]``.
-      key, value: ``[..., kv_len, heads, head_dim]``.
+      key, value: ``[..., kv_len, kv_heads, head_dim]``; ``kv_heads`` divides
+        ``heads`` and query head ``h`` reads key/value head ``h // (heads /
+        kv_heads)`` (grouped-query attention).
       bias: optional logits bias broadcastable to ``[..., heads, q_len, kv_len]``.
       scale: logit scale; defaults to ``head_dim ** -0.5`` (attention.py:39).
       logits_dtype: dtype for softmax math; None = the process default
@@ -165,6 +167,9 @@ def xla_attention(
         logits_dtype = _DEFAULT_LOGITS_DTYPE
     # Canonicalize: config-layer callers pass strings ('bfloat16').
     logits_dtype = jnp.dtype(logits_dtype)
+    if key.shape[-2] != query.shape[-2]:  # grouped heads: the dense path repeats them
+        group = query.shape[-2] // key.shape[-2]
+        key, value = jnp.repeat(key, group, axis=-2), jnp.repeat(value, group, axis=-2)
     probs = _softmax_probs(query, key, bias, scale, logits_dtype, causal)
     if dropout_rate > 0.0 and not deterministic:
         if dropout_rng is None:
@@ -232,20 +237,24 @@ def snapshot_dispatch_log() -> list:
         return [dict(v) for v in _DISPATCH_LOG.values()]
 
 
-def _log_dispatch(shape, kv_len, requested, dispatch: AttentionDispatch, flash_forms=None) -> None:
+def _log_dispatch(shape, kv_len, kv_heads, requested, dispatch: AttentionDispatch, flash_forms=None) -> None:
     # kv_len is part of the identity: cross-attention sites share a query
     # shape with self-attention ones but can resolve differently.
-    key = (shape, kv_len, requested)
+    key = (shape, kv_len, kv_heads, requested)
     with _DISPATCH_LOCK:
         if key not in _DISPATCH_LOG:
             _DISPATCH_LOG[key] = {
                 "shape": list(shape),
                 "kv_len": kv_len,
+                "kv_heads": kv_heads,
                 "requested": requested or "auto",
                 **dispatch.as_note(),
                 # The flash kernel's forms: 'backward' ('one_kernel' |
-                # 'two_kernels', the unbiased path's) and 'layout'
-                # ('in_place' | 'head_major').
+                # 'two_kernels', the unbiased path's), 'layout' ('in_place' |
+                # 'head_major') and, where the key/value heads are fewer than
+                # the query's, 'grouped_kv' ('index_maps': the kernels find a
+                # group's head through their block index | 'repeated': the
+                # head-major copies repeat it).
                 **(flash_forms or {}),
             }
 
@@ -402,7 +411,9 @@ def dot_product_attention(
             flash_forms = {"layout": _flash.layout_form(*lengths, biased=bias is not None, **sizes)}
             if bias is None:
                 flash_forms["backward"] = _flash.backward_form(*lengths, **sizes)
-        _log_dispatch(tuple(query.shape), key.shape[1], requested, dispatch, flash_forms)
+            if key.shape[2] != h:
+                flash_forms["grouped_kv"] = "index_maps" if flash_forms["layout"] == "in_place" else "repeated"
+        _log_dispatch(tuple(query.shape), key.shape[1], key.shape[2], requested, dispatch, flash_forms)
     else:
         if backend in ("pallas", "fused"):
             raise ValueError(
@@ -412,8 +423,8 @@ def dot_product_attention(
             )
         backend, cfg, flash_blocks = "xla", {}, {}
     if backend == "fused":
-        if causal or value.shape[-1] != query.shape[-1]:
-            raise ValueError("the fused attention kernel has no causal arm and one head size")
+        if causal or value.shape[-1] != query.shape[-1] or key.shape[-2] != query.shape[-2]:
+            raise ValueError("the fused attention kernel has no causal arm, one head size and no grouped heads")
         # Shape ineligibility (kv_len over the single-block VMEM budget)
         # raises inside fused_attention with the budget numbers.
         kw = {k: cfg[k] for k in ("block_q", "block_b") if k in cfg}

@@ -907,12 +907,15 @@ def _in_place_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, with_lse: bool, scal
 
 def _in_place_forward(q, k, v, scale, block_q, block_kv, interpret,
                       with_lse: bool = False, *, causal: bool = False):
-    """The forward kernel on ``[B, L, H, D]`` operands where they lie; the
+    """The forward kernel on ``[B, L, H, D]`` operands where they lie (k and
+    v may have fewer heads: query head ``h`` reads key/value head ``h //
+    (H / H_kv)`` through the block index, and nothing is repeated in HBM); the
     output as the kernel writes it, ``[B, Lq, H·D_v]``. With ``with_lse``
     also the logsumexp, one float32 a row as ``[B, H, 1, Lq]`` (a block's
     last two dimensions are then one whole and one of lane tiles)."""
     batch, q_len, heads, dim = q.shape
     kv_len, dim_v = k.shape[1], v.shape[-1]
+    group = heads // k.shape[2]  # query heads a key/value head serves
     if interpret is None:
         interpret = _backend.default_interpret()
     if causal and q_len != kv_len:
@@ -924,7 +927,8 @@ def _in_place_forward(q, k, v, scale, block_q, block_kv, interpret,
         kv_block = lambda i, j: jnp.minimum(j, _last_kv_block(i, block_q, block_kv))
     else:
         kv_block = lambda i, j: j
-    kv_index = lambda b, h, i, j: (b, h, kv_block(i, j))
+    kv_head = (lambda h: h // group) if group > 1 else (lambda h: h)
+    kv_index = lambda b, h, i, j: (b, kv_head(h), kv_block(i, j))
     out_specs = [pl.BlockSpec((1, block_q, dim_v), lambda b, h, i, j: (b, i, h))]
     out_shape = [jax.ShapeDtypeStruct((batch, q_len, heads * dim_v), q.dtype)]
     if with_lse:
@@ -1027,9 +1031,13 @@ def _in_place_backward(q, k, v, out, lse, g, scale, block_q, block_kv,
     """The one-kernel backward on ``[B, L, H, D]`` operands where they lie;
     ``out`` and ``lse`` are the forward's ``[B, Lq, H·D_v]`` and
     ``[B, H, 1, Lq]``. dq, dk and dv are written as ``[B, L, H·D]`` arrays
-    of their own (the operands' buffers hold another order)."""
+    of their own (the operands' buffers hold another order). Where k and v
+    have fewer heads than q, a cell reads its key/value head through the
+    block index, writes its own query head's dk and dv, and the group's are
+    summed after the call."""
     batch, q_len, heads, dim = q.shape
     kv_len, dim_v = k.shape[1], v.shape[-1]
+    group = heads // k.shape[2]
     if interpret is None:
         interpret = _backend.default_interpret()
     block_q, block_kv = _clamp_block(block_q, q_len), _clamp_block(block_kv, kv_len)
@@ -1043,7 +1051,8 @@ def _in_place_backward(q, k, v, out, lse, g, scale, block_q, block_kv,
     else:
         q_block = lambda j, i: i
     q_index = lambda b, h, j, i: (b, h, q_block(j, i))
-    kv_index = lambda b, h, j, i: (b, h, j)
+    kv_head = (lambda h: h // group) if group > 1 else (lambda h: h)
+    kv_index = lambda b, h, j, i: (b, kv_head(h), j)
     dkv_index = lambda b, h, j, i: (b, j, h)
     dq, dk, dv = pl.pallas_call(
         functools.partial(
@@ -1069,8 +1078,9 @@ def _in_place_backward(q, k, v, out, lse, g, scale, block_q, block_kv,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((batch, q_len, heads * dim), q.dtype),
-            jax.ShapeDtypeStruct((batch, kv_len, heads * dim), k.dtype),
-            jax.ShapeDtypeStruct((batch, kv_len, heads * dim_v), v.dtype),
+            # A group's dk and dv leave in float32: eight heads' are summed after.
+            jax.ShapeDtypeStruct((batch, kv_len, heads * dim), k.dtype if group == 1 else jnp.float32),
+            jax.ShapeDtypeStruct((batch, kv_len, heads * dim_v), v.dtype if group == 1 else jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((num_q_blocks, dim, block_q), jnp.float32),
@@ -1083,7 +1093,24 @@ def _in_place_backward(q, k, v, out, lse, g, scale, block_q, block_kv,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_ONE_KERNEL_VMEM_LIMIT),
         interpret=interpret,
     )(_sequence_on_lanes(q), _sequence_on_lanes(k), _sequence_on_lanes(v), _sequence_on_lanes(g), lse, out)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    return dq.reshape(q.shape), _sum_group(dk, k), _sum_group(dv, v)
+
+
+def _sum_group(grad: jax.Array, like: jax.Array) -> jax.Array:
+    """``grad [B, L, H·D]`` or ``[B, L, H, D]``, one a query head -> ``like``'s
+    ``[B, L, H_kv, D]``: the sum over the query heads each key/value head
+    serves, in float32 (a reshape alone where every head has its own)."""
+    if grad.size == like.size:
+        return grad.reshape(like.shape)
+    batch, length, kv_heads, dim = like.shape
+    grad = grad.reshape(batch, length, kv_heads, -1, dim)
+    return jnp.sum(grad.astype(jnp.float32), axis=3).astype(like.dtype)
+
+
+def _repeat_group(x: jax.Array, heads: int) -> jax.Array:
+    """Each of ``x``'s heads repeated to the ``heads / H_kv`` query heads it
+    serves: what the head-major form copies, since it copies anyway."""
+    return x if x.shape[2] == heads else jnp.repeat(x, heads // x.shape[2], axis=2)
 
 
 def _runs_in_place(q, k, v, bias, block_q, block_kv, block_b) -> bool:
@@ -1101,16 +1128,16 @@ def _flash(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b):
         out = _in_place_forward(q, k, v, scale, block_q, block_kv, interpret, causal=causal)
         return out.reshape(q.shape[:3] + v.shape[3:])
     return _flash_forward(
-        q, k, v, bias, scale, block_q, block_kv, interpret,
-        causal=causal, block_b=block_b,
+        q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), bias, scale,
+        block_q, block_kv, interpret, causal=causal, block_b=block_b,
     )
 
 
 def _flash_fwd(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b):
     if bias is not None:
         out = _flash_forward(
-            q, k, v, bias, scale, block_q, block_kv, interpret,
-            causal=causal, block_b=block_b,
+            q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), bias, scale,
+            block_q, block_kv, interpret, causal=causal, block_b=block_b,
         )
         return out, (q, k, v, bias, None, None)
     # The residuals are the output and one float32 a row of logsumexp. A
@@ -1129,8 +1156,8 @@ def _flash_fwd(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block
         # The head-major kernel writes a 128-lane tile a row, which its
         # backward rebuilds.
         out, lse = _flash_forward(
-            q, k, v, bias, scale, block_q, block_kv, interpret, with_lse=True,
-            causal=causal, block_b=block_b,
+            q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), bias, scale,
+            block_q, block_kv, interpret, with_lse=True, causal=causal, block_b=block_b,
         )
         out = kept = checkpoint_name(out, "flash_out")
         lse_row = lse[..., 0]
@@ -1151,12 +1178,15 @@ def _flash_bwd(scale, block_q, block_kv, interpret, causal, block_b, residuals, 
             return dq, dk, dv, None
         lse = jnp.broadcast_to(lse_row[..., None], lse_row.shape + (128,))
         dq, dk, dv = _flash_backward_pallas(
-            q, k, v, out, lse, g, scale, block_q, block_kv, interpret,
-            causal=causal, block_b=block_b,
+            q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), out, lse, g, scale,
+            block_q, block_kv, interpret, causal=causal, block_b=block_b,
         )
-        return dq, dk, dv, None
+        return dq, _sum_group(dk, k), _sum_group(dv, v), None
     del block_q, block_kv, interpret, block_b
-    return _dense_recompute_bwd(q, k, v, bias, g, scale, causal=causal)
+    dq, dk, dv, dbias = _dense_recompute_bwd(
+        q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), bias, g, scale, causal=causal
+    )
+    return dq, _sum_group(dk, k), _sum_group(dv, v), dbias
 
 
 def _dense_recompute_bwd(q, k, v, bias, g, scale, *, causal: bool = False):
@@ -1221,8 +1251,12 @@ def flash_attention(
 
     Args:
       query: ``[B, q_len, heads, head_dim]``.
-      key: ``[B, kv_len, heads, head_dim]``.
-      value: ``[B, kv_len, heads, value_dim]``; ``value_dim`` may differ
+      key: ``[B, kv_len, kv_heads, head_dim]``; ``kv_heads`` divides
+        ``heads`` (grouped-query attention: query head ``h`` reads key/value
+        head ``h // (heads / kv_heads)``). In place the kernels find the head
+        through their block index; the head-major form repeats k and v in the
+        copies it makes anyway. dk and dv are summed over a group either way.
+      value: ``[B, kv_len, kv_heads, value_dim]``; ``value_dim`` may differ
         from ``head_dim`` (latent attention: 192 / 128) on the unbiased
         path, and the output then has the value's head size.
       bias: optional additive logits bias, broadcastable to
@@ -1245,6 +1279,10 @@ def flash_attention(
         raise ValueError(f"expected [B, L, H, D] inputs, got {query.shape}")
     if key.shape[-1] != query.shape[-1]:
         raise ValueError(f"query and key heads differ: {query.shape[-1]} != {key.shape[-1]}")
+    if key.shape[2] != value.shape[2] or query.shape[2] % key.shape[2]:
+        raise ValueError(
+            f"{query.shape[2]} query heads on {key.shape[2]} key and {value.shape[2]} value heads"
+        )
     if scale is None:
         scale = query.shape[-1] ** -0.5
     if bias is not None and bias.ndim != 4:

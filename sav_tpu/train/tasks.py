@@ -287,11 +287,25 @@ class MTPTokenPrediction(TokenPrediction):
     counts (all, and those on the experts it holds), each routed layer's
     fill of its bounded buffers and, where its residual path is
     hyper-connected streams, how far its mixing matrices are from doubly
-    stochastic and what they do to the streams' norm."""
+    stochastic and what they do to the streams' norm; where its token mixers
+    are delta-rule and gated attention blocks, the smallest decay, the largest
+    state and the gate's mean."""
 
     # lambda of the MTP loss (arXiv:2412.19437 section 4.2's first phase;
     # assumed: benchmark/configs/joyai_llm_flash.json).
     mtp_weight = 0.3
+    # What a decoder's layers hand over of themselves, each one number a row,
+    # and how the rows of a step (one a device under data parallelism) become
+    # one. Hyper-connected streams: the largest |row or column sum - 1| of any
+    # mixing matrix, and the largest norm of a sublayer's mixed streams over
+    # the norm of the streams it mixed (the constraint holds it at 1 or
+    # under). A hybrid decoder: the smallest exp(g_t) of the delta-rule
+    # layers, the largest RMS of any head's final state, and the mean of the
+    # gated attention's sigmoid gate.
+    layer_stats = (
+        ("hc_doubly_stochastic_err", jnp.max), ("hc_stream_gain", jnp.max),
+        ("gdn_decay_min", jnp.min), ("gdn_state_rms_max", jnp.max), ("attn_gate_mean", jnp.mean),
+    )
 
     def loss(self, outputs: dict, targets) -> jax.Array:
         del targets  # the model has already scored every position
@@ -301,12 +315,9 @@ class MTPTokenPrediction(TokenPrediction):
         _, main, mtp = mtp_lm_loss(outputs["ce"], outputs.get("ce_mtp"), self.mtp_weight)
         load = jnp.sum(outputs["moe_counts"], axis=0)  # [R, E]: the step's routings
         optional = {} if mtp is None else {"loss_mtp": mtp}
-        # The largest |row or column sum - 1| of any mixing matrix of the
-        # step, and the largest norm of a sublayer's mixed streams over the
-        # norm of the streams it mixed (the constraint holds it at 1 or under).
-        for name in ("hc_doubly_stochastic_err", "hc_stream_gain"):
+        for name, reduce in self.layer_stats:
             if name in outputs:
-                optional[name] = jnp.max(outputs[name])
+                optional[name] = reduce(outputs[name])
         return {
             "loss_main": main,
             **optional,

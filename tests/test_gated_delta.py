@@ -1,0 +1,192 @@
+"""The gated delta rule (``sav_tpu/ops/gated_delta.py``) and the block around
+it (``sav_tpu/models/layers/gated_delta.py``) at toy sizes on the CPU.
+
+Tolerances. The chunked form and the per-token recurrence both compute in
+float32 here, in different orders (a triangular system and matrix products a
+chunk against one rank-one update a token), so ``TIGHT`` = 2e-5 of the
+compared tensor's largest entry; a gradient with respect to ``g`` under decays
+near 0 is itself near 0 everywhere and is held to 1e-3 of its largest entry
+(it reads 1.5e-4: a sum of terms of both signs, each exp(-20) of an
+activation)."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sav_tpu.models.layers.gated_delta import (
+    GatedDeltaNetBlock,
+    causal_conv_silu,
+    causal_depthwise_conv,
+    l2_normalise,
+    split_by_key_head,
+)
+from sav_tpu.ops.gated_delta import (
+    _unit_lower_inverse,
+    gated_delta_rule,
+    gated_delta_rule_recurrent,
+)
+
+TIGHT = 2e-5
+# Jitted: one compile a shape, where op-by-op dispatch compiles every small
+# operation apart.
+chunked = jax.jit(gated_delta_rule, static_argnames="chunk")
+recurrent = jax.jit(gated_delta_rule_recurrent)
+DECAYS = {"near_0": (-20.0, -5.0), "near_1": (-1e-3, -1e-5), "mixed": (-3.0, -0.01)}
+
+
+def close(got, want, tol=TIGHT):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.max(np.abs(got - want))) <= tol * max(float(np.max(np.abs(want))), 1e-30)
+
+
+def operands(length, key_heads, heads, decay, dk=16, dv=16, batch=2, seed=0, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    # Keys with a common component, as a SiLU leaves them: k_i . k_j is far from 0.
+    q = l2_normalise(jax.random.normal(ks[0], (batch, length, key_heads, dk))) * dk ** -0.5
+    k = l2_normalise(jax.random.normal(ks[1], (batch, length, key_heads, dk)) + 0.5)
+    v = jax.random.normal(ks[2], (batch, length, heads, dv))
+    g = jax.random.uniform(ks[3], (batch, length, heads), minval=DECAYS[decay][0], maxval=DECAYS[decay][1])
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (batch, length, heads)) + 2.0)
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+@pytest.mark.parametrize("decay", sorted(DECAYS))
+@pytest.mark.parametrize("length,chunk", [(150, 64), (37, 16), (5, 64)])
+@pytest.mark.parametrize("key_heads,heads", [(2, 4), (3, 3)])
+def test_the_chunked_rule_is_the_recurrence(length, chunk, key_heads, heads, decay):
+    args = operands(length, key_heads, heads, decay)
+    out, state = chunked(*args, chunk=chunk)
+    want, want_state = recurrent(*args)
+    assert out.shape == want.shape == args[2].shape
+    assert close(out, want) and close(state, want_state)
+
+
+@pytest.mark.parametrize("decay", sorted(DECAYS))
+@pytest.mark.parametrize("length,chunk", [(150, 64), (37, 16)])
+def test_the_chunked_rules_gradients_are_the_recurrences(length, chunk, decay):
+    args = operands(length, 2, 4, decay)
+
+    def scalar(rule):
+        def f(*a):
+            out, state = rule(*a)
+            return jnp.sum(jnp.sin(out)) + jnp.sum(jnp.square(state))
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4)))
+
+    got = scalar(functools.partial(gated_delta_rule, chunk=chunk))(*args)
+    want = scalar(gated_delta_rule_recurrent)(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert close(a, b, 1e-3 if (name, decay) == ("g", "near_0") else TIGHT), name
+
+
+def test_padding_rows_leave_the_state_alone():
+    """A length that is no whole number of chunks ends in the state the
+    recurrence ends in, and the same sequence cut at a chunk's edge and
+    continued gives the same outputs as the whole."""
+    q, k, v, g, beta = operands(70, 2, 4, "mixed")
+    whole, state = chunked(q, k, v, g, beta, chunk=64)  # 58 padded rows
+    _, want = recurrent(q, k, v, g, beta)
+    assert close(state, want)
+    short, _ = chunked(q[:, :64], k[:, :64], v[:, :64], g[:, :64], beta[:, :64], chunk=64)
+    assert close(whole[:, :64], short)  # causal: later tokens do not reach back
+
+
+@pytest.mark.parametrize("n", [8, 16, 24, 64])
+def test_the_triangular_inverse_is_exact_where_the_series_is_not(n):
+    """Entries near 1 throughout: ``sum (-A)^k`` loses every digit in float32
+    (its terms reach 1e17 at n 64), the doubling does not; 24 is no power of two."""
+    lower = jnp.tril(0.9 + 0.1 * jax.random.uniform(jax.random.PRNGKey(n), (3, n, n)), -1)
+    got = np.asarray(jax.jit(_unit_lower_inverse)(lower), np.float64)
+    system = np.eye(n) + np.asarray(lower, np.float64)
+    assert np.max(np.abs(got @ system - np.eye(n))) < 1e-4
+    assert np.allclose(got, np.linalg.inv(system), atol=1e-4 * np.max(np.abs(np.linalg.inv(system))))
+    assert np.array_equal(np.triu(got, 1), np.zeros_like(got))
+
+
+def test_bfloat16_operands_stay_near_the_float32_rule():
+    """The compute dtype's operands with float32 sums and a float32 state: the
+    rounding of q, k, v, T and W, a few parts in a thousand of the output."""
+    args = operands(150, 2, 4, "mixed", dtype=jnp.bfloat16)
+    out, state = chunked(*args)
+    want, want_state = recurrent(*args)
+    assert out.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+    assert close(out.astype(jnp.float32), want, 3e-2) and close(state, want_state, 3e-2)
+
+
+def test_mismatched_heads_are_refused():
+    q, k, v, g, beta = operands(16, 3, 4, "mixed")
+    with pytest.raises(ValueError, match="gated delta rule"):
+        gated_delta_rule(q, k, v, g, beta)
+
+
+# ----------------------------------------------------------------- the block
+
+
+@pytest.mark.parametrize("position", [0, 3, 17])
+def test_the_convolution_is_causal_and_reads_four_positions(position):
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 6))
+    kernel = jax.random.normal(jax.random.PRNGKey(2), (4, 6))
+    base = causal_depthwise_conv(x, kernel)
+    moved = causal_depthwise_conv(x.at[:, position].add(1.0), kernel)
+    changed = np.flatnonzero(np.max(np.abs(np.asarray(moved - base)), axis=(0, 2)) > 0)
+    assert list(changed) == [p for p in range(position, position + 4) if p < 24]
+    # Written out: y_t = sum_i kernel[i] x_{t - 3 + i}, zeros before the start.
+    t = 5
+    want = sum(kernel[i] * x[:, t - 3 + i] for i in range(4))
+    assert np.allclose(np.asarray(base[:, t]), np.asarray(want), atol=1e-6)
+    assert np.allclose(np.asarray(base[:, 0]), np.asarray(kernel[3] * x[:, 0]), atol=1e-6)
+
+
+@pytest.mark.parametrize("width,seq", [(4, 24), (4, 3), (2, 9)])
+def test_the_convolutions_written_out_backward_is_the_transposed_forward(width, seq):
+    """``causal_conv_silu``'s own rule against JAX's derivative of the same
+    composition, float32: the two sum in other orders (1e-5 of the largest)."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, seq, 6))
+    kernel = jax.random.normal(jax.random.PRNGKey(4), (width, 6))
+    g = jax.random.normal(jax.random.PRNGKey(5), (2, seq, 6))
+    plain = lambda x, kernel: jax.nn.silu(causal_depthwise_conv(x, kernel))
+    out, pull = jax.vjp(causal_conv_silu, x, kernel)
+    want, want_pull = jax.vjp(plain, x, kernel)
+    assert close(out, want, 1e-6)
+    for got, ref in zip(pull(g), want_pull(g)):
+        assert got.shape == ref.shape and close(got, ref, 1e-5)
+
+
+def test_the_fused_projection_is_split_by_key_head():
+    """Every output channel carries its own index: key head ``j``'s slice is
+    ``[q | k | v of value heads 2j, 2j + 1 | z of the same]``."""
+    key_heads, dk, heads, dv = 2, 3, 4, 5
+    width = 2 * dk + 2 * 2 * dv  # a key head's slice
+    qkvz = jnp.arange(key_heads * width, dtype=jnp.float32)[None, None, :]
+    ba = jnp.arange(2 * heads, dtype=jnp.float32)[None, None, :]
+    q, k, v, z, b, a = split_by_key_head(qkvz, ba, key_heads, dk, heads, dv)
+    assert (q.shape, k.shape, v.shape, z.shape) == ((1, 1, 2, 3), (1, 1, 2, 3), (1, 1, 4, 5), (1, 1, 4, 5))
+    for j in range(key_heads):
+        at = j * width
+        assert list(q[0, 0, j]) == list(range(at, at + dk))
+        assert list(k[0, 0, j]) == list(range(at + dk, at + 2 * dk))
+        assert list(v[0, 0, 2 * j]) == list(range(at + 2 * dk, at + 2 * dk + dv))
+        assert list(v[0, 0, 2 * j + 1]) == list(range(at + 2 * dk + dv, at + 2 * dk + 2 * dv))
+        assert list(z[0, 0, 2 * j]) == list(range(at + 2 * dk + 2 * dv, at + 2 * dk + 3 * dv))
+    assert list(b[0, 0]) == [0, 1, 4, 5] and list(a[0, 0]) == [2, 3, 6, 7]
+
+
+def test_the_blocks_leaves_scopes_and_stats():
+    block = GatedDeltaNetBlock(key_heads=2, heads=4, key_ch=16, value_ch=16, chunk=8)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 20, 32))
+    variables = jax.jit(block.init)({"params": jax.random.PRNGKey(1)}, x)
+    shapes = jax.tree.map(lambda leaf: leaf.shape, variables["params"])
+    assert shapes == {
+        "A_log": (4,), "dt_bias": (4,), "conv": {"kernel": (4, 2 * 2 * 16 + 4 * 16)},
+        "gate_norm": {"scale": (16,)}, "to_out": {"kernel": (4, 16, 32)},
+        "to_qkv": {"qkvz": {"kernel": (32, 2 * (2 * 16 + 2 * 2 * 16))}, "ba": {"kernel": (32, 8)}},
+    }
+    out, stats = jax.jit(block.apply)(variables, x)
+    assert out.shape == x.shape and set(stats) == {"decay_min", "state_rms_max"}
+    assert 0.0 <= float(stats["decay_min"]) <= 1.0 and float(stats["state_rms_max"]) > 0.0
+    text = jax.jit(lambda v, x: block.apply(v, x)[0]).lower(variables, x).as_text(debug_info=True)
+    for scope in ("gdn/conv", "gdn/rule", "gdn/gate_norm", "to_qkv", "to_out"):
+        assert scope in text, scope
+    assert "SelfAttentionBlock" not in text  # the attention readers pass the block by

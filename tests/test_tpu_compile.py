@@ -168,6 +168,10 @@ KERNEL_CASES = [
     ("flash_causal_tuned-ouro_4k", "flash_causal_tuned", (2, 4096, 16, 128)),
     # Latent attention: a query/key head of 192 beside a value head of 128.
     ("flash_latent_tuned-joyai_4k", "flash_latent_tuned", (2, 4096, 32, 192, 128)),
+    # Grouped key/value heads at head size 256: 16 query heads on 2 (the
+    # hybrid decoder's full-attention layer), in place, the group's head
+    # found through the block index. The fifth number is the key/value heads.
+    ("flash_grouped-qwen3_next_4k", "flash_grouped", (4, 4096, 16, 256, 2)),
 ]
 
 
@@ -207,6 +211,17 @@ def _kernel_fn_and_args(kernel, shape, sharding):
         return (
             lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False, **blocks)
         ), qkv[:2] + (value,)
+    if kernel == "flash_grouped":
+        from sav_tpu.ops.flash_attention import layout_form
+
+        from sav_tpu.ops import attn_tuning
+
+        blocks = attn_tuning.block_config(attn_tuning.lookup(*shape[:2], shape[1], heads, dim, causal=True))
+        assert layout_form(shape[1], shape[1], dim, dim, batch_heads=shape[0] * heads, **blocks) == "in_place"
+        few = spec(shape[:2] + (shape[4], dim))
+        return (
+            lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False, **blocks)
+        ), (qkv[0], few, few)
     if kernel == "fused":
         return (lambda q, k, v: fused_attention(q, k, v, interpret=False)), qkv
     if kernel == "talking_heads":

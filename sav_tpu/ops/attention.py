@@ -240,23 +240,36 @@ def snapshot_dispatch_log() -> list:
 def _log_dispatch(shape, kv_len, kv_heads, requested, dispatch: AttentionDispatch, flash_forms=None) -> None:
     # kv_len is part of the identity: cross-attention sites share a query
     # shape with self-attention ones but can resolve differently.
-    key = (shape, kv_len, kv_heads, requested)
+    _log_once((shape, kv_len, kv_heads, requested), {
+        "shape": list(shape),
+        "kv_len": kv_len,
+        "kv_heads": kv_heads,
+        "requested": requested or "auto",
+        **dispatch.as_note(),
+        # The flash kernel's forms: 'backward' ('one_kernel' |
+        # 'two_kernels', the unbiased path's), 'layout' ('in_place' |
+        # 'head_major') and, where the key/value heads are fewer than
+        # the query's, 'grouped_kv' ('index_maps': the kernels find a
+        # group's head through their block index | 'repeated': the
+        # head-major copies repeat it).
+        **(flash_forms or {}),
+    })
+
+
+def _log_once(key, record: dict) -> None:
     with _DISPATCH_LOCK:
-        if key not in _DISPATCH_LOG:
-            _DISPATCH_LOG[key] = {
-                "shape": list(shape),
-                "kv_len": kv_len,
-                "kv_heads": kv_heads,
-                "requested": requested or "auto",
-                **dispatch.as_note(),
-                # The flash kernel's forms: 'backward' ('one_kernel' |
-                # 'two_kernels', the unbiased path's), 'layout' ('in_place' |
-                # 'head_major') and, where the key/value heads are fewer than
-                # the query's, 'grouped_kv' ('index_maps': the kernels find a
-                # group's head through their block index | 'repeated': the
-                # head-major copies repeat it).
-                **(flash_forms or {}),
-            }
+        _DISPATCH_LOG.setdefault(key, record)
+
+
+def log_rule_form(shape, value_heads: int, chunk: int, dtype: str, form: dict) -> None:
+    """The gated delta rule's record (``ops/gated_delta.py::rule_form``) in
+    the same log, one a traced shape: ``op``, the ``[B, L, H_k, d_k]`` shape
+    of q and k, the value heads, the chunk, and the form: ``rule`` (``kernel``
+    | ``xla``) with its ``chunk_tile``, or with what ``refused`` the kernel."""
+    _log_once(("gated_delta_rule", shape, value_heads, chunk, dtype, form["rule"]), {
+        "op": "gated_delta_rule", "shape": list(shape), "value_heads": value_heads,
+        "chunk": chunk, "dtype": dtype, **form,
+    })
 
 
 def resolve_attention_backend(

@@ -29,29 +29,72 @@ k and v: at 4 x 4,096 tokens and 32 value heads 0.34 GB a layer, against
 0.74 GB with ``W``, ``U`` and q, k scaled and repeated a value head
 (compiled for a v5e the training step held 15.8 GB that way).
 
-Everything that does not read ``S`` (``A``, ``T``, the masked ``Q K^T``) is
-computed for all chunks at once; a ``lax.scan`` over the chunks carries ``S``
-through the last three lines. Every exponent is a difference ``gamma_i -
-gamma_j`` with ``i >= j`` (or ``gamma_i`` itself), so nothing overflows
-however fast the state decays; the upper triangle is masked before ``exp``.
+Everything that does not read ``S`` (``gamma``, ``A``, ``T``, the masked ``Q
+K^T``) is computed before the ``lax.scan`` over the chunks that carries ``S``
+through the last three lines, by one of two programs that :func:`rule_form`
+picks from the backend and the shapes (the choice is a record of the dispatch
+log, ``ops/attention.py::snapshot_dispatch_log``):
 
-Precision: the running sums, ``exp``, the triangular system and the carried
-state are float32; q, k, ``T beta``, the masked ``Q K^T``, ``V - e^gamma K
-S``, ``V'`` and the state enter the matrix products in the operands' dtype
-(the model's compute dtype) and are summed in float32. The backward pass is
-JAX's transpose of this program (the scan's included); a caller bounds what
-it keeps with a remat policy.
+* ``kernel`` (:func:`_prepare_in_vmem`; a TPU, chunks of 16 rows times a
+  power of two, key heads of whole lane tiles, a key head's value heads
+  filling whole lane tiles side by side): one Pallas call a direction whose
+  grid step holds a tile of chunks of one (batch, key head) in VMEM. The
+  forward reads the chunks' rows of q and k and the group's ``gamma`` and
+  ``beta``, forms the
+  decay, ``k k^T`` and ``q k^T`` once a key head, ``A`` and ``T`` for the
+  group's value heads, and writes ``T beta`` and the masked ``Q K^T`` in the
+  operands' dtype; nothing ``C x C`` in float32 leaves VMEM. The backward is
+  written out: from the same four operands and the two cotangents it builds
+  ``T`` and the decay again, applies the inverse's own derivative ``dA = -T^T
+  dT T^T``, and writes dq and dk (summed over the group inside the products),
+  ``d gamma`` and ``d beta``. The running sum ``gamma`` and its transpose are
+  XLA's, on 2 MB a layer.
+* ``xla`` (:func:`_prepare`; everything else, and what the tests hold the
+  kernels to): the same for all chunks at once as XLA's program, ``k k^T`` and
+  ``q k^T`` repeated a value head through HBM, the inverse by
+  :func:`_unit_lower_inverse`, the backward JAX's transpose of it under a
+  ``jax.checkpoint``.
+
+Every exponent is a difference ``gamma_i - gamma_j`` with ``i >= j`` (or
+``gamma_i`` itself), so nothing overflows however fast the state decays; the
+upper triangle is masked before ``exp``.
+
+Precision, in either program: the running sums, ``exp``, the triangular system
+and its inverse (exact: the doubling of :func:`_doubled_inverse`, in the kernels
+from 8 x 8 blocks eliminated on the VPU, its products float32 at ``HIGHEST``;
+no power series, no bfloat16 pass through ``A`` or ``T``) and the carried state
+are float32; q, k, ``T beta``, the masked ``Q
+K^T``, ``V - e^gamma K S``, ``V'`` and the state enter the matrix products in
+the operands' dtype (the model's compute dtype) and are summed in float32, and
+so do the cotangents of ``k k^T`` and ``q k^T`` on their way to dq and dk. The
+scan's backward is JAX's transpose of it; a caller bounds what it keeps with a
+remat policy (the state-free part keeps its four operands and nothing else).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from sav_tpu.ops import _backend
+from sav_tpu.ops import attention as _attention
 
 CHUNK = 64  # the published kernels' chunk
+_HIGHEST = jax.lax.Precision.HIGHEST
+_TILE = 8  # rows of a float32 sublane tile
+CHUNK_TILE = 8  # chunks a grid step; a block of the gates' rows is then whole sublane tiles
+# Chunks a trip of a grid step's loop. Two interleave their MXU and VPU work:
+# both calls 13.7 ms a layer at the hybrid decoder's cell for 14.8 at one and
+# 13.0 at four, whose twice-longer body takes a start 2.3 s to trace and lower
+# where two take 1.0 (PERF.md section 6, PR 38).
+_CHUNKS_A_TRIP = 2
+_MAX_WIDTH = 256  # lanes of a wide array: its diagonal blocks are [width, width] float32
 
 
 @jax.custom_vjp
@@ -73,7 +116,10 @@ def _unit_lower_inverse(lower: jax.Array) -> jax.Array:
     kernels' inside their 16 x 16 blocks) computes the same and was measured
     first: as XLA's program its hundreds of row slices each cross the whole
     array, 26 ms a call at 8,192 systems of 64 on a v5e (PERF.md section 6,
-    PR 37)."""
+    PR 37); the doubling reads 14-16 ms there, ten products of ``[8192, 64,
+    64]`` through HBM. Since PR 38 this is the ``xla`` form's inverse only
+    (:func:`_prepare`): where the kernels run, :func:`_wide_inverse` takes the
+    same steps on a chunk's systems in VMEM (PERF.md section 6, PR 38)."""
     return _doubled_inverse(lower)
 
 
@@ -84,7 +130,7 @@ def _unit_lower_inverse_fwd(lower):
 
 def _unit_lower_inverse_bwd(solved, g):
     back = -jnp.einsum(
-        "...ji,...jk,...lk->...il", solved, g, solved, precision=jax.lax.Precision.HIGHEST
+        "...ji,...jk,...lk->...il", solved, g, solved, precision=_HIGHEST
     )
     n = solved.shape[-1]
     return (jnp.where(jnp.arange(n)[:, None] > jnp.arange(n)[None, :], back, 0.0),)
@@ -93,19 +139,22 @@ def _unit_lower_inverse_bwd(solved, g):
 _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
+def _joined(lower, row, col, size: int):
+    """The entries of ``lower`` that join two diagonal blocks of ``size``
+    into one of twice that: row in the lower half of a pair, column in its
+    left half (``row``, ``col``: every entry's own, broadcast against it)."""
+    pair = row // (2 * size) == col // (2 * size)
+    return jnp.where(pair & (row % (2 * size) >= size) & (col % (2 * size) < size), lower, 0.0)
+
+
 def _doubled_inverse(lower: jax.Array) -> jax.Array:
     n = lower.shape[-1]
     row, col = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
-
-    def joined(size):  # the entries that join two blocks of ``size`` into one
-        pair = row // (2 * size) == col // (2 * size)
-        return jnp.where(pair & (row % (2 * size) >= size) & (col % (2 * size) < size), lower, 0.0)
-
-    solved = jnp.eye(n, dtype=lower.dtype) - joined(1)  # blocks of 2: [[1, 0], [b, 1]]^-1
+    solved = jnp.eye(n, dtype=lower.dtype) - _joined(lower, row, col, 1)  # blocks of 2: [[1, 0], [b, 1]]^-1
     size = 2
     while size < n:
         solved = solved - jnp.einsum(
-            "...ij,...jk,...kl->...il", solved, joined(size), solved, precision=jax.lax.Precision.HIGHEST
+            "...ij,...jk,...kl->...il", solved, _joined(lower, row, col, size), solved, precision=_HIGHEST
         )
         size *= 2
     return solved
@@ -144,6 +193,313 @@ def _prepare(q, k, g, beta, group: int):
     return solved, inside, gamma
 
 
+# ---------------------------------------------------------------------------
+# The same as one Pallas kernel a direction: a grid step holds a tile of
+# chunks of one (batch, key head) in VMEM. A chunk's arrays are WIDE, ``[C,
+# group x C]``: the ``C x C`` matrices of a key head's value heads side by
+# side on the lanes (128 of them at chunk 64 and two value heads a key head),
+# so that the masks, ``exp`` and the scalings fill a vector register, ``k k^T``
+# and ``q k^T`` are one MXU pass a key head against ``k`` stacked ``group``
+# times, and a product of two heads' matrices is one pass against the wide
+# right operand laid out as diagonal blocks, ``[group x C, group x C]``.
+# ---------------------------------------------------------------------------
+
+
+def _diagonal_blocks(wide, chunk: int, group: int):
+    """``[C, group C]`` -> ``[group C, group C]``: head ``h``'s matrix at
+    block ``(h, h)``, zeros elsewhere, so that ``x_wide @ blocks`` is every
+    head's ``x_h @ m_h``, wide."""
+    if group == 1:
+        return wide
+    head = jax.lax.broadcasted_iota(jnp.int32, wide.shape, 1) // chunk
+    return jnp.concatenate([jnp.where(head == h, wide, 0.0) for h in range(group)], axis=0)
+
+
+def _tiles_inverse(lower, chunk: int, group: int):
+    """``(I + lower_h)^-1`` of the 8 x 8 diagonal blocks of every head of a
+    wide array, wide and zero elsewhere, by elimination: the multipliers of a
+    unit lower triangle are its own entries, so ``T <- T - a_p T[p, :]`` for
+    the columns ``p`` of a block in turn, from ``T = I``. A block's rows are
+    one float32 sublane tile: the pivot row is a sublane broadcast, and with
+    tile ``b``'s rows turned left by ``8 b`` lanes (every head's block then
+    starts at the head's first lane) a column of multipliers is one lane a
+    head for all the tiles at once."""
+    tiles, width = chunk // _TILE, group * chunk
+
+    def turned(x, lanes):
+        return pltpu.roll(x, lanes % width, 1) if lanes % width else x
+
+    a = jnp.stack([turned(lower[b * _TILE:(b + 1) * _TILE], -b * _TILE) for b in range(tiles)])  # [tiles, 8, width]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _TILE, width), 2)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (1, _TILE, width), 1)
+    solved = jnp.broadcast_to(jnp.where(lane % chunk == sub, 1.0, 0.0), a.shape)
+    for p in range(_TILE - 1):
+        column = a[:, :, p:p + 1]
+        for h in range(1, group):
+            column = jnp.where(lane // chunk == h, a[:, :, h * chunk + p:h * chunk + p + 1], column)
+        solved = solved - column * solved[:, p:p + 1, :]
+    return jnp.concatenate([turned(solved[b], b * _TILE) for b in range(tiles)], axis=0)
+
+
+def _wide_inverse(lower, chunk: int, group: int):
+    """``(I + lower_h)^-1`` of every head of a wide array (``chunk`` 8 times
+    a power of two), float32 and exact as :func:`_doubled_inverse` is: the 8 x
+    8 diagonal blocks by :func:`_tiles_inverse` on the VPU, then that
+    function's doubling, ``X - X L X`` with the products at ``HIGHEST``, for
+    the sizes from 8 on, where the rows that change (the lower half of every
+    pair of blocks) are whole tiles and the only ones sent through the MXU."""
+    row = jax.lax.broadcasted_iota(jnp.int32, lower.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, lower.shape, 1) % chunk
+
+    def product(x, m):
+        return jnp.dot(x, _diagonal_blocks(m, chunk, group), precision=_HIGHEST, preferred_element_type=jnp.float32)
+
+    solved = _tiles_inverse(lower, chunk, group)
+    size = _TILE
+    while size < chunk:
+        starts = range(0, chunk, 2 * size)
+        low = jnp.concatenate([solved[r + size:r + 2 * size] for r in starts], axis=0)
+        low = low - product(product(low, _joined(lower, row, col, size)), solved)
+        solved = jnp.concatenate(
+            [half for i, r in enumerate(starts) for half in (solved[r:r + size], low[i * size:(i + 1) * size])], axis=0
+        )
+        size *= 2
+    return solved
+
+
+def _over_lanes(x, chunk: int, group: int):
+    """A row's sum over each head's lanes, on every lane of that head."""
+    if group == 1:
+        return jnp.broadcast_to(jnp.sum(x, axis=1, keepdims=True), x.shape)
+    head = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) // chunk
+    out = jnp.zeros_like(x)
+    for h in range(group):
+        out = jnp.where(head == h, jnp.sum(jnp.where(head == h, x, 0.0), axis=1, keepdims=True), out)
+    return out
+
+
+def _chunk_system(q, k, gamma_row, beta_row, chunk: int, group: int):
+    """What both kernels build of a chunk from its operands (``q, k [C,
+    d_k]``, a row ``[1, group C]`` of running sums and one of ``beta``), all
+    wide float32: the decay ``exp(gamma_i - gamma_j)`` under the mask, ``k
+    k^T``, ``q k^T``, ``beta`` down the rows and along them, the strictly
+    lower system and its inverse."""
+    shape = (chunk, group * chunk)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1) % chunk
+    eye = row == col
+    gamma_j, beta_j = jnp.broadcast_to(gamma_row, shape), jnp.broadcast_to(beta_row, shape)
+    # A row vector turned into a column: its diagonal, summed over the lanes.
+    gamma_i = _over_lanes(jnp.where(eye, gamma_j, 0.0), chunk, group)
+    beta_i = _over_lanes(jnp.where(eye, beta_j, 0.0), chunk, group)
+    decay = jnp.exp(jnp.where(row >= col, gamma_i - gamma_j, -jnp.inf))
+    stacked = jnp.concatenate([k] * group, axis=0)  # [group C, d_k]
+    along = (((1,), (1,)), ((), ()))
+    kk = jax.lax.dot_general(k, stacked, along, preferred_element_type=jnp.float32)
+    qk = jax.lax.dot_general(q, stacked, along, preferred_element_type=jnp.float32)
+    system = jnp.where(row > col, beta_i * kk * decay, 0.0)
+    return dict(
+        row=row, col=col, eye=eye, decay=decay, kk=kk, qk=qk, beta_i=beta_i, beta_j=beta_j,
+        stacked=stacked, solved=_wide_inverse(system, chunk, group),
+    )
+
+
+def _for_each_chunk(tile: int, one_chunk) -> None:
+    """A grid step's loop over its chunks, ``_CHUNKS_A_TRIP`` of them a trip."""
+    a_trip = _CHUNKS_A_TRIP if tile % _CHUNKS_A_TRIP == 0 else 1
+
+    def trip(i, carry):
+        for j in range(a_trip):
+            one_chunk(i * a_trip + j)
+        return carry
+
+    jax.lax.fori_loop(0, tile // a_trip, trip, None)
+
+
+def _heads(ref, c, chunk: int, group: int):
+    """A chunk's ``[group, C, C]`` block of ``ref`` as one wide float32 array."""
+    return jnp.concatenate([ref[c, 0, h].astype(jnp.float32) for h in range(group)], axis=1)
+
+
+def _prepare_fwd_kernel(q_ref, k_ref, gamma_ref, beta_ref, solved_ref, inside_ref, *, chunk: int, group: int):
+    def one_chunk(c):
+        s = _chunk_system(
+            q_ref[c, 0, 0], k_ref[c, 0, 0], gamma_ref[0, 0, pl.ds(c, 1), :], beta_ref[0, 0, pl.ds(c, 1), :],
+            chunk, group,
+        )
+        solved = (s["solved"] * s["beta_j"]).astype(solved_ref.dtype)  # T beta
+        inside = (s["qk"] * s["decay"]).astype(inside_ref.dtype)
+        for h in range(group):
+            solved_ref[c, 0, h] = solved[:, h * chunk:(h + 1) * chunk]
+            inside_ref[c, 0, h] = inside[:, h * chunk:(h + 1) * chunk]
+
+    _for_each_chunk(q_ref.shape[0], one_chunk)
+
+
+def _prepare_bwd_kernel(q_ref, k_ref, gamma_ref, beta_ref, dsolved_ref, dinside_ref,
+                        dq_ref, dk_ref, dgamma_ref, dbeta_ref, *, chunk: int, group: int):
+    dtype = q_ref.dtype
+
+    def to_row(x_i, eye):  # what is constant along a head's lanes, as a row
+        return jnp.sum(jnp.where(eye, x_i, 0.0), axis=0, keepdims=True)
+
+    def one_chunk(c):
+        q, k = q_ref[c, 0, 0], k_ref[c, 0, 0]
+        s = _chunk_system(q, k, gamma_ref[0, 0, pl.ds(c, 1), :], beta_ref[0, 0, pl.ds(c, 1), :], chunk, group)
+        solved, decay, eye = s["solved"], s["decay"], s["eye"]
+        dsolved, dinside = _heads(dsolved_ref, c, chunk, group), _heads(dinside_ref, c, chunk, group)
+        # T beta: beta scales T's columns.
+        dbeta = jnp.sum(dsolved * solved, axis=0, keepdims=True)
+        # The inverse's own derivative, dA = -T^T dT T^T under the strict mask.
+        blocks = _diagonal_blocks(solved, chunk, group)
+        turned = blocks.T  # T_h^T at block (h, h)
+        right = jnp.dot(dsolved * s["beta_j"], turned, precision=_HIGHEST, preferred_element_type=jnp.float32)
+        turned_wide = sum(turned[h * chunk:(h + 1) * chunk] for h in range(group))
+        dsystem = -jnp.dot(
+            turned_wide, _diagonal_blocks(right, chunk, group), precision=_HIGHEST, preferred_element_type=jnp.float32
+        )
+        dsystem = jnp.where(s["row"] > s["col"], dsystem, 0.0)
+        by_beta = dsystem * s["kk"] * decay  # d system / d beta_i, entry by entry
+        dbeta = dbeta + to_row(_over_lanes(by_beta, chunk, group), eye)
+        # gamma enters through the decay alone: d decay x decay, row sums less column sums.
+        through = s["beta_i"] * by_beta + dinside * s["qk"] * decay
+        dgamma = to_row(_over_lanes(through, chunk, group), eye) - jnp.sum(through, axis=0, keepdims=True)
+        dgamma_ref[0, 0, pl.ds(c, 1), :] = dgamma
+        dbeta_ref[0, 0, pl.ds(c, 1), :] = dbeta
+        # k k^T and q k^T once a key head: their cotangents meet the value
+        # heads' sum inside the products (the contraction runs over group C).
+        dkk = (dsystem * s["beta_i"] * decay).astype(dtype)
+        dqk = (dinside * decay).astype(dtype)
+        straight = jnp.dot(jnp.concatenate([dqk, dkk], axis=0), s["stacked"], preferred_element_type=jnp.float32)
+        across = jax.lax.dot_general(  # [dkk_h^T k + dqk_h^T q] a head, stacked
+            jnp.concatenate([dkk, dqk], axis=0), jnp.concatenate([k, q], axis=0),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        dq_ref[c, 0, 0] = straight[:chunk].astype(dq_ref.dtype)
+        dk = straight[chunk:] + sum(across[h * chunk:(h + 1) * chunk] for h in range(group))
+        dk_ref[c, 0, 0] = dk.astype(dk_ref.dtype)
+
+    _for_each_chunk(q_ref.shape[0], one_chunk)
+
+
+def _wide(x: jax.Array, group: int) -> jax.Array:
+    """``[N, B, H, C]`` -> ``[B, H_k, N, group C]``: a key head's value heads
+    side by side, the chunks down the rows of a block."""
+    chunks, batch, heads, chunk = x.shape
+    return jnp.transpose(x.reshape(chunks, batch, heads // group, group * chunk), (1, 2, 0, 3))
+
+
+def _narrow(x: jax.Array, group: int) -> jax.Array:
+    """:func:`_wide`'s inverse."""
+    batch, key_heads, chunks, width = x.shape
+    return jnp.transpose(x, (2, 0, 1, 3)).reshape(chunks, batch, key_heads * group, width // group)
+
+
+def _specs(q, group: int, tile: int):
+    chunks, batch, key_heads, chunk, dk = q.shape
+    rows = pl.BlockSpec((tile, 1, 1, chunk, dk), lambda b, j, n: (n, b, j, 0, 0))
+    vector = pl.BlockSpec((1, 1, tile, group * chunk), lambda b, j, n: (b, j, n, 0))
+    square = pl.BlockSpec((tile, 1, group, chunk, chunk), lambda b, j, n: (n, b, j, 0, 0))
+    return (batch, key_heads, chunks // tile), rows, vector, square
+
+
+@functools.partial(jax.jit, static_argnames=("group", "tile", "interpret"))
+def _prepare_forward(q, k, gamma, beta, group: int, tile: int, interpret: bool):
+    """Jitted, as :func:`_prepare_backward` is: a model's layers of one shape
+    share one trace and one lowering of the kernel (a second and a half a
+    layer on a host, at every start)."""
+    chunks, batch, key_heads, chunk, _ = q.shape
+    grid, rows, vector, square = _specs(q, group, tile)
+    out = jax.ShapeDtypeStruct((chunks, batch, key_heads * group, chunk, chunk), q.dtype)
+    return pl.pallas_call(
+        functools.partial(_prepare_fwd_kernel, chunk=chunk, group=group),
+        grid=grid,
+        in_specs=[rows, rows, vector, vector],
+        out_specs=[square, square],
+        out_shape=[out, out],
+        interpret=interpret,
+    )(q, k, _wide(gamma, group), _wide(beta, group))
+
+
+@functools.partial(jax.jit, static_argnames=("group", "tile", "interpret"))
+def _prepare_backward(q, k, gamma, beta, dsolved, dinside, group: int, tile: int, interpret: bool):
+    grid, rows, vector, square = _specs(q, group, tile)
+    wide = _wide(gamma, group)
+    dq, dk, dgamma, dbeta = pl.pallas_call(
+        functools.partial(_prepare_bwd_kernel, chunk=q.shape[3], group=group),
+        grid=grid,
+        in_specs=[rows, rows, vector, vector, square, square],
+        out_specs=[rows, rows, vector, vector],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(wide.shape, jnp.float32), jax.ShapeDtypeStruct(wide.shape, jnp.float32),
+        ],
+        interpret=interpret,
+    )(q, k, wide, _wide(beta, group), dsolved, dinside)
+    return dq, dk, _narrow(dgamma, group), _narrow(dbeta, group)
+
+
+def _interpreted(interpret: Optional[bool]) -> bool:
+    return _backend.default_interpret() if interpret is None else interpret
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _prepare_in_vmem(q, k, g, beta, group: int, tile: int = CHUNK_TILE, interpret: Optional[bool] = None):
+    """:func:`_prepare` as the two kernels above: same operands, same three
+    results, nothing ``C x C`` in float32 in HBM in either direction. The
+    running sum ``gamma`` (2 MB a layer) is XLA's on both sides of the call;
+    the backward kernel builds the system, its inverse and the decay again
+    from the four operands, which are all the forward keeps."""
+    gamma = jnp.cumsum(g, axis=-1)
+    solved, inside = _prepare_forward(q, k, gamma, beta, group, tile, _interpreted(interpret))
+    return solved, inside, gamma
+
+
+def _prepare_in_vmem_fwd(q, k, g, beta, group, tile, interpret):
+    return _prepare_in_vmem(q, k, g, beta, group, tile, interpret), (q, k, g, beta)
+
+
+def _prepare_in_vmem_bwd(group, tile, interpret, residuals, cotangents):
+    q, k, g, beta = residuals
+    dsolved, dinside, dgamma_out = cotangents
+    dq, dk, dgamma, dbeta = _prepare_backward(
+        q, k, jnp.cumsum(g, axis=-1), beta, dsolved, dinside, group, tile, _interpreted(interpret)
+    )
+    dgamma = dgamma + dgamma_out
+    dg = jnp.flip(jnp.cumsum(jnp.flip(dgamma, -1), axis=-1), -1)  # the running sum, transposed
+    return dq, dk, dg, dbeta
+
+
+_prepare_in_vmem.defvjp(_prepare_in_vmem_fwd, _prepare_in_vmem_bwd)
+
+
+def rule_form(chunks: int, chunk: int, key_dim: int, group: int, *, on_tpu: Optional[bool] = None) -> dict:
+    """Which program computes the part that does not read the state, from
+    what the code can observe: ``{"rule": "kernel", "chunk_tile": n}`` on a
+    TPU where Mosaic takes the shapes, else ``{"rule": "xla", "refused":
+    why}``. The kernels want a chunk of whole bfloat16 tiles (16 rows) that is a
+    power of two (the inverse doubles its blocks from 8 rows up), a key
+    head of whole lane tiles, the wide arrays (``group x chunk`` lanes) whole
+    lane tiles and no wider than ``_MAX_WIDTH``, and the chunks in tiles of
+    ``CHUNK_TILE`` (or all of them in one)."""
+    if on_tpu is None:
+        on_tpu = _attention._on_tpu()
+    width = group * chunk
+    if not on_tpu:
+        refused = "non-TPU backend"
+    elif chunk % 16 or chunk & (chunk - 1):
+        refused = f"chunk {chunk} is not a power of two of whole 16-row tiles"
+    elif key_dim % 128:
+        refused = f"key head {key_dim} is not whole lane tiles"
+    elif width % 128 or width > _MAX_WIDTH:
+        refused = f"{group} value heads a key head x chunk {chunk} = {width} lanes"
+    elif chunks % CHUNK_TILE and chunks > CHUNK_TILE:
+        refused = f"{chunks} chunks are not whole tiles of {CHUNK_TILE}"
+    else:
+        return {"rule": "kernel", "chunk_tile": min(CHUNK_TILE, chunks)}
+    return {"rule": "xla", "refused": refused}
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     """The chunked gated delta rule.
 
@@ -160,13 +516,32 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     Returns:
       ``(o [B, L, H, d_v]`` in ``v``'s dtype, the final state ``[B, H, d_k,
       d_v]`` float32``)``.
+
+    Which program computes the part that does not read the state is
+    :func:`rule_form`'s to say, from the backend and the shapes; the choice
+    is one record of the dispatch log (``ops/attention.py``).
     """
     batch, length, key_heads, dk = q.shape
-    heads, dv = v.shape[2:]
+    heads = v.shape[2]
     if heads % key_heads or k.shape != q.shape or g.shape != v.shape[:3] or beta.shape != g.shape:
         raise ValueError(
             f"gated delta rule: q {q.shape}, k {k.shape}, v {v.shape}, g {g.shape}, beta {beta.shape}"
         )
+    form = rule_form(-(-length // chunk), chunk, dk, heads // key_heads)
+    _attention.log_rule_form((batch, length, key_heads, dk), heads, chunk, jnp.dtype(v.dtype).name, form)
+    if form["rule"] == "kernel":
+        prepare = functools.partial(_prepare_in_vmem, tile=form["chunk_tile"])
+    else:
+        prepare = _prepare
+    return _chunked(prepare, q, k, v, g, beta, chunk)
+
+
+def _chunked(prepare, q, k, v, g, beta, chunk: int):
+    """:func:`gated_delta_rule` with the state-free part computed by
+    ``prepare(q, k, g, beta, group)`` on ``[N, B, H, C, ...]`` operands:
+    :func:`_prepare` or :func:`_prepare_in_vmem`."""
+    batch, length, key_heads, dk = q.shape
+    heads, dv = v.shape[2:]
     group, dtype = heads // key_heads, v.dtype
     pad = -length % chunk
     if pad:
@@ -178,7 +553,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     g = _by_chunk(g.astype(jnp.float32), chunks, chunk)  # [N, B, H, C]
     beta = _by_chunk(beta.astype(jnp.float32), chunks, chunk)
 
-    solved, inside, gamma = _prepare(q, k, g, beta, group)
+    solved, inside, gamma = prepare(q, k, g, beta, group)
     # Tagged for a caller's remat policy: with these kept (134 MB a layer at 4
     # x 4,096 tokens) its recomputation of the layer runs the scan alone.
     solved, inside = (checkpoint_name(x, "gdn_solved") for x in (solved, inside))

@@ -7,7 +7,12 @@ chunk against one rank-one update a token), so ``TIGHT`` = 2e-5 of the
 compared tensor's largest entry; a gradient with respect to ``g`` under decays
 near 0 is itself near 0 everywhere and is held to 1e-3 of its largest entry
 (it reads 1.5e-4: a sum of terms of both signs, each exp(-20) of an
-activation)."""
+activation).
+
+The kernels of the state-free part run here in the Pallas interpreter, called
+past the rule that picks the path (``rule_form`` says ``xla`` on a CPU and for
+these toy heads), and are held to the same tolerances, against the recurrence
+and against XLA's form."""
 
 import functools
 
@@ -23,10 +28,17 @@ from sav_tpu.models.layers.gated_delta import (
     l2_normalise,
     split_by_key_head,
 )
+from sav_tpu.ops import attention
 from sav_tpu.ops.gated_delta import (
+    _by_chunk,
+    _chunked,
+    _prepare,
+    _prepare_in_vmem,
     _unit_lower_inverse,
+    _wide_inverse,
     gated_delta_rule,
     gated_delta_rule_recurrent,
+    rule_form,
 )
 
 TIGHT = 2e-5
@@ -35,6 +47,13 @@ TIGHT = 2e-5
 chunked = jax.jit(gated_delta_rule, static_argnames="chunk")
 recurrent = jax.jit(gated_delta_rule_recurrent)
 DECAYS = {"near_0": (-20.0, -5.0), "near_1": (-1e-3, -1e-5), "mixed": (-3.0, -0.01)}
+
+
+def in_vmem(tile):
+    """The chunked rule with its state-free part in the kernels, interpreted,
+    ``tile`` chunks a grid step."""
+    prepare = functools.partial(_prepare_in_vmem, tile=tile, interpret=True)
+    return jax.jit(functools.partial(_chunked, prepare), static_argnames="chunk")
 
 
 def close(got, want, tol=TIGHT):
@@ -113,6 +132,159 @@ def test_bfloat16_operands_stay_near_the_float32_rule():
     want, want_state = recurrent(*args)
     assert out.dtype == jnp.bfloat16 and state.dtype == jnp.float32
     assert close(out.astype(jnp.float32), want, 3e-2) and close(state, want_state, 3e-2)
+
+
+# (length, chunk, chunks a grid step): a padded last chunk in each; the second
+# takes two grid steps along the chunks.
+KERNEL_LENGTHS = [(150, 64, 3), (90, 16, 3)]
+
+
+@pytest.mark.parametrize("decay", sorted(DECAYS))
+@pytest.mark.parametrize("length,chunk,tile", KERNEL_LENGTHS)
+@pytest.mark.parametrize("key_heads,heads", [(2, 4), (3, 3)])
+def test_the_kernels_rule_is_the_recurrence_and_xlas_form(length, chunk, tile, key_heads, heads, decay):
+    args = operands(length, key_heads, heads, decay)
+    out, state = in_vmem(tile)(*args, chunk=chunk)
+    want, want_state = recurrent(*args)
+    assert out.shape == want.shape and close(out, want) and close(state, want_state)
+    xla, xla_state = chunked(*args, chunk=chunk)
+    assert close(out, xla, 2e-6) and close(state, xla_state, 2e-6)
+
+
+@pytest.mark.parametrize(
+    "length,chunk,tile,key_heads,heads,decay",
+    [(150, 64, 3, 2, 4, decay) for decay in sorted(DECAYS)] + [(37, 16, 3, 2, 4, decay) for decay in sorted(DECAYS)]
+    + [(37, 16, 1, 3, 3, "mixed"), (37, 16, 1, 3, 3, "near_1")],
+)
+def test_the_kernels_gradients_are_the_recurrences(length, chunk, tile, key_heads, heads, decay):
+    """All five, through the backward kernel (dq, dk, dg, dbeta) and the scan's
+    transpose (dv, and the cotangents the kernel is handed). With one value
+    head a key head and decays near 0 the gradient with respect to ``g`` is
+    1e-3 of an activation and either program reads 2e-3 to 3e-3 of it off the
+    recurrence: not a case."""
+    args = operands(length, key_heads, heads, decay)
+
+    def scalar(rule):
+        def f(*a):
+            out, state = rule(*a)
+            return jnp.sum(jnp.sin(out)) + jnp.sum(jnp.square(state))
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4)))
+
+    got = scalar(functools.partial(in_vmem(tile), chunk=chunk))(*args)
+    want = scalar(gated_delta_rule_recurrent)(*args)
+    xla = scalar(functools.partial(gated_delta_rule, chunk=chunk))(*args)
+    for name, a, b, c in zip("q k v g beta".split(), got, want, xla):
+        # Under decays near 0 each program is within 1e-3 of the recurrence (they
+        # read 5e-4 to 9e-4), so the two are within 2e-3 of each other.
+        near, apart = (1e-3, 2e-3) if (name, decay) == ("g", "near_0") else (TIGHT, TIGHT)
+        assert close(a, b, near) and close(a, c, apart), name
+
+
+def test_the_kernels_results_are_xlas_on_bfloat16_operands():
+    """The state-free part alone, where the two programs round alike: ``T
+    beta`` and the masked ``Q K^T`` to a bfloat16 unit in the last place, the
+    four gradients to the rounding of the bfloat16 cotangents' products."""
+    q, k, _, g, beta = operands(128, 2, 4, "mixed", dtype=jnp.bfloat16)
+    q, k = (_by_chunk(x, 2, 64) for x in (q, k))
+    g, beta = (_by_chunk(x, 2, 64) for x in (g, beta))
+    weights = jax.random.normal(jax.random.PRNGKey(7), (2, 2, 2, 4, 64, 64))
+
+    def scalar(prepare):
+        def f(*a):
+            solved, inside, gamma = prepare(*a, 2)
+            assert solved.dtype == inside.dtype == jnp.bfloat16 and gamma.dtype == jnp.float32
+            return jnp.sum(weights[0] * solved) + jnp.sum(weights[1] * inside) + jnp.sum(jnp.sin(gamma)), (solved, inside)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3), has_aux=True))
+
+    (_, got), got_grads = scalar(functools.partial(_prepare_in_vmem, tile=2, interpret=True))(q, k, g, beta)
+    (_, want), want_grads = scalar(_prepare)(q, k, g, beta)
+    for a, b in zip(got, want):
+        assert close(a.astype(jnp.float32), b.astype(jnp.float32), 2 ** -7)
+    for a, b in zip(got_grads, want_grads):
+        assert a.dtype == b.dtype and close(a.astype(jnp.float32), b.astype(jnp.float32), 2e-2)
+
+
+def test_bfloat16_operands_through_the_kernels_stay_near_the_float32_rule():
+    args = operands(150, 2, 4, "mixed", dtype=jnp.bfloat16)
+    out, state = in_vmem(3)(*args, chunk=64)
+    want, want_state = recurrent(*args)
+    assert out.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+    assert close(out.astype(jnp.float32), want, 3e-2) and close(state, want_state, 3e-2)
+
+
+@pytest.mark.parametrize("n,group", [(16, 1), (16, 2), (64, 2), (32, 4)])
+def test_the_kernels_inverse_is_exact_where_the_series_is_not(n, group):
+    """The same systems as above, a key head's ``group`` side by side as the
+    kernels hold them."""
+    lower = jnp.tril(0.9 + 0.1 * jax.random.uniform(jax.random.PRNGKey(n), (group, n, n)), -1)
+    wide = jnp.concatenate(list(lower), axis=1)
+    got = np.asarray(jax.jit(functools.partial(_wide_inverse, chunk=n, group=group))(wide), np.float64)
+    for h in range(group):
+        system = np.eye(n) + np.asarray(lower[h], np.float64)
+        mine = got[:, h * n:(h + 1) * n]
+        assert np.max(np.abs(mine @ system - np.eye(n))) < 1e-4
+        assert np.allclose(mine, np.linalg.inv(system), atol=1e-4 * np.max(np.abs(np.linalg.inv(system))))
+        assert np.array_equal(np.triu(mine, 1), np.zeros_like(mine))
+
+
+def test_an_ill_conditioned_chunk_goes_through_the_forward_kernel():
+    """Keys that all but coincide, ``beta`` 1 and no decay: the system's
+    entries are ``k_i . k_j`` in 0.9 to 1, and ``T beta`` is its inverse."""
+    chunk, dk = 64, 32
+    k = l2_normalise(1.0 + 0.25 * jax.random.normal(jax.random.PRNGKey(3), (1, 1, 1, chunk, dk)))
+    g, beta = jnp.zeros((1, 1, 2, chunk)), jnp.ones((1, 1, 2, chunk))
+    solved, inside, gamma = _prepare_in_vmem(k, k, g, beta, 2, tile=1, interpret=True)
+    pairs = np.asarray(jnp.einsum("id,jd->ij", k[0, 0, 0], k[0, 0, 0]), np.float64)
+    assert np.min(np.tril(pairs, -1) + np.triu(np.ones_like(pairs))) > 0.85
+    want = np.linalg.inv(np.eye(chunk) + np.tril(pairs, -1))
+    assert np.abs(np.linalg.matrix_power(np.tril(pairs, -1), 32)).max() > 1e12  # a term of the series
+    for h in range(2):
+        assert np.allclose(np.asarray(solved[0, 0, h], np.float64), want, atol=1e-4 * np.abs(want).max())
+        assert np.allclose(np.asarray(inside[0, 0, h]), np.tril(pairs), atol=1e-5)
+    assert not np.any(np.asarray(gamma))
+
+
+RULE_FORMS = [
+    # (chunks, chunk, d_k, value heads a key head, on a TPU) -> the form
+    ((64, 64, 128, 2, True), {"rule": "kernel", "chunk_tile": 8}),  # the hybrid decoder's cell
+    ((3, 64, 128, 2, True), {"rule": "kernel", "chunk_tile": 3}),  # one tile holds every chunk
+    ((16, 128, 256, 1, True), {"rule": "kernel", "chunk_tile": 8}),
+    ((64, 64, 128, 2, False), {"rule": "xla", "refused": "non-TPU backend"}),
+    ((64, 64, 128, 1, True), {"rule": "xla", "refused": "1 value heads a key head x chunk 64 = 64 lanes"}),
+    ((64, 64, 128, 16, True), {"rule": "xla", "refused": "16 value heads a key head x chunk 64 = 1024 lanes"}),
+    ((64, 64, 64, 2, True), {"rule": "xla", "refused": "key head 64 is not whole lane tiles"}),
+    ((64, 24, 128, 16, True), {"rule": "xla", "refused": "chunk 24 is not a power of two of whole 16-row tiles"}),
+    ((64, 48, 128, 8, True), {"rule": "xla", "refused": "chunk 48 is not a power of two of whole 16-row tiles"}),
+    ((12, 64, 128, 2, True), {"rule": "xla", "refused": "12 chunks are not whole tiles of 8"}),
+]
+
+
+@pytest.mark.parametrize("shape,form", RULE_FORMS, ids=[str(shape) for shape, _ in RULE_FORMS])
+def test_the_rule_picks_its_program_from_the_backend_and_the_shapes(shape, form):
+    *sizes, on_tpu = shape
+    assert rule_form(*sizes, on_tpu=on_tpu) == form
+
+
+def test_the_dispatch_log_records_the_rules_form(monkeypatch):
+    """One record a traced shape, beside the attention's: ``kernel`` with its
+    tile where a TPU would run it (nothing runs here: the trace alone writes
+    the record), ``xla`` with what refused on this backend."""
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((1, 192, 2, 128), jnp.bfloat16), ((1, 192, 2, 128), jnp.bfloat16), ((1, 192, 4, 128), jnp.bfloat16),
+        ((1, 192, 4), jnp.float32), ((1, 192, 4), jnp.float32),
+    )]
+    attention.clear_dispatch_log()
+    jax.eval_shape(lambda *a: gated_delta_rule(*a), *shapes)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    out, state = jax.eval_shape(lambda *a: gated_delta_rule(*a), *shapes)
+    assert out.shape == (1, 192, 4, 128) and state.shape == (1, 4, 128, 128)
+    log = attention.snapshot_dispatch_log()
+    attention.clear_dispatch_log()
+    common = {"op": "gated_delta_rule", "shape": [1, 192, 2, 128], "value_heads": 4, "chunk": 64, "dtype": "bfloat16"}
+    assert log == [
+        {**common, "rule": "xla", "refused": "non-TPU backend"},
+        {**common, "rule": "kernel", "chunk_tile": 3},
+    ]
 
 
 def test_mismatched_heads_are_refused():

@@ -16,6 +16,7 @@ persistent compile cache off (a described-device entry cannot be read
 back without a chip and would only produce warnings).
 """
 
+import functools
 import os
 import re
 
@@ -172,6 +173,10 @@ KERNEL_CASES = [
     # hybrid decoder's full-attention layer), in place, the group's head
     # found through the block index. The fifth number is the key/value heads.
     ("flash_grouped-qwen3_next_4k", "flash_grouped", (4, 4096, 16, 256, 2)),
+    # The gated delta rule at the hybrid decoder's cell: q and k [B, L, H_k,
+    # d_k], the fifth number the value heads; chunks of 64, the state-free
+    # part in its two kernels (one a direction), eight chunks a grid step.
+    ("gated_delta-qwen3_next_4k", "gated_delta", (4, 4096, 16, 128, 32)),
 ]
 
 
@@ -222,6 +227,16 @@ def _kernel_fn_and_args(kernel, shape, sharding):
         return (
             lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False, **blocks)
         ), (qkv[0], few, few)
+    if kernel == "gated_delta":
+        from sav_tpu.ops import gated_delta
+
+        form = gated_delta.rule_form(shape[1] // gated_delta.CHUNK, gated_delta.CHUNK, dim, shape[4] // heads, on_tpu=True)
+        assert form == {"rule": "kernel", "chunk_tile": 8}
+        prepare = functools.partial(gated_delta._prepare_in_vmem, tile=form["chunk_tile"], interpret=False)
+        gates = spec(shape[:2] + (shape[4],), jnp.float32)
+        return (
+            lambda q, k, v, g, beta: gated_delta._chunked(prepare, q, k, v, g, beta, gated_delta.CHUNK)[0]
+        ), (qkv[0], qkv[1], spec(shape[:2] + (shape[4], dim)), gates, gates)
     if kernel == "fused":
         return (lambda q, k, v: fused_attention(q, k, v, interpret=False)), qkv
     if kernel == "talking_heads":

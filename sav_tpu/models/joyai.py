@@ -1,10 +1,12 @@
 """The decoder of the expert families: a pre-norm stack of token mixers
-(latent attention; or gated delta-rule blocks with a gated grouped-query
-attention block every few layers), routed and shared experts and
-multi-token-prediction modules, whose residual path is plain or a set of
-hyper-connected streams.
+(latent attention; gated delta-rule blocks with a gated grouped-query
+attention block every few layers; or double-gated short convolutions with a
+grouped-query attention block between them), routed experts with a shared
+expert or without, and multi-token-prediction modules, whose residual path is
+plain or a set of hyper-connected streams, whose head is its own matrix or
+the embedding's table.
 
-Three registry entries build it. ``joyai_llm_flash``: sizes of
+Four registry entries build it. ``joyai_llm_flash``: sizes of
 ``https://huggingface.co/jdopensource/JoyAI-LLM-Flash/blob/main/config.json``,
 the layer equations of the family its config names (arXiv:2412.19437 sections
 2.1, 2.2 and 4.2)::
@@ -25,8 +27,10 @@ is ``h + F(RMSNorm(h))``, the same program as before the streams existed.
 
 ``qwen3_next_80b_a3b``: sizes of ``https://huggingface.co/Qwen/
 Qwen3-Next-80B-A3B-Instruct/blob/main/config.json``. Its token mixers come
-from a per-layer list of kinds (``mixers``; :func:`hybrid_mixers` builds it
-from the config's ``full_attention_interval``): ``gated_delta`` is
+from a per-layer list of kinds (``mixers``, one a layer, of which a depth cut
+runs the first ``num_layers``; this entry gives the config's
+``full_attention_interval`` and :func:`hybrid_mixers` builds the list from
+it): ``gated_delta`` is
 :class:`~sav_tpu.models.layers.gated_delta.GatedDeltaNetBlock`, a recurrence
 over the sequence, ``gated_attention`` is :class:`~sav_tpu.models.layers.
 gated_attention.GatedSelfAttentionBlock`; its norms store their weight as the
@@ -34,6 +38,19 @@ offset from 1 (``norm_offset``), its router scores by a softmax over all
 experts without a selection bias (``scoring``, ``bias_update_rate`` 0), its
 shared expert sits behind a sigmoid gate (``shared_gate``), every layer is an
 expert layer and there is no MTP module.
+
+``lfm2_24b_a2b``: sizes of ``https://huggingface.co/LiquidAI/LFM2-24B-A2B/
+blob/main/config.json``. ``mixers`` is the config's own ``layer_types``, which
+no interval gives: ``conv`` is :class:`~sav_tpu.models.layers.short_conv.
+ShortConvBlock` (two gates around a causal depthwise convolution of width 3),
+``full_attention`` the grouped-query block at this family's sizes (no output
+gate, rotary on the whole head, plain norm weights). Two leading dense
+layers, sigmoid-routed experts with a selection bias and NO shared expert
+(``shared_expert`` False: the layer's result is the routed sum alone), the
+selected scores divided by their sum plus 1e-6 (``router_weight_eps``), no
+MTP module, and a tied head (``tie_head``: ``logits = RMSNorm_f(h) E^T``;
+the tree has no ``lm_head`` and the table's gradient is the sum of both
+uses).
 
 ``Attn`` is :class:`~sav_tpu.models.layers.LatentSelfAttentionBlock` unless
 ``mixers`` says otherwise. ``FFN``
@@ -61,15 +78,16 @@ of an expert-parallel deployment (see ``SparseMoEBlock``); nothing here
 stands in for the chips that hold the rest.
 
 Scopes, for the readers of a trace: layers ``layer_<i>``; in a layer the
-token mixer is ``LatentSelfAttentionBlock_0``, ``GatedSelfAttentionBlock_0``
-or ``GatedDeltaNetBlock_0`` (``to_qkv``, ``to_out`` in each; the last also
-``gdn/conv``, ``gdn/rule``, ``gdn/gate_norm``),
+token mixer is ``LatentSelfAttentionBlock_0``, ``GatedSelfAttentionBlock_0``,
+``GatedDeltaNetBlock_0`` or ``ShortConvBlock_0`` (``to_qkv``, ``to_out`` in
+each; the third also ``gdn/conv``, ``gdn/rule``, ``gdn/gate_norm``, the last
+``sconv/core``),
 the dense MLP ``GatedFFBlock_0`` (``fc1``, ``fc2``), the expert layer
 ``moe`` (``route``, ``dispatch``, ``experts/fc1|fc2``, ``combine``,
-``shared/fc1|fc2``); a hyper-connection's maps under ``hc_attn`` and
+``shared/fc1|fc2`` where it has a shared expert); a hyper-connection's maps under ``hc_attn`` and
 ``hc_ffn`` (``hc/pre``, ``hc/sinkhorn``) and its merge under the layer
 (``hc/post``); the module ``mtp`` (its head and loss under ``mtp/lm_head``);
-the head ``lm_head``.
+the head ``lm_head`` (tied or not).
 """
 
 from __future__ import annotations
@@ -89,6 +107,7 @@ from sav_tpu.models.layers import (
 from sav_tpu.models.layers.gated_attention import GatedSelfAttentionBlock
 from sav_tpu.models.layers.gated_delta import GatedDeltaNetBlock
 from sav_tpu.models.layers.hyper_connection import HyperConnection, fan_in, fan_out
+from sav_tpu.models.layers.short_conv import ShortConvBlock
 from sav_tpu.models.layers.moe import rows_over_bound
 from sav_tpu.models.ouro import LMHead
 
@@ -135,11 +154,37 @@ KEPT_UNDER_REMAT_BESIDE_STREAMS = tuple(name for name in KEPT_UNDER_REMAT if nam
 # tag.
 KEPT_UNDER_REMAT_BESIDE_RECURRENCE = KEPT_UNDER_REMAT + ("gdn_solved", "gdn_conv", "gdn_out")
 
+# The convolution-attention hybrid's choice: the names above (``mla_latent``
+# and ``hc_maps`` tag nothing here) and the short-convolution block's two, a
+# layer at 4 x 8,192 tokens: ``sconv_in`` (the input projection's ``[B | C |
+# x~]``, 403 MB: spares the backward pass the norm and the block's largest
+# matmul, 2048 x 6144) and ``sconv_core`` (``C * c``, 134 MB: spares it the
+# core's forward, a pass over four arrays). Chosen on a v5e at the published
+# widths, 4 x 8,192 tokens and 8 of 64 experts held (seconds a step, the
+# compiled step's bytes; my chip run, PR 39, calls 1 and 2): **both 0.5314 in
+# 14.59 GB**, ``sconv_in`` alone 0.5326 in 14.78 GB (more, not less: XLA
+# schedules the recomputed core where the step's memory peaks), ``sconv_core``
+# alone 0.5498 in 14.71 GB, neither 0.5502 in 14.35 GB: the projection's
+# result is worth 18 ms a step, the core's 1.
+KEPT_UNDER_REMAT_BESIDE_CONVOLUTION = KEPT_UNDER_REMAT + ("sconv_in", "sconv_core")
+
 
 # How a step's per-layer ``stats`` become one number: by key.
 STAT_REDUCTIONS = {
     "hc_doubly_stochastic_err": jnp.max, "hc_stream_gain": jnp.max,
     "gdn_decay_min": jnp.min, "gdn_state_rms_max": jnp.max, "attn_gate_mean": jnp.mean,
+    "sconv_out_rms_max": jnp.max,
+}
+
+# A layer's token mixer by the kind a ``mixers`` list gives it: the block,
+# the prefix its ``stats`` take, and the field of :class:`JoyAILM` that holds
+# its sizes. ``full_attention`` is the public configs' name for a softmax
+# layer; both names build the grouped-query block.
+MIXER_BLOCKS = {
+    "gated_delta": (GatedDeltaNetBlock, "gdn_", "gated_delta"),
+    "gated_attention": (GatedSelfAttentionBlock, "attn_", "gated_attention"),
+    "full_attention": (GatedSelfAttentionBlock, "attn_", "gated_attention"),
+    "conv": (ShortConvBlock, "sconv_", "short_conv"),
 }
 
 
@@ -156,14 +201,15 @@ def hybrid_mixers(num_layers: int, full_attention_interval: int) -> tuple:
 class LatentDecoderBlock(nn.Module):
     """One pre-norm layer; ``num_experts`` 0 makes its FFN the dense SwiGLU.
     ``mixer`` is the token mixer's kind (``latent``: latent attention at the
-    sizes of the fields below; ``gated_attention`` or ``gated_delta``: that
-    block at ``mixer_sizes``). ``hc`` holds :class:`HyperConnection`'s sizes
+    sizes of the fields below; a key of :data:`MIXER_BLOCKS`: that block at
+    ``mixer_sizes``). ``hc`` holds :class:`HyperConnection`'s sizes
     (``streams`` 1: the state is one array and a sublayer is ``h +
     F(RMSNorm(h))``). Returns ``(state, counts, balance, stats)``: the two in
     the middle ``None`` for a dense layer; ``stats`` a dict of float32 scalars
     under the keys of :data:`STAT_REDUCTIONS` (the hyper-connections' two, the
     larger of the two sublayers'; the delta-rule block's two; the gated
-    attention's one), ``None`` where the layer has none."""
+    attention's one; the short convolution's one), ``None`` or empty where
+    the layer has none."""
 
     mlp_ch: int
     num_experts: int
@@ -183,7 +229,9 @@ class LatentDecoderBlock(nn.Module):
     mixer_sizes: Optional[Any] = None  # the gated blocks' sizes as a dict
     norm_offset: bool = False  # the norms' weights are offsets from 1
     scoring: str = "sigmoid"
+    shared_expert: bool = True
     shared_gate: bool = False
+    router_weight_eps: float = 0.0
     hc: Optional[Any] = None  # HyperConnection's sizes as a dict; None = one stream
     backend: Optional[str] = None
     logits_dtype: Optional[Dtype] = None
@@ -202,14 +250,17 @@ class LatentDecoderBlock(nn.Module):
             """``(the token mixer's result, its stats or None)``."""
             shared = dict(norm_eps=self.norm_eps, quant=self.quant, dtype=self.dtype)
             attention = dict(rope_theta=self.rope_theta, backend=self.backend, logits_dtype=self.logits_dtype)
-            if self.mixer == "gated_delta":
-                out, stats = GatedDeltaNetBlock(**self.mixer_sizes, **shared)(x)
-                return out, {"gdn_" + k: v for k, v in stats.items()}
-            if self.mixer == "gated_attention":
-                out, stats = GatedSelfAttentionBlock(**self.mixer_sizes, **attention, **shared)(x)
-                return out, {"attn_" + k: v for k, v in stats.items()}
+            if self.mixer in MIXER_BLOCKS:
+                block, prefix, _ = MIXER_BLOCKS[self.mixer]
+                options = dict(shared)
+                if block is GatedSelfAttentionBlock:
+                    options.update(attention, norm_offset=self.norm_offset)
+                if block is ShortConvBlock:
+                    del options["norm_eps"]  # no norm inside
+                out, stats = block(**self.mixer_sizes, **options)(x)
+                return out, {prefix + k: v for k, v in stats.items()}
             if self.mixer != "latent":
-                raise ValueError(f"token mixer {self.mixer!r}: latent, gated_attention or gated_delta")
+                raise ValueError(f"token mixer {self.mixer!r}: latent or one of {sorted(MIXER_BLOCKS)}")
             return LatentSelfAttentionBlock(
                 num_heads=self.num_heads,
                 q_rank=self.q_rank,
@@ -237,7 +288,9 @@ class LatentDecoderBlock(nn.Module):
                 routed_scale=self.routed_scale,
                 experts_held=self.experts_held,
                 scoring=self.scoring,
+                shared_expert=self.shared_expert,
                 shared_gate=self.shared_gate,
+                weight_eps=self.router_weight_eps,
                 quant=self.quant,
                 dtype=self.dtype,
                 name="moe",
@@ -267,7 +320,9 @@ class JoyAILM(nn.Module):
       any sublayer's :class:`HyperConnection` ``stats``); with delta-rule
       layers ``"gdn_decay_min"`` (the smallest ``exp(g_t)`` of the step) and
       ``"gdn_state_rms_max"`` (the largest RMS of any head's final state);
-      with gated attention layers ``"attn_gate_mean"``.
+      with gated attention layers ``"attn_gate_mean"``; with short-convolution
+      layers ``"sconv_out_rms_max"`` (the largest RMS of any block's and
+      sequence's ``C * c``).
     """
 
     num_classes: int  # the vocabulary held here
@@ -285,15 +340,22 @@ class JoyAILM(nn.Module):
     nope_ch: int = 0
     rope_ch: int = 0
     v_ch: int = 0
-    # A hybrid decoder's token mixers (the public config's
-    # full_attention_interval; 0 = latent attention in every layer) and the
-    # two gated blocks' sizes, as dicts of their constructors' arguments.
+    # A hybrid decoder's token mixers: a kind a layer (``latent`` or a key of
+    # MIXER_BLOCKS; a depth cut runs the first ``num_layers`` of them), or the
+    # public configs' full_attention_interval, from which hybrid_mixers builds
+    # the list; neither = latent attention in every layer. The blocks' sizes
+    # are dicts of their constructors' arguments.
+    mixers: Optional[tuple] = None
     full_attention_interval: int = 0
     gated_attention: Optional[Any] = None
     gated_delta: Optional[Any] = None
+    short_conv: Optional[Any] = None
     norm_offset: bool = False  # RMSNorm weights stored as offsets from 1
     scoring: str = "sigmoid"  # the router's: sigmoid | softmax
+    shared_expert: bool = True  # False: the expert layer is the routed sum alone
     shared_gate: bool = False  # the shared expert behind sigmoid(x w_s)
+    router_weight_eps: float = 0.0  # added to the selected scores' sum before the division
+    tie_head: bool = False  # the head reads the embedding's table
     first_dense: int = 1
     mtp_modules: int = 1  # the public configs' num_nextn_predict_layers: 0 or 1
     bias_update_rate: float = 1e-3
@@ -335,11 +397,15 @@ class JoyAILM(nn.Module):
                   "sinkhorn_eps": self.hc_eps, "res_clamp": tuple(self.hc_res_clamp)}
 
         mixers = ("latent",) * (self.num_layers + self.mtp_modules)
-        if self.full_attention_interval:
+        if self.mixers or self.full_attention_interval:
             if self.mtp_modules:
                 raise ValueError("a hybrid decoder has no multi-token-prediction module here")
-            mixers = hybrid_mixers(self.num_layers, self.full_attention_interval)
-        sizes_of = {"latent": None, "gated_attention": self.gated_attention, "gated_delta": self.gated_delta}
+            mixers = tuple(self.mixers or hybrid_mixers(self.num_layers, self.full_attention_interval))
+            if len(mixers) < self.num_layers:
+                raise ValueError(f"{len(mixers)} token mixers for {self.num_layers} layers")
+
+        def sizes_of(mixer: str):
+            return dict(getattr(self, MIXER_BLOCKS[mixer][2])) if mixer in MIXER_BLOCKS else None
 
         def block(name: str, routed: bool, mixer: str = "latent"):
             return block_cls(
@@ -350,10 +416,12 @@ class JoyAILM(nn.Module):
                 rope_ch=self.rope_ch,
                 v_ch=self.v_ch,
                 mixer=mixer,
-                mixer_sizes=sizes_of[mixer] and dict(sizes_of[mixer]),
+                mixer_sizes=sizes_of(mixer),
                 norm_offset=self.norm_offset,
                 scoring=self.scoring,
+                shared_expert=self.shared_expert,
                 shared_gate=self.shared_gate,
+                router_weight_eps=self.router_weight_eps,
                 mlp_ch=self.expert_ch if routed else self.mlp_ch,
                 num_experts=self.num_experts if routed else 0,
                 top_k=self.top_k,
@@ -392,7 +460,8 @@ class JoyAILM(nn.Module):
                 balances.append(b)
         h = fan_in(h)
         final_norm = RMSNorm(eps=self.norm_eps, offset=self.norm_offset, dtype=self.dtype, name="final_norm")
-        main = head(final_norm(h), targets)
+        table = embed.embedding if self.tie_head else None
+        main = head(final_norm(h), targets, table)
         if targets is None:
             return {"logits": main}
         out = {"ce": main}
@@ -409,7 +478,7 @@ class JoyAILM(nn.Module):
             with jax.named_scope("mtp"):
                 mtp_targets = jnp.concatenate([targets[:, 1:], jnp.zeros_like(targets[:, :1])], axis=1)
                 has_target = jnp.arange(targets.shape[1]) < targets.shape[1] - 1
-                out["ce_mtp"] = jnp.where(has_target[None, :], head(h_mtp, mtp_targets), 0.0)
+                out["ce_mtp"] = jnp.where(has_target[None, :], head(h_mtp, mtp_targets, table), 0.0)
 
         self.sow("losses", "moe_balance_loss", sum(balances))
         counts = jnp.stack(counts, axis=1)  # [B, R, E]

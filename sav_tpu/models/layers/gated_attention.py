@@ -1,17 +1,21 @@
-"""Gated grouped-query softmax attention, the hybrid decoders' full layer.
+"""Grouped-query softmax attention, gated or not: the hybrid decoders' full
+layer.
 
 ``H`` query heads of ``D`` read ``H_kv`` key/value heads (``H_kv`` divides
 ``H``; query head ``h`` reads key/value head ``h // (H / H_kv)``)::
 
     [query | gate] = x W_q          # by head: [query D | gate D]
     k = x W_k;  v = x W_v           # H_kv heads
-    query, k = RMSNorm_D(query), RMSNorm_D(k)       # per head, offset weights
+    query, k = RMSNorm_D(query), RMSNorm_D(k)       # per head, one weight each
     the first ``rotary_ch`` lanes of query and k rotate (lane i with i +
     rotary_ch / 2), the others pass
     out = softmax(query k^T D^-0.5, causal) v
     y = W_o (out sigmoid(gate))
 
-No bias anywhere. Scopes: the module's own name holds ``SelfAttentionBlock``;
+Its sizes tell the family's form: without ``gate`` ``W_q`` has no gate half
+and ``y = W_o out``; ``rotary_ch`` equal to ``D`` turns the whole head;
+``norm_offset`` says whether the two norms store their weight as the offset
+from 1 or plainly. No bias anywhere. Scopes: the module's own name holds ``SelfAttentionBlock``;
 the three input projections, the two norms and the rotary lie under
 ``to_qkv``, the output merge is ``to_out``, so the readers of a trace see it
 as any other attention block.
@@ -40,17 +44,21 @@ def rotate_leading_lanes(x: jax.Array, rotary_ch: int, theta: float) -> jax.Arra
     """Rotary on the first ``rotary_ch`` lanes of ``x [B, S, H, D]`` with the
     rotate-halves pairing inside them; the other lanes pass."""
     tables = half_split_tables(x.shape[1], rotary_ch, theta)
+    if rotary_ch == x.shape[-1]:
+        return apply_rotary_half(x, tables)
     return jnp.concatenate([apply_rotary_half(x[..., :rotary_ch], tables), x[..., rotary_ch:]], axis=-1)
 
 
 class _GatedQKVProj(nn.Module):
     """``x -> (query [B, S, H, D], k [B, S, H_kv, D], v the same, gate [B, S,
-    H, D])``, normed and rotated."""
+    H, D] or None)``, normed and rotated."""
 
     num_heads: int
     kv_heads: int
     head_ch: int
     rotary_ch: int
+    gate: bool
+    norm_offset: bool
     rope_theta: float
     norm_eps: float
     quant: Optional[str]
@@ -63,9 +71,12 @@ class _GatedQKVProj(nn.Module):
         dense = _bias_free_dense(self.quant, self.dtype)
 
         def norm(name):
-            return RMSNorm(eps=self.norm_eps, offset=True, dtype=self.dtype, name=name)
+            return RMSNorm(eps=self.norm_eps, offset=self.norm_offset, dtype=self.dtype, name=name)
 
-        query, gate = jnp.split(dense(h * 2 * d, name="q")(x).reshape(b, s, h, 2 * d), 2, axis=-1)
+        if self.gate:
+            query, gate = jnp.split(dense(h * 2 * d, name="q")(x).reshape(b, s, h, 2 * d), 2, axis=-1)
+        else:
+            query, gate = dense(h * d, name="q")(x).reshape(b, s, h, d), None
         key = dense(kv * d, name="k")(x).reshape(b, s, kv, d)
         value = dense(kv * d, name="v")(x).reshape(b, s, kv, d)
         query = rotate_leading_lanes(norm("q_norm")(query), self.rotary_ch, self.rope_theta)
@@ -74,14 +85,17 @@ class _GatedQKVProj(nn.Module):
 
 
 class GatedSelfAttentionBlock(nn.Module):
-    """Causal grouped-query self-attention with a sigmoid gate on its output;
-    see the module docstring. Returns ``(y, stats)``; ``stats`` holds the mean
-    of the gate (``gate_mean``), a float32 scalar without a gradient."""
+    """Causal grouped-query self-attention, with a sigmoid gate on its output
+    where ``gate``; see the module docstring. Returns ``(y, stats)``;
+    ``stats`` holds the mean of the gate (``gate_mean``), a float32 scalar
+    without a gradient, and nothing without a gate."""
 
     num_heads: int
     kv_heads: int
     head_ch: int
     rotary_ch: int
+    gate: bool = True
+    norm_offset: bool = True
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     backend: Optional[str] = None
@@ -96,13 +110,17 @@ class GatedSelfAttentionBlock(nn.Module):
             kv_heads=self.kv_heads,
             head_ch=self.head_ch,
             rotary_ch=self.rotary_ch,
+            gate=self.gate,
+            norm_offset=self.norm_offset,
             rope_theta=self.rope_theta,
             norm_eps=self.norm_eps,
             quant=self.quant,
             dtype=self.dtype,
             name="to_qkv",
         )(inputs)
-        query, key, value, gate = (checkpoint_name(t, "attn_qkv") for t in (query, key, value, gate))
+        query, key, value, gate = (
+            t if t is None else checkpoint_name(t, "attn_qkv") for t in (query, key, value, gate)
+        )
         out = dot_product_attention(
             query,
             key,
@@ -112,10 +130,13 @@ class GatedSelfAttentionBlock(nn.Module):
             logits_dtype=self.logits_dtype or self.dtype,
             causal=True,
         )
-        opened = jax.nn.sigmoid(gate.astype(jnp.float32))
-        out = (out.astype(jnp.float32) * opened).astype(self.dtype)
+        stats = {}
+        if self.gate:
+            opened = jax.nn.sigmoid(gate.astype(jnp.float32))
+            out = (out.astype(jnp.float32) * opened).astype(self.dtype)
+            stats["gate_mean"] = jax.lax.stop_gradient(jnp.mean(opened))
         dense = functools.partial(QuantDenseGeneral, mode=self.quant) if self.quant else nn.DenseGeneral
         out = dense(
             features=inputs.shape[-1], axis=(-2, -1), use_bias=False, dtype=self.dtype, name="to_out"
         )(out)
-        return checkpoint_name(out, "attn_out"), {"gate_mean": jax.lax.stop_gradient(jnp.mean(opened))}
+        return checkpoint_name(out, "attn_out"), stats

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from sav_tpu.models.layers.causal_conv import KERNEL_INIT, causal_conv_silu, causal_depthwise_conv  # noqa: F401
 from sav_tpu.models.layers.feedforward import _bias_free_dense
 from sav_tpu.ops.gated_delta import CHUNK, gated_delta_rule
 from sav_tpu.ops.quant import QuantDenseGeneral
@@ -51,53 +52,6 @@ def split_by_key_head(qkvz: jax.Array, ba: jax.Array, key_heads: int, key_ch: in
     v, z = (t.reshape(lead + (heads, value_ch)) for t in (v, z))
     b, a = jnp.split(ba.reshape(lead + (key_heads, 2 * group)), 2, axis=-1)
     return q, k, v, z, b.reshape(lead + (heads,)), a.reshape(lead + (heads,))
-
-
-def causal_depthwise_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
-    """``y_t = sum_i kernel[i] x_{t - (W - 1) + i}`` a channel, on ``x [B, S,
-    C]`` with ``kernel [W, C]``: position ``t`` reads ``t - W + 1 .. t`` and
-    zeros before the sequence starts. Summed in float32 from taps that are
-    slices of ``x`` padded once, in its own dtype."""
-    width, seq = kernel.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-    taps = (padded[:, i:i + seq].astype(jnp.float32) * kernel[i].astype(jnp.float32) for i in range(width))
-    return functools.reduce(jnp.add, taps)
-
-
-@jax.custom_vjp
-def causal_conv_silu(x: jax.Array, kernel: jax.Array) -> jax.Array:
-    """``silu(causal_depthwise_conv(x, kernel))`` in ``x``'s dtype, with the
-    backward pass written out: it computes the float32 sums again from ``x``
-    (SiLU's derivative reads them), ``dx_s = sum_i kernel[i] dy_{s + W - 1 -
-    i}`` from ``dy`` padded once at its end, and ``dkernel[i] = sum dy_t x_{t
-    - W + 1 + i}``, each one fusion over the operands. What JAX transposes
-    from the forward is a padded float32 copy a tap: compiled for a v5e at
-    ``[4, 4096, 8192]`` the pass and its gradient move 1.6 GB and hold 0.54 GB
-    beside their operands this way, 7.3 GB and 1.34 GB that way."""
-    return nn.silu(causal_depthwise_conv(x, kernel)).astype(x.dtype)
-
-
-def _causal_conv_silu_fwd(x, kernel):
-    return causal_conv_silu(x, kernel), (x, kernel)
-
-
-def _causal_conv_silu_bwd(residuals, g):
-    x, kernel = residuals
-    width, seq = kernel.shape[0], x.shape[1]
-    y = causal_depthwise_conv(x, kernel)
-    gate = jax.nn.sigmoid(y)
-    dy = g.astype(jnp.float32) * gate * (1.0 + y * (1.0 - gate))  # d silu(y) / dy
-    ahead = jnp.pad(dy, ((0, 0), (0, width - 1), (0, 0)))
-    dx = functools.reduce(
-        jnp.add,
-        (ahead[:, width - 1 - i:width - 1 - i + seq] * kernel[i].astype(jnp.float32) for i in range(width)),
-    )
-    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-    dkernel = jnp.stack([jnp.sum(dy * padded[:, i:i + seq].astype(jnp.float32), axis=(0, 1)) for i in range(width)])
-    return dx.astype(x.dtype), dkernel.astype(kernel.dtype)
-
-
-causal_conv_silu.defvjp(_causal_conv_silu_fwd, _causal_conv_silu_bwd)
 
 
 def l2_normalise(x: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -129,11 +83,7 @@ class _CausalConv(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        kernel = self.param(
-            "kernel", nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=0, out_axis=1),
-            (self.width, x.shape[-1]),
-        )
-        return causal_conv_silu(x, kernel)
+        return causal_conv_silu(x, self.param("kernel", KERNEL_INIT, (self.width, x.shape[-1])))
 
 
 class _GatedNorm(nn.Module):

@@ -14,8 +14,8 @@ sown into the ``'losses'`` collection as ``moe_aux_loss`` for the trainer
 to pick up.
 
 :class:`SparseMoEBlock` is the language family's expert layer: dropless,
-sigmoid-scored, with a shared expert, told which experts of the router's
-range it holds. It sorts the routings, runs grouped matmuls over the ragged
+sigmoid-scored, with a shared expert or without, told which experts of the
+router's range it holds. It sorts the routings, runs grouped matmuls over the ragged
 groups of those that land on the held experts, in buffers bounded by twice
 their expected number (an exact overflow pass takes the rest, a buffer's
 worth at a time), and sums the weighted results by token; it builds nothing
@@ -434,12 +434,14 @@ SCORINGS = {"sigmoid": jax.nn.sigmoid, "softmax": functools.partial(jax.nn.softm
 class _Router(nn.Module):
     """Scores over all ``num_experts`` (``scoring``: a sigmoid an expert, or
     a softmax over them), the top ``top_k`` of score plus selection bias, and
-    the selected scores (without the bias) normalised to ``routed_scale``."""
+    the selected scores (without the bias) normalised to ``routed_scale``,
+    over their sum plus ``weight_eps`` where a family adds one."""
 
     num_experts: int
     top_k: int
     routed_scale: float
     scoring: str = "sigmoid"
+    weight_eps: float = 0.0
 
     @nn.compact
     def __call__(self, x: jax.Array, select_bias: jax.Array):
@@ -449,21 +451,28 @@ class _Router(nn.Module):
         scores = SCORINGS[self.scoring](router_scores(x, kernel))  # [T, E] float32
         _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias), self.top_k)
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
-        weights = self.routed_scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+        scaled = self.routed_scale * picked
+        total = jnp.sum(picked, axis=-1, keepdims=True)
+        weights = scaled / (total + self.weight_eps if self.weight_eps else total)
         # Tagged for a caller's remat policy: a few megabytes that spare the
         # backward pass the router's matmul and a second top-k.
         return tuple(checkpoint_name(t, "moe_route") for t in (scores, chosen, weights))
 
 
 class SparseMoEBlock(nn.Module):
-    """Dropless mixture of experts with a shared expert, on ``[B, S, D]``.
+    """Dropless mixture of experts, with a shared expert or (``shared_expert``
+    False) without one, on ``[B, S, D]``.
 
     ``y = sum_{i selected and held} g_i E_i(x) + E_shared(x)``: every token
     scores all ``num_experts`` (``scoring``: ``sigmoid``, or ``softmax`` over
     all of them), the ``top_k`` of score plus ``select_bias`` are selected,
-    ``g`` is the selected scores over their sum times ``routed_scale``. With
+    ``g`` is the selected scores over their sum (plus ``weight_eps``) times
+    ``routed_scale``. With
     ``shared_gate`` the shared expert's result is multiplied by ``sigmoid(x
-    w_s)``, one number a token (leaf ``shared_gate/kernel``).
+    w_s)``, one number a token (leaf ``shared_gate/kernel``). Without a shared
+    expert the sum is the routed part alone, ``y = sum_{i selected and held}
+    g_i E_i(x)``: a token none of whose experts is held gets zeros, and the
+    tree has no ``shared`` leaves.
     ``experts_held = (offset, count)`` names the experts whose weights live here (one chip's share of an expert-parallel
     layer; ``None`` holds all): the router, the counts and the balance loss
     stay ``num_experts`` wide, the routed leaves have a leading axis of
@@ -497,7 +506,9 @@ class SparseMoEBlock(nn.Module):
     routed_scale: float = 1.0
     experts_held: Optional[Any] = None  # (offset, count); None = all
     scoring: str = "sigmoid"
+    shared_expert: bool = True
     shared_gate: bool = False
+    weight_eps: float = 0.0  # added to the selected scores' sum before the division
     quant: Optional[str] = None
     dtype: Dtype = jnp.float32
 
@@ -510,9 +521,9 @@ class SparseMoEBlock(nn.Module):
             raise ValueError(f"experts_held {self.experts_held} / top_k {k} do not fit {experts} experts")
         x = inputs.reshape(batch * seq, dim)
 
-        scores, chosen, weights = _Router(experts, k, self.routed_scale, self.scoring, name="route")(
-            x, select_bias
-        )
+        scores, chosen, weights = _Router(
+            experts, k, self.routed_scale, self.scoring, self.weight_eps, name="route"
+        )(x, select_bias)
         if self.shared_gate:
             # From the layer's input as the router reads it, in float32.
             opened = jax.nn.sigmoid(
@@ -549,11 +560,13 @@ class SparseMoEBlock(nn.Module):
                     jnp.pad(order, (0, whole_passes - total)), group_sizes,
                 )
 
-        shared = GatedFFBlock(hidden_ch=self.hidden_ch, quant=self.quant, dtype=self.dtype, name="shared")(x)
-        shared = shared.astype(jnp.float32)
-        if self.shared_gate:
-            shared = shared * opened
-        y = (routed + shared).astype(self.dtype)
+        if self.shared_expert:
+            shared = GatedFFBlock(hidden_ch=self.hidden_ch, quant=self.quant, dtype=self.dtype, name="shared")(x)
+            shared = shared.astype(jnp.float32)
+            if self.shared_gate:
+                shared = shared * opened
+            routed = routed + shared
+        y = routed.astype(self.dtype)
 
         with jax.named_scope("route"):
             counts = jnp.roll(by_local, offset, axis=0).T.astype(jnp.float32)  # [B, E]

@@ -128,7 +128,10 @@ class LoopedStack(nn.Module):
 
 
 class LMHead(nn.Module):
-    """The untied output matrix ``[D, V]``. With ``targets`` it returns each
+    """The output matrix ``[D, V]``: its own leaf (untied), or the transpose
+    of the embedding's ``table [V, D]`` where a caller hands that over (tied:
+    the module then has no leaf, and the table's gradient is the sum of the
+    embedding's and the head's). With ``targets`` it returns each
     position's cross-entropy and never holds more than ``block_tokens`` rows
     of float32 logits: the rows go through in blocks, each under
     ``jax.checkpoint``, so the backward pass recomputes a block's logits
@@ -139,10 +142,14 @@ class LMHead(nn.Module):
     dtype: Dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, h: jax.Array, targets: Optional[jax.Array] = None) -> jax.Array:
-        kernel = self.param(
-            "kernel", nn.initializers.lecun_normal(), (h.shape[-1], self.vocab_size)
-        ).astype(self.dtype)
+    def __call__(
+        self, h: jax.Array, targets: Optional[jax.Array] = None, table: Optional[jax.Array] = None
+    ) -> jax.Array:
+        if table is None:
+            kernel = self.param("kernel", nn.initializers.lecun_normal(), (h.shape[-1], self.vocab_size))
+        else:
+            kernel = table.T
+        kernel = kernel.astype(self.dtype)
         if targets is None:
             return jnp.dot(h, kernel, preferred_element_type=jnp.float32)
 

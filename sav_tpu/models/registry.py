@@ -23,6 +23,7 @@ from sav_tpu.models.cait import CaiT
 from sav_tpu.models.ceit import CeiT
 from sav_tpu.models.cvt import CvT
 from sav_tpu.models.joyai import (
+    KEPT_UNDER_REMAT_BESIDE_CONVOLUTION,
     KEPT_UNDER_REMAT_BESIDE_RECURRENCE,
     KEPT_UNDER_REMAT_BESIDE_STREAMS,
     JoyAILM,
@@ -229,6 +230,32 @@ register(
     gated_delta={"key_heads": 16, "heads": 32, "key_ch": 128, "value_ch": 128, "conv_width": 4},
     rope_theta=1e7, norm_eps=1e-6,
     kept_under_remat=KEPT_UNDER_REMAT_BESIDE_RECURRENCE,
+)
+
+# --- LFM2-24B-A2B (short convolutions with a softmax layer, no shared expert) -
+# Sizes of https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json;
+# ``num_classes`` is the vocabulary (65,536 there). ``mixers`` is the config's
+# ``layer_types`` letter for letter (30 ``conv``, 10 ``full_attention``: no
+# interval gives them). Two leading dense layers at 11,776, then 64
+# sigmoid-routed experts of 1,536, top-4 of score + a selection bias that is
+# state, no shared expert; 32 query heads of 64 on 8 key/value heads, plain
+# norm weights, rotary on the whole head; the head reads the embedding's
+# table. 23.8 B parameters (2.3 B active a token): one chip holds a cut in
+# depth and its share of every expert layer (model_overrides={"num_layers": 5,
+# "first_dense": 1, "mixers": [...], "experts_held": (0, 8)}).
+LFM2_LAYER_TYPES = ("conv", "conv", "full_attention") + ("conv", "conv", "conv", "full_attention") * 9 + ("conv",)
+register(
+    "lfm2_24b_a2b",
+    JoyAILM,
+    task="tokens_mtp",
+    embed_dim=2048, num_layers=40, mlp_ch=11776, expert_ch=1536,
+    num_experts=64, top_k=4, routed_scale=1.0, first_dense=2, mtp_modules=0,
+    bias_update_rate=1e-3, scoring="sigmoid", shared_expert=False, router_weight_eps=1e-6,
+    tie_head=True, mixers=LFM2_LAYER_TYPES,
+    gated_attention={"num_heads": 32, "kv_heads": 8, "head_ch": 64, "rotary_ch": 64, "gate": False},
+    short_conv={"conv_width": 3},
+    rope_theta=1e6, norm_eps=1e-5,
+    kept_under_remat=KEPT_UNDER_REMAT_BESIDE_CONVOLUTION,
 )
 
 

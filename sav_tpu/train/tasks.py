@@ -289,7 +289,8 @@ class MTPTokenPrediction(TokenPrediction):
     hyper-connected streams, how far its mixing matrices are from doubly
     stochastic and what they do to the streams' norm; where its token mixers
     are delta-rule and gated attention blocks, the smallest decay, the largest
-    state and the gate's mean."""
+    state and the gate's mean; where they are short convolutions, the largest
+    RMS of a block's gated result."""
 
     # lambda of the MTP loss (arXiv:2412.19437 section 4.2's first phase;
     # assumed: benchmark/configs/joyai_llm_flash.json).
@@ -301,10 +302,12 @@ class MTPTokenPrediction(TokenPrediction):
     # the norm of the streams it mixed (the constraint holds it at 1 or
     # under). A hybrid decoder: the smallest exp(g_t) of the delta-rule
     # layers, the largest RMS of any head's final state, and the mean of the
-    # gated attention's sigmoid gate.
+    # gated attention's sigmoid gate. Short convolutions: the largest RMS of
+    # any block's and sequence's C * c (the block is cubic and holds no norm).
     layer_stats = (
         ("hc_doubly_stochastic_err", jnp.max), ("hc_stream_gain", jnp.max),
         ("gdn_decay_min", jnp.min), ("gdn_state_rms_max", jnp.max), ("attn_gate_mean", jnp.mean),
+        ("sconv_out_rms_max", jnp.max),
     )
 
     def loss(self, outputs: dict, targets) -> jax.Array:

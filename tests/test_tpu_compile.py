@@ -173,6 +173,11 @@ KERNEL_CASES = [
     # hybrid decoder's full-attention layer), in place, the group's head
     # found through the block index. The fifth number is the key/value heads.
     ("flash_grouped-qwen3_next_4k", "flash_grouped", (4, 4096, 16, 256, 2)),
+    # Heads narrower than a lane tile on a quarter as many key/value heads, at
+    # the longest sequence a cell has: 32 query heads of 64 on 8 (the
+    # convolution-attention hybrid's softmax layer). Head-major: the lanes
+    # padded to 128, k and v repeated to the query heads.
+    ("flash_grouped-lfm2_8k", "flash_grouped", (4, 8192, 32, 64, 8)),
     # The gated delta rule at the hybrid decoder's cell: q and k [B, L, H_k,
     # d_k], the fifth number the value heads; chunks of 64, the state-free
     # part in its two kernels (one a direction), eight chunks a grid step.
@@ -221,8 +226,10 @@ def _kernel_fn_and_args(kernel, shape, sharding):
 
         from sav_tpu.ops import attn_tuning
 
-        blocks = attn_tuning.block_config(attn_tuning.lookup(*shape[:2], shape[1], heads, dim, causal=True))
-        assert layout_form(shape[1], shape[1], dim, dim, batch_heads=shape[0] * heads, **blocks) == "in_place"
+        blocks = attn_tuning.block_config(attn_tuning.lookup(*shape[:2], shape[1], heads, dim, causal=True)) or {}
+        # What the dispatch log will name: in place where the head is whole lane tiles.
+        layout = "in_place" if dim % 128 == 0 else "head_major"
+        assert layout_form(shape[1], shape[1], dim, dim, batch_heads=shape[0] * heads, **blocks) == layout
         few = spec(shape[:2] + (shape[4], dim))
         return (
             lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False, **blocks)

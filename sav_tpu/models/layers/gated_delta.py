@@ -32,7 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from sav_tpu.models.layers.causal_conv import KERNEL_INIT, causal_conv_silu, causal_depthwise_conv  # noqa: F401
+from sav_tpu.models.layers.causal_conv import (  # noqa: F401
+    KERNEL_INIT, causal_conv_silu, causal_depthwise_conv, conv_silu_by_key_head, key_head_parts,
+)
 from sav_tpu.models.layers.feedforward import _bias_free_dense
 from sav_tpu.ops.gated_delta import CHUNK, gated_delta_rule
 from sav_tpu.ops.quant import QuantDenseGeneral
@@ -44,14 +46,21 @@ def split_by_key_head(qkvz: jax.Array, ba: jax.Array, key_heads: int, key_ch: in
     """The fused projections' outputs ``[..., H_k (2 d_k + 2 r d_v)]`` and
     ``[..., H_k 2 r]`` (``r = H / H_k``) -> ``q, k [..., H_k, d_k]``, ``v, z
     [..., H, d_v]``, ``b, a [..., H]``: value heads ``j r .. (j + 1) r - 1``
-    are key head ``j``'s."""
-    group = heads // key_heads
+    are key head ``j``'s. The layout written out; the block reads q, k and v
+    through the convolution (:func:`~sav_tpu.models.layers.causal_conv.
+    conv_silu_by_key_head`), which splits by the same rule."""
     lead = qkvz.shape[:-1]
-    qkvz = qkvz.reshape(lead + (key_heads, 2 * key_ch + 2 * group * value_ch))
-    q, k, v, z = jnp.split(qkvz, [key_ch, 2 * key_ch, 2 * key_ch + group * value_ch], axis=-1)
+    q, k, v, z = key_head_parts(qkvz, key_heads, key_ch, heads // key_heads * value_ch)
+    q, k = (t.reshape(lead + (key_heads, key_ch)) for t in (q, k))
     v, z = (t.reshape(lead + (heads, value_ch)) for t in (v, z))
-    b, a = jnp.split(ba.reshape(lead + (key_heads, 2 * group)), 2, axis=-1)
-    return q, k, v, z, b.reshape(lead + (heads,)), a.reshape(lead + (heads,))
+    return (q, k, v, z) + split_gates(ba, key_heads, heads)
+
+
+def split_gates(ba: jax.Array, key_heads: int, heads: int) -> tuple:
+    """``[..., H_k 2 r]``, a key head's ``[b r | a r]`` -> ``b, a [..., H]``."""
+    lead = ba.shape[:-1]
+    b, a = jnp.split(ba.reshape(lead + (key_heads, 2 * heads // key_heads)), 2, axis=-1)
+    return b.reshape(lead + (heads,)), a.reshape(lead + (heads,))
 
 
 def l2_normalise(x: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -72,18 +81,23 @@ class _InputProj(nn.Module):
         dense = _bias_free_dense(self.quant, self.dtype)
         group = self.heads // self.key_heads
         qkvz = dense(self.key_heads * 2 * (self.key_ch + group * self.value_ch), name="qkvz")(x)
-        ba = dense(2 * self.heads, name="ba")(x)
-        return split_by_key_head(qkvz, ba, self.key_heads, self.key_ch, self.heads, self.value_ch)
+        return qkvz, dense(2 * self.heads, name="ba")(x)
 
 
 class _CausalConv(nn.Module):
-    """:func:`causal_conv_silu` with its ``[W, C]`` kernel (no bias)."""
+    """:func:`conv_silu_by_key_head` with its ``[W, C]`` kernel (no bias) over
+    ``C``, the q, k and v among ``qkvz``'s channels: ``q, k, v, z``, flat."""
 
     width: int
+    key_heads: int
+    key_ch: int
+    value_ch: int  # a key head's: r d_v
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
-        return causal_conv_silu(x, self.param("kernel", KERNEL_INIT, (self.width, x.shape[-1])))
+    def __call__(self, qkvz: jax.Array):
+        channels = self.key_heads * (2 * self.key_ch + self.value_ch)
+        kernel = self.param("kernel", KERNEL_INIT, (self.width, channels))
+        return conv_silu_by_key_head(qkvz, kernel, self.key_heads, self.key_ch, self.value_ch)
 
 
 class _GatedNorm(nn.Module):
@@ -130,19 +144,21 @@ class GatedDeltaNetBlock(nn.Module):
     @nn.compact
     def __call__(self, inputs: jax.Array):
         batch, seq, _ = inputs.shape
-        q, k, v, z, b, a = _InputProj(
+        qkvz, ba = _InputProj(
             self.key_heads, self.key_ch, self.heads, self.value_ch, self.quant, self.dtype, name="to_qkv"
         )(inputs)
+        b, a = split_gates(ba, self.key_heads, self.heads)
         a_log = self.param("A_log", _decay_rates, (self.heads,))
         dt_bias = self.param("dt_bias", nn.initializers.ones, (self.heads,))
 
         with jax.named_scope("gdn/conv"):
-            widths = (self.key_heads * self.key_ch,) * 2 + (self.heads * self.value_ch,)
-            mixed = jnp.concatenate([t.reshape(batch, seq, -1) for t in (q, k, v)], axis=-1)
-            mixed = checkpoint_name(_CausalConv(self.conv_width, name="conv")(mixed), "gdn_conv")
-            q, k, v = jnp.split(mixed, [widths[0], widths[0] + widths[1]], axis=-1)
+            conv = _CausalConv(
+                self.conv_width, self.key_heads, self.key_ch, self.heads // self.key_heads * self.value_ch, name="conv"
+            )
+            *qkv, z = conv(qkvz)
+            q, k, v = (checkpoint_name(t, "gdn_conv") for t in qkv)
             q, k = (t.reshape(batch, seq, self.key_heads, self.key_ch) for t in (q, k))
-            v = v.reshape(batch, seq, self.heads, self.value_ch)
+            v, z = (t.reshape(batch, seq, self.heads, self.value_ch) for t in (v, z))
         with jax.named_scope("gdn/rule"):
             @jax.checkpoint  # float32 inside; the backward pass starts from the operands in the compute dtype
             def operands(q, k, b, a, a_log, dt_bias):

@@ -15,7 +15,7 @@ such state yet).
 Scopes, for the readers of a trace: the input projection under ``to_qkv``
 (leaf ``in_proj``), the two gates and the convolution under ``sconv/core``
 (forward, recomputed and backward: :func:`~sav_tpu.models.layers.causal_conv.
-gated_causal_conv`), the output projection under ``to_out`` (leaf
+gated_causal_conv_of_thirds`), the output projection under ``to_out`` (leaf
 ``out_proj``). The module's name holds no ``SelfAttentionBlock``: the
 attention readers pass it by.
 """
@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from sav_tpu.models.layers.causal_conv import KERNEL_INIT, gated_causal_conv
+from sav_tpu.models.layers.causal_conv import KERNEL_INIT, gated_causal_conv_of_thirds
 from sav_tpu.models.layers.feedforward import _bias_free_dense
 
 Dtype = Any
@@ -73,10 +73,10 @@ class ShortConvBlock(nn.Module):
     def __call__(self, inputs: jax.Array):
         dim = inputs.shape[-1]
         gates = _Proj(3 * dim, "in_proj", self.quant, self.dtype, name="to_qkv")(inputs)
-        b, c, x = jnp.split(checkpoint_name(gates, "sconv_in"), 3, axis=-1)
+        gates = checkpoint_name(gates, "sconv_in")  # [B | C | x~], read where it lies
         kernel = _ConvKernel(self.conv_width, name="conv")(dim)
         with jax.named_scope("sconv/core"):
-            mixed = checkpoint_name(gated_causal_conv(b, c, x, kernel), "sconv_core")
+            mixed = checkpoint_name(gated_causal_conv_of_thirds(gates, kernel), "sconv_core")
             square = jnp.square(jax.lax.stop_gradient(mixed).astype(jnp.float32))
             rms = jnp.sqrt(jnp.max(jnp.mean(square, axis=(1, 2))))
         out = _Proj(dim, "out_proj", self.quant, self.dtype, name="to_out")(mixed)

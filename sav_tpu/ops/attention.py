@@ -458,3 +458,17 @@ def dot_product_attention(
         logits_dtype=logits_dtype,
         causal=causal,
     )
+
+
+def log_conv_form(fused: str, shape, width: int, dtype: str, form: dict) -> None:
+    """The causal convolution's record (``ops/causal_conv.py::conv_form``) in
+    the same log, one a traced shape and fused form: ``op``, the form
+    (``silu`` | ``gated``), the ``[B, S, C]`` shape the taps run over, the
+    taps, and the program: ``conv`` (``kernel`` | ``xla``) with its ``block_s``
+    and ``block_c`` (and how it ``reads`` a projection laid out by key head),
+    or with what ``refused`` the kernel. It stands at the file's end: the
+    Mosaic calls' bodies carry this file's line numbers into a compiled step
+    and into its cache key."""
+    _log_once(("causal_conv", fused, shape, width, dtype, form["conv"]), {
+        "op": "causal_conv", "fused": fused, "shape": list(shape), "width": width, "dtype": dtype, **form,
+    })

@@ -12,7 +12,10 @@ activation).
 The kernels of the state-free part run here in the Pallas interpreter, called
 past the rule that picks the path (``rule_form`` says ``xla`` on a CPU and for
 these toy heads), and are held to the same tolerances, against the recurrence
-and against XLA's form."""
+and against XLA's form. So do the convolution's kernels
+(``sav_tpu/ops/causal_conv.py``): ``form = "kernel"`` below is the Pallas call
+of each direction in the interpreter at blocks of 16 or 32 rows, so that a
+sequence is several blocks and a tap reads across their edges."""
 
 import functools
 
@@ -21,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from sav_tpu.models.layers import causal_conv as conv_forms
 from sav_tpu.models.layers.gated_delta import (
     GatedDeltaNetBlock,
     causal_conv_silu,
@@ -29,6 +33,7 @@ from sav_tpu.models.layers.gated_delta import (
     split_by_key_head,
 )
 from sav_tpu.ops import attention
+from sav_tpu.ops.causal_conv import conv_form
 from sav_tpu.ops.gated_delta import (
     _by_chunk,
     _chunked,
@@ -296,34 +301,198 @@ def test_mismatched_heads_are_refused():
 # ----------------------------------------------------------------- the block
 
 
-@pytest.mark.parametrize("position", [0, 3, 17])
-def test_the_convolution_is_causal_and_reads_four_positions(position):
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 6))
-    kernel = jax.random.normal(jax.random.PRNGKey(2), (4, 6))
-    base = causal_depthwise_conv(x, kernel)
-    moved = causal_depthwise_conv(x.at[:, position].add(1.0), kernel)
+def convolution(form, block_s=16):
+    """The convolution alone as the form's program: XLA's, or the gated kernel
+    between two gates of ones (``1 * conv(1 * x)``), interpreted."""
+    if form == "xla":
+        return causal_depthwise_conv
+    ones = jnp.ones_like
+    return lambda x, kernel: conv_forms._gated_conv_in_vmem(
+        jnp.concatenate([ones(x), ones(x), x], axis=-1), kernel, block_s, 128, True)
+
+
+def conv_silu(form, block_s=32):
+    """``causal_conv_silu`` as the form's program (on this CPU ``conv_form``
+    says ``xla``; the kernels are called past it, interpreted)."""
+    if form == "xla":
+        return causal_conv_silu
+    return lambda x, kernel: conv_forms._conv_silu_in_vmem(x, kernel, block_s, 128, True)
+
+
+@pytest.mark.parametrize("position", [0, 3, 14, 17])
+@pytest.mark.parametrize("form,seq,channels", [("xla", 24, 6), ("kernel", 32, 128)])
+def test_the_convolution_is_causal_and_reads_four_positions(form, seq, channels, position):
+    conv = convolution(form)  # the kernel's blocks end at rows 15 and 31: position 14 reaches across
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, seq, channels))
+    kernel = jax.random.normal(jax.random.PRNGKey(2), (4, channels))
+    base = conv(x, kernel)
+    moved = conv(x.at[:, position].add(1.0), kernel)
     changed = np.flatnonzero(np.max(np.abs(np.asarray(moved - base)), axis=(0, 2)) > 0)
-    assert list(changed) == [p for p in range(position, position + 4) if p < 24]
+    assert list(changed) == [p for p in range(position, position + 4) if p < seq]
     # Written out: y_t = sum_i kernel[i] x_{t - 3 + i}, zeros before the start.
-    t = 5
-    want = sum(kernel[i] * x[:, t - 3 + i] for i in range(4))
-    assert np.allclose(np.asarray(base[:, t]), np.asarray(want), atol=1e-6)
+    for t in (5, 16, 17):
+        want = sum(kernel[i] * x[:, t - 3 + i] for i in range(4))
+        assert np.allclose(np.asarray(base[:, t]), np.asarray(want), atol=1e-6)
     assert np.allclose(np.asarray(base[:, 0]), np.asarray(kernel[3] * x[:, 0]), atol=1e-6)
+    assert np.allclose(np.asarray(base[:, 1]), np.asarray(kernel[3] * x[:, 1] + kernel[2] * x[:, 0]), atol=1e-6)
 
 
-@pytest.mark.parametrize("width,seq", [(4, 24), (4, 3), (2, 9)])
-def test_the_convolutions_written_out_backward_is_the_transposed_forward(width, seq):
+@pytest.mark.parametrize("form,width,seq,channels", [
+    ("xla", 4, 24, 6), ("xla", 4, 3, 6), ("xla", 2, 9, 6),
+    ("kernel", 4, 96, 256), ("kernel", 3, 64, 128), ("kernel", 2, 32, 128), ("kernel", 9, 64, 128),
+])
+def test_the_convolutions_written_out_backward_is_the_transposed_forward(form, width, seq, channels):
     """``causal_conv_silu``'s own rule against JAX's derivative of the same
-    composition, float32: the two sum in other orders (1e-5 of the largest)."""
-    x = jax.random.normal(jax.random.PRNGKey(3), (2, seq, 6))
-    kernel = jax.random.normal(jax.random.PRNGKey(4), (width, 6))
-    g = jax.random.normal(jax.random.PRNGKey(5), (2, seq, 6))
+    composition, float32: the two sum in other orders (1e-5 of the largest).
+    The kernels at blocks of 32 rows: three, two and one a sequence, and
+    (width 9) every row a piece carries to the next read."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, seq, channels))
+    kernel = jax.random.normal(jax.random.PRNGKey(4), (width, channels))
+    g = jax.random.normal(jax.random.PRNGKey(5), (2, seq, channels))
     plain = lambda x, kernel: jax.nn.silu(causal_depthwise_conv(x, kernel))
-    out, pull = jax.vjp(causal_conv_silu, x, kernel)
+    out, pull = jax.vjp(conv_silu(form), x, kernel)
     want, want_pull = jax.vjp(plain, x, kernel)
     assert close(out, want, 1e-6)
     for got, ref in zip(pull(g), want_pull(g)):
-        assert got.shape == ref.shape and close(got, ref, 1e-5)
+        assert got.shape == ref.shape and got.dtype == ref.dtype and close(got, ref, 1e-5)
+
+
+def test_the_convolutions_kernels_walk_a_block_in_pieces_and_keep_bfloat16():
+    """A block of 128 rows is two trips of the kernels' loop over 64 (the rows
+    between them carried in registers, those between blocks in VMEM), at two
+    lane pieces a block: bfloat16 in, bfloat16 out, the kernel's gradient
+    float32, each within a rounding of the XLA form's."""
+    x, g = (jax.random.normal(jax.random.PRNGKey(i), (1, 256, 256), jnp.bfloat16) for i in (6, 7))
+    kernel = jax.random.normal(jax.random.PRNGKey(8), (4, 256)) * 0.5
+    in_vmem = lambda x, kernel: conv_forms._conv_silu_in_vmem(x, kernel, 128, 256, True)
+    out, pull = jax.vjp(in_vmem, x, kernel)
+    want, want_pull = jax.vjp(conv_forms._conv_silu_xla, x, kernel)
+    assert out.dtype == jnp.bfloat16 and close(out, want, 1e-2)
+    grads, refs = pull(g), want_pull(g)
+    assert [t.dtype for t in grads] == [jnp.bfloat16, jnp.float32]
+    assert close(grads[0], refs[0], 1e-2) and close(grads[1], refs[1], 1e-5)
+
+
+CONV_FORMS = [
+    # (rows, channels, taps, dtype, on a TPU) -> the form
+    ((4096, 8192, 4, jnp.bfloat16, True), {"conv": "kernel", "block_s": 1024, "block_c": 512}),  # the hybrid decoder's cell
+    ((8192, 2048, 3, jnp.bfloat16, True), {"conv": "kernel", "block_s": 1024, "block_c": 512}),  # the convolution hybrid's
+    ((48, 384, 2, jnp.float32, True), {"conv": "kernel", "block_s": 16, "block_c": 128}),
+    ((4096, 8192, 4, jnp.bfloat16, False), {"conv": "xla", "refused": "non-TPU backend"}),
+    ((4096, 8192, 4, jnp.float16, True), {"conv": "xla", "refused": "operands of float16"}),
+    ((4096, 96, 4, jnp.bfloat16, True), {"conv": "xla", "refused": "96 channels are not whole lane tiles"}),
+    ((24, 128, 4, jnp.bfloat16, True), {"conv": "xla", "refused": "24 rows are not whole 16-row tiles"}),
+    ((64, 128, 10, jnp.bfloat16, True), {"conv": "xla", "refused": "width 10 reaches past the 8 rows a piece carries"}),
+    ((64, 128, 1, jnp.bfloat16, True), {"conv": "xla", "refused": "width 1 reaches past the 8 rows a piece carries"}),
+]
+
+
+@pytest.mark.parametrize("shape,form", CONV_FORMS, ids=[str(shape[:3] + shape[4:]) for shape, _ in CONV_FORMS])
+def test_the_convolution_picks_its_program_from_the_backend_and_the_shapes(shape, form):
+    *sizes, on_tpu = shape
+    assert conv_form(*sizes, on_tpu=on_tpu) == form
+
+
+def test_the_dispatch_log_records_the_convolutions_form(monkeypatch):
+    """One record a traced shape and fused form, beside the attention's and
+    the rule's: ``xla`` with what refused on this backend, ``kernel`` with its
+    blocks where a TPU would run it (nothing runs here: the trace alone writes
+    the record). The gated form's shape is the ``[B, S, C]`` the taps run
+    over, a third of its operand."""
+    x, gates = jax.ShapeDtypeStruct((2, 64, 256), jnp.bfloat16), jax.ShapeDtypeStruct((2, 64, 384), jnp.bfloat16)
+    taps = lambda channels: jax.ShapeDtypeStruct((3, channels), jnp.float32)
+
+    def trace():
+        # Fresh functions: eval_shape keeps the trace of one it has seen.
+        silu = jax.eval_shape(lambda *a: causal_conv_silu(*a), x, taps(256))
+        gated = jax.eval_shape(lambda *a: conv_forms.gated_causal_conv_of_thirds(*a), gates, taps(128))
+        assert (silu.shape, gated.shape, gated.dtype) == ((2, 64, 256), (2, 64, 128), jnp.bfloat16)
+
+    attention.clear_dispatch_log()
+    trace()
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    trace()
+    log = attention.snapshot_dispatch_log()
+    attention.clear_dispatch_log()
+    silu = {"op": "causal_conv", "fused": "silu", "shape": [2, 64, 256], "width": 3, "dtype": "bfloat16"}
+    gated = {**silu, "fused": "gated", "shape": [2, 64, 128]}
+    assert log == [
+        {**silu, "conv": "xla", "refused": "non-TPU backend"},
+        {**gated, "conv": "xla", "refused": "non-TPU backend"},
+        {**silu, "conv": "kernel", "block_s": 64, "block_c": 256},
+        {**gated, "conv": "kernel", "block_s": 64, "block_c": 128},
+    ]
+
+
+@pytest.mark.parametrize("dtype,width,seq,value_ch,tol", [
+    (jnp.float32, 4, 96, 256, 1e-5), (jnp.float32, 3, 32, 128, 1e-5), (jnp.bfloat16, 4, 64, 256, 1e-2),
+])
+def test_the_convolution_reads_a_projection_by_key_head_where_it_lies(dtype, width, seq, value_ch, tol):
+    """The kernels on ``[q | k | v | z]`` a key head (two heads, blocks of 32
+    rows: three, one and two a sequence) against the joined form: q, k and v
+    each an array of its own, z as it came; the projection's gradient whole,
+    z's cotangent in z's lanes; the kernel's gradient in the leaf's order
+    (all q, all k, all v)."""
+    key_heads, key_ch = 2, 128
+    parts = (key_ch, key_ch, value_ch, value_ch)
+    keys = jax.random.split(jax.random.PRNGKey(9), 6)
+    qkvz = jax.random.normal(keys[0], (2, seq, key_heads * sum(parts))).astype(dtype)
+    kernel = jax.random.normal(keys[1], (width, key_heads * sum(parts[:3]))) * 0.5
+    g = tuple(jax.random.normal(k, (2, seq, key_heads * ch)).astype(dtype) for k, ch in zip(keys[2:], parts))
+    in_place = lambda qkvz, kernel: conv_forms._conv_silu_of_key_heads(
+        qkvz, kernel, key_heads, key_ch, value_ch, 32, True)
+    out, pull = jax.vjp(in_place, qkvz, kernel)
+    joined = lambda qkvz, kernel: conv_forms.conv_silu_joined(
+        conv_forms._conv_silu_xla, qkvz, kernel, key_heads, key_ch, value_ch)
+    want, want_pull = jax.vjp(joined, qkvz, kernel)
+    for got, ref in zip(out + pull(g), want + want_pull(g)):
+        assert got.shape == ref.shape and got.dtype == ref.dtype and close(got, ref, tol)
+    assert np.array_equal(np.asarray(out[3]), np.asarray(want[3]))  # z
+
+
+KEY_HEAD_FORMS = [
+    # (d_k, r d_v, rows) -> what the kernel form adds
+    ((128, 256, 4096), {"block_s": 1024, "block_c": 512, "reads": "in_place"}),  # the hybrid decoder's cell
+    ((256, 1024, 4096), {"block_s": 256, "block_c": 1536, "reads": "in_place"}),  # 2,560 lanes a key head
+    ((64, 128, 4096), {"block_s": 1024, "block_c": 512, "reads": "joined"}),  # q and k are half a lane tile
+]
+
+
+@pytest.mark.parametrize("sizes,added", KEY_HEAD_FORMS, ids=[str(sizes) for sizes, _ in KEY_HEAD_FORMS])
+def test_the_convolution_reads_key_heads_in_place_where_their_parts_are_lane_tiles(sizes, added):
+    key_ch, value_ch, seq = sizes
+    channels = 16 * (2 * key_ch + value_ch)
+    form = conv_form(seq, channels, 4, jnp.bfloat16, key_head=(key_ch, value_ch), on_tpu=True)
+    assert form == {"conv": "kernel", **added}
+    assert conv_form(seq, channels, 4, jnp.bfloat16, key_head=(key_ch, value_ch), on_tpu=False) == {
+        "conv": "xla", "refused": "non-TPU backend"}
+
+
+def test_the_block_runs_the_kernels_on_its_projection_and_notes_it(monkeypatch):
+    """The block with ``conv_form`` told it is on a TPU (the kernels in the
+    interpreter, everything else as on this CPU) against the block as it runs
+    here: the same output and the same gradient of every leaf, and a record
+    of the program that read the projection in place."""
+    import types
+    from sav_tpu.ops import causal_conv as conv_kernels
+
+    block = GatedDeltaNetBlock(key_heads=1, heads=2, key_ch=128, value_ch=128, chunk=16)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 64))
+    variables = jax.jit(block.init)({"params": jax.random.PRNGKey(1)}, x)
+    loss = lambda params, x: jnp.sum(jnp.square(block.apply({"params": params}, x)[0]))
+    both = lambda: jax.jit(jax.value_and_grad(loss))(variables["params"], x)  # a trace a call: the form is picked in it
+    want = both()
+    attention.clear_dispatch_log()
+    monkeypatch.setattr(conv_kernels, "_attention", types.SimpleNamespace(_on_tpu=lambda: True))
+    got = both()
+    log = [line for line in attention.snapshot_dispatch_log() if line["op"] == "causal_conv"]
+    attention.clear_dispatch_log()
+    assert log == [{"op": "causal_conv", "fused": "silu", "shape": [2, 32, 512], "width": 4, "dtype": "float32",
+                    "conv": "kernel", "block_s": 32, "block_c": 512, "reads": "in_place"}]
+    flat = lambda tree: jax.tree_util.tree_flatten_with_path(tree)[0]
+    for (path, one), (_, ref) in zip(flat(got), flat(want)):
+        # The decay's two leaves sum a sequence's terms of both signs: XLA's own two orders differ by 2e-4.
+        assert close(one, ref, 2e-3 if "A_log" in (name := jax.tree_util.keystr(path)) or "dt_bias" in name else 1e-5), name
 
 
 def test_the_fused_projection_is_split_by_key_head():

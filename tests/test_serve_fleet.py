@@ -1179,9 +1179,10 @@ def test_fleet_smoke_two_replicas_router_shifts_load(
     What separates the two replicas is the injected second, a sleep of
     the test's own, and every threshold below sits between it and what a
     healthy replica does with a margin a loaded CI host does not eat: the
-    alert rule fires past 700 ms (rank 1 cannot answer under 1,000; rank
-    0 serves its ~40 one-row batches in a few ms each, and would have to
-    run 5x slower than an idle host for its queue's tail to reach that),
+    alert rule fires past 900 ms (rank 1 cannot answer under 1,000; rank
+    0 serves its ~40 one-row batches in a few ms each, and its queue's
+    tail has read 707 ms beside five other xdist workers: at 700 the
+    rule fired on it twice in the builders' tier-1 runs of PRs 39-40),
     the deadline (30 s) admits the whole flood whichever way the first
     requests split, and the router's own meter is held to a bound that
     one file write or socket call on the stamp path would break and a
@@ -1197,10 +1198,10 @@ def test_fleet_smoke_two_replicas_router_shifts_load(
         json.dump({"rules": [{
             # The +1.0 s injected batch delay puts rank 1's windowed
             # p99 over 1,000 ms; rank 0's stays in the tens of ms on an
-            # idle host and far under 700 on a loaded one.
+            # idle host and under 900 on a loaded one.
             "name": "slow-replica-p99", "severity": "warn",
             "when": [
-                {"metric": "w.p99_ms", "op": ">", "value": 700.0},
+                {"metric": "w.p99_ms", "op": ">", "value": 900.0},
             ],
             # Fire on the first hot beat; resolve only via the orderly
             # close (the injected delay never recovers in-run), so the

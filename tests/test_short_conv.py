@@ -4,13 +4,18 @@ against three shifted products written out in float32, on the CPU.
 
 Tolerances. In float32 the block and the written-out form differ in the order
 of three-term sums only: 1e-6 of the compared tensor's largest entry. In
-bfloat16 the program rounds ``B * x~`` and its result once each: 2e-2."""
+bfloat16 the program rounds ``B * x~`` and its result once each: 2e-2.
+
+``form = "kernel"`` is the core as the Pallas calls of
+``sav_tpu/ops/causal_conv.py`` in the interpreter, called past ``conv_form``
+(which says ``xla`` on this CPU), at blocks of 16 rows: three a sequence."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from sav_tpu.models.layers import causal_conv
 from sav_tpu.models.layers.causal_conv import causal_depthwise_conv, gated_causal_conv
 from sav_tpu.models.layers.short_conv import ShortConvBlock
 
@@ -22,10 +27,21 @@ def close(got, want, tol=1e-6):
     return float(np.max(np.abs(got - want))) <= tol * float(np.max(np.abs(want)))
 
 
-def operands(seed=0, width=3, dtype=jnp.float32):
+FORMS = {"xla": (SEQ, DIM), "kernel": (48, 128)}  # the sequence and the channels each is tried at
+
+
+def core(form):
+    if form == "xla":
+        return gated_causal_conv
+    return lambda b, c, x, kernel: causal_conv._gated_conv_in_vmem(
+        jnp.concatenate([b, c, x], axis=-1), kernel, 16, 128, True)
+
+
+def operands(seed=0, width=3, dtype=jnp.float32, form="xla"):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    b, c, x, g = (jax.random.normal(k, (BATCH, SEQ, DIM)).astype(dtype) for k in ks[:4])
-    return b, c, x, jax.random.normal(ks[4], (width, DIM)) * width ** -0.5, g
+    seq, dim = FORMS[form]
+    b, c, x, g = (jax.random.normal(k, (BATCH, seq, dim)).astype(dtype) for k in ks[:4])
+    return b, c, x, jax.random.normal(ks[4], (width, dim)) * width ** -0.5, g
 
 
 def shifted_products(b, c, x, kernel):
@@ -35,32 +51,35 @@ def shifted_products(b, c, x, kernel):
     conv = jnp.zeros_like(u)
     for j in range(width):
         back = width - 1 - j  # tap j reads u_{t - back}
-        shifted = jnp.concatenate([jnp.zeros_like(u[:, :back]), u[:, :SEQ - back]], axis=1)
+        shifted = jnp.concatenate([jnp.zeros_like(u[:, :back]), u[:, :u.shape[1] - back]], axis=1)
         conv = conv + kernel[j] * shifted
     return c * conv
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("width", [3, 4])
-def test_the_core_is_three_shifted_products_between_two_gates(width):
-    b, c, x, kernel, _ = operands(width=width)
-    assert close(gated_causal_conv(b, c, x, kernel), shifted_products(b, c, x, kernel))
+def test_the_core_is_three_shifted_products_between_two_gates(width, form):
+    b, c, x, kernel, _ = operands(width=width, form=form)
+    assert close(core(form)(b, c, x, kernel), shifted_products(b, c, x, kernel))
     # The convolution alone, as the delta-rule block reads it from the same home.
     assert close(causal_depthwise_conv(b * x, kernel), shifted_products(b, jnp.ones_like(c), x, kernel))
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("width", [3, 4])
-def test_the_cores_own_backward_is_jaxs_derivative_of_the_written_out_form(width):
-    b, c, x, kernel, g = operands(seed=1, width=width)
-    out, pull = jax.vjp(gated_causal_conv, b, c, x, kernel)
+def test_the_cores_own_backward_is_jaxs_derivative_of_the_written_out_form(width, form):
+    b, c, x, kernel, g = operands(seed=1, width=width, form=form)
+    out, pull = jax.vjp(core(form), b, c, x, kernel)
     want, want_pull = jax.vjp(shifted_products, b, c, x, kernel)
     assert close(out, want)
     for name, got, ref in zip("db dc dx dkernel".split(), pull(g), want_pull(g)):
         assert got.shape == ref.shape and got.dtype == ref.dtype and close(got, ref, 2e-6), name
 
 
-def test_the_core_in_bfloat16_keeps_its_operands_dtype():
-    b, c, x, kernel, g = operands(seed=2, dtype=jnp.bfloat16)
-    out, pull = jax.vjp(gated_causal_conv, b, c, x, kernel)
+@pytest.mark.parametrize("form", list(FORMS))
+def test_the_core_in_bfloat16_keeps_its_operands_dtype(form):
+    b, c, x, kernel, g = operands(seed=2, dtype=jnp.bfloat16, form=form)
+    out, pull = jax.vjp(core(form), b, c, x, kernel)
     wide = [t.astype(jnp.float32) for t in (b, c, x)]
     assert out.dtype == jnp.bfloat16 and close(out, shifted_products(*wide, kernel), 2e-2)
     grads = pull(g)

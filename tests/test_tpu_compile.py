@@ -182,6 +182,14 @@ KERNEL_CASES = [
     # d_k], the fifth number the value heads; chunks of 64, the state-free
     # part in its two kernels (one a direction), eight chunks a grid step.
     ("gated_delta-qwen3_next_4k", "gated_delta", (4, 4096, 16, 128, 32)),
+    # The causal depthwise convolution's kernels at the two cells that run
+    # them, [B, S, C] and the taps: fused with a SiLU over q, k and v joined
+    # (the hybrid decoder), between two gates on the thirds of the input
+    # projection's [B, S, 3 C] (the convolution-attention hybrid).
+    ("causal_conv_silu-qwen3_next_4k", "conv_silu", (4, 4096, 8192, 4)),
+    # ... and as that cell runs it: on the projection [B, S, 16 (q 128 | k 128 | v 256 | z 256)] where it lies.
+    ("causal_conv_silu_by_key_head-qwen3_next_4k", "conv_key_head", (4, 4096, 8192, 4)),
+    ("gated_causal_conv-lfm2_8k", "conv_gated", (4, 8192, 2048, 3)),
 ]
 
 
@@ -244,6 +252,27 @@ def _kernel_fn_and_args(kernel, shape, sharding):
         return (
             lambda q, k, v, g, beta: gated_delta._chunked(prepare, q, k, v, g, beta, gated_delta.CHUNK)[0]
         ), (qkv[0], qkv[1], spec(shape[:2] + (shape[4], dim)), gates, gates)
+    if kernel in ("conv_silu", "conv_gated", "conv_key_head"):
+        from sav_tpu.models.layers import causal_conv as forms
+        from sav_tpu.ops.causal_conv import conv_form
+
+        batch, seq, channels, width = shape
+        if kernel == "conv_key_head":
+            key_heads, key_ch, value_ch = 16, 128, 256
+            form = conv_form(seq, channels, width, jnp.bfloat16, key_head=(key_ch, value_ch), on_tpu=True)
+            assert form == {"conv": "kernel", "block_s": 1024, "block_c": 512, "reads": "in_place"}
+            return (
+                lambda qkvz, taps: jnp.concatenate(forms._conv_silu_of_key_heads(
+                    qkvz, taps, key_heads, key_ch, value_ch, form["block_s"], False), axis=-1)
+            ), (spec((batch, seq, channels + key_heads * value_ch)), spec((width, channels), jnp.float32))
+        form = conv_form(seq, channels, width, jnp.bfloat16, on_tpu=True)
+        assert form == {"conv": "kernel", "block_s": 1024, "block_c": 512}
+        in_vmem, operand = (
+            (forms._conv_silu_in_vmem, channels) if kernel == "conv_silu" else (forms._gated_conv_in_vmem, 3 * channels)
+        )
+        return (
+            lambda x, taps: in_vmem(x, taps, form["block_s"], form["block_c"], False)
+        ), (spec((batch, seq, operand)), spec((width, channels), jnp.float32))
     if kernel == "fused":
         return (lambda q, k, v: fused_attention(q, k, v, interpret=False)), qkv
     if kernel == "talking_heads":
@@ -298,6 +327,22 @@ def _kernel_vmem(compiled, which: str, field: str = "size") -> list:
         if 'custom_call_target="tpu_custom_call"' in line
     ]
     return [int(found.group(field)) if (found := pattern.search(line)) else None for line in calls]
+
+
+@pytest.mark.parametrize("kernel,shape", [case[1:] for case in KERNEL_CASES if case[1].startswith("conv_")],
+                         ids=[case[0] for case in KERNEL_CASES if case[1].startswith("conv_")])
+def test_causal_conv_kernels_stay_within_their_vmem_limit(one_chip, kernel, shape):
+    """Forward and backward in one program: each Mosaic call is given the
+    module's limit and uses less than half of it (the gated backward, the
+    largest, holds four blocks of 1,024 x 512 twice and stages three twice)."""
+    from sav_tpu.ops import causal_conv
+
+    fn, args = _kernel_fn_and_args(kernel, shape, one_chip)
+    both = lambda *a: jax.value_and_grad(lambda *b: fn(*b).astype(jnp.float32).sum(), argnums=(0, 1))(*a)
+    compiled = jax.jit(both).lower(*args).compile()
+    given, used = (_kernel_vmem(compiled, which) for which in ("scoped_memory_configs", "used_scoped_memory_configs"))
+    assert given == [causal_conv._VMEM_LIMIT] * 2
+    assert all(0 < one <= causal_conv._VMEM_LIMIT // 2 for one in used), used
 
 
 # (name, [B, L, H, D(, Dv)], flash_attention's blocks, the forms the rules pick)

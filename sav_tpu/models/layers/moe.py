@@ -37,6 +37,8 @@ from jax.experimental.pallas.ops.tpu import megablox
 
 from sav_tpu.models.layers.feedforward import GatedFFBlock
 from sav_tpu.ops import _backend
+from sav_tpu.ops import attention as _attention
+from sav_tpu.ops import rows_to_tokens as _sums
 from sav_tpu.ops.quant import quantize_channelwise
 
 Dtype = Any
@@ -171,39 +173,61 @@ class MoEFFBlock(nn.Module):
 
 
 @jax.custom_vjp
-def _rows_of_tokens(x, token, live):
+def _rows_of_tokens(x, token, live, sizes):
     """``x [T, D]`` -> ``[C, D]``: row ``r`` is token ``token[r]``'s where
-    ``live[r]``, zeros elsewhere. Its transpose is :func:`_tokens_of_rows`."""
+    ``live[r]``, zeros elsewhere (``sizes``: the rows' groups, for the
+    transpose). Its transpose is :func:`_tokens_of_rows`, on the cotangent's
+    rows in the dtype they come in."""
     return jnp.where(live[:, None], jnp.take(x, token, axis=0), 0)
 
 
-def _rows_of_tokens_fwd(x, token, live):
-    return _rows_of_tokens(x, token, live), (token, live, x.shape[0])
+def _rows_of_tokens_fwd(x, token, live, sizes):
+    return _rows_of_tokens(x, token, live, sizes), (token, live, sizes, x.shape[0])
 
 
 def _rows_of_tokens_bwd(res, g):
-    token, live, tokens = res
-    return _tokens_of_rows(g.astype(jnp.float32), token, live, tokens).astype(g.dtype), None, None
+    token, live, sizes, tokens = res
+    return _tokens_of_rows(g, None, token, live, sizes, int(tokens), g.dtype), None, None, None
 
 
 _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _tokens_of_rows(rows, token, live, tokens):
-    """``rows [C, D]`` float32 -> ``[tokens, D]``: the sum of the ``live`` rows
-    of each token (what stands in the others is not read: they are handed an
-    index past the end, which a scatter drops). Its transpose is
-    :func:`_rows_of_tokens`."""
-    return jax.ops.segment_sum(rows, jnp.where(live, token, tokens), num_segments=tokens)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _tokens_of_rows(rows, weight, token, live, sizes, tokens, dtype):
+    """``rows [C, D]`` -> ``[tokens, D]`` of ``dtype``: each token's float32
+    sum of its ``live`` rows, each times its ``weight [C]`` float32 where one
+    is given (what stands in the other rows is not read). ``sizes [held]`` are
+    the live rows' groups, inside which ``token`` ascends. On a TPU one Mosaic
+    call (``ops/rows_to_tokens.py``: the rows read once where they lie, in the
+    dtype they have), elsewhere and at shapes Mosaic would not take XLA's scatter-add:
+    :func:`~sav_tpu.ops.rows_to_tokens.sum_form` says which, and the dispatch
+    log notes it. The transpose is :func:`_rows_of_tokens`, XLA's gather."""
+    form = _sums.sum_form(rows.shape[0], tokens, rows.shape[1], sizes.shape[0], rows.dtype)
+    _attention.log_sum_form(
+        rows.shape, tokens, jnp.dtype(rows.dtype).name, jnp.dtype(dtype).name, weight is not None, form)
+    if form["sum"] == "kernel":
+        return _sums.rows_to_tokens(
+            rows, weight, token, live, sizes, tokens=tokens, dtype=jnp.dtype(dtype), tile=form["tile"],
+            unit=form["unit"], interpret=_backend.default_interpret(),
+        )
+    return _sums.sum_xla(rows, weight, token, live, tokens, dtype)
 
 
-def _tokens_of_rows_fwd(rows, token, live, tokens):
-    return _tokens_of_rows(rows, token, live, tokens), (token, live)
+def _tokens_of_rows_fwd(rows, weight, token, live, sizes, tokens, dtype):
+    return _tokens_of_rows(rows, weight, token, live, sizes, tokens, dtype), (rows, weight, token, live, sizes)
 
 
-def _tokens_of_rows_bwd(tokens, res, g):
-    return _rows_of_tokens(g, *res), None, None
+def _tokens_of_rows_bwd(tokens, dtype, res, g):
+    rows, weight, token, live, sizes = res
+    taken = _rows_of_tokens(g, token, live, sizes)
+    if weight is None:
+        return taken.astype(rows.dtype), None, None, None, None
+    # d(float32(row) weight): the gathered cotangent times the other factor,
+    # a dead row's factor masked (zero times what stands there is not zero).
+    taken = taken.astype(jnp.float32)
+    d_weight = jnp.sum(jnp.where(live[:, None], rows.astype(jnp.float32), 0.0) * taken, axis=1)
+    return (weight[:, None] * taken).astype(rows.dtype), d_weight, None, None, None
 
 
 _tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
@@ -368,25 +392,24 @@ def _routed_pass(quant, k, rows, index, x, kernels, weights, order, group_sizes)
     rows)`` add to their tokens (scopes ``dispatch``, ``experts``,
     ``combine``). Their tokens' rows gathered, the held experts on their
     ragged groups, the weighted results summed by token. Rows past the last
-    group are written by nobody: masked before the multiply (zero times what
-    stands there is not zero)."""
+    group are written by nobody, and the sum does not read them."""
     with jax.named_scope("dispatch"):
         routing, live, sizes = _sorted_chunk(order, group_sizes, index, rows)
         token = routing // k
-        taken = _rows_of_tokens(x, token, live)
+        taken = _rows_of_tokens(x, token, live, sizes)
     with jax.named_scope("experts"):
         out = _expert_ffn(taken, kernels, sizes, quant)
     with jax.named_scope("combine"):
-        weighted = jnp.where(live[:, None], out.astype(jnp.float32), 0.0) * jnp.take(weights, routing)[:, None]
-        return _tokens_of_rows(weighted, token, live, x.shape[0])
+        return _tokens_of_rows(out, jnp.take(weights, routing), token, live, sizes, x.shape[0], jnp.float32)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
-def _overflow_pass(quant, k, rows, layer, chunks, x, kernels, weights, order, group_sizes):
-    """``[T, D]`` float32: what passes ``1 .. chunks - 1`` over the sorted
-    routings add (pass 0 is the common one), one :func:`_routed_pass` at a
-    time in a loop of as many trips as there are such passes: none where the
+def _overflow_pass(quant, k, rows, layer, chunks, routed, x, kernels, weights, order, group_sizes):
+    """``[T, D]`` float32: ``routed`` (the common pass's result, pass 0) plus
+    what passes ``1 .. chunks - 1`` over the sorted routings add, one
+    :func:`_routed_pass` at a time in a loop of as many trips as there are
+    such passes, summed onto ``routed`` where it lies: none where the
     held experts' rows fit the common pass. The backward pass is a loop of
     the same trips over the same operands, so what is not taken costs
     nothing in either direction but the zeros its cotangents start from.
@@ -401,11 +424,11 @@ def _overflow_pass(quant, k, rows, layer, chunks, x, kernels, weights, order, gr
             added = _routed_pass(quant, k, rows, index, x, kernels, weights, order, group_sizes)
         return index + 1, y + added
 
-    return jax.lax.while_loop(lambda c: c[0] < chunks, add, (1, jnp.zeros(x.shape, jnp.float32)))[1]
+    return jax.lax.while_loop(lambda c: c[0] < chunks, add, (1, routed))[1]
 
 
-def _overflow_pass_fwd(quant, k, rows, layer, *operands):
-    return _overflow_pass(quant, k, rows, layer, *operands), operands
+def _overflow_pass_fwd(quant, k, rows, layer, chunks, routed, *operands):
+    return _overflow_pass(quant, k, rows, layer, chunks, routed, *operands), (chunks, *operands)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
@@ -422,7 +445,7 @@ def _overflow_pass_bwd(quant, k, rows, layer, res, g):
 
     zeros = jax.tree.map(jnp.zeros_like, (x, kernels, weights))
     grads = jax.lax.while_loop(lambda c: c[0] < chunks, add, (1, zeros))[1]
-    return (None, *grads, None, None)
+    return (None, g, *grads, None, None)
 
 
 _overflow_pass.defvjp(_overflow_pass_fwd, _overflow_pass_bwd)
@@ -554,18 +577,19 @@ class SparseMoEBlock(nn.Module):
         if bound < total:  # else every routing is in the buffers
             with jax.named_scope("overflow"):
                 whole_passes = -(-total // bound) * bound
-                routed = routed + _overflow_pass(
+                routed = _overflow_pass(
                     self.quant, k, bound, self.name or type(self).__name__,
-                    -(-jnp.sum(group_sizes) // bound), x, kernels, weights,
+                    -(-jnp.sum(group_sizes) // bound), routed, x, kernels, weights,
                     jnp.pad(order, (0, whole_passes - total)), group_sizes,
                 )
 
         if self.shared_expert:
             shared = GatedFFBlock(hidden_ch=self.hidden_ch, quant=self.quant, dtype=self.dtype, name="shared")(x)
-            shared = shared.astype(jnp.float32)
-            if self.shared_gate:
-                shared = shared * opened
-            routed = routed + shared
+            with jax.named_scope("shared"):  # its sum onto the routed part is the shared expert's own
+                shared = shared.astype(jnp.float32)
+                if self.shared_gate:
+                    shared = shared * opened
+                routed = routed + shared
         y = routed.astype(self.dtype)
 
         with jax.named_scope("route"):

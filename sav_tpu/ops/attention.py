@@ -472,3 +472,18 @@ def log_conv_form(fused: str, shape, width: int, dtype: str, form: dict) -> None
     _log_once(("causal_conv", fused, shape, width, dtype, form["conv"]), {
         "op": "causal_conv", "fused": fused, "shape": list(shape), "width": width, "dtype": dtype, **form,
     })
+
+
+def log_sum_form(shape, tokens: int, dtype: str, result: str, weighted: bool, form: dict) -> None:
+    """The expert layer's sum of rows by token (``ops/rows_to_tokens.py::
+    sum_form``) in the same log, one a traced shape and caller: ``op``, the
+    ``[C, D]`` shape of the routed buffer, the ``tokens`` summed onto, the
+    rows' ``dtype`` and the ``result``'s, whether a weight a row is multiplied
+    in (``weighted``: ``moe/combine``'s forward; without, ``moe/dispatch``'s
+    backward), and the program: ``sum`` (``kernel`` | ``xla``) with its
+    ``tile`` of tokens and ``unit`` of rows a copy, or with what ``refused``
+    the kernel."""
+    _log_once(("rows_to_tokens", tuple(shape), tokens, dtype, result, weighted, form["sum"]), {
+        "op": "rows_to_tokens", "shape": list(shape), "tokens": tokens, "dtype": dtype, "result": result,
+        "weighted": weighted, **form,
+    })

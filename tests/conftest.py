@@ -13,9 +13,21 @@ os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
 )
 
+# The suite's CPU programs are compiled without LLVM's expensive passes
+# (jax's own switch for "the cost of optimization is greater than that of
+# running a less-optimized program"): toy sizes, where the compile is the
+# time. The HLO and what the tests compare are the same; a third of the
+# suite's CPU seconds go (test_lfm2.py alone: 186 -> 121 s user; PR 41). Set in
+# the environment too, for the children the tests start (train.py,
+# chip_smoke.py). tests/test_tpu_compile.py asks the TPU's compiler at its
+# own settings (its `topo` fixture).
+os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
+
 import jax
 
 jax.config.update("jax_platforms", "cpu")
+if os.environ["JAX_DISABLE_MOST_OPTIMIZATIONS"] == "1":  # (whoever imported jax before this file)
+    jax.config.update("jax_disable_most_optimizations", True)
 
 import pytest
 

@@ -10,6 +10,7 @@ the loop, the sizes of what the layer builds and the scopes its operations
 lie under). ``test_joyai.py`` holds the model, the task and the trainer; the
 toy sizes and the tolerance are its."""
 
+import functools
 import gc
 
 import jax
@@ -24,8 +25,10 @@ from test_joyai import (  # noqa: F401  (fixtures are used by name)
 from benchmark import weights
 from benchmark.reference import joyai as reference
 from sav_tpu.models.layers import LatentSelfAttentionBlock, SparseMoEBlock
+from sav_tpu.models.layers import moe as moe_layers
 from sav_tpu.models.layers.moe import routed_row_bound
-from sav_tpu.ops.attention import xla_attention
+from sav_tpu.ops import rows_to_tokens as sums
+from sav_tpu.ops.attention import snapshot_dispatch_log, xla_attention
 from sav_tpu.ops.flash_attention import flash_attention
 from sav_tpu.ops.rotary import apply_rotary_interleaved
 from sav_tpu.train.tasks import mtp_lm_loss
@@ -134,6 +137,26 @@ def moe_params(params):
     return _routed_at_fan_in_scale(params["layer_1"]["moe"])
 
 
+@pytest.fixture()
+def sum_form_of(monkeypatch):
+    """``sum_form_of("kernel")``: the layer's sum of rows by token takes the
+    form a TPU would (the Mosaic kernel, here in the interpreter) wherever the
+    shapes allow it; ``"xla"`` leaves the CPU's form. The kernel's cases run
+    at a width of their own (whole lane tiles), so no jitted loop traced for
+    one form is reused for the other."""
+    def force(form):
+        if form == "kernel":
+            monkeypatch.setattr(sums, "sum_form", functools.partial(sums.sum_form, on_tpu=True))
+        return 128 if form == "kernel" else 64
+
+    return force
+
+
+def _drawn(layer, x, seed=5):
+    abstract = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x, jnp.zeros((EXPERTS,))))["params"]
+    return _routed_at_fan_in_scale(weights.draw_params(abstract, seed))
+
+
 ROUTINGS = {
     # A large bias on few experts sends every token there; an expert whose
     # bias is -10 sees no token.
@@ -144,10 +167,12 @@ ROUTINGS = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(ROUTINGS))
-def test_sorted_path_matches_the_loop_over_experts(moe_params, case):
+@pytest.mark.parametrize("case,form", [(case, "xla") for case in sorted(ROUTINGS)] + [("uneven", "kernel")])
+def test_sorted_path_matches_the_loop_over_experts(moe_params, sum_form_of, case, form):
     bias = jnp.asarray(ROUTINGS[case], jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(7), (BATCH, SEQ, 64))
+    x = jax.random.normal(jax.random.PRNGKey(7), (BATCH, SEQ, sum_form_of(form)))
+    if form == "kernel":
+        moe_params = _drawn(_moe(), x)
 
     def run(p, x):
         return _moe().apply({"params": p}, x, bias)
@@ -156,6 +181,7 @@ def test_sorted_path_matches_the_loop_over_experts(moe_params, case):
     for b in range(BATCH):
         assert close(y[b], want[b][0]) and np.array_equal(np.asarray(counts[b]), np.asarray(want[b][1]))
     assert float(balance) == pytest.approx(float(np.mean([w[2] for w in want])), rel=1e-5)
+    assert {r["sum"] for r in snapshot_dispatch_log() if r.get("op") == "rows_to_tokens" and r["shape"][1] == x.shape[-1]} == {form}
     if case == "an_expert_with_no_token":
         assert float(jnp.sum(counts[:, 3])) == 0
     if case == "all_tokens_on_the_same_experts":
@@ -221,17 +247,121 @@ def _shapes(jaxpr):
             yield from _shapes(sub)
 
 
-def test_the_expert_path_builds_nothing_of_tokens_by_experts_by_more(moe_params):
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_the_expert_path_builds_nothing_of_tokens_by_experts_by_more(moe_params, sum_form_of, form):
     """No dispatch tensor: beyond the [T, E] scores nothing has both a token
-    axis and an expert axis, and the largest value is [T k, D]."""
-    x = jax.random.normal(jax.random.PRNGKey(7), (BATCH, SEQ, 64))
+    axis and an expert axis, and the largest value is [T k, D], the Mosaic
+    sum's scratch and its few integers included."""
+    x = jax.random.normal(jax.random.PRNGKey(7), (BATCH, SEQ, sum_form_of(form)))
+    if form == "kernel":
+        moe_params = _drawn(_moe(), x)
     jaxpr = jax.make_jaxpr(lambda p, x: _moe().apply({"params": p}, x, jnp.zeros((EXPERTS,))))(moe_params, x)
-    tokens, largest = BATCH * SEQ, BATCH * SEQ * TOP_K * 64
+    tokens, largest = BATCH * SEQ, BATCH * SEQ * TOP_K * x.shape[-1]
+    assert ("pallas_call" in str(jaxpr)) == (form == "kernel")
 
     for shape in _shapes(jaxpr.jaxpr):
         assert int(np.prod(shape)) <= largest, shape
         if EXPERTS in shape and len(shape) >= 2 and tokens in shape:
             assert shape == (tokens, EXPERTS), shape
+
+
+# ------------------------------------------------- the sum of rows by token
+
+
+def _sorted_window(index: int, count: int = 160, tokens: int = 128, groups: int = 4):
+    """A window of ``count`` sorted rows as the layer's one sort leaves them:
+    ``groups`` held experts, inside each the tokens ascending. Token 0 has a
+    row in every group, token 1 in one, token 2 in none, and no row lands in
+    the second tile of 32 tokens; window 1 starts inside the last group's run
+    and ends past it."""
+    picked = np.random.default_rng(3).random((tokens, groups)) < 0.5
+    picked[0], picked[1], picked[2], picked[32:64] = True, [True] + [False] * (groups - 1), False, False
+    order = np.concatenate([np.flatnonzero(picked[:, g]) * groups + g for g in range(groups)])
+    assert count < order.size < 2 * count
+    order = jnp.asarray(np.pad(order, (0, 2 * count - order.size)), jnp.int32)
+    routing, live, sizes = moe_layers._sorted_chunk(order, jnp.asarray(picked.sum(0), jnp.int32), index, count)
+    return routing // groups, live, sizes, picked
+
+
+@pytest.mark.parametrize("rows_of", ["float32_rows", "bfloat16_rows_with_a_weight"])
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_the_sum_of_rows_by_token_is_segment_sum_and_the_gathers_transpose(monkeypatch, sum_form_of, form, rows_of):
+    """Both forms of ``_tokens_of_rows`` against the sum written out and
+    against JAX's own derivative of the gather, in values and in gradients:
+    tokens with no, one and ``k`` live rows, a tile no row lands in, ranges
+    that cross a tile's edge, a window that starts mid-run, and NaN past the
+    last group that must not reach the result."""
+    monkeypatch.setattr(sums, "TOKEN_TILE", 32)  # four tiles of these 128 tokens
+    sum_form_of(form)
+    tokens, dim, dtype = 128, 128, jnp.float32 if rows_of == "float32_rows" else jnp.bfloat16
+    for index in (0, 1):
+        token, live, sizes, picked = _sorted_window(index)
+        keys = jax.random.split(jax.random.PRNGKey(index), 3)
+        rows = jnp.where(live[:, None], jax.random.normal(keys[0], (live.shape[0], dim)), jnp.nan).astype(dtype)
+        weight = None if rows_of == "float32_rows" else jax.random.normal(keys[1], live.shape)
+        got = moe_layers._tokens_of_rows(rows, weight, token, live, sizes, tokens, jnp.float32)
+        terms = jnp.where(live[:, None], rows.astype(jnp.float32) * (1.0 if weight is None else weight[:, None]), 0.0)
+        want = np.zeros((tokens, dim), np.float64)
+        np.add.at(want, np.asarray(token)[np.asarray(live)], np.asarray(terms, np.float64)[np.asarray(live)])
+        assert np.all(np.isfinite(np.asarray(got))) and np.max(np.abs(np.asarray(got) - want)) <= 1e-6 * np.max(np.abs(want))
+        assert not np.any(np.asarray(got[32:64])) and not np.any(np.asarray(got[2]))
+        if index == 0:
+            assert int(jnp.sum(live & (token == 0))) == 4 and int(jnp.sum(live & (token == 1))) == 1
+        else:
+            assert 0 < int(jnp.sum(live)) < live.shape[0] and int(sizes[0]) == 0 and 0 < int(sizes[-1]) < int(jnp.sum(picked[:, -1]))
+        gather = lambda x: jnp.where(live[:, None], jnp.take(x, token, axis=0), 0)
+        (transposed,) = jax.vjp(gather, jnp.zeros((tokens, dim)))[1](terms)
+        assert float(jnp.max(jnp.abs(got - transposed))) <= 1e-6 * float(jnp.max(jnp.abs(transposed)))
+        # The gradients: the cotangent's gathered rows (times the other factor), zeros at dead rows.
+        g = jax.random.normal(keys[2], (tokens, dim))
+        finite = jnp.where(live[:, None], rows, 0)  # (JAX's derivative of the written-out sum would carry the NaN)
+        by_hand = lambda rows, weight: jnp.sum(g * jax.ops.segment_sum(
+            rows.astype(jnp.float32) * (1.0 if weight is None else weight[:, None]), jnp.where(live, token, tokens),
+            num_segments=tokens))
+        layer = lambda rows, weight: jnp.sum(g * moe_layers._tokens_of_rows(rows, weight, token, live, sizes, tokens, jnp.float32))
+        argnums = (0,) if weight is None else (0, 1)
+        for a, b in zip(jax.grad(layer, argnums)(rows, weight), jax.grad(by_hand, argnums)(finite, weight)):
+            assert a.dtype == b.dtype and np.all(np.isfinite(np.asarray(a, np.float32)))
+            assert float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))) <= 1e-2 * float(jnp.max(jnp.abs(b)))
+            assert not np.any(np.asarray(a, np.float32)[~np.asarray(live)])
+        # dispatch's backward: bfloat16 cotangent rows in, their float32 sum cast once.
+        if rows_of != "float32_rows":
+            back = moe_layers._tokens_of_rows(rows, None, token, live, sizes, tokens, jnp.bfloat16)
+            plain = moe_layers._sums.sum_xla(finite, None, token, live, tokens, jnp.bfloat16)
+            assert back.dtype == jnp.bfloat16 and np.array_equal(np.asarray(back, np.float32), np.asarray(plain, np.float32))
+
+
+def test_sum_form_takes_the_kernel_on_a_tpu_at_whole_tiles_and_notes_it():
+    """The rule, from the backend and the shapes alone, and its record in the
+    dispatch log."""
+    from sav_tpu.ops.attention import clear_dispatch_log
+
+    assert sums.sum_form(32768, 32768, 2048, 8, jnp.bfloat16, on_tpu=True) == {"sum": "kernel", "tile": 512, "unit": 16}
+    assert sums.sum_form(8192, 8192, 3584, 8, jnp.float32, on_tpu=True)["tile"] == 512
+    assert sums.sum_form(8192, 8192, 8192, 8, jnp.float32, on_tpu=True)["tile"] == 256  # no more than 2 M elements
+    assert sums.sum_form(512, 96, 128, 4, jnp.float32, on_tpu=True)["tile"] == 32
+    refusals = {
+        "non-TPU backend": sums.sum_form(32768, 32768, 2048, 8, jnp.bfloat16, on_tpu=False),
+        "lane tiles": sums.sum_form(256, 64, 64, 16, jnp.float32, on_tpu=True),
+        "8-row tiles": sums.sum_form(256, 36, 128, 16, jnp.float32, on_tpu=True),
+        "16-row copies": sums.sum_form(24, 8, 128, 16, jnp.float32, on_tpu=True),  # init's handful of rows
+        "float16": sums.sum_form(256, 64, 128, 16, jnp.float16, on_tpu=True),
+        "scalars in SMEM": sums.sum_form(1 << 17, 1 << 15, 128, 16, jnp.float32, on_tpu=True),
+    }
+    for why, form in refusals.items():
+        assert form["sum"] == "xla" and why in form["refused"], form
+    assert sums.sum_form(256, 64, 128, 16, jnp.float32)["refused"] == "non-TPU backend"  # this process's backend
+    clear_dispatch_log()
+    token, live, sizes, _ = _sorted_window(0)
+    rows = jnp.zeros((live.shape[0], 128), jnp.bfloat16)
+    moe_layers._tokens_of_rows(rows, jnp.ones(live.shape), token, live, sizes, 128, jnp.float32)
+    moe_layers._tokens_of_rows(rows, None, token, live, sizes, 128, jnp.bfloat16)
+    records = [r for r in snapshot_dispatch_log() if r.get("op") == "rows_to_tokens"]
+    assert records == [
+        {"op": "rows_to_tokens", "shape": [160, 128], "tokens": 128, "dtype": "bfloat16", "result": result,
+         "weighted": weighted, "sum": "xla", "refused": "non-TPU backend"}
+        for result, weighted in (("float32", True), ("bfloat16", False))
+    ]
 
 
 # ----------------------------------------- a share held: bound and overflow
@@ -242,10 +372,7 @@ BOUND = 512
 
 
 def _held_params(held=HELD, seed=5):
-    layer = _moe(held)
-    x = jnp.zeros((WIDE_BATCH, WIDE_SEQ, 64))
-    abstract = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x, jnp.zeros((EXPERTS,))))["params"]
-    return _routed_at_fan_in_scale(weights.draw_params(abstract, seed))
+    return _drawn(_moe(held), jnp.zeros((WIDE_BATCH, WIDE_SEQ, 64)), seed)
 
 
 def _steered(on_all_held: int, on_one_held: int):
@@ -280,16 +407,18 @@ OVERFLOWS = {
 }
 
 
-def test_three_chunks_the_last_partly_filled_match_the_loop():
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_three_chunks_the_last_partly_filled_match_the_loop(sum_form_of, form):
     """320 tokens x 2 routings of which 2 of 16 experts are held: buffers of
     one tile (256 rows) for 640 routings, all of them on the held experts:
-    the common pass, a whole chunk of the overflow pass and half a chunk."""
+    the common pass, a whole chunk of the overflow pass and half a chunk.
+    With the kernel form the sums inside both loops are the Mosaic call's,
+    their window's offset a traced operand."""
     held, k, tokens = (4, 2), 2, 320
     assert routed_row_bound(tokens * k, held[1], EXPERTS) == 256
-    x = jax.random.normal(jax.random.PRNGKey(7), (2, tokens // 2, 64))
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, tokens // 2, sum_form_of(form)))
     layer = _moe(held, k=k)
-    abstract = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x, jnp.zeros((EXPERTS,))))["params"]
-    p = _routed_at_fan_in_scale(weights.draw_params(abstract, 5))
+    p = _drawn(layer, x)
     bias = jnp.asarray(np.where((np.arange(EXPERTS) >= 4) & (np.arange(EXPERTS) < 6), 10.0, 0.0), jnp.float32)
     g = jax.random.normal(jax.random.PRNGKey(8), x.shape)
 
@@ -385,14 +514,27 @@ def test_with_a_sixteenth_held_nothing_is_larger_than_the_bound(what):
     assert largest == bound * dim == tokens * dim
 
 
-def test_the_common_pass_lies_under_its_scopes_and_in_no_loop_of_the_overflow_pass():
+def _calls_by_scope(jaxpr, above=()):
+    """The name stacks, outermost first, down to every ``pallas_call``."""
+    for eqn in jaxpr.eqns:
+        here = above + (str(eqn.source_info.name_stack), str(eqn.params.get("name", "")) * (eqn.primitive.name == "jit"))
+        if eqn.primitive.name == "pallas_call":
+            yield "/".join(filter(None, here))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _calls_by_scope(sub, here)
+
+
+def test_the_common_pass_lies_under_its_scopes_and_in_no_loop_of_the_overflow_pass(sum_form_of):
     """What the benchmark's share and roofline readers match, on the CPU
     lowering of a held layer's gradient: every operation of the layer lies
     under ``moe`` directly followed by ``route``, ``dispatch``, ``experts``
     (its matmuls then under ``fc1`` or ``fc2``), ``combine``, ``overflow`` or
     ``shared``; the common pass is in no conditional and its only loop is
     ``searchsorted``'s, and the overflow pass's loops lie under ``overflow``,
-    their bodies under the layer's label and the common pass's scopes again."""
+    their bodies under the layer's label and the common pass's scopes again.
+    In the form a TPU takes, the sums by token are Mosaic calls named
+    ``rows_to_tokens`` under the same scopes: ``combine`` forward,
+    ``dispatch`` backward, in the common pass and in a trip of either loop."""
     import flax.linen as nn
 
     from benchmark import tracered
@@ -449,6 +591,17 @@ def test_the_common_pass_lies_under_its_scopes_and_in_no_loop_of_the_overflow_pa
         assert inner[0] in ("dispatch", "experts", "combine"), name
         if inner[0] == "experts":
             assert inner[1] in ("fc1", "fc2"), name
+    # The form a TPU takes, read from the jaxpr (a Mosaic call lowers for no CPU).
+    x = jax.random.normal(jax.random.PRNGKey(7), (WIDE_BATCH, WIDE_SEQ, sum_form_of("kernel")))
+    p = _drawn(_moe(HELD), x)
+    grad = jax.value_and_grad(lambda p, x: jnp.sum(Layer().apply({"params": {"moe": p}}, x, bias)[0]), (0, 1))
+    calls = list(_calls_by_scope(jax.make_jaxpr(grad)(p, x).jaxpr))
+    assert calls and all(name.endswith("rows_to_tokens") and "/moe/" in name for name in calls), calls
+    where = {("/overflow/" in name, "combine" if "combine" in name else "dispatch" if "dispatch" in name else None,
+              name.startswith("transpose")) for name in calls}
+    # (A backward trip forms the forward's sum under ``combine`` again; nobody reads it and XLA drops it.)
+    assert where == {(False, "combine", False), (False, "dispatch", True), (True, "combine", False),
+                     (True, "combine", True), (True, "dispatch", True)}, calls
 
 
 def test_the_routed_leaves_are_what_the_expert_rule_places(params):

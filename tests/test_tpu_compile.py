@@ -44,7 +44,12 @@ def topo():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    # The suite compiles its CPU programs with most optimizations off
+    # (conftest.py); the TPU's compiler is asked at its own settings.
+    unoptimized = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
     yield desc
+    jax.config.update("jax_disable_most_optimizations", unoptimized)
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
 
@@ -617,17 +622,51 @@ def test_looped_lm_optimizer_is_one_pass_over_each_parameter(topo, compiled_kern
     _assert_one_pass_optimizer(*_looped_lm_step(topo, monkeypatch))
 
 
+# The four expert cells' sums: (rows of the routed buffer, width, tokens, experts held).
+SUM_SHAPES = {"lfm2": (32768, 2048, 32768, 8), "xing": (8192, 3584, 8192, 8),
+              "qwen3next": (20480, 2048, 16384, 32), "joyai": (8192, 2048, 8192, 16)}
+
+
+@pytest.mark.parametrize("rows_dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("cell", sorted(SUM_SHAPES))
+def test_the_sum_of_rows_by_token_compiles_at_the_expert_cells_shapes(one_chip, cell, rows_dtype):
+    """``ops/rows_to_tokens.py`` at each expert cell's ``[C, D] -> [T, D]``,
+    for both callers (a weight a row and a float32 result: ``moe/combine``;
+    neither: ``moe/dispatch``'s backward), inside the VMEM it asks for: one
+    Mosaic call, the buffer left in HBM, no scatter."""
+    from sav_tpu.ops import rows_to_tokens as sums
+
+    count, dim, tokens, held = SUM_SHAPES[cell]
+    form = sums.sum_form(count, tokens, dim, held, rows_dtype, on_tpu=True)
+    assert form == {"sum": "kernel", "tile": 512, "unit": 16}
+    at = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    for weight, result in ((at((count,), jnp.float32), jnp.float32), (None, jnp.dtype(rows_dtype))):
+        call = functools.partial(sums.rows_to_tokens, tokens=tokens, dtype=result, tile=form["tile"], unit=form["unit"],
+                                 interpret=False)
+        compiled = jax.jit(call).lower(
+            at((count, dim), rows_dtype), weight, at((count,), jnp.int32), at((count,), jnp.bool_), at((held,), jnp.int32)
+        ).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "scatter" not in text
+        # What the call holds in VMEM: two staging slots, a float32 copy of one, the sum, the result twice.
+        held_in_vmem = (512 + 16) * dim * (2 * jnp.dtype(rows_dtype).itemsize + 4 * (rows_dtype != "float32")) \
+            + 512 * dim * (4 + 2 * jnp.dtype(result).itemsize)
+        assert held_in_vmem < 0.7 * sums._VMEM_LIMIT
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20  # a few thousand integers beside the call
+
+
 def test_a_sixteenth_of_the_experts_held_compiles_with_bounded_buffers(one_chip, monkeypatch):
     """The expert layer at the expert cell's shapes (8,192 tokens x 8 of 256
-    experts, 16 held), forward and backward: the grouped matmuls are Mosaic
-    calls on the 8,192 rows of the bound, nothing has the 65,536 rows of
-    every routing, and the overflow pass is one loop each way, whose body
-    holds the same nine calls."""
+    experts, 16 held), forward and backward: the grouped matmuls and the sums
+    of rows by token are Mosaic calls on the 8,192 rows of the bound, nothing
+    has the 65,536 rows of every routing, no scatter of rows is left, and the
+    overflow pass is one loop each way, whose body holds the same calls."""
     from sav_tpu.models.layers import SparseMoEBlock
     from sav_tpu.models.layers.moe import routed_row_bound
-    from sav_tpu.ops import _backend
+    from sav_tpu.ops import _backend, attention
 
     monkeypatch.setattr(_backend, "default_interpret", lambda: False)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     assert routed_row_bound(8192 * 8, 16, 256) == 8192
     layer = SparseMoEBlock(
         num_experts=256, top_k=8, hidden_ch=768, routed_scale=2.5, experts_held=(0, 16), dtype=jnp.bfloat16
@@ -646,15 +685,31 @@ def test_a_sixteenth_of_the_experts_held_compiles_with_bounded_buffers(one_chip,
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     common = [line for line in calls if "/moe/overflow/" not in line and "/overflow/while" not in line]
-    assert len(common) == 9  # gate, up, down: forward, and two transposes each
-    assert all("/experts/fc" in line for line in common)
-    # The loop's: three in the forward loop; in the backward loop the three again and their six transposes.
-    # They are named as the common pass's, under the layer's label opened again inside the body.
+    # gate, up, down: forward, and two transposes each; the sum back to tokens under ``combine``
+    # (forward) and under ``dispatch``'s transpose (backward: the gather's cotangent rows).
+    matmuls = [line for line in common if "/experts/fc" in line]
+    sums = [line for line in common if "rows_to_tokens" in line]
+    assert len(matmuls) == 9 and len(sums) == 2 and len(common) == 11
+    assert sum("jvp(" in line and "/combine/" in line for line in sums) == 1
+    assert sum("transpose(jvp(" in line and "/dispatch/" in line for line in sums) == 1
+    # The loop's: three matmuls and the sum under ``combine`` in the forward loop; in the backward loop the
+    # three matmuls again, their six transposes and the sum under ``dispatch`` (nobody reads the forward's
+    # sum there). They are named as the common pass's, under the layer's label opened again inside the body.
     looped = set(calls) - set(common)
-    assert len(calls) - len(common) == 12 and all("overflow/while/body/SparseMoEBlock/" in line for line in looped)
-    assert all(re.search(r"experts\)*/fc[12]/", line) for line in looped)
+    assert len(calls) - len(common) == 14 and all("overflow/while/body/SparseMoEBlock/" in line for line in looped)
+    looped_sums = [line for line in looped if "rows_to_tokens" in line]
+    assert len(looped_sums) == 2
+    assert sum(bool(re.search(r"[/(]combine\)*/jit\(rows_to_tokens\)", line)) for line in looped_sums) == 1
+    assert sum(bool(re.search(r"[/(]dispatch\)*/jit\(rows_to_tokens\)", line)) for line in looped_sums) == 1
+    assert all(re.search(r"experts\)*/fc[12]/", line) for line in looped - set(looped_sums))
     assert "[8192,2048]" in text and "[65536,2048]" not in text and "[8192,8,2048]" not in text
     assert " conditional(" not in text
+    # No scatter of [.., 2048] rows is left anywhere: the only sums by token are the calls. What is left
+    # under ``combine`` is the transpose of ``take(weights, routing)``: 8,192 floats into the 65,536 weights.
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert not [line for line in scatters if ",2048]" in line.split(" scatter(")[0]], scatters
+    in_scope = [line for line in scatters if re.search(r"[/(](combine|dispatch)\)*/", line)]
+    assert in_scope and all("= f32[65536]{" in line and "jit(_take)/scatter-add" in line for line in in_scope), in_scope
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 

@@ -4,10 +4,11 @@ grouped matmul to run, and what the sorted path costs beside a dense loop.
 
     python tools/moe_micro.py [--tokens 8192] [--dim 2048] [--width 768]
         [--experts 256] [--held 16] [--top-k 8] [--skip-tilings] [--out chiprun_out/moe_micro.json]
+    python tools/moe_micro.py --sum-only [--seed 0]      # reading 6 alone, at the four cells' shapes
 
-Five readings, each forward and forward + backward (the fifth forward
-alone), minimum over rounds of the mean of ``--iters`` calls (host clock to
-``block_until_ready``):
+Six readings, each forward and forward + backward (the fifth and the sixth
+forward alone), minimum over rounds of the mean of ``--iters`` calls (host
+clock to ``block_until_ready``):
 
 1. the grouped matmul alone on ``[tokens x k, dim] x [held, dim, width]``
    with uniform groups (``tokens x k / experts`` rows each, the rest of the
@@ -26,7 +27,13 @@ alone), minimum over rounds of the mean of ``--iters`` calls (host clock to
    ``[tokens, dim]`` float32, on ids as the sort leaves them (ascending
    within each expert's group, half of the buffer live): a scatter-add, a
    sort of the ids with a sorted segment sum, a Mosaic kernel that adds one
-   row a grid step; and the gather of as many rows, their transpose.
+   row a grid step; and the gather of as many rows, their transpose;
+6. the sum the layer runs (``ops/rows_to_tokens.py``), the kernel against
+   ``segment_sum``, at the four expert cells' shapes and at a live share of
+   0.5 and 1.0 of the buffer, for both callers: ``moe/combine``'s forward
+   (bfloat16 rows, a float32 weight a row, a float32 result) and
+   ``moe/dispatch``'s backward (bfloat16 rows, a bfloat16 result); the dead
+   rows hold NaN, which must not reach either result; ``tile_bounds`` alone.
 
 Not a benchmark: numbers for PERF.md's findings and for the tiling constant
 in ``sav_tpu/models/layers/moe.py``.
@@ -35,6 +42,7 @@ in ``sav_tpu/models/layers/moe.py``.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -50,6 +58,7 @@ if _REPO_ROOT not in sys.path:
 from jax.experimental.pallas.ops.tpu import megablox  # noqa: E402
 
 from sav_tpu.models.layers import moe  # noqa: E402
+from sav_tpu.ops import rows_to_tokens as sums  # noqa: E402
 
 TILINGS = [(128, 128, 128), (256, 512, 256), (512, 512, 256), (512, 1024, 256), (512, 2048, 256),
            (512, 1024, 384), (256, 1024, 768), (512, 768, 512),
@@ -130,6 +139,66 @@ def mosaic_sum(rows, ids, live, tokens):
     return out[:tokens, 0]
 
 
+# (cell, rows of the routed buffer, width, tokens, experts held): reading 6's shapes.
+SUM_SHAPES = [("lfm2.train_ep8_8k", 32768, 2048, 32768, 8), ("xing.train_ep8_4k", 8192, 3584, 8192, 8),
+              ("qwen3next.train_ep16_4k", 20480, 2048, 16384, 32), ("joyai.train_ep16_4k", 8192, 2048, 8192, 16)]
+
+
+def sorted_groups(keys, tokens: int, group: int, count: int):
+    """``(token [count], live [count], sizes)`` as the layer's sort leaves a
+    buffer: a group of ``group`` ascending tokens a key, the live rows first."""
+    token = jnp.concatenate(
+        [jnp.sort(jax.random.permutation(k, tokens)[:group]) for k in keys]
+        + [jnp.zeros((count - group * len(keys),), jnp.int32)]).astype(jnp.int32)
+    return token, jnp.arange(count) < group * len(keys), jnp.full((len(keys),), group, jnp.int32)
+
+
+def write(report: dict, out: str) -> None:
+    os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
+    with open(out, "w") as f:
+        json.dump(report, f, indent=2)
+
+
+segment_sum = jax.jit(sums.sum_xla, static_argnames=("tokens", "dtype"))
+tile_bounds = jax.jit(sums.tile_bounds, static_argnames=("tile", "tokens"))
+
+
+def sum_alone(seed: int, iters: int, rounds: int) -> dict:
+    """Reading 6: operands as the sort leaves them (``held`` groups of
+    ascending tokens, the live rows first); the kernel where ``sum_form``
+    takes the backend and the shape."""
+    report = {}
+    for cell, count, dim, tokens, held in SUM_SHAPES:
+        for share in (0.5, 1.0):
+            keys = jax.random.split(jax.random.PRNGKey(seed), held + 2)
+            group = int(count * share) // held
+            token, live, sizes = sorted_groups(keys[:held], tokens, group, count)
+            rows = jnp.where(live[:, None], jax.random.normal(keys[-1], (count, dim), jnp.bfloat16), jnp.nan)
+            weight = jax.random.normal(keys[-2], (count,), jnp.float32)
+            form = sums.sum_form(count, tokens, dim, held, rows.dtype)
+            entry = {"form": form, "live_rows": group * held}
+            for caller, w, dtype in (("combine_forward", weight, jnp.float32), ("dispatch_backward", None, jnp.bfloat16)):
+                xla = functools.partial(segment_sum, tokens=tokens, dtype=jnp.dtype(dtype))
+                entry[caller] = {"segment_sum_ms": timed(xla, (rows, w, token, live), iters, rounds)}
+                if form["sum"] != "kernel":
+                    continue
+                kernel = functools.partial(sums.rows_to_tokens, tokens=tokens, dtype=jnp.dtype(dtype), tile=form["tile"],
+                                           unit=form["unit"], interpret=False)
+                ms = timed(kernel, (rows, w, token, live, sizes), iters, rounds)
+                entry[caller]["kernel_ms"] = ms
+                if isinstance(ms, float):
+                    got = kernel(rows, w, token, live, sizes).astype(jnp.float32)
+                    want = xla(rows, w, token, live).astype(jnp.float32)
+                    entry[caller]["max_abs_diff"] = float(jnp.max(jnp.abs(got - want)))
+                    entry[caller]["max_abs"] = float(jnp.max(jnp.abs(want)))
+            if form["sum"] == "kernel":
+                bounds = functools.partial(tile_bounds, tile=form["tile"], tokens=tokens)
+                entry["tile_bounds_ms"] = timed(bounds, (token, live, sizes), iters, rounds)
+            report[f"{cell} [{count}, {dim}] -> {tokens}, live {share}"] = entry
+            print(cell, share, json.dumps(entry), flush=True)
+    return report
+
+
 def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--tokens", type=int, default=8192)
@@ -141,8 +210,15 @@ def main(argv=None):
     p.add_argument("--iters", type=int, default=10)
     p.add_argument("--rounds", type=int, default=3)
     p.add_argument("--skip-tilings", action="store_true", help="leave out reading 1's megablox tilings")
+    p.add_argument("--sum-only", action="store_true", help="reading 6 alone, at the four cells' shapes")
+    p.add_argument("--seed", type=int, default=0, help="reading 6's operands")
     p.add_argument("--out", default="chiprun_out/moe_micro.json")
     args = p.parse_args(argv)
+
+    if args.sum_only:
+        write({"device": jax.devices()[0].device_kind, "seed": args.seed,
+               "sum_alone": sum_alone(args.seed, args.iters, args.rounds)}, args.out)
+        return 0
 
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     rows_n = args.tokens * args.top_k
@@ -241,17 +317,14 @@ def main(argv=None):
     bound = moe.routed_row_bound(rows_n, args.held, args.experts)
     report["bound"] = bound
     group = bound // (2 * args.held)
-    ids = jnp.concatenate([
-        jnp.sort(jax.random.permutation(k, args.tokens)[:group])
-        for k in jax.random.split(keys[0], args.held)
-    ] + [jnp.zeros((bound - group * args.held,), jnp.int32)]).astype(jnp.int32)
-    live = jnp.arange(bound) < group * args.held
+    ids, live, sizes = sorted_groups(jax.random.split(keys[0], args.held), args.tokens, group, bound)
     weighted = jax.random.normal(keys[1], (bound, args.dim), jnp.float32)
     candidates = {
-        "scatter_add": jax.jit(lambda rows: moe._tokens_of_rows(rows, ids, live, args.tokens)),
+        "scatter_add": jax.jit(lambda rows: sums.sum_xla(rows, None, ids, live, args.tokens, jnp.float32)),
+        "the_layers_sum": jax.jit(lambda rows: moe._tokens_of_rows(rows, None, ids, live, sizes, args.tokens, jnp.float32)),
         "sort_then_sorted_segment_sum": jax.jit(lambda rows: sorted_segment_sum(rows, ids, live, args.tokens)),
         "mosaic_row_a_step": jax.jit(lambda rows: mosaic_sum(rows, ids, live, args.tokens)),
-        "gather_of_as_many_rows": jax.jit(lambda rows: moe._rows_of_tokens(rows[: args.tokens], ids, live)),
+        "gather_of_as_many_rows": jax.jit(lambda rows: moe._rows_of_tokens(rows[: args.tokens], ids, live, sizes)),
     }
     want = candidates["scatter_add"](weighted)
     report["back_to_tokens"] = {}
@@ -263,9 +336,8 @@ def main(argv=None):
         report["back_to_tokens"][name] = entry
         print(name, entry, flush=True)
 
-    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
-    with open(args.out, "w") as f:
-        json.dump(report, f, indent=2)
+    report["sum_alone"] = sum_alone(args.seed, args.iters, args.rounds)
+    write(report, args.out)
     print(json.dumps(report))
     return 0
 

@@ -12,12 +12,10 @@ Two complementary layers:
   violations fail CI. Stdlib-only — importing this layer never imports
   jax, so the linter works in device-free contexts (pre-commit, CI
   frontends).
-- **Runtime sanitizers** (:mod:`sav_tpu.analysis.sanitize`) — opt-in
-  hard-fail guards for the invariants statics cannot see:
+- **Runtime sanitizer** (:mod:`sav_tpu.analysis.sanitize`) — an opt-in
+  hard-fail guard for the invariant statics cannot see:
   ``jax.transfer_guard("disallow")`` armed around the steady-state hot
-  loop, and a retrace sanitizer that aborts the run the moment the step
-  function re-traces after warmup. Wired through
-  ``TrainConfig.sanitize`` / ``train.py --sanitize``.
+  loop. Wired through ``TrainConfig.sanitize`` / ``train.py --sanitize``.
 
 See docs/static_analysis.md for the rule catalogue, pragma/baseline
 workflow, and how to add a rule.

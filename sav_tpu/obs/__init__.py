@@ -15,8 +15,7 @@ Five signals, one design rule each:
 - :mod:`sav_tpu.obs.goodput` — wall-time ledger splitting a run into
   compile / step / input-wait / eval / checkpoint / stall buckets, with
   per-window anomaly flags for transient slowdowns.
-- :mod:`sav_tpu.obs.memory` — HBM telemetry from ``device.memory_stats()``
-  plus a retrace counter that makes silent recompilation visible.
+- :mod:`sav_tpu.obs.memory` — HBM telemetry from ``device.memory_stats()``.
 - :mod:`sav_tpu.obs.watchdog` — heartbeat thread that turns a steady-state
   hang (a step that never completes) into
   a stack dump + labeled exit instead of a job that stalls forever.
@@ -55,7 +54,6 @@ _EXPORTS = {
     "SpanTracer": "sav_tpu.obs.spans",
     "GoodputLedger": "sav_tpu.obs.goodput",
     "hbm_stats": "sav_tpu.obs.memory",
-    "RetraceCounter": "sav_tpu.obs.memory",
     "HangWatchdog": "sav_tpu.obs.watchdog",
     "WATCHDOG_EXIT_CODE": "sav_tpu.obs.watchdog",
     "StepCost": "sav_tpu.obs.costs",

@@ -30,14 +30,17 @@ from __future__ import annotations
 
 import dataclasses
 import sys
+import time
 import warnings
 from typing import Any, Callable, Optional
 
 import jax
 
+from sav_tpu.obs import compile_log
 from sav_tpu.obs.costs import (
     publish_cost_gauges,
     publish_mfu_gauges,
+    resolve_peak_flops,
     train_step_cost,
 )
 from sav_tpu.obs.fleet import (
@@ -47,7 +50,7 @@ from sav_tpu.obs.fleet import (
     write_fleet_manifest,
 )
 from sav_tpu.obs.memdump import HbmWatermark
-from sav_tpu.obs.memory import RetraceCounter, hbm_stats
+from sav_tpu.obs.memory import hbm_stats
 from sav_tpu.utils.flops import compiled_flops
 
 
@@ -73,7 +76,7 @@ class FitObserver:
     """
 
     def compiled(self, executable) -> None:
-        """The step was compiled ahead of time (the ``use_aot`` side only)."""
+        """The step's executable, once a ``fit``, before its first dispatch."""
 
     def before_step(self, step: int, state) -> None:
         """Top of an iteration, before the batch is waited for."""
@@ -302,11 +305,11 @@ class _Autoprof(FitObserver):
     incidents. Each finished capture is machine-read on the spot
     (obs/traceview.py) against the compiled step's HLO metadata."""
 
-    def __init__(self, cfg, run, process_index, predicted, step_fn):
+    def __init__(self, cfg, run, process_index, predicted):
         from sav_tpu.obs.autoprof import AutoProfiler
 
-        self._run, self._step_fn = run, step_fn
-        self._executable = self._abstract = None
+        self._run = run
+        self._executable = None
         self._op_index_memo: list = []
         self.profiler = AutoProfiler(
             run.obs_dir,
@@ -317,23 +320,18 @@ class _Autoprof(FitObserver):
             op_index_fn=self._op_index,
         )
         # The predicted side of every capture's measured-vs-predicted table
-        # (analytic even when the AOT path upgrades the total: same keys).
+        # (analytic even once XLA's count replaces the total: same keys).
         self.profiler.set_predicted(predicted)
 
     def _op_index(self):
-        # {hlo op -> metadata scope} of the compiled step. The AOT
-        # executable's text is free; the jit side lowers and compiles once
-        # from the first step's abstract shapes: bounded post-capture side
-        # work, never steady state. Memoized including failure.
+        # {hlo op -> metadata scope} of the compiled step, from the text of
+        # the executable the loop runs. Memoized including failure.
         if not self._op_index_memo:
             index = None
             try:
                 from sav_tpu.obs.traceview import parse_hlo_op_index
 
-                executable = self._executable
-                if executable is None and self._abstract is not None:
-                    executable = self._step_fn.lower(*self._abstract).compile()
-                text = executable.as_text() if executable is not None else None
+                text = self._executable.as_text()
                 if text:
                     index = parse_hlo_op_index(text)
             except Exception:
@@ -343,16 +341,6 @@ class _Autoprof(FitObserver):
 
     def compiled(self, executable):
         self._executable = executable
-
-    def first_step(self, state, batch, rng):
-        if self._executable is None:
-            # Host metadata only: the donated state's buffers are not kept.
-            self._abstract = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(
-                    x.shape, x.dtype, sharding=getattr(x, "sharding", None)
-                ),
-                (state, batch, rng),
-            )
 
     def before_step(self, step, state):
         # Host-side state machine: starts an armed capture at this step
@@ -424,16 +412,13 @@ class _Fleet(FitObserver):
 
 
 class _Sanitizer(FitObserver):
-    """Runtime sanitizers (analysis/sanitize.py): from the second step on,
-    an implicit host->device transfer or a retrace of the step is a hard
-    error at the step that caused it. The guard is a thread-local context
-    and unwinds on this thread before ``fit`` returns."""
+    """Runtime sanitizer (analysis/sanitize.py): from the second step on,
+    an implicit host->device transfer is a hard error at the step that
+    caused it. The guard is a thread-local context and unwinds on this
+    thread before ``fit`` returns."""
 
     def __init__(self, sanitizer):
         self._sanitizer = sanitizer
-
-    def after_step(self, step):
-        self._sanitizer.check(step)  # no-op until armed
 
     def first_step(self, state, batch, rng):
         self._sanitizer.arm()
@@ -479,13 +464,15 @@ class _Cost(FitObserver):
     """The step's cost model (obs/costs.py) and everything read off it: the
     ``flops/*`` gauges and the ``cost_model`` note up front (a crashed run's
     manifest still says where the FLOPs were going), XLA's exact total once
-    the step is compiled ahead of time, ``mfu`` on every log line, and the
-    end-of-run ``goodput/mfu`` and ``flops_per_s`` from the ledger's own
-    aggregates."""
+    the step is compiled, ``mfu`` on every log line, and the end-of-run
+    ``goodput/mfu`` and ``flops_per_s`` from the ledger's own aggregates.
+    The peak is the config's override, the device table's, or the CPU's
+    labelled fake; a device the table does not list raises here, before the
+    loop."""
 
-    def __init__(self, cfg, run, params, peak):
+    def __init__(self, cfg, run, params):
         self._run = run
-        self._peak_flops, self._peak_source = peak
+        self._peak_flops, self._peak_source = resolve_peak_flops(cfg.peak_flops)
         self.cost = train_step_cost(
             params,
             batch_size=cfg.global_batch_size,
@@ -544,27 +531,33 @@ class _Memory(FitObserver):
     """HBM watermark (obs/memdump.py), sampled at log boundaries (a host-side
     counter read, no sync; {} on the CPU, backfilled once at the exit) and
     stamped into the manifest on every exit path. Under ``diagnostics`` the
-    log line also carries ``hbm_*`` and ``retraces``, the count of silent
-    recompilations of the step since the last boundary."""
+    log line also carries ``hbm_*`` and ``retraces``: the backend compiles
+    the process made (obs/compile_log.py) since the last boundary, or since
+    the first step for the first. The step cannot be among them (the loop
+    calls one executable); an eager op on a new shape, a mid-run eval's
+    first pass or a listener that jits something is."""
 
-    def __init__(self, run, watermark, step_fn, diagnostics):
-        self._run, self._watermark, self._retraces = run, watermark, None
-        if diagnostics:
-            self._retraces = RetraceCounter(step_fn)
+    def __init__(self, run, watermark, diagnostics):
+        self._run, self._watermark = run, watermark
+        self._diagnostics, self._since = diagnostics, None
 
     def first_step(self, state, batch, rng):
-        if self._retraces is not None:
-            # The first dispatch's trace is compilation, not a re-trace.
-            self._retraces.delta()
+        # The step's own compile and the set-up's are behind this point.
+        self._since = time.perf_counter()
 
     def log(self, step, metrics, steps_since, wall_s):
-        if self._retraces is None:
+        if not self._diagnostics:
             self._watermark.observe()
             return
         hbm = hbm_stats()
         metrics.update(hbm)
         self._watermark.observe(hbm)
-        metrics["retraces"] = float(self._retraces.delta())
+        now = time.perf_counter()
+        metrics["retraces"] = float(sum(
+            r["kind"] == "backend"
+            for r in compile_log.log(since=self._since, until=now)
+        ))
+        self._since = now
 
     def exit(self, exc, state, feeder):
         final = self._watermark.finalize()
@@ -605,19 +598,16 @@ class _Manifest(FitObserver):
 
 def build_observers(
     cfg, *, ledger, tracer, manifest, obs_dir: Optional[str],
-    identity: tuple[int, int], step_fn, checkpointer, params,
-    start_step: int, peak: tuple[Optional[float], str], layout: dict,
+    identity: tuple[int, int], checkpointer, params, start_step: int,
+    layout: dict,
 ) -> FitObservers:
     """The observers of one ``fit``, in exit order (module docstring).
 
-    ``step_fn`` is the jitted step (retraces are counted on it, and the
-    profiler lowers it for its HLO text where nothing was compiled ahead of
-    time), ``checkpointer`` the one whose in-flight save the exit and the
-    watchdog drain, ``params`` what the cost model walks, ``peak`` the
-    (FLOP/s, source) pair ``mfu`` is taken against, ``layout`` the manifest's
-    note. The links between observers are wired here, where both ends live:
-    the watchdog's soft stage writes a fleet event and arms the profiler,
-    the stall anomaly arms the profiler, the heartbeat carries the
+    ``checkpointer`` is the one whose in-flight save the exit and the
+    watchdog drain, ``params`` what the cost model walks, ``layout`` the
+    manifest's note. The links between observers are wired here, where both
+    ends live: the watchdog's soft stage writes a fleet event and arms the
+    profiler, the stall anomaly arms the profiler, the heartbeat carries the
     recorder's last incident, the OOM dump reads the watermark and the cost.
     """
     run = _Run(ledger, tracer, manifest, obs_dir, start_step)
@@ -627,7 +617,7 @@ def build_observers(
     manifest_observer = (
         _Manifest(run, layout, watermark) if manifest is not None else None
     )
-    cost = _Cost(cfg, run, params, peak)
+    cost = _Cost(cfg, run, params)
     recorder = writer = autoprof = None
     if cfg.record and fleet_proc == 0:
         from sav_tpu.obs.recorder import FlightRecorder
@@ -640,9 +630,7 @@ def build_observers(
             obs_dir, process_index=fleet_proc, process_count=fleet_procs
         )
     if cfg.autoprof and obs_dir is not None:
-        autoprof = _Autoprof(
-            cfg, run, fleet_proc, cost.cost.attribution, step_fn
-        )
+        autoprof = _Autoprof(cfg, run, fleet_proc, cost.cost.attribution)
 
     observers: list = []
     if recorder is not None:
@@ -680,17 +668,13 @@ def build_observers(
     if writer is not None:
         observers.append(_Fleet(writer, run, recorder))
     if cfg.sanitize:
-        # Keeps its OWN RetraceCounter, so diagnostics' delta() accounting
-        # is undisturbed when both are on.
         from sav_tpu.analysis.sanitize import StepSanitizer
 
-        observers.append(_Sanitizer(
-            StepSanitizer(step_fn, tag="train-sanitize")
-        ))
+        observers.append(_Sanitizer(StepSanitizer()))
     if cfg.sequence_parallel:
         observers.append(_SeqReplication(run))
     observers.append(cost)
-    observers.append(_Memory(run, watermark, step_fn, cfg.diagnostics))
+    observers.append(_Memory(run, watermark, cfg.diagnostics))
     if manifest_observer is not None:
         observers.append(manifest_observer)
     return FitObservers(observers, recorder)

@@ -13,8 +13,6 @@ Outcome taxonomy (:data:`OUTCOMES`):
 
   ok                   — the run completed
   backend_unreachable  — no TPU at startup (utils.device_check, exit 3)
-  retrace              — killed by the retrace sanitizer (steady-state
-                         recompile, sav_tpu.analysis.sanitize)
   hang                 — the hang watchdog fired (obs.watchdog exit 4)
   oom                  — device allocator exhaustion
   nonfinite            — the run died on NaN/Inf numerics
@@ -56,8 +54,7 @@ import time
 from typing import Any, Optional
 
 OUTCOMES = (
-    "ok", "backend_unreachable", "retrace", "hang", "oom", "nonfinite",
-    "error",
+    "ok", "backend_unreachable", "hang", "oom", "nonfinite", "error",
 )
 MANIFEST_SCHEMA = 1
 
@@ -65,18 +62,15 @@ MANIFEST_SCHEMA = 1
 def classify_exception(exc: BaseException) -> str:
     """Map an exception to a manifest outcome.
 
-    Matches on type *names* (not imports) so this stays stdlib-only:
-    ``RetraceSanitizerError`` → ``retrace``; NaN/Inf numerics deaths
-    (``FloatingPointError`` from the trainer's debug_nans asserts,
+    Matches on type names and message text (not imports) so this stays
+    stdlib-only: NaN/Inf numerics deaths (``FloatingPointError`` from the
+    trainer's debug_nans asserts,
     checkify's "nan generated by" errors, "non-finite" messages) →
     ``nonfinite``; allocator exhaustion (``RESOURCE_EXHAUSTED``,
     "out of memory", ``MemoryError``) → ``oom``; everything else →
     ``error``.
     """
-    name = type(exc).__name__
-    if name == "RetraceSanitizerError":
-        return "retrace"
-    text = f"{name}: {exc}".lower()
+    text = f"{type(exc).__name__}: {exc}".lower()
     if (
         isinstance(exc, FloatingPointError)
         or "non-finite" in text

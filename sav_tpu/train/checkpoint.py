@@ -40,13 +40,13 @@ def _tree_paths(tree: Any, prefix: tuple = ()) -> list:
 def detect_opt_layout(paths: list) -> dict:
     """Classify a checkpoint's optimizer-state layout from its tree paths.
 
-    Two config knobs change the opt-state pytree structure and must match
-    the checkpoint at restore (the mismatch otherwise surfaces as an
-    opaque tree-structure error):
+    Two things a file from outside may differ in (a mismatch at restore
+    otherwise surfaces as an opaque tree-structure error):
 
-    - ``fused_optimizer``: ``optax.flatten`` stores the Adam moments as
-      ONE flat array per moment — the ``mu``/``nu`` segments are leaves.
-      The per-leaf layout mirrors the parameter tree below them.
+    - the moments' layout: ``optax.flatten`` (what trainers up to PR 41
+      could write) stores the Adam moments as ONE flat array per moment —
+      the ``mu``/``nu`` segments are leaves. The per-leaf layout, the one
+      the trainer builds, mirrors the parameter tree below them.
     - ``ema_decay``: ``track_params_ema`` adds an ``ema`` subtree.
 
     Returns ``{"fused": bool|None, "ema": bool}`` — ``None`` when the
@@ -191,10 +191,9 @@ class Checkpointer:
         partial-tree path (``PyTreeRestore(item=subset, transforms={})``)
         so the opt_state arrays are never read off disk, let alone
         materialized on device — and because opt_state is skipped
-        entirely, flat-buffer and per-leaf moment layouts (the PR-9
-        auto-detect distinction, :func:`detect_opt_layout`) are both
-        accepted without an optimizer rebuild; the probed layout is only
-        logged for provenance.
+        entirely, flat-buffer and per-leaf moment layouts
+        (:func:`detect_opt_layout`) are both accepted; the probed layout is
+        only logged for provenance.
 
         Args:
           template: ``{"params": ..., "batch_stats": ..., "step": ...}``

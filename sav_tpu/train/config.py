@@ -106,13 +106,6 @@ class TrainConfig:
     warmup_epochs: int = 5
     weight_decay: float = 0.05
     clip_grad_norm: Optional[float] = 1.0
-    # Layout of Adam's moments. None = auto: per leaf on every mesh (one
-    # pass over each parameter), unless the checkpoint being restored holds
-    # flat moments, which rebuilds to match it. True = one flat vector
-    # (optax.flatten): the same arithmetic at about three times the bytes on
-    # the TPU, and it cannot shard like its parameters; kept for checkpoints
-    # written with it (PERF.md section 6, PR 29).
-    fused_optimizer: Optional[bool] = None
     label_smoothing: float = 0.1
     # Parameter EMA (e.g. 0.9999): eval runs on the averaged weights (the
     # DeiT/CaiT-recipe standard). Lives in opt_state
@@ -188,7 +181,8 @@ class TrainConfig:
     log_dir: Optional[str] = None
     # In-jit optimization diagnostics folded into the step metrics
     # (param/update norms, update-to-param ratio, per-layer-group grad
-    # norms, nonfinite counts) plus HBM + retrace telemetry at log time.
+    # norms, nonfinite counts) plus, at log time, HBM telemetry and
+    # `retraces`: the compile log's backend compiles since the last line.
     # Rides the existing per-log device_get — zero extra transfers.
     diagnostics: bool = False
     # Host-side span tracer around fit()'s phases; writes a
@@ -226,9 +220,11 @@ class TrainConfig:
     autoprof_max: int = 2
     # Per-chip peak FLOP/s override for MFU/roofline accounting
     # (sav_tpu/obs/costs.py; train.py --peak-flops). None = resolve from
-    # the device-kind table; unknown accelerators then report no MFU, and
-    # CPU resolves to a deterministic fake peak (labeled 'cpu-fake') so
-    # the attribution/MFU plumbing stays assertable in tier-1.
+    # the device-kind table (an accelerator it does not list raises when
+    # fit builds its cost observer); the CPU resolves to a deterministic
+    # fake peak (labeled 'cpu-fake') so the attribution/MFU plumbing stays
+    # assertable in tier-1. The peak is a denominator only: it chooses
+    # nothing about how the step is compiled or dispatched.
     peak_flops: Optional[float] = None
     # Flight recorder (sav_tpu.obs.recorder; docs/incident_replay.md):
     # keep a bounded ring of the last record_depth steps' host-side
@@ -262,14 +258,11 @@ class TrainConfig:
     # knob). On by default: forensics only run when the run is already
     # dead.
     memdump: bool = True
-    # Runtime sanitizers (sav_tpu.analysis.sanitize;
+    # Runtime sanitizer (sav_tpu.analysis.sanitize;
     # docs/static_analysis.md): after the first completed step, arm
     # jax.transfer_guard_host_to_device("disallow") on the training
     # thread (an implicit host->device transfer in the hot loop raises —
-    # the feeder's explicit device_puts on its own thread are exempt)
-    # and hard-fail the run the moment the jitted step re-traces
-    # (RetraceSanitizerError names the step; diagnostics' retrace
-    # metric only reports at the next log window).
+    # the feeder's explicit device_puts on its own thread are exempt).
     sanitize: bool = False
 
     @property

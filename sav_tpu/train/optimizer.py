@@ -122,9 +122,10 @@ def make_optimizer(
     arithmetic element by element on one vector of all parameters. On the
     TPU flattening a tiled matrix to 1-D is a copy, and so are the
     concatenate and the split back, so that layout moves about three times
-    the bytes (PERF.md section 6, PR 29). It stays only for checkpoints
-    whose moments are flat (it changes the optimizer state's layout); the
-    decay mask and the global-norm clip are tree-wise in both.
+    the bytes (PERF.md section 6, PR 29). No trainer builds it (a
+    checkpoint that holds it is refused); it stays for
+    ``benchmark/tests/test_reference.py``, which compares the two (ROADMAP
+    D1). The decay mask and the global-norm clip are tree-wise in both.
     """
     chain = []
     if clip_grad_norm is not None:

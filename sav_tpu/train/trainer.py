@@ -256,10 +256,14 @@ class Trainer:
             num_epochs=config.num_epochs,
             end_lr=config.end_lr,
         )
-        # Auto (None) is the per-leaf chain on every mesh: one pass over
-        # each parameter. The flat layout is built only when asked for by
-        # name, or by restore_or_init for a checkpoint that holds it.
-        self._build_optimizer(bool(config.fused_optimizer))
+        # The per-leaf chain on every mesh: one pass over each parameter.
+        self.tx = make_optimizer(
+            self.schedule,
+            weight_decay=config.weight_decay,
+            clip_grad_norm=config.clip_grad_norm,
+            ema_decay=config.ema_decay,
+        )
+        self._train_step = jax.jit(self._train_step_impl, donate_argnums=(0,))
         self.checkpointer = checkpointer
         if checkpointer is None and config.checkpoint_dir:
             self.checkpointer = Checkpointer(
@@ -268,26 +272,6 @@ class Trainer:
         self._eval_step = jax.jit(self._eval_step_impl)
         # Goodput ledger summary of the most recent fit() (sav_tpu.obs).
         self.last_goodput: Optional[dict] = None
-
-    def _build_optimizer(self, fused: bool) -> None:
-        """(Re)build the optax chain + the jitted step programs.
-
-        Split out of ``__init__`` so :meth:`restore_or_init` can swap the
-        optimizer *layout* (per-leaf vs flat Adam moments) to match a
-        probed checkpoint before building the restore template — the
-        arithmetic is the same element by element, the opt-state pytree
-        structure and the bytes the update moves are not (the flat layout's
-        ravel, concatenate and split are copies on the TPU).
-        """
-        self.fused_optimizer = fused
-        self.tx = make_optimizer(
-            self.schedule,
-            weight_decay=self.config.weight_decay,
-            clip_grad_norm=self.config.clip_grad_norm,
-            fused=fused,
-            ema_decay=self.config.ema_decay,
-        )
-        self._train_step = jax.jit(self._train_step_impl, donate_argnums=(0,))
 
     # ------------------------------------------------------------------ init
 
@@ -434,62 +418,23 @@ class Trainer:
             params=params, batch_stats=stats, opt_state=opt_state
         )
 
-    def _match_checkpoint_layout(self) -> None:
-        """Probe the saved opt-state layout and pick the matching
-        optimizer build (docs/elasticity.md).
-
-        The checkpoint knows its layout, so when
-        ``config.fused_optimizer`` is None (auto) the probe's answer wins:
-        auto starts per leaf, and a checkpoint whose moments are flat
-        rebuilds the optimizer to the flat layout and says once that this
-        is the slower one. An *explicit* config that contradicts the
-        checkpoint is kept — the user overrode auto on purpose — but
-        warned about, because the restore is then going to fail with a
-        structure mismatch.
-        """
+    def _check_checkpoint_layout(self) -> None:
+        """Probe the saved opt-state layout (docs/elasticity.md): refuse
+        flat Adam moments, which no optimizer of this tree builds, and say
+        up front when ``config.ema_decay`` contradicts the checkpoint."""
         import logging
 
         layout = self.checkpointer.opt_layout()
-        detected = layout.get("fused")
-        if detected is not None and detected != self.fused_optimizer:
-            pure_data = all(name == "data" for name in self.mesh.axis_names)
-            if self.config.fused_optimizer is None:
-                if detected and not pure_data:
-                    # Flat Adam moments cannot take non-data parameter
-                    # shardings, so a flat-layout checkpoint cannot be
-                    # resumed onto this mesh either way — keep per-leaf
-                    # and let the restore fail loudly.
-                    logging.warning(
-                        "checkpoint uses the flat-buffer optimizer-state "
-                        "layout but the mesh has non-data axes %s (flat "
-                        "moments cannot shard like their parameters); "
-                        "keeping the per-leaf build — restore will fail; "
-                        "resume on the checkpoint's original mesh layout",
-                        list(self.mesh.axis_names),
-                    )
-                    return
-                if detected:
-                    # Auto starts per leaf: said once, where the operator
-                    # who wonders at the slower step will look.
-                    logging.warning(
-                        "checkpoint holds flat Adam moments "
-                        "(fused_optimizer=True, or auto on a data-parallel "
-                        "mesh before PR 29); rebuilding the optimizer to "
-                        "that layout to resume it. optimizer_layout: flat "
-                        "is the slower one on a TPU (its update moves "
-                        "about three times the bytes of the per-leaf "
-                        "layout that fresh runs take)"
-                    )
-                self._build_optimizer(detected)
-            else:
-                logging.warning(
-                    "config.fused_optimizer=%s but the checkpoint's "
-                    "opt-state layout is %s — restore will fail with a "
-                    "structure mismatch unless the flag matches the "
-                    "checkpoint",
-                    self.config.fused_optimizer,
-                    "flat-buffer" if detected else "per-leaf",
-                )
+        if layout.get("fused"):
+            raise ValueError(
+                f"the checkpoint in {self.checkpointer.directory!r} holds "
+                "flat Adam moments (one vector a moment: fused_optimizer="
+                "True, or auto on a data-parallel mesh before PR 29); this "
+                "tree builds the per-leaf layout only. Commit e12e221 (PR "
+                "41) is the last that reads the flat layout: resume there, "
+                "or warm-start from the checkpoint's parameters "
+                "(Trainer.warm_start_from)"
+            )
         if layout.get("ema") is not None and bool(layout.get("ema")) != (
             self.config.ema_decay is not None
         ):
@@ -503,20 +448,19 @@ class Trainer:
 
     def restore_or_init(self) -> TrainState:
         if self.checkpointer is not None and self.checkpointer.latest_step() is not None:
-            # Layout probe BEFORE the template is built: the template's
-            # opt-state structure must match the saved one.
-            self._match_checkpoint_layout()
+            # Before the template is built: a layout that cannot be
+            # restored is named here, not by orbax's structure error.
+            self._check_checkpoint_layout()
         state = self.init_state()
         if self.checkpointer is not None:
             try:
                 restored = self.checkpointer.restore_latest(state)
             except Exception as e:
-                # Only attribute tree/structure mismatches to the optimizer
-                # layout switch (per-leaf vs optax.flatten'd Adam state —
-                # TrainConfig.fused_optimizer); other failures (corrupt
-                # checkpoint, I/O errors) re-raise untouched. Match the
-                # exception type AND an anchored phrase — a bare substring
-                # would false-positive on paths containing 'tree'.
+                # Only attribute tree/structure mismatches to the EMA
+                # knob; other failures (corrupt checkpoint, I/O errors)
+                # re-raise untouched. Match the exception type AND an
+                # anchored phrase — a bare substring would false-positive
+                # on paths containing 'tree'.
                 msg = str(e).lower()
                 mismatch = isinstance(e, (ValueError, TypeError, KeyError)) and any(
                     phrase in msg
@@ -525,15 +469,9 @@ class Trainer:
                 if mismatch:
                     raise RuntimeError(
                         "checkpoint restore failed with a state-structure "
-                        "mismatch; two config knobs change the opt-state "
-                        "layout and must match the checkpoint: (a) "
-                        "--ema-decay (TrainConfig.ema_decay) adds an EMA "
-                        "tree — set it iff the checkpointed run had it; "
-                        "(b) --fused-optimizer/--no-fused-optimizer "
-                        "(TrainConfig.fused_optimizer), where given by "
-                        "name, must be the layout of the checkpoint's "
-                        "Adam moments — leave it unset to follow the "
-                        "checkpoint"
+                        "mismatch; --ema-decay (TrainConfig.ema_decay) adds "
+                        "an EMA tree to the opt-state and must match the "
+                        "checkpoint — set it iff the checkpointed run had it"
                     ) from e
                 raise
             if restored is not None:
@@ -741,14 +679,6 @@ class Trainer:
 
     # ------------------------------------------------------------------ loop
 
-    def _resolve_peak(self) -> tuple[Optional[float], str]:
-        """(per-chip peak FLOP/s, source) for MFU accounting — the
-        config override, the device table, or CPU's deterministic fake
-        (sav_tpu/obs/costs.py)."""
-        from sav_tpu.obs.costs import resolve_peak_flops
-
-        return resolve_peak_flops(self.config.peak_flops)
-
     def train_step(self, state: TrainState, batch: dict, rng: jax.Array):
         return self._train_step(state, self.shard_batch(batch), rng)
 
@@ -766,14 +696,15 @@ class Trainer:
     def compile_train_step(self, state: TrainState, placed: dict, rng):
         """AOT-lower + compile the train step for an already-placed batch.
 
-        Public surface for harnesses that run the compiled executable
-        directly and read its artifacts — XLA cost analysis (bench.py's
-        MFU), HLO metadata for trace attribution
-        (tools/profile_step.py's op index) — instead of poking the
-        private ``_train_step``. Same program as
-        :meth:`train_step_placed`; note AOT compilation does not
-        populate the jit dispatch cache, so mixing the two pays a second
-        compile.
+        What :meth:`fit` calls at its first batch, and the public surface
+        for harnesses that run the compiled executable directly and read
+        its artifacts — XLA cost analysis (bench.py's MFU), HLO metadata
+        for trace attribution (tools/profile_step.py's op index) — instead
+        of poking the private ``_train_step``. Same program as
+        :meth:`train_step_placed`; a second call with the same argument
+        types is answered by jax's in-memory caches, but AOT compilation
+        does not populate the jit dispatch cache, so mixing the two pays a
+        second compile.
         """
         return self._train_step.lower(state, placed, rng).compile()
 
@@ -1023,28 +954,20 @@ class Trainer:
             if cfg.trace_spans and obs_writer else None,
             ledger=ledger,
         )
-        peak_flops, peak_source = self._resolve_peak()
         start_step = int(jax.device_get(state.step))  # savlint: disable=SAV101 -- one-time read before the loop, not per-step
         observers = build_observers(
             cfg, ledger=ledger, tracer=tracer, manifest=manifest,
             obs_dir=obs_dir, identity=(fleet_proc, fleet_procs),
-            step_fn=self._train_step, checkpointer=self.checkpointer,
-            params=state.params, start_step=start_step,
-            peak=(peak_flops, peak_source),
+            checkpointer=self.checkpointer, params=state.params,
+            start_step=start_step,
             layout={
                 **self.layout.describe(self.mesh),
-                "optimizer_layout": "flat" if self.fused_optimizer else "per_leaf",
+                # Always: kept for the readers of manifests that said 'flat'.
+                "optimizer_layout": "per_leaf",
             },
         )
-        # The step is compiled ahead of time ONCE, and the loop calls the
-        # executable (AOT .compile() does not populate the jit dispatch
-        # cache), only when the peak is a real hardware number: under the
-        # CPU fake peak the loop keeps the plain jit dispatch path, whose
-        # retrace behavior the --sanitize and --diagnostics contracts rely
-        # on (ROADMAP D14).
-        use_aot = bool(peak_flops) and peak_source in (
-            "device-table", "override"
-        )
+        # Compiled at the first batch, once a call; the loop calls the
+        # executable, which refuses arguments it was not compiled for.
         compiled_step = None
         t_last = time.time()
         last_logged_step = start_step
@@ -1130,27 +1053,20 @@ class Trainer:
                         "fit/shard_batch", bucket="h2d", step=step + 1
                     ):
                         sharded = self.shard_batch(batch)  # savlint: disable=SAV106 -- the sanctioned serial fallback (async_feed=False)
-                if use_aot and compiled_step is None:
+                if compiled_step is None:
                     with tracer.span(
                         "fit/compile", bucket="compile", in_timeline=True
                     ):
-                        compiled_step = self._train_step.lower(
+                        compiled_step = self.compile_train_step(
                             state, sharded, rng
-                        ).compile()
+                        )
                         observers.compiled(compiled_step)
                     # Don't let compile time pollute the first throughput
                     # and MFU window.
                     t_last = time.time()
-                step_fn = compiled_step if compiled_step is not None else self._train_step
                 t_step = time.perf_counter()
-                # The first jit dispatch blocks through trace and compile:
-                # it is the compile span the AOT path has above.
-                first_jit = step == start_step and compiled_step is None
-                with (
-                    tracer.span("fit/compile", in_timeline=True) if first_jit
-                    else tracer.span("fit/dispatch", step=step + 1)
-                ):
-                    state, metrics = step_fn(state, sharded, rng)
+                with tracer.span("fit/dispatch", step=step + 1):
+                    state, metrics = compiled_step(state, sharded, rng)
                 # Cap dispatch run-ahead the same way evaluate() does: with
                 # the feeder keeping the host fast nothing else blocks
                 # before the log boundary. Waiting on the metrics of the
@@ -1165,13 +1081,7 @@ class Trainer:
                             inflight_metrics.popleft()
                         )
                 observers.after_step(step + 1)
-                dispatch_s = time.perf_counter() - t_step
-                if first_jit:
-                    # Bucketed as compile (it carries one step of device
-                    # time too, noise next to the compile).
-                    ledger.account("compile", dispatch_s)
-                else:
-                    window_s += dispatch_s
+                window_s += time.perf_counter() - t_step
                 if step == start_step:
                     observers.first_step(state, sharded, rng)
                 if cfg.debug_nans:

@@ -4,7 +4,6 @@ answer and the phase span that caused it; its summary counts every moment
 of a thread once; ``fit`` and the serve engine report from it. Records and
 stacks are asserted, never a clock's ratio."""
 
-import itertools
 import json
 import os
 import subprocess
@@ -319,8 +318,9 @@ def test_events_jax_emits_beside_these_are_no_records():
 # ----------------------------------------------------------- fit and its exits
 
 
-@pytest.mark.parametrize("path, overrides", [("jit", {}), ("ahead_of_time", {"peak_flops": 1e12})])
-def test_the_initialiser_and_the_step_name_their_phases(devices, path, overrides):
+@pytest.mark.parametrize("peak, overrides", [("cpu_fake", {}), ("override", {"peak_flops": 1e12})])
+def test_the_initialiser_and_the_step_name_their_phases(devices, peak, overrides):
+    """Whatever the peak's source: it chooses nothing about the step's compile."""
     trainer = _toy_trainer(**overrides)
     t0 = time.perf_counter()
     state = trainer.init_state()
@@ -343,30 +343,39 @@ def test_the_initialiser_and_the_step_name_their_phases(devices, path, overrides
 
 
 def test_a_compile_forced_inside_the_loop_shows_by_name_with_no_cause(devices, tmp_path):
+    """The step compiles once, under its phase; what still can compile
+    inside the loop (here a function a log listener jits at step 4) is a
+    record with its name and no cause."""
     trainer = _toy_trainer(tmp_path)
     state = trainer.init_state()
-    small = fake_data_iterator(batch_size=8, image_size=32, num_classes=10)
-    large = fake_data_iterator(batch_size=16, image_size=32, num_classes=10)
+    late_fn, ones = jax.jit(_fresh("late")), jnp.ones((4, 4))
+
+    def log_fn(m):
+        if m.get("step") == 4 and "loss" in m:
+            late_fn(ones)
+
     t0 = time.perf_counter()
-    _, history = trainer.fit(itertools.chain(itertools.islice(small, 3), large), num_steps=5, state=state)
-    steps = [r for r in _since(t0) if r["fun_name"] == "jit(_train_step_impl)" and r["kind"] == "backend"]
-    assert [r["cause"] for r in steps] == ["sav:fit/compile", None]
+    _, history = trainer.fit(
+        fake_data_iterator(batch_size=8, image_size=32, num_classes=10), num_steps=5, state=state, log_fn=log_fn,
+    )
+    backend = [r for r in _since(t0) if r["kind"] == "backend"]
+    assert [r["cause"] for r in backend if r["fun_name"] == "jit(_train_step_impl)"] == ["sav:fit/compile"]
+    assert [r["cause"] for r in backend if r["fun_name"] == "jit(compile_log_late)"] == [None]
     assert history[-1]["compile/cache_misses"] >= 2
     with open(tmp_path / "goodput.json") as f:
         assert json.load(f)["compile"]["cache_misses"] == history[-1]["compile/cache_misses"]
     with open(tmp_path / "spans.trace.json") as f:
         events = [e for e in json.load(f)["traceEvents"] if e["name"].startswith("sav:compile/")]
-    recompiles = [e for e in events if e["name"] == "sav:compile/backend:jit(_train_step_impl)"]
-    assert [e["args"] for e in recompiles] == [
-        {"cause": "sav:fit/compile", "cache": "off"}, {"cause": None, "cache": "off"},
-    ]
+    by_name = {e["name"]: e for e in events}
+    assert by_name["sav:compile/backend:jit(_train_step_impl)"]["args"] == {"cause": "sav:fit/compile", "cache": "off"}
+    late = by_name["sav:compile/backend:jit(compile_log_late)"]
+    assert late["args"] == {"cause": None, "cache": "off"}
     assert all(e["ph"] == "X" and e["dur"] > 0 for e in events)
-    # On the spans' clock: the recompile lies inside a dispatch span of its thread.
+    # On the spans' clock: the late compile lies inside the log_fn span of its thread.
     with open(tmp_path / "spans.trace.json") as f:
-        dispatches = [e for e in json.load(f)["traceEvents"] if e["name"] == "sav:fit/dispatch"]
-    late = recompiles[1]
+        listeners = [e for e in json.load(f)["traceEvents"] if e["name"] == "sav:fit/log_fn"]
     assert any(d["tid"] == late["tid"] and d["ts"] <= late["ts"] and late["ts"] + late["dur"] <= d["ts"] + d["dur"]
-               for d in dispatches)
+               for d in listeners)
 
 
 def test_the_log_imports_without_jax():

@@ -464,16 +464,13 @@ def test_step_cadence_layout_probe_and_torn_fallback(tmp_path, devices, caplog):
     counts steps SINCE THE LAST SAVE, quantized up to the next log
     boundary (N=3 with log_every=2 saves at 4 and 8 — a step-number
     modulo would misalign to lcm(3,2)=6 and save at 6 only) + writes the
-    resume stamp; (b) a fresh auto-mode trainer starts per leaf on this
-    pure-data mesh, which is the saved layout: the probe leaves it alone
-    and says nothing of a slower layout (the mirror image, a flat
-    checkpoint, is the next test); (c) when the newest step is torn,
+    resume stamp; (b) a fresh trainer restores the per-leaf checkpoint and
+    its probe has nothing to say (the mirror image, a flat checkpoint, is
+    the next test); (c) when the newest step is torn,
     restore falls back to the previous committed one."""
     import jax
 
-    cfg = _smoke_config(
-        tmp_path, checkpoint_every_steps=3, fused_optimizer=False
-    )
+    cfg = _smoke_config(tmp_path, checkpoint_every_steps=3)
     tr = _trainer(cfg)
     state, _ = tr.fit(_synth_iter(), num_steps=10)
     assert tr.checkpointer.all_steps() == [4, 8, 10]
@@ -483,17 +480,12 @@ def test_step_cadence_layout_probe_and_torn_fallback(tmp_path, devices, caplog):
     assert tr.checkpointer.opt_layout() == {"fused": False, "ema": False}
     tr.checkpointer.close()
 
-    # (b) auto mode is per leaf on every mesh, this pure-data one too.
-    cfg2 = _smoke_config(
-        tmp_path, checkpoint_every_steps=3, fused_optimizer=None
-    )
-    tr2 = _trainer(cfg2)
-    assert tr2.fused_optimizer is False
+    # (b) the probe passes the layout every trainer builds, silently.
+    tr2 = _trainer(_smoke_config(tmp_path, checkpoint_every_steps=3))
     with caplog.at_level("WARNING"):
         st = tr2.restore_or_init()
     assert int(jax.device_get(st.step)) == 10
-    assert tr2.fused_optimizer is False
-    assert "optimizer_layout" not in caplog.text
+    assert "checkpoint" not in caplog.text
     # The restored optimizer state actually steps.
     rng = jax.random.fold_in(jax.random.PRNGKey(0), 1)
     st2, m = tr2.train_step(st, next(_synth_iter(10)), rng)
@@ -506,61 +498,42 @@ def test_step_cadence_layout_probe_and_torn_fallback(tmp_path, devices, caplog):
     step_dir = tmp_path / "ckpt" / "10"
     for child in step_dir.iterdir():
         shutil.rmtree(child) if child.is_dir() else child.unlink()
-    cfg3 = _smoke_config(
-        tmp_path, checkpoint_every_steps=3, fused_optimizer=False
-    )
-    tr3 = _trainer(cfg3)
+    tr3 = _trainer(_smoke_config(tmp_path, checkpoint_every_steps=3))
     st3 = tr3.restore_or_init()
     assert int(jax.device_get(st3.step)) == 8
     tr3.checkpointer.close()
 
 
-def test_flat_checkpoint_restores_under_auto_on_the_flat_layout(
-    tmp_path, devices, caplog
-):
-    """The mirror image of (b) above: a checkpoint written with
-    ``fused_optimizer=True`` (what auto chose on a pure-data mesh before
-    PR 29) resumes under auto by rebuilding to the flat layout, steps, and
-    says once that this is the slower layout; the manifest's layout note
-    carries which one the fit ran."""
-    import jax
-
+def test_flat_checkpoint_is_refused_by_name(tmp_path, devices):
+    """The mirror image of (b) above: a checkpoint whose Adam moments are
+    one flat vector each (what ``fused_optimizer=True`` wrote, and auto on
+    a pure-data mesh before PR 29) is refused before any template is
+    built, by an error that names the layout and the last commit that
+    reads it; a fit's manifest says ``per_leaf``, the one layout there is."""
     from sav_tpu.obs.manifest import RunManifest
+    from sav_tpu.train.optimizer import make_optimizer
 
-    cfg = _smoke_config(tmp_path, fused_optimizer=True)
-    tr = _trainer(cfg)
-    assert tr.fused_optimizer is True
-    tr.fit(_synth_iter(), num_steps=2)
+    tr = _trainer(_smoke_config(tmp_path))
+    state = tr.init_state()
+    flat = make_optimizer(tr.schedule, fused=True).init(state.params)
+    tr.checkpointer.save(2, state.replace(step=state.step + 2, opt_state=flat))
+    tr.checkpointer.wait()
     assert tr.checkpointer.opt_layout() == {"fused": True, "ema": False}
     tr.checkpointer.close()
 
-    tr2 = _trainer(_smoke_config(tmp_path, fused_optimizer=None))
-    assert tr2.fused_optimizer is False
-    with caplog.at_level("WARNING"):
-        st = tr2.restore_or_init()
-        assert tr2.fused_optimizer is True
-        assert int(jax.device_get(st.step)) == 2
-        # A second restore of the rebuilt trainer has nothing more to say.
+    tr2 = _trainer(_smoke_config(tmp_path))
+    with pytest.raises(ValueError, match="flat Adam moments.*e12e221"):
         tr2.restore_or_init()
-    slower = [r for r in caplog.records if "optimizer_layout: flat" in r.getMessage()]
-    assert len(slower) == 1 and "slower" in slower[0].getMessage()
-    # Adam's moments came back as one flat vector each.
-    mu = [
-        leaf for path, leaf in jax.tree_util.tree_flatten_with_path(st.opt_state)[0]
-        if any(getattr(k, "name", None) == "mu" for k in path)
-    ]
-    assert len(mu) == 1 and mu[0].ndim == 1
+    with pytest.raises(ValueError, match="per-leaf layout only"):
+        tr2.fit(_synth_iter(), num_steps=4)  # fit restores: refused too
+    tr2.checkpointer.close()
+
     manifest = RunManifest(str(tmp_path / "manifest.json"), kind="train")
     manifest.begin()
-    st2, history = tr2.fit(
-        _synth_iter(2), num_steps=4, state=st, manifest=manifest
-    )
-    assert int(jax.device_get(st2.step)) == 4
-    losses = [h["loss"] for h in history if "loss" in h]
-    assert losses and np.isfinite(losses).all()
+    tr3 = _trainer(_smoke_config(tmp_path, checkpoint_dir=None))
+    tr3.fit(_synth_iter(), num_steps=2, manifest=manifest)
     notes = json.load(open(tmp_path / "manifest.json"))["notes"]
-    assert notes["layout"]["optimizer_layout"] == "flat"
-    tr2.checkpointer.close()
+    assert notes["layout"]["optimizer_layout"] == "per_leaf"
 
 
 def test_secs_cadence_dedupe_and_crash_drain(tmp_path, devices):

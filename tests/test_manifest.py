@@ -138,10 +138,13 @@ def test_concurrent_finalize_single_winner(tmp_path):
 
 
 def test_classify_exception_taxonomy():
-    class RetraceSanitizerError(RuntimeError):
-        pass
-
-    assert classify_exception(RetraceSanitizerError("step 3")) == "retrace"
+    # The executable's refusal of a drifted batch is a plain error: there
+    # is no outcome of its own for it.
+    assert classify_exception(
+        TypeError("Argument types differ from the types for which this "
+                  "computation was compiled.")
+    ) == "error"
+    assert "retrace" not in OUTCOMES
     assert classify_exception(
         RuntimeError("RESOURCE_EXHAUSTED: Out of memory allocating ...")
     ) == "oom"
@@ -160,7 +163,7 @@ def test_classify_exception_taxonomy():
     assert classify_exception(
         ValueError("nan generated by primitive: sub.")
     ) == "nonfinite"
-    for outcome in ("retrace", "oom", "nonfinite", "error"):
+    for outcome in ("oom", "nonfinite", "error"):
         assert outcome in OUTCOMES
 
 

@@ -1,10 +1,7 @@
 """Memory telemetry (sav_tpu/obs/memory.py): hbm_stats degrades to {}
-on backends without memory_stats; RetraceCounter sees new jit traces."""
+on backends without memory_stats."""
 
-import jax
-import jax.numpy as jnp
-
-from sav_tpu.obs.memory import RetraceCounter, hbm_stats
+from sav_tpu.obs.memory import hbm_stats
 
 
 def test_hbm_stats_never_raises_on_cpu():
@@ -38,30 +35,3 @@ def test_hbm_stats_skips_raising_devices():
             raise RuntimeError("backend refused")
 
     assert hbm_stats([Bad()]) == {}
-
-
-def test_retrace_counter_counts_new_traces():
-    @jax.jit
-    def f(x):
-        return x * 2
-
-    f(jnp.ones((2,)))  # first trace
-    counter = RetraceCounter(f)
-    if not counter.active:  # running jax lacks _cache_size(): degrade path
-        assert counter.delta() == 0
-        return
-    assert counter.delta() == 0  # same shape -> cache hit
-    f(jnp.ones((2,)))
-    assert counter.delta() == 0
-    f(jnp.ones((3,)))  # new shape -> retrace
-    assert counter.delta() == 1
-    f(jnp.ones((4, 4)))
-    f(jnp.ones((5, 5)))
-    assert counter.delta() == 2
-    assert counter.delta() == 0  # diffing, not cumulative
-
-
-def test_retrace_counter_degrades_without_cache_size():
-    counter = RetraceCounter(lambda x: x)  # plain function: no _cache_size
-    assert not counter.active
-    assert counter.delta() == 0

@@ -292,8 +292,8 @@ def test_each_step_has_its_wait_and_its_dispatch(toy_fit_events):
     events, _ = toy_fit_events
     count = {name: sum(e["name"] == name for e in events) for name in {e["name"] for e in events}}
     assert count["sav:fit/batch_wait"] == 6
-    # The jit path's first dispatch is the compile span.
-    assert count["sav:fit/compile"] == 1 and count["sav:fit/dispatch"] == 5
+    # One compile, at the first batch; every step is a dispatch.
+    assert count["sav:fit/compile"] == 1 and count["sav:fit/dispatch"] == 6
     # feed_depth 2: the step three back is waited for from the fourth on.
     assert count["sav:fit/run_ahead_wait"] == 3
     assert count["sav:feeder/place"] >= 6

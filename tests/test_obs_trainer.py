@@ -93,7 +93,7 @@ def test_fit_emits_diagnostics_spans_and_goodput(tmp_path, devices):
     # The ledger's wall clock must agree with the caller's stopwatch.
     assert summary["wall_s"] <= wall * 1.05
     assert summary["steps"] == 4
-    assert summary["buckets_s"]["compile"] > 0.0  # first jit dispatch
+    assert summary["buckets_s"]["compile"] > 0.0  # the fit/compile span
     # Serial loop books placement separately from fetch (ISSUE 2): the
     # shard_batch device_put lands in h2d, not input_wait.
     assert summary["buckets_s"]["h2d"] > 0.0
@@ -188,7 +188,8 @@ def test_observer_sees_the_loops_moments_in_order(
     batch = [] if async_feed else [("host_batch",)]
     loop = [e for e in seen if e[0] != "host_batch" or not async_feed]
     assert loop == [
-        ("before_step", 0), *batch, ("after_step", 1), ("first_step",),
+        ("before_step", 0), *batch, ("compiled",), ("after_step", 1),
+        ("first_step",),
         ("before_step", 1), *batch, ("after_step", 2),
         ("log", 2, 2), ("logged", 2),
         ("before_step", 2), *batch, ("after_step", 3),
@@ -200,20 +201,21 @@ def test_observer_sees_the_loops_moments_in_order(
     ]
 
 
-class _FailsAt:
-    """The jitted step, raising at its ``n``-th call."""
+class _Executable:
+    """The step's executable, counting its calls and raising at the
+    ``fail_at``-th."""
 
-    def __init__(self, step_fn, n):
-        self._step_fn, self._n, self._calls = step_fn, n, 0
+    def __init__(self, executable, fail_at=None):
+        self._executable, self._fail_at, self.calls = executable, fail_at, 0
 
     def __call__(self, *args):
-        self._calls += 1
-        if self._calls == self._n:
+        self.calls += 1
+        if self.calls == self._fail_at:
             raise RuntimeError("the step failed")
-        return self._step_fn(*args)
+        return self._executable(*args)
 
     def __getattr__(self, name):
-        return getattr(self._step_fn, name)
+        return getattr(self._executable, name)
 
 
 def test_step_exception_reaches_every_exit_in_the_documented_order(
@@ -227,7 +229,10 @@ def test_step_exception_reaches_every_exit_in_the_documented_order(
         tmp_path, record=True, autoprof=True, sanitize=True,
         watchdog_secs=300.0, checkpoint_dir=str(tmp_path / "ckpt"),
     )
-    trainer._train_step = _FailsAt(trainer._train_step, 3)
+    compile_step = trainer.compile_train_step
+    monkeypatch.setattr(
+        trainer, "compile_train_step", lambda *a: _Executable(compile_step(*a), 3)
+    )
     manifest = RunManifest(os.path.join(str(tmp_path), "manifest.json"), kind="train")
     manifest.begin()
     data = fake_data_iterator(batch_size=8, image_size=32, num_classes=10)
@@ -311,39 +316,33 @@ def test_key_added_at_the_log_boundary_reaches_history_and_log_fn(
     assert keys.index("mfu") < keys.index("steps_in_window")
 
 
-def test_ahead_of_time_side_runs_through_the_seam(tmp_path, devices, monkeypatch):
-    """``use_aot`` is the path fit() takes on the chip (a real peak); tier-1
-    otherwise runs the jit side only. An overridden peak takes it here: the
-    ``compiled`` event upgrades the cost's total to XLA's count, and the
-    profiler's observer reads its op index off the executable it was
-    handed, with no second lowering of the step."""
+def _induce_stall_at(monkeypatch, at):
+    """Have the ledger flag the window that ends at step ``at``."""
     from sav_tpu.obs.goodput import GoodputLedger
-    from sav_tpu.obs.manifest import RunManifest
 
     real_note = GoodputLedger.note_window
 
     def induced(self, num_steps, seconds, step=None):
-        return real_note(self, num_steps, seconds, step=step) or step == 4
+        return real_note(self, num_steps, seconds, step=step) or step == at
 
     monkeypatch.setattr(GoodputLedger, "note_window", induced)
+
+
+def test_ahead_of_time_side_runs_through_the_seam(tmp_path, devices, monkeypatch):
+    """The one path ``fit`` has, on the CPU as on the chip: the ``compiled``
+    event upgrades the cost's total to XLA's count, and the profiler's
+    observer reads its op index off the executable it was handed."""
+    from sav_tpu.obs.manifest import RunManifest
+
+    _induce_stall_at(monkeypatch, 4)
     trainer = _obs_trainer(
         tmp_path, peak_flops=1e12, autoprof=True, autoprof_steps=2,
         autoprof_max=1, diagnostics=False,
-    )
-    lowered = []
-    real_lower = trainer._train_step.lower
-    monkeypatch.setattr(
-        trainer, "_train_step",
-        type("Step", (), {
-            "lower": staticmethod(lambda *a: lowered.append(1) or real_lower(*a)),
-            "__call__": lambda self, *a: pytest.fail("the jit side ran"),
-        })(),
     )
     manifest = RunManifest(os.path.join(str(tmp_path), "manifest.json"), kind="train")
     manifest.begin()
     data = fake_data_iterator(batch_size=8, image_size=32, num_classes=10)
     _, history = trainer.fit(data, num_steps=10, manifest=manifest)
-    assert lowered == [1]
     doc = RunManifest.load(manifest.path)
     cost = doc["notes"]["cost_model"]
     assert cost["source"] == "xla-cost-analysis"
@@ -355,3 +354,188 @@ def test_ahead_of_time_side_runs_through_the_seam(tmp_path, devices, monkeypatch
     assert capture["trigger"] == "stall_anomaly"
     assert capture["summary"]["indexed_frac"] > 0.5  # the executable's text
     assert os.path.exists(os.path.join(capture["path"], "op_index.json"))
+
+
+# ---------------------------------------- one compiled step a fit (ISSUE 42)
+
+
+def test_plain_fit_compiles_once_and_dispatches_the_executable(
+    tmp_path, devices, monkeypatch
+):
+    """No peak override, no switch: the CPU takes the path the chip takes.
+    One ``fit/compile`` span, booked as the ledger's compile bucket and kept
+    for the timeline; one ``compiled`` event; every step a ``fit/dispatch``
+    of that executable; the jitted function itself is never called."""
+    from sav_tpu.obs import spans
+
+    observer, seen = _recording_observer()
+    _with_observers(monkeypatch, extra=[observer])
+    trainer = _obs_trainer(tmp_path, diagnostics=False)
+    handed, compile_step = [], trainer.compile_train_step
+    monkeypatch.setattr(
+        trainer, "compile_train_step",
+        lambda *a: handed.append(_Executable(compile_step(*a))) or handed[-1],
+    )
+    jitted = trainer._train_step
+    monkeypatch.setattr(
+        trainer, "_train_step",
+        type("Step", (), {
+            "lower": staticmethod(jitted.lower),
+            "__call__": lambda self, *a: pytest.fail("the jitted step was called"),
+        })(),
+    )
+    t0 = time.perf_counter()
+    data = fake_data_iterator(batch_size=8, image_size=32, num_classes=10)
+    trainer.fit(data, num_steps=5)
+    assert [e for e in seen if e[0] == "compiled"] == [("compiled",)]
+    assert seen.index(("compiled",)) < seen.index(("after_step", 1))
+    assert [executable.calls for executable in handed] == [5]
+    with open(os.path.join(str(tmp_path), "spans.trace.json")) as f:
+        events = [e for e in json.load(f)["traceEvents"] if e.get("ph") == "X"]
+    compiles = [e for e in events if e["name"] == "sav:fit/compile"]
+    dispatches = [e for e in events if e["name"] == "sav:fit/dispatch"]
+    assert len(compiles) == 1
+    assert [e["args"]["step"] for e in dispatches] == [1, 2, 3, 4, 5]
+    assert compiles[0]["ts"] + compiles[0]["dur"] <= dispatches[0]["ts"]
+    # The compile bucket is the compile span, and nothing else.
+    assert trainer.last_goodput["buckets_s"]["compile"] == pytest.approx(
+        compiles[0]["dur"] / 1e6, abs=2e-3
+    )
+    assert [
+        name for name, start, _ in spans.timeline() if start >= t0
+    ].count("sav:fit/compile") == 1
+
+
+def test_second_fit_continues_from_the_returned_state(tmp_path, devices):
+    """The executable is per call, the state is not: 2 + 3 steps over two
+    ``fit`` calls ask for the step's executable twice and log the losses
+    one 5-step ``fit`` logs."""
+    def losses(splits):
+        trainer = _obs_trainer(
+            tmp_path, diagnostics=False, trace_spans=False, log_every_steps=1
+        )
+        asked, compile_step = [], trainer.compile_train_step
+        trainer.compile_train_step = lambda *a: asked.append(1) or compile_step(*a)
+        data = fake_data_iterator(batch_size=8, image_size=32, num_classes=10)
+        state, out = trainer.init_state(), []
+        for upto in splits:
+            state, history = trainer.fit(data, num_steps=upto, state=state)
+            out += [(m["step"], m["loss"]) for m in history if "loss" in m]
+        assert len(asked) == len(splits)
+        return out
+
+    whole, resumed = losses([5]), losses([2, 5])
+    assert [step for step, _ in whole] == [1, 2, 3, 4, 5]
+    assert resumed == whole
+
+
+def test_retraces_counts_the_compiles_between_two_log_boundaries(
+    tmp_path, devices, monkeypatch
+):
+    """``retraces`` under diagnostics reads the process's compile log: a
+    function compiled between two boundaries shows on the next line, and
+    a quiet window reads 0."""
+    import jax
+
+    from sav_tpu.obs.fit_observers import FitObserver
+
+    ones = jax.numpy.ones(7)
+
+    class CompilesOnce(FitObserver):
+        def after_step(self, step):
+            if step == 3:  # inside the window that ends at step 4
+                jax.jit(lambda x: x * 3)(ones)
+
+    _with_observers(monkeypatch, extra=[CompilesOnce()])
+    trainer = _obs_trainer(tmp_path, trace_spans=False)
+    data = fake_data_iterator(batch_size=8, image_size=32, num_classes=10)
+    _, history = trainer.fit(data, num_steps=6)
+    assert [(m["step"], m["retraces"]) for m in history if "loss" in m] == [
+        (2, 0.0), (4, 1.0), (6, 0.0),
+    ]
+
+
+def test_anomaly_profiler_reads_the_executable_it_was_handed(
+    tmp_path, devices, monkeypatch
+):
+    """A capture's op index comes from the text of the executable the loop
+    runs: from the first step to the exit, a capture included, the process
+    lowers and compiles nothing."""
+    from sav_tpu.obs import compile_log
+    from sav_tpu.obs.fit_observers import FitObserver
+
+    marks = {}
+
+    class Marks(FitObserver):
+        def first_step(self, state, batch, rng):
+            marks["first_step"] = time.perf_counter()
+
+        def exit(self, exc, state, feeder):
+            marks["exit"] = time.perf_counter()
+
+    _with_observers(monkeypatch, extra=[Marks()])
+    _induce_stall_at(monkeypatch, 4)
+    trainer = _obs_trainer(
+        tmp_path, autoprof=True, autoprof_steps=2, autoprof_max=1,
+        diagnostics=False, trace_spans=False,
+    )
+    data = fake_data_iterator(batch_size=8, image_size=32, num_classes=10)
+    trainer.fit(data, num_steps=10)
+    assert trainer.last_goodput["gauges"]["autoprof/captures"] == 1.0
+    index = os.path.join(str(tmp_path), "autoprof")
+    assert any("op_index.json" in files for _, _, files in os.walk(index))
+    assert compile_log.log(since=marks["first_step"], until=marks["exit"]) == []
+
+
+def test_unknown_device_kind_raises_before_the_loop(tmp_path, devices, monkeypatch):
+    """The peak is resolved where its one reader is built: a device the
+    table does not list still stops the run, from the observers'
+    construction, before a batch is asked for; a stated peak trains."""
+    from sav_tpu.obs import fit_observers
+    from sav_tpu.utils.flops import UnknownDeviceKindError
+
+    def table_only(override=None):
+        if override:
+            return float(override), "override"
+        raise UnknownDeviceKindError("no peak FLOP/s for device kind 'TPU v9'")
+
+    monkeypatch.setattr(fit_observers, "resolve_peak_flops", table_only)
+    asked = []
+
+    def data():
+        for batch in fake_data_iterator(batch_size=8, image_size=32, num_classes=10):
+            asked.append(1)
+            yield batch
+
+    trainer = _obs_trainer(tmp_path, diagnostics=False, trace_spans=False)
+    with pytest.raises(UnknownDeviceKindError, match="TPU v9"):
+        trainer.fit(data(), num_steps=2)
+    assert not asked
+    stated = _obs_trainer(
+        tmp_path, diagnostics=False, trace_spans=False, peak_flops=1e12
+    )
+    _, history = stated.fit(data(), num_steps=2)
+    assert history[0]["mfu"] > 0
+
+
+def test_no_module_under_train_imports_the_cost_model():
+    """The step loop is the lowest layer of ``train/``: how it dispatches
+    depends on no FLOP peak. Read from the sources' import statements."""
+    import ast
+    import pathlib
+
+    root = pathlib.Path(__file__).resolve().parent.parent / "sav_tpu" / "train"
+    banned = ("sav_tpu.obs.costs", "sav_tpu.utils.flops")
+    found = []
+    for path in sorted(root.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = [alias.name for alias in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                names = [node.module or ""] + [
+                    f"{node.module}.{alias.name}" for alias in node.names
+                ]
+            else:
+                continue
+            found += [(path.name, n) for n in names if n.startswith(banned)]
+    assert not found, found

@@ -1,12 +1,12 @@
-"""Runtime sanitizers (ISSUE 3): StepSanitizer unit + Trainer integration.
+"""Runtime sanitizer (ISSUE 3): StepSanitizer unit + Trainer integration.
 
-Unit tier: the retrace arm catches both retrace seeds (shape drift,
-static-arg drift) the moment they happen; the transfer arm rejects
-implicit host→device transfers while armed and unwinds cleanly on
-close. Integration tier: ``TrainConfig.sanitize=True`` is silent on a
-healthy run (the acceptance criterion for ``train.py --sanitize``),
-composes with the feeder, the serial fallback, and diagnostics — and a
-seeded retrace mid-fit fails loudly with the step number in the error.
+Unit tier: the transfer guard rejects implicit host→device transfers
+while armed and unwinds cleanly on close. Integration tier:
+``TrainConfig.sanitize=True`` is silent on a healthy run (the acceptance
+criterion for ``train.py --sanitize``) and composes with the feeder, the
+serial fallback, and diagnostics. A batch whose shape drifts mid-fit
+fails at the offending step with the flag or without it: the loop calls
+one executable, which refuses what it was not compiled for.
 """
 
 import numpy as np
@@ -14,44 +14,16 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from sav_tpu.analysis.sanitize import RetraceSanitizerError, StepSanitizer
-from sav_tpu.obs.memory import RetraceCounter
+from sav_tpu.analysis.sanitize import StepSanitizer
 
 
 # -------------------------------------------------------------- unit tier
 
 
-def test_retrace_on_shape_drift_caught_at_the_offending_step():
-    f = jax.jit(lambda x: x * 2)
-    san = StepSanitizer(f, transfer_guard=None)
-    f(jnp.ones(4))
-    san.arm()  # warmup trace forgiven
-    f(jnp.ones(4))
-    san.check(2)  # cache hit: silent
-    f(jnp.ones(5))  # shape drift: new trace
-    with pytest.raises(RetraceSanitizerError, match="step 3"):
-        san.check(3)
-    san.close()
-
-
-def test_retrace_on_static_scalar_drift():
-    g = jax.jit(lambda x, n: x[:n], static_argnums=1)
-    san = StepSanitizer(g, transfer_guard=None)
-    x = jnp.ones(8)
-    g(x, 4)
-    san.arm()
-    g(x, 4)
-    san.check(2)
-    g(x, 5)  # distinct static value: one program per value
-    with pytest.raises(RetraceSanitizerError, match="re-traced 1x"):
-        san.check(3)
-    san.close()
-
-
 def test_transfer_guard_blocks_implicit_h2d_until_close():
     f = jax.jit(lambda x: x + 1)
     placed = jnp.ones(4)
-    san = StepSanitizer(f)
+    san = StepSanitizer()
     f(placed)
     san.arm()
     f(placed)  # device-resident arg: fine
@@ -64,29 +36,15 @@ def test_transfer_guard_blocks_implicit_h2d_until_close():
 
 
 def test_sanitizer_is_idempotent_and_safe_unarmed():
-    f = jax.jit(lambda x: x)
-    san = StepSanitizer(f)
-    san.check(1)  # before arm: no-op
+    san = StepSanitizer()
     san.close()  # before arm: no-op
     san.arm()
     san.arm()  # double-arm: no double guard entry
+    assert san.armed
     san.close()
     san.close()
-    assert san.active  # counter works on this jax
-
-
-def test_sanitizer_counter_is_independent_of_a_diagnostics_counter():
-    """The trainer runs diagnostics' RetraceCounter and the sanitizer's
-    side by side on one jitted fn; each holds its own watermark, so
-    neither steals the other's delta."""
-    f = jax.jit(lambda x: x)
-    a, b = RetraceCounter(f), RetraceCounter(f)
-    f(jnp.ones(3))
-    assert a.delta() == 1
-    assert b.delta() == 1  # a's read did not consume b's view
-    f(jnp.ones(4))
-    assert b.delta() == 1
-    assert a.delta() == 1
+    assert not san.armed
+    jnp.asarray(np.ones(2)) + 1  # guard unwound: implicit uploads are legal
 
 
 # ------------------------------------------------------- integration tier
@@ -151,8 +109,8 @@ def test_fit_with_sanitize_serial_fallback(devices):
 
 
 def test_fit_with_sanitize_and_diagnostics_coexist(devices):
-    """Two RetraceCounters on one step fn (diagnostics' + the
-    sanitizer's) must not steal each other's deltas."""
+    """A quiet run under both switches: the guard fires on nothing the
+    diagnostics read, and no line counts a compile."""
     trainer = _trainer(diagnostics=True)
     state, history = trainer.fit(iter(_batches(4)), num_steps=4)
     assert int(jax.device_get(state.step)) == 4
@@ -160,21 +118,36 @@ def test_fit_with_sanitize_and_diagnostics_coexist(devices):
     assert logged and all(h["retraces"] == 0.0 for h in logged)
 
 
-def test_fit_seeded_retrace_fails_loudly(devices):
-    """A batch whose shape drifts mid-run re-traces the step; with
-    sanitize on that is a hard error naming the step, not a silently
-    slower run."""
-    batches = _batches(2) + _batches(1, batch_size=8)
-    trainer = _trainer()
-    with pytest.raises(RetraceSanitizerError, match="step 3"):
-        trainer.fit(iter(batches), num_steps=3)
+@pytest.mark.parametrize("sanitize", [False, True])
+def test_fit_drifted_batch_fails_at_the_offending_step(
+    devices, tmp_path, monkeypatch, sanitize
+):
+    """A batch whose shape drifts mid-run is refused by the executable at
+    that step, with ``sanitize`` or without: two steps ran, the third
+    raised the executable's own ``TypeError``, every observer's exit ran in
+    the documented order and the manifest carries the run's metrics."""
+    from test_obs_trainer import _with_observers
 
+    from sav_tpu.obs.manifest import RunManifest, classify_exception
 
-def test_fit_without_sanitize_tolerates_the_same_drift(devices):
-    """Control: the drift above is only fatal when asked for — default
-    runs keep the old permissive behavior (retrace telemetry reports,
-    nothing raises)."""
+    exits = []
+    _with_observers(monkeypatch, spy_exit=exits)
     batches = _batches(2) + _batches(1, batch_size=8)
-    trainer = _trainer(sanitize=False)
-    state, _ = trainer.fit(iter(batches), num_steps=3)
-    assert int(jax.device_get(state.step)) == 3
+    trainer = _trainer(sanitize=sanitize, log_dir=str(tmp_path))
+    manifest = RunManifest(str(tmp_path / "manifest.json"), kind="train")
+    manifest.begin()
+    logged = []
+    with pytest.raises(TypeError, match="compiled with.*16.*called with.*8") as info:
+        trainer.fit(
+            iter(batches), num_steps=3, manifest=manifest, log_fn=logged.append
+        )
+    assert [m["step"] for m in logged] == [2]  # two steps ran and logged
+    assert [name for name, _, _ in exits] == [
+        "_MemDump", "_Feeder", "_Fleet", *(["_Sanitizer"] if sanitize else []),
+        "_Cost", "_Memory", "_Manifest",
+    ]
+    assert all(isinstance(exc, TypeError) for _, exc, _ in exits)
+    manifest.finalize(classify_exception(info.value), error=repr(info.value))
+    doc = RunManifest.load(manifest.path)
+    assert doc["outcome"] == "error"
+    assert doc["metrics"]["goodput/step_s"] > 0.0

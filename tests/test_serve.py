@@ -603,21 +603,25 @@ def _tiny_train_config(tmpdir, **overrides):
 )
 def test_restore_params_only_accepts_every_opt_layout(tmp_path, layout):
     """The satellite contract: params-only restore never touches
-    opt_state, so flat-buffer, per-leaf, and EMA-carrying checkpoints
-    all restore WITHOUT an optimizer rebuild — and without requesting a
-    single opt_state leaf from orbax."""
+    opt_state, so flat-buffer (a file from a trainer up to PR 41),
+    per-leaf, and EMA-carrying checkpoints all restore — without
+    requesting a single opt_state leaf from orbax."""
     import jax
 
     from sav_tpu.train.checkpoint import Checkpointer
+    from sav_tpu.train.optimizer import make_optimizer
     from sav_tpu.train.trainer import Trainer
 
     cfg = _tiny_train_config(
         tmp_path,
-        fused_optimizer=(layout == "fused"),
         ema_decay=0.99 if layout == "per_leaf_ema" else None,
     )
     trainer = Trainer(cfg)
     state = trainer.init_state()
+    if layout == "fused":
+        state = state.replace(
+            opt_state=make_optimizer(trainer.schedule, fused=True).init(state.params)
+        )
     trainer.checkpointer.save(0, state)
     trainer.checkpointer.wait()
     reader = Checkpointer(str(tmp_path), read_only=True)

@@ -286,8 +286,22 @@ def test_eval_tiny_set_smaller_than_mesh(devices):
     assert metrics["eval_count"] == 3.0
 
 
+def test_stored_config_with_the_removed_layout_field_fails_by_name():
+    """A config.json written before PR 42 may still carry
+    ``fused_optimizer``: ``from_json`` is ``cls(**json.loads(text))``, so
+    the unknown key is a ``TypeError`` that names it, and one without the
+    key loads as before."""
+    import json
+
+    config = _smoke_config()
+    assert TrainConfig.from_json(config.to_json()) == config
+    stored = {**json.loads(config.to_json()), "fused_optimizer": None}
+    with pytest.raises(TypeError, match="fused_optimizer"):
+        TrainConfig.from_json(json.dumps(stored))
+
+
 def test_fused_optimizer_matches_per_leaf():
-    """The flat layout (``fused_optimizer=True``) and the per-leaf chain
+    """The flat layout (``make_optimizer(fused=True)``) and the per-leaf chain
     compute the same numbers element by element: ``optax.flatten`` changes
     where Adam's moments sit, not what is computed. Three steps with a
     gradient that changes, the global-norm clip engaged on every one, and

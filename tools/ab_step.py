@@ -6,8 +6,6 @@ Variants (any comma list via --variants):
                f32 logits, per-leaf optimizer)
   bf16logits — TrainConfig.attention_logits_dtype='bfloat16' (halved L²
                softmax HBM traffic)
-  flatopt    — fused_optimizer=True (Adam on one flat vector: what auto
-               chose on one-axis meshes before PR 29)
   nomax      — non-stabilized softmax (skip the running-max subtraction):
                one fewer full pass over the [B,H,L,L] tensor. MEASUREMENT
                ONLY — exp overflows past logits ~88, so shipping it would
@@ -73,7 +71,7 @@ def make_batch(bs, image_size):
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--variants", default="base,bf16logits,flatopt")
+    p.add_argument("--variants", default="base,bf16logits")
     p.add_argument("--batch-size", type=int, default=256)
     p.add_argument("--model", default="deit_s_patch16")
     args = p.parse_args()
@@ -83,7 +81,7 @@ def main():
 
     import jax.numpy as jnp
 
-    known = {"base", "bf16logits", "flatopt", "nomax", "bhld",
+    known = {"base", "bf16logits", "nomax", "bhld",
              "noclip", "fused", "flash"}
     variants = args.variants.split(",")
     unknown = set(variants) - known
@@ -142,7 +140,7 @@ def main():
             attention_backend=(
                 {"fused": "fused", "flash": "pallas"}.get(variant, "xla")
             ),
-            # 'float32' explicitly for base/flatopt: None inherits
+            # 'float32' explicitly for base: None inherits
             # the compute dtype (bf16), which would collapse base and
             # bf16logits into the same configuration. The round-4+ variants
             # (nomax/bhld/noclip/fused/flash) ride bf16 logits so their
@@ -160,7 +158,6 @@ def main():
             global_batch_size=args.batch_size,
             transpose_images=False,
             clip_grad_norm=None if variant == "noclip" else 1.0,
-            fused_optimizer=True if variant == "flatopt" else None,
             seed=0,
         )
         trainer = Trainer(config)

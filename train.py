@@ -229,13 +229,6 @@ import click
     "backend_unreachable manifest outcome.",
 )
 @click.option(
-    "--fused-optimizer/--no-fused-optimizer", default=None,
-    help="Layout of Adam's moments (default: auto — per leaf on every mesh, "
-    "or whatever layout the checkpoint being resumed holds). "
-    "--fused-optimizer keeps them on one flat buffer: the same arithmetic, "
-    "slower on a TPU; only for checkpoints written with that layout.",
-)
-@click.option(
     "--log-dir", type=str, default=None,
     help="Telemetry sink: metrics.jsonl, goodput.json and (with "
     "--trace-spans) spans.trace.json land here. Default: the checkpoint "
@@ -245,7 +238,8 @@ import click
     "--diagnostics/--no-diagnostics", default=False,
     help="In-jit optimization diagnostics in the step metrics (param/"
     "update norms, update-to-param ratio, per-layer-group grad norms, "
-    "nonfinite counts) plus HBM + retrace telemetry at log time; rides "
+    "nonfinite counts) plus HBM telemetry and the compiles since the "
+    "last line (`retraces`, from the compile log) at log time; rides "
     "the existing per-log device_get, zero extra transfers "
     "(docs/observability.md).",
 )
@@ -335,11 +329,10 @@ import click
 )
 @click.option(
     "--sanitize/--no-sanitize", default=False,
-    help="Runtime sanitizers around the steady-state hot loop "
+    help="Runtime sanitizer around the steady-state hot loop "
     "(sav_tpu.analysis.sanitize): disallow implicit host->device "
-    "transfers on the training thread and hard-fail the run if the "
-    "jitted step re-traces after step 1 (a silent recompile costs "
-    "seconds to minutes). Armed after the first completed step.",
+    "transfers on the training thread. Armed after the first completed "
+    "step.",
 )
 @click.option(
     "--device-preprocess/--no-device-preprocess", default=False,
@@ -487,7 +480,7 @@ def _run(
     debug_nans, init_from,
     eval_only, steps, num_train_images,
     num_eval_images, crop_min_area, train_flip, platform,
-    fused_optimizer, log_dir, diagnostics, trace_spans, watchdog_secs,
+    log_dir, diagnostics, trace_spans, watchdog_secs,
     watchdog_soft_secs, fleet, autoprof, autoprof_steps, autoprof_max,
     memdump, record, record_depth, record_batches, spike_sigma,
     sanitize, device_preprocess, async_feed, feed_depth,
@@ -634,7 +627,6 @@ def _run(
         ema_decay=ema_decay,
         clip_grad_norm=clip_grad,
         grad_accum_steps=grad_accum,
-        fused_optimizer=fused_optimizer,
         device_preprocess=device_preprocess,
         async_feed=async_feed,
         feed_depth=feed_depth,

@@ -1,12 +1,13 @@
 """The decoder of the expert families: a pre-norm stack of token mixers
 (latent attention; gated delta-rule blocks with a gated grouped-query
-attention block every few layers; or double-gated short convolutions with a
-grouped-query attention block between them), routed experts with a shared
-expert or without, and multi-token-prediction modules, whose residual path is
-plain or a set of hyper-connected streams, whose head is its own matrix or
-the embedding's table.
+attention block every few layers; double-gated short convolutions with a
+grouped-query attention block between them; or vector-decay delta-rule
+blocks with a latent attention block every sixth layer), routed experts with
+a shared expert or without, and multi-token-prediction modules, whose
+residual path is plain or a set of hyper-connected streams, whose head is its
+own matrix or the embedding's table.
 
-Four registry entries build it. ``joyai_llm_flash``: sizes of
+Five registry entries build it. ``joyai_llm_flash``: sizes of
 ``https://huggingface.co/jdopensource/JoyAI-LLM-Flash/blob/main/config.json``,
 the layer equations of the family its config names (arXiv:2412.19437 sections
 2.1, 2.2 and 4.2)::
@@ -52,6 +53,23 @@ MTP module, and a tied head (``tie_head``: ``logits = RMSNorm_f(h) E^T``;
 the tree has no ``lm_head`` and the table's gradient is the sum of both
 uses).
 
+``ling_3.0_flash``: the language model of ``https://huggingface.co/inclusionAI/
+Ling-3.0-flash-VL/blob/main/config.json`` (no vision tower: the public config
+gives it no key). ``mixers`` by the config's ``layer_group_size`` 6: ``kda``
+(:class:`~sav_tpu.models.layers.kda.KDABlock`, the delta rule with a decay a
+key lane, arXiv:2510.26692) in five layers of six and ``latent`` in the sixth,
+there with the direct query (``q_rank`` None), the norms of ``use_qk_norm``
+(``latent_qk_norm``) and a head-wise sigmoid gate on the core's output
+(``latent_gate``). Two leading dense layers; 512 sigmoid-routed experts with a
+selection bias, the top 8 picked inside 4 of 8 groups (``n_group``,
+``topk_group``), a shared expert; the SwiGLU of the last layers' experts
+clamped (``expert_limits``, ``shared_limits``: a number a published layer, 0
+in the first 34); no MTP module; an untied head.
+
+The kinds of mixer a ``mixers`` list may name: ``latent`` (the block at this
+class's own latent sizes) and the keys of :data:`MIXER_BLOCKS`:
+``gated_delta``, ``gated_attention`` / ``full_attention``, ``conv``, ``kda``.
+
 ``Attn`` is :class:`~sav_tpu.models.layers.LatentSelfAttentionBlock` unless
 ``mixers`` says otherwise. ``FFN``
 is SwiGLU at ``mlp_ch`` in the first ``first_dense`` layers and
@@ -79,12 +97,14 @@ stands in for the chips that hold the rest.
 
 Scopes, for the readers of a trace: layers ``layer_<i>``; in a layer the
 token mixer is ``LatentSelfAttentionBlock_0``, ``GatedSelfAttentionBlock_0``,
-``GatedDeltaNetBlock_0`` or ``ShortConvBlock_0`` (``to_qkv``, ``to_out`` in
-each; the third also ``gdn/conv``, ``gdn/rule``, ``gdn/gate_norm``, the last
-``sconv/core``),
+``GatedDeltaNetBlock_0``, ``ShortConvBlock_0`` or ``KDABlock_0`` (``to_qkv``,
+``to_out`` in each; the third also ``gdn/conv``, ``gdn/rule``,
+``gdn/gate_norm``, the fourth ``sconv/core``, the last ``kda/conv``,
+``kda/rule``, ``kda/gate_norm``),
 the dense MLP ``GatedFFBlock_0`` (``fc1``, ``fc2``), the expert layer
-``moe`` (``route``, ``dispatch``, ``experts/fc1|fc2``, ``combine``,
-``shared/fc1|fc2`` where it has a shared expert); a hyper-connection's maps under ``hc_attn`` and
+``moe`` (``route``, with the group-limited selection under ``route/groups``,
+``dispatch``, ``experts/fc1|fc2``, ``combine``, ``shared/fc1|fc2`` where it
+has a shared expert); a hyper-connection's maps under ``hc_attn`` and
 ``hc_ffn`` (``hc/pre``, ``hc/sinkhorn``) and its merge under the layer
 (``hc/post``); the module ``mtp`` (its head and loss under ``mtp/lm_head``);
 the head ``lm_head`` (tied or not).
@@ -107,6 +127,7 @@ from sav_tpu.models.layers import (
 from sav_tpu.models.layers.gated_attention import GatedSelfAttentionBlock
 from sav_tpu.models.layers.gated_delta import GatedDeltaNetBlock
 from sav_tpu.models.layers.hyper_connection import HyperConnection, fan_in, fan_out
+from sav_tpu.models.layers.kda import KDABlock
 from sav_tpu.models.layers.short_conv import ShortConvBlock
 from sav_tpu.models.layers.moe import rows_over_bound
 from sav_tpu.models.ouro import LMHead
@@ -168,12 +189,29 @@ KEPT_UNDER_REMAT_BESIDE_RECURRENCE = KEPT_UNDER_REMAT + ("gdn_solved", "gdn_conv
 # result is worth 18 ms a step, the core's 1.
 KEPT_UNDER_REMAT_BESIDE_CONVOLUTION = KEPT_UNDER_REMAT + ("sconv_in", "sconv_core")
 
+# The vector-decay hybrid's choice: the names above (``hc_maps`` tags nothing
+# here) and two of the KDA block's four, a layer at 2 x 4,096 tokens:
+# ``gdn_solved`` (the rule's ``T beta`` and masked pair terms a chunk, 67 MB:
+# with them the layer's recomputation runs the scan alone) and ``kda_out``
+# (the rule's output, 67 MB); left to be computed again: ``kda_conv`` (q, k, v
+# after the convolutions, 201 MB) and ``kda_gates`` (the two gate projections'
+# results, 134 MB). Chosen on a v5e at the published widths, 2 x 4,096 tokens
+# and 8 of 512 experts held, where the state alone is 12.27 GB (seconds a
+# step, the compiled step's bytes; my chip run, PR 43, call 1): neither
+# 0.6182 in 14.98 GB, ``gdn_solved`` alone 0.5770 in 14.90, **both 0.5712 in
+# 14.71 GB**, with ``kda_conv`` 0.5671 in 15.19, with ``kda_gates`` 0.5565 in
+# 15.19 (the four together compile to 15.67 GB). The last two are 0.7% and
+# 2.6% faster and stand 0.3 GB under the cell's 15.5 GB before the harness's
+# own buffers are counted: a chip with more room passes them.
+KEPT_UNDER_REMAT_BESIDE_VECTOR_DECAY = KEPT_UNDER_REMAT + ("gdn_solved", "kda_out")
+
 
 # How a step's per-layer ``stats`` become one number: by key.
 STAT_REDUCTIONS = {
     "hc_doubly_stochastic_err": jnp.max, "hc_stream_gain": jnp.max,
     "gdn_decay_min": jnp.min, "gdn_state_rms_max": jnp.max, "attn_gate_mean": jnp.mean,
     "sconv_out_rms_max": jnp.max,
+    "kda_decay_min": jnp.min, "kda_state_rms_max": jnp.max, "moe_groups_held": jnp.mean,
 }
 
 # A layer's token mixer by the kind a ``mixers`` list gives it: the block,
@@ -185,17 +223,16 @@ MIXER_BLOCKS = {
     "gated_attention": (GatedSelfAttentionBlock, "attn_", "gated_attention"),
     "full_attention": (GatedSelfAttentionBlock, "attn_", "gated_attention"),
     "conv": (ShortConvBlock, "sconv_", "short_conv"),
+    "kda": (KDABlock, "kda_", "kda"),
 }
 
 
-def hybrid_mixers(num_layers: int, full_attention_interval: int) -> tuple:
+def hybrid_mixers(num_layers: int, interval: int, full: str = "gated_attention", linear: str = "gated_delta") -> tuple:
     """The token mixer of each layer of a hybrid decoder: layer ``i`` is
-    ``gated_attention`` where ``(i + 1) % full_attention_interval == 0`` and
-    ``gated_delta`` otherwise (the public config's rule)."""
-    return tuple(
-        "gated_attention" if (i + 1) % full_attention_interval == 0 else "gated_delta"
-        for i in range(num_layers)
-    )
+    ``full`` where ``(i + 1) % interval == 0`` and ``linear`` otherwise (the
+    public configs' rule, under their ``full_attention_interval`` or
+    ``layer_group_size``)."""
+    return tuple(full if (i + 1) % interval == 0 else linear for i in range(num_layers))
 
 
 class LatentDecoderBlock(nn.Module):
@@ -207,9 +244,9 @@ class LatentDecoderBlock(nn.Module):
     F(RMSNorm(h))``). Returns ``(state, counts, balance, stats)``: the two in
     the middle ``None`` for a dense layer; ``stats`` a dict of float32 scalars
     under the keys of :data:`STAT_REDUCTIONS` (the hyper-connections' two, the
-    larger of the two sublayers'; the delta-rule block's two; the gated
-    attention's one; the short convolution's one), ``None`` or empty where
-    the layer has none."""
+    larger of the two sublayers'; the delta-rule blocks' two each; the gated
+    attention's one; the short convolution's one; a group-limited expert
+    layer's one), ``None`` or empty where the layer has none."""
 
     mlp_ch: int
     num_experts: int
@@ -218,13 +255,15 @@ class LatentDecoderBlock(nn.Module):
     experts_held: Optional[Any]
     norm_eps: float
     num_heads: int = 0
-    q_rank: int = 0
+    q_rank: Optional[int] = 0  # None: latent attention's direct query
     kv_rank: int = 0
     nope_ch: int = 0
     rope_ch: int = 0
     v_ch: int = 0
     rope_theta: float = 1e4
     rope_scaling: Optional[Any] = None
+    latent_qk_norm: bool = False
+    latent_gate: bool = False
     mixer: str = "latent"
     mixer_sizes: Optional[Any] = None  # the gated blocks' sizes as a dict
     norm_offset: bool = False  # the norms' weights are offsets from 1
@@ -232,6 +271,10 @@ class LatentDecoderBlock(nn.Module):
     shared_expert: bool = True
     shared_gate: bool = False
     router_weight_eps: float = 0.0
+    n_group: int = 1
+    topk_group: int = 1
+    expert_limit: float = 0.0  # this layer's SwiGLU clamps; 0 = none
+    shared_limit: float = 0.0
     hc: Optional[Any] = None  # HyperConnection's sizes as a dict; None = one stream
     backend: Optional[str] = None
     logits_dtype: Optional[Dtype] = None
@@ -269,6 +312,8 @@ class LatentDecoderBlock(nn.Module):
                 rope_ch=self.rope_ch,
                 v_ch=self.v_ch,
                 rope_scaling=self.rope_scaling,
+                qk_norm=self.latent_qk_norm,
+                gate=self.latent_gate,
                 **attention,
                 **shared,
             )(x), None
@@ -281,7 +326,7 @@ class LatentDecoderBlock(nn.Module):
         if not self.num_experts:
             m, counts, balance = GatedFFBlock(hidden_ch=self.mlp_ch, quant=self.quant, dtype=self.dtype)(y), None, None
         else:
-            m, counts, balance = SparseMoEBlock(
+            m, counts, balance, moe_stats = SparseMoEBlock(
                 num_experts=self.num_experts,
                 top_k=self.top_k,
                 hidden_ch=self.mlp_ch,
@@ -291,10 +336,16 @@ class LatentDecoderBlock(nn.Module):
                 shared_expert=self.shared_expert,
                 shared_gate=self.shared_gate,
                 weight_eps=self.router_weight_eps,
+                n_group=self.n_group,
+                topk_group=self.topk_group,
+                limit=self.expert_limit,
+                shared_limit=self.shared_limit,
                 quant=self.quant,
                 dtype=self.dtype,
                 name="moe",
             )(y, select_bias)
+            if moe_stats:
+                stats = dict(stats or {}, **{"moe_" + k: v for k, v in moe_stats.items()})
         x, ffn_stats = merge(m)
         if attn_stats:
             err, gain = jax.tree.map(jnp.maximum, attn_stats, ffn_stats)
@@ -322,7 +373,11 @@ class JoyAILM(nn.Module):
       ``"gdn_state_rms_max"`` (the largest RMS of any head's final state);
       with gated attention layers ``"attn_gate_mean"``; with short-convolution
       layers ``"sconv_out_rms_max"`` (the largest RMS of any block's and
-      sequence's ``C * c``).
+      sequence's ``C * c``); with vector-decay layers ``"kda_decay_min"`` (the
+      smallest ``g`` of the step, a log: how near the gate's lower bound it
+      runs) and ``"kda_state_rms_max"``; with group-limited routing
+      ``"moe_groups_held"`` (the mean over the routed layers of the share of
+      tokens whose kept groups include a group of the experts held).
     """
 
     num_classes: int  # the vocabulary held here
@@ -335,11 +390,13 @@ class JoyAILM(nn.Module):
     routed_scale: float
     # Latent attention's sizes, where that is the token mixer.
     num_heads: int = 0
-    q_rank: int = 0
+    q_rank: Optional[int] = 0  # None: the direct query (the public configs' q_lora_rank null)
     kv_rank: int = 0
     nope_ch: int = 0
     rope_ch: int = 0
     v_ch: int = 0
+    latent_qk_norm: bool = False  # use_qk_norm: a norm a query head and one on the rotary key
+    latent_gate: bool = False  # a sigmoid gate a head on the latent core's output (head_wise)
     # A hybrid decoder's token mixers: a kind a layer (``latent`` or a key of
     # MIXER_BLOCKS; a depth cut runs the first ``num_layers`` of them), or the
     # public configs' full_attention_interval, from which hybrid_mixers builds
@@ -350,11 +407,18 @@ class JoyAILM(nn.Module):
     gated_attention: Optional[Any] = None
     gated_delta: Optional[Any] = None
     short_conv: Optional[Any] = None
+    kda: Optional[Any] = None
     norm_offset: bool = False  # RMSNorm weights stored as offsets from 1
     scoring: str = "sigmoid"  # the router's: sigmoid | softmax
     shared_expert: bool = True  # False: the expert layer is the routed sum alone
     shared_gate: bool = False  # the shared expert behind sigmoid(x w_s)
     router_weight_eps: float = 0.0  # added to the selected scores' sum before the division
+    n_group: int = 1  # > 1: the router picks inside topk_group of n_group groups of experts
+    topk_group: int = 1
+    # The SwiGLU clamp of each layer's routed experts and of its shared expert
+    # (the public configs' lists, a number a published layer; 0 = none).
+    expert_limits: Optional[tuple] = None
+    shared_limits: Optional[tuple] = None
     tie_head: bool = False  # the head reads the embedding's table
     first_dense: int = 1
     mtp_modules: int = 1  # the public configs' num_nextn_predict_layers: 0 or 1
@@ -407,10 +471,19 @@ class JoyAILM(nn.Module):
         def sizes_of(mixer: str):
             return dict(getattr(self, MIXER_BLOCKS[mixer][2])) if mixer in MIXER_BLOCKS else None
 
-        def block(name: str, routed: bool, mixer: str = "latent"):
+        def limit_of(limits, index) -> float:
+            return float(limits[index]) if limits and index is not None else 0.0
+
+        def block(name: str, routed: bool, mixer: str = "latent", index: Optional[int] = None):
             return block_cls(
                 num_heads=self.num_heads,
                 q_rank=self.q_rank,
+                latent_qk_norm=self.latent_qk_norm,
+                latent_gate=self.latent_gate,
+                n_group=self.n_group,
+                topk_group=self.topk_group,
+                expert_limit=limit_of(self.expert_limits, index),
+                shared_limit=limit_of(self.shared_limits, index),
                 kv_rank=self.kv_rank,
                 nope_ch=self.nope_ch,
                 rope_ch=self.rope_ch,
@@ -453,7 +526,7 @@ class JoyAILM(nn.Module):
         for i in range(self.num_layers):
             routed = i >= self.first_dense
             bias = select_bias.value[i - self.first_dense] if routed else None
-            h, c, b, stats = block(f"layer_{i}", routed, mixers[i])(h, bias)
+            h, c, b, stats = block(f"layer_{i}", routed, mixers[i], i)(h, bias)
             layer_stats.append(stats)
             if routed:
                 counts.append(c)

@@ -58,21 +58,33 @@ def _bias_free_dense(quant: Optional[str], dtype):
     )
 
 
+def clamped_gate_up(gate: jax.Array, up: jax.Array, limit: float, activation_fn: Callable = nn.silu) -> jax.Array:
+    """``act(gate) * up``, and where ``limit`` > 0 ``act(min(gate, limit)) *
+    clip(up, -limit, limit)``: the clamped SwiGLU of the public configs'
+    ``*_swiglu_limit_list`` (the gate held from above, the linear branch from
+    both sides). ``limit`` 0 is the plain product, the same program as before
+    the clamp existed."""
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return activation_fn(gate) * up
+
+
 class _GateUp(nn.Module):
     """The gated MLP's input matmuls as two children of one scope (``fc1``):
-    ``act(x W_gate) * (x W_up)``."""
+    ``act(x W_gate) * (x W_up)`` (:func:`clamped_gate_up` at ``limit``)."""
 
     hidden_ch: int
     activation_fn: Callable
     quant: Optional[str]
     dtype: Dtype
+    limit: float = 0.0
 
     @nn.compact
     def __call__(self, inputs: jax.Array) -> jax.Array:
         dense = _bias_free_dense(self.quant, self.dtype)
         gate = checkpoint_name(dense(self.hidden_ch, name="gate")(inputs), "ffn_gate")
         up = checkpoint_name(dense(self.hidden_ch, name="up")(inputs), "ffn_up")
-        return self.activation_fn(gate) * up
+        return clamped_gate_up(gate, up, self.limit, self.activation_fn)
 
 
 class GatedFFBlock(nn.Module):
@@ -80,17 +92,19 @@ class GatedFFBlock(nn.Module):
     Scopes as :class:`FFBlock`'s: the input matmuls under ``fc1``, the down
     projection ``fc2``. The three matmuls' outputs carry ``checkpoint_name``
     tags (``ffn_gate``, ``ffn_up``, ``ffn_out``) for a caller's remat policy;
-    without one they are the identity."""
+    without one they are the identity. ``limit`` > 0 clamps the two branches
+    (:func:`clamped_gate_up`)."""
 
     hidden_ch: int
     activation_fn: Callable = nn.silu
     quant: Optional[str] = None  # as FFBlock.quant
     dtype: Dtype = jnp.float32
+    limit: float = 0.0
 
     @nn.compact
     def __call__(self, inputs: jax.Array) -> jax.Array:
         x = _GateUp(
-            self.hidden_ch, self.activation_fn, self.quant, self.dtype, name="fc1"
+            self.hidden_ch, self.activation_fn, self.quant, self.dtype, self.limit, name="fc1"
         )(inputs)
         out = _bias_free_dense(self.quant, self.dtype)(inputs.shape[-1], name="fc2")(x)
         return checkpoint_name(out, "ffn_out")

@@ -101,10 +101,12 @@ class _CausalConv(nn.Module):
 
 
 class _GatedNorm(nn.Module):
-    """``RMSNorm(o) w silu(z)`` over a value head's lanes, float32 inside."""
+    """``RMSNorm(o) w act(z)`` over a value head's lanes, float32 inside
+    (``act``: SiLU here, a sigmoid in the vector-decay block)."""
 
     eps: float
     dtype: Dtype
+    activation: Any = nn.silu
 
     @nn.compact
     def __call__(self, o: jax.Array, z: jax.Array) -> jax.Array:
@@ -114,7 +116,7 @@ class _GatedNorm(nn.Module):
         def gated(o, z, scale):
             o = o.astype(jnp.float32)
             o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + self.eps)
-            return (o * scale * nn.silu(z.astype(jnp.float32))).astype(self.dtype)
+            return (o * scale * self.activation(z.astype(jnp.float32))).astype(self.dtype)
 
         return gated(o, z, scale)
 

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.pallas.ops.tpu import megablox
 
-from sav_tpu.models.layers.feedforward import GatedFFBlock
+from sav_tpu.models.layers.feedforward import GatedFFBlock, clamped_gate_up
 from sav_tpu.ops import _backend
 from sav_tpu.ops import attention as _attention
 from sav_tpu.ops import rows_to_tokens as _sums
@@ -361,15 +361,17 @@ class _RoutedExperts(nn.Module):
         return gate, up, down
 
 
-def _expert_ffn(rows, kernels, group_sizes, quant):
+def _expert_ffn(rows, kernels, group_sizes, quant, limit: float = 0.0):
     """``E_e(x) = W_down[e](silu(W_gate[e] x) * W_up[e] x)`` on sorted rows, in
-    groups of ``group_sizes`` (GatedFFBlock's formula and scopes)."""
+    groups of ``group_sizes`` (GatedFFBlock's formula and scopes); where
+    ``limit`` > 0 the two branches are clamped (``clamped_gate_up``)."""
     gate, up, down = kernels
     tiling = gmm_tiling(*gate.shape[1:])
     if quant:
         rows = _fake_int8(rows, (1,))
     with jax.named_scope("fc1"):
-        hidden = nn.silu(grouped_matmul(rows, gate, group_sizes, tiling)) * grouped_matmul(rows, up, group_sizes, tiling)
+        hidden = clamped_gate_up(
+            grouped_matmul(rows, gate, group_sizes, tiling), grouped_matmul(rows, up, group_sizes, tiling), limit)
     with jax.named_scope("fc2"):
         if quant:
             hidden = _fake_int8(hidden, (1,))
@@ -387,7 +389,7 @@ def _sorted_chunk(order, group_sizes, index, rows):
     return routing, live, jnp.diff(ends, prepend=0)
 
 
-def _routed_pass(quant, k, rows, index, x, kernels, weights, order, group_sizes):
+def _routed_pass(quant, limit, k, rows, index, x, kernels, weights, order, group_sizes):
     """``[T, D]`` float32: what the sorted routings ``[index rows, (index + 1)
     rows)`` add to their tokens (scopes ``dispatch``, ``experts``,
     ``combine``). Their tokens' rows gathered, the held experts on their
@@ -398,14 +400,14 @@ def _routed_pass(quant, k, rows, index, x, kernels, weights, order, group_sizes)
         token = routing // k
         taken = _rows_of_tokens(x, token, live, sizes)
     with jax.named_scope("experts"):
-        out = _expert_ffn(taken, kernels, sizes, quant)
+        out = _expert_ffn(taken, kernels, sizes, quant, limit)
     with jax.named_scope("combine"):
         return _tokens_of_rows(out, jnp.take(weights, routing), token, live, sizes, x.shape[0], jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
-def _overflow_pass(quant, k, rows, layer, chunks, routed, x, kernels, weights, order, group_sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4), inline=True)
+def _overflow_pass(quant, limit, k, rows, layer, chunks, routed, x, kernels, weights, order, group_sizes):
     """``[T, D]`` float32: ``routed`` (the common pass's result, pass 0) plus
     what passes ``1 .. chunks - 1`` over the sorted routings add, one
     :func:`_routed_pass` at a time in a loop of as many trips as there are
@@ -421,25 +423,25 @@ def _overflow_pass(quant, k, rows, layer, chunks, routed, x, kernels, weights, o
     def add(carry):
         index, y = carry
         with jax.named_scope(layer):
-            added = _routed_pass(quant, k, rows, index, x, kernels, weights, order, group_sizes)
+            added = _routed_pass(quant, limit, k, rows, index, x, kernels, weights, order, group_sizes)
         return index + 1, y + added
 
     return jax.lax.while_loop(lambda c: c[0] < chunks, add, (1, routed))[1]
 
 
-def _overflow_pass_fwd(quant, k, rows, layer, chunks, routed, *operands):
-    return _overflow_pass(quant, k, rows, layer, chunks, routed, *operands), (chunks, *operands)
+def _overflow_pass_fwd(quant, limit, k, rows, layer, chunks, routed, *operands):
+    return _overflow_pass(quant, limit, k, rows, layer, chunks, routed, *operands), (chunks, *operands)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
-def _overflow_pass_bwd(quant, k, rows, layer, res, g):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4), inline=True)
+def _overflow_pass_bwd(quant, limit, k, rows, layer, res, g):
     chunks, x, kernels, weights, order, group_sizes = res
 
     def add(carry):
         index, grads = carry
         with jax.named_scope(layer):
             added = jax.vjp(
-                lambda *a: _routed_pass(quant, k, rows, index, *a, order, group_sizes), x, kernels, weights
+                lambda *a: _routed_pass(quant, limit, k, rows, index, *a, order, group_sizes), x, kernels, weights
             )[1](g)
         return index + 1, jax.tree.map(jnp.add, grads, added)
 
@@ -454,17 +456,39 @@ _overflow_pass.defvjp(_overflow_pass_fwd, _overflow_pass_bwd)
 SCORINGS = {"sigmoid": jax.nn.sigmoid, "softmax": functools.partial(jax.nn.softmax, axis=-1)}
 
 
+def kept_groups(biased: jax.Array, n_group: int, topk_group: int) -> jax.Array:
+    """``biased [T, E]`` (score plus selection bias) -> ``[T, n_group]`` bool:
+    the experts in ``n_group`` groups of consecutive ``E / n_group``, a group
+    scored by the sum of its two largest entries, the ``topk_group`` best
+    groups kept (arXiv:2412.19437's group-limited selection, as its public
+    code scores a group)."""
+    by_group = biased.reshape(biased.shape[0], n_group, -1)
+    group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)
+    return jnp.any(kept[..., None] == jnp.arange(n_group), axis=-2)
+
+
 class _Router(nn.Module):
     """Scores over all ``num_experts`` (``scoring``: a sigmoid an expert, or
     a softmax over them), the top ``top_k`` of score plus selection bias, and
     the selected scores (without the bias) normalised to ``routed_scale``,
-    over their sum plus ``weight_eps`` where a family adds one."""
+    over their sum plus ``weight_eps`` where a family adds one.
+
+    With ``n_group`` > 1 the selection is group-limited (scope ``groups``):
+    :func:`kept_groups` keeps ``topk_group`` of the ``n_group`` groups a
+    token, and the top ``top_k`` are taken among the kept groups' experts
+    alone (the others' entries at ``-inf``); scores, weights and the balance
+    term read all ``num_experts`` as before. ``n_group`` 1 is the program
+    without the step. Returns ``(scores, chosen, weights, kept)``: ``kept``
+    the kept groups' mask ``[T, n_group]``, None without the step."""
 
     num_experts: int
     top_k: int
     routed_scale: float
     scoring: str = "sigmoid"
     weight_eps: float = 0.0
+    n_group: int = 1
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, x: jax.Array, select_bias: jax.Array):
@@ -472,14 +496,20 @@ class _Router(nn.Module):
             "kernel", nn.initializers.lecun_normal(), (x.shape[-1], self.num_experts)
         )
         scores = SCORINGS[self.scoring](router_scores(x, kernel))  # [T, E] float32
-        _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias), self.top_k)
+        biased = scores + jax.lax.stop_gradient(select_bias)
+        kept = None
+        if self.n_group > 1:
+            with jax.named_scope("groups"):
+                kept = kept_groups(biased, self.n_group, self.topk_group)
+                biased = jnp.where(jnp.repeat(kept, self.num_experts // self.n_group, axis=-1), biased, -jnp.inf)
+        _, chosen = jax.lax.top_k(biased, self.top_k)
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
         scaled = self.routed_scale * picked
         total = jnp.sum(picked, axis=-1, keepdims=True)
         weights = scaled / (total + self.weight_eps if self.weight_eps else total)
         # Tagged for a caller's remat policy: a few megabytes that spare the
         # backward pass the router's matmul and a second top-k.
-        return tuple(checkpoint_name(t, "moe_route") for t in (scores, chosen, weights))
+        return *(checkpoint_name(t, "moe_route") for t in (scores, chosen, weights)), kept
 
 
 class SparseMoEBlock(nn.Module):
@@ -516,11 +546,20 @@ class SparseMoEBlock(nn.Module):
     need (none, as a rule); ``train/tasks.py`` logs how often
     (``moe_overflow_share``).
 
-    Returns ``(y, counts, balance)``: ``counts [B, num_experts]`` float32,
+    ``n_group`` > 1 limits the selection to ``topk_group`` groups a token
+    (:class:`_Router`); ``limit`` and ``shared_limit`` > 0 clamp the routed
+    experts' and the shared expert's SwiGLU (``feedforward.py::
+    clamped_gate_up``), 0 is the plain product.
+
+    Returns ``(y, counts, balance, stats)``: ``counts [B, num_experts]`` float32,
     each sequence's routings by expert (no gradient: what the caller steps
     ``select_bias`` by), and ``balance``, the mean over sequences of
     ``sum_e f_e P_e`` with ``f_e = E / (k S) counts_e`` and ``P_e`` the mean
-    of ``s_e / sum s`` (arXiv:2412.19437 eq. 17-20 at ``alpha`` 1).
+    of ``s_e / sum s`` (arXiv:2412.19437 eq. 17-20 at ``alpha`` 1);
+    ``stats``, a dict of float32 scalars without a gradient: where the
+    selection is group-limited ``groups_held``, the share of tokens whose
+    kept groups include a group of the experts held (the others can send this
+    chip nothing); empty otherwise.
     """
 
     num_experts: int
@@ -532,6 +571,10 @@ class SparseMoEBlock(nn.Module):
     shared_expert: bool = True
     shared_gate: bool = False
     weight_eps: float = 0.0  # added to the selected scores' sum before the division
+    n_group: int = 1  # groups of consecutive experts; > 1: the selection is limited to topk_group of them
+    topk_group: int = 1
+    limit: float = 0.0  # the routed experts' SwiGLU clamp; 0 = none
+    shared_limit: float = 0.0  # the shared expert's
     quant: Optional[str] = None
     dtype: Dtype = jnp.float32
 
@@ -542,10 +585,13 @@ class SparseMoEBlock(nn.Module):
         offset, held = self.experts_held or (0, experts)
         if not (0 <= offset and 0 < held and offset + held <= experts and k <= experts):
             raise ValueError(f"experts_held {self.experts_held} / top_k {k} do not fit {experts} experts")
+        groups, per_group = self.n_group, experts // self.n_group
+        if experts % groups or not 1 <= self.topk_group <= groups or self.topk_group * per_group < k:
+            raise ValueError(f"{self.topk_group} of {groups} groups of {experts} experts do not hold top_k {k}")
         x = inputs.reshape(batch * seq, dim)
 
-        scores, chosen, weights = _Router(
-            experts, k, self.routed_scale, self.scoring, self.weight_eps, name="route"
+        scores, chosen, weights, kept = _Router(
+            experts, k, self.routed_scale, self.scoring, self.weight_eps, groups, self.topk_group, name="route"
         )(x, select_bias)
         if self.shared_gate:
             # From the layer's input as the router reads it, in float32.
@@ -573,18 +619,20 @@ class SparseMoEBlock(nn.Module):
 
         kernels = _RoutedExperts(held, self.hidden_ch, self.quant, self.dtype, name="experts")(dim)
         # The common pass: the first ``bound`` sorted routings.
-        routed = _routed_pass(self.quant, k, bound, 0, x, kernels, weights, order, group_sizes)
+        routed = _routed_pass(self.quant, self.limit, k, bound, 0, x, kernels, weights, order, group_sizes)
         if bound < total:  # else every routing is in the buffers
             with jax.named_scope("overflow"):
                 whole_passes = -(-total // bound) * bound
                 routed = _overflow_pass(
-                    self.quant, k, bound, self.name or type(self).__name__,
+                    self.quant, self.limit, k, bound, self.name or type(self).__name__,
                     -(-jnp.sum(group_sizes) // bound), routed, x, kernels, weights,
                     jnp.pad(order, (0, whole_passes - total)), group_sizes,
                 )
 
         if self.shared_expert:
-            shared = GatedFFBlock(hidden_ch=self.hidden_ch, quant=self.quant, dtype=self.dtype, name="shared")(x)
+            shared = GatedFFBlock(
+                hidden_ch=self.hidden_ch, quant=self.quant, dtype=self.dtype, limit=self.shared_limit, name="shared"
+            )(x)
             with jax.named_scope("shared"):  # its sum onto the routed part is the shared expert's own
                 shared = shared.astype(jnp.float32)
                 if self.shared_gate:
@@ -598,4 +646,8 @@ class SparseMoEBlock(nn.Module):
             share = scores / jnp.sum(scores, axis=-1, keepdims=True)
             mean_share = jnp.mean(share.reshape(batch, seq, experts), axis=1)  # [B, E]
             balance = jnp.mean(jnp.sum(counts * (experts / (k * seq)) * mean_share, axis=-1))
-        return y.reshape(batch, seq, dim), counts, balance
+            stats = {}
+            if kept is not None:
+                ours = kept[:, offset // per_group:(offset + held - 1) // per_group + 1]
+                stats["groups_held"] = jax.lax.stop_gradient(jnp.mean(jnp.any(ours, axis=-1).astype(jnp.float32)))
+        return y.reshape(batch, seq, dim), counts, balance, stats

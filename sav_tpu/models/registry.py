@@ -26,7 +26,9 @@ from sav_tpu.models.joyai import (
     KEPT_UNDER_REMAT_BESIDE_CONVOLUTION,
     KEPT_UNDER_REMAT_BESIDE_RECURRENCE,
     KEPT_UNDER_REMAT_BESIDE_STREAMS,
+    KEPT_UNDER_REMAT_BESIDE_VECTOR_DECAY,
     JoyAILM,
+    hybrid_mixers,
 )
 from sav_tpu.models.mlp_mixer import MLPMixer
 from sav_tpu.models.ouro import OuroLM
@@ -256,6 +258,39 @@ register(
     short_conv={"conv_width": 3},
     rope_theta=1e6, norm_eps=1e-5,
     kept_under_remat=KEPT_UNDER_REMAT_BESIDE_CONVOLUTION,
+)
+
+
+# --- Ling-3.0-flash (vector-decay delta rule 5:1 with gated latent attention) -
+# The language model of https://huggingface.co/inclusionAI/Ling-3.0-flash-VL/
+# blob/main/config.json (the vision tower has no key there and is not built);
+# ``num_classes`` is the vocabulary (157,184 there). Layer ``i`` mixes by latent
+# attention where ``(i + 1) % layer_group_size (6) == 0`` (7 layers: the direct
+# query, q_lora_rank null; use_qk_norm; a head-wise gate) and by Kimi delta
+# attention otherwise (35 layers: 32 heads of 128, convolution 4, the safe gate
+# at kda_lower_bound -5). Two leading dense layers at 6,144, then 512
+# sigmoid-routed experts of 768, top-8 inside 4 of 8 groups, a selection bias
+# that is state, one shared expert; the experts' SwiGLU clamped in the last
+# layers (the config's two lists). 124.4 B parameters (5.5 B active a token):
+# one chip holds the first period with one dense layer and its share of every
+# expert layer (model_overrides={"num_layers": 6, "first_dense": 1,
+# "experts_held": (0, 8)}).
+LING_EXPERT_LIMITS = (0,) * 35 + (4,) * 7
+LING_SHARED_LIMITS = (0,) * 34 + (5,) * 6 + (7,) * 2
+register(
+    "ling_3.0_flash",
+    JoyAILM,
+    task="tokens_mtp",
+    embed_dim=2560, num_layers=42, mlp_ch=6144, expert_ch=768,
+    num_heads=32, q_rank=None, kv_rank=512, nope_ch=128, rope_ch=64, v_ch=128,
+    latent_qk_norm=True, latent_gate=True,
+    mixers=hybrid_mixers(42, 6, full="latent", linear="kda"),
+    kda={"heads": 32, "key_ch": 128, "value_ch": 128, "conv_width": 4, "lower_bound": -5.0},
+    num_experts=512, top_k=8, routed_scale=2.5, n_group=8, topk_group=4,
+    first_dense=2, mtp_modules=0, bias_update_rate=1e-3, scoring="sigmoid",
+    expert_limits=LING_EXPERT_LIMITS, shared_limits=LING_SHARED_LIMITS,
+    rope_theta=6e6, norm_eps=1e-6,
+    kept_under_remat=KEPT_UNDER_REMAT_BESIDE_VECTOR_DECAY,
 )
 
 

@@ -59,6 +59,37 @@ Every exponent is a difference ``gamma_i - gamma_j`` with ``i >= j`` (or
 ``gamma_i`` itself), so nothing overflows however fast the state decays; the
 upper triangle is masked before ``exp``.
 
+**Two decays.** ``g [B, L, H]`` is one decay a head and token, the rule above
+(arXiv:2412.06464). ``g [B, L, H, d_k]`` is a decay a key lane, Kimi Delta
+Attention (arXiv:2510.26692, section 3): ``S' = Diag(exp(g_t)) S_{t-1}``, the
+rest as written. The scalar decay is the vector with equal lanes, and every
+function here takes either; what differs is where the decay can be applied.
+With a vector the pair term is ``sum_c k_ic k_jc exp(gamma_ic - gamma_jc)``,
+which no product of ``k`` with ``k`` times a table gives: the decay has to
+scale the operands' lanes before the product, and a factor ``exp(gamma_i -
+ref) exp(ref - gamma_j)`` needs a reference row ``ref`` that keeps both
+exponents small. :func:`_prepare_by_lane` cuts a chunk into sub-blocks of
+:data:`SUB_BLOCK` (16) rows and gives sub-block ``I`` its own first row as the
+reference, for the rows ``i`` of ``I`` against every ``j`` up to ``I``'s end::
+
+    gamma_i - ref <= 0                   i in I (the rows after the reference)
+    ref - gamma_j <= 0                   j before I
+    0 <= ref - gamma_j <= 15 max|g|      j in I (the diagonal sub-block)
+
+The first two are differences with the later row first, bounded by 0 as the
+scalar form's. The third is positive and bounded only if ``g`` is: **the
+caller keeps** ``|g| <= 88 / 16 = 5.5`` **a token and lane** (float32's
+``exp`` overflows past 88.7). Kimi's safe gate does, ``g = -5 sigmoid(.)``:
+15 rows move an exponent by at most 75, ``e^75 = 3.7e32``, and a sum of 128
+such lanes stays under float32's 3.4e38 with ``|k| <= 1``. These products
+(diagonal and off-diagonal sub-blocks alike) are float32 at ``HIGHEST``; the
+upper triangle holds finite numbers and is masked after the product. An
+unbounded gate (the paper's own ``-exp(A) softplus(.)``) would need a second
+level of chunking inside the sub-block and is refused by nothing here: it
+overflows. In the scan every exponent is ``gamma``, or ``gamma_C - gamma``:
+``<= 0``. The vector form has no kernel (:func:`rule_form` says what refused
+it) and requires one key head a value head.
+
 Precision, in either program: the running sums, ``exp``, the triangular system
 and its inverse (exact: the doubling of :func:`_doubled_inverse`, in the kernels
 from 8 x 8 blocks eliminated on the VPU, its products float32 at ``HIGHEST``;
@@ -74,6 +105,7 @@ remat policy (the state-free part keeps its four operands and nothing else).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -95,6 +127,7 @@ CHUNK_TILE = 8  # chunks a grid step; a block of the gates' rows is then whole s
 # where two take 1.0 (PERF.md section 6, PR 38).
 _CHUNKS_A_TRIP = 2
 _MAX_WIDTH = 256  # lanes of a wide array: its diagonal blocks are [width, width] float32
+SUB_BLOCK = 16  # rows that share a reference row where the decay is a vector: 15 max|g| < 88
 
 
 @jax.custom_vjp
@@ -190,6 +223,43 @@ def _prepare(q, k, g, beta, group: int):
     system = jnp.where(strict, beta[..., :, None] * pairs(k, k) * decay, 0.0)
     solved = (_unit_lower_inverse(system) * beta[..., None, :]).astype(dtype)  # T beta
     inside = (pairs(q, k) * decay).astype(dtype)  # lower(Q K^T exp(.)), diagonal included
+    return solved, inside, gamma
+
+
+@functools.partial(jax.checkpoint, static_argnums=(4,))
+def _prepare_by_lane(q, k, g, beta, group: int):
+    """:func:`_prepare` where the decay is a vector a key lane: ``g [N, B, H,
+    C, d_k]``, one key head a value head (``group`` 1). The pair terms
+    ``sum_c x_ic k_jc exp(gamma_ic - gamma_jc)`` a sub-block of rows at a
+    time, against the rows up to that sub-block's end, with its first row as
+    the reference (the module docstring has the bound on every exponent);
+    float32 at ``HIGHEST``, the upper triangle masked after the product."""
+    del group  # 1: gated_delta_rule has checked
+    chunk, dtype = q.shape[3], q.dtype
+    sub = math.gcd(chunk, SUB_BLOCK)
+    gamma = jnp.cumsum(g, axis=-2)
+
+    @functools.partial(jax.checkpoint, static_argnums=(3,))  # a sub-block's float32 operands live for its own turn only
+    def rows_of(q, k, gamma, low: int):
+        high = low + sub
+        ref = gamma[..., low:low + 1, :]
+        after = jnp.exp(gamma[..., low:high, :] - ref)  # <= 1
+        # <= 1 before the sub-block, <= e^75 inside it
+        before = k[..., :high, :].astype(jnp.float32) * jnp.exp(ref - gamma[..., :high, :])
+
+        def pairs(x):
+            rows = x[..., low:high, :].astype(jnp.float32) * after
+            dots = jnp.einsum("nbhid,nbhjd->nbhij", rows, before, precision=_HIGHEST)
+            return jnp.pad(dots, ((0, 0),) * 4 + ((0, chunk - high),))
+
+        return pairs(k), pairs(q)
+
+    kk, qk = zip(*(rows_of(q, k, gamma, low) for low in range(0, chunk, sub)))
+    kk, qk = jnp.concatenate(kk, axis=-2), jnp.concatenate(qk, axis=-2)
+    rows = jnp.arange(chunk)
+    system = jnp.where(rows[:, None] > rows[None, :], beta[..., :, None] * kk, 0.0)
+    solved = (_unit_lower_inverse(system) * beta[..., None, :]).astype(dtype)  # T beta
+    inside = jnp.where(rows[:, None] >= rows[None, :], qk, 0.0).astype(dtype)
     return solved, inside, gamma
 
 
@@ -473,15 +543,21 @@ def _prepare_in_vmem_bwd(group, tile, interpret, residuals, cotangents):
 _prepare_in_vmem.defvjp(_prepare_in_vmem_fwd, _prepare_in_vmem_bwd)
 
 
-def rule_form(chunks: int, chunk: int, key_dim: int, group: int, *, on_tpu: Optional[bool] = None) -> dict:
+def rule_form(chunks: int, chunk: int, key_dim: int, group: int, *, by_lane: bool = False,
+              on_tpu: Optional[bool] = None) -> dict:
     """Which program computes the part that does not read the state, from
     what the code can observe: ``{"rule": "kernel", "chunk_tile": n}`` on a
     TPU where Mosaic takes the shapes, else ``{"rule": "xla", "refused":
-    why}``. The kernels want a chunk of whole bfloat16 tiles (16 rows) that is a
+    why}``. A decay a key lane (``by_lane``) is XLA's program on every
+    backend, ``{"rule": "xla", "decay": "vector", "refused": why}``: the
+    kernels form ``k k^T`` once and scale it by a table. The kernels want a chunk of whole bfloat16 tiles (16 rows) that is a
     power of two (the inverse doubles its blocks from 8 rows up), a key
     head of whole lane tiles, the wide arrays (``group x chunk`` lanes) whole
     lane tiles and no wider than ``_MAX_WIDTH``, and the chunks in tiles of
     ``CHUNK_TILE`` (or all of them in one)."""
+    if by_lane:
+        return {"rule": "xla", "decay": "vector",
+                "refused": "a decay a key lane: the kernels scale k k^T by a table of one decay a head"}
     if on_tpu is None:
         on_tpu = _attention._on_tpu()
     width = group * chunk
@@ -508,7 +584,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
         ``H_k`` divides ``H``, and key head ``j`` feeds value heads
         ``j H / H_k .. (j + 1) H / H_k - 1``.
       v: ``[B, L, H, d_v]``.
-      g: ``[B, L, H]`` float32, the log of the decay (``<= 0``).
+      g: ``[B, L, H]`` float32, the log of the decay (``<= 0``), one a head;
+        or ``[B, L, H, d_k]``, one a key lane (then ``H_k = H``, and ``|g| <=
+        5.5``: the module docstring's bound on the exponents).
       beta: ``[B, L, H]`` float32, the write strength in ``[0, 1]``.
       chunk: tokens a chunk; ``L`` is padded to whole chunks with rows
         ``g = 0, beta = 0, k = 0`` that leave the state as it is.
@@ -523,13 +601,18 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     """
     batch, length, key_heads, dk = q.shape
     heads = v.shape[2]
-    if heads % key_heads or k.shape != q.shape or g.shape != v.shape[:3] or beta.shape != g.shape:
+    by_lane = g.ndim == 4
+    lanes_fit = g.shape[3:] == (dk,) and heads == key_heads if by_lane else True
+    if (heads % key_heads or k.shape != q.shape or g.shape[:3] != v.shape[:3] or beta.shape != g.shape[:3]
+            or not lanes_fit):
         raise ValueError(
             f"gated delta rule: q {q.shape}, k {k.shape}, v {v.shape}, g {g.shape}, beta {beta.shape}"
         )
-    form = rule_form(-(-length // chunk), chunk, dk, heads // key_heads)
+    form = rule_form(-(-length // chunk), chunk, dk, heads // key_heads, by_lane=by_lane)
     _attention.log_rule_form((batch, length, key_heads, dk), heads, chunk, jnp.dtype(v.dtype).name, form)
-    if form["rule"] == "kernel":
+    if by_lane:
+        prepare = _prepare_by_lane
+    elif form["rule"] == "kernel":
         prepare = functools.partial(_prepare_in_vmem, tile=form["chunk_tile"])
     else:
         prepare = _prepare
@@ -539,7 +622,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
 def _chunked(prepare, q, k, v, g, beta, chunk: int):
     """:func:`gated_delta_rule` with the state-free part computed by
     ``prepare(q, k, g, beta, group)`` on ``[N, B, H, C, ...]`` operands:
-    :func:`_prepare` or :func:`_prepare_in_vmem`."""
+    :func:`_prepare`, :func:`_prepare_in_vmem` or, for a decay a key lane (``g
+    [B, L, H, d_k]``), :func:`_prepare_by_lane`. The scan is one for both
+    decays but for where the decay goes: a scalar scales rows of the float32
+    results, a vector the lanes of q and k before the products."""
     batch, length, key_heads, dk = q.shape
     heads, dv = v.shape[2:]
     group, dtype = heads // key_heads, v.dtype
@@ -550,7 +636,8 @@ def _chunked(prepare, q, k, v, g, beta, chunk: int):
         )
     chunks = (length + pad) // chunk
     q, k, v = (_by_chunk(x, chunks, chunk) for x in (q, k, v))  # [N, B, H, C, d]
-    g = _by_chunk(g.astype(jnp.float32), chunks, chunk)  # [N, B, H, C]
+    by_lane = g.ndim == 4
+    g = _by_chunk(g.astype(jnp.float32), chunks, chunk)  # [N, B, H, C] or, a decay a lane, [N, B, H, C, d_k]
     beta = _by_chunk(beta.astype(jnp.float32), chunks, chunk)
 
     solved, inside, gamma = prepare(q, k, g, beta, group)
@@ -567,17 +654,27 @@ def _chunked(prepare, q, k, v, g, beta, chunk: int):
     def step(state, xs):
         q, k, v, solved, inside, gamma = xs  # q, k at the key heads
         held = by_key_head(state.astype(dtype))
-        grown = jnp.exp(gamma)[..., None]  # e^gamma, a row
-        read = jnp.einsum("bjck,bjrkv->bjrcv", k, held, preferred_element_type=jnp.float32)
+        if by_lane:  # e^gamma, e^{gamma_C - gamma} on the lanes of q and k: every exponent <= 0
+            grown = jnp.exp(gamma)
+            q, k_read = (q * grown).astype(dtype), (k * grown).astype(dtype)
+            k_write = (k * jnp.exp(gamma[..., -1:, :] - gamma)).astype(dtype)
+            grown = left_rows = 1.0
+            kept = jnp.exp(gamma[..., -1, :])[..., None]  # Diag(e^{gamma_C}) S
+        else:
+            grown = jnp.exp(gamma)[..., None]  # e^gamma, a row
+            k_read = k_write = k
+            left_rows = jnp.exp(gamma[..., -1:] - gamma)[..., None]
+            kept = jnp.exp(gamma[..., -1])[..., None, None]
+        read = jnp.einsum("bjck,bjrkv->bjrcv", k_read, held, preferred_element_type=jnp.float32)
         unread = (v.astype(jnp.float32) - grown * read.reshape(v.shape)).astype(dtype)  # V - e^gamma K S
         fresh = jnp.einsum("bhij,bhjv->bhiv", solved, unread, preferred_element_type=jnp.float32)  # V'
         out = grown * jnp.einsum(
             "bjck,bjrkv->bjrcv", q, held, preferred_element_type=jnp.float32
         ).reshape(v.shape)
         out = out + jnp.einsum("bhij,bhjv->bhiv", inside, fresh.astype(dtype), preferred_element_type=jnp.float32)
-        left = by_key_head((jnp.exp(gamma[..., -1:] - gamma)[..., None] * fresh).astype(dtype))
-        written = jnp.einsum("bjck,bjrcv->bjrkv", k, left, preferred_element_type=jnp.float32)
-        state = jnp.exp(gamma[..., -1])[..., None, None] * state + written.reshape(state.shape)
+        left = by_key_head((left_rows * fresh).astype(dtype))
+        written = jnp.einsum("bjck,bjrcv->bjrkv", k_write, left, preferred_element_type=jnp.float32)
+        state = kept * state + written.reshape(state.shape)
         return state, out.astype(dtype)
 
     start = jnp.zeros((batch, heads, dk, dv), jnp.float32)
@@ -589,7 +686,8 @@ def _chunked(prepare, q, k, v, g, beta, chunk: int):
 def gated_delta_rule_recurrent(q, k, v, g, beta):
     """The rule as its equations state it, a token at a time in float32 (the
     module docstring's three lines); shapes and results as
-    :func:`gated_delta_rule`'s, the output float32."""
+    :func:`gated_delta_rule`'s, the output float32. ``g [B, L, H, d_k]`` decays
+    each key lane of the state by its own ``exp(g)``."""
     batch, _, key_heads, dk = q.shape
     heads, dv = v.shape[2:]
     group = heads // key_heads
@@ -598,7 +696,7 @@ def gated_delta_rule_recurrent(q, k, v, g, beta):
 
     def step(state, xs):
         q, k, v, g, beta = xs  # [B, H, d], [B, H]
-        state = jnp.exp(g)[..., None, None] * state
+        state = (jnp.exp(g)[..., None] if g.ndim == 3 else jnp.exp(g)[..., None, None]) * state
         read = jnp.einsum("bhkv,bhk->bhv", state, k, precision=jax.lax.Precision.HIGHEST)
         write = beta[..., None] * (v - read)
         state = state + k[..., :, None] * write[..., None, :]

@@ -290,7 +290,10 @@ class MTPTokenPrediction(TokenPrediction):
     stochastic and what they do to the streams' norm; where its token mixers
     are delta-rule and gated attention blocks, the smallest decay, the largest
     state and the gate's mean; where they are short convolutions, the largest
-    RMS of a block's gated result."""
+    RMS of a block's gated result; where they are vector-decay delta-rule
+    blocks, the smallest log-decay and the largest state; where the router
+    picks inside groups, the share of tokens whose groups reach the experts
+    held."""
 
     # lambda of the MTP loss (arXiv:2412.19437 section 4.2's first phase;
     # assumed: benchmark/configs/joyai_llm_flash.json).
@@ -304,10 +307,15 @@ class MTPTokenPrediction(TokenPrediction):
     # layers, the largest RMS of any head's final state, and the mean of the
     # gated attention's sigmoid gate. Short convolutions: the largest RMS of
     # any block's and sequence's C * c (the block is cubic and holds no norm).
+    # Vector-decay layers: the smallest g of the step (a log-decay a key lane,
+    # bounded below by the safe gate's lower bound) and the largest state.
+    # Group-limited routing: the share of tokens whose kept groups include a
+    # group of the experts held, the mean over the routed layers.
     layer_stats = (
         ("hc_doubly_stochastic_err", jnp.max), ("hc_stream_gain", jnp.max),
         ("gdn_decay_min", jnp.min), ("gdn_state_rms_max", jnp.max), ("attn_gate_mean", jnp.mean),
         ("sconv_out_rms_max", jnp.max),
+        ("kda_decay_min", jnp.min), ("kda_state_rms_max", jnp.max), ("moe_groups_held", jnp.mean),
     )
 
     def loss(self, outputs: dict, targets) -> jax.Array:

@@ -177,7 +177,7 @@ def test_sorted_path_matches_the_loop_over_experts(moe_params, sum_form_of, case
     def run(p, x):
         return _moe().apply({"params": p}, x, bias)
 
-    (y, counts, balance), want = run(moe_params, x), _moe_reference(x, moe_params, bias)
+    (y, counts, balance, _), want = run(moe_params, x), _moe_reference(x, moe_params, bias)
     for b in range(BATCH):
         assert close(y[b], want[b][0]) and np.array_equal(np.asarray(counts[b]), np.asarray(want[b][1]))
     assert float(balance) == pytest.approx(float(np.mean([w[2] for w in want])), rel=1e-5)
@@ -209,7 +209,7 @@ def test_sixteen_shares_and_the_shared_expert_once_are_the_uncut_layer():
     abstract = jax.eval_shape(lambda: whole.init(jax.random.PRNGKey(0), x, jnp.zeros((experts,))))["params"]
     p = _routed_at_fan_in_scale(weights.draw_params(abstract, 5))
     bias = 0.05 * jax.random.normal(jax.random.PRNGKey(10), (experts,))
-    uncut, counts, balance = whole.apply({"params": p}, x, bias)
+    uncut, counts, balance, _ = whole.apply({"params": p}, x, bias)
     from sav_tpu.models.layers import GatedFFBlock
 
     shared_out = GatedFFBlock(hidden_ch=32).apply({"params": p["shared"]}, x.reshape(64, 64)).reshape(x.shape)
@@ -219,7 +219,7 @@ def test_sixteen_shares_and_the_shared_expert_once_are_the_uncut_layer():
         cut = jax.tree.map(lambda a: a, p)
         for group, leaf in (("fc1", "gate_experts_w1"), ("fc1", "up_experts_w1"), ("fc2", "experts_w2")):
             cut["experts"][group][leaf] = p["experts"][group][leaf][held[0]:held[0] + held[1]]
-        part, part_counts, part_balance = _moe(held, experts=experts, k=k).apply({"params": cut}, x, bias)
+        part, part_counts, part_balance, _ = _moe(held, experts=experts, k=k).apply({"params": cut}, x, bias)
         # The router, the counts and the balance loss stay 256 wide on every chip.
         assert np.array_equal(np.asarray(part_counts), np.asarray(counts))
         assert float(part_balance) == pytest.approx(float(balance), rel=1e-6)
@@ -232,8 +232,8 @@ def test_sixteen_shares_and_the_shared_expert_once_are_the_uncut_layer():
 def test_holding_every_expert_is_the_uncut_layer(moe_params):
     x = jax.random.normal(jax.random.PRNGKey(7), (BATCH, SEQ, 64))
     bias = jnp.zeros((EXPERTS,))
-    y, counts, _ = _moe().apply({"params": moe_params}, x, bias)
-    y_all, counts_all, _ = _moe((0, EXPERTS)).apply({"params": moe_params}, x, bias)
+    y, counts, _, _ = _moe().apply({"params": moe_params}, x, bias)
+    y_all, counts_all, _, _ = _moe((0, EXPERTS)).apply({"params": moe_params}, x, bias)
     assert np.array_equal(np.asarray(y), np.asarray(y_all)) and np.array_equal(np.asarray(counts), np.asarray(counts_all))
     with pytest.raises(ValueError, match="do not fit"):
         _moe((8, 12)).init(jax.random.PRNGKey(0), x, bias)
@@ -423,7 +423,7 @@ def test_three_chunks_the_last_partly_filled_match_the_loop(sum_form_of, form):
     g = jax.random.normal(jax.random.PRNGKey(8), x.shape)
 
     def run(p, x):
-        y, counts, _ = layer.apply({"params": p}, x, bias)
+        y, counts, _, _ = layer.apply({"params": p}, x, bias)
         return jnp.sum(y * g), counts
 
     def loop(p, x):
@@ -451,7 +451,7 @@ def test_a_held_share_matches_the_loop_at_and_beyond_the_bound(case):
     def run(p, x):
         return _moe(HELD).apply({"params": p}, x, bias)
 
-    (y, counts, balance), want = run(p, x), _moe_reference(x, p, bias, held=HELD)
+    (y, counts, balance, _), want = run(p, x), _moe_reference(x, p, bias, held=HELD)
     assert float(jnp.sum(counts[:, HELD[0]:sum(HELD)])) == held_rows
     for b in range(WIDE_BATCH):
         assert close(y[b], want[b][0]) and np.array_equal(np.asarray(counts[b]), np.asarray(want[b][1]))

@@ -197,7 +197,7 @@ def test_the_router_adds_its_eps_to_the_selected_sum():
     x = jax.random.normal(jax.random.PRNGKey(1), (24, 32))
     kernel = jax.random.normal(jax.random.PRNGKey(2), (32, EXPERTS)) * 32 ** -0.5
     bias = 0.3 * jax.random.normal(jax.random.PRNGKey(4), (EXPERTS,))
-    scores, chosen, got = _Router(EXPERTS, TOP_K, 1.0, "sigmoid", 1e-6).apply({"params": {"kernel": kernel}}, x, bias)
+    scores, chosen, got, _ = _Router(EXPERTS, TOP_K, 1.0, "sigmoid", 1e-6).apply({"params": {"kernel": kernel}}, x, bias)
     s = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64) @ np.asarray(kernel, np.float64))))
     order = np.argsort(-(s + np.asarray(bias, np.float64)), axis=-1)[:, :TOP_K]  # by score + bias
     picked = np.take_along_axis(s, order, axis=-1)  # weighted by the score alone

@@ -205,7 +205,7 @@ def test_the_softmax_router_against_a_written_out_top_k(seed):
     x = jax.random.normal(jax.random.PRNGKey(seed), (24, 32))
     kernel = jax.random.normal(jax.random.PRNGKey(seed + 10), (32, EXPERTS)) * 32 ** -0.5
     router = _Router(EXPERTS, TOP_K, 1.0, "softmax")
-    scores, chosen, weights_ = router.apply({"params": {"kernel": kernel}}, x, jnp.zeros((EXPERTS,)))
+    scores, chosen, weights_, _ = router.apply({"params": {"kernel": kernel}}, x, jnp.zeros((EXPERTS,)))
     logits = np.asarray(x, np.float64) @ np.asarray(kernel, np.float64)
     probs = np.exp(logits - logits.max(-1, keepdims=True))
     probs /= probs.sum(-1, keepdims=True)

@@ -321,7 +321,7 @@ def test_one_stream_is_the_plain_residual_to_the_last_bit():
 
     a = LatentSelfAttentionBlock(**sizes).apply({"params": p["LatentSelfAttentionBlock_0"]}, norm("attn_norm", x))
     h = x + a
-    m, want_counts, _ = SparseMoEBlock(num_experts=EXPERTS, top_k=TOP_K, hidden_ch=32, routed_scale=2.0,
+    m, want_counts, _, _ = SparseMoEBlock(num_experts=EXPERTS, top_k=TOP_K, hidden_ch=32, routed_scale=2.0,
                                        experts_held=(4, 8)).apply({"params": p["moe"]}, norm("ffn_norm", h), bias)
     assert np.array_equal(np.asarray(out), np.asarray(h + m)) and np.array_equal(np.asarray(counts), np.asarray(want_counts))
     # And with the dense FFN.

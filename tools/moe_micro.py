@@ -249,7 +249,7 @@ def main(argv=None):
 
     def dense_loop(x, params):
         flat = x.reshape(-1, args.dim)
-        _, chosen, weights = moe._Router(args.experts, args.top_k, 2.5).apply({"params": params["route"]}, flat, bias)
+        _, chosen, weights, _ = moe._Router(args.experts, args.top_k, 2.5).apply({"params": params["route"]}, flat, bias)
         fc1, fc2 = params["experts"]["fc1"], params["experts"]["fc2"]["experts_w2"].astype(x.dtype)
         gate, up = fc1["gate_experts_w1"].astype(x.dtype), fc1["up_experts_w1"].astype(x.dtype)
         y = moe.GatedFFBlock(hidden_ch=args.width, dtype=x.dtype).apply({"params": params["shared"]}, flat)

@@ -18,7 +18,10 @@ The six input projections are matrices of their own (``q``, ``k``, ``v``,
 fused by key head. ``lower_bound`` (the public config's ``kda_lower_bound``,
 -5) is what bounds the chunked rule's exponents: the paper's own gate,
 ``-exp(A_log) softplus(a + dt_bias)``, is unbounded below and is not built
-here (``ops/gated_delta.py`` says what it would take).
+here (``ops/gated_delta.py`` says what it would take). The rule's state-free
+part is one Mosaic call a direction on a TPU at heads of whole lane tiles and
+an even number of chunks (``ops/gated_delta.py::rule_form``, ``decay: vector``
+in the dispatch log), XLA's program elsewhere.
 
 Scopes, for the readers of a trace: ``to_qkv`` and ``to_out`` hold the weight
 matmuls; the work between them lies under ``kda/conv`` (the three convolutions

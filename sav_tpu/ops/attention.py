@@ -265,8 +265,9 @@ def log_rule_form(shape, value_heads: int, chunk: int, dtype: str, form: dict) -
     """The gated delta rule's record (``ops/gated_delta.py::rule_form``) in
     the same log, one a traced shape: ``op``, the ``[B, L, H_k, d_k]`` shape
     of q and k, the value heads, the chunk, and the form: ``rule`` (``kernel``
-    | ``xla``) with its ``chunk_tile``, or with what ``refused`` the kernel
-    (and ``decay: vector`` where the decay is one a key lane)."""
+    | ``xla``) with its ``chunk_tile``, or with what ``refused`` the kernels;
+    ``decay: vector`` beside either where the decay is one a key lane (its
+    kernels are ``_prepare_by_lane_in_vmem``'s)."""
     _log_once(("gated_delta_rule", shape, value_heads, chunk, dtype, form["rule"], form.get("decay")), {
         "op": "gated_delta_rule", "shape": list(shape), "value_heads": value_heads,
         "chunk": chunk, "dtype": dtype, **form,

@@ -31,9 +31,10 @@ k and v: at 4 x 4,096 tokens and 32 value heads 0.34 GB a layer, against
 
 Everything that does not read ``S`` (``gamma``, ``A``, ``T``, the masked ``Q
 K^T``) is computed before the ``lax.scan`` over the chunks that carries ``S``
-through the last three lines, by one of two programs that :func:`rule_form`
-picks from the backend and the shapes (the choice is a record of the dispatch
-log, ``ops/attention.py::snapshot_dispatch_log``):
+through the last three lines, by one of two programs a decay (below: a decay a
+head, or a decay a key lane) that :func:`rule_form` picks from the backend and
+the shapes (the choice is a record of the dispatch log,
+``ops/attention.py::snapshot_dispatch_log``):
 
 * ``kernel`` (:func:`_prepare_in_vmem`; a TPU, chunks of 16 rows times a
   power of two, key heads of whole lane tiles, a key head's value heads
@@ -87,8 +88,32 @@ upper triangle holds finite numbers and is masked after the product. An
 unbounded gate (the paper's own ``-exp(A) softplus(.)``) would need a second
 level of chunking inside the sub-block and is refused by nothing here: it
 overflows. In the scan every exponent is ``gamma``, or ``gamma_C - gamma``:
-``<= 0``. The vector form has no kernel (:func:`rule_form` says what refused
-it) and requires one key head a value head.
+``<= 0``. The vector form requires one key head a value head, and has the
+same two programs (``decay: vector`` in :func:`rule_form`'s record):
+
+* ``kernel`` (:func:`_prepare_by_lane_in_vmem`; a TPU, chunks of 16 rows times
+  a power of two, key heads of whole lane tiles, an even number of chunks in
+  tiles of ``LANE_CHUNK_TILE``): one Pallas call a direction whose grid step
+  holds a tile of chunks of one (batch, head) in VMEM and takes them two at a
+  time, side by side on the lanes (a chunk of 64 alone is half a lane tile),
+  so that the masks, the inverse and its derivative run on the wide arrays of
+  the scalar form at two value heads a key head. A sub-block's pair terms are
+  one product: both chunks' 16 rows of q and of k, scaled by ``exp(gamma_i -
+  ref)`` with the reference row a sublane broadcast, against both chunks' ``k
+  exp(ref - gamma_j)`` (the rows after the sub-block left out of ``exp`` and
+  zero), of which each chunk's rows keep their own chunk's lanes. The backward
+  is written out: the decayed operands, the system and ``T`` again, ``dA`` as
+  above, then a sub-block at a time ``d rows = ddots before``, ``d before =
+  ddots^T rows``, dq and dk through both, and ``d gamma`` a lane, ``+ d rows
+  rows`` on the sub-block's rows and ``- d before before`` up to its end (the
+  reference row cancels in every pair term and is a constant of the
+  derivative). ``gamma`` and ``d gamma``, ``[N, B, H, C, d_k]`` float32, are
+  the only float32 arrays of a direction that cross HBM; the running sum and
+  its transpose are XLA's.
+* ``xla`` (:func:`_prepare_by_lane`; everything else, and what the tests hold
+  the kernels to): the sub-blocks' products for all chunks at once, each under
+  a ``jax.checkpoint`` of its own, the inverse and the backward as the scalar
+  form's.
 
 Precision, in either program: the running sums, ``exp``, the triangular system
 and its inverse (exact: the doubling of :func:`_doubled_inverse`, in the kernels
@@ -97,7 +122,10 @@ no power series, no bfloat16 pass through ``A`` or ``T``) and the carried state
 are float32; q, k, ``T beta``, the masked ``Q
 K^T``, ``V - e^gamma K S``, ``V'`` and the state enter the matrix products in
 the operands' dtype (the model's compute dtype) and are summed in float32, and
-so do the cotangents of ``k k^T`` and ``q k^T`` on their way to dq and dk. The
+so do the cotangents of ``k k^T`` and ``q k^T`` on their way to dq and dk. With
+a decay a key lane the pair terms' operands are float32 products of q or k and
+an ``exp``: they, and their cotangents' products on the way to dq, dk and ``d
+gamma``, are float32 at ``HIGHEST`` in either program. The
 scan's backward is JAX's transpose of it; a caller bounds what it keeps with a
 remat policy (the state-free part keeps its four operands and nothing else).
 """
@@ -124,7 +152,9 @@ CHUNK_TILE = 8  # chunks a grid step; a block of the gates' rows is then whole s
 # Chunks a trip of a grid step's loop. Two interleave their MXU and VPU work:
 # both calls 13.7 ms a layer at the hybrid decoder's cell for 14.8 at one and
 # 13.0 at four, whose twice-longer body takes a start 2.3 s to trace and lower
-# where two take 1.0 (PERF.md section 6, PR 38).
+# where two take 1.0 (PERF.md section 6, PR 38). With a decay a key lane a trip
+# takes two PAIRS of chunks: both calls 9.09 ms a layer at the vector-decay
+# hybrid's cell for 9.51 at one pair (PERF.md section 6, PR 44).
 _CHUNKS_A_TRIP = 2
 _MAX_WIDTH = 256  # lanes of a wide array: its diagonal blocks are [width, width] float32
 SUB_BLOCK = 16  # rows that share a reference row where the decay is a vector: 15 max|g| < 88
@@ -543,24 +573,257 @@ def _prepare_in_vmem_bwd(group, tile, interpret, residuals, cotangents):
 _prepare_in_vmem.defvjp(_prepare_in_vmem_fwd, _prepare_in_vmem_bwd)
 
 
+# ---------------------------------------------------------------------------
+# The decay a key lane, as one Pallas kernel a direction. One key head a value
+# head, so a chunk alone is half a lane tile (64 of 128 lanes at chunk 64): a
+# trip of a grid step's loop takes TWO chunks of the same head and lays their
+# ``C x C`` matrices side by side, ``[C, 2 C]``, which is the wide array of a
+# key head with two value heads: the masks, the inverse and its derivative
+# run as they do there (``group`` 2). The pair terms cannot be one ``k k^T``
+# times a table: a sub-block of SUB_BLOCK rows at a time, both chunks' rows of
+# q and k (scaled by ``exp(gamma_i - ref)``) stacked as the left operand, ``[4
+# x 16, d_k]``, against both chunks' ``k exp(ref - gamma_j)`` stacked, ``[2 C,
+# d_k]``: one full-width product at HIGHEST a sub-block, of which the rows of
+# chunk a keep the lanes of chunk a and those of b the lanes of b (the other
+# half, one chunk's rows against the other's, is finite and dropped by a
+# select). The backward's two products a sub-block run on the same operands
+# with the cotangent laid out block-diagonally, nothing wasted.
+# ---------------------------------------------------------------------------
+
+LANE_CHUNK_TILE = 2 * CHUNK_TILE  # chunks a grid step: eight pairs, a block of beta's rows whole sublane tiles
+
+
+def _paired(x: jax.Array) -> jax.Array:
+    """``[N, B, H, C]`` -> ``[B, H, N / 2, 2 C]``: chunks ``2 p`` and ``2 p +
+    1`` of a head side by side, the pairs down the rows of a block."""
+    chunks, batch, heads, chunk = x.shape
+    x = jnp.transpose(x.reshape(chunks // 2, 2, batch, heads, chunk), (2, 3, 0, 1, 4))
+    return x.reshape(batch, heads, chunks // 2, 2 * chunk)
+
+
+def _unpaired(x: jax.Array) -> jax.Array:
+    """:func:`_paired`'s inverse."""
+    batch, heads, pairs, width = x.shape
+    x = jnp.transpose(x.reshape(batch, heads, pairs, 2, width // 2), (2, 3, 0, 1, 4))
+    return x.reshape(2 * pairs, batch, heads, width // 2)
+
+
+def _pair_system(q_ref, k_ref, gamma_ref, beta_row, p, chunk: int):
+    """What both kernels build of chunks ``2 p`` and ``2 p + 1``: their
+    operands stacked, ``[2 C, d_k]`` float32 (chunk a's rows, then b's); a
+    sub-block's decayed operands (``x [4 x 16, d_k]``, its rows of q and of k
+    of a, then of b, ``after`` their ``exp(gamma - ref)`` and ``rows = x
+    after`` the left operand; ``grown = exp(ref - gamma)`` and ``before = k
+    grown``, ``[2 C, d_k]``, zero past the sub-block's end: masked by leaving
+    those rows out of ``exp``, whose exponent there is unbounded); the pair terms ``kk`` and ``qk``, ``beta``
+    down the rows and along them, the strictly lower system and its inverse,
+    wide (``[C, 2 C]``) and float32."""
+    sub = SUB_BLOCK
+    q, k, gamma = (
+        jnp.concatenate([ref[2 * p, 0, 0], ref[2 * p + 1, 0, 0]], axis=0).astype(jnp.float32)
+        for ref in (q_ref, k_ref, gamma_ref)
+    )
+    shape = (chunk, 2 * chunk)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    col = lane % chunk
+    # A sub-block's rows of q and of k: chunk a's lanes, then b's (an iota of its own: Mosaic keeps one along the
+    # lanes as a single row of registers and cannot slice it by rows)
+    of_a = jax.lax.broadcasted_iota(jnp.int32, (2 * sub, 2 * chunk), 1) < chunk
+    blocks = []
+    for low in range(0, chunk, sub):
+        high = low + sub
+        after, grown, x = [], [], []
+        for side, at in enumerate((0, chunk)):  # chunk a, chunk b
+            ref = gamma_ref[2 * p + side, 0, 0, low:low + 1, :]  # the sub-block's first row, down the sublanes
+            decay = jnp.exp(gamma[at + low:at + high] - ref)  # <= 1
+            after += [decay, decay]
+            x += [q[at + low:at + high], k[at + low:at + high]]
+            # <= 1 before the sub-block, <= e^75 inside it; the rows after it are never exponentiated
+            grown.append(jnp.exp(ref - gamma[at:at + high]))
+            if high < chunk:
+                grown.append(jnp.zeros((chunk - high, gamma.shape[1]), jnp.float32))
+        after, grown = jnp.concatenate(after, axis=0), jnp.concatenate(grown, axis=0)
+        x = jnp.concatenate(x, axis=0)
+        rows, before = x * after, k * grown
+        dots = jax.lax.dot_general(
+            rows, before, (((1,), (1,)), ((), ())), precision=_HIGHEST, preferred_element_type=jnp.float32
+        )  # [4 x 16, 2 C]
+        wide = jnp.where(of_a, dots[:2 * sub], dots[2 * sub:])  # q's rows, then k's
+        blocks.append(dict(low=low, high=high, x=x, after=after, grown=grown, rows=rows, before=before, wide=wide))
+    qk = jnp.concatenate([b["wide"][:sub] for b in blocks], axis=0)
+    kk = jnp.concatenate([b["wide"][sub:] for b in blocks], axis=0)
+    eye = row == col
+    beta_j = jnp.broadcast_to(beta_row, shape)
+    beta_i = _over_lanes(jnp.where(eye, beta_j, 0.0), chunk, 2)  # a row vector turned into a column
+    system = jnp.where(row > col, beta_i * kk, 0.0)
+    return dict(
+        k=k, row=row, of_a=of_a, col=col, eye=eye, blocks=blocks, qk=qk, kk=kk, beta_i=beta_i, beta_j=beta_j,
+        solved=_wide_inverse(system, chunk, 2),
+    )
+
+
+def _prepare_by_lane_fwd_kernel(q_ref, k_ref, gamma_ref, beta_ref, solved_ref, inside_ref, *, chunk: int):
+    def one_pair(p):
+        s = _pair_system(q_ref, k_ref, gamma_ref, beta_ref[0, 0, pl.ds(p, 1), :], p, chunk)
+        solved = (s["solved"] * s["beta_j"]).astype(solved_ref.dtype)  # T beta
+        inside = jnp.where(s["row"] >= s["col"], s["qk"], 0.0).astype(inside_ref.dtype)
+        for side in range(2):
+            solved_ref[2 * p + side, 0, 0] = solved[:, side * chunk:(side + 1) * chunk]
+            inside_ref[2 * p + side, 0, 0] = inside[:, side * chunk:(side + 1) * chunk]
+
+    _for_each_chunk(q_ref.shape[0] // 2, one_pair)  # two pairs a trip
+
+
+def _prepare_by_lane_bwd_kernel(q_ref, k_ref, gamma_ref, beta_ref, dsolved_ref, dinside_ref,
+                                dq_ref, dk_ref, dgamma_ref, dbeta_ref, *, chunk: int):
+    sub = SUB_BLOCK
+
+    def one_pair(p):
+        s = _pair_system(q_ref, k_ref, gamma_ref, beta_ref[0, 0, pl.ds(p, 1), :], p, chunk)
+        solved, row, col, of_a = s["solved"], s["row"], s["col"], s["of_a"]
+        dsolved, dinside = (
+            jnp.concatenate([ref[2 * p, 0, 0], ref[2 * p + 1, 0, 0]], axis=1).astype(jnp.float32)
+            for ref in (dsolved_ref, dinside_ref)
+        )
+        # T beta: beta scales T's columns.
+        dbeta = jnp.sum(dsolved * solved, axis=0, keepdims=True)
+        # The inverse's own derivative, dA = -T^T dT T^T under the strict mask.
+        turned = _diagonal_blocks(solved, chunk, 2).T  # T^T of chunk a at block (0, 0), of b at (1, 1)
+        right = jnp.dot(dsolved * s["beta_j"], turned, precision=_HIGHEST, preferred_element_type=jnp.float32)
+        dsystem = -jnp.dot(
+            turned[:chunk] + turned[chunk:], _diagonal_blocks(right, chunk, 2),
+            precision=_HIGHEST, preferred_element_type=jnp.float32,
+        )
+        dsystem = jnp.where(row > col, dsystem, 0.0)
+        by_beta = _over_lanes(dsystem * s["kk"], chunk, 2)  # d system / d beta_i, summed along the row
+        dbeta_ref[0, 0, pl.ds(p, 1), :] = dbeta + jnp.sum(jnp.where(s["eye"], by_beta, 0.0), axis=0, keepdims=True)
+        dkk, dqk = dsystem * s["beta_i"], jnp.where(row >= col, dinside, 0.0)
+        # The pair terms, a sub-block at a time: dots = rows before^T, rows = x after, before = k grown.
+        through = jnp.zeros_like(s["k"])  # d before x grown, [2 C, d_k]: every sub-block's rows up to its end
+        dk_rows, dgamma_rows = ([], []), ([], [])  # through ``rows``: a sub-block's own, of chunk a and of b
+        for b in s["blocks"]:
+            low, high = b["low"], b["high"]
+            ddots = jnp.concatenate([dqk[low:high], dkk[low:high]], axis=0)  # [2 x 16, 2 C]
+            # Chunk a's rows keep a's lanes, b's rows b's: the forward's select, transposed.
+            ddots = jnp.concatenate([jnp.where(of_a, ddots, 0.0), jnp.where(of_a, 0.0, ddots)], axis=0)
+            drows = jnp.dot(ddots, b["before"], precision=_HIGHEST, preferred_element_type=jnp.float32)
+            dbefore = jax.lax.dot_general(
+                ddots, b["rows"], (((0,), (0,)), ((), ())), precision=_HIGHEST, preferred_element_type=jnp.float32
+            )
+            through = through + dbefore * b["grown"]
+            dx = drows * b["after"]  # [q of a | k of a | q of b | k of b]
+            by_gamma = dx * b["x"]  # d rows x rows
+            for side in range(2):
+                at = 2 * side * sub
+                dq_ref[2 * p + side, 0, 0, low:high, :] = dx[at:at + sub].astype(dq_ref.dtype)
+                dk_rows[side].append(dx[at + sub:at + 2 * sub])
+                dgamma_rows[side].append(by_gamma[at:at + sub] + by_gamma[at + sub:at + 2 * sub])
+        # gamma enters through the two exponents alone, + on a sub-block's own rows and - on the rows up to its
+        # end; the reference row cancels in every pair term and is a constant of the derivative.
+        dk = through + jnp.concatenate(dk_rows[0] + dk_rows[1], axis=0)
+        dgamma = jnp.concatenate(dgamma_rows[0] + dgamma_rows[1], axis=0) - through * s["k"]
+        for side in range(2):
+            dk_ref[2 * p + side, 0, 0] = dk[side * chunk:(side + 1) * chunk].astype(dk_ref.dtype)
+            dgamma_ref[2 * p + side, 0, 0] = dgamma[side * chunk:(side + 1) * chunk]
+
+    _for_each_chunk(q_ref.shape[0] // 2, one_pair)  # two pairs a trip
+
+
+def _by_lane_specs(q, tile: int):
+    """:func:`_specs` at one key head a value head, with ``beta``'s block the
+    pairs' rows of :func:`_paired`."""
+    chunk = q.shape[3]
+    grid, rows, _, square = _specs(q, 1, tile)
+    return grid, rows, pl.BlockSpec((1, 1, tile // 2, 2 * chunk), lambda b, j, n: (b, j, n, 0)), square
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _prepare_by_lane_forward(q, k, gamma, beta, tile: int, interpret: bool):
+    """Jitted, as :func:`_prepare_forward` is and for its reason."""
+    chunks, batch, heads, chunk, _ = q.shape
+    grid, rows, vector, square = _by_lane_specs(q, tile)
+    out = jax.ShapeDtypeStruct((chunks, batch, heads, chunk, chunk), q.dtype)
+    return pl.pallas_call(
+        functools.partial(_prepare_by_lane_fwd_kernel, chunk=chunk),
+        grid=grid,
+        in_specs=[rows, rows, rows, vector],
+        out_specs=[square, square],
+        out_shape=[out, out],
+        interpret=interpret,
+    )(q, k, gamma, _paired(beta))
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _prepare_by_lane_backward(q, k, gamma, beta, dsolved, dinside, tile: int, interpret: bool):
+    grid, rows, vector, square = _by_lane_specs(q, tile)
+    paired = _paired(beta)
+    dq, dk, dgamma, dbeta = pl.pallas_call(
+        functools.partial(_prepare_by_lane_bwd_kernel, chunk=q.shape[3]),
+        grid=grid,
+        in_specs=[rows, rows, rows, vector, square, square],
+        out_specs=[rows, rows, rows, vector],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(gamma.shape, jnp.float32), jax.ShapeDtypeStruct(paired.shape, jnp.float32),
+        ],
+        interpret=interpret,
+    )(q, k, gamma, paired, dsolved, dinside)
+    return dq, dk, dgamma, _unpaired(dbeta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _prepare_by_lane_in_vmem(q, k, g, beta, group: int, tile: int = LANE_CHUNK_TILE,
+                             interpret: Optional[bool] = None):
+    """:func:`_prepare_by_lane` as the two kernels above (``tile`` chunks a
+    grid step, an even number): same operands, same three results. Of a
+    direction's float32 arrays only ``gamma`` (which the scan reads) and ``d
+    gamma`` cross HBM, ``[N, B, H, C, d_k]`` each; the running sum and its
+    transpose are XLA's on either side of the call, as the scalar form's. The
+    backward kernel builds the decayed operands, the system and its inverse
+    again from the four operands, which are all the forward keeps."""
+    del group  # 1: gated_delta_rule has checked
+    gamma = jnp.cumsum(g, axis=-2)
+    solved, inside = _prepare_by_lane_forward(q, k, gamma, beta, tile, _interpreted(interpret))
+    return solved, inside, gamma
+
+
+def _prepare_by_lane_in_vmem_fwd(q, k, g, beta, group, tile, interpret):
+    return _prepare_by_lane_in_vmem(q, k, g, beta, group, tile, interpret), (q, k, g, beta)
+
+
+def _prepare_by_lane_in_vmem_bwd(group, tile, interpret, residuals, cotangents):
+    q, k, g, beta = residuals
+    dsolved, dinside, dgamma_out = cotangents
+    dq, dk, dgamma, dbeta = _prepare_by_lane_backward(
+        q, k, jnp.cumsum(g, axis=-2), beta, dsolved, dinside, tile, _interpreted(interpret)
+    )
+    dgamma = dgamma + dgamma_out
+    return dq, dk, jax.lax.cumsum(dgamma, axis=dgamma.ndim - 2, reverse=True), dbeta  # the running sum, transposed
+
+
+_prepare_by_lane_in_vmem.defvjp(_prepare_by_lane_in_vmem_fwd, _prepare_by_lane_in_vmem_bwd)
+
+
 def rule_form(chunks: int, chunk: int, key_dim: int, group: int, *, by_lane: bool = False,
               on_tpu: Optional[bool] = None) -> dict:
     """Which program computes the part that does not read the state, from
     what the code can observe: ``{"rule": "kernel", "chunk_tile": n}`` on a
     TPU where Mosaic takes the shapes, else ``{"rule": "xla", "refused":
-    why}``. A decay a key lane (``by_lane``) is XLA's program on every
-    backend, ``{"rule": "xla", "decay": "vector", "refused": why}``: the
-    kernels form ``k k^T`` once and scale it by a table. The kernels want a chunk of whole bfloat16 tiles (16 rows) that is a
-    power of two (the inverse doubles its blocks from 8 rows up), a key
-    head of whole lane tiles, the wide arrays (``group x chunk`` lanes) whole
-    lane tiles and no wider than ``_MAX_WIDTH``, and the chunks in tiles of
-    ``CHUNK_TILE`` (or all of them in one)."""
-    if by_lane:
-        return {"rule": "xla", "decay": "vector",
-                "refused": "a decay a key lane: the kernels scale k k^T by a table of one decay a head"}
+    why}``; with a decay a key lane (``by_lane``) either record also says
+    ``"decay": "vector"``. The kernels want a chunk of whole bfloat16 tiles (16
+    rows) that is a power of two (the inverse doubles its blocks from 8 rows
+    up), a key head of whole lane tiles, the wide arrays whole lane tiles and
+    no wider than ``_MAX_WIDTH`` (``group x chunk`` lanes: a key head's value
+    heads side by side; with a decay a key lane ``2 x chunk``, two chunks of
+    one head, so the chunks have to pair up), and the chunks in tiles of
+    ``CHUNK_TILE`` (``LANE_CHUNK_TILE`` with a decay a key lane), or all of
+    them in one."""
     if on_tpu is None:
         on_tpu = _attention._on_tpu()
-    width = group * chunk
+    decay = {"decay": "vector"} if by_lane else {}
+    tile = LANE_CHUNK_TILE if by_lane else CHUNK_TILE
+    width = (2 if by_lane else group) * chunk
     if not on_tpu:
         refused = "non-TPU backend"
     elif chunk % 16 or chunk & (chunk - 1):
@@ -568,12 +831,15 @@ def rule_form(chunks: int, chunk: int, key_dim: int, group: int, *, by_lane: boo
     elif key_dim % 128:
         refused = f"key head {key_dim} is not whole lane tiles"
     elif width % 128 or width > _MAX_WIDTH:
-        refused = f"{group} value heads a key head x chunk {chunk} = {width} lanes"
-    elif chunks % CHUNK_TILE and chunks > CHUNK_TILE:
-        refused = f"{chunks} chunks are not whole tiles of {CHUNK_TILE}"
+        side_by_side = "two chunks side by side" if by_lane else f"{group} value heads a key head"
+        refused = f"{side_by_side} x chunk {chunk} = {width} lanes"
+    elif by_lane and chunks % 2:
+        refused = f"{chunks} chunks do not pair up"
+    elif chunks % tile and chunks > tile:
+        refused = f"{chunks} chunks are not whole tiles of {tile}"
     else:
-        return {"rule": "kernel", "chunk_tile": min(CHUNK_TILE, chunks)}
-    return {"rule": "xla", "refused": refused}
+        return {"rule": "kernel", **decay, "chunk_tile": min(tile, chunks)}
+    return {"rule": "xla", **decay, "refused": refused}
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
@@ -610,12 +876,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
         )
     form = rule_form(-(-length // chunk), chunk, dk, heads // key_heads, by_lane=by_lane)
     _attention.log_rule_form((batch, length, key_heads, dk), heads, chunk, jnp.dtype(v.dtype).name, form)
-    if by_lane:
-        prepare = _prepare_by_lane
-    elif form["rule"] == "kernel":
-        prepare = functools.partial(_prepare_in_vmem, tile=form["chunk_tile"])
+    if form["rule"] == "kernel":
+        prepare = functools.partial(_prepare_by_lane_in_vmem if by_lane else _prepare_in_vmem, tile=form["chunk_tile"])
     else:
-        prepare = _prepare
+        prepare = _prepare_by_lane if by_lane else _prepare
     return _chunked(prepare, q, k, v, g, beta, chunk)
 
 

@@ -187,6 +187,10 @@ KERNEL_CASES = [
     # d_k], the fifth number the value heads; chunks of 64, the state-free
     # part in its two kernels (one a direction), eight chunks a grid step.
     ("gated_delta-qwen3_next_4k", "gated_delta", (4, 4096, 16, 128, 32)),
+    # The same rule with a decay a key lane at the vector-decay hybrid's cell:
+    # one key head a value head, two chunks of a head side by side a trip,
+    # sixteen chunks a grid step.
+    ("gated_delta_by_lane-ling_4k", "gated_delta_by_lane", (2, 4096, 32, 128)),
     # The causal depthwise convolution's kernels at the two cells that run
     # them, [B, S, C] and the taps: fused with a SiLU over q, k and v joined
     # (the hybrid decoder), between two gates on the thirds of the input
@@ -257,6 +261,15 @@ def _kernel_fn_and_args(kernel, shape, sharding):
         return (
             lambda q, k, v, g, beta: gated_delta._chunked(prepare, q, k, v, g, beta, gated_delta.CHUNK)[0]
         ), (qkv[0], qkv[1], spec(shape[:2] + (shape[4], dim)), gates, gates)
+    if kernel == "gated_delta_by_lane":
+        from sav_tpu.ops import gated_delta
+
+        form = gated_delta.rule_form(shape[1] // gated_delta.CHUNK, gated_delta.CHUNK, dim, 1, by_lane=True, on_tpu=True)
+        assert form == {"rule": "kernel", "decay": "vector", "chunk_tile": 16}
+        prepare = functools.partial(gated_delta._prepare_by_lane_in_vmem, tile=form["chunk_tile"], interpret=False)
+        return (
+            lambda q, k, v, g, beta: gated_delta._chunked(prepare, q, k, v, g, beta, gated_delta.CHUNK)[0]
+        ), qkv + (spec(shape, jnp.float32), spec(shape[:3], jnp.float32))
     if kernel in ("conv_silu", "conv_gated", "conv_key_head"):
         from sav_tpu.models.layers import causal_conv as forms
         from sav_tpu.ops.causal_conv import conv_form

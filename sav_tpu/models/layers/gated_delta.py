@@ -9,9 +9,9 @@ For ``H_k`` key heads of ``d_k`` feeding ``H`` value heads of ``d_v``
                                    # [q d_k | k d_k | v (H/H_k) d_v | z (H/H_k) d_v]
     [b | a] = x W_ba               # by key head too: [b H/H_k | a H/H_k]
     [q | k | v] = silu(conv([q | k | v]))   # causal, depthwise, width 4, no bias
-    q, k = l2norm(q), l2norm(k);  q = q d_k^-0.5
     beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)        # float32
-    o = gated_delta_rule(q, k, v, g, beta)          # sav_tpu/ops/gated_delta.py
+    o = gated_delta_rule_from_raw(q, k, v, g, beta)  # sav_tpu/ops/gated_delta.py, whose rule_operands computes
+    #   q, k = l2norm(q), l2norm(k);  q = q d_k^-0.5
     y = W_o (RMSNorm_{d_v}(o) w silu(z))            # per value head; w is plain
 
 Scopes, for the readers of a trace: the two input projections under
@@ -36,7 +36,7 @@ from sav_tpu.models.layers.causal_conv import (  # noqa: F401
     KERNEL_INIT, causal_conv_silu, causal_depthwise_conv, conv_silu_by_key_head, key_head_parts,
 )
 from sav_tpu.models.layers.feedforward import _bias_free_dense
-from sav_tpu.ops.gated_delta import CHUNK, gated_delta_rule
+from sav_tpu.ops.gated_delta import CHUNK, gated_delta_rule_from_raw, l2_normalise  # noqa: F401
 from sav_tpu.ops.quant import QuantDenseGeneral
 
 Dtype = Any
@@ -61,11 +61,6 @@ def split_gates(ba: jax.Array, key_heads: int, heads: int) -> tuple:
     lead = ba.shape[:-1]
     b, a = jnp.split(ba.reshape(lead + (key_heads, 2 * heads // key_heads)), 2, axis=-1)
     return b.reshape(lead + (heads,)), a.reshape(lead + (heads,))
-
-
-def l2_normalise(x: jax.Array, eps: float = 1e-6) -> jax.Array:
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
 
 
 class _InputProj(nn.Module):
@@ -162,18 +157,12 @@ class GatedDeltaNetBlock(nn.Module):
             q, k = (t.reshape(batch, seq, self.key_heads, self.key_ch) for t in (q, k))
             v, z = (t.reshape(batch, seq, self.heads, self.value_ch) for t in (v, z))
         with jax.named_scope("gdn/rule"):
-            @jax.checkpoint  # float32 inside; the backward pass starts from the operands in the compute dtype
-            def operands(q, k, b, a, a_log, dt_bias):
-                q = (l2_normalise(q) * self.key_ch ** -0.5).astype(self.dtype)
-                beta = jax.nn.sigmoid(b.astype(jnp.float32))
-                g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
-                return q, l2_normalise(k).astype(self.dtype), g, beta
-
-            q, k, g, beta = operands(q, k, b, a, a_log, dt_bias)
-            out, state = gated_delta_rule(q, k, v, g, beta, self.chunk)
+            beta = jax.nn.sigmoid(b.astype(jnp.float32))
+            g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
+            out, state, least = gated_delta_rule_from_raw(q, k, v, g, beta, self.chunk)
             out = checkpoint_name(out, "gdn_out")
             stats = jax.lax.stop_gradient({
-                "decay_min": jnp.exp(jnp.min(g)),
+                "decay_min": jnp.exp(least),
                 "state_rms_max": jnp.sqrt(jnp.max(jnp.mean(jnp.square(state), axis=(-2, -1)))),
             })
         with jax.named_scope("gdn/gate_norm"):

@@ -6,11 +6,11 @@ For ``H`` heads of ``d_k`` keys and ``d_v`` values (one key head a value head,
 no grouping)::
 
     q, k, v = silu(conv(x W_q)), silu(conv(x W_k)), silu(conv(x W_v))   # causal, depthwise, width 4, no bias
-    q = l2norm(q) d_k^-0.5;  k = l2norm(k)                              # per head, float32; no rotary
     a = x W_f                                                           # [H d_k], full rank
-    g = lower_bound sigmoid(exp(A_log_h) (a + dt_bias))                 # the SAFE gate: g in (lower_bound, 0) a lane
     beta = sigmoid(x W_b)                                               # [H]
-    o = gated_delta_rule(q, k, v, g, beta)           # sav_tpu/ops/gated_delta.py, g [B, L, H, d_k]
+    o = gated_delta_rule_from_raw(q, k, v, a, beta)  # sav_tpu/ops/gated_delta.py, whose rule_operands computes
+    #   q = l2norm(q) d_k^-0.5;  k = l2norm(k)                          # per head, float32; no rotary
+    #   g = lower_bound sigmoid(exp(A_log_h) (a + dt_bias))             # the SAFE gate: g in (lower_bound, 0) a lane
     y = W_o (RMSNorm_{d_v}(o) w sigmoid(x W_g))                         # per head; w is plain
 
 The six input projections are matrices of their own (``q``, ``k``, ``v``,
@@ -18,10 +18,12 @@ The six input projections are matrices of their own (``q``, ``k``, ``v``,
 fused by key head. ``lower_bound`` (the public config's ``kda_lower_bound``,
 -5) is what bounds the chunked rule's exponents: the paper's own gate,
 ``-exp(A_log) softplus(a + dt_bias)``, is unbounded below and is not built
-here (``ops/gated_delta.py`` says what it would take). The rule's state-free
-part is one Mosaic call a direction on a TPU at heads of whole lane tiles and
+here (``ops/gated_delta.py`` says what it would take). The rule's operands
+(the normalisation, the gate and its running sum inside a chunk, read from the
+flat arrays where they lie and written chunk-major) and its state-free part
+are one Mosaic call a direction each on a TPU at heads of whole lane tiles and
 an even number of chunks (``ops/gated_delta.py::rule_form``, ``decay: vector``
-in the dispatch log), XLA's program elsewhere.
+and ``operands: kernel`` in the dispatch log), XLA's program elsewhere.
 
 Scopes, for the readers of a trace: ``to_qkv`` and ``to_out`` hold the weight
 matmuls; the work between them lies under ``kda/conv`` (the three convolutions
@@ -42,8 +44,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from sav_tpu.models.layers.causal_conv import KERNEL_INIT, causal_conv_silu
 from sav_tpu.models.layers.feedforward import _bias_free_dense
-from sav_tpu.models.layers.gated_delta import _GatedNorm, _decay_rates, l2_normalise
-from sav_tpu.ops.gated_delta import CHUNK, gated_delta_rule
+from sav_tpu.models.layers.gated_delta import _GatedNorm, _decay_rates
+from sav_tpu.ops.gated_delta import CHUNK, gated_delta_rule_from_raw
 from sav_tpu.ops.quant import QuantDenseGeneral
 
 Dtype = Any
@@ -126,18 +128,14 @@ class KDABlock(nn.Module):
         with jax.named_scope("kda/conv"):
             q, k, v = (checkpoint_name(t, "kda_conv") for t in _CausalConvs(self.conv_width, name="conv")(q, k, v))
         with jax.named_scope("kda/rule"):
-            @jax.checkpoint  # float32 inside; the backward pass starts from the operands in the compute dtype
-            def operands(q, k, a, b, a_log, dt_bias):
-                q = (l2_normalise(by_head(q, self.key_ch)) * self.key_ch ** -0.5).astype(self.dtype)
-                k = l2_normalise(by_head(k, self.key_ch)).astype(self.dtype)
-                rate = jnp.exp(a_log)[:, None] * by_head(a.astype(jnp.float32) + dt_bias, self.key_ch)
-                return q, k, self.lower_bound * jax.nn.sigmoid(rate), jax.nn.sigmoid(b.astype(jnp.float32))
-
-            q, k, g, beta = operands(q, k, a, b, a_log, dt_bias)
-            out, state = gated_delta_rule(q, k, by_head(v, self.value_ch), g, beta, self.chunk)
+            out, state, least = gated_delta_rule_from_raw(
+                by_head(q, self.key_ch), by_head(k, self.key_ch), by_head(v, self.value_ch), by_head(a, self.key_ch),
+                jax.nn.sigmoid(b.astype(jnp.float32)), self.chunk,
+                a_log=a_log, dt_bias=dt_bias, lower_bound=self.lower_bound,
+            )
             out = checkpoint_name(out, "kda_out")
             stats = jax.lax.stop_gradient({
-                "decay_min": jnp.min(g),
+                "decay_min": least,
                 "state_rms_max": jnp.sqrt(jnp.max(jnp.mean(jnp.square(state), axis=(-2, -1)))),
             })
         with jax.named_scope("kda/gate_norm"):

@@ -267,8 +267,12 @@ def log_rule_form(shape, value_heads: int, chunk: int, dtype: str, form: dict) -
     of q and k, the value heads, the chunk, and the form: ``rule`` (``kernel``
     | ``xla``) with its ``chunk_tile``, or with what ``refused`` the kernels;
     ``decay: vector`` beside either where the decay is one a key lane (its
-    kernels are ``_prepare_by_lane_in_vmem``'s)."""
-    _log_once(("gated_delta_rule", shape, value_heads, chunk, dtype, form["rule"], form.get("decay")), {
+    kernels are ``_prepare_by_lane_in_vmem``'s); and who computes the rule's
+    operands (the normalisation of q and k, a vector gate and its running
+    sum): ``operands`` ``kernel`` with its ``operands_tile``, ``xla`` with
+    ``operands_refused``, or ``given`` where the caller normalised."""
+    key = ("gated_delta_rule", shape, value_heads, chunk, dtype, form["rule"], form.get("decay"), form["operands"])
+    _log_once(key, {
         "op": "gated_delta_rule", "shape": list(shape), "value_heads": value_heads,
         "chunk": chunk, "dtype": dtype, **form,
     })

@@ -48,8 +48,7 @@ the shapes (the choice is a record of the dispatch log,
   written out: from the same four operands and the two cotangents it builds
   ``T`` and the decay again, applies the inverse's own derivative ``dA = -T^T
   dT T^T``, and writes dq and dk (summed over the group inside the products),
-  ``d gamma`` and ``d beta``. The running sum ``gamma`` and its transpose are
-  XLA's, on 2 MB a layer.
+  ``d gamma`` and ``d beta``. ``gamma`` comes summed (:func:`rule_operands`).
 * ``xla`` (:func:`_prepare`; everything else, and what the tests hold the
   kernels to): the same for all chunks at once as XLA's program, ``k k^T`` and
   ``q k^T`` repeated a value head through HBM, the inverse by
@@ -108,12 +107,44 @@ same two programs (``decay: vector`` in :func:`rule_form`'s record):
   rows`` on the sub-block's rows and ``- d before before`` up to its end (the
   reference row cancels in every pair term and is a constant of the
   derivative). ``gamma`` and ``d gamma``, ``[N, B, H, C, d_k]`` float32, are
-  the only float32 arrays of a direction that cross HBM; the running sum and
-  its transpose are XLA's.
+  the only float32 arrays of a direction that cross HBM; ``gamma`` comes
+  summed (:func:`rule_operands`).
 * ``xla`` (:func:`_prepare_by_lane`; everything else, and what the tests hold
   the kernels to): the sub-blocks' products for all chunks at once, each under
   a ``jax.checkpoint`` of its own, the inverse and the backward as the scalar
   form's.
+
+**The operands.** A block holds q and k as its convolution left them (``[B,
+L, H_k, d_k]``, flat in memory) and its gate: the log decay a head ``g [B, L,
+H]``, or, for a decay a key lane, the pre-activation ``a [B, L, H, d_k]`` of
+the safe gate ``g = lower_bound sigmoid(exp(A_log) (a + dt_bias))``.
+:func:`gated_delta_rule_from_raw` takes those; :func:`rule_operands` makes of
+them what the rule reads, chunk-major: q and k L2-normalised in float32 (q
+scaled by ``d_k^-0.5``) and rounded once to their dtype, and ``gamma``, by one
+of two programs that :func:`rule_form` picks (``operands`` in its record):
+
+* ``kernel`` (:func:`_operands_in_vmem`; a TPU, a chunk of whole 16-row tiles,
+  a key head of whole lane tiles): one Pallas call a direction whose grid step
+  reads a tile of chunks of one (batch, head) as rows of the flat arrays where
+  they lie and writes them chunk-major (the same block of memory seen as
+  ``[tile, C, d_k]``: the turn costs nothing here, and only here). With a
+  decay a key lane the float32 gate, 134 MB a layer of the vector-decay cell,
+  never crosses HBM: the call writes its running sum inside each chunk
+  (:func:`_running_sum`) and the gate's least entry; rows past the sequence
+  get ``g = 0, k = 0`` by their index. The backward is written out: the
+  norms' and the sigmoid's derivatives and the sum's transpose in VMEM, dq, dk
+  and ``da`` written flat where the convolution's backward call and the
+  gate's projection read them, ``d A_log`` and ``d dt_bias`` summed over the
+  sequential chunk axis. Each result leaves once a reader (the state-free
+  part, the scan), so that their cotangents arrive apart and are summed in
+  VMEM, not by XLA over 3 x 134 MB.
+* ``xla`` (:func:`_operands`; everything else, and what the tests hold the
+  kernels to): the blocks' float32 lines of before, :func:`_by_chunk`'s turns
+  and ``jnp.cumsum`` as XLA's program under one ``jax.checkpoint``.
+
+A decay a head is 2 MB a layer: its turn and sum stay XLA's in either program.
+:func:`gated_delta_rule` itself takes q and k normalised and ``g`` (the tests'
+entry; ``operands: given`` in the record) and turns and sums them so.
 
 Precision, in either program: the running sums, ``exp``, the triangular system
 and its inverse (exact: the doubling of :func:`_doubled_inverse`, in the kernels
@@ -223,23 +254,33 @@ def _doubled_inverse(lower: jax.Array) -> jax.Array:
     return solved
 
 
-def _by_chunk(x: jax.Array, chunks: int, chunk: int) -> jax.Array:
-    """``[B, L, H, ...]`` -> ``[N, B, H, C, ...]``."""
-    batch = x.shape[0]
-    x = x.reshape((batch, chunks, chunk) + x.shape[2:])
+def _by_chunk(x: jax.Array, chunk: int) -> jax.Array:
+    """``[B, L, H, ...]`` -> ``[N, B, H, C, ...]``, ``L`` padded to whole
+    chunks with zeros."""
+    batch, length = x.shape[:2]
+    x = jnp.pad(x, ((0, 0), (0, -length % chunk)) + ((0, 0),) * (x.ndim - 2))
+    x = x.reshape((batch, -1, chunk) + x.shape[2:])
     return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)  # [N, B, C, H, ...] -> [N, B, H, C, ...]
 
 
+def _summed_by_chunk(g: jax.Array, chunk: int) -> jax.Array:
+    """``g [B, L, H(, d_k)]`` -> its running sum inside each chunk, ``[N, B,
+    H, C(, d_k)]`` float32, as XLA's program. JAX transposes the sum as
+    ``jax.lax.cumsum(reverse=True)``, never as flips (PERF.md section 6, PR
+    44)."""
+    return jnp.cumsum(_by_chunk(g.astype(jnp.float32), chunk), axis=3)
+
+
 @functools.partial(jax.checkpoint, static_argnums=(4,))
-def _prepare(q, k, g, beta, group: int):
+def _prepare(q, k, gamma, beta, group: int):
     """Everything of a chunk that does not read the state, for all chunks at
-    once, on ``[N, B, H, C, ...]`` operands: ``(T beta, the masked Q K^T,
-    gamma)``, the first two ``[N, B, H, C, C]`` in q's dtype. Checkpointed:
+    once, on ``[N, B, H, C, ...]`` operands (``gamma`` the running sum of the
+    decay's log inside a chunk): ``(T beta, the masked Q K^T)``, ``[N, B, H,
+    C, C]`` in q's dtype. Checkpointed:
     the backward pass computes it again from the four operands, after the
     scan's own backward is done with its residuals, and holds the float32 ``C
     x C`` tensors of one direction at a time."""
     chunk, dtype = q.shape[3], q.dtype
-    gamma = jnp.cumsum(g, axis=-1)
     rows = jnp.arange(chunk)
     visible = rows[:, None] >= rows[None, :]
     # exp of a masked difference: the upper triangle would overflow.
@@ -253,21 +294,20 @@ def _prepare(q, k, g, beta, group: int):
     system = jnp.where(strict, beta[..., :, None] * pairs(k, k) * decay, 0.0)
     solved = (_unit_lower_inverse(system) * beta[..., None, :]).astype(dtype)  # T beta
     inside = (pairs(q, k) * decay).astype(dtype)  # lower(Q K^T exp(.)), diagonal included
-    return solved, inside, gamma
+    return solved, inside
 
 
 @functools.partial(jax.checkpoint, static_argnums=(4,))
-def _prepare_by_lane(q, k, g, beta, group: int):
-    """:func:`_prepare` where the decay is a vector a key lane: ``g [N, B, H,
-    C, d_k]``, one key head a value head (``group`` 1). The pair terms
+def _prepare_by_lane(q, k, gamma, beta, group: int):
+    """:func:`_prepare` where the decay is a vector a key lane: ``gamma [N, B,
+    H, C, d_k]``, one key head a value head (``group`` 1). The pair terms
     ``sum_c x_ic k_jc exp(gamma_ic - gamma_jc)`` a sub-block of rows at a
     time, against the rows up to that sub-block's end, with its first row as
     the reference (the module docstring has the bound on every exponent);
     float32 at ``HIGHEST``, the upper triangle masked after the product."""
-    del group  # 1: gated_delta_rule has checked
+    del group  # 1: the rule has checked
     chunk, dtype = q.shape[3], q.dtype
     sub = math.gcd(chunk, SUB_BLOCK)
-    gamma = jnp.cumsum(g, axis=-2)
 
     @functools.partial(jax.checkpoint, static_argnums=(3,))  # a sub-block's float32 operands live for its own turn only
     def rows_of(q, k, gamma, low: int):
@@ -290,7 +330,7 @@ def _prepare_by_lane(q, k, g, beta, group: int):
     system = jnp.where(rows[:, None] > rows[None, :], beta[..., :, None] * kk, 0.0)
     solved = (_unit_lower_inverse(system) * beta[..., None, :]).astype(dtype)  # T beta
     inside = jnp.where(rows[:, None] >= rows[None, :], qk, 0.0).astype(dtype)
-    return solved, inside, gamma
+    return solved, inside
 
 
 # ---------------------------------------------------------------------------
@@ -544,30 +584,20 @@ def _interpreted(interpret: Optional[bool]) -> bool:
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _prepare_in_vmem(q, k, g, beta, group: int, tile: int = CHUNK_TILE, interpret: Optional[bool] = None):
-    """:func:`_prepare` as the two kernels above: same operands, same three
+def _prepare_in_vmem(q, k, gamma, beta, group: int, tile: int = CHUNK_TILE, interpret: Optional[bool] = None):
+    """:func:`_prepare` as the two kernels above: same operands, same two
     results, nothing ``C x C`` in float32 in HBM in either direction. The
-    running sum ``gamma`` (2 MB a layer) is XLA's on both sides of the call;
-    the backward kernel builds the system, its inverse and the decay again
-    from the four operands, which are all the forward keeps."""
-    gamma = jnp.cumsum(g, axis=-1)
-    solved, inside = _prepare_forward(q, k, gamma, beta, group, tile, _interpreted(interpret))
-    return solved, inside, gamma
+    backward kernel builds the system, its inverse and the decay again from
+    the four operands, which are all the forward keeps."""
+    return tuple(_prepare_forward(q, k, gamma, beta, group, tile, _interpreted(interpret)))
 
 
-def _prepare_in_vmem_fwd(q, k, g, beta, group, tile, interpret):
-    return _prepare_in_vmem(q, k, g, beta, group, tile, interpret), (q, k, g, beta)
+def _prepare_in_vmem_fwd(q, k, gamma, beta, group, tile, interpret):
+    return _prepare_in_vmem(q, k, gamma, beta, group, tile, interpret), (q, k, gamma, beta)
 
 
 def _prepare_in_vmem_bwd(group, tile, interpret, residuals, cotangents):
-    q, k, g, beta = residuals
-    dsolved, dinside, dgamma_out = cotangents
-    dq, dk, dgamma, dbeta = _prepare_backward(
-        q, k, jnp.cumsum(g, axis=-1), beta, dsolved, dinside, group, tile, _interpreted(interpret)
-    )
-    dgamma = dgamma + dgamma_out
-    dg = jnp.flip(jnp.cumsum(jnp.flip(dgamma, -1), axis=-1), -1)  # the running sum, transposed
-    return dq, dk, dg, dbeta
+    return _prepare_backward(*residuals, *cotangents, group, tile, _interpreted(interpret))
 
 
 _prepare_in_vmem.defvjp(_prepare_in_vmem_fwd, _prepare_in_vmem_bwd)
@@ -773,73 +803,420 @@ def _prepare_by_lane_backward(q, k, gamma, beta, dsolved, dinside, tile: int, in
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _prepare_by_lane_in_vmem(q, k, g, beta, group: int, tile: int = LANE_CHUNK_TILE,
+def _prepare_by_lane_in_vmem(q, k, gamma, beta, group: int, tile: int = LANE_CHUNK_TILE,
                              interpret: Optional[bool] = None):
     """:func:`_prepare_by_lane` as the two kernels above (``tile`` chunks a
-    grid step, an even number): same operands, same three results. Of a
-    direction's float32 arrays only ``gamma`` (which the scan reads) and ``d
-    gamma`` cross HBM, ``[N, B, H, C, d_k]`` each; the running sum and its
-    transpose are XLA's on either side of the call, as the scalar form's. The
-    backward kernel builds the decayed operands, the system and its inverse
-    again from the four operands, which are all the forward keeps."""
-    del group  # 1: gated_delta_rule has checked
-    gamma = jnp.cumsum(g, axis=-2)
-    solved, inside = _prepare_by_lane_forward(q, k, gamma, beta, tile, _interpreted(interpret))
-    return solved, inside, gamma
+    grid step, an even number): same operands, same two results. Of a
+    direction's float32 arrays only ``gamma`` and ``d gamma`` cross HBM, ``[N,
+    B, H, C, d_k]`` each. The backward kernel builds the decayed operands, the
+    system and its inverse again from the four operands, which are all the
+    forward keeps."""
+    del group  # 1: the rule has checked
+    return tuple(_prepare_by_lane_forward(q, k, gamma, beta, tile, _interpreted(interpret)))
 
 
-def _prepare_by_lane_in_vmem_fwd(q, k, g, beta, group, tile, interpret):
-    return _prepare_by_lane_in_vmem(q, k, g, beta, group, tile, interpret), (q, k, g, beta)
+def _prepare_by_lane_in_vmem_fwd(q, k, gamma, beta, group, tile, interpret):
+    return _prepare_by_lane_in_vmem(q, k, gamma, beta, group, tile, interpret), (q, k, gamma, beta)
 
 
 def _prepare_by_lane_in_vmem_bwd(group, tile, interpret, residuals, cotangents):
-    q, k, g, beta = residuals
-    dsolved, dinside, dgamma_out = cotangents
-    dq, dk, dgamma, dbeta = _prepare_by_lane_backward(
-        q, k, jnp.cumsum(g, axis=-2), beta, dsolved, dinside, tile, _interpreted(interpret)
-    )
-    dgamma = dgamma + dgamma_out
-    return dq, dk, jax.lax.cumsum(dgamma, axis=dgamma.ndim - 2, reverse=True), dbeta  # the running sum, transposed
+    return _prepare_by_lane_backward(*residuals, *cotangents, tile, _interpreted(interpret))
 
 
 _prepare_by_lane_in_vmem.defvjp(_prepare_by_lane_in_vmem_fwd, _prepare_by_lane_in_vmem_bwd)
 
 
+# ---------------------------------------------------------------------------
+# The rule's operands, as one Pallas kernel a direction: a grid step holds a
+# tile of chunks of one (batch, key head), read as rows of the flat ``[B, L, H
+# d]`` arrays the convolution and the projection wrote (a ``[tile x C, d_k]``
+# block of the flat array IS a ``[tile, C, d_k]`` block of the chunk-major one:
+# writing chunk-major is the one place the turn is free), and walks them a
+# chunk at a time in registers. Bandwidth-bound bodies: a lane reduction a row
+# for each norm, a sigmoid a lane for the gate, the running sum inside a chunk
+# by :func:`_running_sum`.
+# ---------------------------------------------------------------------------
+
+OPERANDS_TILE = 16  # chunks a grid step: 1,024 rows of one head
+# How a chunk's rows are summed in VMEM: "rolls" (log2 C sublane rolls and
+# adds) or "triangle" (a [C, C] triangle of ones against the chunk at HIGHEST).
+# tools/kda_prepare_micro.py times both (PERF.md section 6, PR 45).
+_SUM_FORM = "rolls"
+
+
+_NORM_EPS = 1e-6
+
+
+def l2_normalise(x: jax.Array, eps: float = _NORM_EPS) -> jax.Array:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def _running_sum(x, reverse: bool = False):
+    """The running sum down the rows of ``x [C, lanes]`` float32 (up them,
+    from the last row, where ``reverse``: the sum's transpose), to float32
+    rounding what ``jnp.cumsum`` gives."""
+    chunk = x.shape[0]
+    if _SUM_FORM == "triangle":
+        row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+        ones = jnp.where(row <= col if reverse else row >= col, 1.0, 0.0)
+        return jnp.dot(ones, x, precision=_HIGHEST, preferred_element_type=jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    step = 1
+    while step < chunk:  # row i gains row i - step's sum of step rows
+        if reverse:
+            x = x + jnp.where(row < chunk - step, pltpu.roll(x, chunk - step, 0), 0.0)
+        else:
+            x = x + jnp.where(row >= step, pltpu.roll(x, step, 0), 0.0)
+        step *= 2
+    return x
+
+
+def _chunk_rows(ref, c, chunk: int, live):
+    """Chunk ``c``'s rows of a flat block, float32, zero past the sequence."""
+    x = ref[pl.ds(pl.multiple_of(c * chunk, chunk), chunk), :].astype(jnp.float32)
+    return x if live is None else jnp.where(live, x, 0.0)
+
+
+def _live_rows(tile: int, chunk: int, lanes: int, length: int):
+    """``live(c)``: which rows of chunk ``c`` of this grid step lie inside the
+    sequence (``None`` where every block does: ``length`` is whole tiles)."""
+    if length % (tile * chunk) == 0:
+        return lambda c: None
+    first = pl.program_id(2) * tile  # read here: the interpreter knows no program_id inside a loop's body
+    return lambda c: (first + c) * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, lanes), 0) < length
+
+
+def _operands_fwd_kernel(*refs, chunk: int, length: int, lower_bound):
+    """``refs``: q's and k's rows, with a decay a key lane the gate's ``a``
+    and the head's rate and offsets; then the results: q and k normalised,
+    chunk-major, and with the gate ``gamma`` and the least ``g`` a lane so
+    far (one block a (batch, head), over the sequential chunk axis)."""
+    by_lane = len(refs) > 4
+    operands = 5 if by_lane else 2
+    q_ref, k_ref, *gate_refs = refs[:operands]
+    q_out, k_out, *gate_outs = refs[operands:]
+    tile, dk = q_out.shape[0], q_ref.shape[-1]
+    if by_lane:
+        a_ref, rate_ref, offset_ref = gate_refs
+        gamma_out, least_out = gate_outs
+
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            least_out[...] = jnp.zeros_like(least_out)  # g <= 0
+
+    live_rows = _live_rows(tile, chunk, dk, length)
+
+    def one_chunk(c):
+        live = live_rows(c)
+
+        def unit(ref):  # l2_normalise's arithmetic; a row past the sequence is 0
+            x = _chunk_rows(ref, c, chunk, live)
+            return x * jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + _NORM_EPS)
+
+        q_out[c, 0, 0] = (unit(q_ref) * dk ** -0.5).astype(q_out.dtype)
+        k_out[c, 0, 0] = unit(k_ref).astype(k_out.dtype)
+        if by_lane:
+            g = lower_bound * jax.nn.sigmoid(rate_ref[...] * (_chunk_rows(a_ref, c, chunk, None) + offset_ref[...]))
+            g = g if live is None else jnp.where(live, g, 0.0)
+            gamma_out[c, 0, 0] = _running_sum(g)
+            least_out[0, 0] = jnp.minimum(least_out[0, 0], jnp.min(g, axis=0, keepdims=True))
+
+    _for_each_chunk(tile, one_chunk)
+
+
+def _operands_bwd_kernel(*refs, chunk: int, length: int, lower_bound):
+    """``refs``: the forward's operands; the chunk-major cotangents of q and k
+    normalised and, with the gate, of ``gamma``, once a reader of the
+    forward's results (the state-free part, then the scan) and summed here;
+    then the results: dq, dk and ``da`` as rows of the flat arrays, and the
+    sums over this (batch, head)'s rows so far of ``d offsets`` and ``d rate``
+    a lane."""
+    by_lane = len(refs) > 8
+    operands, n = (5, 3) if by_lane else (2, 2)  # the forward's operands, its results a reader
+    q_ref, k_ref, *gate_refs = refs[:operands]
+    once, again, results = refs[operands:operands + n], refs[operands + n:operands + 2 * n], refs[operands + 2 * n:]
+    if by_lane:
+        a_ref, rate_ref, offset_ref = gate_refs
+        dq_ref, dk_ref, da_ref, sums_ref = results
+
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            sums_ref[...] = jnp.zeros_like(sums_ref)
+    else:
+        dq_ref, dk_ref = results
+    tile, dk = once[0].shape[0], q_ref.shape[-1]
+    live_rows = _live_rows(tile, chunk, dk, length)
+
+    def one_chunk(c):
+        live = live_rows(c)
+        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        dqn, dkn, *dgamma = (a[c, 0, 0].astype(jnp.float32) + b[c, 0, 0].astype(jnp.float32) for a, b in zip(once, again))
+
+        def through_unit(ref, dy, scale):  # y = scale x r, r = rsqrt(x . x + eps): dx = scale r (dy - r^2 x (dy . x))
+            x = _chunk_rows(ref, c, chunk, live)
+            r = jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + _NORM_EPS)
+            return (scale * r) * (dy - (r * r * jnp.sum(dy * x, axis=1, keepdims=True)) * x)
+
+        dq_ref[rows, :] = through_unit(q_ref, dqn, dk ** -0.5).astype(dq_ref.dtype)
+        dk_ref[rows, :] = through_unit(k_ref, dkn, 1.0).astype(dk_ref.dtype)
+        if by_lane:
+            dg = _running_sum(dgamma[0], reverse=True)
+            dg = dg if live is None else jnp.where(live, dg, 0.0)  # a row past the sequence has g = 0 whatever a is
+            rate = rate_ref[...]
+            shifted = _chunk_rows(a_ref, c, chunk, live) + offset_ref[...]
+            s = jax.nn.sigmoid(rate * shifted)
+            dscaled = dg * (lower_bound * s * (1.0 - s))  # d (rate x shifted)
+            da = dscaled * rate
+            da_ref[rows, :] = da.astype(da_ref.dtype)
+            sums_ref[0, 0, 0:1, :] += jnp.sum(da, axis=0, keepdims=True)
+            sums_ref[0, 0, 1:2, :] += jnp.sum(dscaled * shifted, axis=0, keepdims=True)
+
+    _for_each_chunk(tile, one_chunk)
+
+
+def _operands_specs(q, chunk: int, tile: int):
+    batch, length, key_heads, dk = q.shape
+    grid = (batch, key_heads, -(-length // chunk) // tile)
+    flat = pl.BlockSpec((None, tile * chunk, dk), lambda b, h, n: (b, n, h))
+    major = pl.BlockSpec((tile, 1, 1, chunk, dk), lambda b, h, n: (n, b, h, 0, 0))
+    a_head = pl.BlockSpec((None, 1, dk), lambda b, h, n: (h, 0, 0))  # of [H, 1, d_k]
+    params = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return grid, flat, major, a_head, params
+
+
+def _flat(x):  # [B, L, H, d] -> [B, L, H d]: the array as the convolution's call wrote it
+    return x.reshape(x.shape[:2] + (-1,))
+
+
+def _operands_inputs(q, k, gate, flat, a_head):
+    """Both calls' operands and their specs: q's and k's rows and, with the
+    gate, ``a``'s, the rate ``[H]`` and the offsets ``[H d_k]`` as ``[H, 1,
+    d_k]`` float32."""
+    if gate is None:
+        return [_flat(q), _flat(k)], [flat, flat]
+    heads, dk = q.shape[2:]
+    a, *by_head = gate
+    by_head = [jnp.broadcast_to(x.astype(jnp.float32).reshape(heads, 1, -1), (heads, 1, dk)) for x in by_head]
+    return [_flat(q), _flat(k), _flat(a)] + by_head, [flat, flat, flat, a_head, a_head]
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "lower_bound", "tile", "interpret"))
+def _operands_forward(q, k, gate, chunk: int, lower_bound, tile: int, interpret: bool):
+    """``q, k [B, L, H_k, d_k]`` and ``gate`` ``None`` or ``(a [B, L, H, d_k],
+    rate [H], offsets [H d_k])`` -> q and k normalised ``[N, B, H_k, C,
+    d_k]`` and, with the gate, ``gamma [N, B, H, C, d_k]`` float32 and the
+    least ``g`` a (batch, head, lane). Jitted, as :func:`_prepare_forward` is
+    and for its reason."""
+    batch, length, heads, dim = q.shape
+    grid, flat, major, a_head, params = _operands_specs(q, chunk, tile)
+    chunks = grid[2] * tile
+    by_chunk = jax.ShapeDtypeStruct((chunks, batch, heads, chunk, dim), q.dtype)
+    operands, in_specs = _operands_inputs(q, k, gate, flat, a_head)
+    out_specs, out_shape = [major, major], [by_chunk, by_chunk]
+    if gate is not None:
+        out_specs += [major, pl.BlockSpec((1, 1, 1, dim), lambda b, h, n: (b, h, 0, 0))]
+        out_shape += [
+            jax.ShapeDtypeStruct(by_chunk.shape, jnp.float32), jax.ShapeDtypeStruct((batch, heads, 1, dim), jnp.float32)
+        ]
+    return pl.pallas_call(
+        functools.partial(_operands_fwd_kernel, chunk=chunk, length=length, lower_bound=lower_bound),
+        grid=grid, in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        compiler_params=params, interpret=interpret,
+    )(*operands)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "lower_bound", "tile", "interpret"))
+def _operands_backward(q, k, gate, cotangents, chunk: int, lower_bound, tile: int, interpret: bool):
+    """The forward's operands and the cotangents of its results (of q and k
+    normalised and, with the gate, of ``gamma``: a reader's, then the
+    other's) -> ``dq, dk`` and, with the gate, ``(da, d rate, d offsets)``,
+    as the operands lie."""
+    batch, length, heads, dim = q.shape
+    grid, flat, major, a_head, params = _operands_specs(q, chunk, tile)
+    rows = jax.ShapeDtypeStruct(_flat(q).shape, q.dtype)
+    operands, in_specs = _operands_inputs(q, k, gate, flat, a_head)
+    out_specs, out_shape = [flat, flat], [rows, rows]
+    if gate is not None:
+        a, _, offsets = gate
+        out_specs += [flat, pl.BlockSpec((1, 1, 2, dim), lambda b, h, n: (b, h, 0, 0))]
+        out_shape += [jax.ShapeDtypeStruct(rows.shape, a.dtype), jax.ShapeDtypeStruct((batch, heads, 2, dim), jnp.float32)]
+    dq, dk, *dgate = pl.pallas_call(
+        functools.partial(_operands_bwd_kernel, chunk=chunk, length=length, lower_bound=lower_bound),
+        grid=grid, in_specs=in_specs + [major] * len(cotangents), out_specs=out_specs, out_shape=out_shape,
+        compiler_params=params, interpret=interpret,
+    )(*operands, *cotangents)
+    dq, dk = dq.reshape(q.shape), dk.reshape(k.shape)
+    if gate is None:
+        return dq, dk, None
+    da, sums = dgate
+    sums = jnp.sum(sums, axis=0)  # over the batch: [H, 2, d_k], kilobytes
+    return dq, dk, (da.reshape(a.shape), jnp.sum(sums[:, 1], axis=-1), sums[:, 0].reshape(offsets.shape))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _operands_in_vmem(q, k, gate, chunk: int, lower_bound, tile: int = OPERANDS_TILE,
+                      interpret: Optional[bool] = None):
+    """:func:`_operands` as the two kernels above: same operands, same
+    results. Nothing of them crosses HBM but the arrays read where they lie
+    and the chunk-major results; the backward kernel computes the norms and
+    the gate again from the operands, which are all the forward keeps. The
+    results leave once a reader (the same arrays twice: JAX adds the
+    cotangents of a result with two readers before a backward sees them, 3 x
+    134 MB through HBM for ``gamma`` a layer of the vector-decay cell; two
+    results' arrive apart and are summed in VMEM, in float32)."""
+    if gate is None:
+        q, k = _operands_forward(q, k, None, chunk, lower_bound, tile, _interpreted(interpret))
+        return (q, k, None), (q, k, None), None
+    a, a_log, dt_bias = gate
+    *operands, least = _operands_forward(
+        q, k, (a, jnp.exp(a_log), dt_bias), chunk, lower_bound, tile, _interpreted(interpret)
+    )
+    return tuple(operands), tuple(operands), jnp.min(least)
+
+
+def _operands_in_vmem_fwd(q, k, gate, chunk, lower_bound, tile, interpret):
+    return _operands_in_vmem(q, k, gate, chunk, lower_bound, tile, interpret), (q, k, gate)
+
+
+def _operands_in_vmem_bwd(chunk, lower_bound, tile, interpret, residuals, cotangents):
+    q, k, gate = residuals
+    once, again, _ = cotangents
+    if gate is None:
+        return _operands_backward(q, k, None, once[:2] + again[:2], chunk, lower_bound, tile, _interpreted(interpret))
+    a, a_log, dt_bias = gate
+    rate = jnp.exp(a_log)
+    dq, dk, (da, drate, doffsets) = _operands_backward(
+        q, k, (a, rate, dt_bias), once + again, chunk, lower_bound, tile, _interpreted(interpret)
+    )
+    return dq, dk, (da, (drate * rate).astype(a_log.dtype), doffsets.astype(dt_bias.dtype))
+
+
+_operands_in_vmem.defvjp(_operands_in_vmem_fwd, _operands_in_vmem_bwd)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))  # float32 inside; the backward pass starts from the operands
+def _operands(q, k, gate, chunk: int, lower_bound):
+    """What a block hands the rule, as XLA's program: ``q, k [B, L, H_k,
+    d_k]`` as the convolution left them -> L2-normalised (q scaled by
+    ``d_k^-0.5``), rounded once to their dtype, chunk-major ``[N, B, H_k, C,
+    d_k]``; with ``gate = (a [B, L, H, d_k], A_log [H], dt_bias [H d_k])``,
+    the safe gate ``g = lower_bound sigmoid(exp(A_log) (a + dt_bias))`` a key
+    lane, float32, and its running sum inside each chunk. ``((q, k, gamma),
+    the same again, the least g)``: once a reader; ``None`` for ``gamma`` and
+    the least without a gate."""
+    dtype, dk = q.dtype, q.shape[-1]
+    q = _by_chunk((l2_normalise(q) * dk ** -0.5).astype(dtype), chunk)
+    k = _by_chunk(l2_normalise(k).astype(dtype), chunk)
+    if gate is None:
+        return (q, k, None), (q, k, None), None
+    a, a_log, dt_bias = gate
+    rate = jnp.exp(a_log)[:, None] * (a.astype(jnp.float32) + dt_bias.reshape(a.shape[2:]))
+    g = lower_bound * jax.nn.sigmoid(rate)
+    operands = (q, k, _summed_by_chunk(g, chunk))
+    return operands, operands, jnp.min(g)
+
+
 def rule_form(chunks: int, chunk: int, key_dim: int, group: int, *, by_lane: bool = False,
               on_tpu: Optional[bool] = None) -> dict:
-    """Which program computes the part that does not read the state, from
-    what the code can observe: ``{"rule": "kernel", "chunk_tile": n}`` on a
-    TPU where Mosaic takes the shapes, else ``{"rule": "xla", "refused":
-    why}``; with a decay a key lane (``by_lane``) either record also says
-    ``"decay": "vector"``. The kernels want a chunk of whole bfloat16 tiles (16
-    rows) that is a power of two (the inverse doubles its blocks from 8 rows
-    up), a key head of whole lane tiles, the wide arrays whole lane tiles and
-    no wider than ``_MAX_WIDTH`` (``group x chunk`` lanes: a key head's value
-    heads side by side; with a decay a key lane ``2 x chunk``, two chunks of
-    one head, so the chunks have to pair up), and the chunks in tiles of
-    ``CHUNK_TILE`` (``LANE_CHUNK_TILE`` with a decay a key lane), or all of
-    them in one."""
+    """Which programs compute the rule's operands and the part that does not
+    read the state, from what the code can observe. The state-free part:
+    ``{"rule": "kernel", "chunk_tile": n}`` on a TPU where Mosaic takes the
+    shapes, else ``{"rule": "xla", "refused": why}``; with a decay a key lane
+    (``by_lane``) either record also says ``"decay": "vector"``. The kernels
+    want a chunk of whole bfloat16 tiles (16 rows) that is a power of two (the
+    inverse doubles its blocks from 8 rows up), a key head of whole lane tiles,
+    the wide arrays whole lane tiles and no wider than ``_MAX_WIDTH`` (``group
+    x chunk`` lanes: a key head's value heads side by side; with a decay a key
+    lane ``2 x chunk``, two chunks of one head, so the chunks have to pair up),
+    and the chunks in tiles of ``CHUNK_TILE`` (``LANE_CHUNK_TILE`` with a decay
+    a key lane), or all of them in one. The operands (:func:`rule_operands`),
+    beside either: ``"operands": "kernel", "operands_tile": n`` (the most
+    chunks up to ``OPERANDS_TILE`` that divide the sequence's) on a TPU at a
+    chunk of whole 16-row tiles and a key head of whole lane tiles, else
+    ``"operands": "xla", "operands_refused": why``."""
     if on_tpu is None:
         on_tpu = _attention._on_tpu()
     decay = {"decay": "vector"} if by_lane else {}
     tile = LANE_CHUNK_TILE if by_lane else CHUNK_TILE
     width = (2 if by_lane else group) * chunk
+    lanes = f"key head {key_dim} is not whole lane tiles" if key_dim % 128 else None
     if not on_tpu:
-        refused = "non-TPU backend"
+        refused = operands_refused = "non-TPU backend"
     elif chunk % 16 or chunk & (chunk - 1):
         refused = f"chunk {chunk} is not a power of two of whole 16-row tiles"
-    elif key_dim % 128:
-        refused = f"key head {key_dim} is not whole lane tiles"
-    elif width % 128 or width > _MAX_WIDTH:
-        side_by_side = "two chunks side by side" if by_lane else f"{group} value heads a key head"
-        refused = f"{side_by_side} x chunk {chunk} = {width} lanes"
-    elif by_lane and chunks % 2:
-        refused = f"{chunks} chunks do not pair up"
-    elif chunks % tile and chunks > tile:
-        refused = f"{chunks} chunks are not whole tiles of {tile}"
+        operands_refused = f"chunk {chunk} is not whole 16-row tiles" if chunk % 16 else lanes
+    elif lanes:
+        refused = operands_refused = lanes
     else:
-        return {"rule": "kernel", **decay, "chunk_tile": min(tile, chunks)}
-    return {"rule": "xla", **decay, "refused": refused}
+        operands_refused = None
+        if width % 128 or width > _MAX_WIDTH:
+            side_by_side = "two chunks side by side" if by_lane else f"{group} value heads a key head"
+            refused = f"{side_by_side} x chunk {chunk} = {width} lanes"
+        elif by_lane and chunks % 2:
+            refused = f"{chunks} chunks do not pair up"
+        elif chunks % tile and chunks > tile:
+            refused = f"{chunks} chunks are not whole tiles of {tile}"
+        else:
+            refused = None
+    form = {"rule": "xla", **decay, "refused": refused} if refused else {
+        "rule": "kernel", **decay, "chunk_tile": min(tile, chunks)
+    }
+    if operands_refused:
+        return {**form, "operands": "xla", "operands_refused": operands_refused}
+    most = next(n for n in range(min(chunks, OPERANDS_TILE), 0, -1) if chunks % n == 0)
+    return {**form, "operands": "kernel", "operands_tile": most}
+
+
+def rule_operands(q, k, gate, chunk: int = CHUNK, *, a_log=None, dt_bias=None, lower_bound=None):
+    """A block's arrays -> the rule's operands, chunk-major: ``(q, k [N, B,
+    H_k, C, d_k]`` L2-normalised (q scaled by ``d_k^-0.5``) in their dtype,
+    ``gamma [N, B, H, C(, d_k)]``: the running sum of the decay's log inside
+    each chunk, float32``)``, once for the state-free part and once for the
+    scan (the same arrays), and the least log decay of the call.
+
+    ``q, k [B, L, H_k, d_k]`` as the convolution left them. What ``gate`` is
+    tells the decay: ``g [B, L, H]``, the log of a decay a head, summed by
+    XLA (2 MB a layer); or ``a [B, L, H, d_k]``, the pre-activation of the
+    safe gate a key lane ``g = lower_bound sigmoid(exp(a_log) (a +
+    dt_bias))``, which never crosses HBM where the kernels run
+    (:func:`_operands_in_vmem`; ``form["operands"]``, :func:`rule_form`'s to
+    say), only its running sum does."""
+    by_lane = gate.ndim == 4
+    form = rule_form(-(-q.shape[1] // chunk), chunk, q.shape[-1], gate.shape[2] // q.shape[2], by_lane=by_lane)
+    packed = (gate, a_log, dt_bias) if by_lane else None
+    if form["operands"] == "kernel":
+        for_prepare, for_scan, least = _operands_in_vmem(q, k, packed, chunk, lower_bound, form["operands_tile"])
+    else:
+        for_prepare, for_scan, least = _operands(q, k, packed, chunk, lower_bound)
+    if not by_lane:
+        gamma, least = _summed_by_chunk(gate, chunk), jnp.min(gate)
+        for_prepare, for_scan = for_prepare[:2] + (gamma,), for_scan[:2] + (gamma,)
+    return for_prepare, for_scan, least
+
+
+def _checked_form(q, k, v, g, beta, chunk: int) -> tuple:
+    """The rule's shapes checked; ``(its form, the state-free part's program)``."""
+    batch, length, key_heads, dk = q.shape
+    heads = v.shape[2]
+    by_lane = g.ndim == 4
+    lanes_fit = g.shape[3:] == (dk,) and heads == key_heads if by_lane else True
+    if (heads % key_heads or k.shape != q.shape or g.shape[:3] != v.shape[:3] or beta.shape != g.shape[:3]
+            or not lanes_fit):
+        raise ValueError(
+            f"gated delta rule: q {q.shape}, k {k.shape}, v {v.shape}, g {g.shape}, beta {beta.shape}"
+        )
+    form = rule_form(-(-length // chunk), chunk, dk, heads // key_heads, by_lane=by_lane)
+    if form["rule"] == "kernel":
+        prepare = functools.partial(_prepare_by_lane_in_vmem if by_lane else _prepare_in_vmem, tile=form["chunk_tile"])
+    else:
+        prepare = _prepare_by_lane if by_lane else _prepare
+    return form, prepare
+
+
+def _log_form(q, v, chunk: int, form: dict) -> None:
+    _attention.log_rule_form(q.shape, v.shape[2], chunk, jnp.dtype(v.dtype).name, form)
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
@@ -863,48 +1240,50 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
 
     Which program computes the part that does not read the state is
     :func:`rule_form`'s to say, from the backend and the shapes; the choice
-    is one record of the dispatch log (``ops/attention.py``).
+    is one record of the dispatch log (``ops/attention.py``), with
+    ``operands: given``: they are turned chunk-major and summed by XLA. A
+    block hands the rule its arrays as they lie:
+    :func:`gated_delta_rule_from_raw`.
     """
-    batch, length, key_heads, dk = q.shape
-    heads = v.shape[2]
-    by_lane = g.ndim == 4
-    lanes_fit = g.shape[3:] == (dk,) and heads == key_heads if by_lane else True
-    if (heads % key_heads or k.shape != q.shape or g.shape[:3] != v.shape[:3] or beta.shape != g.shape[:3]
-            or not lanes_fit):
-        raise ValueError(
-            f"gated delta rule: q {q.shape}, k {k.shape}, v {v.shape}, g {g.shape}, beta {beta.shape}"
-        )
-    form = rule_form(-(-length // chunk), chunk, dk, heads // key_heads, by_lane=by_lane)
-    _attention.log_rule_form((batch, length, key_heads, dk), heads, chunk, jnp.dtype(v.dtype).name, form)
-    if form["rule"] == "kernel":
-        prepare = functools.partial(_prepare_by_lane_in_vmem if by_lane else _prepare_in_vmem, tile=form["chunk_tile"])
-    else:
-        prepare = _prepare_by_lane if by_lane else _prepare
-    return _chunked(prepare, q, k, v, g, beta, chunk)
+    form, prepare = _checked_form(q, k, v, g, beta, chunk)
+    form = {name: value for name, value in form.items() if not name.startswith("operands")}
+    _log_form(q, v, chunk, {**form, "operands": "given"})
+    operands = (_by_chunk(q, chunk), _by_chunk(k, chunk), _summed_by_chunk(g, chunk))
+    return _chunked(prepare, operands, operands, v, beta)
 
 
-def _chunked(prepare, q, k, v, g, beta, chunk: int):
-    """:func:`gated_delta_rule` with the state-free part computed by
-    ``prepare(q, k, g, beta, group)`` on ``[N, B, H, C, ...]`` operands:
-    :func:`_prepare`, :func:`_prepare_in_vmem` or, for a decay a key lane (``g
-    [B, L, H, d_k]``), :func:`_prepare_by_lane`. The scan is one for both
-    decays but for where the decay goes: a scalar scales rows of the float32
-    results, a vector the lanes of q and k before the products."""
-    batch, length, key_heads, dk = q.shape
-    heads, dv = v.shape[2:]
+def gated_delta_rule_from_raw(q, k, v, gate, beta, chunk: int = CHUNK, *, a_log=None, dt_bias=None, lower_bound=None):
+    """:func:`gated_delta_rule` from a block's own arrays: ``q, k [B, L, H_k,
+    d_k]`` as the convolution left them, normalised here, and ``gate`` the
+    log decay a head ``g [B, L, H]`` or the safe gate's pre-activation a key
+    lane ``a [B, L, H, d_k]`` with its ``a_log [H]``, ``dt_bias [H d_k]`` and
+    ``lower_bound`` (:func:`rule_operands`: one Mosaic call a direction where
+    :func:`rule_form` says ``operands: kernel``). Returns ``(o, the final
+    state, the least log decay of the call)``."""
+    form, prepare = _checked_form(q, k, v, gate, beta, chunk)
+    _log_form(q, v, chunk, form)
+    for_prepare, for_scan, least = rule_operands(q, k, gate, chunk, a_log=a_log, dt_bias=dt_bias, lower_bound=lower_bound)
+    return _chunked(prepare, for_prepare, for_scan, v, beta) + (least,)
+
+
+def _chunked(prepare, for_prepare, for_scan, v, beta):
+    """The rule on chunk-major operands ``(q, k [N, B, H_k, C, d_k], gamma
+    [N, B, H, C(, d_k)])``, :func:`rule_operands`' results: once for the
+    state-free part and once for the scan; ``v [B, L, H, d_v]`` and ``beta
+    [B, L, H]`` as the block has them. The state-free part is computed by
+    ``prepare(q, k, gamma, beta, group)``: :func:`_prepare`,
+    :func:`_prepare_in_vmem` or, for a decay a key lane, their ``by_lane``
+    forms. The scan is one for both decays but for where the decay goes: a
+    scalar scales rows of the float32 results, a vector the lanes of q and k
+    before the products."""
+    chunks, batch, key_heads, chunk, dk = for_scan[0].shape
+    length, heads, dv = v.shape[1:]
     group, dtype = heads // key_heads, v.dtype
-    pad = -length % chunk
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in (q, k, v, g, beta)
-        )
-    chunks = (length + pad) // chunk
-    q, k, v = (_by_chunk(x, chunks, chunk) for x in (q, k, v))  # [N, B, H, C, d]
-    by_lane = g.ndim == 4
-    g = _by_chunk(g.astype(jnp.float32), chunks, chunk)  # [N, B, H, C] or, a decay a lane, [N, B, H, C, d_k]
-    beta = _by_chunk(beta.astype(jnp.float32), chunks, chunk)
+    by_lane = for_scan[2].ndim == 5
+    v = _by_chunk(v, chunk)  # [N, B, H, C, d_v]
+    beta = _by_chunk(beta.astype(jnp.float32), chunk)
 
-    solved, inside, gamma = prepare(q, k, g, beta, group)
+    solved, inside = prepare(*for_prepare, beta, group)
     # Tagged for a caller's remat policy: with these kept (134 MB a layer at 4
     # x 4,096 tokens) its recomputation of the layer runs the scan alone.
     solved, inside = (checkpoint_name(x, "gdn_solved") for x in (solved, inside))
@@ -942,6 +1321,7 @@ def _chunked(prepare, q, k, v, g, beta, chunk: int):
         return state, out.astype(dtype)
 
     start = jnp.zeros((batch, heads, dk, dv), jnp.float32)
+    q, k, gamma = for_scan
     final, out = jax.lax.scan(step, start, (q, k, v, solved, inside, gamma))
     out = jnp.moveaxis(jnp.moveaxis(out, 2, 3), 0, 1)  # [B, N, C, H, d_v]
     return out.reshape(batch, chunks * chunk, heads, dv)[:, :length], final

@@ -37,11 +37,15 @@ from sav_tpu.ops.causal_conv import conv_form
 from sav_tpu.ops.gated_delta import (
     _by_chunk,
     _chunked,
+    _operands,
+    _operands_in_vmem,
     _prepare,
     _prepare_in_vmem,
+    _summed_by_chunk,
     _unit_lower_inverse,
     _wide_inverse,
     gated_delta_rule,
+    gated_delta_rule_from_raw,
     gated_delta_rule_recurrent,
     rule_form,
 )
@@ -58,7 +62,12 @@ def in_vmem(tile):
     """The chunked rule with its state-free part in the kernels, interpreted,
     ``tile`` chunks a grid step."""
     prepare = functools.partial(_prepare_in_vmem, tile=tile, interpret=True)
-    return jax.jit(functools.partial(_chunked, prepare), static_argnames="chunk")
+
+    def rule(q, k, v, g, beta, chunk):
+        operands = (_by_chunk(q, chunk), _by_chunk(k, chunk), _summed_by_chunk(g, chunk))
+        return _chunked(prepare, operands, operands, v, beta)
+
+    return jax.jit(rule, static_argnames="chunk")
 
 
 def close(got, want, tol=TIGHT):
@@ -190,19 +199,19 @@ def test_the_kernels_results_are_xlas_on_bfloat16_operands():
     beta`` and the masked ``Q K^T`` to a bfloat16 unit in the last place, the
     four gradients to the rounding of the bfloat16 cotangents' products."""
     q, k, _, g, beta = operands(128, 2, 4, "mixed", dtype=jnp.bfloat16)
-    q, k = (_by_chunk(x, 2, 64) for x in (q, k))
-    g, beta = (_by_chunk(x, 2, 64) for x in (g, beta))
+    q, k, beta = (_by_chunk(x, 64) for x in (q, k, beta))
+    gamma = _summed_by_chunk(g, 64)
     weights = jax.random.normal(jax.random.PRNGKey(7), (2, 2, 2, 4, 64, 64))
 
     def scalar(prepare):
         def f(*a):
-            solved, inside, gamma = prepare(*a, 2)
-            assert solved.dtype == inside.dtype == jnp.bfloat16 and gamma.dtype == jnp.float32
-            return jnp.sum(weights[0] * solved) + jnp.sum(weights[1] * inside) + jnp.sum(jnp.sin(gamma)), (solved, inside)
+            solved, inside = prepare(*a, 2)
+            assert solved.dtype == inside.dtype == jnp.bfloat16
+            return jnp.sum(weights[0] * solved) + jnp.sum(weights[1] * inside), (solved, inside)
         return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3), has_aux=True))
 
-    (_, got), got_grads = scalar(functools.partial(_prepare_in_vmem, tile=2, interpret=True))(q, k, g, beta)
-    (_, want), want_grads = scalar(_prepare)(q, k, g, beta)
+    (_, got), got_grads = scalar(functools.partial(_prepare_in_vmem, tile=2, interpret=True))(q, k, gamma, beta)
+    (_, want), want_grads = scalar(_prepare)(q, k, gamma, beta)
     for a, b in zip(got, want):
         assert close(a.astype(jnp.float32), b.astype(jnp.float32), 2 ** -7)
     for a, b in zip(got_grads, want_grads):
@@ -237,8 +246,8 @@ def test_an_ill_conditioned_chunk_goes_through_the_forward_kernel():
     entries are ``k_i . k_j`` in 0.9 to 1, and ``T beta`` is its inverse."""
     chunk, dk = 64, 32
     k = l2_normalise(1.0 + 0.25 * jax.random.normal(jax.random.PRNGKey(3), (1, 1, 1, chunk, dk)))
-    g, beta = jnp.zeros((1, 1, 2, chunk)), jnp.ones((1, 1, 2, chunk))
-    solved, inside, gamma = _prepare_in_vmem(k, k, g, beta, 2, tile=1, interpret=True)
+    gamma, beta = jnp.zeros((1, 1, 2, chunk)), jnp.ones((1, 1, 2, chunk))
+    solved, inside = _prepare_in_vmem(k, k, gamma, beta, 2, tile=1, interpret=True)
     pairs = np.asarray(jnp.einsum("id,jd->ij", k[0, 0, 0], k[0, 0, 0]), np.float64)
     assert np.min(np.tril(pairs, -1) + np.triu(np.ones_like(pairs))) > 0.85
     want = np.linalg.inv(np.eye(chunk) + np.tril(pairs, -1))
@@ -246,28 +255,115 @@ def test_an_ill_conditioned_chunk_goes_through_the_forward_kernel():
     for h in range(2):
         assert np.allclose(np.asarray(solved[0, 0, h], np.float64), want, atol=1e-4 * np.abs(want).max())
         assert np.allclose(np.asarray(inside[0, 0, h]), np.tril(pairs), atol=1e-5)
-    assert not np.any(np.asarray(gamma))
+
+
+def in_vmem_operands(tile):
+    """The operands' kernels in the interpreter, ``tile`` chunks a grid step."""
+    return lambda q, k, gate, chunk, bound=None: _operands_in_vmem(q, k, gate, chunk, bound, tile, True)
+
+
+OPERANDS_IN_VMEM = {"operands": "kernel", "operands_tile": 16}
+# (length, chunk, chunks a grid step): whole chunks in two grid steps; a padded
+# last chunk in one step and in two; chunks of one 16-row tile.
+OPERAND_LENGTHS = [(256, 64, 2), (150, 64, 3), (250, 64, 2), (90, 16, 2)]
+
+
+def raw_operands(length, dtype=jnp.float32, seed=0, batch=2, key_heads=2, dk=128):
+    """q and k as the convolution leaves them (a SiLU: a common component)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return tuple(jax.nn.silu(jax.random.normal(key, (batch, length, key_heads, dk)) + 0.5).astype(dtype) for key in ks)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("length,chunk,tile", OPERAND_LENGTHS)
+def test_the_operands_kernels_normalise_as_xlas_program(length, chunk, tile, dtype):
+    """The scalar decay's operands: q and k normalised, rounded once to their
+    dtype and chunk-major, zero past the sequence, and dq, dk through the
+    normalisation's own derivative; the gate's part is absent (``gate`` None:
+    ``g [B, L, H]`` is summed by XLA)."""
+    q, k = raw_operands(length, dtype)
+    weights = jax.random.normal(jax.random.PRNGKey(3), (2, -(-length // chunk), 2, 2, chunk, 128))
+
+    def both(program):
+        def f(q, k):
+            (qn, kn, gamma), again, least = program(q, k, None, chunk)
+            assert gamma is None and least is None and again[2] is None
+            return jnp.sum(weights[0] * qn.astype(jnp.float32)) + jnp.sum(weights[1] * kn.astype(jnp.float32)), (qn, kn)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(q, k)
+
+    (_, got), got_grads = both(in_vmem_operands(tile))
+    (_, want), want_grads = both(lambda q, k, gate, chunk: _operands(q, k, gate, chunk, None))
+    pad = -length % chunk
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype == dtype and a.shape == b.shape == weights.shape[1:]
+        assert close(a.astype(jnp.float32), b.astype(jnp.float32), 2.0 ** -7 if dtype == jnp.bfloat16 else 1e-6)
+        assert not pad or not np.any(np.asarray(a[-1, :, :, chunk - pad:].astype(jnp.float32)))
+    for a, b in zip(got_grads, want_grads):
+        assert a.dtype == b.dtype == dtype and a.shape == q.shape
+        assert close(a.astype(jnp.float32), b.astype(jnp.float32), 2e-2 if dtype == jnp.bfloat16 else 1e-5)
+
+
+@pytest.mark.parametrize("decay", sorted(DECAYS))
+@pytest.mark.parametrize("length", [128, 150])
+def test_the_rule_from_a_blocks_arrays_is_the_recurrence(length, decay, monkeypatch):
+    """Through the new entry, on this backend (XLA's programs) and as a TPU
+    would run it (``rule_form`` told so: both pairs of kernels in the
+    interpreter), with all five gradients: against the recurrence on
+    operands normalised outside. dq and dk pass through the normalisation,
+    which takes out a gradient's largest part (the one along the vector): what
+    is left is held to 1e-4 of its largest entry (it reads 1.4e-5), and ``dg``
+    under decays near 0 to 5e-3 (2.2e-3: the note at the top)."""
+    _, _, v, g, beta = operands(length, 1, 2, decay, dv=32, batch=1)
+    q, k = raw_operands(length, batch=1, key_heads=1)
+    normalised = lambda rule: lambda q, k, *rest: rule(l2_normalise(q) * 128 ** -0.5, l2_normalise(k), *rest)
+
+    def scalar(rule):
+        def f(*a):
+            out, state, *least = rule(*a)
+            return jnp.sum(jnp.sin(out)) + jnp.sum(jnp.square(state)), (out, state, least)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4), has_aux=True))  # a trace a call: the form is picked in it
+
+    (_, (want, want_state, _)), want_grads = scalar(normalised(gated_delta_rule_recurrent))(q, k, v, g, beta)
+    for on_tpu in (False, True):
+        monkeypatch.setattr(attention, "_on_tpu", lambda: on_tpu)
+        (_, (out, state, (least,))), grads = scalar(gated_delta_rule_from_raw)(q, k, v, g, beta)
+        assert close(out, want) and close(state, want_state) and float(least) == float(jnp.min(g))
+        for name, a, b in zip("q k v g beta".split(), grads, want_grads):
+            tol = 5e-3 if (name, decay) == ("g", "near_0") else 1e-4 if name in "qk" else TIGHT
+            assert close(a, b, tol), (on_tpu, name)
 
 
 RULE_FORMS = [
-    # (chunks, chunk, d_k, value heads a key head, on a TPU) -> the form
-    ((64, 64, 128, 2, True), {"rule": "kernel", "chunk_tile": 8}),  # the hybrid decoder's cell
-    ((3, 64, 128, 2, True), {"rule": "kernel", "chunk_tile": 3}),  # one tile holds every chunk
-    ((16, 128, 256, 1, True), {"rule": "kernel", "chunk_tile": 8}),
-    ((64, 64, 128, 2, False), {"rule": "xla", "refused": "non-TPU backend"}),
-    ((64, 64, 128, 1, True), {"rule": "xla", "refused": "1 value heads a key head x chunk 64 = 64 lanes"}),
-    ((64, 64, 128, 16, True), {"rule": "xla", "refused": "16 value heads a key head x chunk 64 = 1024 lanes"}),
-    ((64, 64, 64, 2, True), {"rule": "xla", "refused": "key head 64 is not whole lane tiles"}),
-    ((64, 24, 128, 16, True), {"rule": "xla", "refused": "chunk 24 is not a power of two of whole 16-row tiles"}),
-    ((64, 48, 128, 8, True), {"rule": "xla", "refused": "chunk 48 is not a power of two of whole 16-row tiles"}),
-    ((12, 64, 128, 2, True), {"rule": "xla", "refused": "12 chunks are not whole tiles of 8"}),
+    # (chunks, chunk, d_k, value heads a key head, on a TPU) -> the state-free part's form, the operands'
+    ((64, 64, 128, 2, True), {"rule": "kernel", "chunk_tile": 8}, OPERANDS_IN_VMEM),  # the hybrid decoder's cell
+    ((3, 64, 128, 2, True), {"rule": "kernel", "chunk_tile": 3},
+     {"operands": "kernel", "operands_tile": 3}),  # one tile holds every chunk
+    ((16, 128, 256, 1, True), {"rule": "kernel", "chunk_tile": 8}, OPERANDS_IN_VMEM),
+    ((64, 64, 128, 2, False), {"rule": "xla", "refused": "non-TPU backend"},
+     {"operands": "xla", "operands_refused": "non-TPU backend"}),
+    ((64, 64, 128, 1, True), {"rule": "xla", "refused": "1 value heads a key head x chunk 64 = 64 lanes"},
+     OPERANDS_IN_VMEM),  # the operands' calls know no group
+    ((64, 64, 128, 16, True), {"rule": "xla", "refused": "16 value heads a key head x chunk 64 = 1024 lanes"},
+     OPERANDS_IN_VMEM),
+    ((64, 64, 64, 2, True), {"rule": "xla", "refused": "key head 64 is not whole lane tiles"},
+     {"operands": "xla", "operands_refused": "key head 64 is not whole lane tiles"}),
+    ((64, 24, 128, 16, True), {"rule": "xla", "refused": "chunk 24 is not a power of two of whole 16-row tiles"},
+     {"operands": "xla", "operands_refused": "chunk 24 is not whole 16-row tiles"}),
+    ((64, 48, 128, 8, True), {"rule": "xla", "refused": "chunk 48 is not a power of two of whole 16-row tiles"},
+     OPERANDS_IN_VMEM),  # three 16-row tiles: no inverse to double
+    ((64, 48, 192, 8, True), {"rule": "xla", "refused": "chunk 48 is not a power of two of whole 16-row tiles"},
+     {"operands": "xla", "operands_refused": "key head 192 is not whole lane tiles"}),
+    ((12, 64, 128, 2, True), {"rule": "xla", "refused": "12 chunks are not whole tiles of 8"},
+     {"operands": "kernel", "operands_tile": 12}),
+    ((63, 64, 128, 2, True), {"rule": "xla", "refused": "63 chunks are not whole tiles of 8"},
+     {"operands": "kernel", "operands_tile": 9}),  # the most chunks up to 16 that divide 63
 ]
 
 
-@pytest.mark.parametrize("shape,form", RULE_FORMS, ids=[str(shape) for shape, _ in RULE_FORMS])
-def test_the_rule_picks_its_program_from_the_backend_and_the_shapes(shape, form):
+@pytest.mark.parametrize("shape,form,operands_form", RULE_FORMS, ids=[str(shape) for shape, _, _ in RULE_FORMS])
+def test_the_rule_picks_its_program_from_the_backend_and_the_shapes(shape, form, operands_form):
     *sizes, on_tpu = shape
-    assert rule_form(*sizes, on_tpu=on_tpu) == form
+    assert rule_form(*sizes, on_tpu=on_tpu) == {**form, **operands_form}
 
 
 def test_the_dispatch_log_records_the_rules_form(monkeypatch):
@@ -287,8 +383,17 @@ def test_the_dispatch_log_records_the_rules_form(monkeypatch):
     attention.clear_dispatch_log()
     common = {"op": "gated_delta_rule", "shape": [1, 192, 2, 128], "value_heads": 4, "chunk": 64, "dtype": "bfloat16"}
     assert log == [
-        {**common, "rule": "xla", "refused": "non-TPU backend"},
-        {**common, "rule": "kernel", "chunk_tile": 3},
+        {**common, "rule": "xla", "refused": "non-TPU backend", "operands": "given"},
+        {**common, "rule": "kernel", "chunk_tile": 3, "operands": "given"},
+    ]
+    jax.eval_shape(lambda *a: gated_delta_rule_from_raw(*a), *shapes)  # the same shapes from a block's own arrays
+    monkeypatch.setattr(attention, "_on_tpu", lambda: False)
+    jax.eval_shape(lambda *a: gated_delta_rule_from_raw(*a), *shapes)
+    log = attention.snapshot_dispatch_log()
+    attention.clear_dispatch_log()
+    assert log == [
+        {**common, "rule": "kernel", "chunk_tile": 3, "operands": "kernel", "operands_tile": 3},
+        {**common, "rule": "xla", "refused": "non-TPU backend", "operands": "xla", "operands_refused": "non-TPU backend"},
     ]
 
 
@@ -493,6 +598,39 @@ def test_the_block_runs_the_kernels_on_its_projection_and_notes_it(monkeypatch):
     for (path, one), (_, ref) in zip(flat(got), flat(want)):
         # The decay's two leaves sum a sequence's terms of both signs: XLA's own two orders differ by 2e-4.
         assert close(one, ref, 2e-3 if "A_log" in (name := jax.tree_util.keystr(path)) or "dt_bias" in name else 1e-5), name
+
+
+def test_the_block_hands_the_rule_its_arrays_where_they_lie(monkeypatch):
+    """The block with ``rule_form`` told it is on a TPU (the operands' kernels
+    in the interpreter; chunks of 16 at two value heads a key head are 32
+    lanes wide, so the state-free part stays XLA's) against the block as it
+    runs here: the same output, stats and gradient of every leaf, and the
+    record says who computed the operands."""
+    import types
+    from sav_tpu.ops import gated_delta as rule_ops
+
+    block = GatedDeltaNetBlock(key_heads=1, heads=2, key_ch=128, value_ch=128, chunk=16)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, 64))  # a ragged last chunk
+    variables = jax.jit(block.init)({"params": jax.random.PRNGKey(1)}, x)
+
+    def loss(params, x):
+        out, stats = block.apply({"params": params}, x)
+        return jnp.sum(jnp.square(out)), stats
+
+    both = lambda: jax.jit(jax.value_and_grad(loss, has_aux=True))(variables["params"], x)
+    want = both()
+    attention.clear_dispatch_log()
+    monkeypatch.setattr(rule_ops, "_attention", types.SimpleNamespace(
+        _on_tpu=lambda: True, log_rule_form=attention.log_rule_form))
+    got = both()
+    log = [line for line in attention.snapshot_dispatch_log() if line["op"] == "gated_delta_rule"]
+    attention.clear_dispatch_log()
+    assert log == [{"op": "gated_delta_rule", "shape": [2, 40, 1, 128], "value_heads": 2, "chunk": 16, "dtype": "float32",
+                    "rule": "xla", "refused": "2 value heads a key head x chunk 16 = 32 lanes",
+                    "operands": "kernel", "operands_tile": 3}]
+    flat = lambda tree: jax.tree_util.tree_flatten_with_path(tree)[0]
+    for (path, one), (_, ref) in zip(flat(got), flat(want)):
+        assert close(one, ref, 1e-5), jax.tree_util.keystr(path)
 
 
 def test_the_fused_projection_is_split_by_key_head():

@@ -202,6 +202,28 @@ KERNEL_CASES = [
 ]
 
 
+def _rule_from_raw(prepare, form):
+    """The delta rule as a TPU runs it from a block's own arrays, the
+    kernels compiled (``interpret=False``: on this backend the rule's own
+    entry would pick XLA's programs, or the interpreter): the operands' two
+    calls at ``form``'s tile (a scalar decay's ``g`` summed by XLA), the
+    state-free part's two, the scan."""
+    from sav_tpu.ops import gated_delta
+
+    prepare = functools.partial(prepare, tile=form["chunk_tile"], interpret=False)
+
+    def rule(q, k, v, gate, beta):
+        by_lane = isinstance(gate, tuple)
+        for_prepare, for_scan, _ = gated_delta._operands_in_vmem(
+            q, k, gate if by_lane else None, gated_delta.CHUNK, -5.0, form["operands_tile"], False)
+        if not by_lane:
+            gamma = gated_delta._summed_by_chunk(gate, gated_delta.CHUNK)
+            for_prepare, for_scan = for_prepare[:2] + (gamma,), for_scan[:2] + (gamma,)
+        return gated_delta._chunked(prepare, for_prepare, for_scan, v, beta)[0]
+
+    return rule
+
+
 def _kernel_fn_and_args(kernel, shape, sharding):
     from sav_tpu.ops.flash_attention import (
         flash_attention,
@@ -255,21 +277,19 @@ def _kernel_fn_and_args(kernel, shape, sharding):
         from sav_tpu.ops import gated_delta
 
         form = gated_delta.rule_form(shape[1] // gated_delta.CHUNK, gated_delta.CHUNK, dim, shape[4] // heads, on_tpu=True)
-        assert form == {"rule": "kernel", "chunk_tile": 8}
-        prepare = functools.partial(gated_delta._prepare_in_vmem, tile=form["chunk_tile"], interpret=False)
+        assert form == {"rule": "kernel", "chunk_tile": 8, "operands": "kernel", "operands_tile": 16}
         gates = spec(shape[:2] + (shape[4],), jnp.float32)
-        return (
-            lambda q, k, v, g, beta: gated_delta._chunked(prepare, q, k, v, g, beta, gated_delta.CHUNK)[0]
-        ), (qkv[0], qkv[1], spec(shape[:2] + (shape[4], dim)), gates, gates)
+        return _rule_from_raw(gated_delta._prepare_in_vmem, form), (
+            qkv[0], qkv[1], spec(shape[:2] + (shape[4], dim)), gates, gates)
     if kernel == "gated_delta_by_lane":
         from sav_tpu.ops import gated_delta
 
         form = gated_delta.rule_form(shape[1] // gated_delta.CHUNK, gated_delta.CHUNK, dim, 1, by_lane=True, on_tpu=True)
-        assert form == {"rule": "kernel", "decay": "vector", "chunk_tile": 16}
-        prepare = functools.partial(gated_delta._prepare_by_lane_in_vmem, tile=form["chunk_tile"], interpret=False)
+        assert form == {"rule": "kernel", "decay": "vector", "chunk_tile": 16, "operands": "kernel", "operands_tile": 16}
+        rule = _rule_from_raw(gated_delta._prepare_by_lane_in_vmem, form)
         return (
-            lambda q, k, v, g, beta: gated_delta._chunked(prepare, q, k, v, g, beta, gated_delta.CHUNK)[0]
-        ), qkv + (spec(shape, jnp.float32), spec(shape[:3], jnp.float32))
+            lambda q, k, v, a, beta, a_log, dt_bias: rule(q, k, v, (a, a_log, dt_bias), beta)
+        ), qkv + (qkv[0], spec(shape[:3], jnp.float32), spec((heads,), jnp.float32), spec((heads * dim,), jnp.float32))
     if kernel in ("conv_silu", "conv_gated", "conv_key_head"):
         from sav_tpu.models.layers import causal_conv as forms
         from sav_tpu.ops.causal_conv import conv_form
@@ -331,6 +351,21 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shape, direction):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _bytes_on_device(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("kernel,shape", [case[1:] for case in KERNEL_CASES if case[1].startswith("gated_delta")],
+                         ids=[case[0] for case in KERNEL_CASES if case[1].startswith("gated_delta")])
+def test_the_delta_rule_is_four_calls_and_the_scan_from_a_blocks_arrays(one_chip, kernel, shape):
+    """Forward and backward in one program: the operands' call and the
+    state-free part's, a direction each, and with a decay a key lane no
+    running sum of XLA's (``reduce-window``) on the 134 MB of ``g``."""
+    fn, args = _kernel_fn_and_args(kernel, shape, one_chip)
+    both = lambda *a: jax.value_and_grad(lambda *b: fn(*b).astype(jnp.float32).sum(), argnums=tuple(range(len(a))))(*a)
+    text = jax.jit(both).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    for name in ("_operands_forward", "_operands_backward"):
+        assert sum(name in line for line in text.splitlines() if "tpu_custom_call" in line) == 1, name
+    assert ("reduce-window" in text) == (kernel == "gated_delta")
 
 
 def _kernel_vmem(compiled, which: str, field: str = "size") -> list:

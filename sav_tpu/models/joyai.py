@@ -1,13 +1,14 @@
 """The decoder of the expert families: a pre-norm stack of token mixers
 (latent attention; gated delta-rule blocks with a gated grouped-query
 attention block every few layers; double-gated short convolutions with a
-grouped-query attention block between them; or vector-decay delta-rule
-blocks with a latent attention block every sixth layer), routed experts with
+grouped-query attention block between them; vector-decay delta-rule
+blocks with a latent attention block every sixth layer; or sliding-window
+grouped-query attention with a full layer of fewer heads every fourth), routed experts with
 a shared expert or without, and multi-token-prediction modules, whose
 residual path is plain or a set of hyper-connected streams, whose head is its
 own matrix or the embedding's table.
 
-Five registry entries build it. ``joyai_llm_flash``: sizes of
+Six registry entries build it. ``joyai_llm_flash``: sizes of
 ``https://huggingface.co/jdopensource/JoyAI-LLM-Flash/blob/main/config.json``,
 the layer equations of the family its config names (arXiv:2412.19437 sections
 2.1, 2.2 and 4.2)::
@@ -66,9 +67,27 @@ selection bias, the top 8 picked inside 4 of 8 groups (``n_group``,
 clamped (``expert_limits``, ``shared_limits``: a number a published layer, 0
 in the first 34); no MTP module; an untied head.
 
+``laguna_s_2.1``: sizes of ``https://huggingface.co/poolside/Laguna-S-2.1/
+blob/main/config.json``. ``mixers`` is the config's own ``layer_types``: two
+kinds of softmax layer WITH DIFFERENT HEAD COUNTS, so ``to_qkv`` and
+``to_out`` differ in shape by the layer's kind. ``sliding_attention`` (36
+layers): 72 query heads of 128 on 8 key/value heads, each position attending
+to itself and the 511 before it (``window``), rotary on the whole head at
+base 10,000; ``full_attention`` (12 layers, every fourth from layer 0): 48
+query heads on 8, causal, rotary on the leading 64 lanes at base 500,000
+under YaRN with the group's ``attention_factor`` on the tables. Both norm q
+and k a head with a plain weight and gate the core's output a head
+(``gate`` ``"head"``). One leading dense layer; 256 softmax-routed experts,
+top-10 normalised and scaled by 2.5 (``routed_scale`` on a softmax router),
+no selection bias, a shared expert behind a sigmoid gate; no MTP module; an
+untied head.
+
 The kinds of mixer a ``mixers`` list may name: ``latent`` (the block at this
 class's own latent sizes) and the keys of :data:`MIXER_BLOCKS`:
-``gated_delta``, ``gated_attention`` / ``full_attention``, ``conv``, ``kda``.
+``gated_delta``, ``gated_attention`` / ``full_attention`` (one block, the
+sizes of ``gated_attention``), ``sliding_attention`` (the same block at the
+sizes of ``sliding_attention``, which give it a ``window``), ``conv``,
+``kda``.
 
 ``Attn`` is :class:`~sav_tpu.models.layers.LatentSelfAttentionBlock` unless
 ``mixers`` says otherwise. ``FFN``
@@ -98,7 +117,8 @@ stands in for the chips that hold the rest.
 Scopes, for the readers of a trace: layers ``layer_<i>``; in a layer the
 token mixer is ``LatentSelfAttentionBlock_0``, ``GatedSelfAttentionBlock_0``,
 ``GatedDeltaNetBlock_0``, ``ShortConvBlock_0`` or ``KDABlock_0`` (``to_qkv``,
-``to_out`` in each; the third also ``gdn/conv``, ``gdn/rule``,
+``to_out`` in each; the second's core under ``attn/full`` or, with a window,
+``attn/window``; the third also ``gdn/conv``, ``gdn/rule``,
 ``gdn/gate_norm``, the fourth ``sconv/core``, the last ``kda/conv``,
 ``kda/rule``, ``kda/gate_norm``),
 the dense MLP ``GatedFFBlock_0`` (``fc1``, ``fc2``), the expert layer
@@ -206,10 +226,32 @@ KEPT_UNDER_REMAT_BESIDE_CONVOLUTION = KEPT_UNDER_REMAT + ("sconv_in", "sconv_cor
 KEPT_UNDER_REMAT_BESIDE_VECTOR_DECAY = KEPT_UNDER_REMAT + ("gdn_solved", "kda_out")
 
 
+# The window/full hybrid's choice: the names above (``mla_latent`` and
+# ``hc_maps`` tag nothing here; the block tags no name of its own), a layer at
+# 1 x 4,096 tokens and 72 or 48 query heads of 128, where the state alone is
+# 12.98 GB. By choice, the step's bytes compiled for a described v5e and, where
+# a machine was had, sequences/s and ``memory_program_bytes`` on the chip (my
+# chip run, PR 46, call A): **every name 4.727 in 13.30 GB (13.54 on the
+# chip)**; without ``attn_qkv`` 13.82 GB and with ``flash_out``,
+# ``flash_lse``, ``moe_route`` and ``moe_order`` alone 13.59 GB (more, not
+# less: XLA schedules what is computed again where the step's memory peaks,
+# as PR 39 met); nothing kept 13.24 GB (five more Mosaic calls: the forward
+# kernels run again); no rematerialisation at all 14.82 GB, near the cell's
+# 15.5 once the harness's own buffers are counted: not taken.
+# On the chip (calls A and C, 5 s windows): without ``attn_qkv`` 4.563 in 14.06
+# GB, the four names 4.312 in 13.84, nothing kept 4.058 in 13.50.
+# Every name kept is within 0.06 GB of the least any choice compiles to and
+# 2 GB under the cell's limit, so nothing is given up for room; what the kept
+# ``attn_qkv`` does not spare is the q projection, which the backward pass
+# computes again for the norm's sake (PERF.md section 7).
+KEPT_UNDER_REMAT_BESIDE_WINDOWS = KEPT_UNDER_REMAT
+
+
 # How a step's per-layer ``stats`` become one number: by key.
 STAT_REDUCTIONS = {
     "hc_doubly_stochastic_err": jnp.max, "hc_stream_gain": jnp.max,
     "gdn_decay_min": jnp.min, "gdn_state_rms_max": jnp.max, "attn_gate_mean": jnp.mean,
+    "attn_gate_mean_window": jnp.mean, "attn_gate_mean_full": jnp.mean,
     "sconv_out_rms_max": jnp.max,
     "kda_decay_min": jnp.min, "kda_state_rms_max": jnp.max, "moe_groups_held": jnp.mean,
 }
@@ -217,11 +259,14 @@ STAT_REDUCTIONS = {
 # A layer's token mixer by the kind a ``mixers`` list gives it: the block,
 # the prefix its ``stats`` take, and the field of :class:`JoyAILM` that holds
 # its sizes. ``full_attention`` is the public configs' name for a softmax
-# layer; both names build the grouped-query block.
+# layer; both names build the grouped-query block. ``sliding_attention`` is
+# their name for one over a window, the same block at sizes of its own (its
+# head count may differ from the full layer's).
 MIXER_BLOCKS = {
     "gated_delta": (GatedDeltaNetBlock, "gdn_", "gated_delta"),
     "gated_attention": (GatedSelfAttentionBlock, "attn_", "gated_attention"),
     "full_attention": (GatedSelfAttentionBlock, "attn_", "gated_attention"),
+    "sliding_attention": (GatedSelfAttentionBlock, "attn_", "sliding_attention"),
     "conv": (ShortConvBlock, "sconv_", "short_conv"),
     "kda": (KDABlock, "kda_", "kda"),
 }
@@ -245,8 +290,9 @@ class LatentDecoderBlock(nn.Module):
     the middle ``None`` for a dense layer; ``stats`` a dict of float32 scalars
     under the keys of :data:`STAT_REDUCTIONS` (the hyper-connections' two, the
     larger of the two sublayers'; the delta-rule blocks' two each; the gated
-    attention's one; the short convolution's one; a group-limited expert
-    layer's one), ``None`` or empty where the layer has none."""
+    attention's one, and a head-wise gate's by its layer's kind; the short
+    convolution's one; a group-limited expert layer's one), ``None`` or empty
+    where the layer has none."""
 
     mlp_ch: int
     num_experts: int
@@ -300,7 +346,9 @@ class LatentDecoderBlock(nn.Module):
                     options.update(attention, norm_offset=self.norm_offset)
                 if block is ShortConvBlock:
                     del options["norm_eps"]  # no norm inside
-                out, stats = block(**self.mixer_sizes, **options)(x)
+                # A kind's own sizes come last: two kinds of softmax layer in
+                # one decoder differ in their rotary base too.
+                out, stats = block(**{**options, **self.mixer_sizes})(x)
                 return out, {prefix + k: v for k, v in stats.items()}
             if self.mixer != "latent":
                 raise ValueError(f"token mixer {self.mixer!r}: latent or one of {sorted(MIXER_BLOCKS)}")
@@ -371,7 +419,9 @@ class JoyAILM(nn.Module):
       any sublayer's :class:`HyperConnection` ``stats``); with delta-rule
       layers ``"gdn_decay_min"`` (the smallest ``exp(g_t)`` of the step) and
       ``"gdn_state_rms_max"`` (the largest RMS of any head's final state);
-      with gated attention layers ``"attn_gate_mean"``; with short-convolution
+      with gated attention layers ``"attn_gate_mean"`` (and, gated a head,
+      ``"attn_gate_mean_window"`` / ``"attn_gate_mean_full"``: the mean over
+      the layers of that kind); with short-convolution
       layers ``"sconv_out_rms_max"`` (the largest RMS of any block's and
       sequence's ``C * c``); with vector-decay layers ``"kda_decay_min"`` (the
       smallest ``g`` of the step, a log: how near the gate's lower bound it
@@ -405,6 +455,7 @@ class JoyAILM(nn.Module):
     mixers: Optional[tuple] = None
     full_attention_interval: int = 0
     gated_attention: Optional[Any] = None
+    sliding_attention: Optional[Any] = None
     gated_delta: Optional[Any] = None
     short_conv: Optional[Any] = None
     kda: Optional[Any] = None

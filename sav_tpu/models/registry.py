@@ -27,6 +27,7 @@ from sav_tpu.models.joyai import (
     KEPT_UNDER_REMAT_BESIDE_RECURRENCE,
     KEPT_UNDER_REMAT_BESIDE_STREAMS,
     KEPT_UNDER_REMAT_BESIDE_VECTOR_DECAY,
+    KEPT_UNDER_REMAT_BESIDE_WINDOWS,
     JoyAILM,
     hybrid_mixers,
 )
@@ -291,6 +292,48 @@ register(
     expert_limits=LING_EXPERT_LIMITS, shared_limits=LING_SHARED_LIMITS,
     rope_theta=6e6, norm_eps=1e-6,
     kept_under_remat=KEPT_UNDER_REMAT_BESIDE_VECTOR_DECAY,
+)
+
+
+# --- Laguna-S-2.1 (three sliding-window layers of 72 query heads to one full
+# layer of 48, per-head output gates, two rotaries) ---------------------------
+# Sizes of https://huggingface.co/poolside/Laguna-S-2.1/blob/main/config.json;
+# ``num_classes`` is the vocabulary (100,352 there). ``mixers`` is the config's
+# ``layer_types`` (layer ``i`` is ``full_attention`` where ``i % 4 == 0``, 12
+# layers, and ``sliding_attention`` otherwise, 36), and the head counts are its
+# ``num_attention_heads_per_layer``: 48 query heads in a full layer, 72 in a
+# window layer, each on 8 key/value heads of 128. A window layer sees 512
+# positions (``sliding_window``), rotary on the whole head at base 10,000; a
+# full layer turns the leading 64 lanes (``partial_rotary_factor`` 0.5) at base
+# 500,000 under YaRN (factor 128 over 8,192 positions) with ``attention_factor``
+# on the tables. Both norm q and k a head (plain weights) and gate the output a
+# head. One leading dense layer at 12,288, then 256 softmax-routed experts of
+# 1,024, top-10 normalised and scaled by 2.5, no selection bias, a shared
+# expert behind a sigmoid gate; an untied head. 117.56 B parameters by the
+# tree's count: one chip holds the first five layers and its share of every
+# expert layer (model_overrides={"num_layers": 5, "experts_held": (0, 8)}).
+LAGUNA_LAYER_TYPES = ("full_attention", "sliding_attention", "sliding_attention", "sliding_attention") * 12
+register(
+    "laguna_s_2.1",
+    JoyAILM,
+    task="tokens_mtp",
+    embed_dim=3072, num_layers=48, mlp_ch=12288, expert_ch=1024,
+    num_experts=256, top_k=10, routed_scale=2.5, first_dense=1, mtp_modules=0,
+    bias_update_rate=0.0, scoring="softmax", shared_gate=True, norm_offset=False,
+    mixers=LAGUNA_LAYER_TYPES,
+    gated_attention={
+        "num_heads": 48, "kv_heads": 8, "head_ch": 128, "rotary_ch": 64, "gate": "head", "rope_theta": 5e5,
+        "rope_scaling": {
+            "rope_type": "yarn", "factor": 128.0, "original_max_position_embeddings": 8192,
+            "beta_fast": 32.0, "beta_slow": 1.0, "attention_factor": 1.4852030263919618,
+        },
+    },
+    sliding_attention={
+        "num_heads": 72, "kv_heads": 8, "head_ch": 128, "rotary_ch": 128, "gate": "head", "rope_theta": 1e4,
+        "window": 512,
+    },
+    norm_eps=1e-6,
+    kept_under_remat=KEPT_UNDER_REMAT_BESIDE_WINDOWS,
 )
 
 

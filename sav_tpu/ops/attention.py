@@ -42,7 +42,12 @@ fused kernel reads ``[B, L, H*D]`` in place.
                    same rule on the cache's ``.causal`` entries, never
                    resolves to ``fused``, masks by an iota comparison on
                    the dense path and skips the blocks above the diagonal
-                   in the flash kernel.
+                   in the flash kernel. A ``window`` beside it (a
+                   sliding-window layer: ``i - window < j <= i``) is the
+                   same comparison with a far edge, the blocks behind it
+                   skipped too; it reads the cache's ``.causal.window<W>``
+                   entries and never a causal entry's blocks, which were
+                   chosen for another amount of work.
 
                    Every resolution is recorded in a trace-time dispatch
                    log (:func:`snapshot_dispatch_log`) that ``bench.py``
@@ -141,6 +146,7 @@ def xla_attention(
     deterministic: bool = True,
     logits_dtype=None,
     causal: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Reference attention core in pure XLA ops.
 
@@ -157,6 +163,7 @@ def xla_attention(
       causal: position ``i`` attends to ``j <= i`` (self-attention:
         ``q_len == kv_len``). The mask is an iota comparison inside the
         program, never an ``[L, L]`` bias operand.
+      window: with ``causal``, ``i`` attends to ``i - window < j <= i``.
 
     Returns:
       ``[..., q_len, heads, head_dim]`` in the query dtype.
@@ -170,7 +177,7 @@ def xla_attention(
     if key.shape[-2] != query.shape[-2]:  # grouped heads: the dense path repeats them
         group = query.shape[-2] // key.shape[-2]
         key, value = jnp.repeat(key, group, axis=-2), jnp.repeat(value, group, axis=-2)
-    probs = _softmax_probs(query, key, bias, scale, logits_dtype, causal)
+    probs = _softmax_probs(query, key, bias, scale, logits_dtype, causal, window)
     if dropout_rate > 0.0 and not deterministic:
         if dropout_rng is None:
             raise ValueError("dropout_rng required for non-deterministic attention dropout")
@@ -180,16 +187,20 @@ def xla_attention(
     return jnp.einsum("...hqk,...khd->...qhd", probs, value)
 
 
-def causal_mask(q_len: int, kv_len: int) -> jax.Array:
-    """``[q_len, kv_len]`` bool, true where a query may look: ``j <= i``."""
+def causal_mask(q_len: int, kv_len: int, window: Optional[int] = None) -> jax.Array:
+    """``[q_len, kv_len]`` bool, true where a query may look: ``j <= i`` and,
+    under a ``window``, ``j > i - window`` (the kernels' own comparison:
+    ``flash_attention.band_keep``)."""
     if q_len != kv_len:
         raise ValueError(
             f"causal attention is self-attention: q_len {q_len} != kv_len {kv_len}"
         )
-    return _flash._causal_keep(0, 0, q_len, kv_len)
+    if window is not None and window < 1:
+        raise ValueError(f"a window of {window} positions")
+    return _flash._causal_keep(0, 0, q_len, kv_len, window=window)
 
 
-def _softmax_probs(q, k, bias, scale, logits_dtype, causal=False):
+def _softmax_probs(q, k, bias, scale, logits_dtype, causal=False, window=None):
     """Scaled-QK softmax: the dense path's forward numerics."""
     qs = q * jnp.asarray(scale, dtype=q.dtype)
     logits = jnp.einsum(
@@ -197,8 +208,10 @@ def _softmax_probs(q, k, bias, scale, logits_dtype, causal=False):
     )
     if bias is not None:
         logits = logits + bias.astype(logits_dtype)
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
     if causal:
-        mask = causal_mask(logits.shape[-2], logits.shape[-1])
+        mask = causal_mask(logits.shape[-2], logits.shape[-1], window)
         logits = jnp.where(mask, logits, jnp.asarray(-jnp.inf, logits.dtype))
     return jax.nn.softmax(logits, axis=-1)
 
@@ -237,14 +250,21 @@ def snapshot_dispatch_log() -> list:
         return [dict(v) for v in _DISPATCH_LOG.values()]
 
 
-def _log_dispatch(shape, kv_len, kv_heads, requested, dispatch: AttentionDispatch, flash_forms=None) -> None:
+def _log_dispatch(shape, kv_len, kv_heads, requested, dispatch: AttentionDispatch, flash_forms=None,
+                  window: Optional[int] = None) -> None:
     # kv_len is part of the identity: cross-attention sites share a query
-    # shape with self-attention ones but can resolve differently.
-    _log_once((shape, kv_len, kv_heads, requested), {
+    # shape with self-attention ones but can resolve differently; so is the
+    # window: a banded core and a causal one of one shape are two records.
+    _log_once((shape, kv_len, kv_heads, requested, window), {
         "shape": list(shape),
         "kv_len": kv_len,
         "kv_heads": kv_heads,
         "requested": requested or "auto",
+        # A banded core's window (absent from a record without one) and,
+        # with the flash kernel, 'kv_blocks_visited' / 'kv_blocks_causal'
+        # among the forms below: the grid cells with work a batch·head
+        # slice, under the window and under the causal mask alone.
+        **({} if window is None else {"window": window}),
         **dispatch.as_note(),
         # The flash kernel's forms: 'backward' ('one_kernel' |
         # 'two_kernels', the unbiased path's), 'layout' ('in_place' |
@@ -292,6 +312,7 @@ def resolve_attention_backend(
     num_devices: Optional[int] = None,
     causal: bool = False,
     value_dim: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> AttentionDispatch:
     """The three-way ``auto`` rule on static shapes (see module docstring).
 
@@ -304,13 +325,15 @@ def resolve_attention_backend(
     core reads the cache's ``.causal`` entries only, and is never ``fused``
     (the single-pass kernel has no mask); nor is a core whose value head
     (``value_dim``) is not the query's, which has cache entries of its own.
+    A banded core (``window``) reads ``.causal.window<W>`` entries only:
+    without one it takes the rule's verdict and the kernel's default blocks.
     """
     if on_tpu is None:
         on_tpu = _on_tpu()
     if num_devices is None:
         num_devices = getattr(_TRACE, "devices", 1)
     entry = attn_tuning.lookup(
-        batch, q_len, kv_len, heads, dim, dtype, causal=causal, value_dim=value_dim
+        batch, q_len, kv_len, heads, dim, dtype, causal=causal, value_dim=value_dim, window=window
     )
     tuned_cfg = attn_tuning.block_config(entry)
     if requested and requested != "auto":
@@ -390,12 +413,15 @@ def dot_product_attention(
     backend: Optional[str] = None,
     logits_dtype=None,
     causal: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Backend-dispatched attention. See module docstring.
 
     ``causal`` masks ``j > i`` on the dense path (an iota comparison) and
     in the flash kernel (blocks above the diagonal are skipped); the
     single-pass ``fused`` kernel has no causal arm and refuses it.
+    ``window`` (with ``causal``) narrows the mask to ``i - window < j <= i``
+    on both paths; one no shorter than the sequence is the causal mask.
 
     ``logits_dtype`` sets the XLA path's softmax dtype (None = the
     deprecated process-wide default, f32 unless configured). The Pallas
@@ -406,6 +432,8 @@ def dot_product_attention(
     if backend not in ("auto", "xla", "pallas", "fused"):
         raise ValueError(f"unknown attention backend: {backend!r}")
 
+    if window is not None:
+        window = _flash.effective_window(window, causal, query.shape[-3])
     has_dropout = dropout_rate > 0.0 and not deterministic
     kernels_ok = (
         not has_dropout
@@ -418,7 +446,7 @@ def dot_product_attention(
         dispatch = resolve_attention_backend(
             b, lq, key.shape[1], h, d,
             dtype=query.dtype, requested=requested, kernels_ok=True,
-            causal=causal, value_dim=value.shape[-1],
+            causal=causal, value_dim=value.shape[-1], window=window,
         )
         backend = dispatch.backend
         cfg = dispatch.block_config or {}
@@ -432,7 +460,10 @@ def dot_product_attention(
                 flash_forms["backward"] = _flash.backward_form(*lengths, **sizes)
             if key.shape[2] != h:
                 flash_forms["grouped_kv"] = "index_maps" if flash_forms["layout"] == "in_place" else "repeated"
-        _log_dispatch(tuple(query.shape), key.shape[1], key.shape[2], requested, dispatch, flash_forms)
+            if window is not None:
+                blocks = {k: v for k, v in flash_blocks.items() if k != "block_b"}
+                flash_forms.update(_flash.visited_blocks(lq, key.shape[1], window=window, **blocks))
+        _log_dispatch(tuple(query.shape), key.shape[1], key.shape[2], requested, dispatch, flash_forms, window)
     else:
         if backend in ("pallas", "fused"):
             raise ValueError(
@@ -450,7 +481,7 @@ def dot_product_attention(
         return _fused.fused_attention(query, key, value, bias, scale=scale, **kw)
     if backend == "pallas":
         return _flash.flash_attention(
-            query, key, value, bias, scale=scale, causal=causal, **flash_blocks
+            query, key, value, bias, scale=scale, causal=causal, window=window, **flash_blocks
         )
     return xla_attention(
         query,
@@ -463,6 +494,7 @@ def dot_product_attention(
         deterministic=deterministic,
         logits_dtype=logits_dtype,
         causal=causal,
+        window=window,
     )
 
 

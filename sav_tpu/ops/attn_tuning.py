@@ -42,7 +42,9 @@ Cache schema (version 1)::
     }
 
 Keys come from :func:`shape_key` (a causal core's end in ``.causal``: it
-does half the work of its shape unmasked and is measured apart); a lookup
+does half the work of its shape unmasked and is measured apart; a banded
+core's in ``.causal.window<W>``: it does a band's work, and a causal
+entry's blocks were chosen for a triangle's); a lookup
 tries the exact batch first,
 then the batch-wildcard key (``B*``) so one measured model-zoo shape
 covers every batch size that shares its sequence geometry — from
@@ -76,15 +78,18 @@ _loaded: dict = {}
 
 def shape_key(
     batch, q_len: int, kv_len: int, heads: int, dim: int, dtype="bfloat16",
-    causal: bool = False, value_dim: Optional[int] = None,
+    causal: bool = False, value_dim: Optional[int] = None, window: Optional[int] = None,
 ) -> str:
     """Canonical cache key. ``batch`` may be ``'*'`` for the wildcard; a
     causal core does half the work of the same shape unmasked and has
-    entries of its own (``....causal``); a value head of another size than
-    the query's is part of the head's name (``D192v128``)."""
+    entries of its own (``....causal``), a banded one a band's
+    (``....causal.window512``); a value head of another size than the
+    query's is part of the head's name (``D192v128``)."""
     dt = jnp.dtype(dtype).name
     head = f"D{dim}" if value_dim in (None, dim) else f"D{dim}v{value_dim}"
     key = f"B{batch}.Lq{q_len}.Lkv{kv_len}.H{heads}.{head}.{dt}"
+    if window is not None:
+        return f"{key}.causal.window{window}"
     return key + ".causal" if causal else key
 
 
@@ -139,6 +144,7 @@ def lookup(
     *,
     causal: bool = False,
     value_dim: Optional[int] = None,
+    window: Optional[int] = None,
     path: Optional[str] = None,
 ) -> Optional[dict]:
     """Measured entry for a shape (exact batch, then batch-wildcard);
@@ -146,7 +152,7 @@ def lookup(
     backend name are ignored rather than dispatched on."""
     entries = load_cache(path).get("entries", {})
     for b in (batch, "*"):
-        entry = entries.get(shape_key(b, q_len, kv_len, heads, dim, dtype, causal, value_dim))
+        entry = entries.get(shape_key(b, q_len, kv_len, heads, dim, dtype, causal, value_dim, window))
         if not isinstance(entry, dict) or entry.get("backend") not in _BACKENDS:
             continue
         if b == "*" and batch < entry.get("min_batch", 0):

@@ -8,7 +8,10 @@ in HBM. An optional additive bias input carries 2-D relative-position logits
 (BoTNet) or masks through the fused softmax. ``causal=True`` (decoder
 self-attention) needs no bias: a block wholly above the diagonal is neither
 fetched nor computed, a block the diagonal crosses is masked in VMEM by an
-iota comparison, forward and backward.
+iota comparison, forward and backward. ``window`` beside it is a band
+(``i - window < j <= i``, sliding-window layers): the blocks wholly behind
+the window are skipped as those above the diagonal are, and the block its
+far edge crosses is masked by the same comparison (:func:`band_keep`).
 
 Layout: where every block can be one head's rows of the caller's own
 arrays, the unbiased kernels read and write those in place
@@ -107,38 +110,104 @@ def _pick_block_b(bh: int, *, force_one: bool = False) -> int:
     return 1
 
 
-def _causal_blocks(qi, ki, block_q: int, block_kv: int):
+def band_keep(row, col, window: Optional[int] = None):
+    """Where a query at position ``row`` may look: ``col <= row`` and, under
+    a ``window``, ``col > row - window`` (itself and the ``window - 1``
+    before it). The one place the mask is written: the kernels' element
+    masks, the dense paths and the block tests below all call it."""
+    keep = col <= row
+    if window is not None:
+        keep = jnp.logical_and(keep, col > row - window)
+    return keep
+
+
+def _causal_blocks(qi, ki, block_q: int, block_kv: int, window: Optional[int] = None):
     """For q block ``qi`` against kv block ``ki`` under the causal mask:
-    ``(visible, crossed)``. ``visible``: some ``col <= row`` exists, the
+    ``(visible, crossed, far)``. ``visible``: some ``col <= row`` exists, the
     block has work. ``crossed``: the diagonal runs through it, so it needs
     the element mask (a visible block that is not crossed lies wholly below
-    the diagonal)."""
+    the diagonal). ``far``: the far edge of a ``window`` (``col == row -
+    window``) runs through it (never, without one); ``visible`` then also
+    wants a column inside some row's window. Takes Python integers (the
+    static counts of :func:`band_blocks`) as well as traced ones."""
     first_row, last_row = qi * block_q, qi * block_q + block_q - 1
     first_col, last_col = ki * block_kv, ki * block_kv + block_kv - 1
     visible = first_col <= last_row
-    crossed = jnp.logical_and(visible, last_col > first_row)
-    return visible, crossed
+    crossed = visible & (last_col > first_row)
+    if window is None:
+        return visible, crossed, False
+    visible = visible & (last_col > first_row - window)
+    return visible, visible & crossed, visible & (first_col <= last_row - window)
 
 
-def _causal_keep(qi, ki, block_q: int, block_kv: int, *, transposed: bool = False):
-    """``[block_q, block_kv]`` bool: ``col <= row`` in global positions
-    (``transposed``: of a ``[block_kv, block_q]`` tile, rows on its lanes)."""
+def _causal_keep(qi, ki, block_q: int, block_kv: int, *, transposed: bool = False,
+                 window: Optional[int] = None, edges: tuple = (True, True)):
+    """``[block_q, block_kv]`` bool: :func:`band_keep` in global positions
+    (``transposed``: of a ``[block_kv, block_q]`` tile, rows on its lanes).
+    ``edges = (diagonal, far)`` names the comparisons a block needs: the one
+    the diagonal crosses ``col <= row`` alone, the one the window's far edge
+    crosses ``col > row - window`` alone."""
     shape = (block_kv, block_q) if transposed else (block_q, block_kv)
     row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transposed else 0)
     col = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, shape, 0 if transposed else 1)
-    return col <= row
+    diagonal, far = edges
+    if window is None or not far:
+        return col <= row
+    if not diagonal:
+        return col > row - window
+    return band_keep(row, col, window)
 
 
-def _for_causal_blocks(causal: bool, qi, ki, block_q: int, block_kv: int, body):
+def band_blocks(num_q_blocks: int, num_kv_blocks: int, block_q: int, block_kv: int,
+                window: Optional[int]) -> dict:
+    """Static counts of a causal grid: ``visited`` cells (those with work
+    under ``window``; None = the causal mask alone), ``causal`` (those with
+    work under the causal mask alone), and ``cases``, the ``(diagonal,
+    far)`` pairs of :func:`_causal_blocks` that some visited cell meets, in
+    a fixed order (a kernel builds one body a case)."""
+    cases, visited, causal = set(), 0, 0
+    for qi in range(num_q_blocks):
+        for ki in range(num_kv_blocks):
+            causal += _causal_blocks(qi, ki, block_q, block_kv)[0]
+            visible, *edges = _causal_blocks(qi, ki, block_q, block_kv, window)
+            if visible:
+                visited += 1
+                cases.add(tuple(edges))
+    return {"visited": visited, "causal": causal, "cases": tuple(sorted(cases))}
+
+
+def _band_statics(num_q_blocks: int, num_kv_blocks: int, block_q: int, block_kv: int,
+                  window: Optional[int]) -> dict:
+    """What a kernel is told of a window beside ``causal``: nothing without
+    one (the causal kernels are built as they were), else the window and the
+    grid's ``cases``."""
+    if window is None:
+        return {}
+    return {"window": window, "cases": band_blocks(num_q_blocks, num_kv_blocks, block_q, block_kv, window)["cases"]}
+
+
+def _for_causal_blocks(causal: bool, qi, ki, block_q: int, block_kv: int, body,
+                       window: Optional[int] = None, cases: tuple = ()):
     """Run ``body(masked)`` for this grid cell: always and unmasked without
     ``causal``; else not at all above the diagonal, masked on it, unmasked
-    below it."""
+    below it. Under a ``window`` not at all behind its far edge either, and
+    ``masked`` is the ``(diagonal, far)`` pair of edges that cross the cell
+    (false where neither does): one body for each of ``cases``, the pairs
+    the grid meets (:func:`band_blocks`)."""
     if not causal:
         body(False)
         return
-    visible, crossed = _causal_blocks(qi, ki, block_q, block_kv)
-    pl.when(crossed)(lambda: body(True))
-    pl.when(jnp.logical_and(visible, jnp.logical_not(crossed)))(lambda: body(False))
+    if window is None:
+        visible, crossed, _ = _causal_blocks(qi, ki, block_q, block_kv)
+        pl.when(crossed)(lambda: body((True, False)))
+        pl.when(jnp.logical_and(visible, jnp.logical_not(crossed)))(lambda: body(False))
+        return
+    visible, diagonal, far = _causal_blocks(qi, ki, block_q, block_kv, window)
+    for case in cases:
+        met = visible
+        for edge, wanted in zip((diagonal, far), case):
+            met = jnp.logical_and(met, edge if wanted else jnp.logical_not(edge))
+        pl.when(met)(lambda case=case: body(case if any(case) else False))
 
 
 def _last_kv_block(qi, block_q: int, block_kv: int):
@@ -146,9 +215,42 @@ def _last_kv_block(qi, block_q: int, block_kv: int):
     return (qi * block_q + block_q - 1) // block_kv
 
 
+def _first_kv_block(qi, block_q: int, block_kv: int, window: Optional[int]):
+    """Index of the first kv block a q block can see: 0 without a window,
+    else the block of its first row's farthest column."""
+    if window is None:
+        return 0
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_kv
+
+
 def _first_q_block(ki, block_q: int, block_kv: int):
     """Index of the first q block that can see a causal kv block."""
     return (ki * block_kv) // block_q
+
+
+def _last_q_block(ki, block_q: int, block_kv: int, num_q_blocks: int, window: Optional[int]):
+    """Index of the last q block that can see a kv block: the grid's last
+    without a window, else the block of the row ``window - 1`` past the kv
+    block's last column."""
+    if window is None:
+        return num_q_blocks - 1
+    return jnp.minimum((ki * block_kv + block_kv - 1 + window - 1) // block_q, num_q_blocks - 1)
+
+
+def _visible_kv_block(qi, ki, block_q: int, block_kv: int, window: Optional[int]):
+    """The kv block a forward (or dq) cell names: its own where it has work,
+    else the nearest one its q block visits, so that nothing is fetched for
+    a skipped cell at either edge (Pallas fetches a block only when its
+    index changes)."""
+    last = jnp.minimum(ki, _last_kv_block(qi, block_q, block_kv))
+    return last if window is None else jnp.maximum(last, _first_kv_block(qi, block_q, block_kv, window))
+
+
+def _visible_q_block(ki, qi, block_q: int, block_kv: int, num_q_blocks: int, window: Optional[int]):
+    """The q block a dk/dv cell names: as :func:`_visible_kv_block`, for the
+    q sweep of a kv block."""
+    first = jnp.maximum(qi, _first_q_block(ki, block_q, block_kv))
+    return first if window is None else jnp.minimum(first, _last_q_block(ki, block_q, block_kv, num_q_blocks, window))
 
 
 def _resolve_block_b(block_b: Optional[int], bh: int, *, force_one: bool = False) -> int:
@@ -161,14 +263,24 @@ def _resolve_block_b(block_b: Optional[int], bh: int, *, force_one: bool = False
     return block_b
 
 
-def _online_softmax_step(s, v, m_scr, l_scr, acc_scr, bi):
+def _finite_max(m_new, banded: bool):
+    """The running max a block's exponentials are taken against. Under the
+    causal mask alone a row's first block shows it column 0 and the max is
+    finite from then on; under a window a q block's first kv block can hide
+    every column of its later rows, whose max is still ``-inf``: 0 stands in
+    for it (``exp(-inf - 0)`` is the 0 such a row's entries are)."""
+    return jnp.where(m_new == _NEG_INF, 0.0, m_new) if banded else m_new
+
+
+def _online_softmax_step(s, v, m_scr, l_scr, acc_scr, bi, banded: bool = False):
     """Fold one block's logits ``s`` of batch·head slice ``bi`` into the
     running (max, sum, acc) statistics."""
     m_prev = m_scr[bi, :, 0:1]
     l_prev = l_scr[bi, :, 0:1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
+    against = _finite_max(m_new, banded)
+    alpha = jnp.exp(m_prev - against)
+    p = jnp.exp(s - against)
     l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
     m_scr[bi] = jnp.broadcast_to(m_new, m_scr.shape[1:])
     l_scr[bi] = jnp.broadcast_to(l_new, l_scr.shape[1:])
@@ -204,6 +316,8 @@ def _kernel(
     block_kv: int,
     num_kv_blocks: int,
     causal: bool,
+    window: Optional[int] = None,
+    cases: tuple = (),
 ):
     """Online-softmax flash kernel;
     ``rest`` = ([bias_ref], o_ref, [lse_ref], m, l, acc).
@@ -245,11 +359,12 @@ def _kernel(
             if masked:
                 # Block 0 comes first and shows every row its column 0, so
                 # the running max is finite before a row meets a block that
-                # hides all of its columns.
-                s = jnp.where(_causal_keep(qi, ki, block_q, block_kv), s, _NEG_INF)
-            _online_softmax_step(s, v_ref[bi], m_scr, l_scr, acc_scr, bi)
+                # hides all of its columns (under a window: _finite_max).
+                keep = _causal_keep(qi, ki, block_q, block_kv, window=window, edges=masked)
+                s = jnp.where(keep, s, _NEG_INF)
+            _online_softmax_step(s, v_ref[bi], m_scr, l_scr, acc_scr, bi, window is not None)
 
-    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
+    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold, window, cases)
 
     @pl.when(ki == num_kv_blocks - 1)
     def _finalize():
@@ -270,6 +385,7 @@ def _flash_forward(
     *,
     causal: bool = False,
     block_b: Optional[int] = None,
+    window: Optional[int] = None,
 ):
     """Run the kernel. Layout in/out: ``[B, L, H, D]``.
 
@@ -319,10 +435,11 @@ def _flash_forward(
     num_kv_blocks = kv_len_p // block_kv
     grid = (batch * heads // block_b, num_q_blocks, num_kv_blocks)
 
-    # A causal cell above the diagonal names the block the cell before it
-    # held: Pallas fetches a block only when its index changes.
+    # A causal cell above the diagonal (or behind the window) names the
+    # block a neighbour held: Pallas fetches a block only when its index
+    # changes.
     if causal:
-        kv_index = lambda b, i, j: (b, jnp.minimum(j, _last_kv_block(i, block_q, block_kv)), 0)
+        kv_index = lambda b, i, j: (b, _visible_kv_block(i, j, block_q, block_kv, window), 0)
     else:
         kv_index = lambda b, i, j: (b, j, 0)
     in_specs = [
@@ -354,6 +471,7 @@ def _flash_forward(
         block_kv=block_kv,
         num_kv_blocks=num_kv_blocks,
         causal=causal,
+        **_band_statics(num_q_blocks, num_kv_blocks, block_q, block_kv, window),
     )
 
     out_specs = [
@@ -505,7 +623,7 @@ def _bwd_prep(q, k, v, out, g, block_q, block_kv) -> _BwdGeom:
 
 
 def _bwd_tile(q, k, v, do, lse, delta, *, scale, qi, ki, q_len, kv_len,
-              block_q, block_kv, masked):
+              block_q, block_kv, masked, window=None):
     """One ``(q block, kv block)`` pair's ``(p, ds)``, float32: the
     probabilities rebuilt from the logsumexp, zero on padded rows and
     columns and above the diagonal, and ``ds = p (dO v^T - delta)``."""
@@ -523,7 +641,7 @@ def _bwd_tile(q, k, v, do, lse, delta, *, scale, qi, ki, q_len, kv_len,
         row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         p = jnp.where(row < q_len, p, 0.0)
     if masked:
-        p = jnp.where(_causal_keep(qi, ki, block_q, block_kv), p, 0.0)
+        p = jnp.where(_causal_keep(qi, ki, block_q, block_kv, window=window, edges=masked), p, 0.0)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -533,7 +651,8 @@ def _bwd_tile(q, k, v, do, lse, delta, *, scale, qi, ki, q_len, kv_len,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_acc, *, scale: float, q_len: int, kv_len: int,
                    block_b: int, block_q: int, block_kv: int,
-                   num_kv_blocks: int, causal: bool):
+                   num_kv_blocks: int, causal: bool,
+                   window: Optional[int] = None, cases: tuple = ()):
     qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -546,14 +665,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             _, ds = _bwd_tile(
                 q_ref[bi], k, v_ref[bi], do_ref[bi], lse_ref[bi], delta_ref[bi],
                 scale=scale, qi=qi, ki=ki, q_len=q_len, kv_len=kv_len,
-                block_q=block_q, block_kv=block_kv, masked=masked,
+                block_q=block_q, block_kv=block_kv, masked=masked, window=window,
             )
             dq_acc[bi] += jax.lax.dot_general(
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale
 
-    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
+    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold, window, cases)
 
     @pl.when(ki == num_kv_blocks - 1)
     def _write():
@@ -563,7 +682,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                     with_dq: bool, scale: float, q_len: int, kv_len: int,
                     block_b: int, block_q: int, block_kv: int,
-                    num_q_blocks: int, num_kv_blocks: int, causal: bool):
+                    num_q_blocks: int, num_kv_blocks: int, causal: bool,
+                    window: Optional[int] = None, cases: tuple = ()):
     """dk and dv of one kv block, summed over its q sweep (q innermost);
     ``rest`` = ([dq_ref], dk_ref, dv_ref, [dq_acc], dk_acc, dv_acc).
 
@@ -594,7 +714,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             p, ds = _bwd_tile(
                 q, k, v_ref[bi], do, lse_ref[bi], delta_ref[bi], scale=scale,
                 qi=qi, ki=ki, q_len=q_len, kv_len=kv_len, block_q=block_q,
-                block_kv=block_kv, masked=masked,
+                block_kv=block_kv, masked=masked, window=window,
             )
             dv_acc[bi] += jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -609,7 +729,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                     ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
                 ) * scale
 
-    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
+    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold, window, cases)
 
     @pl.when(qi == num_q_blocks - 1)
     def _write():
@@ -665,7 +785,9 @@ def backward_form(q_len: int, kv_len: int, dim: int, dim_v: int, *,
     shapes and blocks runs: ``one_kernel`` where its working set (at the
     geometry :func:`_bwd_prep` pads to) fits the budget, else
     ``two_kernels`` (dq apart from dk/dv, each rebuilding the
-    probabilities, neither holding more than its tiles)."""
+    probabilities, neither holding more than its tiles). A ``window``
+    changes neither this nor :func:`layout_form`: the banded kernels keep the
+    causal grid, its blocks and the resident dq, and skip cells."""
     block_q = _clamp_block(block_q, q_len)
     fits = one_kernel_backward_vmem_bytes(
         _round_up(q_len, block_q), _pad_head(dim), _pad_head(dim_v),
@@ -721,7 +843,8 @@ def layout_form(q_len: int, kv_len: int, dim: int, dim_v: int, *,
 
 def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
                            interpret, *, causal: bool = False,
-                           block_b: Optional[int] = None):
+                           block_b: Optional[int] = None,
+                           window: Optional[int] = None):
     """Blocked backward; q/k/v/out/g are ``[B, L, H, D]``, lse is the padded
     ``[B·H, q_len_p, 128]`` forward residual. One Mosaic call where
     :func:`backward_form` says its working set fits, else two."""
@@ -742,13 +865,14 @@ def _flash_backward_pallas(q, k, v, out, lse, g, scale, block_q, block_kv,
     static = dict(
         scale=scale, q_len=q_len, kv_len=kv_len, block_b=block_b,
         block_q=block_q, block_kv=block_kv, causal=causal,
+        **_band_statics(num_q_blocks, num_kv_blocks, block_q, block_kv, window),
     )
 
     # Under the causal mask a skipped cell names the block its neighbour
     # held, so nothing is fetched for it (see _flash_forward).
     if causal:
-        kv_index = lambda b, i, j: (b, jnp.minimum(j, _last_kv_block(i, block_q, block_kv)), 0)
-        q_index2 = lambda b, j, i: (b, jnp.maximum(i, _first_q_block(j, block_q, block_kv)), 0)
+        kv_index = lambda b, i, j: (b, _visible_kv_block(i, j, block_q, block_kv, window), 0)
+        q_index2 = lambda b, j, i: (b, _visible_q_block(j, i, block_q, block_kv, num_q_blocks, window), 0)
     else:
         kv_index = lambda b, i, j: (b, j, 0)
         q_index2 = lambda b, j, i: (b, i, 0)
@@ -851,7 +975,8 @@ def _sequence_on_lanes(x: jax.Array) -> jax.Array:
 
 
 def _in_place_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, with_lse: bool, scale: float,
-                         block_q: int, block_kv: int, num_kv_blocks: int, causal: bool):
+                         block_q: int, block_kv: int, num_kv_blocks: int, causal: bool,
+                         window: Optional[int] = None, cases: tuple = ()):
     """The online softmax of :func:`_kernel` for one head's ``[d, block]``
     blocks of q, k and v; ``rest`` = ([lse_ref], m, l, acc, q_scr). The q
     block is turned to ``[block_q, d]`` once, when its kv sweep starts;
@@ -879,14 +1004,15 @@ def _in_place_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, with_lse: bool, scal
             q_scr[...], k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [block_q, block_kv]
         if masked:
-            # As in :func:`_kernel`: a finite mask value, and a row's first
-            # visited block always shows it a column.
-            s = jnp.where(_causal_keep(qi, ki, block_q, block_kv), s, _NEG_INF)
+            # As in :func:`_kernel`: under the causal mask alone a row's
+            # first visited block always shows it a column.
+            s = jnp.where(_causal_keep(qi, ki, block_q, block_kv, window=window, edges=masked), s, _NEG_INF)
         # The running max and sum are kept broadcast across a lane tile.
         m_prev, l_prev = m_scr[:, 0:1], l_scr[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
+        against = _finite_max(m_new, window is not None)
+        alpha = jnp.exp(m_prev - against)
+        p = jnp.exp(s - against)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
@@ -894,7 +1020,7 @@ def _in_place_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, with_lse: bool, scal
             p.astype(v.dtype), v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
+    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold, window, cases)
 
     @pl.when(ki == num_kv_blocks - 1)
     def _finalize():
@@ -906,7 +1032,8 @@ def _in_place_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, with_lse: bool, scal
 
 
 def _in_place_forward(q, k, v, scale, block_q, block_kv, interpret,
-                      with_lse: bool = False, *, causal: bool = False):
+                      with_lse: bool = False, *, causal: bool = False,
+                      window: Optional[int] = None):
     """The forward kernel on ``[B, L, H, D]`` operands where they lie (k and
     v may have fewer heads: query head ``h`` reads key/value head ``h //
     (H / H_kv)`` through the block index, and nothing is repeated in HBM); the
@@ -924,7 +1051,7 @@ def _in_place_forward(q, k, v, scale, block_q, block_kv, interpret,
     num_kv_blocks = kv_len // block_kv
 
     if causal:
-        kv_block = lambda i, j: jnp.minimum(j, _last_kv_block(i, block_q, block_kv))
+        kv_block = lambda i, j: _visible_kv_block(i, j, block_q, block_kv, window)
     else:
         kv_block = lambda i, j: j
     kv_head = (lambda h: h // group) if group > 1 else (lambda h: h)
@@ -938,6 +1065,7 @@ def _in_place_forward(q, k, v, scale, block_q, block_kv, interpret,
         functools.partial(
             _in_place_fwd_kernel, with_lse=with_lse, scale=scale, block_q=block_q,
             block_kv=block_kv, num_kv_blocks=num_kv_blocks, causal=causal,
+            **_band_statics(q_len // block_q, num_kv_blocks, block_q, block_kv, window),
         ),
         grid=(batch, heads, q_len // block_q, num_kv_blocks),
         in_specs=[
@@ -962,7 +1090,8 @@ def _in_place_forward(q, k, v, scale, block_q, block_kv, interpret,
 def _in_place_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref, dk_ref, dv_ref,
                          dq_acc, dk_acc, dv_acc, k_scr, v_scr, delta_scr, *,
                          scale: float, block_q: int, block_kv: int,
-                         num_q_blocks: int, num_kv_blocks: int, causal: bool):
+                         num_q_blocks: int, num_kv_blocks: int, causal: bool,
+                         window: Optional[int] = None, cases: tuple = ()):
     """dq, dk and dv of one batch·head cell, as :func:`_bwd_dkv_kernel`
     with ``with_dq`` computes them, on ``[d, block]`` blocks of q, k, v and
     dO. A pair of tiles is computed transposed, ``[block_kv, block_q]``:
@@ -970,7 +1099,8 @@ def _in_place_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref, dk
     the tile, k and v are turned to ``[block_kv, d]`` once a kv block, and
     every matmul takes its operands as they are. ``delta = sum_d dO·O`` of a
     q block is computed when the cell first meets it (kv block 0, which
-    every q block sees) and kept; the float32 dq of the cell is resident as
+    every q block sees; under a window the first kv block the q block sees)
+    and kept; the float32 dq of the cell is resident as
     ``[num_q_blocks, d, block_q]`` and turned as it is written out."""
     ki, qi = pl.program_id(2), pl.program_id(3)
 
@@ -985,7 +1115,7 @@ def _in_place_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref, dk
         k_scr[...] = k_ref[0].T
         v_scr[...] = v_ref[0].T
 
-    @pl.when(ki == 0)
+    @pl.when(ki == _first_kv_block(qi, block_q, block_kv, window))
     def _delta():
         delta_scr[qi] = jnp.sum(
             do_ref[0].astype(jnp.float32) * o_ref[0].T.astype(jnp.float32), axis=0, keepdims=True
@@ -998,7 +1128,8 @@ def _in_place_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref, dk
         ) * scale  # [block_kv, block_q]
         p = jnp.exp(s - lse_ref[0, 0])
         if masked:
-            p = jnp.where(_causal_keep(qi, ki, block_q, block_kv, transposed=True), p, 0.0)
+            keep = _causal_keep(qi, ki, block_q, block_kv, transposed=True, window=window, edges=masked)
+            p = jnp.where(keep, p, 0.0)
         dp = jax.lax.dot_general(
             v_scr[...], do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -1013,7 +1144,7 @@ def _in_place_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref, dk
             k_ref[0], ds, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         ) * scale
 
-    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold)
+    _for_causal_blocks(causal, qi, ki, block_q, block_kv, fold, window, cases)
 
     @pl.when(qi == num_q_blocks - 1)
     def _write():
@@ -1027,7 +1158,8 @@ def _in_place_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref, dk
 
 
 def _in_place_backward(q, k, v, out, lse, g, scale, block_q, block_kv,
-                       interpret, *, causal: bool = False):
+                       interpret, *, causal: bool = False,
+                       window: Optional[int] = None):
     """The one-kernel backward on ``[B, L, H, D]`` operands where they lie;
     ``out`` and ``lse`` are the forward's ``[B, Lq, H·D_v]`` and
     ``[B, H, 1, Lq]``. dq, dk and dv are written as ``[B, L, H·D]`` arrays
@@ -1046,10 +1178,20 @@ def _in_place_backward(q, k, v, out, lse, g, scale, block_q, block_kv,
     # Under the causal mask a skipped cell names the block its neighbour
     # held, so nothing is fetched for it; the output block is read while the
     # cell's first kv block computes ``delta`` and stays where it is after.
+    # Under a window a q block's ``delta`` waits for the first kv block that
+    # sees it: kv block ``j`` brings in the output blocks past the last one
+    # kv block ``j - 1`` saw, up to the last it sees itself.
     if causal:
-        q_block = lambda j, i: jnp.maximum(i, _first_q_block(j, block_q, block_kv))
+        q_block = lambda j, i: _visible_q_block(j, i, block_q, block_kv, num_q_blocks, window)
     else:
         q_block = lambda j, i: i
+    if window is None:
+        out_block = lambda j, i: jnp.where(j == 0, i, num_q_blocks - 1)
+    else:
+        last_seen = lambda j: _last_q_block(j, block_q, block_kv, num_q_blocks, window)
+        out_block = lambda j, i: jnp.minimum(
+            jnp.maximum(i, jnp.where(j == 0, 0, last_seen(j - 1) + 1)), last_seen(j)
+        )
     q_index = lambda b, h, j, i: (b, h, q_block(j, i))
     kv_head = (lambda h: h // group) if group > 1 else (lambda h: h)
     kv_index = lambda b, h, j, i: (b, kv_head(h), j)
@@ -1058,6 +1200,7 @@ def _in_place_backward(q, k, v, out, lse, g, scale, block_q, block_kv,
         functools.partial(
             _in_place_bwd_kernel, scale=scale, block_q=block_q, block_kv=block_kv,
             num_q_blocks=num_q_blocks, num_kv_blocks=num_kv_blocks, causal=causal,
+            **_band_statics(num_q_blocks, num_kv_blocks, block_q, block_kv, window),
         ),
         grid=(batch, heads, num_kv_blocks, num_q_blocks),
         in_specs=[
@@ -1066,10 +1209,7 @@ def _in_place_backward(q, k, v, out, lse, g, scale, block_q, block_kv,
             pl.BlockSpec((1, dim_v, block_kv), kv_index),
             pl.BlockSpec((1, dim_v, block_q), q_index),
             pl.BlockSpec((1, 1, 1, block_q), lambda b, h, j, i: (b, h, 0, q_block(j, i))),
-            pl.BlockSpec(
-                (1, block_q, dim_v),
-                lambda b, h, j, i: (b, jnp.where(j == 0, i, num_q_blocks - 1), h),
-            ),
+            pl.BlockSpec((1, block_q, dim_v), lambda b, h, j, i: (b, out_block(j, i), h)),
         ],
         out_specs=[
             pl.BlockSpec((1, q_len, dim), lambda b, h, j, i: (b, 0, h)),
@@ -1122,22 +1262,22 @@ def _runs_in_place(q, k, v, bias, block_q, block_kv, block_b) -> bool:
     ) == "in_place"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b, window):
     if _runs_in_place(q, k, v, bias, block_q, block_kv, block_b):
-        out = _in_place_forward(q, k, v, scale, block_q, block_kv, interpret, causal=causal)
+        out = _in_place_forward(q, k, v, scale, block_q, block_kv, interpret, causal=causal, window=window)
         return out.reshape(q.shape[:3] + v.shape[3:])
     return _flash_forward(
         q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), bias, scale,
-        block_q, block_kv, interpret, causal=causal, block_b=block_b,
+        block_q, block_kv, interpret, causal=causal, block_b=block_b, window=window,
     )
 
 
-def _flash_fwd(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b):
+def _flash_fwd(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block_b, window):
     if bias is not None:
         out = _flash_forward(
             q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), bias, scale,
-            block_q, block_kv, interpret, causal=causal, block_b=block_b,
+            block_q, block_kv, interpret, causal=causal, block_b=block_b, window=window,
         )
         return out, (q, k, v, bias, None, None)
     # The residuals are the output and one float32 a row of logsumexp. A
@@ -1148,7 +1288,7 @@ def _flash_fwd(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block
         # Both as the kernel wrote them and as the backward kernel reads
         # them: a kept [B, L, H, D] would be laid out anew for every read.
         kept, lse_row = _in_place_forward(
-            q, k, v, scale, block_q, block_kv, interpret, with_lse=True, causal=causal
+            q, k, v, scale, block_q, block_kv, interpret, with_lse=True, causal=causal, window=window
         )
         kept = checkpoint_name(kept, "flash_out")
         out = kept.reshape(q.shape[:3] + v.shape[3:])
@@ -1157,14 +1297,14 @@ def _flash_fwd(q, k, v, bias, scale, block_q, block_kv, interpret, causal, block
         # backward rebuilds.
         out, lse = _flash_forward(
             q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), bias, scale,
-            block_q, block_kv, interpret, with_lse=True, causal=causal, block_b=block_b,
+            block_q, block_kv, interpret, with_lse=True, causal=causal, block_b=block_b, window=window,
         )
         out = kept = checkpoint_name(out, "flash_out")
         lse_row = lse[..., 0]
     return out, (q, k, v, bias, kept, checkpoint_name(lse_row, "flash_lse"))
 
 
-def _flash_bwd(scale, block_q, block_kv, interpret, causal, block_b, residuals, g):
+def _flash_bwd(scale, block_q, block_kv, interpret, causal, block_b, window, residuals, g):
     """Backward dispatch: blocked Pallas kernels when there is no bias, in
     the layout the forward ran in (the rule reads the same shapes); XLA
     flash-style recompute when a dbias is needed (the dense ``ds`` is
@@ -1173,23 +1313,25 @@ def _flash_bwd(scale, block_q, block_kv, interpret, causal, block_b, residuals, 
     if bias is None:
         if _runs_in_place(q, k, v, bias, block_q, block_kv, block_b):
             dq, dk, dv = _in_place_backward(
-                q, k, v, out, lse_row, g, scale, block_q, block_kv, interpret, causal=causal
+                q, k, v, out, lse_row, g, scale, block_q, block_kv, interpret, causal=causal, window=window
             )
             return dq, dk, dv, None
         lse = jnp.broadcast_to(lse_row[..., None], lse_row.shape + (128,))
         dq, dk, dv = _flash_backward_pallas(
             q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), out, lse, g, scale,
-            block_q, block_kv, interpret, causal=causal, block_b=block_b,
+            block_q, block_kv, interpret, causal=causal, block_b=block_b, window=window,
         )
         return dq, _sum_group(dk, k), _sum_group(dv, v), None
     del block_q, block_kv, interpret, block_b
     dq, dk, dv, dbias = _dense_recompute_bwd(
-        q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), bias, g, scale, causal=causal
+        q, _repeat_group(k, q.shape[2]), _repeat_group(v, q.shape[2]), bias, g, scale, causal=causal,
+        window=window,
     )
     return dq, _sum_group(dk, k), _sum_group(dv, v), dbias
 
 
-def _dense_recompute_bwd(q, k, v, bias, g, scale, *, causal: bool = False):
+def _dense_recompute_bwd(q, k, v, bias, g, scale, *, causal: bool = False,
+                         window: Optional[int] = None):
     """XLA flash-style recompute backward for the biased path — shared by
     this kernel and the fused short-sequence kernel
     (:mod:`sav_tpu.ops.fused_attention`): a dense dbias is O(L²) by
@@ -1200,7 +1342,7 @@ def _dense_recompute_bwd(q, k, v, bias, g, scale, *, causal: bool = False):
     if bias is not None:
         s = s + bias.astype(jnp.float32)
     if causal:
-        s = jnp.where(_causal_keep(0, 0, *s.shape[-2:]), s, _NEG_INF)
+        s = jnp.where(_causal_keep(0, 0, *s.shape[-2:], window=window), s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)  # [B, H, Lq, Lk] fp32
     p_mm = p.astype(mm_dtype)
     g_mm = g.astype(mm_dtype)
@@ -1246,6 +1388,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
     causal: bool = False,
     block_b: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused flash attention.
 
@@ -1271,6 +1414,12 @@ def flash_attention(
         :func:`_pick_block_b`. A long causal sequence wants 1: many kv
         blocks a cell already amortise the grid's step, and VMEM holds
         ``block_b`` tiles of everything.
+      window: with ``causal``, position ``i`` attends to ``i - window < j <=
+        i`` (itself and the ``window - 1`` before it). A q block visits the
+        kv blocks from its first row's farthest column to its diagonal and
+        no others, forward and backward; the far edge is masked in the
+        blocks it crosses. A window no shorter than the sequence is the
+        causal mask and runs the causal kernels.
 
     Returns:
       ``[B, q_len, heads, value_dim]`` in the query dtype.
@@ -1287,10 +1436,34 @@ def flash_attention(
         scale = query.shape[-1] ** -0.5
     if bias is not None and bias.ndim != 4:
         raise ValueError(f"bias must be 4-D broadcastable, got {bias.shape}")
+    window = effective_window(window, causal, query.shape[1])
     return _flash(
         query, key, value, bias, float(scale), block_q, block_kv, interpret,
-        bool(causal), block_b,
+        bool(causal), block_b, window,
     )
+
+
+def effective_window(window: Optional[int], causal: bool, q_len: int) -> Optional[int]:
+    """The window the kernels are built with: None for one that hides
+    nothing of a sequence of ``q_len`` (the causal mask alone)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"a window of {window} needs causal attention and at least one position")
+    return None if window >= q_len else int(window)
+
+
+def visited_blocks(q_len: int, kv_len: int, *, block_q: int = DEFAULT_BLOCK, block_kv: int = DEFAULT_BLOCK,
+                   window: Optional[int] = None) -> dict:
+    """``kv_blocks_visited`` and ``kv_blocks_causal`` of a causal call of
+    these lengths and blocks, a batch·head slice: the grid cells with work
+    under ``window`` and under the causal mask alone (static, from the grid
+    :func:`flash_attention` builds)."""
+    block_q, block_kv = _clamp_block(block_q, q_len), _clamp_block(block_kv, kv_len)
+    counts = band_blocks(
+        -(-q_len // block_q), -(-kv_len // block_kv), block_q, block_kv, effective_window(window, True, q_len)
+    )
+    return {"kv_blocks_visited": counts["visited"], "kv_blocks_causal": counts["causal"]}
 
 # ---------------------------------------------------------------------------
 # BoTNet 2-D relative-position flash attention (SURVEY.md §7 "hard parts"):

@@ -44,8 +44,9 @@ def yarn_inv_freq(dim: int, base, scaling: Mapping) -> jax.Array:
     base's frequency, those that turn ``beta_slow`` times or fewer take it
     over ``factor``, and between the two pair indices (floor of the first,
     ceiling of the second) the two are blended linearly in the index."""
-    if scaling.get("type", "yarn") != "yarn":
-        raise ValueError(f"rotary scaling {scaling.get('type')!r} is not implemented: yarn alone")
+    kind = scaling.get("type") or scaling.get("rope_type", "yarn")
+    if kind != "yarn":
+        raise ValueError(f"rotary scaling {kind!r} is not implemented: yarn alone")
     original = scaling["original_max_position_embeddings"]
 
     def pair_index(rotations: float) -> float:
@@ -104,12 +105,22 @@ def apply_rotary_pos_emb(x: jax.Array, sincos) -> jax.Array:
     return x * cos + rotate_every_two(x) * sin
 
 
-def half_split_tables(seq_len: int, dim: int, base: float = 10000.0):
+def half_split_tables(seq_len: int, dim: int, base: float = 10000.0, scaling: Optional[Mapping] = None):
     """Float32 ``(sin, cos)`` tables ``[seq_len, dim]`` for :func:`rotate_half`
-    pairing: frequency ``i`` sits at lanes ``i`` and ``i + dim/2``."""
-    freqs = _angles(seq_len, dim, base)
+    pairing: frequency ``i`` sits at lanes ``i`` and ``i + dim/2``. Under
+    ``scaling`` (a public config's YaRN group) the frequencies are
+    :func:`yarn_inv_freq`'s and both tables are multiplied by the group's
+    ``attention_factor`` (``0.1 ln(factor) + 1`` where it gives none): the
+    convention of the configs that spell the key so, the temperature on the
+    tables and not on the softmax scale (:func:`yarn_softmax_scale` is the
+    other convention's)."""
+    freqs = _angles(seq_len, dim, base, scaling)
     freqs = jnp.concatenate([freqs, freqs], axis=-1)  # [L, dim]
-    return jnp.sin(freqs), jnp.cos(freqs)
+    sin, cos = jnp.sin(freqs), jnp.cos(freqs)
+    if scaling:
+        factor = scaling.get("attention_factor") or yarn_mscale(scaling["factor"], 1.0)
+        sin, cos = sin * factor, cos * factor
+    return sin, cos
 
 
 def rotate_half(x: jax.Array) -> jax.Array:

@@ -314,6 +314,7 @@ class MTPTokenPrediction(TokenPrediction):
     layer_stats = (
         ("hc_doubly_stochastic_err", jnp.max), ("hc_stream_gain", jnp.max),
         ("gdn_decay_min", jnp.min), ("gdn_state_rms_max", jnp.max), ("attn_gate_mean", jnp.mean),
+        ("attn_gate_mean_window", jnp.mean), ("attn_gate_mean_full", jnp.mean),
         ("sconv_out_rms_max", jnp.max),
         ("kda_decay_min", jnp.min), ("kda_state_rms_max", jnp.max), ("moe_groups_held", jnp.mean),
     )

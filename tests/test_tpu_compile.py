@@ -493,6 +493,110 @@ def test_flash_backward_compiles_in_the_form_its_rule_picks(one_chip, shape, blo
     )
 
 
+def _pallas_grids(jaxpr) -> list:
+    """The grid of every ``pallas_call`` of a traced program, in order."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                grids.extend(_pallas_grids(inner))
+    return grids
+
+
+# (name, [B, L, H, D], key/value heads, window, layout): the window/full hybrid
+# cell's two cores, and a causal cell's core (Ouro's) for what a window must not change.
+BAND_CASES = [
+    ("laguna_window", (1, 4096, 72, 128), 8, 512, "head_major"),
+    ("laguna_full", (1, 4096, 48, 128), 8, None, "in_place"),
+    ("ouro_causal", (2, 4096, 16, 128), 16, None, "in_place"),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,kv_heads,window,layout", [case[1:] for case in BAND_CASES], ids=[case[0] for case in BAND_CASES]
+)
+def test_banded_and_causal_cores_compile_at_the_cells_shapes(one_chip, shape, kv_heads, window, layout):
+    """Forward and backward of ``flash_attention`` at the blocks the tune
+    cache gives the shape (a banded core reads its own entries), compiled for
+    a described v5e: two Mosaic calls (the forward and the one-kernel
+    backward) on the full causal grid, of which a banded core visits the
+    band's cells alone. Without a window the program is the one a window no
+    shorter than the sequence builds: the same calls on the same grid, told
+    nothing of a window."""
+    import importlib
+
+    from sav_tpu.ops import attn_tuning
+
+    flmod = importlib.import_module("sav_tpu.ops.flash_attention")
+    batch, length, heads, dim = shape
+    entry = attn_tuning.lookup(batch, length, length, heads, dim, causal=True, window=window)
+    blocks = attn_tuning.block_config(entry)
+    assert blocks and entry["backend"] == "pallas", "the cell's core shapes have measured entries"
+    sizes = dict(batch_heads=batch * heads, **blocks)
+    assert flmod.layout_form(length, length, dim, dim, **sizes) == layout
+    assert flmod.backward_form(length, length, dim, dim, **sizes) == "one_kernel"
+
+    def spec(h):
+        return jax.ShapeDtypeStruct((batch, length, h, dim), jnp.bfloat16, sharding=one_chip)
+
+    def both(window):
+        def loss(q, k, v):
+            out = flmod.flash_attention(q, k, v, causal=True, window=window, interpret=False, **blocks)
+            return jnp.sum(out.astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+    args = (spec(heads), spec(kv_heads), spec(kv_heads))
+    q_blocks, kv_blocks = length // blocks["block_q"], length // blocks["block_kv"]
+    if layout == "in_place":  # a head a cell
+        grids = [(batch, heads, q_blocks, kv_blocks), (batch, heads, kv_blocks, q_blocks)]
+    else:  # block_b slices a cell
+        slices = batch * heads // blocks["block_b"]
+        grids = [(slices, q_blocks, kv_blocks), (slices, kv_blocks, q_blocks)]
+    assert _pallas_grids(jax.make_jaxpr(both(window))(*args).jaxpr) == grids
+    compiled = jax.jit(both(window)).lower(*args).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+    assert _bytes_on_device(compiled) < HBM_BYTES
+    counts = flmod.visited_blocks(length, length, window=window, **{k: v for k, v in blocks.items() if k != "block_b"})
+    if window is not None:
+        assert counts["kv_blocks_visited"] < counts["kv_blocks_causal"] <= q_blocks * kv_blocks
+        return
+    assert counts["kv_blocks_visited"] == counts["kv_blocks_causal"]
+    # The causal program of before: a window that hides nothing builds it, letter for letter.
+    text = str(jax.make_jaxpr(both(None))(*args))
+    assert "window" not in text and "cases" not in text
+    assert str(jax.make_jaxpr(both(length))(*args)) == text
+
+
+def test_the_banded_core_compiles_in_place_too(one_chip):
+    """The in-place banded kernels at the cell's window shape and 512-row
+    blocks (the sweep's runner-up, what a model whose projections write the
+    sequence on the lanes may prefer): a head a cell through the index maps,
+    15 of 36 cells with work."""
+    import importlib
+
+    flmod = importlib.import_module("sav_tpu.ops.flash_attention")
+    blocks = dict(block_q=512, block_kv=512, block_b=1)
+    assert flmod.layout_form(4096, 4096, 128, 128, batch_heads=72, **blocks) == "in_place"
+
+    def spec(h):
+        return jax.ShapeDtypeStruct((1, 4096, h, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flmod.flash_attention(q, k, v, causal=True, window=512, interpret=False, **blocks)
+        return jnp.sum(out.astype(jnp.float32))
+
+    both = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    args = (spec(72), spec(8), spec(8))
+    assert _pallas_grids(jax.make_jaxpr(both)(*args).jaxpr) == [(1, 72, 8, 8), (1, 72, 8, 8)]
+    compiled = jax.jit(both).lower(*args).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+    assert flmod.visited_blocks(4096, 4096, window=512, block_q=512, block_kv=512) == {
+        "kv_blocks_visited": 15, "kv_blocks_causal": 36}
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 def test_ring_attention_compiles_for_four_chips(topo, backend, direction):

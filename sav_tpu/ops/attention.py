@@ -261,9 +261,16 @@ def _log_dispatch(shape, kv_len, kv_heads, requested, dispatch: AttentionDispatc
         "kv_heads": kv_heads,
         "requested": requested or "auto",
         # A banded core's window (absent from a record without one) and,
-        # with the flash kernel, 'kv_blocks_visited' / 'kv_blocks_causal'
-        # among the forms below: the grid cells with work a batch·head
-        # slice, under the window and under the causal mask alone.
+        # with the flash kernel, among the forms below: 'band', the kernels
+        # that run it ('resident': the pair whose grid is the band, a q
+        # block and key/value head a cell; 'skipped_cells': the causal
+        # kernels' arm, which skips the cells outside it), and a head's
+        # counts of (q block, kv block) pairs: 'kv_blocks_visited' with work
+        # under the window, 'kv_blocks_causal' under the causal mask alone,
+        # 'kv_blocks_grid' spanned by the grid (the causal square, or the
+        # resident blocks of every q block, the clipped ones before a
+        # sequence's start among them), 'flush_cells' (resident: the
+        # backward's cells past a sequence's end, which write dk and dv).
         **({} if window is None else {"window": window}),
         **dispatch.as_note(),
         # The flash kernel's forms: 'backward' ('one_kernel' |
@@ -271,7 +278,8 @@ def _log_dispatch(shape, kv_len, kv_heads, requested, dispatch: AttentionDispatc
         # 'head_major') and, where the key/value heads are fewer than
         # the query's, 'grouped_kv' ('index_maps': the kernels find a
         # group's head through their block index | 'repeated': the
-        # head-major copies repeat it).
+        # head-major copies repeat it | 'in_cell': the resident pair holds
+        # a group's query heads in the cell of their key/value head).
         **(flash_forms or {}),
     })
 
@@ -421,7 +429,12 @@ def dot_product_attention(
     in the flash kernel (blocks above the diagonal are skipped); the
     single-pass ``fused`` kernel has no causal arm and refuses it.
     ``window`` (with ``causal``) narrows the mask to ``i - window < j <= i``
-    on both paths; one no shorter than the sequence is the causal mask.
+    on both paths; one no shorter than the sequence is the causal mask. In
+    the flash kernel the shapes decide which kernels run the band
+    (``flash_attention.band_form``): the resident pair, whose grid is the
+    band (heads of whole lane tiles, equal blocks that divide the sequence,
+    no bias, a cell that fits VMEM), else the causal kernels' arm, which
+    skips the cells outside it; the dispatch log's ``band`` says which.
 
     ``logits_dtype`` sets the XLA path's softmax dtype (None = the
     deprecated process-wide default, f32 unless configured). The Pallas
@@ -455,14 +468,23 @@ def dot_product_attention(
         if backend == "pallas":
             lengths = (lq, key.shape[1], d, value.shape[-1])
             sizes = dict(batch_heads=b * h, itemsize=query.dtype.itemsize, **flash_blocks)
-            flash_forms = {"layout": _flash.layout_form(*lengths, biased=bias is not None, **sizes)}
-            if bias is None:
-                flash_forms["backward"] = _flash.backward_form(*lengths, **sizes)
+            blocks = {k: v for k, v in flash_blocks.items() if k != "block_b"}
+            band = _flash.band_form(
+                *lengths, heads=h, kv_heads=key.shape[2], window=window, biased=bias is not None,
+                itemsize=query.dtype.itemsize, **blocks,
+            )
+            if band == "resident":  # operands where they lie, one call a direction
+                flash_forms = {"layout": "in_place", "backward": "one_kernel"}
+            else:
+                flash_forms = {"layout": _flash.layout_form(*lengths, biased=bias is not None, **sizes)}
+                if bias is None:
+                    flash_forms["backward"] = _flash.backward_form(*lengths, **sizes)
             if key.shape[2] != h:
-                flash_forms["grouped_kv"] = "index_maps" if flash_forms["layout"] == "in_place" else "repeated"
-            if window is not None:
-                blocks = {k: v for k, v in flash_blocks.items() if k != "block_b"}
-                flash_forms.update(_flash.visited_blocks(lq, key.shape[1], window=window, **blocks))
+                by_layout = "index_maps" if flash_forms["layout"] == "in_place" else "repeated"
+                flash_forms["grouped_kv"] = "in_cell" if band == "resident" else by_layout
+            if band:
+                flash_forms["band"] = band
+                flash_forms.update(_flash.band_cells(lq, key.shape[1], window=window, form=band, **blocks))
         _log_dispatch(tuple(query.shape), key.shape[1], key.shape[2], requested, dispatch, flash_forms, window)
     else:
         if backend in ("pallas", "fused"):

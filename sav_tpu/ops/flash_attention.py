@@ -9,9 +9,22 @@ in HBM. An optional additive bias input carries 2-D relative-position logits
 self-attention) needs no bias: a block wholly above the diagonal is neither
 fetched nor computed, a block the diagonal crosses is masked in VMEM by an
 iota comparison, forward and backward. ``window`` beside it is a band
-(``i - window < j <= i``, sliding-window layers): the blocks wholly behind
-the window are skipped as those above the diagonal are, and the block its
-far edge crosses is masked by the same comparison (:func:`band_keep`).
+(``i - window < j <= i``, sliding-window layers; the mask is
+:func:`band_keep`), and the shapes say which kernels run it
+(:func:`band_form`, logged as ``band``). ``resident``: a kernel pair of its
+own whose grid IS the band, one cell a q block and key/value head, the
+group's query heads and the kv blocks the q block sees inside it, each
+head's softmax finished in the cell, dk and dv summed over the group and
+the cells in a VMEM ring, operands and results all ``[B, H·D, L]`` (the
+section before :func:`_band_forward`). It takes an unbiased call whose
+heads are whole 128-lane tiles, whose q and kv blocks are equal whole lane
+tiles that divide the sequence, and whose cell fits the one-kernel
+backward's VMEM budget and a bound on the unrolled program (a window of a
+few blocks). ``skipped_cells``: everything else (a head of 64, a ragged
+length, a bias, unequal blocks, a window of many blocks) runs as an arm of
+the causal kernels below, on the causal grid: the blocks wholly behind the
+window are skipped as those above the diagonal are, and the block the far
+edge crosses is masked by the same comparison.
 
 Layout: where every block can be one head's rows of the caller's own
 arrays, the unbiased kernels read and write those in place
@@ -786,8 +799,9 @@ def backward_form(q_len: int, kv_len: int, dim: int, dim_v: int, *,
     geometry :func:`_bwd_prep` pads to) fits the budget, else
     ``two_kernels`` (dq apart from dk/dv, each rebuilding the
     probabilities, neither holding more than its tiles). A ``window``
-    changes neither this nor :func:`layout_form`: the banded kernels keep the
-    causal grid, its blocks and the resident dq, and skip cells."""
+    changes neither this nor :func:`layout_form`: the causal kernels' banded
+    arm keeps the causal grid, its blocks and the resident dq, and skips
+    cells (the band's resident pair, :func:`band_form`, asks neither)."""
     block_q = _clamp_block(block_q, q_len)
     fits = one_kernel_backward_vmem_bytes(
         _round_up(q_len, block_q), _pad_head(dim), _pad_head(dim_v),
@@ -1376,6 +1390,390 @@ def _dense_recompute_bwd(q, k, v, bias, g, scale, *, causal: bool = False,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# ---------------------------------------------------------------------------
+# The band-resident pair (band_form): a sliding-window layer's kernels, whose
+# grid IS the band. One grid cell is a q block and a key/value head. It holds
+# the group's query heads (adjacent rows of the ``[B, H·D, L]`` view, so one
+# ``(1, group·D, block)`` block) and the ``resident`` kv blocks the q block
+# can see (its own and those before it: the same k array named ``resident``
+# times, at ``i - t``), and finishes each head's softmax inside the cell: no
+# statistics carried across grid steps, no accumulator rescaled, no cell
+# without work, k and v fetched once a group and repeated nowhere. With equal
+# q and kv blocks the geometry of the ``t``-th block behind the q block's own
+# is the same in every cell: which edge crosses it is static
+# (:func:`_band_tile_edges`), and only the first ``resident - 1`` cells of a
+# sequence differ, by holding fewer blocks (a body a count).
+#
+# Operands AND results lie with the sequence on the lanes, ``[B, H·D, L]``:
+# q, k, v and dO as the in-place causal kernels read them, and out, dq, dk
+# and dv too (those kernels write theirs ``[B, L, H·D]``). What takes the
+# results next in a decoder block is not a matmul alone: the output meets a
+# head-wise gate, dq, dk and dv the rotary's and the norm's backward, all of
+# which XLA runs in the order their forward left, the sequence on the lanes;
+# written ``[B, L, H·D]``, each result was laid out anew in float32 before
+# its first use (six q-sized copies a layer in the compiled step, one with
+# the results where they are read: PERF.md section 6, PR 47).
+#
+# The forward stacks the group's heads on the rows, ``[group·block_q, d]``
+# against k and v as they lie: one matmul a kv block streams the whole group
+# past it (12% under a matmul a head on the chip, same section). The
+# backward computes a head's tiles transposed, ``[block_kv, block_q]``, as
+# the in-place causal backward does: the logsumexp and ``delta`` are rows, k
+# and v are turned once a cell for the whole group, and o, dO, q and dq are
+# used as they lie. It runs over the same cells: dq of a (head, q block) is
+# whole inside its cell; dk and dv of a kv block gather from ``resident``
+# consecutive cells and the group's heads in a float32 VMEM ring, and leave
+# once, at the key/value heads and in the operands' dtype, when the last
+# cell that sees the block has added its share (``resident - 1`` flush cells
+# at a sequence's end write the ring's tail).
+# ---------------------------------------------------------------------------
+
+# The pair's bodies are unrolled over the group's heads and the resident
+# blocks, one body a count of blocks held: ``group * resident * (resident + 1)
+# / 2`` tiles of code a kernel. Past this many the banded arm of the causal
+# kernels runs (a window of many blocks is nearer the triangle than the band).
+BAND_MAX_UNROLLED_TILES = 192
+
+
+def band_resident_blocks(q_len: int, block: int, window: int) -> int:
+    """The kv blocks a q block of ``block`` rows can see under ``window``:
+    its own and the ``ceil((window - 1) / block)`` before it (no more than
+    the sequence has)."""
+    return min(-(-(window - 1) // block) + 1, -(-q_len // block))
+
+
+def _band_tile_edges(t: int, block: int, window: int) -> tuple:
+    """``(diagonal, far)`` of the ``t``-th kv block behind a q block's own:
+    the diagonal crosses the q block's own alone, the window's far edge
+    (``col == row - window``) every block whose first column some row of the
+    q block, ``t`` blocks on, no longer sees."""
+    return t == 0, t * block + block - 1 >= window
+
+
+def band_vmem_bytes(group: int, dim: int, dim_v: int, *, block: int, resident: int, itemsize: int = 2) -> dict:
+    """Estimated VMEM working set of a cell of each kernel of the resident
+    pair. ``backward``: the group's q, dO, o and dq blocks and one k, v
+    block a resident block in, dk and dv out, every block double-buffered;
+    a resident block's slot of the float32 ring, its partial sums in the
+    cell and their sum under way, k and v turned; and six float32 logits
+    tiles (two heads' ``s``, ``dp``, ``ds`` in flight). ``forward``: q and o,
+    the logsumexp and the k, v blocks double-buffered, the group's q turned,
+    and five bytes a logit of the whole group against every resident block
+    (``s`` and what of ``p`` Mosaic keeps beside it). Compiled for a
+    described v5e at groups of 1 to 9, blocks 128 to 1,024 and two to
+    seventeen resident blocks, Mosaic reports using 44 to 94% of the
+    forward's and 35 to 96% of the backward's (a group of one holds the
+    least of it)."""
+    heads, kv_blocks = dim + dim_v, resident * (dim + dim_v) * block * itemsize
+    rows = _round_up(group, 8) * block * 4  # the logsumexp
+    backward = 2 * (2 * group * heads * block * itemsize + rows + kv_blocks + heads * block * itemsize)
+    backward += resident * heads * block * (4 + 4 + 4 + itemsize) + 6 * block * block * 4
+    forward = 2 * (group * heads * block * itemsize + rows + kv_blocks) + group * dim * block * itemsize
+    forward += 5 * resident * group * block * block
+    return {"forward": forward, "backward": backward}
+
+
+def band_form(q_len: int, kv_len: int, dim: int, dim_v: int, *, heads: int, kv_heads: int,
+              window: Optional[int], biased: bool = False, block_q: int = DEFAULT_BLOCK,
+              block_kv: int = DEFAULT_BLOCK, itemsize: int = 2) -> Optional[str]:
+    """Which kernels a :func:`flash_attention` of these shapes and blocks
+    runs a band in: None without an effective ``window``; ``resident``, the
+    pair above, for an unbiased call whose heads are whole 128-lane tiles,
+    whose q and kv blocks are equal whole lane tiles that divide the
+    sequence, and whose cell (the group's heads, the resident blocks, the
+    ring) fits the one-kernel backward's VMEM budget and the unrolled
+    program's bound; else ``skipped_cells``, the causal kernels' banded arm
+    (a head of 64, a ragged length, a bias, unequal blocks, a window of too
+    many blocks). A rule on what the call can see, nothing a caller sets."""
+    if window is None:
+        return None
+    block = _clamp_block(block_q, q_len)
+    whole = (
+        not biased and q_len == kv_len and block == _clamp_block(block_kv, kv_len)
+        and dim % 128 == 0 and dim_v % 128 == 0 and block % 128 == 0 and q_len % block == 0
+    )
+    if not whole:
+        return "skipped_cells"
+    group, resident = heads // kv_heads, band_resident_blocks(q_len, block, window)
+    fits = (
+        group * resident * (resident + 1) // 2 <= BAND_MAX_UNROLLED_TILES
+        and max(band_vmem_bytes(group, dim, dim_v, block=block, resident=resident, itemsize=itemsize).values())
+        <= ONE_KERNEL_VMEM_BUDGET
+    )
+    return "resident" if fits else "skipped_cells"
+
+
+def band_cells(q_len: int, kv_len: int, *, block_q: int = DEFAULT_BLOCK, block_kv: int = DEFAULT_BLOCK,
+               window: int, form: str) -> dict:
+    """Static counts of a banded call a head (``form`` as :func:`band_form`
+    gives it): ``kv_blocks_visited``, the (q block, kv block) pairs with work
+    under the window; ``kv_blocks_causal``, those under the causal mask alone;
+    ``kv_blocks_grid``, the pairs the grid spans: the causal square for
+    ``skipped_cells``, ``resident`` a q block for ``resident``, of which the
+    ``resident (resident - 1) / 2`` before a sequence's start are clipped
+    (held by no body); and, ``resident``, the ``flush_cells`` after a
+    sequence's last q block in which the backward writes the ring's tail."""
+    counts = visited_blocks(q_len, kv_len, block_q=block_q, block_kv=block_kv, window=window)
+    block_q, block_kv = _clamp_block(block_q, q_len), _clamp_block(block_kv, kv_len)
+    if form != "resident":
+        return dict(counts, kv_blocks_grid=-(-q_len // block_q) * -(-kv_len // block_kv))
+    resident = band_resident_blocks(q_len, block_q, window)
+    return dict(counts, kv_blocks_grid=(q_len // block_q) * resident, flush_cells=resident - 1)
+
+
+def _band_keeps(count: int, block: int, window: int, transposed: bool) -> list:
+    """The element mask of each of a cell's ``count`` tiles (None where no
+    edge crosses it): :func:`band_keep` through :func:`_causal_keep`, the q
+    block ``t`` blocks on from the kv block (``transposed``: rows on the lanes)."""
+    def keep(t):
+        edges = _band_tile_edges(t, block, window)
+        return _causal_keep(t, 0, block, block, transposed=transposed, window=window, edges=edges) if any(edges) else None
+    return [keep(t) for t in range(count)]
+
+
+def _for_band_cells(i, resident: int, num_blocks: int, cell):
+    """Run ``cell(count)`` for q block ``i``: the first ``resident - 1`` cells
+    of a sequence hold ``i + 1`` blocks, every later one ``resident``; past
+    the last q block (the backward's flush cells) nothing."""
+    for count in range(1, resident):
+        pl.when(i == count - 1)(functools.partial(cell, count))
+    pl.when(jnp.logical_and(i >= resident - 1, i < num_blocks))(functools.partial(cell, resident))
+
+
+def _band_fwd_kernel(q_ref, *rest, resident: int, group: int, dim: int, dim_v: int, block: int,
+                     window: int, scale: float, with_lse: bool, num_blocks: int):
+    """One q block of a key/value head's ``group`` query heads against the
+    kv blocks it sees; ``rest`` = (k_ref x resident, v_ref x resident, o_ref,
+    [lse_ref]). The heads are stacked on the rows (a row is a head and a
+    position; the q blocks are turned once a cell), so a kv block meets the
+    whole group in one matmul; a row's softmax is whole inside the cell: the
+    logits of its tiles, their joint max and sum, ``p v`` summed and
+    normalised."""
+    k_refs, v_refs = rest[:resident], rest[resident:2 * resident]
+    o_ref = rest[2 * resident]
+    lse_ref = rest[2 * resident + 1] if with_lse else None
+
+    def cell(count: int):
+        q = jnp.concatenate([q_ref[0, g * dim:(g + 1) * dim, :].T for g in range(group)], axis=0)  # [group·block, d]
+        keeps = [
+            None if keep is None else jnp.concatenate([keep] * group, axis=0)
+            for keep in _band_keeps(count, block, window, transposed=False)
+        ]
+        s = []
+        for t in range(count):
+            s_t = jax.lax.dot_general(
+                q, k_refs[t][0], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale  # [group·block_q, block_kv]
+            s.append(s_t if keeps[t] is None else jnp.where(keeps[t], s_t, _NEG_INF))
+        # Every row sees its own column: the max is finite.
+        m = jnp.max(functools.reduce(jnp.maximum, s), axis=1, keepdims=True)
+        p = [jnp.exp(s_t - m) for s_t in s]
+        l = jnp.sum(functools.reduce(jnp.add, p), axis=1, keepdims=True)
+        acc = sum(
+            jax.lax.dot_general(
+                p[t].astype(v_refs[t].dtype), v_refs[t][0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for t in range(count)
+        )  # [group·block_q, d_v]
+        out = (acc / l).astype(o_ref.dtype)
+        # A head's rows of the output go onto the lanes through the transpose
+        # unit; so do the logsumexp's, a lane tile a head, whose first lane is
+        # the row.
+        lse = jnp.broadcast_to(m + jnp.log(l), (group * block, 128)) if lse_ref is not None else None
+        for g in range(group):
+            o_ref[0, g * dim_v:(g + 1) * dim_v, :] = out[g * block:(g + 1) * block].T
+            if lse_ref is not None:
+                lse_ref[0, 0, g:g + 1, :] = lse[g * block:(g + 1) * block].T[0:1, :]
+
+    _for_band_cells(pl.program_id(2), resident, num_blocks, cell)
+
+
+def _band_kv_specs(resident: int, dim: int, dim_v: int, block: int, num_blocks: int) -> list:
+    """k, then v, ``resident`` times each: block ``t`` of a cell is the kv
+    block ``t`` before the q block's own (before a sequence's start: block 0
+    again, which no body reads and nothing new is fetched for; in the
+    backward's flush cells, past the last q block: the last cell's)."""
+    def index(t):
+        return lambda b, h, i: (b, h, jnp.maximum(jnp.minimum(i, num_blocks - 1) - t, 0))
+    return [pl.BlockSpec((1, d, block), index(t)) for d in (dim, dim_v) for t in range(resident)]
+
+
+def _sequence_off_lanes(x: jax.Array, heads: int) -> jax.Array:
+    """``[B, H·D, L]`` -> ``[B, L, H, D]``: :func:`_sequence_on_lanes` undone."""
+    batch, rows, length = x.shape
+    return jnp.transpose(x.reshape(batch, heads, rows // heads, length), (0, 3, 1, 2))
+
+
+def _band_geometry(q, k, v, block: int, window: int) -> tuple:
+    """``(batch, length, kv_heads, group, dim, dim_v, block, num_blocks,
+    resident)`` of a call :func:`band_form` gave the resident pair."""
+    batch, length, heads, dim = q.shape
+    block = _clamp_block(block, length)
+    return (batch, length, k.shape[2], heads // k.shape[2], dim, v.shape[-1], block, length // block,
+            band_resident_blocks(length, block, window))
+
+
+def _band_forward(q, k, v, scale, block, interpret, window, with_lse: bool = False):
+    """The resident forward on ``[B, L, H, D]`` operands where they lie; the
+    output as the kernel writes it, ``[B, H·D_v, L]``, and with ``with_lse``
+    the logsumexp, one float32 a row as ``[B, H_kv, group, L]``."""
+    batch, length, kv_heads, group, dim, dim_v, block, num_blocks, resident = _band_geometry(q, k, v, block, window)
+    if interpret is None:
+        interpret = _backend.default_interpret()
+    out_specs = [pl.BlockSpec((1, group * dim_v, block), lambda b, h, i: (b, h, i))]
+    out_shape = [jax.ShapeDtypeStruct((batch, kv_heads * group * dim_v, length), q.dtype)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((1, 1, group, block), lambda b, h, i: (b, h, 0, i)))
+        out_shape.append(jax.ShapeDtypeStruct((batch, kv_heads, group, length), jnp.float32))
+    k_lanes, v_lanes = _sequence_on_lanes(k), _sequence_on_lanes(v)
+    outs = pl.pallas_call(
+        functools.partial(
+            _band_fwd_kernel, resident=resident, group=group, dim=dim, dim_v=dim_v, block=block,
+            window=window, scale=scale, with_lse=with_lse, num_blocks=num_blocks,
+        ),
+        grid=(batch, kv_heads, num_blocks),
+        in_specs=[pl.BlockSpec((1, group * dim, block), lambda b, h, i: (b, h, i))]
+        + _band_kv_specs(resident, dim, dim_v, block, num_blocks),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_ONE_KERNEL_VMEM_LIMIT),
+        interpret=interpret,
+    )(_sequence_on_lanes(q), *([k_lanes] * resident), *([v_lanes] * resident))
+    return tuple(outs) if with_lse else outs[0]
+
+
+def _band_bwd_kernel(q_ref, do_ref, lse_ref, o_ref, *rest, resident: int, group: int, dim: int, dim_v: int,
+                     block: int, window: int, scale: float, num_blocks: int):
+    """dq of the cell's q block, whole, and the cell's share of dk and dv of
+    the kv blocks it sees; ``rest`` = (k_ref x resident, v_ref x resident,
+    dq_ref, dk_ref, dv_ref, dk_ring, dv_ring). kv block ``j`` lives in slot
+    ``j % resident`` of the float32 rings from cell ``j``, which opens it, to
+    cell ``j + resident - 1``, which adds the last share and writes it out.
+    The arithmetic of :func:`_in_place_bwd_kernel`: tiles transposed,
+    ``delta`` from the o and dO blocks the cell holds."""
+    k_refs, v_refs = rest[:resident], rest[resident:2 * resident]
+    dq_ref, dk_ref, dv_ref, dk_ring, dv_ring = rest[2 * resident:]
+    i = pl.program_id(2)
+
+    def cell(count: int):
+        k = [k_refs[t][0] for t in range(count)]  # [d, block]
+        k_t = [x.T for x in k]  # [block, d]
+        v_t = [v_refs[t][0].T for t in range(count)]  # [block, d_v]
+        keeps = _band_keeps(count, block, window, transposed=True)
+        dk, dv = [None] * count, [None] * count
+        for g in range(group):
+            q = q_ref[0, g * dim:(g + 1) * dim, :]  # [d, block]
+            do = do_ref[0, g * dim_v:(g + 1) * dim_v, :]  # [d_v, block]
+            out = o_ref[0, g * dim_v:(g + 1) * dim_v, :]
+            delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=0, keepdims=True)
+            lse = lse_ref[0, 0, g:g + 1, :]
+            dq = None
+            for t in range(count):
+                s = jax.lax.dot_general(
+                    k_t[t], q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                ) * scale  # [block_kv, block_q]
+                p = jnp.exp(s - lse)
+                if keeps[t] is not None:
+                    p = jnp.where(keeps[t], p, 0.0)
+                dp = jax.lax.dot_general(
+                    v_t[t], do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                ds = (p * (dp - delta)).astype(q.dtype)
+                dv_g = jax.lax.dot_general(
+                    p.astype(do.dtype), do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                dk_g = jax.lax.dot_general(ds, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                dq_g = jax.lax.dot_general(k[t], ds, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                dv[t] = dv_g if dv[t] is None else dv[t] + dv_g
+                dk[t] = dk_g if dk[t] is None else dk[t] + dk_g
+                dq = dq_g if dq is None else dq + dq_g
+            dq_ref[0, g * dim:(g + 1) * dim, :] = (dq * scale).astype(dq_ref.dtype)
+        for t in range(count):
+            slot = jax.lax.rem(i - t, resident)
+            if t == 0:  # the cell of a kv block's own q block opens its slot
+                dk_ring[slot], dv_ring[slot] = dk[t] * scale, dv[t]
+            else:
+                dk_ring[slot] += dk[t] * scale
+                dv_ring[slot] += dv[t]
+
+    _for_band_cells(i, resident, num_blocks, cell)
+
+    @pl.when(i >= resident - 1)
+    def _write():  # kv block i - (resident - 1) has met its last q block
+        slot = jax.lax.rem(i + 1, resident)
+        dk_ref[0] = dk_ring[slot].astype(dk_ref.dtype).T
+        dv_ref[0] = dv_ring[slot].astype(dv_ref.dtype).T
+
+
+def _band_backward(q, k, v, out, lse, g, scale, block, interpret, window):
+    """The resident backward, one call: ``out`` and ``lse`` as
+    :func:`_band_forward` wrote them; dq, and dk and dv at the key/value
+    heads in the operands' dtype, written ``[B, H·D, L]`` and returned as
+    the ``[B, L, H, D]`` views of that; nothing summed after the call."""
+    batch, length, kv_heads, group, dim, dim_v, block, num_blocks, resident = _band_geometry(q, k, v, block, window)
+    if interpret is None:
+        interpret = _backend.default_interpret()
+    # Past the last q block the flush cells name the last cell's blocks:
+    # nothing is fetched, and the dq block stays where it is until the end.
+    q_index = lambda b, h, i: (b, h, jnp.minimum(i, num_blocks - 1))
+    # dk and dv of kv block j leave after cell j + resident - 1; until the
+    # first block is whole the output block named is block 0, written last.
+    dkv_index = lambda b, h, i: (b, h, jnp.maximum(i - (resident - 1), 0))
+    k_lanes, v_lanes = _sequence_on_lanes(k), _sequence_on_lanes(v)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _band_bwd_kernel, resident=resident, group=group, dim=dim, dim_v=dim_v, block=block,
+            window=window, scale=scale, num_blocks=num_blocks,
+        ),
+        grid=(batch, kv_heads, num_blocks + resident - 1),
+        in_specs=[
+            pl.BlockSpec((1, group * dim, block), q_index),
+            pl.BlockSpec((1, group * dim_v, block), q_index),
+            pl.BlockSpec((1, 1, group, block), lambda b, h, i: (b, h, 0, jnp.minimum(i, num_blocks - 1))),
+            pl.BlockSpec((1, group * dim_v, block), q_index),
+        ] + _band_kv_specs(resident, dim, dim_v, block, num_blocks),
+        out_specs=[
+            pl.BlockSpec((1, group * dim, block), q_index),
+            pl.BlockSpec((1, dim, block), dkv_index),
+            pl.BlockSpec((1, dim_v, block), dkv_index),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, kv_heads * group * dim, length), q.dtype),
+            jax.ShapeDtypeStruct((batch, kv_heads * dim, length), k.dtype),
+            jax.ShapeDtypeStruct((batch, kv_heads * dim_v, length), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((resident, block, dim), jnp.float32),
+            pltpu.VMEM((resident, block, dim_v), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_ONE_KERNEL_VMEM_LIMIT),
+        interpret=interpret,
+    )(_sequence_on_lanes(q), _sequence_on_lanes(g), lse, out, *([k_lanes] * resident), *([v_lanes] * resident))
+    return _sequence_off_lanes(dq, q.shape[2]), _sequence_off_lanes(dk, kv_heads), _sequence_off_lanes(dv, kv_heads)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _band(q, k, v, scale, block, interpret, window):
+    return _sequence_off_lanes(_band_forward(q, k, v, scale, block, interpret, window), q.shape[2])
+
+
+def _band_fwd(q, k, v, scale, block, interpret, window):
+    # The residuals as :func:`_flash_fwd` names them: the output and the
+    # logsumexp as the kernel wrote them and as the backward kernel reads them.
+    kept, lse = _band_forward(q, k, v, scale, block, interpret, window, with_lse=True)
+    kept = checkpoint_name(kept, "flash_out")
+    return _sequence_off_lanes(kept, q.shape[2]), (q, k, v, kept, checkpoint_name(lse, "flash_lse"))
+
+
+def _band_bwd(scale, block, interpret, window, residuals, g):
+    q, k, v, out, lse = residuals
+    return _band_backward(q, k, v, out, lse, g, scale, block, interpret, window)
+
+
+_band.defvjp(_band_fwd, _band_bwd)
+
+
 def flash_attention(
     query: jax.Array,
     key: jax.Array,
@@ -1415,11 +1813,14 @@ def flash_attention(
         blocks a cell already amortise the grid's step, and VMEM holds
         ``block_b`` tiles of everything.
       window: with ``causal``, position ``i`` attends to ``i - window < j <=
-        i`` (itself and the ``window - 1`` before it). A q block visits the
+        i`` (itself and the ``window - 1`` before it). A q block meets the
         kv blocks from its first row's farthest column to its diagonal and
         no others, forward and backward; the far edge is masked in the
-        blocks it crosses. A window no shorter than the sequence is the
-        causal mask and runs the causal kernels.
+        blocks it crosses. Which kernels run the band is :func:`band_form`'s
+        rule on the shapes: the resident pair, whose grid is the band, or
+        the causal kernels' arm, which skips the cells outside it. A window
+        no shorter than the sequence is the causal mask and runs the causal
+        kernels.
 
     Returns:
       ``[B, q_len, heads, value_dim]`` in the query dtype.
@@ -1437,6 +1838,13 @@ def flash_attention(
     if bias is not None and bias.ndim != 4:
         raise ValueError(f"bias must be 4-D broadcastable, got {bias.shape}")
     window = effective_window(window, causal, query.shape[1])
+    banded = band_form(
+        query.shape[1], key.shape[1], query.shape[-1], value.shape[-1], heads=query.shape[2],
+        kv_heads=key.shape[2], window=window, biased=bias is not None, block_q=block_q, block_kv=block_kv,
+        itemsize=query.dtype.itemsize,
+    )
+    if banded == "resident":
+        return _band(query, key, value, float(scale), block_q, interpret, window)
     return _flash(
         query, key, value, bias, float(scale), block_q, block_kv, interpret,
         bool(causal), block_b, window,

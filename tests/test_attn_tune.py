@@ -138,6 +138,67 @@ def test_sweep_times_both_backward_forms_and_names_them(attn_tune, monkeypatch):
     assert budgets == [budget, 0] and flmod.ONE_KERNEL_VMEM_BUDGET == budget
 
 
+def test_sweep_of_a_window_times_both_bands_on_grouped_heads(attn_tune, monkeypatch):
+    """``--kv-heads`` and ``--window``: a pair of blocks is timed in the form
+    the band's rule gives it and, where that is the resident pair, in the
+    causal kernels' banded arm too, pinned while both directions trace; the
+    operands held with the sequence on the lanes or in their default order."""
+    flmod = attn_tune.flmod
+    bounds = []
+    real = flmod.band_form
+
+    def spy(*a, **kw):
+        bounds.append(flmod.BAND_MAX_UNROLLED_TILES)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(flmod, "band_form", spy)
+    bound = flmod.BAND_MAX_UNROLLED_TILES
+    for on_lanes in (True, False):
+        results, infeasible = attn_tune.sweep_shape(
+            (1, 256, 256, 4, 128), blocks=[(128, 128)], block_bs=[1], backends=["pallas"], iters=1, rounds=1,
+            bwd=True, log=lambda *_: None, causal=True, kv_heads=2, window=100, sequence_on_lanes=on_lanes,
+            dtype=jnp.float32,
+        )
+        assert not infeasible
+        assert [r["config"].get("band") for r in results] == ["resident", "skipped_cells", "skipped_cells"]
+        assert results[0]["config"]["block_b"] is None and results[0]["name"].endswith("band=resident")
+        assert all(r["fwd_bwd_ms"] is not None for r in results)
+    assert -1 in bounds and bound in bounds and flmod.BAND_MAX_UNROLLED_TILES == bound
+
+
+def test_a_window_sweep_writes_the_banded_key_and_names_its_command(attn_tune, tmp_path):
+    out = tmp_path / "cache.json"
+    attn_tune.main([
+        "--shapes", "1,256,4,128", "--kv-heads", "2", "--window", "100", "--causal", "--backends", "pallas",
+        "--blocks", "128,128", "--block-b", "1", "--iters", "1", "--rounds", "1", "--fwd-only",
+        "--sequence-on-lanes", "--dtype", "float32", "--out", str(out),
+    ])
+    entries = json.loads(out.read_text())["entries"]
+    key = "B*.Lq256.Lkv256.H4.D128.float32.causal.window100"
+    assert set(entries) == {key, key.replace("B*", "B1")}
+    assert "tools/attn_tune.py --kv-heads 2 --window 100 --sequence-on-lanes" in entries[key]["source"]
+    # A window no shorter than the sequence is the causal core's key.
+    attn_tune.main([
+        "--shapes", "1,256,4,128", "--window", "256", "--causal", "--backends", "xla", "--iters", "1",
+        "--rounds", "1", "--fwd-only", "--dtype", "float32", "--out", str(out),
+    ])
+    assert set(json.loads(out.read_text())["entries"]) == {"B*.Lq256.Lkv256.H4.D128.float32.causal",
+                                                           "B1.Lq256.Lkv256.H4.D128.float32.causal"}
+
+
+def test_on_lanes_holds_operands_where_a_projection_leaves_them(attn_tune):
+    """Operands held ``[B, H, D, L]`` are the ``[B, L, H, D]`` ones to the
+    function, and the transposes are traced inside it (two that cancel at
+    the kernels' own ``[B, H·D, L]`` view), not made ahead of it."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q, k, v = (jax.random.normal(key, (1, 64, h, 16)) for key, h in zip(ks, (4, 2, 2)))
+    fn = lambda q, k, v: attn_tune.att.xla_attention(q, k, v, causal=True, window=20)
+    held = [jnp.transpose(x, (0, 2, 3, 1)) for x in (q, k, v)]
+    np.testing.assert_allclose(np.asarray(attn_tune.on_lanes(fn)(*held)), np.asarray(fn(q, k, v)), atol=1e-6)
+    names = [eqn.primitive.name for eqn in jax.make_jaxpr(attn_tune.on_lanes(fn))(*held).jaxpr.eqns]
+    assert names[:3] == ["transpose"] * 3
+
+
 def test_sweep_precheck_skips_over_budget_without_compiling(attn_tune):
     """Configs the VMEM estimator rules out are recorded infeasible
     without paying a compile (block_b=8 at a deliberately fat shape)."""

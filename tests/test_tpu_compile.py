@@ -509,7 +509,7 @@ def _pallas_grids(jaxpr) -> list:
 # (name, [B, L, H, D], key/value heads, window, layout): the window/full hybrid
 # cell's two cores, and a causal cell's core (Ouro's) for what a window must not change.
 BAND_CASES = [
-    ("laguna_window", (1, 4096, 72, 128), 8, 512, "head_major"),
+    ("laguna_window", (1, 4096, 72, 128), 8, 512, "in_place"),
     ("laguna_full", (1, 4096, 48, 128), 8, None, "in_place"),
     ("ouro_causal", (2, 4096, 16, 128), 16, None, "in_place"),
 ]
@@ -521,11 +521,12 @@ BAND_CASES = [
 def test_banded_and_causal_cores_compile_at_the_cells_shapes(one_chip, shape, kv_heads, window, layout):
     """Forward and backward of ``flash_attention`` at the blocks the tune
     cache gives the shape (a banded core reads its own entries), compiled for
-    a described v5e: two Mosaic calls (the forward and the one-kernel
-    backward) on the full causal grid, of which a banded core visits the
-    band's cells alone. Without a window the program is the one a window no
-    shorter than the sequence builds: the same calls on the same grid, told
-    nothing of a window."""
+    a described v5e: two Mosaic calls, the forward and the one-kernel
+    backward: of a causal core on the full causal grid, of the banded core
+    (the resident pair at this shape) on a grid that is the band, a q block
+    and key/value head a cell. Without a window the program is the one a
+    window no shorter than the sequence builds: the same calls on the same
+    grid, told nothing of a window."""
     import importlib
 
     from sav_tpu.ops import attn_tuning
@@ -536,8 +537,14 @@ def test_banded_and_causal_cores_compile_at_the_cells_shapes(one_chip, shape, kv
     blocks = attn_tuning.block_config(entry)
     assert blocks and entry["backend"] == "pallas", "the cell's core shapes have measured entries"
     sizes = dict(batch_heads=batch * heads, **blocks)
-    assert flmod.layout_form(length, length, dim, dim, **sizes) == layout
-    assert flmod.backward_form(length, length, dim, dim, **sizes) == "one_kernel"
+    band = flmod.band_form(
+        length, length, dim, dim, heads=heads, kv_heads=kv_heads, window=window,
+        **{k: v for k, v in blocks.items() if k != "block_b"},
+    )
+    assert band == (None if window is None else "resident")
+    if band is None:
+        assert flmod.layout_form(length, length, dim, dim, **sizes) == layout
+        assert flmod.backward_form(length, length, dim, dim, **sizes) == "one_kernel"
 
     def spec(h):
         return jax.ShapeDtypeStruct((batch, length, h, dim), jnp.bfloat16, sharding=one_chip)
@@ -550,7 +557,10 @@ def test_banded_and_causal_cores_compile_at_the_cells_shapes(one_chip, shape, kv
 
     args = (spec(heads), spec(kv_heads), spec(kv_heads))
     q_blocks, kv_blocks = length // blocks["block_q"], length // blocks["block_kv"]
-    if layout == "in_place":  # a head a cell
+    if band == "resident":  # a q block and key/value head a cell, the ring's flush cells after the last
+        resident = flmod.band_resident_blocks(length, blocks["block_q"], window)
+        grids = [(batch, kv_heads, q_blocks), (batch, kv_heads, q_blocks + resident - 1)]
+    elif layout == "in_place":  # a head a cell
         grids = [(batch, heads, q_blocks, kv_blocks), (batch, heads, kv_blocks, q_blocks)]
     else:  # block_b slices a cell
         slices = batch * heads // blocks["block_b"]
@@ -562,6 +572,17 @@ def test_banded_and_causal_cores_compile_at_the_cells_shapes(one_chip, shape, kv
     counts = flmod.visited_blocks(length, length, window=window, **{k: v for k, v in blocks.items() if k != "block_b"})
     if window is not None:
         assert counts["kv_blocks_visited"] < counts["kv_blocks_causal"] <= q_blocks * kv_blocks
+        assert _kernel_vmem(compiled, "scoped_memory_configs") == [flmod._ONE_KERNEL_VMEM_LIMIT] * 2
+        used = [
+            total - offset for total, offset in zip(
+                _kernel_vmem(compiled, "used_scoped_memory_configs"), _kernel_vmem(compiled, "scoped_memory_configs", "offset"))
+        ]
+        estimate = flmod.band_vmem_bytes(heads // kv_heads, dim, dim, block=blocks["block_q"], resident=resident)
+        print(f"Mosaic VMEM used by the resident pair at {shape} {blocks}: forward {used[0] / 2**20:.1f} MiB of an "
+              f"estimated {estimate['forward'] / 2**20:.1f}, backward {used[1] / 2**20:.1f} of {estimate['backward'] / 2**20:.1f}")
+        # The rule's estimates bound what Mosaic takes, and the budget what the limit leaves.
+        assert used[0] <= estimate["forward"] <= flmod.ONE_KERNEL_VMEM_BUDGET
+        assert used[1] <= estimate["backward"] <= flmod.ONE_KERNEL_VMEM_BUDGET
         return
     assert counts["kv_blocks_visited"] == counts["kv_blocks_causal"]
     # The causal program of before: a window that hides nothing builds it, letter for letter.
@@ -570,14 +591,41 @@ def test_banded_and_causal_cores_compile_at_the_cells_shapes(one_chip, shape, kv
     assert str(jax.make_jaxpr(both(length))(*args)) == text
 
 
-def test_the_banded_core_compiles_in_place_too(one_chip):
-    """The in-place banded kernels at the cell's window shape and 512-row
-    blocks (the sweep's runner-up, what a model whose projections write the
-    sequence on the lanes may prefer): a head a cell through the index maps,
-    15 of 36 cells with work."""
+@pytest.mark.parametrize("block", [128, 256, 512])
+def test_the_resident_pair_compiles_at_each_block(one_chip, block):
+    """The resident pair at the cell's window shape at each block its sweep
+    times (five, three and two kv blocks a cell): one call a direction."""
     import importlib
 
     flmod = importlib.import_module("sav_tpu.ops.flash_attention")
+    blocks = dict(block_q=block, block_kv=block)
+    assert flmod.band_form(4096, 4096, 128, 128, heads=72, kv_heads=8, window=512, **blocks) == "resident"
+
+    def spec(h):
+        return jax.ShapeDtypeStruct((1, 4096, h, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flmod.flash_attention(q, k, v, causal=True, window=512, interpret=False, **blocks)
+        return jnp.sum(out.astype(jnp.float32))
+
+    both = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    args = (spec(72), spec(8), spec(8))
+    resident = flmod.band_resident_blocks(4096, block, 512)
+    assert _pallas_grids(jax.make_jaxpr(both)(*args).jaxpr) == [(1, 8, 4096 // block), (1, 8, 4096 // block + resident - 1)]
+    compiled = jax.jit(both).lower(*args).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_the_banded_core_compiles_in_place_too(one_chip, monkeypatch):
+    """The causal kernels' banded arm in place (what a shape the resident
+    pair does not take runs where its heads are whole lane tiles; here the
+    cell's window shape with the resident pair's rule pinned shut) at
+    512-row blocks: a head a cell through the index maps, 15 of 36 cells
+    with work."""
+    import importlib
+
+    flmod = importlib.import_module("sav_tpu.ops.flash_attention")
+    monkeypatch.setattr(flmod, "BAND_MAX_UNROLLED_TILES", -1)
     blocks = dict(block_q=512, block_kv=512, block_b=1)
     assert flmod.layout_form(4096, 4096, 128, 128, batch_heads=72, **blocks) == "in_place"
 

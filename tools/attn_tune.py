@@ -82,18 +82,31 @@ def make_loop(fn, args, iters):
     return lambda: jax.device_get(loop(*args))
 
 
-def grad_wrap(fn, cot):
-    """fwd+bwd callable whose cotangent is tied to the output (Trap 2)."""
+def grad_wrap(fn, cot, cot_on_lanes=False):
+    """fwd+bwd callable whose cotangent is tied to the output (Trap 2);
+    ``cot_on_lanes``: ``cot`` is held as ``[B, H, D, L]`` (:func:`on_lanes`)."""
 
     def run(q, k, v):
         out, vjp = jax.vjp(fn, q, k, v)
-        g = (cot + jnp.sum(out.astype(jnp.float32)) * 1e-30).astype(out.dtype)
+        tied = jnp.transpose(cot, (0, 3, 1, 2)) if cot_on_lanes else cot
+        g = (tied + jnp.sum(out.astype(jnp.float32)) * 1e-30).astype(out.dtype)
         dq, dk, dv = vjp(g)
+        if dk.shape != dq.shape:  # fewer key/value heads than query heads
+            return sum(jnp.sum(x.astype(jnp.float32)) for x in (dq, dk, dv))
         if dv.shape != dq.shape:  # a value head of its own size
             return jnp.concatenate([dq + dk, dv], axis=-1)
         return dq + dk + dv
 
     return run
+
+
+def on_lanes(fn):
+    """``fn`` on operands (and, through :func:`grad_wrap`, a cotangent) held
+    as ``[B, H, D, L]``, the order in which a model's projections and rotary
+    leave q, k and v (the sequence on the lanes): the transposes back to
+    ``[B, L, H, D]`` meet the in-place kernels' own and XLA copies nothing.
+    The gradients leave as the kernels write them."""
+    return lambda *held: fn(*(jnp.transpose(x, (0, 3, 1, 2)) for x in held))
 
 
 def _parse_shape(spec: str):
@@ -107,20 +120,25 @@ def _parse_shape(spec: str):
 
 
 def variant_specs(b, lq, lkv, h, d, *, blocks, block_bs, backends, itemsize,
-                  causal=False, dv=None):
+                  causal=False, dv=None, kv_heads=None, window=None):
     """Yield (name, backend, config, builder) for every candidate; builder
     returns the (q, k, v) -> out callable. Configs the VMEM estimator
     rules out are yielded with builder=None (recorded infeasible for free).
     The fused kernel has no causal arm and one head size: it is left out
-    of a sweep that asks for either.
+    of a sweep that asks for either, or for grouped heads (``kv_heads``).
+    Under a ``window`` each pair of blocks is swept in the form the kernel's
+    own rule gives it (``flash_attention.band_form``) and, where that is the
+    resident pair (which has no ``block_b``), in the causal kernels' banded
+    arm as well, pinned for the variant's compile.
     """
     bh = b * h
     dv = d if dv is None else dv
+    kv_heads = h if kv_heads is None else kv_heads
     if "xla" in backends:
         yield "xla", "xla", None, lambda: (
-            lambda q, k, v: att.xla_attention(q, k, v, causal=causal)
+            lambda q, k, v: att.xla_attention(q, k, v, causal=causal, window=window)
         )
-    if "fused" in backends and not causal and dv == d:
+    if "fused" in backends and not causal and dv == d and kv_heads == h:
         for bq, _ in blocks:
             for bb in block_bs:
                 if b % bb != 0:  # the fused kernel's cells hold batch elements
@@ -142,15 +160,23 @@ def variant_specs(b, lq, lkv, h, d, *, blocks, block_bs, backends, itemsize,
                     )
                 )
     if "pallas" in backends:
+        band_window = flmod.effective_window(window, causal, lq)
         for bq, bkv in blocks:
+            build = (
+                lambda bq=bq, bkv=bkv: lambda q, k, v: flmod.flash_attention(
+                    q, k, v, block_q=bq, block_kv=bkv, causal=causal, window=window
+                )
+            )
+            band = flmod.band_form(
+                lq, lkv, d, dv, heads=h, kv_heads=kv_heads, window=band_window, block_q=bq, block_kv=bkv,
+                itemsize=itemsize,
+            )
+            if band == "resident":
+                cfg = {"block_q": bq, "block_kv": bkv, "block_b": None, "band": band}
+                yield f"pallas bq={bq} bkv={bkv} band={band}", "pallas", cfg, build
             for bb in block_bs:
                 if bh % bb != 0:
                     continue
-                build = (
-                    lambda bq=bq, bkv=bkv: lambda q, k, v: flmod.flash_attention(
-                        q, k, v, block_q=bq, block_kv=bkv, causal=causal
-                    )
-                )
                 # Each form of the blocked backward the shape can run: the
                 # one the kernel's own rule picks, and where that is the
                 # one-kernel form, the two kernels as well (pinned for the
@@ -161,6 +187,8 @@ def variant_specs(b, lq, lkv, h, d, *, blocks, block_bs, backends, itemsize,
                 )
                 for bwd_form in dict.fromkeys((form, "two_kernels")):
                     cfg = {"block_q": bq, "block_kv": bkv, "block_b": bb, "backward": bwd_form}
+                    if band:
+                        cfg["band"] = "skipped_cells"
                     yield f"pallas bq={bq} bkv={bkv} bb={bb} bwd={bwd_form}", "pallas", cfg, build
 
 
@@ -170,13 +198,17 @@ class _pin_flash:
     variant's COMPILE (make_loop traces fwd AND bwd inside this scope —
     the backward's own _pick_block_b call and its choice of form at
     vjp-trace time must see the swept values too, not the defaults).
-    A no-op for block_b=None and the rule's own form."""
+    Likewise the banded arm of the causal kernels where the band's rule
+    would give the resident pair (``skipped_cells``). A no-op for
+    block_b=None and the rules' own forms."""
 
-    def __init__(self, bb, two_kernels=False):
-        self.bb, self.two_kernels = bb, two_kernels
+    def __init__(self, bb, two_kernels=False, skipped_cells=False):
+        self.bb, self.two_kernels, self.skipped_cells = bb, two_kernels, skipped_cells
 
     def __enter__(self):
-        self.orig = flmod._pick_block_b, flmod.ONE_KERNEL_VMEM_BUDGET
+        self.orig = flmod._pick_block_b, flmod.ONE_KERNEL_VMEM_BUDGET, flmod.BAND_MAX_UNROLLED_TILES
+        if self.skipped_cells:
+            flmod.BAND_MAX_UNROLLED_TILES = -1
         if self.bb is not None:
             bb = self.bb
             flmod._pick_block_b = (
@@ -187,26 +219,35 @@ class _pin_flash:
         return self
 
     def __exit__(self, *exc):
-        flmod._pick_block_b, flmod.ONE_KERNEL_VMEM_BUDGET = self.orig
+        flmod._pick_block_b, flmod.ONE_KERNEL_VMEM_BUDGET, flmod.BAND_MAX_UNROLLED_TILES = self.orig
         return False
 
 
 def sweep_shape(shape, *, blocks, block_bs, backends, iters, rounds,
-                dtype=jnp.bfloat16, bwd=True, log=print, causal=False):
-    """Measure one shape; returns (results, infeasible) lists."""
+                dtype=jnp.bfloat16, bwd=True, log=print, causal=False,
+                kv_heads=None, window=None, sequence_on_lanes=False):
+    """Measure one shape; returns (results, infeasible) lists. With
+    ``sequence_on_lanes`` the operands and the cotangent are held where a
+    model's projections leave them (:func:`on_lanes`); else in their default
+    order, and a kernel that wants another pays its copies inside its time."""
     b, lq, lkv, h, d = shape[:5]
     dv = shape[5] if len(shape) == 6 else d
+    kv_heads = h if kv_heads is None else kv_heads
     rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((b, lq, h, d)), dtype=dtype)
-    k = jnp.asarray(rng.standard_normal((b, lkv, h, d)), dtype=dtype)
-    v = jnp.asarray(rng.standard_normal((b, lkv, h, dv)), dtype=dtype)
-    cot = jnp.asarray(rng.standard_normal((b, lq, h, dv)), dtype=jnp.float32)
+
+    def draw(length, heads, dim, as_dtype):
+        held = (b, heads, dim, length) if sequence_on_lanes else (b, length, heads, dim)
+        return jnp.asarray(rng.standard_normal(held), dtype=as_dtype)
+
+    q, k, v = draw(lq, h, d, dtype), draw(lkv, kv_heads, d, dtype), draw(lkv, kv_heads, dv, dtype)
+    cot = draw(lq, h, dv, jnp.float32)
+    held = on_lanes if sequence_on_lanes else (lambda fn: fn)
 
     results, infeasible, loops = [], [], {}
     for name, backend, cfg, build in variant_specs(
         b, lq, lkv, h, d, blocks=blocks, block_bs=block_bs,
         backends=backends, itemsize=jnp.dtype(dtype).itemsize,
-        causal=causal, dv=dv,
+        causal=causal, dv=dv, kv_heads=kv_heads, window=window,
     ):
         if build is None:
             infeasible.append({
@@ -217,14 +258,15 @@ def sweep_shape(shape, *, blocks, block_bs, backends, iters, rounds,
             continue
         pin_bb = (cfg or {}).get("block_b") if backend == "pallas" else None
         two_kernels = (cfg or {}).get("backward") == "two_kernels"
+        skipped_cells = (cfg or {}).get("band") == "skipped_cells"
         try:
             fn = build()
             entry = {"name": name, "backend": backend, "config": cfg}
-            with _pin_flash(pin_bb, two_kernels):
-                entry["_fwd"] = make_loop(fn, (q, k, v), iters)
+            with _pin_flash(pin_bb, two_kernels, skipped_cells):
+                entry["_fwd"] = make_loop(held(fn), (q, k, v), iters)
                 if bwd:
                     entry["_bwd"] = make_loop(
-                        grad_wrap(fn, cot), (q, k, v), iters
+                        held(grad_wrap(fn, cot, sequence_on_lanes)), (q, k, v), iters
                     )
             loops[name] = entry
         except Exception as e:  # noqa: BLE001 — a bad config must not kill the sweep
@@ -316,6 +358,13 @@ def main(argv=None):
     p.add_argument("--dtype", default="bfloat16")
     p.add_argument("--causal", action="store_true",
                    help="sweep the causal core (its entries are keyed .causal)")
+    p.add_argument("--kv-heads", type=int, default=None,
+                   help="key/value heads, where fewer than the shape's query heads (grouped-query attention)")
+    p.add_argument("--window", type=int, default=None,
+                   help="with --causal: a sliding window's positions (entries keyed .causal.window<W>)")
+    p.add_argument("--sequence-on-lanes", action="store_true",
+                   help="hold q, k, v and the cotangent as [B, H, D, L], where a model's projections "
+                   "leave them: the in-place kernels then pay no layout copy, as inside a train step")
     p.add_argument(
         "--out", default=".tpu_results/attn_tune_cache.json",
         help="shape→config cache to write (the dispatcher-consumable JSON)",
@@ -351,28 +400,34 @@ def main(argv=None):
         shape = _parse_shape(spec)
         b, lq, lkv, h, d = shape[:5]
         dv = shape[5] if len(shape) == 6 else d
-        print(f"== shape B={b} Lq={lq} Lkv={lkv} H={h} D={d} Dv={dv} causal={args.causal} "
-              f"({dtype.name})", flush=True)
+        print(f"== shape B={b} Lq={lq} Lkv={lkv} H={h} on {args.kv_heads or h} D={d} Dv={dv} "
+              f"causal={args.causal} window={args.window} ({dtype.name})", flush=True)
         results, infeasible = sweep_shape(
             shape, blocks=blocks, block_bs=block_bs, backends=backends,
             iters=args.iters, rounds=args.rounds, dtype=dtype,
-            bwd=not args.fwd_only, causal=args.causal,
+            bwd=not args.fwd_only, causal=args.causal, kv_heads=args.kv_heads,
+            window=args.window, sequence_on_lanes=args.sequence_on_lanes,
         )
-        key = attn_tuning.shape_key(b, lq, lkv, h, d, dtype, args.causal, dv)
+        window = flmod.effective_window(args.window, args.causal, lq)
+        key = attn_tuning.shape_key(b, lq, lkv, h, d, dtype, args.causal, dv, window)
         if infeasible:
             infeasible_all[key] = infeasible
         winner = pick_winner(results, bwd=not args.fwd_only)
         if winner is None:
             print("  (no feasible variant)", flush=True)
             continue
+        asked = "".join(
+            f" {flag} {value}" for flag, value in (("--kv-heads", args.kv_heads), ("--window", args.window))
+            if value is not None
+        ) + (" --sequence-on-lanes" if args.sequence_on_lanes else "")
         src = (
-            f"tools/attn_tune.py on {device} "
+            f"tools/attn_tune.py{asked} on {device} "
             f"({'fwd' if args.fwd_only else 'fwd+bwd'} min of "
             f"{args.rounds}x{args.iters})"
         )
         entries[key] = winner_entry(winner, src)
         if args.star_batch:
-            entries[attn_tuning.shape_key("*", lq, lkv, h, d, dtype, args.causal, dv)] = (
+            entries[attn_tuning.shape_key("*", lq, lkv, h, d, dtype, args.causal, dv, window)] = (
                 winner_entry(winner, src + f" at B={b}")
             )
         print(f"  -> winner: {winner['name']}", flush=True)
